@@ -20,6 +20,8 @@ import numpy as np
 
 def run_and_trace(iters=6):
     import jax
+    from paddle_tpu.device import require_tpu
+    require_tpu("benchmarks/profile_bert.py")
     import jax.numpy as jnp
     import paddle_tpu as paddle
     from benchmarks.configs_bench import _bert_job
@@ -72,6 +74,7 @@ CATS = [
 
 
 def parse(tdir, iters, flops):
+    from paddle_tpu.observability.flops import peak_flops
     paths = glob.glob(os.path.join(
         tdir, "**", "*.trace.json.gz"), recursive=True)
     if not paths:
@@ -111,7 +114,8 @@ def parse(tdir, iters, flops):
     top = sorted(per_step.items(), key=lambda kv: -kv[1])[:35]
     print(f"== total device time/step: {total/iters:.2f} ms "
           f"(useful {flops/1e12:.2f} TF -> "
-          f"{flops/ (total/iters/1e3)/197e12*100:.1f}% MFU if device-bound)")
+          f"{flops / (total / iters / 1e3) / peak_flops() * 100:.1f}% MFU "
+          "if device-bound)")
     print("== top ops (ms/step):")
     for k, v in top:
         print(f"  {v:8.3f}  {k[:110]}")

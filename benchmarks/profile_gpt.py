@@ -19,6 +19,8 @@ def run_and_trace(iters=3):
     import jax
     import jax.numpy as jnp
     import paddle_tpu as paddle
+    from paddle_tpu.device import require_tpu
+    require_tpu("benchmarks/profile_gpt.py")
     from paddle_tpu.models import gpt as G
     from bench import FLAGSHIP
 

@@ -4,8 +4,8 @@ The param-streaming tier (distributed/sharding/param_stream.py) keeps
 params AND optimizer moments in host memory (pinned_host) and streams one
 transformer block at a time through HBM — forward and backward, with the
 Adam update fused into the backward so gradients never exist model-wide.
-This is how GPT-3 6.7B and Llama-2 7B train on a single 16 GB v5e
-(BASELINE.md; reference analogue: GroupShardedStage3 param slicing with
+This is how GPT-3 6.7B and Llama-2 7B are meant to train on a single
+16 GB v5e (not measured on the current installation; reference analogue: GroupShardedStage3 param slicing with
 gather-on-use + offload, group_sharded_stage3.py:85).
 
 Run (CPU demo shapes):   python examples/train_bigger_than_hbm.py

@@ -13,10 +13,7 @@ Brand-new framework with the capabilities of the PaddlePaddle reference
 The public API mirrors the reference's `paddle.*` surface so users can port.
 """
 
-from . import utils  # noqa: F401  (installs the jax version-compat shims
-#                      — e.g. jax.lax.axis_size on 0.4.x — BEFORE any
-#                      module that traces with them; engine/model modules
-#                      must never depend on who imported utils first)
+from . import utils  # noqa: F401
 from . import dtypes  # noqa: F401
 from .dtypes import *  # noqa: F401,F403
 from . import flags as _flags_mod  # noqa: F401

@@ -27,9 +27,10 @@ multiplier would break the model's measurement-validated contract — the
 constraint checker still guarantees emitted fp8 configs compose legally.
 
 Model constants (the ``_HIDE_*`` tables, ``gemm_efficiency``) are
-calibrated against this repo's recorded rounds — BASELINE.md round 5/6
-(mp_overlap temp-bytes + the CPU-proxy op-count ordering), the PR 2
-bucketed-overlap deltas — and are *re-calibratable from measurement*:
+table constants from earlier rounds (mp_overlap temp-bytes, the
+CPU-mesh op-count ordering, the PR 2 bucketed-overlap deltas) that no run
+on the current installation has checked — and are *re-calibratable from
+measurement*:
 :meth:`CostModel.calibrate` fits the compute rate and per-collective
 launch overhead to a measured anchor sweep (``auto_tuner.sweep``), which
 is how the CPU-smoke validation closes the loop between predicted and
@@ -43,6 +44,9 @@ import itertools
 import math
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+from ...observability.flops import (CPU_NOMINAL_PEAK, PROFILE_PEAKS as _PEAK,
+                                     chip_profile_name)
+
 __all__ = ["PlanCandidate", "ModelSpec", "HardwareProfile", "profile_for",
            "KNOWN_PROFILES", "CostModel", "Prediction",
            "generate_plan_candidates", "plan", "PlanReport", "ScoredPlan",
@@ -53,9 +57,9 @@ SCHEDULES = ("1f1b", "zbh1", "interleaved")
 MP_OVERLAP_MODES = (None, "seq_parallel", "collective_matmul")
 
 # T3-style hidable fractions: the share of a mode's wire time the
-# adjacent compute can hide (exposed = wire * (1 - hide)). Calibrated to
-# the recorded rounds: plain allreduce TP leaves most of the wire exposed
-# (the 43.3% multichip MFU of BENCH_r05's secondary), seq-parallel's
+# adjacent compute can hide (exposed = wire * (1 - hide)). Table
+# constants, never measured on the current installation: plain allreduce
+# TP leaves most of the wire exposed, seq-parallel's
 # AG/RS pairs schedule async against the GEMMs, the ring collective
 # matmul interleaves chunk transfers with partial products (PR 5).
 # These are the TABLE defaults; a measured HardwareProfile (the
@@ -94,14 +98,14 @@ HIDE_KEYS = ("mp:allreduce", "mp:seq_parallel", "mp:collective_matmul",
 class HardwareProfile:
     """Per-chip rates the cost model converts bytes/flops into seconds
     with. ``gemm_efficiency`` is the achievable fraction of peak on the
-    dense stack (the measured-or-peak rate: ~0.6 is this repo's measured
-    flagship MFU); ``collective_launch_s`` is the per-collective dispatch
+    dense stack (a table constant until a chip run calibrates it);
+    ``collective_launch_s`` is the per-collective dispatch
     overhead — microseconds on TPU, ~fractions of a millisecond on the
     CPU smoke mesh where collectives are scheduler ops, which is exactly
-    why the CPU proxy ranks mp modes by op count (BASELINE.md round 6)
+    why the CPU proxy ranks mp modes by op count
     while a real pod ranks them by exposed wire."""
     name: str = "tpu-v5e"
-    peak_flops: float = 197e12
+    peak_flops: float = _PEAK["tpu-v5e"]
     hbm_gb: float = 16.0
     ici_gbs: float = 45.0
     collective_launch_s: float = 2e-6
@@ -123,16 +127,24 @@ class HardwareProfile:
     source: str = dataclasses.field(default="table", compare=False)
 
 
+# peaks come from the one table (observability.flops.CHIP_PEAKS); the
+# rows here add what the cost model needs beside them
 KNOWN_PROFILES: Dict[str, HardwareProfile] = {
-    "tpu-v5e": HardwareProfile("tpu-v5e", 197e12, 16.0, 45.0, 2e-6, 0.6),
-    "tpu-v5p": HardwareProfile("tpu-v5p", 459e12, 95.0, 90.0, 2e-6, 0.6),
-    "tpu-v4": HardwareProfile("tpu-v4", 275e12, 32.0, 45.0, 2e-6, 0.6),
-    "tpu-v6e": HardwareProfile("tpu-v6e", 918e12, 32.0, 90.0, 2e-6, 0.6),
-    "tpu-v3": HardwareProfile("tpu-v3", 123e12, 16.0, 35.0, 2e-6, 0.6),
-    # CPU smoke mesh: nominal 1e12 "peak" (flops.peak_flops convention),
-    # collectives are cheap memcpys but each costs real scheduling time,
-    # and nothing hides under anything (overlap_capable=False).
-    "cpu": HardwareProfile("cpu", 1e12, 4.0, 8.0, 5e-4, 0.5,
+    "tpu-v5e": HardwareProfile("tpu-v5e", _PEAK["tpu-v5e"], 16.0, 45.0,
+                               2e-6, 0.6),
+    "tpu-v5p": HardwareProfile("tpu-v5p", _PEAK["tpu-v5p"], 95.0, 90.0,
+                               2e-6, 0.6),
+    "tpu-v4": HardwareProfile("tpu-v4", _PEAK["tpu-v4"], 32.0, 45.0,
+                              2e-6, 0.6),
+    "tpu-v6e": HardwareProfile("tpu-v6e", _PEAK["tpu-v6e"], 32.0, 90.0,
+                               2e-6, 0.6),
+    "tpu-v3": HardwareProfile("tpu-v3", _PEAK["tpu-v3"], 16.0, 35.0,
+                              2e-6, 0.6),
+    # CPU dry-run mesh: the nominal flops.CPU_NOMINAL_PEAK (not a device
+    # metric), collectives are cheap memcpys but each costs real
+    # scheduling time, and nothing hides under anything
+    # (overlap_capable=False).
+    "cpu": HardwareProfile("cpu", CPU_NOMINAL_PEAK, 4.0, 8.0, 5e-4, 0.5,
                            overlap_capable=False),
 }
 
@@ -141,22 +153,7 @@ def profile_for(devices=None, *, hbm_gb: Optional[float] = None
                 ) -> HardwareProfile:
     """Profile of the current backend (flag/CLI ``--hbm-gb`` overrides the
     budget — FLAGS_auto_parallel_hbm_gb is read by the CLI/launcher)."""
-    import jax
-    devices = devices if devices is not None else jax.devices()
-    kind = (getattr(devices[0], "device_kind", "") or "").lower()
-    plat = devices[0].platform.lower()
-    name = "cpu"
-    if plat == "tpu":
-        for key, prof in (("v5 lite", "tpu-v5e"), ("v5litepod", "tpu-v5e"),
-                          ("v5e", "tpu-v5e"), ("v5p", "tpu-v5p"),
-                          ("v6", "tpu-v6e"), ("v4", "tpu-v4"),
-                          ("v3", "tpu-v3")):
-            if key in kind:
-                name = prof
-                break
-        else:
-            name = "tpu-v5e"
-    prof = KNOWN_PROFILES[name]
+    prof = KNOWN_PROFILES[chip_profile_name(devices)]
     if hbm_gb is not None and hbm_gb > 0:
         prof = dataclasses.replace(prof, hbm_gb=float(hbm_gb))
     return prof
@@ -665,7 +662,7 @@ class CostModel:
     (c) per-collective launch overhead — n_collectives x
         ``collective_launch_s``; negligible on TPU, DOMINANT on the CPU
         smoke mesh (which is why the CPU proxy ranks ring > sp >
-        allreduce by op count — BASELINE.md round 6 — while the same
+        allreduce by op count, while the same
         model with TPU rates ranks them the other way around).
     """
 

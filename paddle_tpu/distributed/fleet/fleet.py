@@ -214,9 +214,7 @@ class Fleet:
             return fn, out, vol
 
         fn, out_spec, vol = make(comm_type)
-        # version-proof shard_map (jax 0.4.x has no top-level jax.shard_map
-        # and spells the replication-check kwarg check_rep) — the same
-        # compat shim every engine uses
+        # the engine's shard_map wrapper (varying-axes check off)
         from ...utils import shard_map as _smap
         for mb in sizes_mb:
             elems = max(mb * (1 << 20) // 4 // (n * n) * (n * n), n * n)

@@ -393,13 +393,8 @@ _ring_tiled.defvjp(_ring_tiled_fwd, _ring_tiled_bwd)
 
 def _pvary(x, axis):
     """Mark a freshly-created constant as device-varying over `axis`
-    (shard_map's varying-axis type system; no-op on older jax). pcast
-    first: lax.pvary is deprecated where both exist."""
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, (axis,), to="varying")
-    if hasattr(lax, "pvary"):
-        return lax.pvary(x, (axis,))
-    return x
+    (shard_map's varying-axis type system)."""
+    return lax.pcast(x, (axis,), to="varying")
 
 
 def ulysses_attention(q, k, v, axis: str = "sep", causal: bool = False,

@@ -8,8 +8,7 @@ the real shape over a virtual device mesh: XLA partitions, schedules and
 memory-plans the program exactly as it would on hardware, but no 27 GB
 parameter tree ever exists. The compiled executable's
 ``memory_analysis()`` gives per-device argument/temp bytes — the numbers
-a v5p-128 deployment plans against (BASELINE.md "6.7B multi-chip
-projection").
+a v5p-128 deployment plans against.
 
 Reference anchor: the reference's hybrid tests train real Llama-shaped
 models (test/auto_parallel/hybrid_strategy/semi_auto_parallel_llama_model

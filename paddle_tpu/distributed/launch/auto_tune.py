@@ -68,8 +68,12 @@ def _launcher_profile(cfg_json: dict):
     — on a TPU host that would lock libtpu before the trial subprocesses
     spawn and every trial would fail to acquire the chip. Resolution:
     explicit json "profile" name > env sniff (JAX_PLATFORMS=cpu) >
-    generic TPU default. (The planner math is trace/shape-only and never
-    initializes a backend either.)"""
+    tpu-v5e. That last guess is DELIBERATE and the one place a chip is
+    assumed without asking the device: asking would load the TPU library
+    in the launcher. It only orders trials (each trial measures itself on
+    the real device, where an unknown kind raises); name the profile in
+    the json to rank for another chip. (The planner math is
+    trace/shape-only and never initializes a backend either.)"""
     from ..auto_tuner import KNOWN_PROFILES
     name = cfg_json.get("profile")
     if name is None:

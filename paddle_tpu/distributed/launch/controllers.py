@@ -31,6 +31,17 @@ ELASTIC_EXIT_CODE = 101           # worker requests rescheduling
 ELASTIC_AUTO_PARALLEL_EXIT_CODE = 102
 
 
+def _tpu_host(envs) -> bool:
+    """Whether workers started here would run on TPU chips — decided
+    WITHOUT touching jax (the launcher must never load the TPU library:
+    its workers need the chips): the platform is not pinned to the CPU
+    and the host exposes accelerator device nodes."""
+    import glob
+    if str(envs.get("JAX_PLATFORMS", "")).startswith("cpu"):
+        return False
+    return bool(glob.glob("/dev/accel*") or glob.glob("/dev/vfio/[0-9]*"))
+
+
 class Master:
     """Rendezvous over the TCPStore: every node publishes its endpoints,
     node 0 aggregates and republishes the full list."""
@@ -177,6 +188,16 @@ class CollectiveController:
                 self._elastic.store.delete_key(
                     self._elastic._key("registered_count"))
         ctx = self.ctx
+        if ctx.nproc > 1 and _tpu_host(ctx.envs):
+            # nothing here confines a worker to one chip
+            # (PADDLE_DEVICE_ID is a label): N workers would each open
+            # every chip, and a chip belongs to one process
+            raise RuntimeError(
+                f"--nproc_per_node {ctx.nproc} on a TPU host: every worker "
+                "would open all of the host's chips, and a chip belongs to "
+                "one process. Launch ONE process per host "
+                "(--nproc_per_node 1) and build the mesh over its chips in "
+                "that process (paddle_tpu.distributed.build_mesh).")
         from ...flags import flag
         base_port = (int(flag("launch_base_port"))
                      + (os.getpid() + generation * 131) % 2000)

@@ -37,9 +37,8 @@ backward that only accumulates the fp32 global grad-norm² (the forward's
 cached boundary activations serve both passes — no second forward), then
 pass 2 is the normal fused update backward with every grad scaled by the
 shared clip coefficient. Cost: one extra param down-stream + backward
-flops (measured +26% step time on the host-link-bound tiers: 25.4 vs
-20.2 s/step on the 6.7B GPT, 27.7 vs 22.0 on Llama-2 7B — BASELINE.md
-round 5). By-value clip
+flops (its step-time cost on the host-link-bound tiers is not measured
+on the current installation). By-value clip
 is free — it fuses into the per-block update. Reference equivalents:
 GroupShardedStage3 param slicing with clip (group_sharded_stage3.py:85
 region) and HybridParallelClipGrad (hybrid_parallel_optimizer.py:41).
@@ -76,7 +75,7 @@ def _pinned_host_supported(device) -> bool:
 
 def supports_pinned_host(device=None) -> bool:
     """Whether the backend can address a ``pinned_host`` memory kind (TPU
-    runtimes can; CPU jax 0.4.x exposes only ``unpinned_host``). The
+    runtimes can; the CPU backend exposes only ``unpinned_host``). The
     offload/streaming tiers need it; tests skip cleanly without it."""
     return _pinned_host_supported(_dev(device))
 

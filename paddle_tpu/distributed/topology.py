@@ -233,11 +233,10 @@ def build_mesh(dims: Dict[str, int], devices: Optional[Sequence] = None) -> Mesh
     if n_proc > 1:
         arr = _hybrid_device_array(shape, devices)
     elif all(getattr(d, "platform", "") == "tpu" for d in devices):
-        try:
-            from jax.experimental import mesh_utils
-            arr = mesh_utils.create_device_mesh(shape, devices=devices)
-        except Exception:
-            arr = np.array(devices).reshape(shape)
+        # no silent flat-reshape fallback: a layout mesh_utils cannot
+        # place on the physical topology is an error the caller must see
+        from jax.experimental import mesh_utils
+        arr = mesh_utils.create_device_mesh(shape, devices=devices)
     else:
         arr = np.array(devices).reshape(shape)
     return Mesh(arr, tuple(dims.keys()))

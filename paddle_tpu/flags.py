@@ -266,7 +266,21 @@ define_flag("dump_dir", "",
 # --- compile / cache -------------------------------------------------------
 
 
+# The one in-checkout home of the persistent compile cache (git-ignored).
+# The path is part of the cache key, so it is FIXED: never built from
+# tempfile, a pid or the time. The chip entries chip_smoke.py and bench.py
+# set FLAGS_jit_cache_dir to it.
+REPO_JIT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
+
+
 def _bind_cache_dir(v):
+    # JAX_COMPILATION_CACHE_DIR places the cache from outside: jax reads
+    # it into its own config, and no code here may set another directory
+    # (or reset it — the tests' autouse fixture re-applies flags).
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
     import jax
     jax.config.update("jax_compilation_cache_dir", v if v else None)
 
@@ -274,15 +288,13 @@ def _bind_cache_dir(v):
 define_flag("jit_cache_dir", "",
             "Persistent XLA compilation cache directory (bound to "
             "jax_compilation_cache_dir; the reference caches cuDNN algo "
-            "choices — TPU caches whole executables).",
+            "choices — TPU caches whole executables). Ignored where "
+            "JAX_COMPILATION_CACHE_DIR is set: that directory wins.",
             on_set=_bind_cache_dir)
 def _bind_cache_min_time(v):
     import jax
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          float(v))
-    except Exception:
-        pass  # older jax: knob absent
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      float(v))
 
 
 define_flag("jit_cache_min_compile_time_secs", 1.0,

@@ -404,6 +404,21 @@ def run_serving_resilient(
 
 
 # -- spawn-based acceptance harness (the resilience_worker pattern) ----------
+def refuse_cpu_children_on_tpu(what: str) -> None:
+    """Spawned serving workers pin ``JAX_PLATFORMS=cpu`` — a chip belongs
+    to one process, and the parent holds it. That is right when the
+    parent itself runs on the CPU (the tests). On a TPU parent it would
+    quietly serve from the CPU, so refuse instead."""
+    import jax
+    if jax.default_backend() == "tpu":
+        raise RuntimeError(
+            f"{what} starts CPU-pinned child processes, but this process "
+            "runs on the TPU and a chip belongs to one process: the "
+            "children would silently serve from the CPU. Run the replicas "
+            "in this process, each on its own device "
+            "(paddle_tpu.inference.router.InProcessReplica).")
+
+
 def kill_replay_check(workdir: str, *, ragged: bool = False,
                       timeout: float = 300.0) -> Dict[str, Any]:
     """Hard-kill-and-replay acceptance (ISSUE 13): spawn the replay
@@ -418,6 +433,7 @@ def kill_replay_check(workdir: str, *, ragged: bool = False,
     import sys
     from ..distributed.resilience.faults import FAULT_EXIT_CODE
 
+    refuse_cpu_children_on_tpu("kill_replay_check")
     repo = os.path.dirname(os.path.dirname(
         os.path.dirname(os.path.abspath(__file__))))
 

@@ -284,6 +284,8 @@ class SpawnedReplica(_ReplicaBase):
     def start(self) -> None:
         import subprocess
         import sys
+        from .resilient import refuse_cpu_children_on_tpu
+        refuse_cpu_children_on_tpu("SpawnedReplica")
         _faults().maybe_fail("replica/spawn")
         self.gen += 1
         self._close_inbox_handle()

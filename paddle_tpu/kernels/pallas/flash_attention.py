@@ -410,14 +410,10 @@ def _build_specs(*, grid_kind, h, h_kv, g, nq, block_q, block_k, d,
 def _sds(shape, dtype, vma=None):
     """ShapeDtypeStruct with an optional varying-mesh-axes set — required
     when the kernel runs inside shard_map with check_vma=True (the ring
-    attention path passes its mesh axis here). Older jax has no vma kwarg
-    (and no vma checking): degrade gracefully."""
+    attention path passes its mesh axis here)."""
     if vma is None:
         return jax.ShapeDtypeStruct(shape, dtype)
-    try:
-        return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(vma))
-    except TypeError:  # pre-vma jax: nothing to declare
-        return jax.ShapeDtypeStruct(shape, dtype)
+    return jax.ShapeDtypeStruct(shape, dtype, vma=frozenset(vma))
 
 
 def _prep_mask_operands(qseg, kseg, fm_start, fm_end):

@@ -162,6 +162,7 @@ def adam_update(p, g, slot, lr, step, rng, *, beta1, beta2, epsilon,
         out_shape=outs,
         input_output_aliases=aliases,
         interpret=_interpret(),
+        name="fused_adam",  # stable: chip_smoke.py finds it in the step
     )(scalars, seed, *ins)
     new_p = res[0].reshape(shape)
     out = {"moment1": res[1].reshape(shape),

@@ -20,12 +20,13 @@ Conventions (the PaLM/Chinchilla accounting):
 
 from __future__ import annotations
 
-from typing import Any, Dict, Optional
+from typing import Any, Dict, Optional, Tuple
 
 __all__ = ["transformer_flops_per_token", "attention_flops_per_token",
            "gpt_flops_per_token",
            "llama_flops_per_token", "gpt_moe_flops_per_token",
-           "param_count", "mfu", "peak_flops",
+           "param_count", "mfu", "peak_flops", "chip_profile_name",
+           "CHIP_PEAKS", "PROFILE_PEAKS", "CPU_NOMINAL_PEAK",
            "collective_seconds", "plan_wire_bytes"]
 
 _REMAT_MODES = ("none", "full", "selective")
@@ -174,20 +175,52 @@ def gpt_moe_flops_per_token(cfg, *, tokens_per_rank: int,
     }
 
 
-def peak_flops(devices=None) -> float:
-    """Per-chip peak (bf16 matmul FLOP/s) of the current backend. Known
-    TPU generations by device_kind; CPU gets a nominal 1e12 so MFU-shaped
-    numbers stay finite in smoke runs (never comparable to TPU rounds)."""
+# THE peak table: per-chip bf16 matmul FLOP/s keyed by the device_kind
+# jax reports, with the auto-tuner profile name each kind maps to. Every
+# MFU and every planner profile reads it; no peak literal lives anywhere
+# else. Source: Google Cloud TPU documentation, the per-generation system
+# architecture pages ("TPU v5e": 197 TFLOP/s bf16 per chip; v2 45, v3 123,
+# v4 275, v5p 459, v6e 918). An unknown TPU kind is an error, never a
+# default — add the row with its source.
+CHIP_PEAKS: Dict[str, Tuple[str, float]] = {
+    "TPU v2": ("tpu-v2", 45e12),
+    "TPU v3": ("tpu-v3", 123e12),
+    "TPU v4": ("tpu-v4", 275e12),
+    "TPU v5 lite": ("tpu-v5e", 197e12),
+    "TPU v5": ("tpu-v5p", 459e12),
+    "TPU v5p": ("tpu-v5p", 459e12),
+    "TPU v6 lite": ("tpu-v6e", 918e12),
+}
+PROFILE_PEAKS: Dict[str, float] = dict(CHIP_PEAKS.values())
+# The CPU has no published matmul peak. Tier-1 tests and CPU dry runs get
+# this nominal value so MFU-SHAPED numbers stay finite; it is not a device
+# metric, and every chip entry refuses a non-TPU platform before it could
+# reach it.
+CPU_NOMINAL_PEAK = 1e12
+
+
+def chip_profile_name(devices=None) -> str:
+    """Auto-tuner profile name of the current backend: the CHIP_PEAKS row
+    of a TPU's device_kind (unknown kind = error), ``"cpu"`` on the CPU."""
     import jax
-    devices = devices if devices is not None else jax.devices()
-    kind = (getattr(devices[0], "device_kind", "") or "").lower()
-    table = {"v5 lite": 197e12, "v5litepod": 197e12, "v5e": 197e12,
-             "v5p": 459e12, "v4": 275e12, "v6e": 918e12,
-             "v6 lite": 918e12, "v3": 123e12, "v2": 45e12}
-    for key, val in table.items():
-        if key in kind:
-            return val
-    return 197e12 if devices[0].platform.lower() == "tpu" else 1e12
+    dev = (devices if devices is not None else jax.devices())[0]
+    if dev.platform == "cpu":
+        return "cpu"
+    kind = getattr(dev, "device_kind", "")
+    if dev.platform != "tpu" or kind not in CHIP_PEAKS:
+        raise ValueError(
+            f"no published peak for platform {dev.platform!r} device_kind "
+            f"{kind!r}: add it, with its source, to "
+            "observability.flops.CHIP_PEAKS")
+    return CHIP_PEAKS[kind][0]
+
+
+def peak_flops(devices=None) -> float:
+    """Per-chip bf16 matmul peak (FLOP/s) of the current backend, from
+    CHIP_PEAKS. An unknown TPU device_kind raises; the CPU gets
+    CPU_NOMINAL_PEAK (tier-1 only, never a device metric)."""
+    name = chip_profile_name(devices)
+    return CPU_NOMINAL_PEAK if name == "cpu" else PROFILE_PEAKS[name]
 
 
 def mfu(tokens_per_sec: float, flops_per_token: float,

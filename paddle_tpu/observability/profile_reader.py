@@ -80,10 +80,17 @@ _CALLED_RE = re.compile(
     r"(?:to_apply|calls|true_computation|false_computation|"
     r"branch_computations)=\{?%?(?P<names>[\w.\-]+(?:,\s*%?[\w.\-]+)*)")
 _TRIP_RE = re.compile(r"=\s*[su]\d+\[\]\s*constant\((\d+)\)")
+# XLA prints operands by NAME only (``dot(%a, %b)``): the lhs shape comes
+# from the operand's own definition in the same computation (_DEF_RE)
 _DOT_RE = re.compile(
     r"= (?P<shape>[a-z][a-z0-9]*\[[0-9,]*\])\S* dot\("
-    r"(?P<lhs>[a-z][a-z0-9]*\[[0-9,]*\]).*?"
+    r"%?(?P<lhs>[\w.\-]+)[,)].*?"
     r"lhs_contracting_dims=\{(?P<cdims>[0-9,]*)\}")
+_DEF_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+) = "
+    r"(?P<shape>[a-z][a-z0-9]*\[[0-9,]*\])", re.MULTILINE)
+_COMMENT_RE = re.compile(r"/\*.*?\*/")
+_KNOWN_TRIP_RE = re.compile(r'"known_trip_count":\{"n":"(\d+)"\}')
 
 
 def _itemsize(dtype: str) -> int:
@@ -195,7 +202,13 @@ def _computation_multipliers(entry: Optional[str],
         consumed = set()
         for w in _WHILE_RE.finditer(body_text):
             cond, body = w.group("cond"), w.group("body")
-            trips = [int(t) for t in _TRIP_RE.findall(comps.get(cond, ""))]
+            # XLA's own analysis, printed on the while's line, wins over
+            # the largest-constant-in-the-condition heuristic
+            eol = body_text.find("\n", w.end())
+            known = _KNOWN_TRIP_RE.search(
+                body_text, w.end(), eol if eol > 0 else len(body_text))
+            trips = ([int(known.group(1))] if known else
+                     [int(t) for t in _TRIP_RE.findall(comps.get(cond, ""))])
             trip = float(max(trips)) if trips else 1.0
             if not trips:
                 notes.append(f"while body {body}: trip count not found "
@@ -219,6 +232,9 @@ def hlo_census(text: str, *, default_group: int = 1) -> Census:
     proxy: XLA's cost_analysis reports loop bodies ONCE, so a pipelined
     or layer-scanned train step needs the trip-aware census."""
     notes: List[str] = []
+    # long tuple shapes carry ``/*index=5*/`` markers whose "=" would cut
+    # a combined (many-operand) collective out of _COLL_RE's match
+    text = _COMMENT_RE.sub("", text)
     entry, comps = _split_computations(text)
     if not comps:
         # not module text at all — census the flat text at multiplier 1
@@ -252,14 +268,22 @@ def hlo_census(text: str, *, default_group: int = 1) -> Census:
                 k = int(gi.group(2)) if gi else default_group
             coll[kind]["count"] += m
             coll[kind]["wire_bytes"] += m * _wire_bytes(kind, payload, k)
+        defs = None
         for d in _DOT_RE.finditer(body):
             out_dt, out_dims = _SHAPE_RE.match(d.group("shape")).groups()
             out_elems = 1
             for x in out_dims.split(","):
                 if x:
                     out_elems *= int(x)
-            lhs_dt, lhs_dims = _SHAPE_RE.match(d.group("lhs")).groups()
-            lhs_shape = [int(x) for x in lhs_dims.split(",") if x]
+            if defs is None:
+                defs = {m_.group("name"): m_.group("shape")
+                        for m_ in _DEF_RE.finditer(body)}
+            lhs = _SHAPE_RE.match(defs.get(d.group("lhs"), ""))
+            if lhs is None:
+                notes.append(f"dot in {name}: lhs operand shape not "
+                             "found; its FLOPs are not counted")
+                continue
+            lhs_shape = [int(x) for x in lhs.group("dims").split(",") if x]
             contract = 1
             for ci in d.group("cdims").split(","):
                 if ci:

@@ -5,8 +5,8 @@ TPU design: fake-quant as straight-through-estimator ops (custom_vjp),
 QuantConfig + QAT wrapper inserting FakeQuant layers around Linear/Conv;
 PTQ observers collect absmax ranges. Round 2 adds REAL int8 execution
 (quantize_to_int8 / int8_matmul / qlinear / QuantizedLinear): int8×int8
-→int32 on the v5e MXU via preferred_element_type, measured 1.26× the
-bf16 rate at large shapes (BASELINE.md).
+→int32 on the v5e MXU via preferred_element_type (its rate against
+bf16 is not measured on the current installation).
 
 Scale convention (ONE convention module-wide): scale = absmax, integer
 value q ≈ x·qmax/scale, dequant = q·scale/qmax — what absmax_scale /

@@ -1,69 +1,21 @@
-"""Version-compat shims."""
+"""Thin wrappers over jax APIs the framework calls with its own defaults."""
 
 from __future__ import annotations
 
 import functools
 
 import jax
+from jax._src.core import trace_state_clean  # noqa: F401  (no public home)
 
-__all__ = ["shard_map", "axis_size", "trace_state_clean"]
-
-
-def trace_state_clean() -> bool:
-    """jax's trace_state_clean across versions (True = not inside any
-    trace). It only ever lived under private paths (jax._src.core on
-    0.4.x, jax.core before the _src split), so a jax upgrade can drop it
-    without notice — degrade to True ("not tracing"), which callers use
-    as the no-warning/no-guard-needed direction (the lax.axis_size shim
-    pattern: one guarded lookup here instead of a private import at every
-    dispatch site)."""
-    for mod in ("jax._src.core", "jax.core"):
-        try:
-            import importlib
-            fn = getattr(importlib.import_module(mod),
-                         "trace_state_clean", None)
-        except ImportError:
-            fn = None
-        if fn is not None:
-            return bool(fn())
-    return True
-
-
-def axis_size(axis_name):
-    """jax.lax.axis_size across versions: newer jax exposes it directly;
-    on 0.4.x the bound frame comes from jax.core.axis_frame (which
-    already returns the size as an int there)."""
-    if isinstance(axis_name, (tuple, list)):
-        n = 1
-        for a in axis_name:
-            n *= axis_size(a)
-        return n
-    frame = jax.core.axis_frame(axis_name)
-    return frame if isinstance(frame, int) else frame.size
-
-
-# every engine/model file calls lax.axis_size at trace time; fill it in
-# on jax versions that predate the public accessor
-if not hasattr(jax.lax, "axis_size"):
-    jax.lax.axis_size = axis_size
+__all__ = ["shard_map", "trace_state_clean"]
 
 
 def shard_map(f=None, *, mesh, in_specs, out_specs, check=False, **kwargs):
-    """jax.shard_map across jax versions: the replication-check kwarg was
-    renamed check_rep -> check_vma. We default it OFF because explicit-mode
-    collectives legitimately mix replicated and varying values."""
+    """jax.shard_map with the varying-axes check defaulting OFF: the
+    engine's explicit-mode collectives legitimately mix replicated and
+    varying values."""
     if f is None:
         return functools.partial(shard_map, mesh=mesh, in_specs=in_specs,
                                  out_specs=out_specs, check=check, **kwargs)
-    try:
-        from jax import shard_map as _sm
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-    for kw in ("check_vma", "check_rep"):
-        try:
-            return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                       **{kw: check}, **kwargs)
-        except TypeError as e:
-            if kw not in str(e):
-                raise
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check, **kwargs)

@@ -6,7 +6,7 @@ tuner's "sharding"/"sep" vocabulary is gone), engine_kwargs round-trips
 through build_hybrid_train_step for every family, the shared MoE flop math
 (bit-for-bit the bench.py formulas), cost-model rankings against this
 repo's RECORDED ground truth (PR 2 bucketed-overlap and PR 5 mp-overlap
-directions on the TPU profile; the BASELINE.md round-6 CPU proxy ordering
+directions on the TPU profile; the CPU-mesh op-count ordering
 allreduce < sp < ring on the CPU profile), analytic-OOM-vs-compiled
 ``memory_analysis`` agreement, the CLI, and (slow tier) the
 predicted-vs-measured CPU sweep with the documented tolerances.
@@ -232,9 +232,9 @@ def test_tpu_ranking_mp_overlap_beats_baseline_and_bucketed_beats_mono():
 
 def test_cpu_ranking_matches_round6_proxy_op_count_ordering():
     """The CPU profile (overlap_capable=False, per-collective launch
-    dominant) must reproduce the BASELINE.md round-6 CPU proxy ordering
-    allreduce (90.0 ms) < seq_parallel (120.4) < ring (174.3): on the
-    smoke mesh the modes rank by op count, not wire."""
+    dominant) must reproduce the CPU-mesh ordering allreduce <
+    seq_parallel < ring: on the virtual-device mesh the modes rank by
+    op count, not wire."""
     cfg = _tiny_gpt()
     spec = ModelSpec.from_config(cfg, "gpt")
     cm = CostModel(spec, KNOWN_PROFILES["cpu"], global_batch=GB, seq=SEQ)

@@ -718,8 +718,8 @@ def test_reshard_1b_checkpoint_throughput(tmp_path):
     interleaved vpp=2 block layout through the ASYNC crash-safe commit,
     then reshard-load it onto dp4·pp2·mp1 / vpp=1 / zero1-OFF — mesh
     regroup, zero1 toggle, pp-adaptor block permutation and carry
-    policies all at once, on hundreds of on-disk chunks. Prints the MB/s
-    numbers recorded in BASELINE.md."""
+    policies all at once, on hundreds of on-disk chunks. Prints the
+    host-disk MB/s (not a device metric)."""
     import time
     from paddle_tpu.models import gpt as G
 

@@ -38,14 +38,49 @@ JAX_BOUND = {
 
 
 @pytest.mark.parametrize("name", sorted(JAX_BOUND))
-def test_jax_bound_flags(name):
+def test_jax_bound_flags(name, monkeypatch):
     cfg, on, off = JAX_BOUND[name]
+    # the cache-dir binding stands only where the environment has not
+    # placed the cache itself (test_jit_cache_dir_env_wins covers that)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     old = flag(name)
     try:
         set_flags({name: on})
-        assert getattr(jax.config, cfg) == on or jax.config.read(cfg) == on
+        assert getattr(jax.config, cfg) == on
     finally:
         _restore(name, old)
+
+
+def test_jit_cache_dir_env_wins(monkeypatch):
+    """Where JAX_COMPILATION_CACHE_DIR is set, no code path sets another
+    cache directory: FLAGS_jit_cache_dir (any value, and the "" the tests'
+    autouse fixture re-applies) leaves jax's config alone."""
+    before = jax.config.jax_compilation_cache_dir
+    old = flag("jit_cache_dir")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/from/outside")
+    try:
+        jax.config.update("jax_compilation_cache_dir",
+                          "/placed/from/outside")
+        for v in ("/tmp/pt_cache", ""):
+            set_flags({"FLAGS_jit_cache_dir": v})
+            assert (jax.config.jax_compilation_cache_dir
+                    == "/placed/from/outside")
+    finally:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        _restore("FLAGS_jit_cache_dir", old)
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_repo_jit_cache_dir_is_fixed_and_inside_checkout():
+    """The in-checkout cache path is a constant of the checkout — never
+    built from tempfile, a pid or the time (the path is in the cache
+    key) — and git-ignored."""
+    import os
+    from paddle_tpu.flags import REPO_JIT_CACHE_DIR
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert REPO_JIT_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".jax_cache/" in f.read().split()
 
 
 def test_matmul_precision_bound():

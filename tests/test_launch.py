@@ -244,3 +244,34 @@ def test_auto_tune_picks_best_and_runs_real_job(tmp_path, monkeypatch):
     assert (final["dp"], final["mp"], final["pp"], final["ep"]) == \
         (1, 1, 1, 1)
     assert final["micro_batches"] == 2, final
+
+
+def test_several_processes_per_tpu_host_refused(tmp_path, monkeypatch):
+    """Nothing confines a launched worker to one chip, and a chip belongs
+    to one process: on a TPU host --nproc_per_node > 1 is refused with a
+    pointer to the one-process mesh, before any worker starts. The host
+    check never touches jax (the launcher must not load the TPU
+    library)."""
+    from paddle_tpu.distributed.launch import controllers
+    script = tmp_path / "train.py"
+    script.write_text("open(__import__('sys').argv[1] + '/ran', 'w')\n")
+    monkeypatch.setattr(controllers, "_tpu_host", lambda envs: True)
+    with pytest.raises(RuntimeError, match="one process"):
+        launch(["--nproc_per_node", "2", "--log_dir",
+                str(tmp_path / "log"), str(script), str(tmp_path)])
+    assert not (tmp_path / "ran").exists()
+    # one process per host stays allowed there
+    rc = launch(["--nproc_per_node", "1", "--log_dir",
+                 str(tmp_path / "log"), str(script), str(tmp_path)])
+    assert rc == 0 and (tmp_path / "ran").exists()
+
+
+def test_tpu_host_detection_is_jax_free(monkeypatch):
+    import glob
+    from paddle_tpu.distributed.launch import controllers
+    monkeypatch.setattr(glob, "glob",
+                        lambda pat: ["/dev/accel0"] if "accel" in pat else [])
+    assert controllers._tpu_host({}) is True
+    assert controllers._tpu_host({"JAX_PLATFORMS": "cpu"}) is False
+    monkeypatch.setattr(glob, "glob", lambda pat: [])
+    assert controllers._tpu_host({}) is False

@@ -86,6 +86,52 @@ def test_hlo_census_beats_cost_analysis_on_loops():
     assert ca_flops < c.dot_flops  # cost_analysis undercounts the loop
 
 
+_HAND_HLO = """HloModule m, entry_computation_layout={()->f32[]}
+
+%add (a: f32[], b: f32[]) -> f32[] {
+  %a = f32[] parameter(0)
+  %b = f32[] parameter(1)
+  ROOT %s = f32[] add(%a, %b)
+}
+
+%body (p: (s32[], f32[4,8], f32[8,8])) -> (s32[], f32[4,8], f32[8,8]) {
+  %p = (s32[], f32[4,8]{1,0}, f32[8,8]{1,0}) parameter(0)
+  %x = f32[4,8]{1,0} get-tuple-element(%p), index=1
+  %w = f32[8,8]{1,0} get-tuple-element(%p), index=2
+  %i = s32[] get-tuple-element(%p), index=0
+  %d = f32[4,8]{1,0} dot(%x, %w), lhs_contracting_dims={1}, rhs_contracting_dims={0}
+  %ar = (f32[4,8]{1,0}, f32[4]{0}, f32[4]{0}, f32[4]{0}, f32[4]{0}, /*index=5*/f32[4]{0}) all-reduce(%d, %d, %d, %d, %d, %d), replica_groups={{0,1},{2,3}}, to_apply=%add
+  ROOT %t = (s32[], f32[4,8]{1,0}, f32[8,8]{1,0}) tuple(%i, %d, %w)
+}
+
+%cond (p: (s32[], f32[4,8], f32[8,8])) -> pred[] {
+  %p = (s32[], f32[4,8]{1,0}, f32[8,8]{1,0}) parameter(0)
+  %c = s32[] constant(99)
+  ROOT %lt = pred[] compare(%c, %c), direction=LT
+}
+
+ENTRY %main () -> f32[] {
+  %init = (s32[], f32[4,8]{1,0}, f32[8,8]{1,0}) tuple()
+  %w0 = (s32[], f32[4,8]{1,0}, f32[8,8]{1,0}) while(%init), condition=%cond, body=%body, backend_config={"known_trip_count":{"n":"7"}}
+  ROOT %z = f32[] constant(0)
+}
+"""
+
+
+def test_hlo_census_reads_the_installed_printer():
+    """The three spellings that broke the reader on the installed XLA:
+    dot operands printed by name only (shape from the operand's own
+    definition), ``/*index=N*/`` markers inside a combined collective's
+    tuple shape, and the trip count XLA prints on the while's own line
+    (which beats the constant in the condition)."""
+    c = PR.hlo_census(_HAND_HLO, default_group=4)
+    assert c.dot_flops == 7 * 2 * (4 * 8) * 8
+    assert c.collectives["all_reduce"]["count"] == 7
+    payload = 4 * 8 * 4 + 5 * 4 * 4  # f32[4,8] + five f32[4]
+    assert c.collectives["all_reduce"]["wire_bytes"] == 7 * payload
+    assert not c.notes
+
+
 def test_attribution_math():
     """attribute_window: exposed clamps to [0, wire], hidden is the
     remainder, residual beyond compute+wire lands in overhead."""
@@ -220,7 +266,7 @@ def test_profile_attribution_gate():
              (Pc(dp=4, mp=2), "mp:allreduce")]
     # the bad-overlap config: ring collective-matmul pays 4*(mp-1)
     # collectives per GEMM pair for overlap this backend cannot deliver —
-    # the measured-worst config of the round-6 CPU proxy (BASELINE.md)
+    # the worst config on the CPU mesh, where modes rank by op count
     bad = Pc(dp=2, mp=4, mp_overlap="collective_matmul")
     host_params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
     windows, exposed = [], {}

@@ -221,14 +221,29 @@ def test_parity_bf16(mesh8):
     assert _max_rel(base, sp) < 2e-2, (base, sp)
 
 
+def _f32_ulps(a, b):
+    bits = lambda x: int(np.float32(x).view(np.int32))
+    return abs(bits(a) - bits(b))
+
+
 def test_mp1_degenerate():
     """mp=1 mesh: every sp collective degenerates to identity/local
-    matmul — losses must equal the baseline exactly."""
+    matmul, so the three modes compute the same math. They are NOT the
+    same program, though: the sp modes still trace their sequence
+    slicing (dynamic_slice at axis_index * S) and the ring its one-chunk
+    loop, the lowered text differs in thousands of lines, and XLA fuses
+    — and so associates the float32 sums of — each differently. Step 0
+    (before any update feeds a difference back) is exact; afterwards the
+    losses agree to a few float32 ulps (measured on jax 0.9.0: sp
+    bitwise, ring 1 ulp at step 2). Bound: 4 ulps."""
     mesh = dist.build_mesh({"dp": 4, "pp": 2, "mp": 1})
     base = _run_gpt(mesh, None, 3)
     sp = _run_gpt(mesh, "seq_parallel", 3)
     ring = _run_gpt(mesh, "collective_matmul", 3)
-    assert base == sp == ring, (base, sp, ring)
+    assert base[0] == sp[0] == ring[0], (base, sp, ring)
+    for other in (sp, ring):
+        ulps = [_f32_ulps(x, y) for x, y in zip(base, other)]
+        assert max(ulps) <= 4, (base, other, ulps)
 
 
 @pytest.mark.slow

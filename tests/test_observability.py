@@ -566,3 +566,45 @@ def test_model_fit_telemetry_report():
     assert rep["compile_s"] > 0
     assert rep["steady_steps"] == 1  # 2 batches: 1 compile + 1 steady
     assert rep["phases_ms"]["data"]["count"] >= 1
+
+
+# ---------------------------------------------------------------------------
+# the one peak table (PR 23): unknown TPU kinds are errors, not defaults
+# ---------------------------------------------------------------------------
+class _FakeDev:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+def test_peak_table_known_kinds():
+    from paddle_tpu.distributed.auto_tuner import planner as PL
+    from paddle_tpu.observability import flops
+    v5e = [_FakeDev("tpu", "TPU v5 lite")]
+    assert flops.peak_flops(v5e) == flops.CHIP_PEAKS["TPU v5 lite"][1]
+    assert PL.profile_for(v5e).name == "tpu-v5e"
+    # the planner's profiles read the same table — no second copy
+    for name, prof in PL.KNOWN_PROFILES.items():
+        want = (flops.CPU_NOMINAL_PEAK if name == "cpu"
+                else flops.PROFILE_PEAKS[name])
+        assert prof.peak_flops == want, name
+    assert flops.peak_flops([_FakeDev("cpu", "cpu")]) == \
+        flops.CPU_NOMINAL_PEAK
+
+
+@pytest.mark.parametrize("dev", [_FakeDev("tpu", "TPU v9 mega"),
+                                 _FakeDev("tpu", ""),
+                                 _FakeDev("gpu", "H100")],
+                         ids=["unknown-tpu", "blank-kind", "other-platform"])
+def test_unknown_device_kind_raises(dev):
+    from paddle_tpu.distributed.auto_tuner import planner as PL
+    from paddle_tpu.observability import flops
+    with pytest.raises(ValueError, match="CHIP_PEAKS"):
+        flops.peak_flops([dev])
+    with pytest.raises(ValueError, match="CHIP_PEAKS"):
+        PL.profile_for([dev])
+
+
+def test_require_tpu_refuses_cpu():
+    from paddle_tpu.device import require_tpu
+    with pytest.raises(SystemExit, match="needs a TPU"):
+        require_tpu("some_bench.py")

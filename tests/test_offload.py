@@ -16,7 +16,7 @@ from paddle_tpu.distributed.sharding.group_sharded import (
     build_sharded_train_step, group_sharded_parallel)
 from paddle_tpu.distributed.sharding.param_stream import supports_pinned_host
 
-# CPU jax 0.4.x addresses only unpinned_host: the offload/streaming tiers
+# The CPU backend addresses only unpinned_host: the offload/streaming tiers
 # (which literally park bytes in pinned_host) cannot run there — skip with
 # the reason rather than fail (the TPU backend runs them all).
 requires_pinned_host = pytest.mark.skipif(

@@ -336,3 +336,30 @@ def test_spawned_fleet_kill_failover_bitwise(params, tmp_path):
     assert out["tokens_post_failover"] > 0
     assert out["failovers"] == 1
     assert out["survivor_free_blocks"] == out["survivor_pool_blocks"]
+
+
+# ---------------------------------------------------------------------------
+# one process per chip (PR 23): CPU-pinned children are refused on a TPU
+# parent instead of quietly serving from the CPU
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("path", ["spawned_replica", "kill_replay"])
+def test_cpu_children_refused_when_parent_is_on_tpu(path, tmp_path,
+                                                    monkeypatch):
+    from paddle_tpu.inference import resilient
+    from paddle_tpu.inference.router import SpawnedReplica
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="InProcessReplica"):
+        if path == "spawned_replica":
+            SpawnedReplica(0, str(tmp_path)).start()
+        else:
+            resilient.kill_replay_check(str(tmp_path))
+    # nothing was started
+    assert not list(tmp_path.rglob("out.*.log"))
+
+
+def test_cpu_parent_keeps_its_cpu_children(monkeypatch):
+    """The CPU pin stays when the parent itself runs on the CPU — the
+    tier-1 spawn tests depend on it."""
+    from paddle_tpu.inference import resilient
+    assert jax.default_backend() == "cpu"
+    resilient.refuse_cpu_children_on_tpu("test")  # no raise
