@@ -33,6 +33,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .....observability.trace import SCOPES
+
 __all__ = ["c_identity", "mp_allreduce", "c_split", "c_concat",
            "ag_matmul", "matmul_rs",
            "explicit_mode", "in_explicit_mode", "explicit_axis"]
@@ -75,6 +77,11 @@ def explicit_axis() -> Optional[str]:
     return _mode.axis
 
 
+@jax.named_scope(SCOPES.coll_mp)
+def _psum(x, axis):
+    return lax.psum(x, axis)
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def _c_identity(x, axis: str):
     return x
@@ -85,7 +92,7 @@ def _c_identity_fwd(x, axis):
 
 
 def _c_identity_bwd(axis, res, g):
-    return (lax.psum(g, axis),)
+    return (_psum(g, axis),)
 
 
 _c_identity.defvjp(_c_identity_fwd, _c_identity_bwd)
@@ -99,11 +106,11 @@ def c_identity(x, axis: str):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
 def _mp_allreduce(x, axis: str):
-    return lax.psum(x, axis)
+    return _psum(x, axis)
 
 
 def _mp_allreduce_fwd(x, axis):
-    return lax.psum(x, axis), None
+    return _psum(x, axis), None
 
 
 def _mp_allreduce_bwd(axis, res, g):
@@ -144,6 +151,7 @@ def _c_split_bwd(axis, dim, res, g):
 c_split.defvjp(_c_split_fwd, _c_split_bwd)
 
 
+@jax.named_scope(SCOPES.coll_mp)
 def _all_gather_concat(x, axis: str, dim: int):
     d = dim if dim >= 0 else x.ndim + dim
     return lax.all_gather(x, axis, axis=d, tiled=True)

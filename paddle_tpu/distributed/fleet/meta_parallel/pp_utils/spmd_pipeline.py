@@ -27,10 +27,17 @@ import jax.numpy as jnp
 from .....enforce import enforce
 from jax import lax
 
+from .....observability.trace import SCOPES
+
 __all__ = ["spmd_pipeline", "spmd_pipeline_interleaved",
            "spmd_pipeline_zero_bubble", "pipeline_last_stage_value",
            "vpp_block_permutation", "vpp_chunk_blocks",
            "vpp_wrap_shard_params"]
+
+
+@jax.named_scope(SCOPES.coll_pp)
+def _ppermute(x, axis, perm):
+    return lax.ppermute(x, axis, perm)
 
 
 def vpp_block_permutation(num_layers: int, pp: int, vpp: int):
@@ -140,7 +147,7 @@ def spmd_pipeline(stage_fn: Callable, stage_params, x_microbatches,
     def step(carry, t):
         state, outputs, aux_acc = carry
         # rotate activations one stage down the ring (stage d-1 -> d)
-        prev = lax.ppermute(state, axis, [(i, i + 1) for i in range(P - 1)])
+        prev = _ppermute(state, axis, [(i, i + 1) for i in range(P - 1)])
         inj = jnp.take(x_microbatches, jnp.clip(t, 0, M - 1), axis=0)
         inj = jnp.where(t < M, inj, jnp.zeros_like(inj))
         inp = jnp.where(idx == 0, inj, prev)
@@ -212,7 +219,7 @@ def spmd_pipeline_interleaved(stage_fn: Callable, stage_params_chunks,
         # ONE circular permute: ranks > 0 read their predecessor ("prev"),
         # rank 0 reads rank P-1's value (the wrap) — halves the collective
         # count vs separate shift + wrap permutes on this hot loop
-        rotated = lax.ppermute(state, axis,
+        rotated = _ppermute(state, axis,
                                [(i, (i + 1) % P) for i in range(P)])
         prev = rotated
         wrapped = rotated  # meaningful on rank 0 only
@@ -364,7 +371,7 @@ def _zb_fwd(stage_fn, axis, stage_params, x_microbatches):
 
     def step(carry, t):
         state, outputs, saved = carry
-        prev = lax.ppermute(state, axis, [(i, i + 1) for i in range(P - 1)])
+        prev = _ppermute(state, axis, [(i, i + 1) for i in range(P - 1)])
         inj = jnp.take(x_microbatches, jnp.clip(t, 0, M - 1), axis=0)
         inj = jnp.where(t < M, inj, jnp.zeros_like(inj))
         inp = jnp.where(idx == 0, inj, prev)
@@ -417,7 +424,7 @@ def _zb_bwd(stage_fn, axis, res, g):
         dx_prev, ct_buf, wacc, dx_inputs = carry
         # activation cotangents flow upstream (rank r+1 -> r); the last
         # rank injects the loss cotangent for its current microbatch
-        ring = lax.ppermute(dx_prev, axis,
+        ring = _ppermute(dx_prev, axis,
                             [(i, i - 1) for i in range(1, P)])
         m_d = u - start
         mdc = jnp.clip(m_d, 0, M - 1)

@@ -35,10 +35,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..models import gpt as G
+from ..observability.trace import SCOPES
 from ..kernels.pallas.ragged_paged_attention import ragged_paged_attention
 from ..quantization.kv_cache import (append_tokens_quantized,
                                      reset_page_scales)
-from .serving import _embed, _qkv, _block_math, _head_logits
+from .serving import _embed, _qkv, _block_math, _head_logits, _sample
 
 __all__ = ["ragged_pass", "unified_step"]
 
@@ -80,39 +81,40 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
         else:
             (p, kpl, vpl), ksl, vsl = layer, None, None
         q, k, v = _qkv(p, x, cfg, mp_axis)                   # [1, T, h, D]
-        if quantized:
-            kpl, ksl = append_tokens_quantized(
-                kpl, ksl, k[0][tile_idx], pos0, q_lens, tables, bs)
-            vpl, vsl = append_tokens_quantized(
-                vpl, vsl, v[0][tile_idx], pos0, q_lens, tables, bs)
-        else:
-            kpl = kpl.at[:, blk_t, off_t].set(
-                jnp.moveaxis(k[0], 1, 0).astype(kpl.dtype))  # [h, T, D]
-            vpl = vpl.at[:, blk_t, off_t].set(
-                jnp.moveaxis(v[0], 1, 0).astype(vpl.dtype))
-        attn_t = ragged_paged_attention(
-            q[0][tile_idx], kpl, vpl, tables, q_lens, kv_lens, scale,
-            ksl, vsl)                                        # [R,c_att,h,D]
-        attn_p = attn_t[row_of, jnp.minimum(off_of, c_att - 1)]
+        with jax.named_scope(SCOPES.kv_write):
+            if quantized:
+                kpl, ksl = append_tokens_quantized(
+                    kpl, ksl, k[0][tile_idx], pos0, q_lens, tables, bs)
+                vpl, vsl = append_tokens_quantized(
+                    vpl, vsl, v[0][tile_idx], pos0, q_lens, tables, bs)
+            else:
+                kpl = kpl.at[:, blk_t, off_t].set(
+                    jnp.moveaxis(k[0], 1, 0).astype(kpl.dtype))  # [h,T,D]
+                vpl = vpl.at[:, blk_t, off_t].set(
+                    jnp.moveaxis(v[0], 1, 0).astype(vpl.dtype))
+        with jax.named_scope(SCOPES.ragged_attn):
+            attn_t = ragged_paged_attention(
+                q[0][tile_idx], kpl, vpl, tables, q_lens, kv_lens, scale,
+                ksl, vsl)                                    # [R,c_att,h,D]
+            attn_p = attn_t[row_of, jnp.minimum(off_of, c_att - 1)]
         x = _block_math(p, x, attn_p[None], cfg, mp_axis)
         return x, (kpl, vpl) + ((ksl, vsl) if quantized else ())
 
     xs = (params["blocks"], kp, vp) + ((ks, vs) if quantized else ())
     x, pools = lax.scan(body, x, xs)
-    x = G._ln(x, params["lnf_g"], params["lnf_b"])
+    with jax.named_scope(SCOPES.head):
+        x = G._ln(x, params["lnf_g"], params["lnf_b"])
     last_idx = jnp.clip(starts + jnp.maximum(q_lens, 1) - 1, 0, T - 1)
     if all_greedy:
         # spec verify: the head GEMM widens from [R, V] to [T, V] so the
         # model's argmax is known at every draft position in ONE pass
         logits_all = _head_logits(params, x[0], cfg, mp_axis)    # [T, V]
-        greedy_t = jnp.argmax(logits_all, axis=-1).astype(jnp.int32)
+        with jax.named_scope(SCOPES.sample):
+            greedy_t = jnp.argmax(logits_all, axis=-1).astype(jnp.int32)
         logits = logits_all[last_idx]                            # [R, V]
     else:
         logits = _head_logits(params, x[0][last_idx], cfg, mp_axis)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-    sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
-    tok = jnp.where(temps > 0, sampled, greedy)
+    tok = _sample(logits, temps, key)
     if all_greedy:
         return tok, greedy_t, pools
     return tok, pools
@@ -152,14 +154,16 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
     quantized = ks is not None
     if quantized:
         rt = tables if reset_tables is None else reset_tables
-        ks = reset_page_scales(ks, rt, fresh)
-        vs = reset_page_scales(vs, rt, fresh)
+        with jax.named_scope(SCOPES.kv_write):
+            ks = reset_page_scales(ks, rt, fresh)
+            vs = reset_page_scales(vs, rt, fresh)
     if cow_src is not None:
-        kp = kp.at[:, :, cow_dst].set(kp[:, :, cow_src])
-        vp = vp.at[:, :, cow_dst].set(vp[:, :, cow_src])
-        if quantized:
-            ks = ks.at[:, :, cow_dst].set(ks[:, :, cow_src])
-            vs = vs.at[:, :, cow_dst].set(vs[:, :, cow_src])
+        with jax.named_scope(SCOPES.cow):
+            kp = kp.at[:, :, cow_dst].set(kp[:, :, cow_src])
+            vp = vp.at[:, :, cow_dst].set(vp[:, :, cow_src])
+            if quantized:
+                ks = ks.at[:, :, cow_dst].set(ks[:, :, cow_src])
+                vs = vs.at[:, :, cow_dst].set(vs[:, :, cow_src])
     key, sub = jax.random.split(key)
     out = ragged_pass(params, tokens, row_of, off_of, starts,
                       pos0, q_lens, tables, temps, sub,
@@ -200,8 +204,9 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
 
     if K > 1:
         carry = (tok0, kp, vp, ks, vs, lens, rem, alive, key)
-        (_, kp, vp, ks, vs, lens, _, _, _), toks = lax.scan(
-            micro, carry, jnp.arange(K - 1))
+        with jax.named_scope(SCOPES.burst):
+            (_, kp, vp, ks, vs, lens, _, _, _), toks = lax.scan(
+                micro, carry, jnp.arange(K - 1))
         all_toks = jnp.concatenate([tok0[None], toks], axis=0)
     else:
         all_toks = tok0[None]

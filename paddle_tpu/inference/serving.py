@@ -88,6 +88,8 @@ import numpy as np
 from jax import lax
 
 from ..models import gpt as G
+from ..observability.trace import (SCOPES, SERVING_SPANS,
+                                   TWO_PROGRAM_SPANS)
 from ..profiler.utils import RecordEvent
 
 __all__ = ["Request", "ServingEngine", "RunResult", "NonFiniteSampleError",
@@ -159,6 +161,22 @@ class Request:
     ttft_s: Optional[float] = None
 
 
+@dataclasses.dataclass
+class _PackedStep:
+    """What `_pack_ragged` hands the rest of one ragged step."""
+    dec: list            # decode rows (Requests), packed first
+    pre: list            # prefilling rows
+    grants: dict         # slot -> prefill tokens granted this step
+    props_by_slot: dict  # slot -> draft tokens riding this step
+    use_spec: bool
+    K: int               # burst size: passes of the program this step
+    q_tokens: int        # packed query tokens (the cursor)
+    kv_tokens: int       # KV positions attended over the K passes
+    starts: np.ndarray
+    pos0: np.ndarray
+    arrays: tuple        # the host arrays, in the program's order
+
+
 class RunResult(dict):
     """``ServingEngine.run`` return value: a plain ``{rid: output}`` dict
     plus the resilience markers — ``statuses`` ({rid: Request.status} for
@@ -172,6 +190,7 @@ class RunResult(dict):
         self.leftover: List[int] = []
 
 
+@jax.named_scope(SCOPES.embed)
 def _embed(params, tokens, pos, cfg):
     return (jnp.take(params["wte"], tokens, axis=0)
             + jnp.take(params["wpe"], pos, axis=0)).astype(cfg.dtype)
@@ -216,6 +235,7 @@ def quantize_serving_params(params):
     return out
 
 
+@jax.named_scope(SCOPES.proj_mlp)
 def _block_math(p, x, attn, cfg, mp_axis=None):
     """Post-attention half of the GPT block (shared by both programs).
     mp_axis: Megatron TP inside shard_map — proj/fc2 are row-parallel
@@ -225,7 +245,8 @@ def _block_math(p, x, attn, cfg, mp_axis=None):
     q_axis = mp_axis if "proj_w@q" in p else None
     out = _mm(attn.reshape(B, S, -1), p, "proj_w", cfg, psum_axis=q_axis)
     if mp_axis is not None and q_axis is None:
-        out = lax.psum(out, mp_axis)
+        with jax.named_scope(SCOPES.coll_mp):
+            out = lax.psum(out, mp_axis)
     x = x + out + p["proj_b"].astype(cfg.dtype)
     h = G._ln(x, p["ln2_g"], p["ln2_b"])
     m = _mm(h.astype(cfg.dtype), p, "fc1_w", cfg) + p["fc1_b"].astype(cfg.dtype)
@@ -233,10 +254,12 @@ def _block_math(p, x, attn, cfg, mp_axis=None):
     q_axis = mp_axis if "fc2_w@q" in p else None
     m = _mm(m, p, "fc2_w", cfg, psum_axis=q_axis)
     if mp_axis is not None and q_axis is None:
-        m = lax.psum(m, mp_axis)
+        with jax.named_scope(SCOPES.coll_mp):
+            m = lax.psum(m, mp_axis)
     return x + m + p["fc2_b"].astype(cfg.dtype)
 
 
+@jax.named_scope(SCOPES.qkv)
 def _qkv(p, x, cfg, mp_axis=None):
     """Column-parallel under TP: the local qkv_w shard holds COMPLETE
     heads (head-major [H, heads*3*D] channel layout), so the reshape uses
@@ -250,6 +273,7 @@ def _qkv(p, x, cfg, mp_axis=None):
     return qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2]
 
 
+@jax.named_scope(SCOPES.head)
 def _head_logits(params, x_last, cfg, mp_axis=None):
     """LM head on the last position; vocab-parallel under TP (local
     partial logits all-gathered — [B, V] is tiny at decode time). When
@@ -263,11 +287,23 @@ def _head_logits(params, x_last, cfg, mp_axis=None):
         logits = x_last.astype(jnp.float32) @ params["head_w"].astype(
             jnp.float32)
     if mp_axis is not None and logits.shape[-1] < cfg.vocab_size:
-        logits = lax.all_gather(logits, mp_axis, axis=logits.ndim - 1,
-                                tiled=True)
+        with jax.named_scope(SCOPES.coll_mp):
+            logits = lax.all_gather(logits, mp_axis, axis=logits.ndim - 1,
+                                    tiled=True)
     return logits
 
 
+@jax.named_scope(SCOPES.sample)
+def _sample(logits, temps, key):
+    """Per-row next token: argmax where temps == 0, else a categorical
+    draw at that temperature. logits: [R, V]; temps: [R]."""
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+    sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
+    return jnp.where(temps > 0, sampled, greedy)
+
+
+@jax.named_scope(SCOPES.kv_write)
 def _write_token(pool, val, tables, lens, bs):
     """Scatter one token's k or v ([B, H, D]) at each sequence's current
     position (idle slots point at scratch block 0 — harmless)."""
@@ -313,12 +349,7 @@ def _decode_burst(params, tokens, k_pools, v_pools, tables, lens,
         x = G._ln(x, params["lnf_g"], params["lnf_b"])
         logits = _head_logits(params, x[:, 0], cfg, mp_axis)
         key, sub = jax.random.split(key)
-        greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-        sampled = jax.random.categorical(sub, scaled, axis=-1).astype(
-            jnp.int32)
-        tok = jnp.where(temps > 0, sampled, greedy)
-        tok = jnp.where(active, tok, 0)
+        tok = jnp.where(active, _sample(logits, temps, sub), 0)
         lens = lens + active.astype(lens.dtype)
         remaining = remaining - active.astype(remaining.dtype)
         alive = alive & ~(active & (tok == eos_ids))
@@ -382,10 +413,7 @@ def _prefill_chunk(params, chunk_tokens, pos0, tables, last_idx, temps,
     x_last = jnp.take_along_axis(
         x, last_idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
     logits = _head_logits(params, x_last, cfg, mp_axis)  # [P, V]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-    sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
-    return jnp.where(temps > 0, sampled, greedy), ks, vs
+    return _sample(logits, temps, key), ks, vs
 
 
 def _verify_chunk(params, draft_tokens, pos0, q_lens, tables, temps,
@@ -936,7 +964,7 @@ class ServingEngine:
             self._cow_jit = jax.jit(fn, donate_argnums=(0, 1))
             self._jit_programs.append(self._cow_jit)
         self.dispatches += 1
-        with RecordEvent("serving_cow_dispatch"):
+        with RecordEvent(TWO_PROGRAM_SPANS.cow):
             self.k_pools, self.v_pools = self._cow_jit(
                 self.k_pools, self.v_pools, jnp.asarray(src),
                 jnp.asarray(dst))
@@ -1706,26 +1734,31 @@ class ServingEngine:
         overload-shed, rejected, and submit-time sheds queued since the
         last step (check ``Request.status``).
 
-        The whole step runs inside a ``serving_step`` RecordEvent span
-        (dispatches get their own nested spans), so serving lands on the
-        SAME host timeline as training: Profiler summaries, chrome-trace
-        exports and observability.capture_spans all see it. The
-        ``serving/step`` fault-injection site fires FIRST — a kill/hang
-        clause takes the whole step down exactly as a wedged device
-        would."""
+        The whole step runs inside a ``serving_step`` RecordEvent span,
+        and the ragged path's host work inside it is covered, without
+        holes, by the child spans of ``observability.trace.SERVING_SPANS``
+        (sweep, admission, pack, upload, unified dispatch, fetch, walk,
+        metrics; the two-program path opens sweep and metrics and its own
+        per-program dispatch spans). Every span is a
+        ``jax.profiler.TraceAnnotation`` too, so serving lands on the SAME
+        host timeline as training — Profiler summaries, chrome-trace
+        exports, observability.capture_spans — and on the device trace's
+        clock in any ``jax.profiler`` session. The ``serving/step``
+        fault-injection site fires FIRST — a kill/hang clause takes the
+        whole step down exactly as a wedged device would."""
         self.engine_steps += 1
         _faults().maybe_fail("serving/step")
-        with RecordEvent("serving_step"):
-            terminal = self._take_notifications()
-            terminal += self._expire()
-            terminal += self._shed_overload()
+        with RecordEvent(SERVING_SPANS.step):
+            with RecordEvent(SERVING_SPANS.sweep):
+                terminal = self._take_notifications()
+                terminal += self._expire()
+                terminal += self._shed_overload()
             if self.ragged:
                 out = self._step_ragged()
             else:
                 out = self._step_two_program()
             if self._health == "loading":
                 self._health = "ready"
-            self._numerics_kv_poll()
             # admission-time rejections land in _notify DURING the path
             # body — drain them now so a run that ends this step still
             # reports them
@@ -1828,7 +1861,7 @@ class ServingEngine:
                 his[i] = hi
             self._key, sub = jax.random.split(self._key)
             self.dispatches += 1
-            with RecordEvent("serving_prefill_dispatch"):
+            with RecordEvent(TWO_PROGRAM_SPANS.prefill):
                 _faults().maybe_fail("serving/dispatch")
                 tok_dev, self.k_pools, self.v_pools = self._prefill(
                     self.params, jnp.asarray(buf), jnp.asarray(pos0),
@@ -1895,7 +1928,7 @@ class ServingEngine:
             self._key, sub = jax.random.split(self._key)
             self.dispatches += 1
             self.decode_microsteps += 1
-            with RecordEvent("serving_verify_dispatch"):
+            with RecordEvent(TWO_PROGRAM_SPANS.verify):
                 _faults().maybe_fail("serving/dispatch")
                 tok_dev, greedy_dev, self.k_pools, self.v_pools = (
                     self._verify()(self.params, jnp.asarray(buf),
@@ -1955,7 +1988,7 @@ class ServingEngine:
                         break
             self.decode_microsteps += K
             self.dispatches += 1
-            with RecordEvent("serving_decode_dispatch"):
+            with RecordEvent(TWO_PROGRAM_SPANS.decode):
                 _faults().maybe_fail("serving/dispatch")
                 toks, self.k_pools, self.v_pools, lens = self._decode_k[K](
                     self.params, jnp.asarray(self._pending_tok),
@@ -1994,23 +2027,55 @@ class ServingEngine:
         (decode rows first — one token each, always granted — then
         prefill chunks sharing the leftover token budget), run the ONE
         unified program (K-token decode burst fused in), walk the [K, R]
-        token matrix on the host. One compiled dispatch, one fetch."""
+        token matrix on the host. One compiled dispatch, one fetch. Each
+        phase is a child span of ``serving_step`` (SERVING_SPANS)."""
         t_step0 = time.perf_counter()
         if self._t_first_step is None:
             self._t_first_step = t_step0
         tokens_before = self._tokens_total
         finished: List[Request] = []
-        fresh_slots = self._admit()
-        self._note_pool_peak()
+        with RecordEvent(SERVING_SPANS.admission):
+            fresh_slots = self._admit()
+            self._note_pool_peak()
+        b = self._pack_ragged(fresh_slots)
+        if b is None:
+            self._step_metrics(t_step0, tokens_before, 0, 0, finished)
+            return finished
+        args = self._upload_ragged(b)
+        self.decode_microsteps += b.K
+        self.dispatches += 1
+        greedy_all = None
+        with RecordEvent(SERVING_SPANS.dispatch, step=self.engine_steps,
+                         k=b.K, n_dec=len(b.dec), n_pre=len(b.pre),
+                         q_tokens=b.q_tokens, kv_tokens=b.kv_tokens):
+            _faults().maybe_fail("serving/dispatch")
+            out = self._unified(b.K, spec=b.use_spec)(*args)
+        with RecordEvent(SERVING_SPANS.fetch):
+            if b.use_spec:
+                (toks, greedy_all, self.k_pools, self.v_pools,
+                 self.k_scales, self.v_scales, lens) = out
+                toks, greedy_all = jax.device_get((toks, greedy_all))
+                greedy_all = np.asarray(greedy_all)      # [T]
+            else:
+                (toks, self.k_pools, self.v_pools, self.k_scales,
+                 self.v_scales, lens) = out
+            toks = np.asarray(toks)          # [K, R] — ONE host fetch
+        self._walk_ragged(b, toks, greedy_all, lens, finished)
+        self._step_metrics(t_step0, tokens_before, len(b.pre), len(b.dec),
+                           finished)
+        return finished
 
+    @RecordEvent(SERVING_SPANS.pack)
+    def _pack_ragged(self, fresh_slots):
+        """The step's packed host arrays, burst size and row lists, or
+        None when no slot has work."""
         R, T = self.max_batch, self.token_budget
         dec = [r for r in self.slots
                if r is not None and r.prefill_done >= len(r.prompt)]
         pre = [r for r in self.slots
                if r is not None and r.prefill_done < len(r.prompt)]
         if not dec and not pre:
-            self._step_metrics(t_step0, tokens_before, 0, 0, finished)
-            return finished
+            return None
 
         tokens = np.zeros((T,), np.int32)
         row_of = np.zeros((T,), np.int32)
@@ -2102,20 +2167,36 @@ class ServingEngine:
                 # forward passes over all-zero q_lens. K=1 is an
                 # already-compiled size.
                 K = 1
-        self.decode_microsteps += K
+        # KV positions the step attends: each row of pass 1 its whole
+        # context, then each sampling row one position more per burst
+        # pass while it may still emit (an EOS inside the burst stops a
+        # row earlier; the host learns that only from the fetch)
+        ran = q_lens > 0
+        kv_end = (pos0 + q_lens).astype(np.int64)
+        kv_tokens = int(kv_end[ran].sum())
+        for j in range(1, K):
+            alive = sample0 & (remaining > j)
+            kv_tokens += int((kv_end[alive] + j).sum())
+        return _PackedStep(
+            dec=dec, pre=pre, grants=grants, props_by_slot=props_by_slot,
+            use_spec=use_spec, K=K, q_tokens=cursor, kv_tokens=kv_tokens,
+            starts=starts, pos0=pos0,
+            arrays=(tokens, row_of, off_of, starts, pos0, q_lens,
+                    self.tables, fresh, sample0, remaining, eos_ids, temps))
+
+    @RecordEvent(SERVING_SPANS.upload)
+    def _upload_ragged(self, b):
+        """The unified program's arguments: the packed arrays on the
+        device, this step's PRNG key, the pools."""
         self._key, sub = jax.random.split(self._key)
-        args = (self.params, jnp.asarray(tokens), jnp.asarray(row_of),
-                jnp.asarray(off_of), jnp.asarray(starts),
-                jnp.asarray(pos0), jnp.asarray(q_lens),
-                jnp.asarray(self.tables), jnp.asarray(fresh),
-                jnp.asarray(sample0), jnp.asarray(remaining),
-                jnp.asarray(eos_ids), jnp.asarray(temps), sub,
-                self.k_pools, self.v_pools)
+        args = ((self.params,) + tuple(jnp.asarray(a) for a in b.arrays)
+                + (sub, self.k_pools, self.v_pools))
         if self.kv_quantized:
             args = args + (self.k_scales, self.v_scales)
         if self.prefix_share:
             # pending COW pairs ride this dispatch (executed before any
             # append); idle lanes self-copy the scratch block — a no-op
+            R = self.max_batch
             cow_src = np.zeros((R,), np.int32)
             cow_dst = np.zeros((R,), np.int32)
             for j, (s, d) in enumerate(self._cow_pairs[:R]):
@@ -2124,32 +2205,24 @@ class ServingEngine:
             del self._cow_pairs[:R]
             args = args + (jnp.asarray(cow_src), jnp.asarray(cow_dst),
                            jnp.asarray(self._reset_tables))
-        self.dispatches += 1
-        greedy_all = None
-        with RecordEvent("serving_unified_dispatch"):
-            _faults().maybe_fail("serving/dispatch")
-            if use_spec:
-                (toks, greedy_all, self.k_pools, self.v_pools,
-                 self.k_scales, self.v_scales, lens) = self._unified(
-                     K, spec=True)(*args)
-                toks, greedy_all = jax.device_get((toks, greedy_all))
-                toks = np.asarray(toks)      # [K, R]; greedy_all: [T]
-                greedy_all = np.asarray(greedy_all)
-            else:
-                (toks, self.k_pools, self.v_pools, self.k_scales,
-                 self.v_scales, lens) = self._unified(K)(*args)
-                toks = np.asarray(toks)      # [K, R] — ONE host fetch
+        return args
+
+    @RecordEvent(SERVING_SPANS.walk)
+    def _walk_ragged(self, b, toks, greedy_all, lens, finished):
+        """Commit the step on the host: lengths, prefix pages, draft
+        acceptance, then every emitted token through _emit/_finish."""
+        dec, pre, props_by_slot = b.dec, b.pre, b.props_by_slot
         self.lens = np.array(lens)
         for r in pre:
-            r.prefill_done += grants.get(r.slot, 0)
+            r.prefill_done += b.grants.get(r.slot, 0)
             self._register_pages(r)
-        if use_spec:
+        if b.use_spec:
             for r in dec:
                 i = r.slot
                 props = props_by_slot.get(i)
                 if not props:
                     continue  # plain row: emitted by the generic walk
-                base = int(starts[i])
+                base = int(b.starts[i])
                 acc = 0
                 for j, p in enumerate(props):
                     if int(greedy_all[base + j]) != p:
@@ -2162,7 +2235,7 @@ class ServingEngine:
                 # returned lens for) all k+1 draft positions, but the
                 # block table simply forgets the rejected tail — those
                 # positions are past lens, never read, rewritten later
-                self.lens[i] = int(pos0[i]) + acc + 1
+                self.lens[i] = int(b.pos0[i]) + acc + 1
                 for tok in props[:acc] + [int(greedy_all[base + acc])]:
                     tok = self._check_tok(r, tok)
                     self._pending_tok[i] = tok
@@ -2172,7 +2245,7 @@ class ServingEngine:
                         break
         for r in dec + [r for r in pre
                         if r.prefill_done >= len(r.prompt)]:
-            if use_spec and props_by_slot.get(r.slot):
+            if b.use_spec and props_by_slot.get(r.slot):
                 continue  # spec row: already emitted above
             for t in range(toks.shape[0]):
                 if r.done:
@@ -2183,9 +2256,6 @@ class ServingEngine:
                     finished.append(r)
                     self._finish(r)
                     break
-        self._step_metrics(t_step0, tokens_before, len(pre), len(dec),
-                           finished)
-        return finished
 
     # -- observability -------------------------------------------------------
     def _note_pool_peak(self):
@@ -2200,7 +2270,10 @@ class ServingEngine:
                 1.0 - self.free_pages() / total_blocks,
                 help="high-water allocated fraction of the KV pool")
 
+    @RecordEvent(SERVING_SPANS.metrics)
     def _step_metrics(self, t_step0, tokens_before, n_pre, n_dec, finished):
+        """End-of-step telemetry: the prom gauges and counters, the
+        completion events, and the KV-scale poll."""
         prom = self._prom
         dt = max(time.perf_counter() - t_step0, 1e-9)
         emitted = self._tokens_total - tokens_before
@@ -2245,10 +2318,6 @@ class ServingEngine:
                        help="mean compiled dispatches per engine step")
         prom.counter_inc("tokens_total", emitted,
                          help="sampled tokens emitted")
-        prom.counter_inc("prefill_slots_total", n_pre,
-                         help="slot-steps spent prefilling")
-        prom.counter_inc("decode_slots_total", n_dec,
-                         help="slot-steps spent decoding")
         prom.gauge_set("prefill_decode_mix",
                        n_pre / (n_pre + n_dec) if (n_pre + n_dec) else 0.0,
                        help="prefill share of this step's active slots")
@@ -2277,6 +2346,7 @@ class ServingEngine:
                 if log is not None:
                     log.emit("serving_complete", role="serving", rid=r.rid,
                              tokens=len(r.output), ttft_s=r.ttft_s)
+        self._numerics_kv_poll()
 
     def metrics_text(self) -> str:
         """Prometheus text-format exposition of the engine's telemetry

@@ -60,6 +60,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ._common import LANES as _LANES
 from ._common import interpret as _interpret
+from ...observability.trace import KERNELS
 
 __all__ = ["flash_attention", "supported", "FLASH_REMAT_NAMES"]
 
@@ -526,6 +527,7 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, *, h, h_kv,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=_interpret(),
+        name=KERNELS.flash_fwd,
     )(*inputs)
     if save_lse:
         out, lse = res
@@ -758,6 +760,7 @@ def _bwd_impl(q, k, v, out, lse, do, sm_scale, causal, block_q, block_k, *,
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=_interpret(),
+        name=KERNELS.flash_bwd_dkv,
     )(*seed_inputs, q, k, v, out, do, lse_r, *extra_inputs)
 
     # ---- dq: grid (B*H, q blocks, k blocks)
@@ -802,6 +805,7 @@ def _bwd_impl(q, k, v, out, lse, do, sm_scale, causal, block_q, block_k, *,
         out_shape=out_shape,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=_interpret(),
+        name=KERNELS.flash_bwd_dq,
     )(*seed_inputs, q, k, v, out, do, lse_r, *extra_inputs)
     if emit_db:
         dq, db_full = res

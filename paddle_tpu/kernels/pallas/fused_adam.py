@@ -27,6 +27,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ._common import LANES, interpret as _interpret
+from ...observability.trace import KERNELS
 
 __all__ = ["supported", "adam_update"]
 
@@ -162,7 +163,7 @@ def adam_update(p, g, slot, lr, step, rng, *, beta1, beta2, epsilon,
         out_shape=outs,
         input_output_aliases=aliases,
         interpret=_interpret(),
-        name="fused_adam",  # stable: chip_smoke.py finds it in the step
+        name=KERNELS.fused_adam,  # chip_smoke.py finds it in the step
     )(scalars, seed, *ins)
     new_p = res[0].reshape(shape)
     out = {"moment1": res[1].reshape(shape),

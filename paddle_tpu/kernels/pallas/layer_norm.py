@@ -30,6 +30,7 @@ from jax.experimental import pallas as pl
 
 from ._common import LANES as _LANES
 from ._common import interpret as _interpret
+from ...observability.trace import KERNELS
 
 __all__ = ["layer_norm", "supported"]
 
@@ -123,6 +124,7 @@ def _ln_fwd(x, weight, bias, epsilon):
             jax.ShapeDtypeStruct((n, 2 * _LANES), jnp.float32),
         ],
         interpret=_interpret(),
+        name=KERNELS.layer_norm_fwd,
     )(x2, weight.reshape(1, h), b_in)
     return y.reshape(shape), (x2, weight, has_bias, stat, shape)
 
@@ -145,6 +147,7 @@ def _ln_bwd(epsilon, res, g):
         out_specs=pl.BlockSpec((rows, h), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, h), x2.dtype),
         interpret=_interpret(),
+        name=KERNELS.layer_norm_bwd,
     )(x2, weight.reshape(1, h), stat, dy)
     # dw/db: cross-row reductions — one fused XLA reduce over the saved
     # stats (xhat recomputed elementwise, fuses into the reduction)

@@ -43,6 +43,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ._common import LANES as _LANES
 from ._common import interpret as _interpret
+from ...observability.trace import KERNELS
 
 __all__ = ["paged_decode_attention"]
 
@@ -136,5 +137,6 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, seq_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, hkv, g8, D), q.dtype),
         interpret=_interpret(),
+        name=KERNELS.paged_attn,
     )(block_tables, seq_lens, qg, k_pool, v_pool)
     return out[:, :, :g].reshape(B, hq, D)

@@ -35,6 +35,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ._common import LANES
 from ._common import interpret as _interpret
+from ...observability.trace import KERNELS
 
 __all__ = ["elementwise", "row_reduce", "online_softmax_update",
            "layer_norm"]
@@ -83,6 +84,7 @@ def elementwise(fn: Callable, *arrays, block_rows: int = 256,
         out_specs=pl.BlockSpec((br, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((r, c), out_dtype),
         interpret=_interpret(),
+        name=KERNELS.rowwise,
     )(*xs2)
     return out.reshape(shape[0] or (1,)) if shape[0] != () else out[0, 0]
 
@@ -133,6 +135,7 @@ def row_reduce(fn: Callable, identity, x, block_rows: int = 256,
         out_shape=jax.ShapeDtypeStruct((r, LANES), jnp.float32),
         scratch_shapes=[pltpu.VMEM((br, LANES), jnp.float32)],
         interpret=_interpret(),
+        name=KERNELS.row_reduce,
     )(x2)
     res = functools.reduce(fn, [out[:, k] for k in range(LANES)])
     return res.reshape(shape[:-1])
@@ -226,6 +229,7 @@ def _ln_fwd(x, weight, bias, eps):
                    jax.ShapeDtypeStruct((r, LANES), jnp.float32),
                    jax.ShapeDtypeStruct((r, LANES), jnp.float32)],
         interpret=_interpret(),
+        name=KERNELS.prim_layer_norm_fwd,
     )(x2, jnp.asarray(weight)[None, :], jnp.asarray(bias)[None, :])
     return y.reshape(shape), (x2, shape, mu, rstd)
 
@@ -258,6 +262,7 @@ def _ln_bwd_rule(eps, res, dy):
         scratch_shapes=[pltpu.VMEM((1, c), jnp.float32),
                         pltpu.VMEM((1, c), jnp.float32)],
         interpret=_interpret(),
+        name=KERNELS.prim_layer_norm_bwd,
     )(x2, weight[None, :], mu, rstd, dy2)
     return (dx.reshape(shape), dg[0].astype(weight.dtype),
             db[0].astype(weight.dtype))

@@ -49,6 +49,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ._common import LANES as _LANES
 from ._common import interpret as _interpret
+from ...observability.trace import KERNELS
 
 __all__ = ["ragged_paged_attention"]
 
@@ -180,6 +181,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_lens,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((R, hkv, CG8, D), q.dtype),
         interpret=_interpret(),
+        name=KERNELS.ragged_paged_attn,
     )(*prefetch, qt, k_pool, v_pool)
     out = out[:, :, :CG].reshape(R, hkv, C, g, D)
     return out.transpose(0, 2, 1, 3, 4).reshape(R, C, hq, D)

@@ -20,6 +20,7 @@ from jax.experimental import pallas as pl
 
 from ._common import LANES as _LANES
 from ._common import interpret as _interpret
+from ...observability.trace import KERNELS
 
 __all__ = ["rms_norm", "supported"]
 
@@ -85,6 +86,7 @@ def _rms_fwd(x, weight, epsilon):
             jax.ShapeDtypeStruct((n, _LANES), jnp.float32),
         ],
         interpret=_interpret(),
+        name=KERNELS.rms_norm_fwd,
     )(x2, weight.reshape(1, h))
     return y.reshape(shape), (x2, weight, rstd, shape)
 
@@ -107,6 +109,7 @@ def _rms_bwd(epsilon, res, g):
         out_specs=pl.BlockSpec((rows, h), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n, h), x2.dtype),
         interpret=_interpret(),
+        name=KERNELS.rms_norm_bwd,
     )(x2, weight.reshape(1, h), rstd, dy)
     # dw: cross-row reduction — a single fused XLA reduce over saved rstd
     xf = x2.astype(jnp.float32)

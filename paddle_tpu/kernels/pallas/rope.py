@@ -23,6 +23,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from ._common import interpret as _interpret
+from ...observability.trace import KERNELS
 
 __all__ = ["apply_rope", "supported"]
 
@@ -75,6 +76,7 @@ def _rope_call(x, cos, sin, neg_sin):
         out_specs=pl.BlockSpec((1, bs, h * d), lambda i, j: (i, j, 0)),
         out_shape=jax.ShapeDtypeStruct((b, s, h * d), x.dtype),
         interpret=_interpret(),
+        name=KERNELS.rope,
     )(x2, cos.reshape(1, s, d // 2), sin.reshape(1, s, d // 2))
     return y.reshape(b, s, h, d)
 

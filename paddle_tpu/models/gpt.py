@@ -35,6 +35,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from .. import nn
 from ..nn import functional as F
+from ..observability.trace import SCOPES
 from ..quantization.fp8 import site_mm as _fp8_mm
 from ..distributed.fleet.meta_parallel.pp_utils.spmd_pipeline import (
     spmd_pipeline, spmd_pipeline_interleaved, spmd_pipeline_zero_bubble,
@@ -312,6 +313,7 @@ def _attention(q, k, v):
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
 
 
+@jax.named_scope(SCOPES.attn)
 def _attn_sublayer(p, x, cfg: GPTConfig, mp_axis: str = "mp", fp8=None,
                    sp=None, flash=None, sep_axis=None):
     """ln1 + Megatron-TP causal attention + residual — the shared first
@@ -331,51 +333,54 @@ def _attn_sublayer(p, x, cfg: GPTConfig, mp_axis: str = "mp", fp8=None,
     H = cfg.hidden_size
     from ..distributed.fleet.layers.mpu import mp_ops
 
-    if sp is None:
-        S = x.shape[1]
+    with jax.named_scope(SCOPES.qkv):
         h = _ln(x, p["ln1_g"], p["ln1_b"])
-        hi = mp_ops.c_identity(h, mp_axis)
-        qkv = (_fp8_mm(fp8, "qkv")(hi.astype(cfg.dtype),
-                                   p["qkv_w"].astype(cfg.dtype))
-               + p["qkv_b"].astype(cfg.dtype))  # [B, S, 3H/mp]
-    else:
-        S = x.shape[1] * mp  # x is this rank's sequence shard
-        h = _ln(x, p["ln1_g"], p["ln1_b"])
-        qkv = (mp_ops.ag_matmul(
-            h.astype(cfg.dtype), p["qkv_w"].astype(cfg.dtype), mp_axis,
-            ring=sp.ring,
-            mm=None if fp8 is None else _fp8_mm(fp8, "qkv"))
-            + p["qkv_b"].astype(cfg.dtype))  # [B, S, 3H/mp]
-    qkv = qkv.reshape(B, S, heads_local, 3, cfg.head_dim)
+        if sp is None:
+            S = x.shape[1]
+            hi = mp_ops.c_identity(h, mp_axis)
+            qkv = (_fp8_mm(fp8, "qkv")(hi.astype(cfg.dtype),
+                                       p["qkv_w"].astype(cfg.dtype))
+                   + p["qkv_b"].astype(cfg.dtype))  # [B, S, 3H/mp]
+        else:
+            S = x.shape[1] * mp  # x is this rank's sequence shard
+            qkv = (mp_ops.ag_matmul(
+                h.astype(cfg.dtype), p["qkv_w"].astype(cfg.dtype), mp_axis,
+                ring=sp.ring,
+                mm=None if fp8 is None else _fp8_mm(fp8, "qkv"))
+                + p["qkv_b"].astype(cfg.dtype))  # [B, S, 3H/mp]
+        qkv = qkv.reshape(B, S, heads_local, 3, cfg.head_dim)
     # heads are fully local under TP, so per-shard attention is the whole
     # computation (over the FULL sequence under sp — only the
     # between-block residual stream is seq-sharded there; over this
     # rank's sequence SHARD under a sep-mode flash plan)
-    if flash is not None:
-        # training-grade path: the fused kernel (interpreter mode on CPU
-        # tier-1) wired directly, bypassing the registry hop — with
-        # flash.sep, ring/Ulysses context parallelism over sep_axis
-        from ..kernels.pallas import flash_training as _ft
-        attn = _ft.attention(qkv[:, :, :, 0], qkv[:, :, :, 1],
-                             qkv[:, :, :, 2], flash, sep_axis=sep_axis)
-    else:
-        # registry op: Pallas flash on TPU (the engine's shard_map runs
-        # with check_vma=False, so the kernel traces inside it); composed
-        # O(S^2) fallback elsewhere
-        attn = F.scaled_dot_product_attention(
-            qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2],
-            is_causal=True)
-    attn = attn.reshape(B, S, H // mp)
-    if sp is None:
-        out = _fp8_mm(fp8, "proj")(attn, p["proj_w"].astype(cfg.dtype))
-        out = (mp_ops.mp_allreduce(out, mp_axis)
-               + p["proj_b"].astype(cfg.dtype))
-    else:
-        out = (mp_ops.matmul_rs(
-            attn, p["proj_w"].astype(cfg.dtype), mp_axis, ring=sp.ring,
-            mm=None if fp8 is None else _fp8_mm(fp8, "proj"))
-            + p["proj_b"].astype(cfg.dtype))
-    return x + out
+    with jax.named_scope(SCOPES.flash):
+        if flash is not None:
+            # training-grade path: the fused kernel (interpreter mode on
+            # CPU tier-1) wired directly, bypassing the registry hop —
+            # with flash.sep, ring/Ulysses context parallelism over
+            # sep_axis
+            from ..kernels.pallas import flash_training as _ft
+            attn = _ft.attention(qkv[:, :, :, 0], qkv[:, :, :, 1],
+                                 qkv[:, :, :, 2], flash, sep_axis=sep_axis)
+        else:
+            # registry op: Pallas flash on TPU (the engine's shard_map
+            # runs with check_vma=False, so the kernel traces inside it);
+            # composed O(S^2) fallback elsewhere
+            attn = F.scaled_dot_product_attention(
+                qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2],
+                is_causal=True)
+    with jax.named_scope(SCOPES.attn_out):
+        attn = attn.reshape(B, S, H // mp)
+        if sp is None:
+            out = _fp8_mm(fp8, "proj")(attn, p["proj_w"].astype(cfg.dtype))
+            out = (mp_ops.mp_allreduce(out, mp_axis)
+                   + p["proj_b"].astype(cfg.dtype))
+        else:
+            out = (mp_ops.matmul_rs(
+                attn, p["proj_w"].astype(cfg.dtype), mp_axis, ring=sp.ring,
+                mm=None if fp8 is None else _fp8_mm(fp8, "proj"))
+                + p["proj_b"].astype(cfg.dtype))
+        return x + out
 
 
 def _block_fn(p, x, cfg: GPTConfig, mp_axis: str = "mp", fp8=None, sp=None,
@@ -424,7 +429,16 @@ def _block_fn(p, x, cfg: GPTConfig, mp_axis: str = "mp", fp8=None, sp=None,
             p[k] = mp_ops.c_identity(p[k], mp_axis)
     x = _attn_sublayer(p, x, cfg, mp_axis, fp8=fp8, sp=sp, flash=flash,
                        sep_axis=sep_axis)
+    return _mlp_sublayer(p, x, cfg, mp_axis, fp8=fp8, sp=sp)
 
+
+@jax.named_scope(SCOPES.mlp)
+def _mlp_sublayer(p, x, cfg: GPTConfig, mp_axis: str = "mp", fp8=None,
+                  sp=None):
+    """ln2 + Megatron-TP MLP + residual — the second half of the dense
+    hybrid block (column-parallel fc1, row-parallel fc2; see _block_fn
+    for the sp forms)."""
+    from ..distributed.fleet.layers.mpu import mp_ops
     h = _ln(x, p["ln2_g"], p["ln2_b"])
     if sp is None:
         hi = mp_ops.c_identity(h, mp_axis)
@@ -546,8 +560,9 @@ def _vocab_parallel_ce(logits_local, labels, mp_axis: str = "mp",
     # max-shift is for stability only; its gradient cancels, and pmax has no
     # differentiation rule — stop_gradient is exact here
     from ..distributed.fleet.layers.mpu import mp_ops
-    lmax = lax.pmax(lax.stop_gradient(jnp.max(lf, -1, keepdims=True)),
-                    mp_axis)
+    with jax.named_scope(SCOPES.coll_mp):
+        lmax = lax.pmax(lax.stop_gradient(jnp.max(lf, -1, keepdims=True)),
+                        mp_axis)
     shifted = lf - lmax
     # mp_allreduce (identity bwd) — see _vocab_parallel_embed
     lse = jnp.log(mp_ops.mp_allreduce(
@@ -562,6 +577,7 @@ def _vocab_parallel_ce(logits_local, labels, mp_axis: str = "mp",
     return jnp.where(valid, loss, 0.0), valid
 
 
+@jax.named_scope(SCOPES.embed)
 def dense_embed(params, tokens, cfg: GPTConfig):
     """Token+position embedding over the embed sub-tree {wte, wpe}."""
     x = jnp.take(params["wte"], tokens, axis=0) + params["wpe"][None, :tokens.shape[1]]
@@ -576,33 +592,47 @@ def dense_block(p, x, cfg: GPTConfig, fp8=None, flash=None):
     path, bitwise-unchanged). flash: None or a FlashAttentionConfig —
     the fused kernel instead of the registry attention (sep does not
     apply to the single-device dense path)."""
+    x = _dense_attn(p, x, cfg, fp8, flash)
+    return _dense_mlp(p, x, cfg, fp8)
+
+
+@jax.named_scope(SCOPES.attn)
+def _dense_attn(p, x, cfg: GPTConfig, fp8, flash):
     from jax.ad_checkpoint import checkpoint_name
     B, S, H = x.shape
-    h = _ln(x, p["ln1_g"], p["ln1_b"])
-    qkv = (_fp8_mm(fp8, "qkv")(h.astype(cfg.dtype),
-                               p["qkv_w"].astype(cfg.dtype))
-           + p["qkv_b"].astype(cfg.dtype))
-    # checkpoint_name tags are inert under plain jax.checkpoint; the
-    # selective remat policy (dense_forward remat_save=) keys on them
-    qkv = checkpoint_name(qkv, "qkv")
-    qkv = qkv.reshape(B, S, cfg.num_heads, 3, cfg.head_dim)
-    if flash is not None:
-        # direct fused path; its (out, lse) residuals carry the
-        # FLASH_REMAT_NAMES tags, so selective remat reuses the flash
-        # forward instead of re-running the kernel
-        from ..kernels.pallas import flash_training as _ft
-        attn = _ft.attention(qkv[:, :, :, 0], qkv[:, :, :, 1],
-                             qkv[:, :, :, 2], flash)
-    else:
-        # registry op: Pallas flash kernel on TPU (O(S) VMEM), XLA
-        # composition elsewhere — same math as the hybrid engine's
-        attn = F.scaled_dot_product_attention(
-            qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2],
-            is_causal=True)
-    attn = checkpoint_name(attn, "attn_out")
-    out = _fp8_mm(fp8, "proj")(attn.reshape(B, S, H),
-                               p["proj_w"].astype(cfg.dtype))
-    x = x + out + p["proj_b"].astype(cfg.dtype)
+    with jax.named_scope(SCOPES.qkv):
+        h = _ln(x, p["ln1_g"], p["ln1_b"])
+        qkv = (_fp8_mm(fp8, "qkv")(h.astype(cfg.dtype),
+                                   p["qkv_w"].astype(cfg.dtype))
+               + p["qkv_b"].astype(cfg.dtype))
+        # checkpoint_name tags are inert under plain jax.checkpoint; the
+        # selective remat policy (dense_forward remat_save=) keys on them
+        qkv = checkpoint_name(qkv, "qkv")
+        qkv = qkv.reshape(B, S, cfg.num_heads, 3, cfg.head_dim)
+    with jax.named_scope(SCOPES.flash):
+        if flash is not None:
+            # direct fused path; its (out, lse) residuals carry the
+            # FLASH_REMAT_NAMES tags, so selective remat reuses the flash
+            # forward instead of re-running the kernel
+            from ..kernels.pallas import flash_training as _ft
+            attn = _ft.attention(qkv[:, :, :, 0], qkv[:, :, :, 1],
+                                 qkv[:, :, :, 2], flash)
+        else:
+            # registry op: Pallas flash kernel on TPU (O(S) VMEM), XLA
+            # composition elsewhere — same math as the hybrid engine's
+            attn = F.scaled_dot_product_attention(
+                qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2],
+                is_causal=True)
+        attn = checkpoint_name(attn, "attn_out")
+    with jax.named_scope(SCOPES.attn_out):
+        out = _fp8_mm(fp8, "proj")(attn.reshape(B, S, H),
+                                   p["proj_w"].astype(cfg.dtype))
+        return x + out + p["proj_b"].astype(cfg.dtype)
+
+
+@jax.named_scope(SCOPES.mlp)
+def _dense_mlp(p, x, cfg: GPTConfig, fp8):
+    from jax.ad_checkpoint import checkpoint_name
     h = _ln(x, p["ln2_g"], p["ln2_b"])
     m = (_fp8_mm(fp8, "fc1")(h.astype(cfg.dtype),
                              p["fc1_w"].astype(cfg.dtype))
@@ -613,6 +643,7 @@ def dense_block(p, x, cfg: GPTConfig, fp8=None, flash=None):
             + p["fc2_b"].astype(cfg.dtype))
 
 
+@jax.named_scope(SCOPES.head_loss)
 def lm_logsumexp_ce(logits, labels):
     """Mean next-token CE in logsumexp+gather form, shared by the GPT and
     Llama dense losses. Logits stay in their compute dtype (bf16 on TPU)
@@ -626,6 +657,7 @@ def lm_logsumexp_ce(logits, labels):
     return jnp.mean(lse - picked)
 
 
+@jax.named_scope(SCOPES.head_loss)
 def dense_head_loss(params, x, labels, cfg: GPTConfig):
     """Final LN + LM head + logsumexp CE over the head sub-tree
     {lnf_g, lnf_b, head_w}. Identical math to dense_loss's tail."""
@@ -691,8 +723,9 @@ def dense_forward(params, tokens, cfg: GPTConfig, remat: bool = True,
         def body(carry, p):
             return blk(p, carry), None
         x, _ = lax.scan(body, x, params["blocks"])
-    x = _ln(x, params["lnf_g"], params["lnf_b"])
-    return x.astype(cfg.dtype) @ params["head_w"].astype(cfg.dtype)
+    with jax.named_scope(SCOPES.head_loss):
+        x = _ln(x, params["lnf_g"], params["lnf_b"])
+        return x.astype(cfg.dtype) @ params["head_w"].astype(cfg.dtype)
 
 
 def dense_loss(params, tokens, labels, cfg: GPTConfig, remat: bool = True,
@@ -885,8 +918,9 @@ def _deposit_act_stats(aux, M: int, axes):
     sq = aux["sq"] / float(M)
     am = aux["am"] / float(M)
     if axes:
-        sq = lax.pmean(sq, axes)
-        am = lax.pmax(am, axes)
+        with jax.named_scope(SCOPES.coll_dp):
+            sq = lax.pmean(sq, axes)
+            am = lax.pmax(am, axes)
     for i in range(int(sq.shape[0])):
         _metrics.observe(f"num_act_rms_l{i}", jnp.sqrt(sq[i]))
         _metrics.observe(f"num_act_absmax_l{i}", am[i])
@@ -1138,27 +1172,28 @@ def hybrid_loss_fn(params, tokens, labels, cfg: GPTConfig,
             if zd_ >= 0:
                 params[name] = _z3g.all_gather_param(params[name], zd_,
                                                      z3["axis"])
-    x = _vocab_parallel_embed(params["wte"], tokens, mp_axis)
-    if sep_on:
-        # tokens are this rank's sequence shard: position embedding reads
-        # the rank's GLOBAL slice (causal masking inside ring/Ulysses
-        # likewise uses global positions). The GLOBAL length must fit the
-        # table — dynamic_slice CLAMPS an out-of-range start, so an
-        # oversized sequence would silently hand later ranks the first
-        # ranks' position rows instead of erroring
-        n_sep = lax.axis_size(sep_axis)
-        enforce(S * n_sep <= cfg.max_seq_len,
-                "sep context parallelism: the global sequence "
-                "(per-rank S x sep degree) must fit max_seq_len — the "
-                "position table is sliced per rank",
-                op="gpt.hybrid_loss_fn", seq_local=S, sep=n_sep,
-                max_seq_len=cfg.max_seq_len)
-        off = lax.axis_index(sep_axis) * S
-        x = x + lax.dynamic_slice_in_dim(params["wpe"], off, S,
-                                         axis=0)[None]
-    else:
-        x = x + params["wpe"][None, :S]
-    x = x.astype(cfg.dtype)
+    with jax.named_scope(SCOPES.embed):
+        x = _vocab_parallel_embed(params["wte"], tokens, mp_axis)
+        if sep_on:
+            # tokens are this rank's sequence shard: position embedding reads
+            # the rank's GLOBAL slice (causal masking inside ring/Ulysses
+            # likewise uses global positions). The GLOBAL length must fit the
+            # table — dynamic_slice CLAMPS an out-of-range start, so an
+            # oversized sequence would silently hand later ranks the first
+            # ranks' position rows instead of erroring
+            n_sep = lax.axis_size(sep_axis)
+            enforce(S * n_sep <= cfg.max_seq_len,
+                    "sep context parallelism: the global sequence "
+                    "(per-rank S x sep degree) must fit max_seq_len — the "
+                    "position table is sliced per rank",
+                    op="gpt.hybrid_loss_fn", seq_local=S, sep=n_sep,
+                    max_seq_len=cfg.max_seq_len)
+            off = lax.axis_index(sep_axis) * S
+            x = x + lax.dynamic_slice_in_dim(params["wpe"], off, S,
+                                             axis=0)[None]
+        else:
+            x = x + params["wpe"][None, :S]
+        x = x.astype(cfg.dtype)
     if sp is not None:
         enforce(S % lax.axis_size(mp_axis) == 0,
                 "sequence parallelism needs S divisible by the mp degree",
@@ -1264,43 +1299,44 @@ def hybrid_loss_fn(params, tokens, labels, cfg: GPTConfig,
             num_aux = aux["num"]
         else:
             out = spmd_pipeline(stage_fn, stage_params, x_mb, axis=pp_axis)
-    out = out.reshape(b_local, x.shape[1], cfg.hidden_size)
-    from ..distributed.fleet.layers.mpu import mp_ops
-    lnf_g, lnf_b = params["lnf_g"], params["lnf_b"]
-    if sp is not None:
-        # final LN runs on the seq shard — its param grads are partial
-        # (see the _block_fn sp note)
-        lnf_g = mp_ops.c_identity(lnf_g, mp_axis)
-        lnf_b = mp_ops.c_identity(lnf_b, mp_axis)
-    out = _ln(out, lnf_g, lnf_b)
-    if sp is None:
-        # column-parallel head: identity fwd / allreduce bwd on its input
-        out = mp_ops.c_identity(out, mp_axis)
-        logits_local = (out.astype(cfg.dtype)
-                        @ params["head_w"].astype(cfg.dtype))
-    else:
-        # seq-sharded final LN, then AG -> column GEMM (bwd RS) — same
-        # wire as the allreduce-mode head boundary
-        logits_local = mp_ops.ag_matmul(
-            out.astype(cfg.dtype), params["head_w"].astype(cfg.dtype),
-            mp_axis, ring=sp.ring)
-    if moe_on:
-        _note_moe_wire(cfg, tokens, mp_axis, pp_axis, ep_axis, M,
-                       jax.tree.leaves(params["blocks"]["dense"])[0]
-                       .shape[0], moe)
-    else:
-        _note_mp_wire(cfg, tokens, sp, mp_axis, pp_axis, M,
-                      jax.tree.leaves(params["blocks"])[0].shape[0],
-                      virtual_pp=virtual_pp)
-    if num_aux is not None:
-        # sp shards the sequence over mp (per-rank shards differ); plain
-        # TP replicates the activations, so mp needs no reduction there
-        _deposit_act_stats(num_aux, M,
-                           (dp_axis,)
-                           + ((mp_axis,) if sp is not None else ())
-                           + ((sep_axis,) if sep_on else ()))
-    loss, valid = _vocab_parallel_ce(logits_local, labels, mp_axis)
-    total = jnp.sum(loss) / jnp.maximum(jnp.sum(valid), 1)
+    with jax.named_scope(SCOPES.head_loss):
+        out = out.reshape(b_local, x.shape[1], cfg.hidden_size)
+        from ..distributed.fleet.layers.mpu import mp_ops
+        lnf_g, lnf_b = params["lnf_g"], params["lnf_b"]
+        if sp is not None:
+            # final LN runs on the seq shard — its param grads are partial
+            # (see the _block_fn sp note)
+            lnf_g = mp_ops.c_identity(lnf_g, mp_axis)
+            lnf_b = mp_ops.c_identity(lnf_b, mp_axis)
+        out = _ln(out, lnf_g, lnf_b)
+        if sp is None:
+            # column-parallel head: identity fwd / allreduce bwd on its input
+            out = mp_ops.c_identity(out, mp_axis)
+            logits_local = (out.astype(cfg.dtype)
+                            @ params["head_w"].astype(cfg.dtype))
+        else:
+            # seq-sharded final LN, then AG -> column GEMM (bwd RS) — same
+            # wire as the allreduce-mode head boundary
+            logits_local = mp_ops.ag_matmul(
+                out.astype(cfg.dtype), params["head_w"].astype(cfg.dtype),
+                mp_axis, ring=sp.ring)
+        if moe_on:
+            _note_moe_wire(cfg, tokens, mp_axis, pp_axis, ep_axis, M,
+                           jax.tree.leaves(params["blocks"]["dense"])[0]
+                           .shape[0], moe)
+        else:
+            _note_mp_wire(cfg, tokens, sp, mp_axis, pp_axis, M,
+                          jax.tree.leaves(params["blocks"])[0].shape[0],
+                          virtual_pp=virtual_pp)
+        if num_aux is not None:
+            # sp shards the sequence over mp (per-rank shards differ); plain
+            # TP replicates the activations, so mp needs no reduction there
+            _deposit_act_stats(num_aux, M,
+                               (dp_axis,)
+                               + ((mp_axis,) if sp is not None else ())
+                               + ((sep_axis,) if sep_on else ()))
+        loss, valid = _vocab_parallel_ce(logits_local, labels, mp_axis)
+        total = jnp.sum(loss) / jnp.maximum(jnp.sum(valid), 1)
     if moe_on:
         from ..observability import metrics as _metrics
         L2 = cfg.num_layers // 2
@@ -1315,7 +1351,8 @@ def hybrid_loss_fn(params, tokens, labels, cfg: GPTConfig,
                 _metrics.observe(f"moe_tokens_e{i}",
                                  moe_stats["tokens"][i])
         # the batch is sharded over dp AND ep — the loss mean spans both
-        total = lax.pmean(total, (dp_axis, ep_axis))
+        with jax.named_scope(SCOPES.coll_dp):
+            total = lax.pmean(total, (dp_axis, ep_axis))
         if moe_ef is not None:
             return total, new_moe_ef
         return total
@@ -1324,9 +1361,11 @@ def hybrid_loss_fn(params, tokens, labels, cfg: GPTConfig,
         # the mean of per-shard means IS the global mean; sep grads are
         # genuinely partial and combine through the engine's
         # extra_grad_axes pmean — the same convention as dp
-        total = lax.pmean(total, (dp_axis, sep_axis))
+        with jax.named_scope(SCOPES.coll_dp):
+            total = lax.pmean(total, (dp_axis, sep_axis))
     else:
-        total = lax.pmean(total, dp_axis)
+        with jax.named_scope(SCOPES.coll_dp):
+            total = lax.pmean(total, dp_axis)
     if z3_ef is not None:
         return total, new_z3_ef
     return total

@@ -18,6 +18,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from ..observability.trace import SCOPES
 from ..utils import shard_map as _shard_map
 
 __all__ = ["build_train_step", "state_specs_for",
@@ -181,7 +182,8 @@ def _global_leaf_reduce(per_leaf, red, leaves_spec, leaves_z, mesh: Mesh,
         if g is None:
             continue
         acc = acc + per_leaf(g) / _repl_factor(sp, zd, mesh, dp_axis)
-    return lax.psum(acc, tuple(mesh.axis_names))
+    with jax.named_scope(SCOPES.coll_dp):   # scalars, every mesh axis
+        return lax.psum(acc, tuple(mesh.axis_names))
 
 
 def _global_sq_norm(red, leaves_spec, leaves_z, mesh: Mesh, dp_axis):
@@ -750,9 +752,12 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
         acc = sum(per)
         other = tuple(a for a in mesh.axis_names if a != layer_gather_ax)
         if other:
-            acc = lax.psum(acc, other)
+            with jax.named_scope(SCOPES.coll_dp):
+                acc = lax.psum(acc, other)
         if layer_gather_ax is not None:
-            acc = lax.all_gather(acc, layer_gather_ax, axis=0, tiled=True)
+            with jax.named_scope(SCOPES.coll_pp):
+                acc = lax.all_gather(acc, layer_gather_ax, axis=0,
+                                     tiled=True)
         return acc
 
     def _numerics_layer_tele(tele, red_tree, z_blocks):
@@ -767,6 +772,7 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
                                            z_blocks)
         return tele
 
+    @jax.named_scope(SCOPES.optimizer)
     def _zero_apply(params, grads, opt_state, lr, pre_reduced=False):
         """Per-leaf ZeRO update inside shard_map, all stages.
 
@@ -813,7 +819,8 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
                     red.append(None)
                     continue
                 if extra_grad_axes:
-                    g = lax.pmean(g, tuple(extra_grad_axes))
+                    with jax.named_scope(SCOPES.coll_dp):
+                        g = lax.pmean(g, tuple(extra_grad_axes))
                 if zero_stage >= 3 and zd >= 0:
                     # the gather's AD transpose already psum_scattered
                     # this leaf (dp SUM at the shard) — only the loss
@@ -822,12 +829,13 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
                     continue
                 gr = g.astype(grad_reduce_dtype) \
                     if grad_reduce_dtype is not None else g
-                if zd < 0:
-                    gm = lax.pmean(gr, dp_axis).astype(g.dtype)
-                else:
-                    gm = (lax.psum_scatter(gr, dp_axis,
-                                           scatter_dimension=zd,
-                                           tiled=True) / dp).astype(g.dtype)
+                with jax.named_scope(SCOPES.coll_dp):
+                    if zd < 0:
+                        gm = lax.pmean(gr, dp_axis).astype(g.dtype)
+                    else:
+                        gm = (lax.psum_scatter(
+                            gr, dp_axis, scatter_dimension=zd,
+                            tiled=True) / dp).astype(g.dtype)
                 red.append(gm)
 
         tele = None
@@ -918,8 +926,9 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
                                                     zd)
                     np_sh, ns_ = optimizer._update_ctx(ctx, p_sh, g, s, lr,
                                                        step_no, rng=rng)
-                    np_ = lax.all_gather(np_sh, dp_axis, axis=zd,
-                                         tiled=True)
+                    with jax.named_scope(SCOPES.coll_dp):
+                        np_ = lax.all_gather(np_sh, dp_axis, axis=zd,
+                                             tiled=True)
             new_p.append(np_)
             new_s.append(ns_)
         return (jax.tree.unflatten(treedef, new_p),
@@ -995,7 +1004,8 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
             if extra_axes:
                 # sep/context-parallel partial grads combine in their own
                 # dtype, exactly as the monolithic path does
-                g = jax.tree.map(lambda x: lax.pmean(x, extra_axes), g)
+                with jax.named_scope(SCOPES.coll_dp):
+                    g = jax.tree.map(lambda x: lax.pmean(x, extra_axes), g)
             if tcfg is not None and tele_comms["reduce"] is None:
                 # idempotent: the scan body may trace twice (eval_shape)
                 z_leaves = (jax.tree.structure(g).flatten_up_to(zdims)
@@ -1016,7 +1026,8 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
                     gr = (g_.astype(wire_dtype) if wire_dtype is not None
                           else g_)
                     gr = gr * jnp.asarray(weight, gr.dtype)
-                    return lax.pmean(gr, dp_axis).astype(g_.dtype)
+                    with jax.named_scope(SCOPES.coll_dp):
+                        return lax.pmean(gr, dp_axis).astype(g_.dtype)
                 return jax.tree.map(z3_one, g, zdims,
                                     is_leaf=lambda x: x is None), res
             if zero_stage:
@@ -1257,13 +1268,14 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
                 # reduced-dtype compression applies only to the dp
                 # all-reduce of identical replicas, matching the reference
                 # fp16_allreduce scope (dp grad allreduce only).
-                if extra_axes:
-                    g = lax.pmean(g, extra_axes)
-                if dp_axes:
-                    if grad_reduce_dtype is not None:
-                        return lax.pmean(g.astype(grad_reduce_dtype),
-                                         dp_axes).astype(g.dtype)
-                    return lax.pmean(g, dp_axes)
+                with jax.named_scope(SCOPES.coll_dp):
+                    if extra_axes:
+                        g = lax.pmean(g, extra_axes)
+                    if dp_axes:
+                        if grad_reduce_dtype is not None:
+                            return lax.pmean(g.astype(grad_reduce_dtype),
+                                             dp_axes).astype(g.dtype)
+                        return lax.pmean(g, dp_axes)
                 return g
 
             grads = jax.tree.map(reduce_one, grads)
@@ -1333,8 +1345,9 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
             # multi-tensor Adam ships default-off (measured slower on
             # TPU), so clip+mp/pp configs simply get the default path.
             step_no = opt_state["step"] + 1
-            new_p, new_slots = optimizer._apply_leaves(
-                params, grads, opt_state["slots"], lr, step_no)
+            with jax.named_scope(SCOPES.optimizer):
+                new_p, new_slots = optimizer._apply_leaves(
+                    params, grads, opt_state["slots"], lr, step_no)
             return rewrap(new_p, {"step": step_no, "slots": new_slots},
                           ef, fmeta, loss, tele=tele, amax=amax, obs=obs)
         new_params, new_state = optimizer.apply(params, grads, opt_state, lr)

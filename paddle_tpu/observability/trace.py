@@ -8,19 +8,93 @@ spans with no extra plumbing. ``write_chrome_trace`` is the standalone
 export for code that wants a trace file without driving a Profiler
 session (same JSON schema as the profiler's exporter, so the files are
 interchangeable in chrome://tracing / Perfetto).
+
+This module also owns the NAMES the program gives its work, so that a
+trace of one commit can be held against a trace of the next:
+
+* ``SERVING_SPANS`` — the host spans of one ``ServingEngine.step()``;
+* ``DISPATCH_ATTRS`` — the attributes of the ``serving_unified_dispatch``
+  span (stats of the event in a profiler trace);
+* ``SCOPES`` — the ``jax.named_scope``s inside the compiled programs (the
+  serving step, the dense train step, the hybrid train step). A device
+  operation's ``op_name`` path carries them; an operation under none is
+  work no line of the program asked for by name;
+* ``KERNELS`` — the ``name=`` of every ``pallas_call``; the compiler names
+  the custom call after it (``ragged_paged_attn.7``).
+
+Call sites take the names from these tuples (``SCOPES.qkv``), never from a
+string of their own; the benchmark's metric files name the same strings
+and a test holds the two together.
 """
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 from typing import Iterable, Optional
 
 from ..profiler.utils import HostEvent, RecordEvent, collector
 
-__all__ = ["span", "capture_spans", "write_chrome_trace"]
+__all__ = ["span", "capture_spans", "write_chrome_trace", "SERVING_SPANS",
+           "TWO_PROGRAM_SPANS", "DISPATCH_ATTRS", "SCOPES", "KERNELS"]
 
 span = RecordEvent
+
+
+def _names(typename, **names):
+    """A tuple of names that also answers by attribute."""
+    return collections.namedtuple(typename, names)(**names)
+
+
+# One ragged engine step: `step` is the whole call, the others are its
+# children and cover it without holes (the two-program path opens `step`,
+# `sweep` and `metrics` and keeps its own dispatch spans).
+SERVING_SPANS = _names(
+    "ServingSpans",
+    step="serving_step",            # the whole ServingEngine.step()
+    sweep="serving_sweep",          # notifications, deadlines, overload
+    admission="serving_admission",  # _admit() and the pool's peak
+    pack="serving_pack",            # the packed host arrays, _pick_burst
+    upload="serving_upload",        # key split + jnp.asarray of each array
+    dispatch="serving_unified_dispatch",   # the call of the one program
+    fetch="serving_fetch",          # the host blocked on the device
+    walk="serving_walk",            # lens, pages, acceptance, token walk
+    metrics="serving_metrics")      # _step_metrics, _numerics_kv_poll
+
+# The two-program path's own dispatch spans, one a compiled program.
+TWO_PROGRAM_SPANS = _names(
+    "TwoProgramSpans",
+    cow="serving_cow_dispatch", prefill="serving_prefill_dispatch",
+    verify="serving_verify_dispatch", decode="serving_decode_dispatch")
+
+# Attributes of the dispatch span: the engine step's number, the burst
+# size, decode and prefill rows, packed query tokens, and KV positions
+# attended (summed over the rows that run and over the step's k passes).
+DISPATCH_ATTRS = ("step", "k", "n_dec", "n_pre", "q_tokens", "kv_tokens")
+
+SCOPES = _names(
+    "Scopes",
+    # serving program (inference/serving.py, inference/ragged_step.py)
+    embed="embed", qkv="qkv", kv_write="kv_write", ragged_attn="ragged_attn",
+    proj_mlp="proj_mlp", head="head", sample="sample", cow="cow",
+    burst="burst",
+    # train programs (models/gpt.py, optimizer/); embed and qkv as above
+    attn="attn", flash="flash", attn_out="attn_out", mlp="mlp",
+    head_loss="head_loss", optimizer="optimizer",
+    # around every collective, the mesh axis it crosses
+    coll_mp="coll_mp", coll_dp="coll_dp", coll_pp="coll_pp")
+
+KERNELS = _names(
+    "Kernels",
+    flash_fwd="flash_fwd", flash_bwd_dkv="flash_bwd_dkv",
+    flash_bwd_dq="flash_bwd_dq", ragged_paged_attn="ragged_paged_attn",
+    paged_attn="paged_attn", fused_adam="fused_adam",
+    layer_norm_fwd="layer_norm_fwd", layer_norm_bwd="layer_norm_bwd",
+    rms_norm_fwd="rms_norm_fwd", rms_norm_bwd="rms_norm_bwd", rope="rope",
+    rowwise="rowwise", row_reduce="row_reduce",
+    prim_layer_norm_fwd="prim_layer_norm_fwd",
+    prim_layer_norm_bwd="prim_layer_norm_bwd")
 
 
 class capture_spans:
@@ -48,10 +122,7 @@ def write_chrome_trace(path: str, events: Iterable[HostEvent],
                        extra: Optional[Iterable[dict]] = None) -> str:
     """Write chrome://tracing JSON from HostEvents (plus optional raw
     trace dicts — e.g. instant events from a JSONL log)."""
-    trace = [{"name": ev.name, "ph": "X", "cat": ev.event_type,
-              "ts": ev.start * 1e6, "dur": ev.duration * 1e6,
-              "pid": os.getpid(), "tid": ev.tid}
-             for ev in events]
+    trace = [ev.chrome() for ev in events]
     trace.extend(extra or ())
     d = os.path.dirname(path)
     if d:

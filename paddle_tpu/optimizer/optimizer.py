@@ -22,6 +22,7 @@ from ..enforce import (InvalidArgumentError, InvalidTypeError,
 import numpy as np
 
 from ..nn.layer.layers import Layer, Parameter
+from ..observability.trace import SCOPES
 from .lr import LRScheduler
 
 __all__ = ["Optimizer", "SGD", "Momentum", "Adagrad", "Adadelta", "RMSProp",
@@ -183,6 +184,7 @@ class Optimizer:
         return (jax.tree.unflatten(treedef, new_p),
                 jax.tree.unflatten(treedef, new_s))
 
+    @jax.named_scope(SCOPES.optimizer)
     def apply(self, params, grads, state, lr=None):
         """Pure update: returns (new_params, new_state). jit/pjit-safe."""
         lr = self.get_lr() if lr is None else lr
@@ -499,13 +501,14 @@ class Adam(Optimizer):
                 "grads)")
         if not use_mt:
             return super().apply(params, grads, state, lr)
-        lr = self.get_lr() if lr is None else lr
-        step = state["step"] + 1
-        if self._grad_clip is not None:
-            grads = self._grad_clip(grads)
-        new_p, new_slots = self._fused_update(params, grads, state["slots"],
-                                              lr, step)
-        return new_p, {"step": step, "slots": new_slots}
+        with jax.named_scope(SCOPES.optimizer):
+            lr = self.get_lr() if lr is None else lr
+            step = state["step"] + 1
+            if self._grad_clip is not None:
+                grads = self._grad_clip(grads)
+            new_p, new_slots = self._fused_update(
+                params, grads, state["slots"], lr, step)
+            return new_p, {"step": step, "slots": new_slots}
 
     def _fused_update(self, params, grads, slots, lr, step):
         """Multi-tensor update (reference: use_multi_tensor /

@@ -81,13 +81,7 @@ def export_chrome_tracing(dir_name: Optional[str] = None,
         name = worker_name or f"worker_{os.getpid()}"
         path = os.path.join(dir_name,
                             f"{name}_step{prof.step_num}.json")
-        events = []
-        for ev in prof._recorded:
-            events.append({
-                "name": ev.name, "ph": "X", "cat": ev.event_type,
-                "ts": ev.start * 1e6, "dur": ev.duration * 1e6,
-                "pid": os.getpid(), "tid": ev.tid,
-            })
+        events = [ev.chrome() for ev in prof._recorded]
         with open(path, "w") as f:
             json.dump({"traceEvents": events}, f)
         prof.last_export_path = path

@@ -4,19 +4,27 @@ surface python/paddle/profiler/utils.py RecordEvent).
 
 TPU design: device-side tracing belongs to jax.profiler (XPlane/Perfetto);
 host spans are collected in-process so the Profiler can build the summary
-tables and a chrome trace without any vendor tooling, and are mirrored into
-jax.profiler.TraceAnnotation so they also appear on the device timeline
-when a jax trace is active.
+tables and a chrome trace without any vendor tooling. Every span is ALSO a
+jax.profiler.TraceAnnotation, whoever started the profiler session (this
+package's Profiler, `jax.profiler.start_trace`/`start_server`, a
+benchmark): the program's spans sit on the host plane of the same
+`.xplane.pb` as the device operations, on their clock, with their
+attributes as the event's stats. With no session a TraceAnnotation is a
+flag check.
 """
 
 from __future__ import annotations
 
+import itertools
+import os
 import threading
-
-from ..flags import flag as _flag
 import time
 from dataclasses import dataclass, field
-from typing import List, Optional
+from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+from ..flags import flag as _flag
 
 __all__ = ["RecordEvent", "HostEvent", "EventCollector", "collector", "Stat",
            "active_spans"]
@@ -50,10 +58,22 @@ class HostEvent:
     end: float
     tid: int
     event_type: str = "UserDefined"
+    span_id: int = 0      # unique in the process
+    parent: Optional[int] = None   # span_id of the span open on this
+    #                                thread when this one began
+    attrs: Dict[str, Any] = field(default_factory=dict)
 
     @property
     def duration(self) -> float:
         return self.end - self.start
+
+    def chrome(self) -> Dict[str, Any]:
+        """This span as one chrome://tracing complete event."""
+        return {"name": self.name, "ph": "X", "cat": self.event_type,
+                "ts": self.start * 1e6, "dur": self.duration * 1e6,
+                "pid": os.getpid(), "tid": self.tid,
+                "args": {"span_id": self.span_id, "parent": self.parent,
+                         **self.attrs}}
 
 
 class EventCollector:
@@ -91,6 +111,16 @@ collector = EventCollector()
 # exactly when no profiler session is active.
 _OPEN_SPANS: dict = {}
 _OPEN_LOCK = threading.Lock()
+_SPAN_IDS = itertools.count(1)
+_STACK = threading.local()     # .spans: this thread's open span ids
+
+
+def _open_ids() -> list:
+    """This thread's stack of open span ids."""
+    stack = getattr(_STACK, "spans", None)
+    if stack is None:
+        stack = _STACK.spans = []
+    return stack
 
 
 def active_spans():
@@ -110,38 +140,48 @@ def active_spans():
 class RecordEvent:
     """Context manager/decorator recording one host span.
 
-    Usage: ``with profiler.RecordEvent("forward"): ...`` — nesting works,
-    and spans show on the jax device trace via TraceAnnotation."""
+    Usage: ``with profiler.RecordEvent("forward", step=3): ...``. A span
+    records its name, start, end, the span that was open on its thread
+    when it began (``HostEvent.parent``) and its keyword attributes
+    (``HostEvent.attrs``; numbers or strings). It is a
+    jax.profiler.TraceAnnotation too, so any profiler session shows it
+    beside the device operations, attributes as the event's stats."""
 
-    def __init__(self, name: str, event_type: str = "UserDefined"):
+    def __init__(self, name: str, event_type: str = "UserDefined", **attrs):
         self.name = name
         self.event_type = event_type
+        self.attrs = attrs
         self._start: Optional[float] = None
         self._jax_ctx = None
+        self._id = 0
+        self._parent: Optional[int] = None
 
     def begin(self):
+        stack = _open_ids()
+        self._id = next(_SPAN_IDS)
+        self._parent = stack[-1] if stack else None
+        stack.append(self._id)
         self._start = time.perf_counter()
         with _OPEN_LOCK:
             _OPEN_SPANS[id(self)] = (self.name, self._start,
                                      threading.get_ident(), self.event_type)
-        if collector.enabled:
-            try:
-                import jax.profiler
-                self._jax_ctx = jax.profiler.TraceAnnotation(self.name)
-                self._jax_ctx.__enter__()
-            except Exception:
-                self._jax_ctx = None
+        self._jax_ctx = TraceAnnotation(self.name, **self.attrs)
+        self._jax_ctx.__enter__()
 
     def end(self):
         if self._start is None:
             return
+        end = time.perf_counter()
+        self._jax_ctx.__exit__(None, None, None)
+        self._jax_ctx = None
         with _OPEN_LOCK:
             _OPEN_SPANS.pop(id(self), None)
-        if self._jax_ctx is not None:
-            self._jax_ctx.__exit__(None, None, None)
-            self._jax_ctx = None
-        collector.add(HostEvent(self.name, self._start, time.perf_counter(),
-                                threading.get_ident(), self.event_type))
+        stack = _open_ids()
+        if self._id in stack:     # begin()/end() pairs need not nest, and
+            stack.remove(self._id)   # end() may run on another thread
+        collector.add(HostEvent(self.name, self._start, end,
+                                threading.get_ident(), self.event_type,
+                                self._id, self._parent, self.attrs))
         self._start = None
 
     def __enter__(self):
@@ -157,6 +197,7 @@ class RecordEvent:
 
         @functools.wraps(fn)
         def wrapped(*a, **kw):
-            with RecordEvent(self.name or fn.__qualname__, self.event_type):
+            with RecordEvent(self.name or fn.__qualname__, self.event_type,
+                             **self.attrs):
                 return fn(*a, **kw)
         return wrapped

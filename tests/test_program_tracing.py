@@ -1,0 +1,436 @@
+"""The program names its own work (ISSUE 26): host spans with parent and
+attributes that reach any jax.profiler trace, the spans inside one ragged
+serving step, `jax.named_scope`s inside the three compiled programs, a
+name on every Pallas kernel, and the benchmark's readers of all that on
+slices recorded on the TPU v5e."""
+
+import ast
+import glob
+import gzip
+import json
+import os
+import re
+import sys
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if REPO not in sys.path:
+    sys.path.insert(0, REPO)
+
+import paddle_tpu as paddle  # noqa: E402
+import paddle_tpu.distributed as dist  # noqa: E402
+from paddle_tpu import observability as obs  # noqa: E402
+from paddle_tpu.inference.serving import ServingEngine  # noqa: E402
+from paddle_tpu.models import gpt as G  # noqa: E402
+from paddle_tpu.observability.trace import (DISPATCH_ATTRS, KERNELS,  # noqa: E402
+                                            SCOPES, SERVING_SPANS,
+                                            TWO_PROGRAM_SPANS)
+from paddle_tpu.profiler.utils import RecordEvent, collector  # noqa: E402
+
+from chipbench import harness, program_trace  # noqa: E402
+
+DATA = os.path.join(REPO, "tests", "data")
+
+
+def tiny_cfg(**kw):
+    base = dict(vocab_size=64, hidden_size=32, num_layers=2, num_heads=4,
+                max_seq_len=64, dtype=jnp.float32)
+    base.update(kw)
+    return G.GPTConfig(**base)
+
+
+# -- the span primitive -----------------------------------------------------
+def test_a_span_reaches_a_jax_profiler_trace_without_the_collector(tmp_path):
+    """Whoever starts the profiler session, the program's spans are in its
+    file, attributes as the event's stats."""
+    assert not collector.enabled
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with RecordEvent("outer_probe", step=3, k=2):
+            with RecordEvent("inner_probe", kv_tokens=12345678901):
+                jnp.ones((4,)).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    files = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    assert files
+    host = {h[0]: h for h in program_trace.from_xplane(files[0])["host"]}
+    assert host["outer_probe"][3] == {"step": 3, "k": 2}
+    assert host["inner_probe"][3] == {"kv_tokens": 12345678901}
+    o, i = host["outer_probe"], host["inner_probe"]
+    assert o[1] <= i[1] and i[1] + i[2] <= o[1] + o[2]
+
+
+def test_parent_and_attributes_are_recorded_and_nest(tmp_path):
+    with obs.capture_spans() as cap:
+        with obs.span("a", rows=4) as a:
+            with obs.span("b"):
+                with obs.span("c", note="x"):
+                    pass
+            with obs.span("b2"):
+                pass
+        with obs.span("lone"):
+            pass
+    ev = {e.name: e for e in cap.events}
+    assert ev["a"].parent is None and ev["lone"].parent is None
+    assert ev["b"].parent == ev["a"].span_id == ev["b2"].parent
+    assert ev["c"].parent == ev["b"].span_id
+    assert ev["a"].attrs == {"rows": 4} and ev["c"].attrs == {"note": "x"}
+    assert len({e.span_id for e in cap.events}) == 5
+    assert a.name == "a"
+    path = obs.write_chrome_trace(str(tmp_path / "t.json"), cap.events)
+    args = {e["name"]: e["args"] for e in
+            json.load(open(path))["traceEvents"]}
+    assert args["c"] == {"span_id": ev["c"].span_id,
+                         "parent": ev["b"].span_id, "note": "x"}
+    assert args["a"]["rows"] == 4 and args["a"]["parent"] is None
+
+
+def test_begin_end_pairs_that_do_not_nest_keep_the_stack_sound():
+    with obs.capture_spans() as cap:
+        a, b = RecordEvent("a"), RecordEvent("b")
+        a.begin()
+        b.begin()
+        a.end()          # ends before its child
+        b.end()
+        with RecordEvent("after"):
+            pass
+    ev = {e.name: e for e in cap.events}
+    assert ev["b"].parent == ev["a"].span_id
+    assert ev["after"].parent is None
+
+
+# -- the serving step -------------------------------------------------------
+def _expected_dispatch(eng):
+    """The dispatch attributes one step should carry, from the engine's
+    state before it (no speculation, budget never binds)."""
+    n_dec = n_pre = q_tokens = 0
+    rows = []       # (kv positions after pass 1, samples, may still emit)
+    for r in eng.slots:
+        if r is None:
+            continue
+        if r.prefill_done >= len(r.prompt):
+            n_dec += 1
+            q_tokens += 1
+            rows.append((int(eng.lens[r.slot]) + 1, True,
+                         r.max_new_tokens - len(r.output)))
+        else:
+            n_pre += 1
+            grant = min(eng.chunk, len(r.prompt) - r.prefill_done)
+            q_tokens += grant
+            done = r.prefill_done + grant >= len(r.prompt)
+            rows.append((r.prefill_done + grant, done,
+                         r.max_new_tokens - len(r.output) if done else 0))
+    return n_dec, n_pre, q_tokens, rows
+
+
+def test_one_ragged_step_yields_every_serving_span_once():
+    cfg = tiny_cfg()
+    params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(params, cfg, ragged=True, max_batch=4,
+                        block_size=16, num_blocks=16, chunk=8,
+                        decode_burst=4)
+    eng.add_request(np.arange(5) % 64, max_new_tokens=12)
+    eng.add_request(np.arange(7) % 64, max_new_tokens=12)
+    for _ in range(2):
+        eng.step()                      # both rows decode from here on
+    eng.add_request(np.arange(20) % 64, max_new_tokens=4)
+    eng.step()                          # the newcomer is admitted
+    n_dec, n_pre, q_tokens, rows = _expected_dispatch(eng)
+    assert n_dec == 2 and n_pre == 1
+    micro0 = eng.decode_microsteps
+    with obs.capture_spans() as cap:
+        eng.step()
+    by_name = {}
+    for e in cap.events:
+        by_name.setdefault(e.name, []).append(e)
+    assert set(SERVING_SPANS) <= set(by_name)
+    assert all(len(by_name[n]) == 1 for n in SERVING_SPANS)
+    step = by_name[SERVING_SPANS.step][0]
+    children = [by_name[n][0] for n in SERVING_SPANS
+                if n != SERVING_SPANS.step]
+    assert all(c.parent == step.span_id for c in children)
+    assert all(step.start <= c.start and c.end <= step.end
+               for c in children)
+    order = sorted(children, key=lambda c: c.start)
+    assert all(a.end <= b.start for a, b in zip(order, order[1:]))
+    assert sum(c.duration for c in children) >= 0.95 * step.duration
+    attrs = by_name[SERVING_SPANS.dispatch][0].attrs
+    assert tuple(attrs) == DISPATCH_ATTRS
+    k = eng.decode_microsteps - micro0
+    kv = sum(end for end, _, _ in rows) + sum(
+        end + j for j in range(1, k) for end, samples, left in rows
+        if samples and left > j)
+    assert attrs == {"step": eng.engine_steps, "k": k, "n_dec": n_dec,
+                     "n_pre": n_pre, "q_tokens": q_tokens, "kv_tokens": kv}
+
+
+def test_an_idle_step_and_the_two_program_path_keep_their_spans():
+    cfg = tiny_cfg()
+    params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(params, cfg, ragged=True, max_batch=2,
+                        block_size=16, num_blocks=16, chunk=8)
+    with obs.capture_spans() as cap:
+        eng.step()
+    assert [e.name for e in cap.events] == [
+        SERVING_SPANS.sweep, SERVING_SPANS.admission, SERVING_SPANS.pack,
+        SERVING_SPANS.metrics, SERVING_SPANS.step]
+    two = ServingEngine(params, cfg, ragged=False, max_batch=2,
+                        block_size=16, num_blocks=16, chunk=8,
+                        decode_burst=2, adaptive_burst=False)
+    two.add_request(np.arange(4) % 64, max_new_tokens=3)
+    with obs.capture_spans() as cap:
+        two.run(max_steps=20)
+    names = {e.name for e in cap.events}
+    assert {SERVING_SPANS.step, SERVING_SPANS.sweep, SERVING_SPANS.metrics,
+            TWO_PROGRAM_SPANS.prefill, TWO_PROGRAM_SPANS.decode} <= names
+
+
+def test_the_two_unread_prom_counters_are_gone():
+    cfg = tiny_cfg()
+    params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(params, cfg, ragged=True, max_batch=2,
+                        block_size=16, num_blocks=16, chunk=8)
+    eng.add_request(np.arange(4) % 64, max_new_tokens=2)
+    eng.run(max_steps=10)
+    text = eng.metrics_text()
+    assert "engine_steps_total" in text
+    assert "prefill_slots_total" not in text
+    assert "decode_slots_total" not in text
+
+
+# -- names inside the compiled programs -------------------------------------
+def _scopes_in(lowered):
+    text = lowered.as_text(debug_info=True)
+    return {s for s in SCOPES if re.search(r"[/(\"]%s[/)\"]" % s, text)}
+
+
+TRAIN_SCOPES = {SCOPES.embed, SCOPES.attn, SCOPES.qkv, SCOPES.flash,
+                SCOPES.attn_out, SCOPES.mlp, SCOPES.head_loss,
+                SCOPES.optimizer}
+
+
+def test_the_dense_step_carries_its_scopes():
+    cfg = tiny_cfg(max_seq_len=32)
+    params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3)
+    state = opt.init_state(params)
+
+    def step(params, state, tokens, labels):     # the benchmark's step
+        loss, grads = jax.value_and_grad(
+            lambda p: G.dense_loss(p, tokens, labels, cfg))(params)
+        params, state = opt.apply(params, grads, state, 1e-3)
+        return params, state, loss
+    tok = jnp.zeros((2, 16), jnp.int32)
+    assert _scopes_in(jax.jit(step).lower(params, state, tok, tok)) == \
+        TRAIN_SCOPES
+
+
+def test_the_hybrid_step_carries_its_scopes_and_its_axes():
+    cfg = tiny_cfg(max_seq_len=32)
+    mesh = dist.build_mesh({"dp": 2, "pp": 2, "mp": 2},
+                           devices=jax.devices()[:8])
+    step, shard_params, init_state = G.build_hybrid_train_step(
+        cfg, mesh, paddle.optimizer.AdamW(learning_rate=1e-3),
+        num_microbatches=2)
+    params = shard_params(G.init_hybrid_params(cfg, jax.random.PRNGKey(0)))
+    state = init_state(params)
+    tok = jnp.zeros((4, 16), jnp.int32)
+    found = _scopes_in(step.lower(params, state, tok, tok,
+                                  jnp.float32(1e-3)))
+    assert found == TRAIN_SCOPES | {SCOPES.coll_mp, SCOPES.coll_dp,
+                                    SCOPES.coll_pp}
+
+
+def test_the_unified_serving_step_carries_its_scopes():
+    cfg = tiny_cfg()
+    params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(params, cfg, ragged=True, max_batch=2,
+                        block_size=16, num_blocks=16, chunk=8,
+                        decode_burst=4, prefix_share=True)
+    eng.add_request(np.arange(6) % 64, max_new_tokens=8)
+    batch = eng._pack_ragged(eng._admit())
+    lowered = eng._build_unified(2).lower(*eng._upload_ragged(batch))
+    assert _scopes_in(lowered) == {
+        SCOPES.embed, SCOPES.qkv, SCOPES.kv_write, SCOPES.ragged_attn,
+        SCOPES.proj_mlp, SCOPES.head, SCOPES.sample, SCOPES.cow,
+        SCOPES.burst}
+
+
+def _calls(tree, attr):
+    return [n for n in ast.walk(tree) if isinstance(n, ast.Call)
+            and isinstance(n.func, ast.Attribute) and n.func.attr == attr]
+
+
+def _from_tuple(node, tuple_name, fields):
+    return (isinstance(node, ast.Attribute)
+            and isinstance(node.value, ast.Name)
+            and node.value.id == tuple_name and node.attr in fields)
+
+
+def test_every_pallas_call_has_a_name_in_KERNELS():
+    used = set()
+    files = glob.glob(os.path.join(REPO, "paddle_tpu", "kernels", "pallas",
+                                   "*.py"))
+    for path in files:
+        for call in _calls(ast.parse(open(path).read()), "pallas_call"):
+            name = {k.arg: k.value for k in call.keywords}.get("name")
+            assert _from_tuple(name, "KERNELS", KERNELS._fields), \
+                f"{path}:{call.lineno}: pallas_call without name=KERNELS.x"
+            used.add(name.attr)
+    assert used == set(KERNELS._fields)
+    assert len(set(KERNELS)) == len(KERNELS)
+
+
+def test_no_scope_or_serving_span_is_a_free_string():
+    for path in glob.glob(os.path.join(REPO, "paddle_tpu", "**", "*.py"),
+                          recursive=True):
+        tree = ast.parse(open(path).read())
+        for call in _calls(tree, "named_scope"):
+            assert _from_tuple(call.args[0], "SCOPES", SCOPES._fields), \
+                f"{path}:{call.lineno}"
+    serving = ast.parse(open(os.path.join(
+        REPO, "paddle_tpu", "inference", "serving.py")).read())
+    spans = [n for n in ast.walk(serving) if isinstance(n, ast.Call)
+             and isinstance(n.func, ast.Name) and n.func.id == "RecordEvent"]
+    assert len(spans) >= 12
+    for call in spans:
+        assert _from_tuple(call.args[0], "SERVING_SPANS",
+                           SERVING_SPANS._fields) or \
+            _from_tuple(call.args[0], "TWO_PROGRAM_SPANS",
+                        TWO_PROGRAM_SPANS._fields), call.lineno
+
+
+# -- the benchmark's readers ------------------------------------------------
+SPEC = harness.load_spec()
+NEW_READERS = {"span_ms", "idle_unattributed", "scope_share", "kernel_hbm",
+               "exposed_collective", "scope_collective"}
+NEW = [m["name"] for m in SPEC["per_layer"]
+       if harness.load_json("metrics", m["name"] + ".json")["reader"]
+       in NEW_READERS]
+
+
+def test_the_new_metrics_are_the_issues_and_two_more():
+    # ISSUE 26's thirty, plus the hybrid cell's gradient-sync share and
+    # the pp axis's time in flight (PERF.md, PR 26: why they were needed)
+    assert len(NEW) == 32
+    assert len([n for n in NEW if n.endswith(".docs")]) == 11
+    assert len([n for n in NEW if n.endswith(".chat")]) == 11
+
+
+@pytest.mark.parametrize("name", NEW)
+def test_metric_files_name_only_what_the_program_names(name):
+    """A rename in the program fails here instead of reading 0 there."""
+    params = harness.load_json("metrics", name + ".json")["params"]
+    spans = set(SERVING_SPANS)
+    for key in ("spans", "span", "until", "per"):
+        value = params.get(key, [])
+        for s in [value] if isinstance(value, str) else value:
+            assert s in spans, (name, s)
+    for key in ("scopes", "of"):
+        assert set(params.get(key, [])) <= set(SCOPES), name
+    if "kernel" in params:
+        assert params["kernel"] in KERNELS
+    if "attr" in params:
+        assert params["attr"] in DISPATCH_ATTRS
+
+
+def test_the_share_metrics_of_a_cell_divide_its_program():
+    """Each cell's shares name disjoint scopes that together are `of`, so
+    the shares and the unscoped share sum to 100."""
+    for suffix in (".docs", ".chat", ".train"):
+        shares = [harness.load_json("metrics", n + ".json")["params"]
+                  for n in NEW if n.endswith("_time_pct" + suffix)]
+        of = shares[0]["of"]
+        assert all(p["of"] == of for p in shares)
+        named = [s for p in shares for s in p["scopes"]]
+        assert sorted(named) == sorted(of)
+        assert sum(1 for p in shares if not p["scopes"]) == 1
+
+
+def _slice(name):
+    with gzip.open(os.path.join(DATA, name), "rt") as f:
+        return json.load(f)
+
+
+class _Dev:
+    device_kind = "TPU v5 lite"
+
+
+def _run_of(pt):
+    """A run as the readers see it: the slice as the program trace, and
+    its reduced form as the harness's own."""
+    reduced = {"devices": {k: [e[:3] for e in v]
+                           for k, v in pt["devices"].items()},
+               "async": {k: [e[:3] for e in v]
+                         for k, v in pt["async"].items()},
+               "host": [h[:3] for h in pt["host"]
+                        if h[0] in harness.HOST_SPANS]}
+    return {"program_trace": pt, "trace": reduced, "facts": {},
+            "devices": [_Dev()]}
+
+
+SLICES = {".docs": "ptrace-v5e-serve-docs-slice.json.gz",
+          ".chat": "ptrace-v5e-serve-chat-slice.json.gz",
+          ".train": "ptrace-v5e-train-hybrid-slice.json.gz"}
+
+
+@pytest.mark.parametrize("name", NEW)
+def test_reader_on_a_slice_recorded_on_the_chip(name):
+    pt = _slice(SLICES["." + name.rsplit(".", 1)[1]])
+    value = harness.read_metric(name, _run_of(pt))
+    assert value is not None and 0.0 <= value
+    if name.split(".")[0].endswith("_pct"):
+        assert value <= 100.0
+    expected = pt["note"]["expected"]
+    assert value == pytest.approx(expected[name], rel=1e-6), name
+
+
+@pytest.mark.parametrize("suffix", sorted(SLICES))
+def test_the_shares_of_a_recorded_slice_sum_to_100(suffix):
+    run = _run_of(_slice(SLICES[suffix]))
+    shares = [harness.read_metric(n, run) for n in NEW
+              if n.endswith("_time_pct" + suffix)]
+    assert sum(shares) == pytest.approx(100.0, abs=1.0)
+
+
+@pytest.mark.parametrize("name", NEW)
+def test_reader_returns_nothing_without_the_programs_names(name):
+    """The parent commit's program: no spans of its own, no scopes."""
+    pt = _slice(SLICES["." + name.rsplit(".", 1)[1]])
+    bare = {"devices": {k: [e[:3] + [re.sub(r"[^/()]+", "x", e[3])]
+                            for e in v] for k, v in pt["devices"].items()},
+            "async": {k: [e[:3] + [""] for e in v]
+                      for k, v in pt["async"].items()},
+            "host": [h for h in pt["host"] if h[0] in harness.HOST_SPANS]}
+    value = harness.read_metric(name, _run_of(bare))
+    if name.startswith("exposed_collective_pct"):
+        assert value is not None    # reads the reduced form alone
+    else:
+        assert value is None
+
+
+def test_scope_of_reads_paths_as_the_profiler_writes_them():
+    s = set(SCOPES)
+    f = program_trace.scope_of
+    assert f("jit(step)/jvp()/while/body/closed_call/attn/qkv/dot_general:",
+             s) == "qkv"
+    assert f("jit(step)/transpose(jvp(head_loss))/add_any:", s) == \
+        "head_loss"
+    assert f("jit(step)/transpose(jvp())/while/body/closed_call/checkpoint/"
+             "rematted_computation/attn/flash/flash_fwd/pallas_call:",
+             s) == "flash"
+    assert f("jit(step)/jvp()/while/body/dynamic_update_slice:", s) is None
+    assert f("", s) is None
+    assert f("jit(fn)/burst/while/body/qkv/dot_general:",
+             {"burst"}) == "burst"
+    assert program_trace.kernel_of(
+        "ragged_paged_attn.7|tpu_custom_call|bf16[64,16,8,128]") == \
+        "ragged_paged_attn"
+    assert program_trace.kernel_of("fusion.3|fusion|bf16[8]") is None
