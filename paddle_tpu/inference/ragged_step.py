@@ -26,6 +26,31 @@ buffer holds each active row's tokens contiguously at ``starts[r]``;
 tail padding points past every row's ``q_len`` (masked everywhere).
 Attention tiles are gathered per row to a static ``[R, c_att]`` window —
 the GEMM stages, where the FLOPs live, stay unpadded.
+
+The KV pool's contract (the one place it is stated; the kernels and
+`quantization.kv_cache` refer here). The K and the V pool are ONE buffer
+each, ``[L, H_kv, NB, bs, D]`` (+ ``[L, H_kv, NB]`` f32 scales when
+quantized), from `unified_step`'s donated argument to its aliased
+result:
+
+  * it rides the layer scan's and the burst scan's CARRY, never ``xs`` /
+    ``ys`` — a scan that takes the pool as ``xs`` slices a layer's page
+    set out of the stack and stacks a fresh one back, 2 x 3.2 GB a pass;
+  * it is never sliced by layer: the attention kernel and the append
+    take the whole pool and a ``layer`` scalar (scalar prefetch,
+    dereferenced in their index maps);
+  * it is written only in place and in the layout the kernel reads: new
+    rows by `kernels.pallas.kv_append` (aliased in/out, one aligned
+    sublane tile a grid step); the quantized append and the copy-on-write
+    copy through the page-flat view ``pool.reshape(L*H*NB, bs, D)``
+    (`kv_cache.page_rows`) — collapsing leading dims is a bitcast in the
+    tiled layout, so a scatter on it updates in place. A
+    multi-dimensional ``pool.at[li, :, blk, off].set`` lets the compiler
+    pick the scatter's operand layout and copy the whole pool to and
+    fro, inside the layer loop (PERF.md, PR 27).
+
+`tests/test_chip_compile.py` holds the compiled step to it: no
+pool-sized copy, slice or update, temp under 1 GiB.
 """
 
 from __future__ import annotations
@@ -36,8 +61,9 @@ from jax import lax
 
 from ..models import gpt as G
 from ..observability.trace import SCOPES
+from ..kernels.pallas.kv_append import append_tile, kv_append, tile_work
 from ..kernels.pallas.ragged_paged_attention import ragged_paged_attention
-from ..quantization.kv_cache import (append_tokens_quantized,
+from ..quantization.kv_cache import (append_tokens_quantized, page_rows,
                                      reset_page_scales)
 from .serving import _embed, _qkv, _block_math, _head_logits, _sample
 
@@ -51,7 +77,8 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
     sampling. tokens/row_of/off_of: [T] packed (off_of >= q_len marks
     padding); starts/pos0/q_lens/temps: [R]; tables: [R, nb]; pools:
     [L, H_kv, NB, bs, D] (+ [L, H_kv, NB] scales when quantized).
-    Returns (tok [R], (kp, vp[, ks, vs]) updated); with ``all_greedy``
+    Returns (tok [R], (kp, vp, ks, vs) updated — ks, vs None when the
+    pool is not quantized); with ``all_greedy``
     the head runs over EVERY packed position and the return gains a
     ``greedy_t [T]`` argmax vector between tok and the pools — the
     speculative-decoding verify signal (draft token i is accepted iff it
@@ -61,12 +88,12 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
     pos_t = jnp.minimum(pos0[row_of] + off_of, cfg.max_seq_len - 1)
     x = _embed(params, tokens[None], pos_t[None], cfg)       # [1, T, H]
     kv_lens = pos0 + q_lens
-    valid_t = off_of < q_lens[row_of]
-    # packed-token scatter targets (unquantized pools); invalid tokens
-    # land in the reserved scratch block 0, same as the two-program path
-    posb = jnp.clip(pos_t // bs, 0, tables.shape[1] - 1)
-    blk_t = jnp.where(valid_t, tables[row_of, posb], 0)
-    off_t = jnp.where(valid_t, pos_t % bs, 0)
+    # the (page, tile) list the in-place append walks (unquantized pools;
+    # quantized ones requantize whole pages, `append_tokens_quantized`)
+    if not quantized:
+        tile = append_tile(kp.dtype, bs)
+        work = tile_work(starts, pos0, q_lens, tables, bs=bs, tile=tile,
+                         c_att=c_att, T=T)
     # per-row attention tile gather (clamped duplicates are masked by the
     # kernel's c < q_len predicate)
     tile_idx = jnp.clip(
@@ -75,33 +102,28 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
         0, T - 1)                                            # [R, c_att]
     scale = 1.0 / (cfg.head_dim ** 0.5)
 
-    def body(x, layer):
-        if quantized:
-            p, kpl, vpl, ksl, vsl = layer
-        else:
-            (p, kpl, vpl), ksl, vsl = layer, None, None
+    def body(carry, layer):
+        x, kp, vp, ks, vs = carry
+        p, li = layer
         q, k, v = _qkv(p, x, cfg, mp_axis)                   # [1, T, h, D]
         with jax.named_scope(SCOPES.kv_write):
             if quantized:
-                kpl, ksl = append_tokens_quantized(
-                    kpl, ksl, k[0][tile_idx], pos0, q_lens, tables, bs)
-                vpl, vsl = append_tokens_quantized(
-                    vpl, vsl, v[0][tile_idx], pos0, q_lens, tables, bs)
+                kp, ks = append_tokens_quantized(
+                    kp, ks, k[0][tile_idx], pos0, q_lens, tables, bs, li)
+                vp, vs = append_tokens_quantized(
+                    vp, vs, v[0][tile_idx], pos0, q_lens, tables, bs, li)
             else:
-                kpl = kpl.at[:, blk_t, off_t].set(
-                    jnp.moveaxis(k[0], 1, 0).astype(kpl.dtype))  # [h,T,D]
-                vpl = vpl.at[:, blk_t, off_t].set(
-                    jnp.moveaxis(v[0], 1, 0).astype(vpl.dtype))
+                kp, vp = kv_append(kp, vp, k[0], v[0], li, work, tile=tile)
         with jax.named_scope(SCOPES.ragged_attn):
             attn_t = ragged_paged_attention(
-                q[0][tile_idx], kpl, vpl, tables, q_lens, kv_lens, scale,
-                ksl, vsl)                                    # [R,c_att,h,D]
+                q[0][tile_idx], kp, vp, tables, q_lens, kv_lens, scale,
+                ks, vs, li)                                  # [R,c_att,h,D]
             attn_p = attn_t[row_of, jnp.minimum(off_of, c_att - 1)]
         x = _block_math(p, x, attn_p[None], cfg, mp_axis)
-        return x, (kpl, vpl) + ((ksl, vsl) if quantized else ())
+        return (x, kp, vp, ks, vs), None
 
-    xs = (params["blocks"], kp, vp) + ((ks, vs) if quantized else ())
-    x, pools = lax.scan(body, x, xs)
+    xs = (params["blocks"], jnp.arange(kp.shape[0], dtype=jnp.int32))
+    (x, *pools), _ = lax.scan(body, (x, kp, vp, ks, vs), xs)
     with jax.named_scope(SCOPES.head):
         x = G._ln(x, params["lnf_g"], params["lnf_b"])
     last_idx = jnp.clip(starts + jnp.maximum(q_lens, 1) - 1, 0, T - 1)
@@ -159,24 +181,25 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
             vs = reset_page_scales(vs, rt, fresh)
     if cow_src is not None:
         with jax.named_scope(SCOPES.cow):
-            kp = kp.at[:, :, cow_dst].set(kp[:, :, cow_src])
-            vp = vp.at[:, :, cow_dst].set(vp[:, :, cow_src])
+            src = page_rows(kp.shape, None, cow_src).reshape(-1)
+            dst = page_rows(kp.shape, None, cow_dst).reshape(-1)
+
+            def copy_pages(a):  # pool [L,H,NB,bs,D] or scales [L,H,NB]
+                flat = a.reshape((-1,) + a.shape[3:])
+                return flat.at[dst].set(flat[src]).reshape(a.shape)
+
+            kp, vp = copy_pages(kp), copy_pages(vp)
             if quantized:
-                ks = ks.at[:, :, cow_dst].set(ks[:, :, cow_src])
-                vs = vs.at[:, :, cow_dst].set(vs[:, :, cow_src])
+                ks, vs = copy_pages(ks), copy_pages(vs)
     key, sub = jax.random.split(key)
     out = ragged_pass(params, tokens, row_of, off_of, starts,
                       pos0, q_lens, tables, temps, sub,
                       kp, vp, ks, vs, cfg=cfg, bs=bs,
                       c_att=c_att, mp_axis=mp_axis, all_greedy=spec)
     if spec:
-        tok0, greedy_all, pools = out
+        tok0, greedy_all, (kp, vp, ks, vs) = out
     else:
-        tok0, pools = out
-    if quantized:
-        kp, vp, ks, vs = pools
-    else:
-        kp, vp = pools
+        tok0, (kp, vp, ks, vs) = out
     tok0 = jnp.where(sample0, tok0, 0)
     lens = pos0 + q_lens
     rem = remaining - sample0.astype(remaining.dtype)
@@ -189,13 +212,9 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
         active = alive & (rem > 0)
         ql = active.astype(jnp.int32)
         key, sub = jax.random.split(key)
-        tok2, pools = ragged_pass(params, tok, ar, zero, ar, lens, ql,
-                                  tables, temps, sub, kp, vp, ks, vs,
-                                  cfg=cfg, bs=bs, c_att=1, mp_axis=mp_axis)
-        if quantized:
-            kp, vp, ks, vs = pools
-        else:
-            kp, vp = pools
+        tok2, (kp, vp, ks, vs) = ragged_pass(
+            params, tok, ar, zero, ar, lens, ql, tables, temps, sub,
+            kp, vp, ks, vs, cfg=cfg, bs=bs, c_att=1, mp_axis=mp_axis)
         tok2 = jnp.where(active, tok2, 0)
         lens = lens + ql
         rem = rem - ql

@@ -12,9 +12,18 @@ paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu).
 Design (extends paged_attention.py, which stays as the decode-only
 baseline the two-program engine path compiles):
 
-  * pools head-major ``[H_kv, num_blocks, bs, D]`` — one (head, block)
-    tile is a contiguous ``[bs, D]`` VMEM block; the K/V BlockSpec index
-    maps dereference ``tables[r, j]`` so only referenced blocks stream;
+  * the pool is the serving engine's WHOLE buffer, layer-major then
+    head-major: ``[L, H_kv, num_blocks, bs, D]`` (+ ``[L, H_kv,
+    num_blocks]`` scales), and the layer to attend over is a scalar that
+    rides scalar prefetch — the caller never slices a layer out of the
+    pool, so the compiled step holds no pool-shaped copy (the pool's
+    contract is stated once, in inference/ragged_step.py; the scales are
+    small, and the wrapper hands SMEM the one layer's). One (layer,
+    head, block) tile is a contiguous ``[bs, D]`` VMEM block; the K/V
+    BlockSpec index maps dereference ``layer[0]`` and ``tables[r, j]`` so
+    only referenced blocks stream. A 4-D ``[H_kv, num_blocks, bs, D]``
+    pool is the same call with ``L = 1``, ``layer = 0`` (decided from
+    ``k_pool.ndim``);
   * grid ``(R, H_kv, nb)``: rows × kv heads × table slots. Per-row
     ``kv_len`` clamps past-end steps to the last used block (Pallas skips
     the re-fetch when consecutive steps map to the same block) and the
@@ -58,10 +67,10 @@ _NEG_INF = -1e30
 
 def _ragged_kernel(*refs, scale, bs, nb, g, quantized, qmax):
     if quantized:
-        (tables_ref, qlens_ref, kvlens_ref, ks_ref, vs_ref,
+        (tables_ref, qlens_ref, kvlens_ref, layer_ref, ks_ref, vs_ref,
          q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc) = refs
     else:
-        (tables_ref, qlens_ref, kvlens_ref,
+        (tables_ref, qlens_ref, kvlens_ref, layer_ref,
          q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc) = refs
         ks_ref = vs_ref = None
     r = pl.program_id(0)
@@ -81,8 +90,8 @@ def _ragged_kernel(*refs, scale, bs, nb, g, quantized, qmax):
     @pl.when((j < used) & (ql > 0))
     def _compute():
         q = q_ref[0, 0]  # [CG, D] — (chunk, group) folded, c-major
-        k = k_ref[0, 0]  # [bs, D] (int8 when quantized)
-        v = v_ref[0, 0]
+        k = k_ref[0, 0, 0]  # [bs, D] (int8 when quantized)
+        v = v_ref[0, 0, 0]
         if quantized:
             page = tables_ref[r, j]
             k_deq = k.astype(jnp.float32) * (ks_ref[h, page] / qmax)
@@ -121,15 +130,25 @@ def _ragged_kernel(*refs, scale, bs, nb, g, quantized, qmax):
 
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_lens,
                            kv_lens, scale: float,
-                           k_scales=None, v_scales=None):
+                           k_scales=None, v_scales=None, layer=0):
     """q: [R, C, H_q, D] — row r's chunk occupies columns [0, q_lens[r]);
-    pools: [H_kv, num_blocks, bs, D] (float, or int8 with k_scales /
-    v_scales: [H_kv, num_blocks] f32 per-page absmax scales);
+    pools: [L, H_kv, num_blocks, bs, D] (float, or int8 with k_scales /
+    v_scales: [L, H_kv, num_blocks] f32 per-page absmax scales) and
+    ``layer`` the (traced) int32 index of the layer to attend over — or
+    one layer's [H_kv, num_blocks, bs, D] pool (+ [H_kv, num_blocks]
+    scales), which is the L = 1 form of the same call;
     block_tables: [R, nb] int32; q_lens: [R] int32 (0 = inactive row);
     kv_lens: [R] int32 — TOTAL kv length including this chunk (query c
     sits at absolute position kv_lens - q_lens + c) → [R, C, H_q, D]."""
     R, C, hq, D = q.shape
-    hkv, _, bs, _ = k_pool.shape
+    if k_pool.ndim == 4:  # one layer's pool: a free leading-1 reshape
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+    elif k_scales is not None:
+        # the layer's [H_kv, num_blocks] scales alone go to SMEM: all
+        # layers' (2 x 0.75 MB at 512 pages) do not fit its 1 MB
+        k_scales, v_scales = (jax.lax.dynamic_index_in_dim(
+            s, layer, keepdims=False) for s in (k_scales, v_scales))
+    _, hkv, _, bs, _ = k_pool.shape
     nb = block_tables.shape[1]
     g = hq // hkv
     quantized = k_scales is not None
@@ -153,10 +172,12 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_lens,
         # clamp past-end steps to the last used block: the index repeats,
         # so Pallas skips the re-fetch and the tail costs nothing
         used_last = jnp.maximum((kvlens[r] + bs - 1) // bs - 1, 0)
-        return (h, tables[r, jnp.minimum(j, used_last)], 0, 0)
+        return (prefetch[3][0], h, tables[r, jnp.minimum(j, used_last)],
+                0, 0)
 
     prefetch = [block_tables, q_lens.astype(jnp.int32),
-                kv_lens.astype(jnp.int32)]
+                kv_lens.astype(jnp.int32),
+                jnp.asarray(layer, jnp.int32).reshape(1)]
     if quantized:
         prefetch += [k_scales.astype(jnp.float32),
                      v_scales.astype(jnp.float32)]
@@ -165,8 +186,8 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_lens,
         grid=(R, hkv, nb),
         in_specs=[
             pl.BlockSpec((1, 1, CG8, D), q_idx),
-            pl.BlockSpec((1, 1, bs, D), kv_idx),
-            pl.BlockSpec((1, 1, bs, D), kv_idx),
+            pl.BlockSpec((1, 1, 1, bs, D), kv_idx),
+            pl.BlockSpec((1, 1, 1, bs, D), kv_idx),
         ],
         out_specs=pl.BlockSpec((1, 1, CG8, D), q_idx),
         scratch_shapes=[

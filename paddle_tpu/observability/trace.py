@@ -89,7 +89,7 @@ KERNELS = _names(
     "Kernels",
     flash_fwd="flash_fwd", flash_bwd_dkv="flash_bwd_dkv",
     flash_bwd_dq="flash_bwd_dq", ragged_paged_attn="ragged_paged_attn",
-    paged_attn="paged_attn", fused_adam="fused_adam",
+    kv_append="kv_append", paged_attn="paged_attn", fused_adam="fused_adam",
     layer_norm_fwd="layer_norm_fwd", layer_norm_bwd="layer_norm_bwd",
     rms_norm_fwd="rms_norm_fwd", rms_norm_bwd="rms_norm_bwd", rope="rope",
     rowwise="rowwise", row_reduce="row_reduce",

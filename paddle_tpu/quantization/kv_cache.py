@@ -7,6 +7,11 @@ ISSUE 6 targets. The machinery reuses the module-wide quantization
 convention (``__init__.quantize_to_int8``: scale = absmax, dequant =
 q·scale/127); scales live per (layer, kv_head, page) so one SMEM scalar
 dequantizes a whole ``[bs, D]`` page tile inside the ragged kernel.
+The pool is the engine's one ``[L, H_kv, NB, bs, D]`` buffer (scales
+``[L, H_kv, NB]``): donated, never sliced by layer, written here only
+through its page-flat views — the contract is stated in
+inference/ragged_step.py and `page_rows` is the one place that numbers
+the flat views' rows.
 
 Append semantics (deterministic, functional — runs INSIDE the serving
 program): pages accept tokens incrementally, so a page's scale is a
@@ -23,7 +28,7 @@ from __future__ import annotations
 
 import jax.numpy as jnp
 
-__all__ = ["kv_cache_dtype", "kv_pool_blocks_for_budget",
+__all__ = ["kv_cache_dtype", "kv_pool_blocks_for_budget", "page_rows",
            "append_tokens_quantized", "reset_page_scales",
            "KV_CACHE_DTYPES"]
 
@@ -67,27 +72,56 @@ def kv_pool_blocks_for_budget(budget_bytes: int, num_layers: int,
     return int(budget_bytes // per_block)
 
 
+def page_rows(shape, layer, blk):
+    """Row numbers of pages ``blk`` (any int shape) in the page-flat view
+    ``pool.reshape(L*H*NB, bs, D)`` / ``scales.reshape(L*H*NB)`` of a
+    ``shape = [L, H, NB, ...]`` pool, for every head of ``layer`` (a
+    traced scalar → [H, *blk.shape]) or of every layer (``layer=None`` →
+    [L*H, *blk.shape]). Page-wise pool writes go through these flat
+    views: a reshape of leading dims is a bitcast in the tiled layout,
+    and a scatter on it keeps the default layout the attention kernel
+    reads — a multi-dimensional ``pool.at[li, :, blk]`` lets the compiler
+    pick another one and copy the whole pool to and fro (PERF.md, PR 27)."""
+    L, H, NB = shape[:3]
+    if layer is None:
+        lh = jnp.arange(L * H, dtype=jnp.int32)
+    else:
+        lh = layer * H + jnp.arange(H, dtype=jnp.int32)
+    return lh.reshape((-1,) + (1,) * blk.ndim) * NB + blk[None]
+
+
 def reset_page_scales(scales, tables, fresh):
     """Zero the per-page scales of every block in a freshly-admitted
-    row's table, in-program (one scatter, no extra dispatch). scales:
-    [L, H, NB]; tables: [R, nb] int32; fresh: [R] bool — rows admitted
-    this step. Non-fresh rows route their scatter at block 0 (the
-    reserved scratch block), whose scale is meaningless by construction."""
+    row's table, in-program (no extra dispatch). scales: [L, H, NB];
+    tables: [R, nb] int32; fresh: [R] bool — rows admitted this step.
+    Non-fresh rows route to block 0 (the reserved scratch block), whose
+    scale is meaningless by construction. A [NB] hit mask and one
+    elementwise select: in place, in whatever layout the scales have."""
     idx = jnp.where(fresh[:, None], tables, 0).reshape(-1)
-    return scales.at[:, :, idx].set(0.0)
+    hit = jnp.zeros((scales.shape[-1],), jnp.bool_).at[idx].set(True)
+    return jnp.where(hit, 0.0, scales)
 
 
-def append_tokens_quantized(pool, scales, val, pos0, q_lens, tables, bs):
+def append_tokens_quantized(pool, scales, val, pos0, q_lens, tables, bs,
+                            layer=0):
     """Quantize-on-append into the paged pool with per-(head, page)
     running-absmax scales.
 
-    pool: [H, NB, bs, D] int8/fp8; scales: [H, NB] f32; val: [R, C, H, D]
+    pool: [L, H, NB, bs, D] int8/fp8 — the WHOLE pool, of which only
+    ``layer``'s (a traced int32 scalar) pages are touched — with scales
+    [L, H, NB] f32; or one layer's [H, NB, bs, D] pool + [H, NB] scales
+    (the L = 1 form, decided from ``pool.ndim``). val: [R, C, H, D]
     float chunk tiles (row r's tokens occupy columns [0, q_lens[r]) and
     land at positions pos0[r]..pos0[r]+q_lens[r]-1); tables: [R, nb].
-    Returns (pool', scales'). Rows with q_len = 0 are exact no-ops on
-    their own pages (ratio-1 requantize); idle rows' writes land in the
-    scratch block 0 like the unquantized scatter path.
+    Returns (pool', scales') in the shapes given. Pages are read and
+    written through the page-flat views (`page_rows`), so the update is
+    in place and in the kernel's layout. Rows with q_len = 0 are exact
+    no-ops on their own pages (ratio-1 requantize); idle rows' writes
+    land in the reserved scratch block 0.
     """
+    in_shape = pool_shape = pool.shape
+    if pool.ndim == 4:
+        pool_shape, layer = (1,) + in_shape, 0
     R, C, H, D = val.shape
     nb = tables.shape[1]
     qmax = _qmax(pool.dtype)
@@ -114,13 +148,16 @@ def append_tokens_quantized(pool, scales, val, pos0, q_lens, tables, bs):
     av = jnp.where(valid[..., None, None],
                    jnp.abs(vals_sel.astype(jnp.float32)), 0.0)
     vmax = av.max(axis=(2, 4))                                 # [R, PT, H]
+    rows = page_rows(pool_shape, layer, blk)                   # [H, R, PT]
+    flat_s = scales.reshape(-1)
+    pool = pool.reshape((-1,) + pool_shape[3:])                # [LHNB,bs,D]
     # grow the touched pages' scales (scatter-max: associative, so pages
     # hit by several tokens — or several idle rows at scratch — are safe)
-    new_scales = scales.at[:, blk].max(jnp.moveaxis(vmax, 2, 0))
-    s_new = new_scales[:, blk]                                 # [H, R, PT]
-    s_old = scales[:, blk]
+    new_scales = flat_s.at[rows].max(jnp.moveaxis(vmax, 2, 0))
+    s_new = new_scales[rows]                                   # [H, R, PT]
+    s_old = flat_s[rows]
     ratio = jnp.where(s_new > 0, s_old / jnp.maximum(s_new, _EPS), 1.0)
-    pages = pool[:, blk]                                       # [H,R,PT,bs,D]
+    pages = pool[rows]                                         # [H,R,PT,bs,D]
     is_int = pool.dtype == jnp.dtype(jnp.int8)
     requant = pages.astype(jnp.float32) * ratio[..., None, None]
     vt = jnp.moveaxis(vals_sel, 3, 0).astype(jnp.float32)      # [H,R,PT,bs,D]
@@ -130,5 +167,5 @@ def append_tokens_quantized(pool, scales, val, pos0, q_lens, tables, bs):
         q_new = jnp.round(q_new)
     q_new = jnp.clip(q_new, -qmax, qmax)
     merged = jnp.where(valid[None, :, :, :, None], q_new, requant)
-    pool = pool.at[:, blk].set(merged.astype(pool.dtype))
-    return pool, new_scales
+    pool = pool.at[rows].set(merged.astype(pool.dtype))
+    return pool.reshape(in_shape), new_scales.reshape(scales.shape)

@@ -21,7 +21,10 @@ Rules this file follows (on-chip-measurement guide, section 2):
 A compile that passes is not a chip run and is never reported as one.
 """
 
+import functools
 import importlib
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,7 +33,7 @@ from jax.sharding import SingleDeviceSharding
 
 _KERNEL_MODULES = ("flash_attention", "layer_norm", "rms_norm", "rope",
                    "primitives", "fused_adam", "paged_attention",
-                   "ragged_paged_attention")
+                   "ragged_paged_attention", "kv_append")
 
 # the serving smoke's pool geometry (chip_smoke.py): GPT-1.3B heads,
 # 128-token pages
@@ -174,3 +177,96 @@ def test_paged_decode_attention(one_chip, compiled_kernels):
                                       HEAD_DIM ** -0.5)
 
     _compile(fn, q, pool, pool, tables, lens)
+
+
+# ---------------------------------------------------------------------------
+# the serving step at the benchmark cells' geometry: the KV pool stays ONE
+# buffer, written in place (inference/ragged_step.py states the contract)
+# ---------------------------------------------------------------------------
+LAYERS, ROWS, TOKENS, TABLE, C_ATT = 24, 64, 192, 16, 128
+_HLO_RESULT = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = \w+\[([\d,]*)\]\S* ([\w\-]+)\(")
+# what moved a layer's page set or the whole pool in the parent's step
+_POOL_MOVERS = {"copy", "copy-start", "dynamic-slice", "dynamic-update-slice"}
+
+
+def _pool_copies(text, pages):
+    """Copies, slices, updates and loop fusions of the compiled text whose
+    result is a whole number of layers' page sets ([H, pages, PAGE, D]: the
+    smallest pool-shaped copy the parent made), up to a pool. Views
+    (`bitcast`), the while/tuple plumbing, the in-place scatter and the
+    copy-on-write page gather (`kCustom` fusions) may carry that size."""
+    layer_set = HEADS * pages * PAGE * HEAD_DIM
+    found = []
+    for line in text.splitlines():
+        m = _HLO_RESULT.match(line)
+        if not m or not m.group(1):
+            continue
+        n = math.prod(int(d) for d in m.group(1).split(","))
+        op = m.group(2)
+        if (n % layer_set == 0 and n <= LAYERS * layer_set
+                and (op in _POOL_MOVERS
+                     or op == "fusion" and "kind=kCustom" not in line)):
+            found.append(line.strip()[:160])
+    return found
+
+
+def _compile_unified_step(one_chip, K, kv_dtype, pages, share=False):
+    from paddle_tpu.inference import ragged_step as RS
+    from paddle_tpu.models import gpt as G
+    cfg = G.GPTConfig(vocab_size=50304, hidden_size=HEADS * HEAD_DIM,
+                      num_layers=LAYERS, num_heads=HEADS, ffn_hidden=8192,
+                      max_seq_len=2048, dtype=jnp.bfloat16,
+                      param_dtype=jnp.bfloat16)
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: G.init_hybrid_params(
+            cfg, jax.random.PRNGKey(0))))
+
+    def i32(*shape):
+        return _sds(one_chip, shape, jnp.int32)
+
+    def flags():
+        return _sds(one_chip, (ROWS,), jnp.bool_)
+
+    quant = kv_dtype == "int8"
+    pool = _sds(one_chip, (LAYERS, HEADS, pages, PAGE, HEAD_DIM),
+                jnp.int8 if quant else jnp.bfloat16)
+    scales = (_sds(one_chip, (LAYERS, HEADS, pages), jnp.float32)
+              if quant else None)
+    args = [params, i32(TOKENS), i32(TOKENS), i32(TOKENS), i32(ROWS),
+            i32(ROWS), i32(ROWS), i32(ROWS, TABLE), flags(), flags(),
+            i32(ROWS), i32(ROWS), _sds(one_chip, (ROWS,), jnp.float32),
+            _sds(one_chip, (2,), jnp.uint32), pool, pool, scales, scales]
+    if share:   # cow_src, cow_dst, reset_tables
+        args += [i32(ROWS), i32(ROWS), i32(ROWS, TABLE)]
+    step = functools.partial(RS.unified_step, cfg=cfg, bs=PAGE,
+                             c_att=C_ATT, K=K)
+    return jax.jit(step, donate_argnums=(14, 15, 16, 17) if quant
+                   else (14, 15)).lower(*args).compile()
+
+
+@pytest.mark.parametrize("K,kv_dtype,pages,share", [
+    (1, "bf16", 256, False), (8, "bf16", 256, False),
+    (1, "int8", 256, False), (8, "int8", 256, False),
+    (1, "bf16", 256, True), (8, "bf16", 320, False),
+    (1, "int8", 512, False),
+], ids=["k1-bf16", "k8-bf16", "k1-int8", "k8-int8", "k1-bf16-cow",
+        "k8-bf16-320pages", "k1-int8-512pages"])
+def test_unified_step_keeps_pool_in_place(one_chip, compiled_kernels, K,
+                                          kv_dtype, pages, share):
+    """The guard against the pool copies coming back (PERF.md, PR 27):
+    the parent's step at this geometry held ten pool-shaped copies,
+    slices and updates, 7.0 GiB of temp at K = 1 and 7.6 GiB at K = 8,
+    and did not compile at 320 pages. An int8 pool of the same bytes
+    (512 pages) has to fit too: its scales go to SMEM a layer at a time."""
+    compiled = _compile_unified_step(one_chip, K, kv_dtype, pages, share)
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "kernel was not lowered for the chip"
+    assert _pool_copies(text, pages) == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
+    # both pools (and scales) alias their arguments: donated in, out in place
+    item = 1 if kv_dtype == "int8" else 2
+    pool_bytes = LAYERS * HEADS * pages * PAGE * HEAD_DIM * item
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes

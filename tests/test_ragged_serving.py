@@ -202,9 +202,160 @@ def test_quantized_append_into_last_table_page():
     assert err < 0.05, err                      # int8 grid error only
 
 
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+def test_ragged_kernel_whole_pool_layer_index(kv_dtype):
+    """The engine's form of the call: the whole [L, H, NB, bs, D] pool and
+    a traced layer index give bit for bit what the 4-D call gives on that
+    layer's pool — and the quantized append on the whole pool touches
+    that layer's pages alone."""
+    from paddle_tpu.kernels.pallas.ragged_paged_attention import (
+        ragged_paged_attention)
+    from paddle_tpu.quantization.kv_cache import append_tokens_quantized
+    rng = np.random.RandomState(11)
+    L, hkv, NB, bs, D, R, C, nb = 3, 2, 10, 8, 16, 3, 8, 4
+    tables = np.zeros((R, nb), np.int32)
+    tables[0, :2], tables[1, :2], tables[2, :1] = [1, 2], [3, 4], [5]
+    tables = jnp.asarray(tables)
+    q_lens = jnp.asarray(np.array([8, 5, 0], np.int32))
+    kv_lens = jnp.asarray(np.array([16, 5, 0], np.int32))
+    q = jnp.asarray(rng.randn(R, C, hkv, D).astype(np.float32))
+    scale = 1.0 / np.sqrt(D)
+    if kv_dtype == "int8":
+        kp = jnp.asarray(rng.randint(-127, 128, (L, hkv, NB, bs, D)),
+                         jnp.int8)
+        vp = jnp.asarray(rng.randint(-127, 128, (L, hkv, NB, bs, D)),
+                         jnp.int8)
+        ks = jnp.asarray(rng.rand(L, hkv, NB).astype(np.float32) + 0.5)
+        vs = jnp.asarray(rng.rand(L, hkv, NB).astype(np.float32) + 0.5)
+        val = jnp.asarray(rng.randn(R, C, hkv, D).astype(np.float32))
+        pos0 = kv_lens - q_lens
+        kp5, ks5 = jax.jit(append_tokens_quantized, static_argnums=6)(
+            kp, ks, val, pos0, q_lens, tables, bs, jnp.int32(1))
+        kp4, ks4 = append_tokens_quantized(kp[1], ks[1], val, pos0, q_lens,
+                                           tables, bs)
+        np.testing.assert_array_equal(kp5[1], kp4)
+        np.testing.assert_array_equal(ks5[1], ks4)
+        for other in (0, 2):    # the append strays into no other layer
+            np.testing.assert_array_equal(kp5[other], kp[other])
+            np.testing.assert_array_equal(ks5[other], ks[other])
+        kp, ks = kp5, ks5
+    else:
+        kp = jnp.asarray(rng.randn(L, hkv, NB, bs, D)).astype(jnp.bfloat16)
+        vp = jnp.asarray(rng.randn(L, hkv, NB, bs, D)).astype(jnp.bfloat16)
+        q = q.astype(jnp.bfloat16)
+        ks = vs = None
+
+    @jax.jit
+    def whole(layer):
+        return ragged_paged_attention(q, kp, vp, tables, q_lens, kv_lens,
+                                      scale, ks, vs, layer)
+
+    for layer in (1, 2):
+        one = ragged_paged_attention(
+            q, kp[layer], vp[layer], tables, q_lens, kv_lens, scale,
+            None if ks is None else ks[layer],
+            None if vs is None else vs[layer])
+        np.testing.assert_array_equal(
+            np.asarray(whole(jnp.int32(layer)), np.float32),
+            np.asarray(one, np.float32))
+    assert not np.array_equal(np.asarray(whole(jnp.int32(1)), np.float32),
+                              np.asarray(whole(jnp.int32(2)), np.float32))
+
+
+@pytest.mark.parametrize("dtype,bs", [("float32", 8), ("float32", 16),
+                                      ("bfloat16", 16), ("bfloat16", 32)])
+def test_kv_append_writes_the_rows_and_nothing_else(dtype, bs):
+    """The in-place append (kernels/pallas/kv_append.py) against a plain
+    loop: decode rows, a prefill chunk crossing tiles and a page, an
+    empty row, a decode row on a page's last position — bit for bit,
+    every other element of every layer untouched."""
+    from paddle_tpu.kernels.pallas.kv_append import (append_tile, kv_append,
+                                                     tile_work)
+    dtype = jnp.dtype(dtype)
+    L, H, NB, D, R, nb, c_att, T = 3, 2, 24, 16, 5, 4, 12, 24
+    tile = append_tile(dtype, bs)
+    tables = np.arange(1, R * nb + 1, dtype=np.int32).reshape(R, nb)
+    q_lens = np.array([1, 12, 0, 7, 1], np.int32)
+    starts = np.concatenate([[0], np.cumsum(q_lens)[:-1]]).astype(np.int32)
+    append = jax.jit(
+        lambda kp, vp, k, v, layer, starts, pos0, q_lens: kv_append(
+            kp, vp, k, v, layer, tile_work(
+                starts, pos0, q_lens, jnp.asarray(tables), bs=bs, tile=tile,
+                c_att=c_att, T=T), tile=tile))
+    for seed in range(3):
+        rng = np.random.RandomState(seed)
+        pos0 = np.array([rng.randint(0, 3 * bs), rng.randint(0, 2 * bs), 0,
+                         rng.randint(0, bs), bs - 1], np.int32)
+        kp, vp = (jnp.asarray(rng.randn(L, H, NB, bs, D)).astype(dtype)
+                  for _ in range(2))
+        k, v = (jnp.asarray(rng.randn(T, H, D)).astype(dtype)
+                for _ in range(2))
+        want = [np.asarray(a, np.float32).copy() for a in (kp, vp)]
+        for r in range(R):
+            for c in range(q_lens[r]):
+                page, off = tables[r, (pos0[r] + c) // bs], (pos0[r] + c) % bs
+                for pool, val in zip(want, (k, v)):
+                    pool[1, :, page, off] = np.asarray(
+                        val, np.float32)[starts[r] + c]
+        got = append(kp, vp, k, v, jnp.int32(1), jnp.asarray(starts),
+                     jnp.asarray(pos0), jnp.asarray(q_lens))
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g, np.float32), w)
+    # a pass with no row at all leaves the pools as they were
+    got = append(kp, vp, k, v, jnp.int32(1), jnp.asarray(starts),
+                 jnp.asarray(pos0), jnp.zeros((R,), jnp.int32))
+    np.testing.assert_array_equal(np.asarray(got[0], np.float32),
+                                  np.asarray(kp, np.float32))
+
+
 # ---------------------------------------------------------------------------
 # engine: single-dispatch contract + flags-off bitwise baseline
 # ---------------------------------------------------------------------------
+@pytest.mark.parametrize("kv_cache_dtype", ["f32", "int8"])
+def test_step_writes_only_its_own_pages(params, kv_cache_dtype):
+    """The pool is written in place through its flat views
+    (ragged_step.py): one step may change, in every layer, the pages of
+    the rows it ran (up to the tokens they hold now) and the scratch
+    block 0 — every other page of every layer is bit-identical
+    afterwards. A row number that strays shows here."""
+    rng = np.random.RandomState(12)
+    eng = mk(params, ragged=True, kv_cache_dtype=kv_cache_dtype)
+    shape = eng.k_pools.shape                   # [L, H, NB, bs, D]
+    if kv_cache_dtype == "int8":
+        noise = [rng.randint(-127, 128, shape).astype(np.int8)
+                 for _ in range(2)]
+    else:
+        noise = [rng.randn(*shape).astype(np.float32) for _ in range(2)]
+    eng.k_pools = jnp.asarray(noise[0], eng.k_pools.dtype)
+    eng.v_pools = jnp.asarray(noise[1], eng.v_pools.dtype)
+    before = [np.asarray(eng.k_pools).copy(), np.asarray(eng.v_pools).copy()]
+    eng.add_request(rng.randint(0, CFG.vocab_size, (11,)), 4)
+    eng.add_request(rng.randint(0, CFG.vocab_size, (5,)), 4)
+    eng.step()
+    mine = {0}
+    written = {}                                # page -> tokens it holds
+    for slot, req in enumerate(eng.slots):
+        if req is None:
+            continue
+        n = int(eng.lens[slot])
+        for j in range(-(-n // eng.bs)):
+            page = int(eng.tables[slot, j])
+            mine.add(page)
+            written[page] = min(eng.bs, n - j * eng.bs)
+    assert written, "the step ran no row"
+    others = [b for b in range(shape[2]) if b not in mine]
+    for was, pool in zip(before, (eng.k_pools, eng.v_pools)):
+        now = np.asarray(pool)
+        np.testing.assert_array_equal(now[:, :, others], was[:, :, others])
+        for page, n in written.items():
+            for layer in range(shape[0]):       # every layer wrote its own
+                assert not np.array_equal(now[layer, :, page, :n],
+                                          was[layer, :, page, :n])
+            if kv_cache_dtype != "int8":        # int8 requantizes the page
+                np.testing.assert_array_equal(now[:, :, page, n:],
+                                              was[:, :, page, n:])
+
+
 def test_one_dispatch_per_step_and_program_cache(params):
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, CFG.vocab_size, (n,)) for n in (5, 13, 9, 16)]
