@@ -49,8 +49,13 @@ result:
     pick the scatter's operand layout and copy the whole pool to and
     fro, inside the layer loop (PERF.md, PR 27).
 
+A recurrent model's per-slot state (`unified_step`'s ``ssm_state`` and
+``conv_tail``; `kernels.pallas.ssm`) keeps the same contract: one donated
+buffer each, on both scans' carry, the layer by scalar prefetch, written
+in place by the mixer's kernels.
+
 `tests/test_chip_compile.py` holds the compiled step to it: no
-pool-sized copy, slice or update, temp under 1 GiB.
+pool-sized (or state-sized) copy, slice or update, temp under 1 GiB.
 """
 
 from __future__ import annotations
@@ -59,34 +64,37 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..models import gpt as G
 from ..observability.trace import SCOPES
 from ..kernels.pallas.kv_append import append_tile, kv_append, tile_work
 from ..kernels.pallas.ragged_paged_attention import ragged_paged_attention
 from ..quantization.kv_cache import (append_tokens_quantized, page_rows,
                                      reset_page_scales)
-from .serving import _embed, _qkv, _block_math, _head_logits, _sample
+from .serving import _sample, serving_model
 
 __all__ = ["ragged_pass", "unified_step"]
 
 
 def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
-                tables, temps, key, kp, vp, ks, vs, *, cfg, bs, c_att,
-                mp_axis=None, all_greedy=False):
+                tables, temps, key, kp, vp, ks, vs, ssm=None, *, cfg, bs,
+                c_att, mp_axis=None, all_greedy=False):
     """One transformer forward over the packed ragged batch + per-row
     sampling. tokens/row_of/off_of: [T] packed (off_of >= q_len marks
     padding); starts/pos0/q_lens/temps: [R]; tables: [R, nb]; pools:
     [L, H_kv, NB, bs, D] (+ [L, H_kv, NB] scales when quantized).
-    Returns (tok [R], (kp, vp, ks, vs) updated — ks, vs None when the
-    pool is not quantized); with ``all_greedy``
+    ssm: a recurrent model's (state, tail) or None; it rides the layer
+    scan's carry beside the pools and is the last entry of the returned
+    pools tuple.
+    Returns (tok [R], (kp, vp, ks, vs, ssm) updated — ks, vs None when
+    the pool is not quantized); with ``all_greedy``
     the head runs over EVERY packed position and the return gains a
     ``greedy_t [T]`` argmax vector between tok and the pools — the
     speculative-decoding verify signal (draft token i is accepted iff it
     equals the model's own argmax one position earlier)."""
     T = tokens.shape[0]
     quantized = ks is not None
-    pos_t = jnp.minimum(pos0[row_of] + off_of, cfg.max_seq_len - 1)
-    x = _embed(params, tokens[None], pos_t[None], cfg)       # [1, T, H]
+    model = serving_model(cfg)
+    pos_t = model.positions(pos0[row_of] + off_of, cfg)
+    x = model.embed(params, tokens[None], pos_t[None], cfg)  # [1, T, H]
     kv_lens = pos0 + q_lens
     # the (page, tile) list the in-place append walks (unquantized pools;
     # quantized ones requantize whole pages, `append_tokens_quantized`)
@@ -101,11 +109,19 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
                                       jnp.maximum(q_lens - 1, 0)[:, None]),
         0, T - 1)                                            # [R, c_att]
     scale = 1.0 / (cfg.head_dim ** 0.5)
+    if ssm is not None:
+        # a row that starts at position 0 starts from a zero state
+        plan = {"row_of": row_of, "off_of": off_of, "starts": starts,
+                "q_lens": q_lens, "tile_idx": tile_idx,
+                "reset": (pos0 == 0) & (q_lens > 0)}
 
     def body(carry, layer):
-        x, kp, vp, ks, vs = carry
+        x, kp, vp, ks, vs, ssm = carry
         p, li = layer
-        q, k, v = _qkv(p, x, cfg, mp_axis)                   # [1, T, h, D]
+        q, k, v, u = model.qkv(p, x, pos_t[None], cfg, mp_axis)  # [1,T,h,D]
+        mixed = None
+        if ssm is not None:
+            mixed, ssm = model.mixer(p, u, ssm, li, plan, cfg)
         with jax.named_scope(SCOPES.kv_write):
             if quantized:
                 kp, ks = append_tokens_quantized(
@@ -119,23 +135,23 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
                 q[0][tile_idx], kp, vp, tables, q_lens, kv_lens, scale,
                 ks, vs, li)                                  # [R,c_att,h,D]
             attn_p = attn_t[row_of, jnp.minimum(off_of, c_att - 1)]
-        x = _block_math(p, x, attn_p[None], cfg, mp_axis)
-        return (x, kp, vp, ks, vs), None
+        x = model.block_math(p, x, attn_p[None], mixed, cfg, mp_axis)
+        return (x, kp, vp, ks, vs, ssm), None
 
     xs = (params["blocks"], jnp.arange(kp.shape[0], dtype=jnp.int32))
-    (x, *pools), _ = lax.scan(body, (x, kp, vp, ks, vs), xs)
+    (x, *pools), _ = lax.scan(body, (x, kp, vp, ks, vs, ssm), xs)
     with jax.named_scope(SCOPES.head):
-        x = G._ln(x, params["lnf_g"], params["lnf_b"])
+        x = model.final_norm(params, x, cfg)
     last_idx = jnp.clip(starts + jnp.maximum(q_lens, 1) - 1, 0, T - 1)
     if all_greedy:
         # spec verify: the head GEMM widens from [R, V] to [T, V] so the
         # model's argmax is known at every draft position in ONE pass
-        logits_all = _head_logits(params, x[0], cfg, mp_axis)    # [T, V]
+        logits_all = model.head_logits(params, x[0], cfg, mp_axis)  # [T, V]
         with jax.named_scope(SCOPES.sample):
             greedy_t = jnp.argmax(logits_all, axis=-1).astype(jnp.int32)
         logits = logits_all[last_idx]                            # [R, V]
     else:
-        logits = _head_logits(params, x[0][last_idx], cfg, mp_axis)
+        logits = model.head_logits(params, x[0][last_idx], cfg, mp_axis)
     tok = _sample(logits, temps, key)
     if all_greedy:
         return tok, greedy_t, pools
@@ -145,8 +161,8 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
 def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
                  tables, fresh, sample0, remaining, eos_ids, temps, key,
                  kp, vp, ks, vs, cow_src=None, cow_dst=None,
-                 reset_tables=None, *, cfg, bs, c_att, K, spec=False,
-                 mp_axis=None):
+                 reset_tables=None, ssm_state=None, conv_tail=None, *, cfg,
+                 bs, c_att, K, spec=False, mp_axis=None):
     """ONE compiled program per engine step: the ragged pass (prefill
     chunks + first decode token for every row) followed by K-1 decode
     micro-steps for every sampling row. fresh: [R] bool — slots admitted
@@ -166,6 +182,13 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
     onto cached pages never wipes the canonical pages' quantization
     scales. Scale order matters: reset first, COW copy after, so a COW
     destination inherits its source page's running absmax.
+
+    A model with a recurrent mixer (``serving_model(cfg).recurrent``)
+    appends two more: ssm_state [L, R, heads, P, N] and conv_tail
+    [L, K-1, R, channels], its per-SLOT state (row r is slot r). They
+    keep the pool's contract, stated above: donated, on the scans' carry,
+    written in place by the mixer's kernels; a row whose pass starts at
+    position 0 starts from zeros. Both come back after ``lens``.
 
     Returns (toks [K, R], kp, vp, ks, vs, lens [R]); with ``spec=True``
     (K must be 1) the return gains ``greedy_all [T]`` after toks — the
@@ -191,15 +214,16 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
             kp, vp = copy_pages(kp), copy_pages(vp)
             if quantized:
                 ks, vs = copy_pages(ks), copy_pages(vs)
+    ssm = None if ssm_state is None else (ssm_state, conv_tail)
     key, sub = jax.random.split(key)
     out = ragged_pass(params, tokens, row_of, off_of, starts,
                       pos0, q_lens, tables, temps, sub,
-                      kp, vp, ks, vs, cfg=cfg, bs=bs,
+                      kp, vp, ks, vs, ssm, cfg=cfg, bs=bs,
                       c_att=c_att, mp_axis=mp_axis, all_greedy=spec)
     if spec:
-        tok0, greedy_all, (kp, vp, ks, vs) = out
+        tok0, greedy_all, (kp, vp, ks, vs, ssm) = out
     else:
-        tok0, (kp, vp, ks, vs) = out
+        tok0, (kp, vp, ks, vs, ssm) = out
     tok0 = jnp.where(sample0, tok0, 0)
     lens = pos0 + q_lens
     rem = remaining - sample0.astype(remaining.dtype)
@@ -208,27 +232,29 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
     zero = jnp.zeros((R,), jnp.int32)
 
     def micro(carry, _):
-        tok, kp, vp, ks, vs, lens, rem, alive, key = carry
+        tok, kp, vp, ks, vs, ssm, lens, rem, alive, key = carry
         active = alive & (rem > 0)
         ql = active.astype(jnp.int32)
         key, sub = jax.random.split(key)
-        tok2, (kp, vp, ks, vs) = ragged_pass(
+        tok2, (kp, vp, ks, vs, ssm) = ragged_pass(
             params, tok, ar, zero, ar, lens, ql, tables, temps, sub,
-            kp, vp, ks, vs, cfg=cfg, bs=bs, c_att=1, mp_axis=mp_axis)
+            kp, vp, ks, vs, ssm, cfg=cfg, bs=bs, c_att=1, mp_axis=mp_axis)
         tok2 = jnp.where(active, tok2, 0)
         lens = lens + ql
         rem = rem - ql
         alive = alive & ~(active & (tok2 == eos_ids))
-        return (tok2, kp, vp, ks, vs, lens, rem, alive, key), tok2
+        return (tok2, kp, vp, ks, vs, ssm, lens, rem, alive, key), tok2
 
     if K > 1:
-        carry = (tok0, kp, vp, ks, vs, lens, rem, alive, key)
+        carry = (tok0, kp, vp, ks, vs, ssm, lens, rem, alive, key)
         with jax.named_scope(SCOPES.burst):
-            (_, kp, vp, ks, vs, lens, _, _, _), toks = lax.scan(
+            (_, kp, vp, ks, vs, ssm, lens, _, _, _), toks = lax.scan(
                 micro, carry, jnp.arange(K - 1))
         all_toks = jnp.concatenate([tok0[None], toks], axis=0)
     else:
         all_toks = tok0[None]
     if spec:
         return all_toks, greedy_all, kp, vp, ks, vs, lens
+    if ssm is not None:
+        return (all_toks, kp, vp, ks, vs, lens) + tuple(ssm)
     return all_toks, kp, vp, ks, vs, lens

@@ -42,6 +42,18 @@ TPU shape discipline, two engine modes:
 All cache state is functional jax arrays threaded through the programs;
 sampling happens in-program on both paths.
 
+The model behind the ragged path is a seam (`serving_model`, ISSUE 28):
+the GPT block's answers are `GPTServing`; a configuration of another
+architecture brings its own (``cfg.serving_model``, today
+`models.falcon_h1`: GQA attention with RoPE beside a Mamba-2 mixer in
+every block). A model with a recurrent mixer gets a SECOND kind of
+per-request device state beside the KV pages: one recurrent state and
+one conv tail a SLOT, zeroed in-program when a row starts at position 0,
+released with the slot, rebuilt by re-prefill after a preemption. What
+the engine cannot give such a model yet (the two-program path, a mesh,
+int8 weights, prefix sharing, speculative decoding) raises at
+construction.
+
 Resilience layer (ISSUE 13) — all host-side scheduler state, no compiled
 program changes (flags-off the step behavior is byte-identical and the
 programs lower to the same HLO):
@@ -89,7 +101,7 @@ from jax import lax
 
 from ..models import gpt as G
 from ..observability.trace import (SCOPES, SERVING_SPANS,
-                                   TWO_PROGRAM_SPANS)
+                                   SSM_DISPATCH_ATTRS, TWO_PROGRAM_SPANS)
 from ..profiler.utils import RecordEvent
 
 __all__ = ["Request", "ServingEngine", "RunResult", "NonFiniteSampleError",
@@ -175,6 +187,8 @@ class _PackedStep:
     starts: np.ndarray
     pos0: np.ndarray
     arrays: tuple        # the host arrays, in the program's order
+    ssm_attrs: dict = dataclasses.field(default_factory=dict)
+    #                      a recurrent model's dispatch attributes
 
 
 class RunResult(dict):
@@ -293,6 +307,15 @@ def _head_logits(params, x_last, cfg, mp_axis=None):
     return logits
 
 
+@jax.jit
+def _split_key(key):
+    """`key, sub = jax.random.split(key)` as one compiled call: the same
+    two keys, without the eager split's unpacking slices (0.4 ms a step
+    on the chip's host)."""
+    both = jax.random.split(key)
+    return both[0], both[1]
+
+
 @jax.named_scope(SCOPES.sample)
 def _sample(logits, temps, key):
     """Per-row next token: argmax where temps == 0, else a categorical
@@ -301,6 +324,43 @@ def _sample(logits, temps, key):
     scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
     sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
     return jnp.where(temps > 0, sampled, greedy)
+
+
+class GPTServing:
+    """The GPT block's side of the seam between the serving step
+    (`ragged_step.ragged_pass`) and a model: positions, embedding, the
+    per-layer mixing (queries, and K/V for the paged pool), the post-mix
+    half and the head. A configuration of another architecture names its
+    own answers as ``cfg.serving_model`` (`models.falcon_h1.Serving`);
+    one with ``recurrent = True`` also has a `mixer` over the packed rows
+    and a per-slot state that the engine keeps beside the KV pages."""
+
+    recurrent = False
+
+    @staticmethod
+    def positions(pos, cfg):
+        return jnp.minimum(pos, cfg.max_seq_len - 1)   # inside the table
+
+    embed = staticmethod(_embed)
+
+    @staticmethod
+    def qkv(p, x, pos, cfg, mp_axis=None):
+        return _qkv(p, x, cfg, mp_axis) + (None,)
+
+    @staticmethod
+    def block_math(p, x, attn, mixed, cfg, mp_axis=None):
+        return _block_math(p, x, attn, cfg, mp_axis)
+
+    @staticmethod
+    def final_norm(params, x, cfg):
+        return G._ln(x, params["lnf_g"], params["lnf_b"])
+
+    head_logits = staticmethod(_head_logits)
+
+
+def serving_model(cfg):
+    """The seam's functions for a configuration."""
+    return getattr(cfg, "serving_model", GPTServing)
 
 
 @jax.named_scope(SCOPES.kv_write)
@@ -485,7 +545,8 @@ class ServingEngine:
                  ttft_slo_s: Optional[float] = None, queue_max=None,
                  shed=None, shed_headroom: float = 0.5, preempt=None,
                  preempt_wait_steps: int = 2, prefix_share=None,
-                 spec_decode_k=None, proposer=None, pool_audit=None):
+                 spec_decode_k=None, proposer=None, pool_audit=None,
+                 ssm_state_dtype="float32"):
         from ..flags import flag
         from ..enforce import enforce
         block_size = (int(flag("paged_block_size")) if block_size is None
@@ -512,7 +573,38 @@ class ServingEngine:
                 "path (ragged=True / FLAGS_serving_ragged) — the "
                 "two-program baseline kernels read float pools",
                 op="ServingEngine")
-        L, Hkv, D = cfg.num_layers, cfg.num_heads, cfg.head_dim
+        L, D = cfg.num_layers, cfg.head_dim
+        Hkv = getattr(cfg, "num_kv_heads", cfg.num_heads)
+        self.model = serving_model(cfg)
+        if prefix_share is None or prefix_share == "auto":
+            prefix_share = bool(flag("serving_prefix_share"))
+        self.prefix_share = bool(prefix_share)
+        if spec_decode_k is None or spec_decode_k == "auto":
+            spec_decode_k = int(flag("serving_spec_decode_k"))
+        self.spec_k = max(int(spec_decode_k), 0)
+        # -- a model with a recurrent mixer: its state and conv tail live
+        # beside the KV pages, one entry a SLOT (not a page), zeroed
+        # in-program when a row starts at position 0 and rebuilt by
+        # re-prefill after a preemption. What the engine cannot give such
+        # a model yet is refused here, not served some other way.
+        if self.model.recurrent:
+            for ok, what in (
+                    (ragged, "the two-program path (ragged=False) has no "
+                             "state in its programs"),
+                    (mesh is None, "a mesh: the mixer is not sharded"),
+                    (not int8, "int8 weights: the mixer's leaves have no "
+                               "quantized form"),
+                    (not self.prefix_share,
+                     "prefix_share: a shared page has no state to go "
+                     "with it"),
+                    (self.spec_k == 0,
+                     "spec_decode_k > 0: a rejected draft would have to "
+                     "roll the state back")):
+                enforce(ok, "a model with a recurrent state cannot be "
+                            f"served with {what}", op="ServingEngine")
+            enforce(chunk <= cfg.ssm_chunk,
+                    f"chunk {chunk} must not pass the mixer's scan chunk "
+                    f"{cfg.ssm_chunk}", op="ServingEngine")
         if kv_pool_bytes is not None:
             # capacity from a fixed HBM byte budget: the int8-pool mode
             # admits ~2x the blocks of bf16 at the same budget
@@ -545,12 +637,6 @@ class ServingEngine:
         # block tables may reference the same page from several rows.
         # Flags-off the refcounts are all 0/1 and every path below
         # degenerates to the pre-sharing behavior byte-for-byte.
-        if prefix_share is None or prefix_share == "auto":
-            prefix_share = bool(flag("serving_prefix_share"))
-        self.prefix_share = bool(prefix_share)
-        if spec_decode_k is None or spec_decode_k == "auto":
-            spec_decode_k = int(flag("serving_spec_decode_k"))
-        self.spec_k = max(int(spec_decode_k), 0)
         if proposer is None:
             from .speculative import ngram_propose
             proposer = ngram_propose
@@ -558,6 +644,14 @@ class ServingEngine:
         if pool_audit is None or pool_audit == "auto":
             pool_audit = bool(flag("serving_pool_audit"))
         self.pool_audit = bool(pool_audit)
+        self.ssm_state = self.conv_tail = None
+        self.ssm_resets = self._ssm_resets_reported = 0
+        if self.model.recurrent:
+            state_shape, tail_shape = self.model.state_shapes(cfg,
+                                                              max_batch)
+            self.ssm_state = jnp.zeros(state_shape,
+                                       jnp.dtype(ssm_state_dtype))
+            self.conv_tail = jnp.zeros(tail_shape, cfg.dtype)
         self.refcount = np.zeros((num_blocks,), np.int32)
         # page-granular prefix cache: chained page hash -> resident block
         # (and the reverse index). Pages whose last holder left stay
@@ -842,6 +936,13 @@ class ServingEngine:
         quant = self.kv_quantized
         share = self.prefix_share
         mesh, ax = self._mesh, self._mp_axis
+        if self.model.recurrent:
+            # pools, scales (None unless quantized), state, tail: donated
+            jfn = jax.jit(functools.partial(
+                RS.unified_step, cfg=cfg, bs=bsz, c_att=c_att, K=K),
+                donate_argnums=(14, 15, 16, 17, 21, 22))
+            self._jit_programs.append(jfn)
+            return jfn
         if mesh is None:
             if quant:
                 # positional passthrough: with prefix sharing on, the
@@ -2047,19 +2148,26 @@ class ServingEngine:
         greedy_all = None
         with RecordEvent(SERVING_SPANS.dispatch, step=self.engine_steps,
                          k=b.K, n_dec=len(b.dec), n_pre=len(b.pre),
-                         q_tokens=b.q_tokens, kv_tokens=b.kv_tokens):
+                         q_tokens=b.q_tokens, kv_tokens=b.kv_tokens,
+                         **b.ssm_attrs):
             _faults().maybe_fail("serving/dispatch")
             out = self._unified(b.K, spec=b.use_spec)(*args)
+        if self.model.recurrent:
+            *out, self.ssm_state, self.conv_tail = out
         with RecordEvent(SERVING_SPANS.fetch):
+            # ONE host fetch: the copies start together, then the host
+            # waits (a fetch of its own for `lens` cost 0.4 ms a step)
             if b.use_spec:
                 (toks, greedy_all, self.k_pools, self.v_pools,
                  self.k_scales, self.v_scales, lens) = out
-                toks, greedy_all = jax.device_get((toks, greedy_all))
+                toks, greedy_all, lens = jax.device_get(
+                    (toks, greedy_all, lens))
                 greedy_all = np.asarray(greedy_all)      # [T]
             else:
                 (toks, self.k_pools, self.v_pools, self.k_scales,
                  self.v_scales, lens) = out
-            toks = np.asarray(toks)          # [K, R] — ONE host fetch
+                toks, lens = jax.device_get((toks, lens))
+            toks = np.asarray(toks)          # [K, R]
         self._walk_ragged(b, toks, greedy_all, lens, finished)
         self._step_metrics(t_step0, tokens_before, len(b.pre), len(b.dec),
                            finished)
@@ -2174,13 +2282,22 @@ class ServingEngine:
         ran = q_lens > 0
         kv_end = (pos0 + q_lens).astype(np.int64)
         kv_tokens = int(kv_end[ran].sum())
+        burst_rows = 0      # rows the K-1 burst passes run, summed
         for j in range(1, K):
             alive = sample0 & (remaining > j)
             kv_tokens += int((kv_end[alive] + j).sum())
+            burst_rows += int(alive.sum())
+        ssm_attrs = {}
+        if self.model.recurrent:
+            # a row that starts at position 0 has its state zeroed by the
+            # program (admission, and re-prefill after a preemption)
+            self.ssm_resets += int((ran & (pos0 == 0)).sum())
+            ssm_attrs = dict(zip(SSM_DISPATCH_ATTRS, (
+                int(ran.sum()), burst_rows, cursor + burst_rows)))
         return _PackedStep(
             dec=dec, pre=pre, grants=grants, props_by_slot=props_by_slot,
             use_spec=use_spec, K=K, q_tokens=cursor, kv_tokens=kv_tokens,
-            starts=starts, pos0=pos0,
+            starts=starts, pos0=pos0, ssm_attrs=ssm_attrs,
             arrays=(tokens, row_of, off_of, starts, pos0, q_lens,
                     self.tables, fresh, sample0, remaining, eos_ids, temps))
 
@@ -2188,9 +2305,16 @@ class ServingEngine:
     def _upload_ragged(self, b):
         """The unified program's arguments: the packed arrays on the
         device, this step's PRNG key, the pools."""
-        self._key, sub = jax.random.split(self._key)
-        args = ((self.params,) + tuple(jnp.asarray(a) for a in b.arrays)
+        self._key, sub = _split_key(self._key)
+        # one transfer call for the twelve small arrays, not an asarray
+        # each: the host's share of a step is what a short step feels
+        args = ((self.params,) + tuple(jax.device_put(list(b.arrays)))
                 + (sub, self.k_pools, self.v_pools))
+        if self.model.recurrent:
+            # unified_step's full argument list: scales (or None), no
+            # copy-on-write, then the recurrent state and the conv tail
+            return args + (self.k_scales, self.v_scales, None, None, None,
+                           self.ssm_state, self.conv_tail)
         if self.kv_quantized:
             args = args + (self.k_scales, self.v_scales)
         if self.prefix_share:
@@ -2203,8 +2327,8 @@ class ServingEngine:
                 cow_src[j] = s
                 cow_dst[j] = d
             del self._cow_pairs[:R]
-            args = args + (jnp.asarray(cow_src), jnp.asarray(cow_dst),
-                           jnp.asarray(self._reset_tables))
+            args = args + tuple(jax.device_put(
+                [cow_src, cow_dst, self._reset_tables]))
         return args
 
     @RecordEvent(SERVING_SPANS.walk)
@@ -2303,6 +2427,16 @@ class ServingEngine:
                                   "speculation health rate")
             self._spec_prop_reported = self.spec_proposed
             self._spec_acc_reported = self.spec_accepted
+        if self.ssm_state is not None:
+            prom.gauge_set("ssm_state_bytes",
+                           self.ssm_state.nbytes + self.conv_tail.nbytes,
+                           help="recurrent state and conv tail held for "
+                                "the slots")
+            prom.counter_inc("ssm_state_resets_total",
+                             self.ssm_resets - self._ssm_resets_reported,
+                             help="slot states zeroed in-program (a row "
+                                  "starting at position 0)")
+            self._ssm_resets_reported = self.ssm_resets
         prom.gauge_set("queue_depth", len(self.queue))
         prom.gauge_set("running_requests",
                        sum(s is not None for s in self.slots),

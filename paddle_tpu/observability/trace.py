@@ -14,7 +14,8 @@ trace of one commit can be held against a trace of the next:
 
 * ``SERVING_SPANS`` — the host spans of one ``ServingEngine.step()``;
 * ``DISPATCH_ATTRS`` — the attributes of the ``serving_unified_dispatch``
-  span (stats of the event in a profiler trace);
+  span (stats of the event in a profiler trace), and ``SSM_DISPATCH_ATTRS``
+  — the ones a model with a recurrent state adds;
 * ``SCOPES`` — the ``jax.named_scope``s inside the compiled programs (the
   serving step, the dense train step, the hybrid train step). A device
   operation's ``op_name`` path carries them; an operation under none is
@@ -37,7 +38,8 @@ from typing import Iterable, Optional
 from ..profiler.utils import HostEvent, RecordEvent, collector
 
 __all__ = ["span", "capture_spans", "write_chrome_trace", "SERVING_SPANS",
-           "TWO_PROGRAM_SPANS", "DISPATCH_ATTRS", "SCOPES", "KERNELS"]
+           "TWO_PROGRAM_SPANS", "DISPATCH_ATTRS", "SSM_DISPATCH_ATTRS",
+           "SCOPES", "KERNELS"]
 
 span = RecordEvent
 
@@ -72,6 +74,10 @@ TWO_PROGRAM_SPANS = _names(
 # size, decode and prefill rows, packed query tokens, and KV positions
 # attended (summed over the rows that run and over the step's k passes).
 DISPATCH_ATTRS = ("step", "k", "n_dec", "n_pre", "q_tokens", "kv_tokens")
+# A model with a recurrent state adds: rows whose state the first pass read
+# and wrote (the chunk scan's), rows of the k - 1 burst passes (the state
+# update's, summed), and tokens through its mixer over all k passes.
+SSM_DISPATCH_ATTRS = ("ssm_scan_rows", "ssm_update_rows", "ssm_tokens")
 
 SCOPES = _names(
     "Scopes",
@@ -79,6 +85,9 @@ SCOPES = _names(
     embed="embed", qkv="qkv", kv_write="kv_write", ragged_attn="ragged_attn",
     proj_mlp="proj_mlp", head="head", sample="sample", cow="cow",
     burst="burst",
+    # a hybrid block's recurrent mixer beside attention (models/falcon_h1.py)
+    rope="rope", ssm_in="ssm_in", ssm_conv="ssm_conv", ssm_scan="ssm_scan",
+    ssm_out="ssm_out",
     # train programs (models/gpt.py, optimizer/); embed and qkv as above
     attn="attn", flash="flash", attn_out="attn_out", mlp="mlp",
     head_loss="head_loss", optimizer="optimizer",
@@ -94,7 +103,10 @@ KERNELS = _names(
     rms_norm_fwd="rms_norm_fwd", rms_norm_bwd="rms_norm_bwd", rope="rope",
     rowwise="rowwise", row_reduce="row_reduce",
     prim_layer_norm_fwd="prim_layer_norm_fwd",
-    prim_layer_norm_bwd="prim_layer_norm_bwd")
+    prim_layer_norm_bwd="prim_layer_norm_bwd",
+    # the recurrent-state path of a Mamba-2 mixer (kernels/pallas/ssm.py)
+    ssm_conv="ssm_conv", ssm_chunk_scan="ssm_chunk_scan",
+    ssm_state_update="ssm_state_update")
 
 
 class capture_spans:

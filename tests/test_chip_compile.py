@@ -33,7 +33,7 @@ from jax.sharding import SingleDeviceSharding
 
 _KERNEL_MODULES = ("flash_attention", "layer_norm", "rms_norm", "rope",
                    "primitives", "fused_adam", "paged_attention",
-                   "ragged_paged_attention", "kv_append")
+                   "ragged_paged_attention", "kv_append", "ssm")
 
 # the serving smoke's pool geometry (chip_smoke.py): GPT-1.3B heads,
 # 128-token pages
@@ -190,13 +190,11 @@ _HLO_RESULT = re.compile(
 _POOL_MOVERS = {"copy", "copy-start", "dynamic-slice", "dynamic-update-slice"}
 
 
-def _pool_copies(text, pages):
+def _moved(text, hit):
     """Copies, slices, updates and loop fusions of the compiled text whose
-    result is a whole number of layers' page sets ([H, pages, PAGE, D]: the
-    smallest pool-shaped copy the parent made), up to a pool. Views
-    (`bitcast`), the while/tuple plumbing, the in-place scatter and the
-    copy-on-write page gather (`kCustom` fusions) may carry that size."""
-    layer_set = HEADS * pages * PAGE * HEAD_DIM
+    result's element count `hit` accepts. Views (`bitcast`), the
+    while/tuple plumbing, the in-place scatter and the copy-on-write page
+    gather (`kCustom` fusions) may carry that size."""
     found = []
     for line in text.splitlines():
         m = _HLO_RESULT.match(line)
@@ -204,11 +202,18 @@ def _pool_copies(text, pages):
             continue
         n = math.prod(int(d) for d in m.group(1).split(","))
         op = m.group(2)
-        if (n % layer_set == 0 and n <= LAYERS * layer_set
-                and (op in _POOL_MOVERS
-                     or op == "fusion" and "kind=kCustom" not in line)):
+        if hit(n) and (op in _POOL_MOVERS
+                       or op == "fusion" and "kind=kCustom" not in line):
             found.append(line.strip()[:160])
     return found
+
+
+def _pool_copies(text, pages):
+    """What moved a whole number of layers' page sets ([H, pages, PAGE, D]:
+    the smallest pool-shaped copy the parent made), up to a pool."""
+    layer_set = HEADS * pages * PAGE * HEAD_DIM
+    return _moved(text, lambda n: n % layer_set == 0
+                  and n <= LAYERS * layer_set)
 
 
 def _compile_unified_step(one_chip, K, kv_dtype, pages, share=False):
@@ -270,3 +275,65 @@ def test_unified_step_keeps_pool_in_place(one_chip, compiled_kernels, K,
     item = 1 if kv_dtype == "int8" else 2
     pool_bytes = LAYERS * HEADS * pages * PAGE * HEAD_DIM * item
     assert mem.alias_size_in_bytes >= 2 * pool_bytes
+
+
+# ---------------------------------------------------------------------------
+# Falcon-H1-34B's serving step at its cell's geometry (ISSUE 28): six
+# layers at the published widths, 64 slots, 640 pages of 4 KV heads, and
+# the recurrent state [6, 64, 32, 128, 256] f32 (1.6 GB) beside them.
+H1_LAYERS, H1_ROWS, H1_PAGES, H1_TABLE = 6, 64, 640, 8
+
+
+def _state_copies(text, shapes):
+    """What moved a buffer of one of the `shapes`' sizes: the state or the
+    pool, one layer's or all."""
+    return _moved(text, {math.prod(s) for s in shapes}.__contains__)
+
+
+@pytest.mark.parametrize("K", [1, 8], ids=["k1", "k8"])
+def test_falcon_h1_step_keeps_state_and_pool_in_place(one_chip,
+                                                      compiled_kernels, K):
+    """The state's contract is the pool's: one donated buffer each for the
+    recurrent state and the conv tail, on the scans' carry, written only
+    by the mixer's kernels. No state-sized or pool-sized copy, slice or
+    update outside them (one layer's slots or all six), temp under 1 GiB,
+    and everything donated comes back aliased."""
+    from paddle_tpu.inference import ragged_step as RS
+    from paddle_tpu.models import falcon_h1 as FH
+    cfg = FH.FalconH1Config(num_layers=H1_LAYERS)
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: FH.init_params(cfg, jax.random.PRNGKey(0))))
+    tokens = H1_ROWS + cfg.ssm_chunk
+
+    def i32(*shape):
+        return _sds(one_chip, shape, jnp.int32)
+
+    def flags():
+        return _sds(one_chip, (H1_ROWS,), jnp.bool_)
+
+    pool_shape = (H1_LAYERS, cfg.num_kv_heads, H1_PAGES, PAGE, cfg.head_dim)
+    state_shape, tail_shape = FH.state_shapes(cfg, H1_ROWS)
+    pool = _sds(one_chip, pool_shape, jnp.bfloat16)
+    args = [params, i32(tokens), i32(tokens), i32(tokens), i32(H1_ROWS),
+            i32(H1_ROWS), i32(H1_ROWS), i32(H1_ROWS, H1_TABLE), flags(),
+            flags(), i32(H1_ROWS), i32(H1_ROWS),
+            _sds(one_chip, (H1_ROWS,), jnp.float32),
+            _sds(one_chip, (2,), jnp.uint32), pool, pool, None, None, None,
+            None, None, _sds(one_chip, state_shape, jnp.float32),
+            _sds(one_chip, tail_shape, jnp.bfloat16)]
+    step = functools.partial(RS.unified_step, cfg=cfg, bs=PAGE,
+                             c_att=cfg.ssm_chunk, K=K)
+    compiled = jax.jit(step, donate_argnums=(14, 15, 21, 22)
+                       ).lower(*args).compile()
+    text = compiled.as_text()
+    for kernel in ("ssm_conv", "ssm_chunk_scan", "ragged_paged_attn",
+                   "kv_append") + (("ssm_state_update",) if K > 1 else ()):
+        assert kernel in text, f"{kernel} was not lowered for the chip"
+    assert _state_copies(text, [state_shape, state_shape[1:], pool_shape,
+                                pool_shape[1:], tail_shape]) == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
+    donated = (2 * math.prod(pool_shape) * 2 + math.prod(state_shape) * 4
+               + math.prod(tail_shape) * 2)
+    assert mem.alias_size_in_bytes >= donated

@@ -26,8 +26,10 @@ import paddle_tpu.distributed as dist  # noqa: E402
 from paddle_tpu import observability as obs  # noqa: E402
 from paddle_tpu.inference.serving import ServingEngine  # noqa: E402
 from paddle_tpu.models import gpt as G  # noqa: E402
+from paddle_tpu.models import falcon_h1 as FH  # noqa: E402
 from paddle_tpu.observability.trace import (DISPATCH_ATTRS, KERNELS,  # noqa: E402
                                             SCOPES, SERVING_SPANS,
+                                            SSM_DISPATCH_ATTRS,
                                             TWO_PROGRAM_SPANS)
 from paddle_tpu.profiler.utils import RecordEvent, collector  # noqa: E402
 
@@ -263,6 +265,36 @@ def test_the_unified_serving_step_carries_its_scopes():
         SCOPES.burst}
 
 
+def test_the_hybrid_serving_step_carries_its_scopes_and_attributes():
+    """Falcon-H1 through the same engine: the GPT step's scopes (no `cow`:
+    prefix sharing is refused), the mixer's four and `rope`; the dispatch
+    span gains the three state attributes after the six it had."""
+    cfg = FH.FalconH1Config(
+        vocab_size=64, hidden_size=32, num_layers=2, num_heads=4,
+        num_kv_heads=2, head_dim=8, ffn_hidden=64, ssm_heads=4,
+        ssm_head_dim=8, ssm_groups=2, ssm_state=16, ssm_chunk=8,
+        dtype=jnp.float32, param_dtype=jnp.float32)
+    params = FH.init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(params, cfg, ragged=True, max_batch=2,
+                        block_size=16, num_blocks=16, chunk=8,
+                        decode_burst=4)
+    eng.add_request(np.arange(6) % 64, max_new_tokens=8)
+    batch = eng._pack_ragged(eng._admit())
+    lowered = eng._build_unified(2).lower(*eng._upload_ragged(batch))
+    assert _scopes_in(lowered) == {
+        SCOPES.embed, SCOPES.qkv, SCOPES.rope, SCOPES.kv_write,
+        SCOPES.ragged_attn, SCOPES.ssm_in, SCOPES.ssm_conv, SCOPES.ssm_scan,
+        SCOPES.ssm_out, SCOPES.proj_mlp, SCOPES.head, SCOPES.sample,
+        SCOPES.burst}
+    with obs.capture_spans() as cap:
+        eng.step()
+    attrs = [e.attrs for e in cap.events
+             if e.name == SERVING_SPANS.dispatch][0]
+    assert tuple(attrs) == DISPATCH_ATTRS + SSM_DISPATCH_ATTRS
+    assert (attrs["ssm_scan_rows"], attrs["ssm_update_rows"],
+            attrs["ssm_tokens"]) == (1, 0, 6)
+
+
 def _calls(tree, attr):
     return [n for n in ast.walk(tree) if isinstance(n, ast.Call)
             and isinstance(n.func, ast.Attribute) and n.func.attr == attr]
@@ -318,10 +350,14 @@ NEW = [m["name"] for m in SPEC["per_layer"]
 
 def test_the_new_metrics_are_the_issues_and_two_more():
     # ISSUE 26's thirty, plus the hybrid cell's gradient-sync share and
-    # the pp axis's time in flight (PERF.md, PR 26: why they were needed)
-    assert len(NEW) == 32
+    # the pp axis's time in flight (PERF.md, PR 26: why they were needed);
+    # ISSUE 28's cell reads sixteen through the same readers: chat's
+    # eleven, two more shares (the mixer's projections, its state path)
+    # and three rooflines
+    assert len(NEW) == 48
     assert len([n for n in NEW if n.endswith(".docs")]) == 11
     assert len([n for n in NEW if n.endswith(".chat")]) == 11
+    assert len([n for n in NEW if n.endswith(".h1chat")]) == 16
 
 
 @pytest.mark.parametrize("name", NEW)
@@ -338,13 +374,13 @@ def test_metric_files_name_only_what_the_program_names(name):
     if "kernel" in params:
         assert params["kernel"] in KERNELS
     if "attr" in params:
-        assert params["attr"] in DISPATCH_ATTRS
+        assert params["attr"] in DISPATCH_ATTRS + SSM_DISPATCH_ATTRS
 
 
 def test_the_share_metrics_of_a_cell_divide_its_program():
     """Each cell's shares name disjoint scopes that together are `of`, so
     the shares and the unscoped share sum to 100."""
-    for suffix in (".docs", ".chat", ".train"):
+    for suffix in (".docs", ".chat", ".train", ".h1chat"):
         shares = [harness.load_json("metrics", n + ".json")["params"]
                   for n in NEW if n.endswith("_time_pct" + suffix)]
         of = shares[0]["of"]
@@ -378,7 +414,8 @@ def _run_of(pt):
 
 SLICES = {".docs": "ptrace-v5e-serve-docs-slice.json.gz",
           ".chat": "ptrace-v5e-serve-chat-slice.json.gz",
-          ".train": "ptrace-v5e-train-hybrid-slice.json.gz"}
+          ".train": "ptrace-v5e-train-hybrid-slice.json.gz",
+          ".h1chat": "ptrace-v5e-h1chat.json.gz"}
 
 
 @pytest.mark.parametrize("name", NEW)
@@ -386,7 +423,7 @@ def test_reader_on_a_slice_recorded_on_the_chip(name):
     pt = _slice(SLICES["." + name.rsplit(".", 1)[1]])
     value = harness.read_metric(name, _run_of(pt))
     assert value is not None and 0.0 <= value
-    if name.split(".")[0].endswith("_pct"):
+    if name.split(".")[0].endswith(("_pct", "_roofline")):
         assert value <= 100.0
     expected = pt["note"]["expected"]
     assert value == pytest.approx(expected[name], rel=1e-6), name

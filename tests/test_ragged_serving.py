@@ -446,6 +446,20 @@ def test_ragged_matches_two_program_outputs(params):
     assert run(True) == run(False)
 
 
+@pytest.mark.parametrize("seed", [0, 2 ** 31 - 1])
+def test_the_steps_one_call_key_split_is_the_eager_split(seed):
+    """The ragged step splits its key in one compiled call; the keys are
+    bit for bit those of `key, sub = jax.random.split(key)`, which the
+    two-program path still makes, so both paths sample alike."""
+    from paddle_tpu.inference.serving import _split_key
+    key = jax.random.PRNGKey(seed)
+    for _ in range(3):
+        want_key, want_sub = jax.random.split(key)
+        key, sub = _split_key(key)
+        np.testing.assert_array_equal(np.asarray(key), np.asarray(want_key))
+        np.testing.assert_array_equal(np.asarray(sub), np.asarray(want_sub))
+
+
 # ---------------------------------------------------------------------------
 # pool-pressure scheduling
 # ---------------------------------------------------------------------------
