@@ -38,7 +38,8 @@ result:
     set out of the stack and stacks a fresh one back, 2 x 3.2 GB a pass;
   * it is never sliced by layer: the attention kernel and the append
     take the whole pool and a ``layer`` scalar (scalar prefetch,
-    dereferenced in their index maps);
+    dereferenced in the append's index maps and in the attention
+    kernel's page copies, which read the pool where it lies in HBM);
   * it is written only in place and in the layout the kernel reads: new
     rows by `kernels.pallas.kv_append` (aliased in/out, one aligned
     sublane tile a grid step); the quantized append and the copy-on-write

@@ -184,6 +184,7 @@ class _PackedStep:
     K: int               # burst size: passes of the program this step
     q_tokens: int        # packed query tokens (the cursor)
     kv_tokens: int       # KV positions attended over the K passes
+    attn_pages: int      # (row, page) pairs the K passes' attention walks
     starts: np.ndarray
     pos0: np.ndarray
     arrays: tuple        # the host arrays, in the program's order
@@ -2149,7 +2150,7 @@ class ServingEngine:
         with RecordEvent(SERVING_SPANS.dispatch, step=self.engine_steps,
                          k=b.K, n_dec=len(b.dec), n_pre=len(b.pre),
                          q_tokens=b.q_tokens, kv_tokens=b.kv_tokens,
-                         **b.ssm_attrs):
+                         attn_pages=b.attn_pages, **b.ssm_attrs):
             _faults().maybe_fail("serving/dispatch")
             out = self._unified(b.K, spec=b.use_spec)(*args)
         if self.model.recurrent:
@@ -2278,14 +2279,19 @@ class ServingEngine:
         # KV positions the step attends: each row of pass 1 its whole
         # context, then each sampling row one position more per burst
         # pass while it may still emit (an EOS inside the burst stops a
-        # row earlier; the host learns that only from the fetch)
+        # row earlier; the host learns that only from the fetch). The
+        # pages those positions fill are the (row, page) pairs the
+        # attention kernel walks: of a pass's R x nb table slots, the
+        # ones that carry work
         ran = q_lens > 0
         kv_end = (pos0 + q_lens).astype(np.int64)
         kv_tokens = int(kv_end[ran].sum())
+        attn_pages = int((-(-kv_end[ran] // self.bs)).sum())
         burst_rows = 0      # rows the K-1 burst passes run, summed
         for j in range(1, K):
             alive = sample0 & (remaining > j)
             kv_tokens += int((kv_end[alive] + j).sum())
+            attn_pages += int((-(-(kv_end[alive] + j) // self.bs)).sum())
             burst_rows += int(alive.sum())
         ssm_attrs = {}
         if self.model.recurrent:
@@ -2297,6 +2303,7 @@ class ServingEngine:
         return _PackedStep(
             dec=dec, pre=pre, grants=grants, props_by_slot=props_by_slot,
             use_spec=use_spec, K=K, q_tokens=cursor, kv_tokens=kv_tokens,
+            attn_pages=attn_pages,
             starts=starts, pos0=pos0, ssm_attrs=ssm_attrs,
             arrays=(tokens, row_of, off_of, starts, pos0, q_lens,
                     self.tables, fresh, sample0, remaining, eos_ids, temps))

@@ -9,7 +9,8 @@ ONE compiled dispatch instead of a prefill program plus a decode program
 (Ragged Paged Attention, arXiv:2604.15464; reference block kernels
 paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu).
 
-Design (extends paged_attention.py, which stays as the decode-only
+The kernel's cost follows ``(q_lens, kv_lens, tables)``, not the static
+shapes ``(R, H_kv, nb, C)`` (paged_attention.py stays as the decode-only
 baseline the two-program engine path compiles):
 
   * the pool is the serving engine's WHOLE buffer, layer-major then
@@ -18,33 +19,61 @@ baseline the two-program engine path compiles):
     rides scalar prefetch — the caller never slices a layer out of the
     pool, so the compiled step holds no pool-shaped copy (the pool's
     contract is stated once, in inference/ragged_step.py; the scales are
-    small, and the wrapper hands SMEM the one layer's). One (layer,
-    head, block) tile is a contiguous ``[bs, D]`` VMEM block; the K/V
-    BlockSpec index maps dereference ``layer[0]`` and ``tables[r, j]`` so
-    only referenced blocks stream. A 4-D ``[H_kv, num_blocks, bs, D]``
-    pool is the same call with ``L = 1``, ``layer = 0`` (decided from
-    ``k_pool.ndim``);
-  * grid ``(R, H_kv, nb)``: rows × kv heads × table slots. Per-row
-    ``kv_len`` clamps past-end steps to the last used block (Pallas skips
-    the re-fetch when consecutive steps map to the same block) and the
-    compute body is predicated off — a decode row costs its own blocks,
-    never the batch max;
-  * the q tile folds (chunk, GQA group) into one ``[C*g, D]`` MXU
-    operand; in-kernel masking applies BOTH raggedness (``c < q_len``)
-    and causality (``col_pos <= kv_len - q_len + c``), so decode rows and
-    prefill chunks share the grid with no inter-row padding;
-  * optional int8 KV: pools stored int8 with per-(head, page) scales in
-    the module's absmax convention (quantization/: dequant = q·s/127),
-    dequantized IN-KERNEL right after the VMEM fetch — decode is
-    bandwidth-bound, so the kernel streams half the HBM bytes per step
-    and a fixed pool budget admits ~2x the sequences;
-  * online softmax in VMEM scratch, exactly like the training flash
-    kernel; empty rows (q_len = 0) emit zeros.
+    small, and the wrapper hands SMEM the one layer's). A 4-D ``[H_kv,
+    num_blocks, bs, D]`` pool is the same call with ``L = 1``,
+    ``layer = 0`` (decided from ``k_pool.ndim``);
+  * grid ``(R,)``: one step a row, 64 a layer at the cells' batch where
+    ``(R, H_kv, nb)`` was 16,384, each 0.15-0.3 us whether or not it
+    carried work. The pool stays in HBM (``pl.ANY``); a row walks its own
+    ``cdiv(kv_len, bs)`` pages in a loop, and each page is ONE strided
+    copy of EVERY KV head's ``[bs, D]`` tile (``pool.at[layer, :, page]``,
+    H_kv contiguous 32 KB pieces at 128 x 128 bf16) into one of two VMEM
+    buffers, the next page's copy in flight while this one is attended.
+    The row's last page starts the NEXT live row's first copy, so the
+    stream does not drain between rows (grid steps run in order:
+    ``dimension_semantics`` is ``arbitrary``). A table slot nobody owns
+    costs nothing — no step, no fetch; an empty row (q_len = 0) costs its
+    grid step and the zeros it emits. Bytes and time both follow the
+    descriptors;
+  * queries come and go as the caller has them, ``[C, H_q, D]`` a row
+    (no transposed copy of the ``[R, C, H_q, D]`` tiles in or out: 2 x 67
+    MB a layer at the GPT cells' shapes, 18% of a docs step once the
+    kernel itself was short). A row stages its queries head-major in VMEM
+    once, folding the GQA group into the rows of one ``[g * c, D]`` MXU
+    operand a KV head (row f is query head f // c of the group at chunk
+    position f % c), and un-folds its output the same way; in-kernel
+    masking applies BOTH raggedness (``c < q_len``) and causality
+    (``col_pos <= kv_len - q_len + c``), so decode rows and prefill chunks
+    share the grid with no inter-row padding;
+  * a row's arithmetic is sized by its own ``q_len``, in two arms chosen
+    from the prefetched descriptor (``pl.when``, static slices in each):
+    a row whose folded queries fit one sublane tile (``q_len <= 8 // g``:
+    every decode row, for MHA a short verify row too) stages and attends
+    its first ``8 // g`` chunk positions only, every head of the page in
+    ONE ``[H_kv, 8, bs]`` soft-max update (the heads' products are
+    independent, so the MXU and the vector units pipeline across them);
+    any other row runs the whole chunk, head by head. Both arms write the
+    same output tile; chunk positions past the arm's are zeros;
+  * optional int8 / fp8 KV: pools stored quantized with per-(head, page)
+    scales in the module's absmax convention (quantization/: dequant =
+    q·s/qmax), dequantized IN-KERNEL: the page's values widen exactly to
+    the query's dtype and each head's products take that head's scalar
+    ``s/qmax`` from SMEM — decode is bandwidth-bound, so the kernel
+    streams half the HBM bytes per step and a fixed pool budget admits
+    ~2x the sequences;
+  * online softmax (f32 scores, statistics and accumulator) in VMEM
+    scratch, exactly like the training flash kernel; empty rows emit
+    zeros.
+
+Naming rule: every ``pallas_call`` that does attention for the ragged
+step is named ``KERNELS.ragged_paged_attn``. The benchmark's
+``attn_hbm_pct`` divides the step's KV bytes by the device time of kernels
+of exactly that name: attention time under another name, or attention
+arithmetic moved out of Pallas into XLA operations, makes it over-read.
 
 Interpreter mode runs the same kernel on CPU (tier-1 parity tests).
 Page-size guidance is unchanged from paged_attention.py: pick
-block_size >= 128 on real TPUs; tiny vLLM-style pages drown in grid
-overhead.
+block_size >= 128 on real TPUs; a page is the unit of every copy.
 """
 
 from __future__ import annotations
@@ -63,69 +92,192 @@ from ...observability.trace import KERNELS
 __all__ = ["ragged_paged_attention"]
 
 _NEG_INF = -1e30
+# the narrow arm's tile height: one f32 sublane tile of folded queries
+_NARROW = 8
 
 
-def _ragged_kernel(*refs, scale, bs, nb, g, quantized, qmax):
+def _ragged_kernel(*refs, scale, bs, g, quantized, qmax):
     if quantized:
-        (tables_ref, qlens_ref, kvlens_ref, layer_ref, ks_ref, vs_ref,
-         q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc) = refs
+        (tables_ref, qlens_ref, kvlens_ref, layer_ref, next_ref, ks_ref,
+         vs_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, stream,
+         qs, ot, m_sc, l_sc, acc_sc) = refs
     else:
-        (tables_ref, qlens_ref, kvlens_ref, layer_ref,
-         q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc) = refs
+        (tables_ref, qlens_ref, kvlens_ref, layer_ref, next_ref,
+         q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, stream,
+         qs, ot, m_sc, l_sc, acc_sc) = refs
         ks_ref = vs_ref = None
     r = pl.program_id(0)
-    h = pl.program_id(1)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
-        l_sc[:] = jnp.zeros_like(l_sc)
-        acc_sc[:] = jnp.zeros_like(acc_sc)
-
+    R, nb = tables_ref.shape
+    C, hq = q_ref.shape[1], q_ref.shape[2]
+    hkv = qs.shape[0]
     ql = qlens_ref[r]
     kl = kvlens_ref[r]
-    used = (kl + bs - 1) // bs
+    layer = layer_ref[0]
 
-    @pl.when((j < used) & (ql > 0))
-    def _compute():
-        q = q_ref[0, 0]  # [CG, D] — (chunk, group) folded, c-major
-        k = k_ref[0, 0, 0]  # [bs, D] (int8 when quantized)
-        v = v_ref[0, 0, 0]
-        if quantized:
-            page = tables_ref[r, j]
-            k_deq = k.astype(jnp.float32) * (ks_ref[h, page] / qmax)
-            v_deq = v.astype(jnp.float32) * (vs_ref[h, page] / qmax)
-        else:
-            k_deq, v_deq = k, v
-        s = jax.lax.dot_general(
-            q, k_deq, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale  # [CG, bs]
-        # row f of the folded tile is chunk position c = f // g; its
+    def page_copies(row, j, slot):
+        """Every KV head's tile of `row`'s j-th page: K and V, one strided
+        copy each into buffer `slot`."""
+        page = tables_ref[row, j]
+        return (pltpu.make_async_copy(k_hbm.at[layer, :, page],
+                                      kbuf.at[slot], sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[layer, :, page],
+                                      vbuf.at[slot], sem.at[1, slot]))
+
+    def start(row, j, slot):
+        for copy in page_copies(row, j, slot):
+            copy.start()
+
+    # `stream`: the buffer the next page copy lands in, and whether this
+    # row's first page is already in flight (started by the row before)
+    @pl.when(r == 0)
+    def _first_row():
+        stream[0] = 0
+        stream[1] = 0
+
+    # an empty row, and a live row's positions past its q_len, emit zeros
+    o_ref[...] = jnp.zeros_like(o_ref)
+
+    def arm(ch, nh):
+        """The row's first `ch` chunk positions, folded group-major into
+        `rows` = g * ch rows a KV head (row f is query head f // ch of the
+        group at chunk position f % ch), `nh` heads a soft-max update."""
+        rows = max(g * ch, _NARROW)
+        filled = jax.lax.min(ql, ch)    # chunk positions that hold a query
+        # the row's queries, head-major: staged once, read every page. One
+        # [H_q, D] tile a chunk position, a loop as long as the row's own
+        # q_len (unrolled, the chunk's C x H_q single-row moves were most
+        # of the kernel's compile time)
+        if g * ch < rows:
+            qs[:, :rows] = jnp.zeros((hkv, rows, qs.shape[2]), qs.dtype)
+
+        def stage(c, carry):
+            tile = q_ref[0, c].astype(qs.dtype)
+            for j in range(hq):
+                qs[j // g, pl.ds(j % g * ch + c, 1), :] = tile[j:j + 1]
+            return carry
+
+        jax.lax.fori_loop(0, filled, stage, None)
+        m_sc[:, :rows] = jnp.full((hkv, rows, _LANES), _NEG_INF, jnp.float32)
+        l_sc[:, :rows] = jnp.zeros((hkv, rows, _LANES), jnp.float32)
+        acc_sc[:, :rows] = jnp.zeros((hkv, rows) + acc_sc.shape[2:],
+                                     jnp.float32)
+        # the row's own pages (scalars go through lax, not the jitted jnp
+        # helpers: non-negative operands need no sign fix-up)
+        n = jax.lax.clamp(1, jax.lax.div(kl + bs - 1, bs), nb)
+        base = stream[0]
+        nxt = next_ref[r]
+
+        @pl.when(stream[1] == 0)
+        def _own_first_page():
+            start(r, 0, base)
+
+        # row f of the folded tile is chunk position c = f % ch; its
         # absolute query position is kv_len - q_len + c (the chunk holds
         # the LAST q_len tokens of the sequence)
-        c = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // g
-        col = j * bs + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        ok = (c < ql) & (col <= kl - ql + c)
-        s = jnp.where(ok, s, _NEG_INF)
-        m_prev = m_sc[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(ok, p, 0.0)
-        l_sc[:] = l_sc[:] * alpha[:, None] + jnp.sum(p, axis=1)[:, None]
-        m_sc[:] = jnp.broadcast_to(m_new[:, None], m_sc.shape)
-        pv = jax.lax.dot_general(
-            p.astype(v_deq.dtype), v_deq, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        acc_sc[:] = acc_sc[:] * alpha[:, None] + pv
+        c = jax.lax.rem(
+            jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 0), ch)
+        col = jax.lax.broadcasted_iota(jnp.int32, (rows, bs), 1)
+        last_col = kl - ql + c
 
-    @pl.when(j == nb - 1)
-    def _finalize():
-        l = l_sc[:, 0]
-        dead = (l == 0.0) | (m_sc[:, 0] <= _NEG_INF * 0.5)
+        def page_step(j, carry):
+            slot = jax.lax.rem(base + j, 2)
+
+            @pl.when(j + 1 < n)
+            def _next_page():
+                start(r, j + 1, 1 - slot)
+
+            @pl.when((j + 1 == n) & (nxt < R))
+            def _next_row():
+                start(nxt, 0, 1 - slot)
+
+            for copy in page_copies(r, j, slot):
+                copy.wait()
+            ok = ((c < ql) & (j * bs + col <= last_col))[None]
+            page = tables_ref[r, j]
+
+            def head_scales(ref, h0):
+                """ref[h, page] / qmax for the update's heads: a scalar
+                for one head, else a [nh, 1, 1] vector built from the
+                SMEM scalars."""
+                if nh == 1:
+                    return ref[h0, page] / qmax
+                head = jax.lax.broadcasted_iota(jnp.int32, (nh, 1, 1), 0)
+                vec = jnp.zeros((nh, 1, 1), jnp.float32)
+                for h in range(nh):
+                    vec = jnp.where(head == h, ref[h, page], vec)
+                return vec / qmax
+
+            def attend(h0):
+                """One soft-max update of heads [h0, h0 + nh): the heads
+                are the batch dimension of the two contractions."""
+                hs = pl.ds(h0, nh)
+                q = qs[hs, :rows, :].astype(q_ref.dtype)  # [nh, rows, D]
+                k = kbuf[slot, hs]                        # [nh, bs, D]
+                v = vbuf[slot, hs]
+                sk = scale
+                if quantized:
+                    # absmax dequantisation (x = q * s / qmax) on the
+                    # products: the values widen exactly, one scale a
+                    # (head, page)
+                    k, v = k.astype(q.dtype), v.astype(q.dtype)
+                    sk = scale * head_scales(ks_ref, h0)
+                s = jax.lax.dot_general(
+                    q, k, (((2,), (2,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32) * sk
+                s = jnp.where(ok, s, _NEG_INF)          # [nh, rows, bs]
+                m_prev = m_sc[hs, :rows, :1]
+                m_new = jnp.maximum(m_prev,
+                                    jnp.max(s, axis=2, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+                l_sc[hs, :rows] = jnp.broadcast_to(
+                    l_sc[hs, :rows, :1] * alpha
+                    + jnp.sum(p, axis=2, keepdims=True), (nh, rows, _LANES))
+                m_sc[hs, :rows] = jnp.broadcast_to(m_new,
+                                                   (nh, rows, _LANES))
+                pv = jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32)
+                if quantized:
+                    pv = pv * head_scales(vs_ref, h0)
+                acc_sc[hs, :rows] = acc_sc[hs, :rows] * alpha + pv
+
+            if nh == hkv:
+                attend(0)
+            else:   # traced once, unrolled when lowered
+                def group(i, carry):
+                    attend(i * nh)
+                    return carry
+                jax.lax.fori_loop(0, hkv // nh, group, None, unroll=True)
+            return carry
+
+        jax.lax.fori_loop(0, n, page_step, None)
+        stream[0] = jax.lax.rem(base + n, 2)
+        stream[1] = (nxt < R).astype(jnp.int32)
+        l = l_sc[:, :rows, :1]
+        dead = (l == 0.0) | (m_sc[:, :rows, :1] <= _NEG_INF * 0.5)
         inv = jnp.where(dead, 0.0, 1.0 / jnp.maximum(l, 1e-37))
-        o_ref[0, 0] = (acc_sc[:] * inv[:, None]).astype(o_ref.dtype)
+        acc_sc[:, :rows] = acc_sc[:, :rows] * inv
+
+        def emit(c, carry):     # un-fold: the [H_q, D] tile of position c
+            for j in range(hq):
+                ot[j:j + 1, :] = acc_sc[j // g, pl.ds(j % g * ch + c, 1), :]
+            o_ref[0, c] = ot[...].astype(o_ref.dtype)
+            return carry
+
+        jax.lax.fori_loop(0, filled, emit, None)
+
+    # A row's arithmetic is sized by its own q_len: folded queries that
+    # fit one sublane tile run on 8 rows, every head of a page in one
+    # update; any other row on the whole chunk, a head an update (a
+    # [g * C, bs] f32 score tile is 16 vregs or more already).
+    cn = min(_NARROW // g, C)   # chunk positions the narrow arm holds
+    if 0 < cn < C:
+        narrow = ql <= cn
+        pl.when((ql > 0) & narrow)(lambda: arm(cn, hkv))
+        pl.when((ql > 0) & jnp.logical_not(narrow))(lambda: arm(C, 1))
+    else:
+        pl.when(ql > 0)(lambda: arm(C, hkv if g * C <= _NARROW else 1))
 
 
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_lens,
@@ -149,60 +301,67 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_lens,
         k_scales, v_scales = (jax.lax.dynamic_index_in_dim(
             s, layer, keepdims=False) for s in (k_scales, v_scales))
     _, hkv, _, bs, _ = k_pool.shape
-    nb = block_tables.shape[1]
     g = hq // hkv
     quantized = k_scales is not None
     # quantized pools dequantize in-kernel in the absmax convention
     # (quantization/kv_cache.py): int8 grid tops at 127, e4m3 at 448
     qmax = (448.0 if k_pool.dtype == jnp.dtype(jnp.float8_e4m3fn)
             else 127.0)
-    CG = C * g
-    CG8 = max(8, -(-CG // 8) * 8)  # sublane-align the folded tile
-    # [R, C, hkv, g, D] -> [R, hkv, C*g, D], chunk-major rows (c = f // g)
-    qt = q.reshape(R, C, hkv, g, D).transpose(0, 2, 1, 3, 4)
-    qt = qt.reshape(R, hkv, CG, D)
-    if CG8 != CG:
-        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, CG8 - CG), (0, 0)))
+    CG8 = max(_NARROW, -(-C * g // 8) * 8)  # a head's folded tile
+    q_lens = q_lens.astype(jnp.int32)
+    # the next row that has work (R past the last): a row starts that
+    # row's first page copy beside its own last page's arithmetic
+    rows = jnp.arange(R, dtype=jnp.int32)
+    later_live = (rows[None, :] > rows[:, None]) & (q_lens > 0)[None, :]
+    next_live = jnp.min(jnp.where(later_live, rows[None, :], R), axis=1)
 
-    def q_idx(r, h, j, *prefetch):
-        return (r, h, 0, 0)
+    def q_idx(r, *prefetch):
+        return (r, 0, 0, 0)
 
-    def kv_idx(r, h, j, *prefetch):
-        tables, qlens, kvlens = prefetch[:3]
-        # clamp past-end steps to the last used block: the index repeats,
-        # so Pallas skips the re-fetch and the tail costs nothing
-        used_last = jnp.maximum((kvlens[r] + bs - 1) // bs - 1, 0)
-        return (prefetch[3][0], h, tables[r, jnp.minimum(j, used_last)],
-                0, 0)
+    # staged in 32 bits: a packed dtype has no single-row loads or stores
+    stage = jnp.float32 if q.dtype.itemsize < 4 else q.dtype
 
-    prefetch = [block_tables, q_lens.astype(jnp.int32),
+    prefetch = [block_tables.astype(jnp.int32), q_lens,
                 kv_lens.astype(jnp.int32),
-                jnp.asarray(layer, jnp.int32).reshape(1)]
+                jnp.asarray(layer, jnp.int32).reshape(1), next_live]
     if quantized:
         prefetch += [k_scales.astype(jnp.float32),
                      v_scales.astype(jnp.float32)]
+    tile = hkv * CG8 * D                    # a row's folded query tile
+    page = 2 * hkv * bs * D * k_pool.dtype.itemsize     # two buffers
+    vmem = (2 * 2 * C * hq * D * q.dtype.itemsize + 2 * page
+            + 4 * tile * (2 + 2 * _LANES // D))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
-        grid=(R, hkv, nb),
+        grid=(R,),
         in_specs=[
-            pl.BlockSpec((1, 1, CG8, D), q_idx),
-            pl.BlockSpec((1, 1, 1, bs, D), kv_idx),
-            pl.BlockSpec((1, 1, 1, bs, D), kv_idx),
+            pl.BlockSpec((1, C, hq, D), q_idx),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((1, 1, CG8, D), q_idx),
+        out_specs=pl.BlockSpec((1, C, hq, D), q_idx),
         scratch_shapes=[
-            pltpu.VMEM((CG8, _LANES), jnp.float32),
-            pltpu.VMEM((CG8, _LANES), jnp.float32),
-            pltpu.VMEM((CG8, D), jnp.float32),
+            pltpu.VMEM((2, hkv, bs, D), k_pool.dtype),
+            pltpu.VMEM((2, hkv, bs, D), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((hkv, CG8, D), stage),
+            pltpu.VMEM((hq, D), jnp.float32),
+            pltpu.VMEM((hkv, CG8, _LANES), jnp.float32),
+            pltpu.VMEM((hkv, CG8, _LANES), jnp.float32),
+            pltpu.VMEM((hkv, CG8, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_ragged_kernel, scale=scale, bs=bs, nb=nb, g=g,
+        functools.partial(_ragged_kernel, scale=scale, bs=bs, g=g,
                           quantized=quantized, qmax=qmax),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, hkv, CG8, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((R, C, hq, D), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            # the buffers above and as much again for the score tiles
+            vmem_limit_bytes=min(max(2 * vmem, 32 << 20), 96 << 20)),
         interpret=_interpret(),
         name=KERNELS.ragged_paged_attn,
-    )(*prefetch, qt, k_pool, v_pool)
-    out = out[:, :, :CG].reshape(R, hkv, C, g, D)
-    return out.transpose(0, 2, 1, 3, 4).reshape(R, C, hq, D)
+    )(*prefetch, q, k_pool, v_pool)
+    return out

@@ -71,9 +71,12 @@ TWO_PROGRAM_SPANS = _names(
     verify="serving_verify_dispatch", decode="serving_decode_dispatch")
 
 # Attributes of the dispatch span: the engine step's number, the burst
-# size, decode and prefill rows, packed query tokens, and KV positions
-# attended (summed over the rows that run and over the step's k passes).
-DISPATCH_ATTRS = ("step", "k", "n_dec", "n_pre", "q_tokens", "kv_tokens")
+# size, decode and prefill rows, packed query tokens, KV positions
+# attended (summed over the rows that run and over the step's k passes),
+# and the (row, page) pairs those positions fill: what the attention
+# kernel walks, of k x max_batch x max_blocks_per_seq table slots.
+DISPATCH_ATTRS = ("step", "k", "n_dec", "n_pre", "q_tokens", "kv_tokens",
+                  "attn_pages")
 # A model with a recurrent state adds: rows whose state the first pass read
 # and wrote (the chunk scan's), rows of the k - 1 burst passes (the state
 # update's, summed), and tokens through its mixer over all k passes.

@@ -163,6 +163,48 @@ def test_ragged_paged_attention(one_chip, compiled_kernels, kv_dtype):
         _compile(fn, q, pool, pool, tables, lens, lens)
 
 
+# the serving cells' attention shapes (PERF.md section 4): rows, chunk,
+# query heads, the whole pool [L, H_kv, pages, PAGE, HEAD_DIM], table width
+_CELL_ATTENTION = {
+    "gpt-pass1": (64, 128, 16, (24, 16, 256), 16),
+    "gpt-burst": (64, 1, 16, (24, 16, 256), 16),
+    "falconh1-pass1": (64, 128, 20, (6, 4, 640), 8),
+}
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("cell", sorted(_CELL_ATTENTION))
+def test_ragged_paged_attention_at_the_cells_shapes(one_chip,
+                                                    compiled_kernels, cell,
+                                                    kv_dtype):
+    """The kernel as the three serving cells call it: the whole pool, a
+    traced layer, every KV head of a page in one copy (16 x 32 KB for GPT,
+    4 x 32 KB for Falcon-H1 with its 640-row folded query tile). A VMEM
+    overrun or a refused slice shows here, without the chip."""
+    from paddle_tpu.kernels.pallas.ragged_paged_attention import (
+        ragged_paged_attention)
+    rows, chunk, hq, (layers, hkv, pages), table = _CELL_ATTENTION[cell]
+    quant = kv_dtype == "int8"
+    q = _sds(one_chip, (rows, chunk, hq, HEAD_DIM), jnp.bfloat16)
+    pool = _sds(one_chip, (layers, hkv, pages, PAGE, HEAD_DIM),
+                jnp.int8 if quant else jnp.bfloat16)
+    scales = (_sds(one_chip, (layers, hkv, pages), jnp.float32)
+              if quant else None)
+    tables = _sds(one_chip, (rows, table), jnp.int32)
+    lens = _sds(one_chip, (rows,), jnp.int32)
+    layer = _sds(one_chip, (), jnp.int32)
+
+    def fn(q, kp, vp, tables, q_lens, kv_lens, ks, vs, layer):
+        return ragged_paged_attention(q, kp, vp, tables, q_lens, kv_lens,
+                                      HEAD_DIM ** -0.5, ks, vs, layer)
+
+    compiled, text = _compile(fn, q, pool, pool, tables, lens, lens, scales,
+                              scales, layer)
+    assert "ragged_paged_attn" in text
+    # the pool is read where it lies: nothing pool-sized is staged
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 26
+
+
 def test_paged_decode_attention(one_chip, compiled_kernels):
     from paddle_tpu.kernels.pallas.paged_attention import (
         paged_decode_attention)

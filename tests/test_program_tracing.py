@@ -169,8 +169,36 @@ def test_one_ragged_step_yields_every_serving_span_once():
     kv = sum(end for end, _, _ in rows) + sum(
         end + j for j in range(1, k) for end, samples, left in rows
         if samples and left > j)
+    pages = sum(-(-end // 16) for end, _, _ in rows) + sum(
+        -(-(end + j) // 16) for j in range(1, k)
+        for end, samples, left in rows if samples and left > j)
     assert attrs == {"step": eng.engine_steps, "k": k, "n_dec": n_dec,
-                     "n_pre": n_pre, "q_tokens": q_tokens, "kv_tokens": kv}
+                     "n_pre": n_pre, "q_tokens": q_tokens, "kv_tokens": kv,
+                     "attn_pages": pages}
+
+
+def test_attn_pages_counts_the_row_page_pairs_of_a_hand_built_step():
+    """`attn_pages` is what the attention kernel walks: for every row that
+    runs, the pages its context fills after the pass (16-token pages
+    here). With `k`, the batch and the table width it gives the share of
+    the kernel's (row, page) slots that carry work."""
+    cfg = tiny_cfg()
+    params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(params, cfg, ragged=True, max_batch=4,
+                        block_size=16, num_blocks=16, chunk=8,
+                        decode_burst=1)
+    eng.add_request(np.arange(5) % 64, max_new_tokens=12)    # row A
+    eng.add_request(np.arange(20) % 64, max_new_tokens=4)    # row B
+    seen = []
+    for _ in range(4):
+        with obs.capture_spans() as cap:
+            eng.step()
+        seen += [(e.attrs["kv_tokens"], e.attrs["attn_pages"])
+                 for e in cap.events if e.name == SERVING_SPANS.dispatch]
+    # contexts after each pass, A / B: 5 / 7 (the token budget is 12),
+    # 6 / 15, 7 / 20, 8 / 21 -- B crosses into its second page on the
+    # third step
+    assert seen == [(12, 2), (21, 2), (27, 3), (29, 3)]
 
 
 def test_an_idle_step_and_the_two_program_path_keep_their_spans():
@@ -209,8 +237,15 @@ def test_the_two_unread_prom_counters_are_gone():
 
 # -- names inside the compiled programs -------------------------------------
 def _scopes_in(lowered):
+    """Scopes on the name stacks of the lowered text: a path component
+    (`.../embed/...`, `jvp(mlp)`), never the bare quoted `"ssm_conv"(` of a
+    Python frame. A jitted jnp helper keeps the traceback of its first
+    trace in the process, so a function named like a scope (`ssm_conv`,
+    `ssm_scan`) shows up in another program's locations when its tests ran
+    first in the same worker."""
     text = lowered.as_text(debug_info=True)
-    return {s for s in SCOPES if re.search(r"[/(\"]%s[/)\"]" % s, text)}
+    return {s for s in SCOPES
+            if re.search(r'[/(]%s[/)"]|"%s[/)]' % (s, s), text)}
 
 
 TRAIN_SCOPES = {SCOPES.embed, SCOPES.attn, SCOPES.qkv, SCOPES.flash,
@@ -268,7 +303,7 @@ def test_the_unified_serving_step_carries_its_scopes():
 def test_the_hybrid_serving_step_carries_its_scopes_and_attributes():
     """Falcon-H1 through the same engine: the GPT step's scopes (no `cow`:
     prefix sharing is refused), the mixer's four and `rope`; the dispatch
-    span gains the three state attributes after the six it had."""
+    span gains the three state attributes after the seven it had."""
     cfg = FH.FalconH1Config(
         vocab_size=64, hidden_size=32, num_layers=2, num_heads=4,
         num_kv_heads=2, head_dim=8, ffn_hidden=64, ssm_heads=4,

@@ -78,24 +78,45 @@ def _composed_reference(q, kp, vp, tables, q_lens, kv_lens, scale):
     return out
 
 
-@pytest.mark.parametrize("hq,hkv", [(4, 4), (8, 2)])  # MHA + GQA
-def test_ragged_kernel_matches_composed_reference(hq, hkv):
+# (q_lens, kv_lens) a batch; bs = 8, C = 6, five table slots (40 positions)
+_KERNEL_BATCHES = {
+    # row 0 decode, row 1 prefill chunk mid-sequence, row 2 EMPTY
+    # (finished slot), row 3 fresh prefill
+    "mixed": ([1, 6, 0, 3], [19, 11, 0, 3]),
+    # both arms and the empty row in one call: q_len 0, 1 (a decode row),
+    # 2-8 (verify rows; the narrow arm while q_len * g <= 8) and the full
+    # chunk, the narrow rows before, between and after the wide ones
+    "arms": ([1, 0, 2, 6, 4, 1, 6], [9, 0, 17, 6, 30, 33, 40]),
+    # kv_len at 1, bs, bs + 1, and into the table's last page (its first
+    # position, its last)
+    "edges": ([1, 1, 1, 1, 6, 3], [1, 8, 9, 33, 40, 40]),
+}
+
+
+def _own_pages(kv_lens, bs, nb):
+    """A table a row: the pages its context fills, numbered from 1 on."""
+    tables = np.zeros((len(kv_lens), nb), np.int32)
+    blk = 1
+    for r, kv_len in enumerate(kv_lens):
+        for j in range(-(-int(kv_len) // bs)):
+            tables[r, j] = blk
+            blk += 1
+    return tables
+
+
+@pytest.mark.parametrize("batch", sorted(_KERNEL_BATCHES))
+@pytest.mark.parametrize("hq,hkv", [(4, 4), (8, 2), (20, 4)])
+def test_ragged_kernel_matches_composed_reference(hq, hkv, batch):
+    """MHA, GQA, and a 5-query group whose decode row (5 folded rows) takes
+    the 8-row arm while its 2-query row (10) takes the whole tile."""
     from paddle_tpu.kernels.pallas.ragged_paged_attention import (
         ragged_paged_attention)
     rng = np.random.RandomState(0)
-    R, C, D, bs, nb, NB = 4, 6, 16, 8, 5, 16
+    q_lens, kv_lens = (np.array(a, np.int32) for a in _KERNEL_BATCHES[batch])
+    R, C, D, bs, nb, NB = len(q_lens), 6, 16, 8, 5, 32
     kp = jnp.asarray(rng.randn(hkv, NB, bs, D).astype(np.float32))
     vp = jnp.asarray(rng.randn(hkv, NB, bs, D).astype(np.float32))
-    # row 0 decode, row 1 prefill chunk mid-sequence, row 2 EMPTY
-    # (finished slot), row 3 fresh prefill
-    q_lens = np.array([1, 6, 0, 3], np.int32)
-    kv_lens = np.array([19, 11, 0, 3], np.int32)
-    tables = np.zeros((R, nb), np.int32)
-    blk = 1
-    for r in range(R):
-        for j in range(-(-int(kv_lens[r]) // bs)):
-            tables[r, j] = blk
-            blk += 1
+    tables = _own_pages(kv_lens, bs, nb)
     q = jnp.asarray(rng.randn(R, C, hq, D).astype(np.float32))
     scale = 1.0 / np.sqrt(D)
     out = ragged_paged_attention(q, kp, vp, jnp.asarray(tables),
@@ -106,8 +127,86 @@ def test_ragged_kernel_matches_composed_reference(hq, hkv):
            / max(np.abs(ref).max(), 1e-9))
     assert rel <= 1e-2, rel  # acceptance: <=1e-2 rel (exceeds it: fp32)
     assert np.abs(np.asarray(out) - ref).max() < 1e-5
-    # empty row emits zeros
-    assert (np.asarray(out)[2] == 0).all()
+    # empty rows, and chunk positions past a row's q_len, emit zeros
+    for r in range(R):
+        assert (np.asarray(out)[r, q_lens[r]:] == 0).all()
+
+
+@pytest.mark.parametrize("hq,hkv", [(4, 4), (20, 4)])
+def test_ragged_kernel_arms_agree_on_a_decode_row(hq, hkv):
+    """The same decode rows through a C = 1 call (the burst passes' form:
+    one arm, an 8-row tile) and as q_len = 1 rows of a C = 128 call beside
+    a full chunk (pass 1: the narrow arm of a 128 * g-row tile) give the
+    same output."""
+    from paddle_tpu.kernels.pallas.ragged_paged_attention import (
+        ragged_paged_attention)
+    rng = np.random.RandomState(5)
+    R, C, D, bs, nb, NB = 4, 128, 16, 16, 9, 24
+    kp = jnp.asarray(rng.randn(hkv, NB, bs, D).astype(np.float32))
+    vp = jnp.asarray(rng.randn(hkv, NB, bs, D).astype(np.float32))
+    q_lens = np.array([1, 128, 0, 1], np.int32)
+    kv_lens = np.array([37, 140, 0, 16], np.int32)
+    tables = _own_pages(kv_lens, bs, nb)
+    q = jnp.asarray(rng.randn(R, C, hq, D).astype(np.float32))
+    scale = 1.0 / np.sqrt(D)
+    wide = np.asarray(ragged_paged_attention(
+        q, kp, vp, jnp.asarray(tables), jnp.asarray(q_lens),
+        jnp.asarray(kv_lens), scale))
+    decode = np.array([1, 0, 0, 1], np.int32)       # the chunk row sits out
+    one = np.asarray(ragged_paged_attention(
+        q[:, :1], kp, vp, jnp.asarray(tables), jnp.asarray(decode),
+        jnp.asarray(kv_lens * decode), scale))
+    for r in (0, 3):
+        np.testing.assert_allclose(wide[r, 0], one[r, 0], rtol=0,
+                                   atol=1e-6)
+    ref = _composed_reference(q, kp, vp, tables, q_lens, kv_lens, scale)
+    assert np.abs(wide - ref).max() < 1e-5
+
+
+@pytest.mark.parametrize("kv_dtype", ["int8", "fp8"])
+def test_ragged_kernel_quantized_scales_are_per_head(kv_dtype):
+    """A quantized pool whose (head, page) scales differ widely by head:
+    the kernel dequantizes each head's products with that head's scale (in
+    both arms, whole-pool form, layer > 0), and a scale taken from the
+    neighbouring head would miss by far more than the tolerance."""
+    from paddle_tpu.kernels.pallas.ragged_paged_attention import (
+        ragged_paged_attention)
+    rng = np.random.RandomState(9)
+    L, hq, hkv, NB, bs, D, R, C, nb = 2, 8, 4, 12, 8, 16, 4, 12, 3
+    if kv_dtype == "int8":
+        qmax, store = 127.0, jnp.int8
+        grid = rng.randint(-127, 128, (2, L, hkv, NB, bs, D))
+    else:
+        qmax, store = 448.0, jnp.float8_e4m3fn
+        grid = rng.uniform(-448, 448, (2, L, hkv, NB, bs, D))
+    kp, vp = (jnp.asarray(a, jnp.float32).astype(store) for a in grid)
+    per_head = 4.0 ** np.arange(hkv)                # 1, 4, 16, 64
+    ks = (rng.rand(L, hkv, NB) + 0.5) * per_head[None, :, None]
+    vs = (rng.rand(L, hkv, NB) + 0.5) * per_head[None, ::-1, None]
+    q_lens = np.array([1, 12, 0, 3], np.int32)      # narrow, wide, -, narrow
+    kv_lens = np.array([20, 12, 0, 9], np.int32)
+    tables = _own_pages(kv_lens, bs, nb)
+    q = jnp.asarray(rng.randn(R, C, hq, D).astype(np.float32))
+    scale = 1.0 / np.sqrt(D)
+    out = np.asarray(jax.jit(ragged_paged_attention, static_argnums=6)(
+        q, kp, vp, jnp.asarray(tables), jnp.asarray(q_lens),
+        jnp.asarray(kv_lens), scale, jnp.asarray(ks, jnp.float32),
+        jnp.asarray(vs, jnp.float32), jnp.int32(1)))
+
+    def reference(ks, vs):
+        kf = np.asarray(kp[1], np.float32) * ks[1][..., None, None] / qmax
+        vf = np.asarray(vp[1], np.float32) * vs[1][..., None, None] / qmax
+        return _composed_reference(q, kf, vf, tables, q_lens, kv_lens, scale)
+
+    ref = reference(ks, vs)
+    live = np.zeros(out.shape, bool)
+    for r in range(R):
+        live[r, :q_lens[r]] = True
+    tol = 1e-4 * np.abs(ref).max()
+    assert np.abs(out - ref)[live].max() < tol
+    for wrong in (reference(np.roll(ks, 1, axis=1), vs),
+                  reference(ks, np.roll(vs, 1, axis=1))):
+        assert np.abs(out - wrong)[live].max() > 100 * tol
 
 
 def test_ragged_kernel_bf16_rel_tolerance():
@@ -119,12 +218,7 @@ def test_ragged_kernel_bf16_rel_tolerance():
     vp = jnp.asarray(rng.randn(hkv, NB, bs, D)).astype(jnp.bfloat16)
     q_lens = np.array([1, 4, 2], np.int32)
     kv_lens = np.array([9, 12, 2], np.int32)
-    tables = np.zeros((R, nb), np.int32)
-    blk = 1
-    for r in range(R):
-        for j in range(-(-int(kv_lens[r]) // bs)):
-            tables[r, j] = blk
-            blk += 1
+    tables = _own_pages(kv_lens, bs, nb)
     q = jnp.asarray(rng.randn(R, C, hq, D)).astype(jnp.bfloat16)
     scale = 1.0 / np.sqrt(D)
     out = np.asarray(ragged_paged_attention(
