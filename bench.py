@@ -986,26 +986,17 @@ def _run_profile_attribution_config(jax, G, conf, iters=3):
 
 
 def _run_serving_config(jax, G):
-    """Serving engine comparison at serving_bench's chip scenario (the
-    64-request 125M-shape workload): the single-dispatch numbers the
-    standalone `benchmarks/serving_bench.py` measures."""
+    """The serving engine's sections at serving_bench's chip scenario
+    (the 125M-shape model), as the standalone
+    `benchmarks/serving_bench.py` measures them."""
     from benchmarks.serving_bench import (run_overload_comparison,
                                           run_prefix_spec_comparison,
-                                          run_router_comparison,
-                                          run_single_dispatch_comparison,
-                                          scenario)
+                                          run_router_comparison, scenario)
 
-    cfg, n_req, plens, out_hi, mk = scenario(True)
+    cfg, _, _, _, mk = scenario(True)
     params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
-    rng = np.random.RandomState(0)
-    prompts = [rng.randint(0, cfg.vocab_size, (int(rng.choice(plens)),))
-               for _ in range(n_req)]
-    news = rng.randint(8, out_hi + 1, (n_req,)).tolist()
-    report = run_single_dispatch_comparison(params, cfg, prompts, news,
-                                            mk, batch=8)
-    report["config"] = (f"{n_req} reqs, prompts {plens} mixed, outputs "
-                        f"U[8,{out_hi}], batch 8, chunk {mk['chunk']}, "
-                        f"decode burst {mk['decode_burst']}, fixed mix")
+    report = {"config": f"batch 8, chunk {mk['chunk']}, decode burst "
+                        f"{mk['decode_burst']}"}
     # ISSUE 13: offered load at ~2x measured capacity, shedding on vs
     # off — admitted p99 TTFT vs SLO, shed rate, goodput
     report["overload"] = run_overload_comparison(
@@ -1112,11 +1103,9 @@ def main():
     # HardwareProfile JSON `auto_tuner plan --profile` consumes
     out["profile_attribution"] = _run_profile_attribution_config(
         jax, G, planner_conf)
-    # single-dispatch ragged serving (FLAGS_serving_ragged): the unified
-    # prefill+decode engine vs the frozen two-program baseline — tokens/s,
-    # dispatches/step (the contract: halved, 1.0/step), latency
-    # percentiles, and the HBM bytes/decoded-token model the int8 KV
-    # pool halves (benchmarks/serving_bench.py owns the harness)
+    # the serving engine under overload, behind the router, and with
+    # prefix sharing and speculation (benchmarks/serving_bench.py owns
+    # the harness)
     out["serving"] = _run_serving_config(jax, G)
     print(json.dumps(out))
 

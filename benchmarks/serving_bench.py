@@ -1,10 +1,7 @@
 """Serving bench: continuous batching + chunked prefill vs static batching
-(VERDICT r2 #4, widened per r3 #8: >=64 requests, MIXED prompt lengths,
-adaptive decode bursts that free slots at the earliest finisher), plus —
-ISSUE 6 — the single-dispatch ragged engine vs the two-program baseline:
-per-request latency percentiles (p50/p95/p99), dispatches per engine
-step, and an analytic HBM bytes-per-decoded-token model (weights + KV
-pages read) that shows where the int8 KV pool halves the decode traffic.
+(VERDICT r2 #4, widened per r3 #8: >=64 requests, MIXED prompt lengths)
+with per-request latency percentiles (p50/p95/p99), plus the engine under
+overload, behind the router, and with prefix sharing and speculation.
 
 Workload: 64 requests, prompt lengths drawn from {32, 48, 64, 96}, ragged
 output lengths U[8, 96] — the variance that makes static batches idle at
@@ -12,10 +9,10 @@ the barrier. The static baseline is the STRONGEST version: requests
 bucketed by prompt length, each batch padded only to its own max.
 Model: GPT ~125M-shape (bf16 on TPU); `--shape gpt1p3b` runs the
 flagship 1.3B shape on-chip (VERDICT weak #2 — the regime where decode
-is genuinely weight-bound and int8 W8A8 shows its worth).
+is genuinely weight-bound).
 
-Run: `python benchmarks/serving_bench.py` — one JSON line. bench.py and
-the tier-1 smoke import `run_single_dispatch_comparison` directly.
+Run: `python benchmarks/serving_bench.py` — one JSON line. bench.py
+imports the `run_*` sections directly.
 """
 
 import json
@@ -43,126 +40,6 @@ def _pct(v, q):
 def _lat_stats(lat):
     return {"mean": round(float(np.mean(lat)), 3), "p50": _pct(lat, 50),
             "p95": _pct(lat, 95), "p99": _pct(lat, 99)}
-
-
-def _run_engine(make_engine, prompts, news, waves: int = 3):
-    """Steady-state timing: run the whole workload once on the engine to
-    compile every program shape the scheduler will ask for, then submit
-    the same workload `waves` more times and keep the BEST wave (compile
-    amortized — the regime a long-lived server lives in; each engine
-    owns fresh jit programs, so a fresh-engine timing would re-pay
-    compilation, and best-of-N damps host scheduling noise).
-    Returns (wall_s, per-request latency list, outputs, dispatches/step)."""
-    eng = make_engine()
-    for p, n in zip(prompts, news):
-        eng.add_request(p, n)
-    eng.run()  # warmup wave: compiles amortized before the timed waves
-    best = None
-    for _ in range(waves):
-        d0, s0 = eng.dispatches, eng.engine_steps
-        rids = [eng.add_request(p, n) for p, n in zip(prompts, news)]
-        done_at, outs = {}, {}
-        t0 = time.perf_counter()
-        while eng.has_work():
-            for r in eng.step():
-                done_at[r.rid] = time.perf_counter() - t0
-                outs[r.rid] = r.output
-        dt = time.perf_counter() - t0
-        wave = (dt, [done_at[rid] for rid in rids],
-                [outs[rid] for rid in rids],
-                (eng.dispatches - d0) / max(eng.engine_steps - s0, 1))
-        if best is None or dt < best[0]:
-            best = wave
-    return best
-
-
-def hbm_bytes_per_decoded_token(cfg, kv_itemsize, mean_ctx, decode_batch,
-                                block_size, param_bytes,
-                                kv_scales: bool = False):
-    """Analytic HBM traffic per decoded token: every decode microstep
-    streams the full weight set once (amortized over the co-scheduled
-    decode rows) plus each row's referenced KV pages — ceil(ctx/bs)
-    pages x bs rows x D x H_kv x 2 (k+v) x L at the pool itemsize (+4
-    bytes/page/head/side for the f32 scales of a quantized pool). This
-    is the model the int8 KV pool attacks: KV bytes halve vs bf16, and
-    capacity per pool byte doubles."""
-    D = cfg.head_dim
-    pages = -(-int(mean_ctx) // block_size)
-    kv = 2 * cfg.num_layers * cfg.num_heads * pages * block_size * D \
-        * kv_itemsize
-    if kv_scales:
-        kv += 2 * cfg.num_layers * cfg.num_heads * pages * 4
-    return {"weights": int(param_bytes // decode_batch),
-            "kv_read": int(kv),
-            "total": int(param_bytes // decode_batch + kv)}
-
-
-def run_single_dispatch_comparison(params, cfg, prompts, news, mk,
-                                   batch, int8_weights: bool = False):
-    """Ragged single-dispatch engine vs the frozen two-program baseline
-    on the SAME workload: tokens/s, dispatches/step, latency percentiles,
-    greedy-output parity, the int8-KV variant, and the bytes/token model
-    evaluated at this shape. Returns a JSON-ready dict."""
-    import jax
-    from paddle_tpu.inference.serving import ServingEngine
-
-    total_tokens = sum(news)
-    param_bytes = sum(np.dtype(v.dtype).itemsize * v.size
-                      for v in jax.tree.leaves(params))
-    if int8_weights:  # W8A8 storage ~1 byte/weight (+f32 per-out scales)
-        param_bytes = sum(v.size for v in jax.tree.leaves(params))
-
-    def mk_eng(**kw):
-        # fixed prefill/decode mix for an apples-to-apples dispatch
-        # comparison (the adaptive policy is exercised by tests); the
-        # token budget grants every slot a decode token PLUS a full
-        # prefill chunk — the same per-step work ceiling the two-program
-        # path's batched-prefill program has
-        def make():
-            return ServingEngine(params, cfg, max_batch=batch,
-                                 int8=int8_weights, adaptive_mix=False,
-                                 token_budget=batch * (1 + mk["chunk"]),
-                                 **mk, **kw)
-        return make
-
-    dt_two, lat_two, out_two, dps_two = _run_engine(
-        mk_eng(ragged=False), prompts, news)
-    dt_rag, lat_rag, out_rag, dps_rag = _run_engine(
-        mk_eng(ragged=True), prompts, news)
-    dt_q, lat_q, out_q, dps_q = _run_engine(
-        mk_eng(ragged=True, kv_cache_dtype="int8"), prompts, news)
-
-    mean_ctx = float(np.mean([len(p) + n for p, n in zip(prompts, news)]))
-    kv_item = np.dtype(cfg.dtype).itemsize
-    bytes_kv = hbm_bytes_per_decoded_token(
-        cfg, kv_item, mean_ctx, batch, mk["block_size"], param_bytes)
-    bytes_q = hbm_bytes_per_decoded_token(
-        cfg, 1, mean_ctx, batch, mk["block_size"], param_bytes,
-        kv_scales=True)
-    return {
-        "device": _device(),
-        "tokens_per_sec": {
-            "ragged": round(total_tokens / dt_rag, 1),
-            "two_program": round(total_tokens / dt_two, 1),
-            "ragged_int8_kv": round(total_tokens / dt_q, 1)},
-        "speedup_vs_two_program": round(dt_two / dt_rag, 2),
-        "dispatches_per_step": {
-            "ragged": round(dps_rag, 3), "two_program": round(dps_two, 3),
-            "ragged_int8_kv": round(dps_q, 3)},
-        "latency_s": {"ragged": _lat_stats(lat_rag),
-                      "two_program": _lat_stats(lat_two),
-                      "ragged_int8_kv": _lat_stats(lat_q)},
-        # greedy decode: the ragged program must reproduce the baseline
-        "outputs_match_two_program": out_rag == out_two,
-        "hbm_bytes_per_decoded_token": {
-            "model": f"weights/batch + 2*L*Hkv*ceil(ctx/bs)*bs*D*itemsize "
-                     f"@ mean_ctx {mean_ctx:.0f}, decode batch {batch}",
-            "kv_" + ("bf16" if kv_item == 2 else
-                     np.dtype(cfg.dtype).name): bytes_kv,
-            "kv_int8": bytes_q,
-            "kv_bytes_ratio_int8_vs_float":
-                round(bytes_q["kv_read"] / max(bytes_kv["kv_read"], 1), 3)},
-    }
 
 
 def run_overload_comparison(params, cfg, mk, batch, *, n_req: int = 64,
@@ -384,7 +261,7 @@ def run_prefix_spec_comparison(params, cfg, mk, batch, *, seed=0):
 
     def residency(share):
         eng = ServingEngine(
-            params, cfg, max_batch=slots, adaptive_mix=False, ragged=True,
+            params, cfg, max_batch=slots, adaptive_mix=False,
             block_size=bs, num_blocks=usable + 1,
             max_blocks_per_seq=mk["max_blocks_per_seq"], chunk=mk["chunk"],
             # burst=1 so a resident decodes across many engine steps —
@@ -419,7 +296,7 @@ def run_prefix_spec_comparison(params, cfg, mk, batch, *, seed=0):
 
     def mk_eng(k=0, proposer=None):
         return ServingEngine(
-            params, cfg, max_batch=batch, adaptive_mix=False, ragged=True,
+            params, cfg, max_batch=batch, adaptive_mix=False,
             block_size=bs, num_blocks=mk["num_blocks"],
             max_blocks_per_seq=mk["max_blocks_per_seq"], chunk=mk["chunk"],
             decode_burst=1, token_budget=batch * (1 + mk["chunk"]),
@@ -634,18 +511,11 @@ def main(big: bool = False, shape: str = "auto"):
             "static": _lat_stats(lat_s),
         },
         "config": f"{n_req} reqs, prompts {plens} mixed, outputs "
-                  f"U[8,{out_hi}], batch {batch}, BATCHED chunked "
-                  f"prefill {mk['chunk']} (all prefilling slots per "
-                  f"dispatch), decode bursts {mk['decode_burst']}, "
-                  "paged kernel decode, "
-                  "adaptive='auto'; static "
-                  "baseline bucketed by prompt length; latency = "
+                  f"U[8,{out_hi}], batch {batch}, chunked prefill "
+                  f"{mk['chunk']}, decode bursts up to "
+                  f"{mk['decode_burst']}, one ragged dispatch a step; "
+                  "static baseline bucketed by prompt length; latency = "
                   "submit-all-at-t0 to request completion",
-        # ISSUE 6: the single-dispatch ragged engine vs the two-program
-        # baseline on the same workload (+ the int8 KV pool variant)
-        "single_dispatch": run_single_dispatch_comparison(
-            params, cfg, prompts, news, mk, batch,
-            int8_weights=(shape == "gpt1p3b")),
         # ISSUE 13: offered load at ~2x capacity, shedding on vs off —
         # admitted-request TTFT percentiles, shed rate, goodput
         "overload": run_overload_comparison(
@@ -660,7 +530,7 @@ def main(big: bool = False, shape: str = "auto"):
         "prefix_spec": run_prefix_spec_comparison(params, cfg, mk, batch),
     }
     if shape == "gpt1p3b":
-        out["metric"] = "serving_single_dispatch_gpt1p3b"
+        out["metric"] += "_gpt1p3b"
     print(json.dumps(out))
 
 

@@ -7,7 +7,7 @@ weights random from a seed):
 
   train   G.init_hybrid_params -> jitted, donated G.dense_loss + AdamW step,
           5 steps on a seeded batch
-  serve   inference.ServingEngine(ragged=True) answering 8 seeded requests
+  serve   inference.ServingEngine answering 8 seeded requests
           of mixed prompt lengths, twice (fresh engine each time)
 
     python chip_smoke.py             # one chip: train + serve
@@ -194,12 +194,12 @@ def serve_phase(dev):
     rng = np.random.RandomState(SEED + 1)
     prompts = [rng.randint(0, cfg.vocab_size, (n,)) for n in PROMPT_LENS]
     _mem_line("serve params on device", [dev])
-    log(f"[serve] ServingEngine(ragged=True) {SERVE}, prompts "
+    log(f"[serve] ServingEngine {SERVE}, prompts "
         f"{PROMPT_LENS}, {NEW_TOKENS} new tokens each, greedy")
 
     def run_once(tag):
         t_build = time.perf_counter()
-        eng = ServingEngine(params, cfg, ragged=True, seed=SEED,
+        eng = ServingEngine(params, cfg, seed=SEED,
                             pool_audit=True, **SERVE)
         first = {}
 
@@ -218,8 +218,7 @@ def serve_phase(dev):
             f"{wall:.2f} s incl. compile ({n_tok / wall:.1f} tok/s), "
             f"engine build {t0 - t_build:.2f} s, TTFT min/median/max "
             f"{ttft[0]:.3f}/{ttft[len(ttft) // 2]:.3f}/{ttft[-1]:.3f} s, "
-            f"{eng.engine_steps} engine steps, {eng.dispatches} dispatches, "
-            f"adaptive_burst={eng.adaptive_burst}")
+            f"{eng.engine_steps} engine steps, {eng.dispatches} dispatches")
         assert all(res.statuses[r] == "ok" for r in rids), res.statuses
         for o in outs:
             assert len(o) == NEW_TOKENS, [len(x) for x in outs]
@@ -234,9 +233,8 @@ def serve_phase(dev):
         gc.collect()
         return outs
 
-    rtt = dispatch_rtt_s()
-    log(f"[serve] measured dispatch+fetch round trip {rtt * 1e3:.3f} ms -> "
-        f"adaptive_burst='auto' resolves to {rtt * 1e3 < 5.0}")
+    log(f"[serve] measured dispatch+fetch round trip "
+        f"{dispatch_rtt_s() * 1e3:.3f} ms")
     first_run = run_once("cold")
     second_run = run_once("repeat")
     assert first_run == second_run, "repeat run produced different tokens"

@@ -46,7 +46,7 @@ Sites currently planted (grep for ``maybe_fail`` /
   must rebuild + replay), and a ``hangN`` clause wedges the engine like
   a stuck device would
 * ``serving/dispatch``        — immediately before each compiled serving
-  program is invoked (prefill / decode burst / unified ragged step)
+  program is invoked (the unified ragged step)
 * ``router/dispatch``         — in the fleet router, immediately before a
   request is handed to the chosen replica: a ``raise`` clause makes that
   dispatch fail (the request requeues, the replica's consecutive-failure

@@ -700,20 +700,12 @@ define_flag("serving_decode_burst", 8,
             "(one host round trip per burst).")
 define_flag("serving_prefill_chunk", 32,
             "Chunked-prefill slice length in the serving engine.")
-define_flag("serving_ragged", False,
-            "Single-dispatch ragged serving: ServingEngine.step() packs "
-            "decode rows + prefill chunks into ONE ragged token batch "
-            "and runs ONE compiled program per step (unified Pallas "
-            "ragged-paged-attention kernel, in-program sampling + KV "
-            "append, fused decode burst). Off = the frozen two-program "
-            "baseline (bitwise-unchanged HLO).")
 define_flag("serving_kv_cache_dtype", "auto",
             "KV-pool storage dtype for the serving engine: auto (model "
             "compute dtype), bf16, f32, int8 or fp8_e4m3. Quantized "
             "pools (int8/fp8_e4m3) quantize on append with per-page "
             "scales and dequantize in-kernel — half the decode HBM "
-            "bytes, ~2x the sequences per pool byte budget; requires "
-            "the ragged path (serving_ragged).")
+            "bytes, ~2x the sequences per pool byte budget.")
 define_flag("serving_queue_max", 0,
             "Admission control for the serving engine: max requests "
             "waiting in the queue — arrivals beyond it are SHED at "
@@ -742,7 +734,7 @@ define_flag("serving_preempt", False,
             "behind a long decode (consumed by "
             "inference.serving.ServingEngine).")
 define_flag("serving_adaptive_mix", True,
-            "Adapt the per-step prefill/decode mix on the ragged path "
+            "Adapt the serving engine's per-step prefill/decode mix "
             "from the queue-depth and TTFT telemetry series: admission "
             "pressure shortens the fused decode burst so prefill slices "
             "come around sooner; an idle queue runs full bursts.")
@@ -766,8 +758,7 @@ define_flag("serving_spec_decode_k", 0,
             "inference.speculative.ngram_propose) for up to k draft "
             "tokens and ONE dispatch verifies the row with q_len=k+1 "
             "(the ragged kernel's per-row descriptors handle mixed "
-            "q_lens for free; the two-program path uses a dedicated "
-            "verify program). Exact-match acceptance under greedy keeps "
+            "q_lens for free). Exact-match acceptance under greedy keeps "
             "outputs bitwise identical to plain decode — only tokens/"
             "step changes; rejected draft KV rolls back via the block "
             "table. 0 = off, byte-identical step (consumed by "

@@ -1,11 +1,7 @@
-"""Single-dispatch ragged serving step (ISSUE 6 tentpole).
-
-The two-program engine path costs up to TWO compiled dispatches per step
-(a batched prefill chunk + a decode burst) plus a host fetch
-(serving.py module doc). This module is the fused alternative: ONE
-compiled program advances EVERY slot — decode rows and chunked-prefill
-rows ride one PACKED ragged token buffer with per-row ``(slot, q_len,
-kv_len)`` descriptors, so
+"""Single-dispatch ragged serving step (ISSUE 6 tentpole): the serving
+engine's one step (serving.py module doc). ONE compiled program advances
+EVERY slot — decode rows and chunked-prefill rows ride one PACKED ragged
+token buffer with per-row ``(slot, q_len, kv_len)`` descriptors, so
 
   * the QKV/projection/FFN GEMMs batch over ``sum(q_lens)`` real tokens
     (a decode row contributes 1 row of GEMM work, not a padded chunk);
@@ -17,8 +13,8 @@ kv_len)`` descriptors, so
     `quantization.kv_cache`);
   * sampling happens in-program at each row's last valid position, and a
     K-1-step decode-burst `lax.scan` continues freshly-sampled rows —
-    K tokens per dispatch, same amortization the two-program burst had,
-    now including the token that completes a prefill (better TTFT).
+    K tokens per dispatch, including the token that completes a prefill
+    (better TTFT).
 
 Layout contract (host side, `ServingEngine._step_ragged`): the packed
 buffer holds each active row's tokens contiguously at ``starts[r]``;

@@ -9,10 +9,8 @@ respawn it onto the same journal, and assert the resumed outputs are
 bitwise-identical to an uninterrupted run with exactly-once token
 delivery and zero leaked KV pages.
 
-Usage: ``python -m paddle_tpu.inference.replay_worker <workdir> [--two]``
-(``--two`` runs the two-program engine path; default is the
-single-dispatch ragged path). Crash points come from
-``FLAGS_fault_inject`` in the environment. Prints one
+Usage: ``python -m paddle_tpu.inference.replay_worker <workdir>``. Crash
+points come from ``FLAGS_fault_inject`` in the environment. Prints one
 ``RESULT {json}`` line: per-request outputs, the tokens delivered by
 THIS process, final pool accounting, statuses and rebuild count.
 """
@@ -44,7 +42,6 @@ def workload():
 
 def main(argv):
     workdir = argv[1]
-    ragged = "--two" not in argv[2:]
     from paddle_tpu.inference.resilient import run_serving_resilient
     from paddle_tpu.inference.serving import ServingEngine
 
@@ -53,7 +50,7 @@ def main(argv):
     def make_engine():
         return ServingEngine(params, cfg, max_batch=2, block_size=8,
                              num_blocks=24, max_blocks_per_seq=8, chunk=8,
-                             ragged=ragged, adaptive_mix=False)
+                             adaptive_mix=False)
 
     delivered_here = {i: [] for i in range(len(prompts))}
 

@@ -419,8 +419,8 @@ def refuse_cpu_children_on_tpu(what: str) -> None:
             "(paddle_tpu.inference.router.InProcessReplica).")
 
 
-def kill_replay_check(workdir: str, *, ragged: bool = False,
-                      timeout: float = 300.0) -> Dict[str, Any]:
+def kill_replay_check(workdir: str, *, timeout: float = 300.0
+                      ) -> Dict[str, Any]:
     """Hard-kill-and-replay acceptance (ISSUE 13): spawn the replay
     worker three times — an uninterrupted golden run, a run hard-killed
     by an armed ``serving/step:3:kill`` fault (os._exit, no cleanup), and
@@ -446,7 +446,7 @@ def kill_replay_check(workdir: str, *, ragged: bool = False,
         # count / multiprocess env
         env.pop("XLA_FLAGS", None)
         args = [sys.executable, "-m", "paddle_tpu.inference.replay_worker",
-                jdir] + ([] if ragged else ["--two"])
+                jdir]
         p = subprocess.Popen(args, env=env, stdout=subprocess.PIPE,
                              stderr=subprocess.PIPE, text=True)
         out, err = p.communicate(timeout=timeout)
@@ -508,5 +508,4 @@ def kill_replay_check(workdir: str, *, ragged: bool = False,
                                       for v in resumed["delivered"]
                                       .values()),
             "free_blocks": resumed["free_blocks"],
-            "pool_blocks": resumed["pool_blocks"],
-            "ragged": ragged}
+            "pool_blocks": resumed["pool_blocks"]}

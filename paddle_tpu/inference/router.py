@@ -265,12 +265,10 @@ class SpawnedReplica(_ReplicaBase):
 
     kind = "spawn"
 
-    def __init__(self, idx: int, workdir: str, *, two_program: bool = False,
-                 fault: str = ""):
+    def __init__(self, idx: int, workdir: str, *, fault: str = ""):
         self.rdir = os.path.join(workdir, f"replica{idx}")
         os.makedirs(self.rdir, exist_ok=True)
         super().__init__(idx, os.path.join(self.rdir, "journal.jsonl"))
-        self.two_program = two_program
         self._fault = fault  # armed for the FIRST spawn only
         self.gen = 0
         self.proc = None
@@ -302,8 +300,6 @@ class SpawnedReplica(_ReplicaBase):
         self._fault = ""  # a respawn must not re-arm the injected crash
         args = [sys.executable, "-m", "paddle_tpu.inference.router_worker",
                 self.rdir, "--gen", str(self.gen)]
-        if self.two_program:
-            args.append("--two")
         out = open(os.path.join(self.rdir, f"out.{self.gen}.log"), "w")
         err = open(os.path.join(self.rdir, f"err.{self.gen}.log"), "w")
         self.proc = subprocess.Popen(args, env=env, stdout=out, stderr=err)
@@ -464,11 +460,9 @@ class ReplicaSet:
 
     @classmethod
     def spawned(cls, workdir: str, n: int = 2, *,
-                two_program: bool = False,
                 faults: Optional[Dict[int, str]] = None) -> "ReplicaSet":
         faults = faults or {}
-        return cls([SpawnedReplica(i, workdir, two_program=two_program,
-                                   fault=faults.get(i, ""))
+        return cls([SpawnedReplica(i, workdir, fault=faults.get(i, ""))
                     for i in range(n)])
 
     def __len__(self):
@@ -897,8 +891,7 @@ class Router:
 
 
 # -- acceptance harnesses ----------------------------------------------------
-def router_failover_check(workdir: str, *, ragged: bool = False,
-                          n_replicas: int = 2,
+def router_failover_check(workdir: str, *, n_replicas: int = 2,
                           fault: str = "serving/step:5"
                           ) -> Dict[str, Any]:
     """In-process acceptance (tier-1 + dryrun leg): a 2-replica fleet,
@@ -924,8 +917,7 @@ def router_failover_check(workdir: str, *, ragged: bool = False,
         # full burst would finish the whole workload before it fires)
         return ServingEngine(params, cfg, max_batch=2, block_size=8,
                              num_blocks=24, max_blocks_per_seq=8, chunk=8,
-                             decode_burst=2, ragged=ragged,
-                             adaptive_mix=False)
+                             decode_burst=2, adaptive_mix=False)
 
     golden = {}
     for lid, (p, n) in enumerate(zip(prompts, news)):
@@ -982,11 +974,11 @@ def router_failover_check(workdir: str, *, ragged: bool = False,
             "tokens_pre_failover": tokens_at_failover or 0,
             "failovers": router.failovers, "requeued": router.requeues,
             "healthz_polls": healthz_polls,
-            "failed_replica": fo[0].get("replica"), "ragged": ragged}
+            "failed_replica": fo[0].get("replica")}
 
 
-def router_spawn_check(workdir: str, *, ragged: bool = False,
-                       timeout: float = 300.0) -> Dict[str, Any]:
+def router_spawn_check(workdir: str, *, timeout: float = 300.0
+                       ) -> Dict[str, Any]:
     """Cross-process acceptance (ISSUE 16 satellite): a 2-replica SPAWNED
     fleet, replica 0 hard-killed (``serving/step:3:kill`` — os._exit in
     the worker, a real crash) mid-generation. Every request must complete
@@ -1016,8 +1008,7 @@ def router_spawn_check(workdir: str, *, ragged: bool = False,
                + os.environ.get("PYTHONPATH", ""))
     env.pop("XLA_FLAGS", None)
     proc = subprocess.Popen(
-        [sys.executable, "-m", "paddle_tpu.inference.replay_worker",
-         g_dir] + ([] if ragged else ["--two"]),
+        [sys.executable, "-m", "paddle_tpu.inference.replay_worker", g_dir],
         env=env, stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True)
     g_out, g_err = proc.communicate(timeout=timeout)
     assert proc.returncode == 0, (proc.returncode, g_err)
@@ -1028,7 +1019,7 @@ def router_spawn_check(workdir: str, *, ragged: bool = False,
             golden = {int(k): v for k, v in rec["delivered"].items()}
     assert golden, ("no RESULT from golden run", g_out, g_err)
 
-    rs = ReplicaSet.spawned(workdir, n=2, two_program=not ragged,
+    rs = ReplicaSet.spawned(workdir, n=2,
                             faults={0: "serving/step:3:kill"})
     # generous heartbeat budget: this check asserts the SCRIPTED kill is
     # the death cause — on a loaded CI box a live worker can stall past
@@ -1113,5 +1104,4 @@ def router_spawn_check(workdir: str, *, ragged: bool = False,
             "failovers": router.failovers, "requeued": router.requeues,
             "healthz_polls": healthz_polls,
             "survivor_free_blocks": res1["free_blocks"],
-            "survivor_pool_blocks": res1["pool_blocks"],
-            "ragged": ragged}
+            "survivor_pool_blocks": res1["pool_blocks"]}

@@ -27,8 +27,7 @@ the spawn-leg acceptance kill). Exits printing one ``RESULT {json}``
 line: pool accounting (the zero-leak gate), per-lid delivery counts and
 statuses.
 
-Usage: ``python -m paddle_tpu.inference.router_worker <rdir> --gen N
-[--two]`` (``--two`` = frozen two-program engine path; default ragged).
+Usage: ``python -m paddle_tpu.inference.router_worker <rdir> --gen N``.
 """
 
 import json
@@ -55,7 +54,6 @@ def main(argv):
     gen = 1
     if "--gen" in argv:
         gen = int(argv[argv.index("--gen") + 1])
-    ragged = "--two" not in argv
 
     import numpy as np
     from paddle_tpu.flags import flag
@@ -72,7 +70,7 @@ def main(argv):
     # journaled (the spawn-leg acceptance needs a real partial prefix)
     eng = ServingEngine(params, cfg, max_batch=2, block_size=8,
                         num_blocks=24, max_blocks_per_seq=8, chunk=8,
-                        decode_burst=2, ragged=ragged, adaptive_mix=False)
+                        decode_burst=2, adaptive_mix=False)
     journal = ServingJournal(os.path.join(rdir, "journal.jsonl"))
     delivered = {}
 

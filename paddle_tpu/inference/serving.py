@@ -19,30 +19,21 @@ first-class (VERDICT r2 #4):
 * **Streaming** — each sampled token fires the request's callback
   immediately (detokenize hook).
 
-TPU shape discipline, two engine modes:
+TPU shape discipline: the engine has ONE step, the single-dispatch ragged
+one. Every step builds ONE packed ragged token batch (decode rows
+q_len=1, prefill chunks q_len≤chunk sharing a fixed token budget) and
+runs ONE compiled program — GEMMs batched over the real tokens, the
+unified ragged-paged-attention kernel, in-program sampling, prefill KV
+appended in-program, plus a K-1-step decode-burst scan
+(inference/ragged_step.py): one dispatch and one host fetch a step.
+Supports an int8 (or fp8-e4m3) KV pool — quantize-on-append per-page
+scales, dequantize in-kernel — so a fixed HBM budget admits ~2x the
+sequences (kv_cache_dtype / `kv_pool_bytes`), and an adaptive
+prefill/decode mix driven by the queue-depth and TTFT series the
+Prometheus registry already exports. All cache state is functional jax
+arrays threaded through the program.
 
-* **two-program path** (FLAGS_serving_ragged off — the frozen parity
-  baseline): a decode burst and a BATCHED prefill chunk covering every
-  prefilling slot at once, both static-shaped; each engine step costs at
-  most two dispatches + one host fetch. Decode attention is the
-  Pallas paged kernel (scalar-prefetch block tables).
-
-* **single-dispatch ragged path** (FLAGS_serving_ragged / ragged=True —
-  ISSUE 6): every step builds ONE packed ragged token batch (decode rows
-  q_len=1, prefill chunks q_len≤chunk sharing a fixed token budget) and
-  runs ONE compiled program — GEMMs batched over the real tokens, the
-  unified ragged-paged-attention kernel, in-program sampling, prefill KV
-  appended in-program, plus a K-1-step decode-burst scan
-  (inference/ragged_step.py). Supports an int8 (or fp8-e4m3) KV pool —
-  quantize-on-append per-page scales, dequantize in-kernel — so a fixed
-  HBM budget admits ~2x the sequences (kv_cache_dtype / `kv_pool_bytes`),
-  and an adaptive prefill/decode mix driven by the queue-depth and TTFT
-  series the Prometheus registry already exports.
-
-All cache state is functional jax arrays threaded through the programs;
-sampling happens in-program on both paths.
-
-The model behind the ragged path is a seam (`serving_model`, ISSUE 28):
+The model behind the step is a seam (`serving_model`, ISSUE 28):
 the GPT block's answers are `GPTServing`; a configuration of another
 architecture brings its own (``cfg.serving_model``, today
 `models.falcon_h1`: GQA attention with RoPE beside a Mamba-2 mixer in
@@ -50,9 +41,8 @@ every block). A model with a recurrent mixer gets a SECOND kind of
 per-request device state beside the KV pages: one recurrent state and
 one conv tail a SLOT, zeroed in-program when a row starts at position 0,
 released with the slot, rebuilt by re-prefill after a preemption. What
-the engine cannot give such a model yet (the two-program path, a mesh,
-int8 weights, prefix sharing, speculative decoding) raises at
-construction.
+the engine cannot give such a model yet (a mesh, int8 weights, prefix
+sharing, speculative decoding) raises at construction.
 
 Resilience layer (ISSUE 13) — all host-side scheduler state, no compiled
 program changes (flags-off the step behavior is byte-identical and the
@@ -101,7 +91,7 @@ from jax import lax
 
 from ..models import gpt as G
 from ..observability.trace import (SCOPES, SERVING_SPANS,
-                                   SSM_DISPATCH_ATTRS, TWO_PROGRAM_SPANS)
+                                   SSM_DISPATCH_ATTRS)
 from ..profiler.utils import RecordEvent
 
 __all__ = ["Request", "ServingEngine", "RunResult", "NonFiniteSampleError",
@@ -131,11 +121,6 @@ class NonFiniteSampleError(RuntimeError):
             "nonfinite/poisoned sampling state")
         self.rid = rid
         self.token = token
-
-
-def _dispatch_rtt_ms() -> float:
-    from ..utils.timing import dispatch_rtt_s
-    return dispatch_rtt_s() * 1e3
 
 
 def _faults():
@@ -252,7 +237,7 @@ def quantize_serving_params(params):
 
 @jax.named_scope(SCOPES.proj_mlp)
 def _block_math(p, x, attn, cfg, mp_axis=None):
-    """Post-attention half of the GPT block (shared by both programs).
+    """Post-attention half of the GPT block.
     mp_axis: Megatron TP inside shard_map — proj/fc2 are row-parallel
     (partial matmul + psum), fc1 column-parallel. Quantized row-parallel
     weights psum INSIDE qlinear (int32 accumulator — exact vs dense)."""
@@ -364,174 +349,6 @@ def serving_model(cfg):
     return getattr(cfg, "serving_model", GPTServing)
 
 
-@jax.named_scope(SCOPES.kv_write)
-def _write_token(pool, val, tables, lens, bs):
-    """Scatter one token's k or v ([B, H, D]) at each sequence's current
-    position (idle slots point at scratch block 0 — harmless)."""
-    B = val.shape[0]
-    blks = tables[jnp.arange(B), lens // bs]          # [B]
-    offs = lens % bs                                  # [B]
-    return pool.at[:, blks, offs].set(
-        jnp.moveaxis(val, 1, 0).astype(pool.dtype))   # [H, B, D] scatter
-
-
-def _decode_burst(params, tokens, k_pools, v_pools, tables, lens,
-                 remaining, eos_ids, temps, key, *, cfg, bs, K,
-                 mp_axis=None):
-    """K decode micro-steps in ONE compiled program with in-program
-    sampling — one host round trip per K tokens instead of per token
-    (K-1 fewer dispatches and fetches). tokens: [B] last
-    sampled token per slot; remaining: [B] tokens each slot may still
-    emit; eos_ids: [B] (-1 = none); temps: [B] (0 = greedy).
-    mp_axis: set when running inside shard_map — Megatron TP decode
-    (local heads, vocab-parallel head).
-    Returns (toks [K, B], k_pools', v_pools', lens')."""
-
-    def one_token(carry, kt):
-        tokens, k_pools, v_pools, lens, remaining, alive, key = carry
-        active = alive & (remaining > 0)
-        x = _embed(params, tokens[:, None], lens[:, None], cfg)
-
-        def body(x, layer):
-            p, kp, vp = layer
-            q, k, v = _qkv(p, x, cfg, mp_axis)
-            kp = _write_token(kp, k[:, 0], tables, lens, bs)
-            vp = _write_token(vp, v[:, 0], tables, lens, bs)
-            from ..kernels.pallas.paged_attention import (
-                paged_decode_attention)
-            attn = paged_decode_attention(
-                q[:, 0], kp, vp, tables, lens + 1,
-                1.0 / (cfg.head_dim ** 0.5))
-            x = _block_math(p, x, attn[:, None], cfg, mp_axis)
-            return x, (kp, vp)
-
-        x, (ks, vs) = lax.scan(body, x, (params["blocks"], k_pools,
-                                         v_pools))
-        x = G._ln(x, params["lnf_g"], params["lnf_b"])
-        logits = _head_logits(params, x[:, 0], cfg, mp_axis)
-        key, sub = jax.random.split(key)
-        tok = jnp.where(active, _sample(logits, temps, sub), 0)
-        lens = lens + active.astype(lens.dtype)
-        remaining = remaining - active.astype(remaining.dtype)
-        alive = alive & ~(active & (tok == eos_ids))
-        return (tok, ks, vs, lens, remaining, alive, key), tok
-
-    alive0 = jnp.ones(tokens.shape, bool)
-    (tokens, ks, vs, lens, remaining, alive, _), toks = lax.scan(
-        one_token,
-        (tokens, k_pools, v_pools, lens, remaining, alive0, key),
-        jnp.arange(K))
-    return toks, ks, vs, lens
-
-
-def _gather_seqs(pool, tables, bs):
-    """Every slot's K or V from the pool, position-contiguous:
-    [P, capacity, H, D] (tables: [P, max_blocks])."""
-    g = pool[:, tables]                               # [H, P, mb, bs, D]
-    H, P, mb, _, D = g.shape
-    return jnp.moveaxis(g.reshape(H, P, mb * bs, D), 0, 2)
-
-
-def _prefill_chunk(params, chunk_tokens, pos0, tables, last_idx, temps,
-                   key, k_pools, v_pools, *, cfg, bs, mp_axis=None):
-    """One `chunk`-token slice of EVERY prefilling slot's prompt in ONE
-    program (round 4 — the single-sequence version cost one host-driven
-    engine step per request per chunk, ~2x the request count in dispatch
-    round trips). chunk_tokens: [P, C] (pad tail rows attend but are
-    discarded; non-prefilling slots ride all-zero tables -> their writes
-    land in scratch block 0). pos0/last_idx/temps: [P]. Samples the
-    next token IN-PROGRAM from each slot's last valid row.
-    Returns (tok [P], k_pools', v_pools')."""
-    P, C = chunk_tokens.shape
-    pos = pos0[:, None] + jnp.arange(C)[None, :]      # [P, C]
-    x = _embed(params, chunk_tokens, pos, cfg)        # [P, C, H]
-
-    def body(x, layer):
-        p, kp, vp = layer
-        q, k, v = _qkv(p, x, cfg, mp_axis)            # [P, C, h_loc, D]
-        blks = jnp.take_along_axis(tables, pos // bs, axis=1)  # [P, C]
-        offs = pos % bs
-        h_loc, D = k.shape[2], k.shape[3]
-        kp = kp.at[:, blks.ravel(), offs.ravel()].set(
-            jnp.moveaxis(k.reshape(P * C, h_loc, D), 1, 0).astype(kp.dtype))
-        vp = vp.at[:, blks.ravel(), offs.ravel()].set(
-            jnp.moveaxis(v.reshape(P * C, h_loc, D), 1, 0).astype(vp.dtype))
-        # attend over [0, pos] — gather each slot's sequence (contiguous
-        # by construction) and mask per query row
-        ck = _gather_seqs(kp, tables, bs)             # [P, cap, H, D]
-        cv = _gather_seqs(vp, tables, bs)
-        cap = ck.shape[1]
-        allowed = (jnp.arange(cap)[None, None, :]
-                   <= pos[:, :, None])                # [P, C, cap]
-        from ..nn import functional as F
-        attn = F.scaled_dot_product_attention(
-            q, ck, cv, attn_mask=allowed[:, None])
-        x = _block_math(p, x, attn, cfg, mp_axis)
-        return x, (kp, vp)
-
-    x, (ks, vs) = lax.scan(body, x, (params["blocks"], k_pools, v_pools))
-    x = G._ln(x, params["lnf_g"], params["lnf_b"])
-    x_last = jnp.take_along_axis(
-        x, last_idx[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-    logits = _head_logits(params, x_last, cfg, mp_axis)  # [P, V]
-    return _sample(logits, temps, key), ks, vs
-
-
-def _verify_chunk(params, draft_tokens, pos0, q_lens, tables, temps,
-                  key, k_pools, v_pools, *, cfg, bs, mp_axis=None):
-    """Speculative verify on the two-program path: every decode slot's
-    [pending, draft_1..draft_k] row scores in ONE dispatch (ISSUE 17).
-    draft_tokens: [P, C] with per-row q_lens in [0, C] — NOT
-    _prefill_chunk, because pad columns here can sit past a row's
-    pre-allocated footprint, where the table lookup's index clamp would
-    alias them onto a REAL page; invalid columns instead write to the
-    reserved scratch block 0 (the ragged path's convention). Returns
-    (tok [P] sampled at each row's last valid column — the plain-decode
-    token for temperature > 0 rows — greedy [P, C] argmax at EVERY
-    column for host-side exact-match acceptance, k_pools', v_pools')."""
-    P, C = draft_tokens.shape
-    pos = pos0[:, None] + jnp.arange(C)[None, :]          # [P, C]
-    valid = jnp.arange(C)[None, :] < q_lens[:, None]      # [P, C]
-    x = _embed(params, draft_tokens, pos, cfg)            # [P, C, H]
-
-    def body(x, layer):
-        p, kp, vp = layer
-        q, k, v = _qkv(p, x, cfg, mp_axis)                # [P, C, h, D]
-        posb = jnp.clip(pos // bs, 0, tables.shape[1] - 1)
-        blks = jnp.where(valid, jnp.take_along_axis(tables, posb, axis=1),
-                         0)
-        offs = jnp.where(valid, pos % bs, 0)
-        h_loc, D = k.shape[2], k.shape[3]
-        kp = kp.at[:, blks.ravel(), offs.ravel()].set(
-            jnp.moveaxis(k.reshape(P * C, h_loc, D), 1, 0).astype(kp.dtype))
-        vp = vp.at[:, blks.ravel(), offs.ravel()].set(
-            jnp.moveaxis(v.reshape(P * C, h_loc, D), 1, 0).astype(vp.dtype))
-        ck = _gather_seqs(kp, tables, bs)                 # [P, cap, H, D]
-        cv = _gather_seqs(vp, tables, bs)
-        cap = ck.shape[1]
-        allowed = (jnp.arange(cap)[None, None, :]
-                   <= pos[:, :, None])                    # [P, C, cap]
-        from ..nn import functional as F
-        attn = F.scaled_dot_product_attention(
-            q, ck, cv, attn_mask=allowed[:, None])
-        x = _block_math(p, x, attn, cfg, mp_axis)
-        return x, (kp, vp)
-
-    x, (ks, vs) = lax.scan(body, x, (params["blocks"], k_pools, v_pools))
-    x = G._ln(x, params["lnf_g"], params["lnf_b"])
-    logits = _head_logits(params, x.reshape(P * C, -1), cfg, mp_axis)
-    logits = logits.reshape(P, C, -1)                     # [P, C, V]
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    last_idx = jnp.clip(q_lens - 1, 0, C - 1)
-    logits_last = jnp.take_along_axis(
-        logits, last_idx[:, None, None], axis=1)[:, 0]    # [P, V]
-    scaled = logits_last / jnp.maximum(temps, 1e-6)[:, None]
-    sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
-    g_last = jnp.take_along_axis(greedy, last_idx[:, None], axis=1)[:, 0]
-    tok = jnp.where(temps > 0, sampled, g_last)
-    return tok, greedy, ks, vs
-
-
 class ServingEngine:
     """Continuous-batching engine over a paged KV pool (see module doc)."""
 
@@ -539,8 +356,8 @@ class ServingEngine:
                  block_size: int = None, num_blocks: int = 256,
                  max_blocks_per_seq: int = 32, chunk: int = None,
                  decode_burst: int = None, seed: int = 0, mesh=None,
-                 mp_axis: str = "mp", adaptive_burst="auto",
-                 int8: bool = False, ragged=None, kv_cache_dtype=None,
+                 mp_axis: str = "mp", int8: bool = False, ragged=None,
+                 kv_cache_dtype=None,
                  kv_pool_bytes: Optional[int] = None,
                  token_budget: Optional[int] = None, adaptive_mix=None,
                  ttft_slo_s: Optional[float] = None, queue_max=None,
@@ -556,8 +373,14 @@ class ServingEngine:
                  else chunk)
         decode_burst = (int(flag("serving_decode_burst"))
                         if decode_burst is None else decode_burst)
-        if ragged is None or ragged == "auto":
-            ragged = bool(flag("serving_ragged"))
+        # `ragged` selects nothing: the benchmark's runners pass
+        # ragged=True and may not be edited by the PR that removed the
+        # other path; it goes once they drop the word (ROADMAP Queue 3)
+        enforce(ragged is None or ragged is True,
+                f"ragged={ragged!r}: the engine has one step, the "
+                "single-dispatch ragged one; the two-program path was "
+                "removed in PR 30. Pass ragged=True or leave it out",
+                op="ServingEngine")
         if kv_cache_dtype is None:
             kv_cache_dtype = str(flag("serving_kv_cache_dtype"))
         if adaptive_mix is None or adaptive_mix == "auto":
@@ -568,12 +391,6 @@ class ServingEngine:
             pool_dtype, kv_quantized = cfg.dtype, False
         else:
             pool_dtype, kv_quantized = _kv_dtype(kv_cache_dtype)
-        enforce(not kv_quantized or ragged,
-                "quantized KV pools (kv_cache_dtype="
-                f"{kv_cache_dtype!r}) need the single-dispatch ragged "
-                "path (ragged=True / FLAGS_serving_ragged) — the "
-                "two-program baseline kernels read float pools",
-                op="ServingEngine")
         L, D = cfg.num_layers, cfg.head_dim
         Hkv = getattr(cfg, "num_kv_heads", cfg.num_heads)
         self.model = serving_model(cfg)
@@ -590,8 +407,6 @@ class ServingEngine:
         # a model yet is refused here, not served some other way.
         if self.model.recurrent:
             for ok, what in (
-                    (ragged, "the two-program path (ragged=False) has no "
-                             "state in its programs"),
                     (mesh is None, "a mesh: the mixer is not sharded"),
                     (not int8, "int8 weights: the mixer's leaves have no "
                                "quantized form"),
@@ -615,12 +430,11 @@ class ServingEngine:
             # W8A8 decode: weights stored int8 with per-output-channel
             # scales; decode reads every weight per token, so halving the
             # bytes attacks its memory-bound cost directly. Under TP the
-            # scales shard with their weight's output channels (_init_tp).
+            # scales shard with their weight's output channels (_tp_shard).
             params = quantize_serving_params(params)
         self.params, self.cfg = params, cfg
         self.bs, self.chunk = block_size, chunk
         self.max_batch = max_batch
-        self.ragged = ragged
         self.kv_quantized = kv_quantized
         self.k_pools = jnp.zeros((L, Hkv, num_blocks, block_size, D),
                                  pool_dtype)
@@ -668,8 +482,6 @@ class ServingEngine:
         # copy-on-write pairs (src, dst) scheduled by admission and
         # executed IN-PROGRAM by the next dispatch (one-dispatch contract)
         self._cow_pairs: List = []
-        self._cow_jit = None
-        self._verify_prog = None
         self._reset_tables = np.zeros_like(self.tables)
         self.cow_copies = 0
         self.spec_proposed = 0
@@ -682,8 +494,8 @@ class ServingEngine:
         self._next_rid = 0
         self._key = jax.random.PRNGKey(seed)
         self.decode_burst = decode_burst
-        # ragged path: fixed per-step token budget shared by decode rows
-        # (1 each, always granted) and prefill chunks (the leftover)
+        # fixed per-step token budget shared by decode rows (1 each,
+        # always granted) and prefill chunks (the leftover)
         self.token_budget = (int(token_budget) if token_budget
                              else max_batch + chunk)
         enforce(self.token_budget >= max_batch,
@@ -721,23 +533,12 @@ class ServingEngine:
         # shortened bursts for the engine's whole life, and a p95 SLO is
         # what the fleet router will compare across replicas
         self._ttft_window = 16
-        # dispatch accounting (the ragged path's contract is ONE compiled
-        # dispatch per engine step; the bench reports dispatches/step)
+        # dispatch accounting (the contract is ONE compiled dispatch per
+        # engine step; the benchmark reports dispatches/step)
         self.dispatches = 0
         self.engine_steps = 0
         self._dispatches_reported = 0
         self._jit_programs: List = []
-        # adaptive bursts shorten to the earliest finisher so its slot
-        # re-admits sooner — a win ONLY when one dispatch + fetch costs
-        # less than a few decode steps; every shortened burst adds a
-        # round trip. "auto" measures that round trip once per process
-        # and enables adaptive bursts when it is under 5 ms; True/False
-        # force it either way. (The 5 ms line has not been re-measured
-        # on the current installation; chip_smoke.py prints the measured
-        # round trip and the decision.)
-        if adaptive_burst == "auto":
-            adaptive_burst = _dispatch_rtt_ms() < 5.0
-        self.adaptive_burst = adaptive_burst
         self.decode_microsteps = 0  # device decode steps issued (telemetry)
         self._pending_tok = np.zeros((max_batch,), np.int32)
         # -- observability: per-engine Prometheus registry (TTFT, tokens/s,
@@ -778,25 +579,9 @@ class ServingEngine:
         self._mp_axis = mp_axis if mesh is not None else None
         if mesh is not None:
             self._tp_shard(mesh, mp_axis)
-        if ragged:
-            # unified single-dispatch programs compile lazily per burst
-            # length K (only the sizes the scheduler asks for)
-            self._unified_cache = {}
-        elif mesh is None:
-            # decode programs per burst length (powers of two up to
-            # decode_burst; only the sizes the scheduler uses compile)
-            self._decode_k = {
-                k: jax.jit(functools.partial(_decode_burst, cfg=cfg,
-                                             bs=block_size, K=k),
-                           donate_argnums=(2, 3))
-                for k in self._burst_sizes(decode_burst)}
-            self._prefill = jax.jit(functools.partial(_prefill_chunk,
-                                                      cfg=cfg,
-                                                      bs=block_size),
-                                    donate_argnums=(7, 8))
-            self._jit_programs += [self._prefill, *self._decode_k.values()]
-        else:
-            self._init_tp(mesh, mp_axis, block_size, decode_burst)
+        # the unified programs compile lazily per burst length K (only
+        # the sizes the scheduler asks for)
+        self._unified_cache = {}
 
     @staticmethod
     def _burst_sizes(k_max):
@@ -862,61 +647,7 @@ class ServingEngine:
                                            NamedSharding(mesh, pool_spec))
         self._tp_pspec, self._tp_pool_spec = pspec, pool_spec
 
-    def _init_tp(self, mesh, mp_axis, block_size, decode_burst):
-        """Megatron-TP two-program path (VERDICT r3 #8): decode+prefill
-        wrapped in shard_map over the shardings _tp_shard placed — qkv
-        column-parallel (complete local heads), proj/fc2 row-parallel
-        with psum, vocab-parallel head with an all-gather of the tiny
-        [B, V] logits."""
-        from jax.sharding import PartitionSpec as P
-        from ..utils import shard_map
-        cfg = self.cfg
-        pspec, pool_spec = self._tp_pspec, self._tp_pool_spec
-        rep = P()
-
-        def mk_decode(k):
-            def fn(params, tokens, kp, vp, tables, lens, remaining,
-                   eos_ids, temps, key_data):
-                return _decode_burst(
-                    params, tokens, kp, vp, tables, lens, remaining,
-                    eos_ids, temps, jax.random.wrap_key_data(key_data),
-                    cfg=cfg, bs=block_size, K=k, mp_axis=mp_axis)
-            sm = shard_map(
-                fn, mesh=mesh,
-                in_specs=(pspec, rep, pool_spec, pool_spec, rep, rep, rep,
-                          rep, rep, rep),
-                out_specs=(rep, pool_spec, pool_spec, rep))
-            jfn = jax.jit(sm, donate_argnums=(2, 3))
-            self._jit_programs.append(jfn)
-            return (lambda params, tokens, kp, vp, tables, lens, remaining,
-                    eos_ids, temps, key: jfn(
-                        params, tokens, kp, vp, tables, lens, remaining,
-                        eos_ids, temps, jax.random.key_data(key)))
-
-        self._decode_k = {k: mk_decode(k)
-                          for k in self._burst_sizes(decode_burst)}
-
-        def prefill_fn(params, chunk_tokens, pos0, tables, last_idx, temps,
-                       key_data, kp, vp):
-            return _prefill_chunk(params, chunk_tokens, pos0, tables,
-                                  last_idx, temps,
-                                  jax.random.wrap_key_data(key_data),
-                                  kp, vp, cfg=cfg, bs=block_size,
-                                  mp_axis=mp_axis)
-
-        jpre = jax.jit(
-            shard_map(prefill_fn, mesh=mesh,
-                      in_specs=(pspec, rep, rep, rep, rep, rep, rep,
-                                pool_spec, pool_spec),
-                      out_specs=(rep, pool_spec, pool_spec)),
-            donate_argnums=(7, 8))
-        self._jit_programs.append(jpre)
-        self._prefill = (lambda params, buf, pos0, tables, last_idx, temps,
-                         key, kp, vp: jpre(
-                             params, buf, pos0, tables, last_idx, temps,
-                             jax.random.key_data(key), kp, vp))
-
-    # -- single-dispatch ragged path (ISSUE 6) -------------------------------
+    # -- the unified program (ISSUE 6) ---------------------------------------
     def _unified(self, K, spec=False):
         """The ONE compiled program for a ragged step with a K-token
         decode burst (lazily built per K — only scheduler-chosen sizes
@@ -980,8 +711,8 @@ class ServingEngine:
             self._jit_programs.append(jfn)
             return jfn
 
-        # TP: the unified program runs inside shard_map over the same
-        # shardings as the two-program path (pools/scales head-sharded,
+        # TP: the unified program runs inside shard_map over the
+        # shardings _tp_shard placed (pools/scales head-sharded,
         # descriptors replicated)
         from jax.sharding import PartitionSpec as P
         from ..utils import shard_map
@@ -1045,76 +776,11 @@ class ServingEngine:
                 return toks, kp, vp, None, None, lens
         return call
 
-    def _apply_cow(self):
-        """Two-program path: flush pending copy-on-write page copies as
-        one tiny dispatch BEFORE this step's prefill writes into the
-        copies (the ragged path instead folds the pairs into the unified
-        program — no extra dispatch there)."""
-        if not self._cow_pairs:
-            return
-        R = self.max_batch
-        src = np.zeros((R,), np.int32)
-        dst = np.zeros((R,), np.int32)
-        for j, (s, d) in enumerate(self._cow_pairs[:R]):
-            src[j], dst[j] = s, d
-        del self._cow_pairs[:R]
-        if self._cow_jit is None:
-            def fn(kp, vp, src, dst):
-                kp = kp.at[:, :, dst].set(kp[:, :, src])
-                vp = vp.at[:, :, dst].set(vp[:, :, src])
-                return kp, vp
-            self._cow_jit = jax.jit(fn, donate_argnums=(0, 1))
-            self._jit_programs.append(self._cow_jit)
-        self.dispatches += 1
-        with RecordEvent(TWO_PROGRAM_SPANS.cow):
-            self.k_pools, self.v_pools = self._cow_jit(
-                self.k_pools, self.v_pools, jnp.asarray(src),
-                jnp.asarray(dst))
-
-    def _verify(self):
-        """Lazily-built spec-verify program for the two-program path
-        (static [P, spec_k + 1] draft buffer; the ragged path needs no
-        extra program — verify rows are just q_len = k + 1 rows)."""
-        if self._verify_prog is None:
-            cfg, bsz = self.cfg, self.bs
-            mesh, ax = self._mesh, self._mp_axis
-            if mesh is None:
-                jfn = jax.jit(functools.partial(
-                    _verify_chunk, cfg=cfg, bs=bsz),
-                    donate_argnums=(7, 8))
-                self._jit_programs.append(jfn)
-                self._verify_prog = jfn
-            else:
-                from jax.sharding import PartitionSpec as P
-                from ..utils import shard_map
-                pspec, pool_spec = self._tp_pspec, self._tp_pool_spec
-                rep = P()
-
-                def fn(params, draft, pos0, q_lens, tables, temps,
-                       key_data, kp, vp):
-                    return _verify_chunk(
-                        params, draft, pos0, q_lens, tables, temps,
-                        jax.random.wrap_key_data(key_data), kp, vp,
-                        cfg=cfg, bs=bsz, mp_axis=ax)
-                jfn = jax.jit(
-                    shard_map(fn, mesh=mesh,
-                              in_specs=(pspec,) + (rep,) * 6
-                              + (pool_spec, pool_spec),
-                              out_specs=(rep, rep, pool_spec, pool_spec)),
-                    donate_argnums=(7, 8))
-                self._jit_programs.append(jfn)
-                self._verify_prog = (
-                    lambda params, draft, pos0, q_lens, tables, temps,
-                    key, kp, vp: jfn(params, draft, pos0, q_lens, tables,
-                                     temps, jax.random.key_data(key),
-                                     kp, vp))
-        return self._verify_prog
-
     def compiled_cache_entries(self) -> int:
         """Total traced-program cache entries across every jit program
-        this engine built — the ragged path's one-dispatch-per-step
-        contract is asserted against this in tests (and reported by the
-        serving bench)."""
+        this engine built — the one-dispatch-per-step contract is
+        asserted against this in tests (and reported by the serving
+        bench)."""
         return sum(f._cache_size() for f in self._jit_programs)
 
     def _pick_burst(self, n_prefilling: int) -> int:
@@ -1472,8 +1138,8 @@ class ServingEngine:
 
     def _admit(self) -> List[int]:
         """Admit queued requests into free slots while the pool has
-        pages; returns the freshly-admitted slot ids (the ragged path
-        resets those slots' page scales in-program). When free pages run
+        pages; returns the freshly-admitted slot ids (the step resets
+        those slots' page scales in-program). When free pages run
         out the head of the queue WAITS (no starvation) — unless
         ``preempt`` lets it evict a decode victim; a request that could
         never fit even in an empty pool is rejected PER-REQUEST
@@ -1828,20 +1494,18 @@ class ServingEngine:
                 or (r.eos_id is not None and tok == r.eos_id))
 
     def step(self) -> List[Request]:
-        """One engine iteration. Ragged path: admit -> ONE compiled
-        program (prefill chunks + decode burst fused over a packed
-        ragged batch). Two-program path: admit -> one prefill chunk ->
-        one decode burst. Returns every request that reached a TERMINAL
-        state this step — finished, plus deadline-shed/cancelled,
-        overload-shed, rejected, and submit-time sheds queued since the
-        last step (check ``Request.status``).
+        """One engine iteration: admit -> ONE compiled program (prefill
+        chunks + decode burst fused over a packed ragged batch). Returns
+        every request that reached a TERMINAL state this step —
+        finished, plus deadline-shed/cancelled, overload-shed, rejected,
+        and submit-time sheds queued since the last step (check
+        ``Request.status``).
 
         The whole step runs inside a ``serving_step`` RecordEvent span,
-        and the ragged path's host work inside it is covered, without
-        holes, by the child spans of ``observability.trace.SERVING_SPANS``
-        (sweep, admission, pack, upload, unified dispatch, fetch, walk,
-        metrics; the two-program path opens sweep and metrics and its own
-        per-program dispatch spans). Every span is a
+        and the host work inside it is covered, without holes, by the
+        child spans of ``observability.trace.SERVING_SPANS`` (sweep,
+        admission, pack, upload, unified dispatch, fetch, walk,
+        metrics). Every span is a
         ``jax.profiler.TraceAnnotation`` too, so serving lands on the SAME
         host timeline as training — Profiler summaries, chrome-trace
         exports, observability.capture_spans — and on the device trace's
@@ -1855,13 +1519,10 @@ class ServingEngine:
                 terminal = self._take_notifications()
                 terminal += self._expire()
                 terminal += self._shed_overload()
-            if self.ragged:
-                out = self._step_ragged()
-            else:
-                out = self._step_two_program()
+            out = self._step_ragged()
             if self._health == "loading":
                 self._health = "ready"
-            # admission-time rejections land in _notify DURING the path
+            # admission-time rejections land in _notify DURING the step
             # body — drain them now so a run that ends this step still
             # reports them
             return terminal + out + self._take_notifications()
@@ -1925,194 +1586,6 @@ class ServingEngine:
                                   "gen": self._numerics_kv_gen.copy()}
         self._numerics_kv_last = stats
         self._emit_event("numerics_kv", step=self.engine_steps, **stats)
-
-    def _step_two_program(self) -> List[Request]:
-        """The frozen parity baseline: one batched prefill-chunk dispatch
-        plus one decode-burst dispatch per step (flags-off compiles this
-        path unchanged — asserted bitwise in tests)."""
-        t_step0 = time.perf_counter()
-        if self._t_first_step is None:
-            self._t_first_step = t_step0
-        tokens_before = self._tokens_total
-        finished: List[Request] = []
-        self._admit()
-        self._apply_cow()
-        self._note_pool_peak()
-
-        # ---- one chunked-prefill slice for EVERY prefilling slot (one
-        # program, one dispatch — not one engine step per request)
-        pre = [r for r in self.slots
-               if r is not None and r.prefill_done < len(r.prompt)]
-        if pre:
-            P = self.max_batch
-            buf = np.zeros((P, self.chunk), np.int32)
-            pos0 = np.zeros((P,), np.int32)
-            tables_pre = np.zeros_like(self.tables)  # zeros -> scratch
-            last_idx = np.zeros((P,), np.int32)
-            temps = np.zeros((P,), np.float32)
-            his = {}
-            for r in pre:
-                i = r.slot
-                lo = r.prefill_done
-                hi = min(lo + self.chunk, len(r.prompt))
-                buf[i, : hi - lo] = r.prompt[lo:hi]
-                pos0[i] = lo
-                tables_pre[i] = self.tables[i]
-                last_idx[i] = hi - lo - 1  # last VALID prompt row
-                temps[i] = r.temperature
-                his[i] = hi
-            self._key, sub = jax.random.split(self._key)
-            self.dispatches += 1
-            with RecordEvent(TWO_PROGRAM_SPANS.prefill):
-                _faults().maybe_fail("serving/dispatch")
-                tok_dev, self.k_pools, self.v_pools = self._prefill(
-                    self.params, jnp.asarray(buf), jnp.asarray(pos0),
-                    jnp.asarray(tables_pre), jnp.asarray(last_idx),
-                    jnp.asarray(temps), sub, self.k_pools, self.v_pools)
-                completing = [r for r in pre
-                              if his[r.slot] >= len(r.prompt)]
-                # the fetch stays INSIDE the span: dispatch is async, the
-                # wall time lands here — a span around only the call
-                # would attribute prefill to nothing on the timeline
-                tok_np = np.asarray(tok_dev) if completing else None
-            for r in pre:
-                r.prefill_done = his[r.slot]
-                self.lens[r.slot] = his[r.slot]
-                self._register_pages(r)
-            for r in completing:
-                tok = self._check_tok(r, int(tok_np[r.slot]))
-                self._pending_tok[r.slot] = tok
-                if self._emit(r, tok):
-                    finished.append(r)
-                    self._finish(r)
-
-        # ---- one decode BURST for every slot in the decode phase
-        dec = [r for r in self.slots
-               if r is not None and r.prefill_done >= len(r.prompt)]
-        props_by_slot: Dict[int, List[int]] = {}
-        if dec and self.spec_k > 0:
-            for r in dec:
-                if r.temperature != 0:
-                    continue
-                cap = min(self.spec_k,
-                          r.max_new_tokens - len(r.output) - 1)
-                if cap <= 0:
-                    continue
-                ctx = np.concatenate(
-                    [np.asarray(r.prompt, np.int64),
-                     np.asarray(r.output[r.folded:], np.int64)])
-                props: List[int] = []
-                for t in self._proposer(ctx, cap)[:cap]:
-                    if not 0 <= int(t) < self.cfg.vocab_size:
-                        break  # defensive: never embed out-of-vocab
-                    props.append(int(t))
-                if props:
-                    props_by_slot[r.slot] = props
-        if props_by_slot:
-            # ---- speculative verify: ONE [P, k+1] dispatch replaces
-            # the decode burst; temperature > 0 rows ride it with
-            # q_len = 1 (their sampled token comes off the same pass)
-            P, C = self.max_batch, self.spec_k + 1
-            buf = np.zeros((P, C), np.int32)
-            pos0 = np.zeros((P,), np.int32)
-            q_lens = np.zeros((P,), np.int32)
-            tables_v = np.zeros_like(self.tables)
-            temps = np.zeros((P,), np.float32)
-            for r in dec:
-                i = r.slot
-                props = props_by_slot.get(i, [])
-                buf[i, 0] = self._pending_tok[i]
-                buf[i, 1:1 + len(props)] = props
-                pos0[i] = self.lens[i]
-                q_lens[i] = 1 + len(props)
-                tables_v[i] = self.tables[i]
-                temps[i] = r.temperature
-            self._key, sub = jax.random.split(self._key)
-            self.dispatches += 1
-            self.decode_microsteps += 1
-            with RecordEvent(TWO_PROGRAM_SPANS.verify):
-                _faults().maybe_fail("serving/dispatch")
-                tok_dev, greedy_dev, self.k_pools, self.v_pools = (
-                    self._verify()(self.params, jnp.asarray(buf),
-                                   jnp.asarray(pos0), jnp.asarray(q_lens),
-                                   jnp.asarray(tables_v),
-                                   jnp.asarray(temps), sub,
-                                   self.k_pools, self.v_pools))
-                tok_np, greedy_np = jax.device_get((tok_dev, greedy_dev))
-            for r in dec:
-                i = r.slot
-                props = props_by_slot.get(i, [])
-                acc = 0
-                for j, p in enumerate(props):
-                    if int(greedy_np[i, j]) != p:
-                        break
-                    acc += 1
-                if props:
-                    self.spec_proposed += len(props)
-                    self.spec_accepted += acc
-                # host-managed lens: the verified prefix commits, the
-                # rejected draft tail rolls back via the block table
-                self.lens[i] = int(pos0[i]) + acc + 1
-                if r.temperature == 0:
-                    emit = props[:acc] + [int(greedy_np[i, acc])]
-                else:
-                    emit = [int(tok_np[i])]
-                for tok in emit:
-                    tok = self._check_tok(r, tok)
-                    self._pending_tok[i] = tok
-                    if self._emit(r, tok):
-                        finished.append(r)
-                        self._finish(r)
-                        break
-            self._step_metrics(t_step0, tokens_before, len(pre),
-                               len(dec), finished)
-            return finished
-        if dec:
-            remaining = np.zeros((self.max_batch,), np.int32)
-            eos_ids = np.full((self.max_batch,), -1, np.int32)
-            temps = np.zeros((self.max_batch,), np.float32)
-            for r in dec:
-                remaining[r.slot] = r.max_new_tokens - len(r.output)
-                if r.eos_id is not None:
-                    eos_ids[r.slot] = r.eos_id
-                temps[r.slot] = r.temperature
-            self._key, sub = jax.random.split(self._key)
-            K = self.decode_burst
-            if self.adaptive_burst and self.queue:
-                # adaptive burst: end exactly when the earliest active
-                # request can finish, so its slot + blocks free for the
-                # waiting queue before the next burst (smallest compiled
-                # power-of-two burst that covers it)
-                min_rem = min(r.max_new_tokens - len(r.output) for r in dec)
-                for k in sorted(self._decode_k):
-                    if k >= min_rem:
-                        K = k
-                        break
-            self.decode_microsteps += K
-            self.dispatches += 1
-            with RecordEvent(TWO_PROGRAM_SPANS.decode):
-                _faults().maybe_fail("serving/dispatch")
-                toks, self.k_pools, self.v_pools, lens = self._decode_k[K](
-                    self.params, jnp.asarray(self._pending_tok),
-                    self.k_pools, self.v_pools, jnp.asarray(self.tables),
-                    jnp.asarray(self.lens), jnp.asarray(remaining),
-                    jnp.asarray(eos_ids), jnp.asarray(temps), sub)
-                toks = np.asarray(toks)      # [K, B] — ONE host fetch
-            self.lens = np.array(lens)
-            for r in dec:
-                for t in range(toks.shape[0]):
-                    if r.done:
-                        break
-                    tok = self._check_tok(r, int(toks[t, r.slot]))
-                    self._pending_tok[r.slot] = tok
-                    if self._emit(r, tok):
-                        finished.append(r)
-                        self._finish(r)
-                        break
-
-        self._step_metrics(t_step0, tokens_before, len(pre), len(dec),
-                           finished)
-        return finished
 
     def _check_tok(self, r: Request, tok: int) -> int:
         """Sampled-token sanity gate: an out-of-range token means the
@@ -2452,7 +1925,7 @@ class ServingEngine:
         prom.counter_inc("dispatches_total",
                          self.dispatches - self._dispatches_reported,
                          help="compiled-program dispatches issued (the "
-                              "ragged path's contract: one per step)")
+                              "contract: one per step)")
         self._dispatches_reported = self.dispatches
         prom.gauge_set("dispatches_per_step",
                        self.dispatches / max(self.engine_steps, 1),

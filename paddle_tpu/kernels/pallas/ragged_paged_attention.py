@@ -10,8 +10,8 @@ ONE compiled dispatch instead of a prefill program plus a decode program
 paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu).
 
 The kernel's cost follows ``(q_lens, kv_lens, tables)``, not the static
-shapes ``(R, H_kv, nb, C)`` (paged_attention.py stays as the decode-only
-baseline the two-program engine path compiles):
+shapes ``(R, H_kv, nb, C)`` (paged_attention.py is the decode-only
+kernel `models/generation.py` calls; the serving engine does not):
 
   * the pool is the serving engine's WHOLE buffer, layer-major then
     head-major: ``[L, H_kv, num_blocks, bs, D]`` (+ ``[L, H_kv,

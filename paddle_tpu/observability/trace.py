@@ -38,8 +38,7 @@ from typing import Iterable, Optional
 from ..profiler.utils import HostEvent, RecordEvent, collector
 
 __all__ = ["span", "capture_spans", "write_chrome_trace", "SERVING_SPANS",
-           "TWO_PROGRAM_SPANS", "DISPATCH_ATTRS", "SSM_DISPATCH_ATTRS",
-           "SCOPES", "KERNELS"]
+           "DISPATCH_ATTRS", "SSM_DISPATCH_ATTRS", "SCOPES", "KERNELS"]
 
 span = RecordEvent
 
@@ -49,9 +48,8 @@ def _names(typename, **names):
     return collections.namedtuple(typename, names)(**names)
 
 
-# One ragged engine step: `step` is the whole call, the others are its
-# children and cover it without holes (the two-program path opens `step`,
-# `sweep` and `metrics` and keeps its own dispatch spans).
+# One engine step: `step` is the whole call, the others are its children
+# and cover it without holes.
 SERVING_SPANS = _names(
     "ServingSpans",
     step="serving_step",            # the whole ServingEngine.step()
@@ -63,12 +61,6 @@ SERVING_SPANS = _names(
     fetch="serving_fetch",          # the host blocked on the device
     walk="serving_walk",            # lens, pages, acceptance, token walk
     metrics="serving_metrics")      # _step_metrics, _numerics_kv_poll
-
-# The two-program path's own dispatch spans, one a compiled program.
-TWO_PROGRAM_SPANS = _names(
-    "TwoProgramSpans",
-    cow="serving_cow_dispatch", prefill="serving_prefill_dispatch",
-    verify="serving_verify_dispatch", decode="serving_decode_dispatch")
 
 # Attributes of the dispatch span: the engine step's number, the burst
 # size, decode and prefill rows, packed query tokens, KV positions

@@ -1,4 +1,4 @@
-"""Host-side timing probes shared by the serving scheduler and benches."""
+"""Host-side timing probes for the smoke and the benches."""
 
 from __future__ import annotations
 
@@ -9,9 +9,8 @@ _RTT_S = None
 
 def dispatch_rtt_s() -> float:
     """Measured dispatch + scalar-fetch round trip on the default
-    device, cached for the process — the number that decides whether
-    chatty scheduling strategies (adaptive decode bursts, per-step
-    fetches) pay for themselves."""
+    device, cached for the process: what a synchronous host step pays
+    per dispatch whatever the program does."""
     global _RTT_S
     if _RTT_S is None:
         import jax.numpy as jnp

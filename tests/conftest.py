@@ -95,7 +95,6 @@ _SLOW_TESTS = {
     "test_two_process_pipeline_parity",
     "test_two_process_ring_attention_parity",
     "test_tp_sharded_decode_matches_generate",
-    "test_adaptive_burst_frees_slots_early",
     "test_static_batch_mixed_prompt_lengths",
     "test_flash_bias_grad_with_dropout_and_window",
     "test_flash_bias_grad_broadcast_shapes",
@@ -120,7 +119,6 @@ _SLOW_TESTS = {
     "test_fp8_kv_pool_runs",
     "test_page_scale_reset_on_block_reuse",
     "test_adaptive_mix_shortens_bursts_under_pressure",
-    "test_ragged_matches_two_program_outputs",
     "test_tp_int8_weights_match_dense_int8_exactly",
     "test_int8_kv_outputs_close_to_float",
     # round 7: elastic-reshard hybrid-engine legs — each builds 2-3 hybrid
@@ -132,13 +130,10 @@ _SLOW_TESTS = {
     "test_elastic_hybrid_fp8_carries_rescaled",
     "test_two_process_elastic_restart",
     "test_reshard_1b_checkpoint_throughput",
-    # round 8: serving-resilience heavies — the ragged kill-and-replay
-    # spawn (3 fresh processes each recompiling the interpret-mode
-    # unified program; the two-program spawn stays fast-tier) and the
-    # wall-clock overload/SLO acceptance (open-loop arrival schedule,
-    # ~30 s of timed waves). The fast tier keeps the deterministic
-    # deadline/shed/preempt/replay coverage on both engine paths.
-    "test_spawned_kill_and_replay_ragged",
+    # round 8: serving-resilience heavy — the wall-clock overload/SLO
+    # acceptance (open-loop arrival schedule, ~30 s of timed waves). The
+    # fast tier keeps the deterministic deadline/shed/preempt/replay
+    # coverage and the kill-and-replay spawn.
     "test_overload_shedding_preserves_admitted_slo",
     # round 9: ZeRO-stage heavies — the 50-step zero3 acceptance curve,
     # the 4-leg heavy compose matrix (ring/vpp/overlap/moe — each builds

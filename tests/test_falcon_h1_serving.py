@@ -58,7 +58,7 @@ M = dict(embedding_multiplier=5.656854249492381,
          ssm_multipliers=[0.7, 0.5, 0.6, 0.9, 0.7], ssm_out_multiplier=0.4,
          mlp_multipliers=[0.5, 0.3], lm_head_multiplier=2.0)
 LOGIT_ATOL = 4e-6
-ENGINE = dict(ragged=True, max_batch=4, block_size=8, num_blocks=40,
+ENGINE = dict(max_batch=4, block_size=8, num_blocks=40,
               max_blocks_per_seq=8, chunk=8, decode_burst=4)
 
 
@@ -259,7 +259,7 @@ def test_the_pool_is_sized_by_kv_heads_and_the_state_by_slots(params):
     gcfg = G.GPTConfig(vocab_size=64, hidden_size=32, num_layers=2,
                        num_heads=4, max_seq_len=64, dtype=jnp.float32)
     geng = ServingEngine(G.init_hybrid_params(gcfg, jax.random.PRNGKey(0)),
-                         gcfg, ragged=True, max_batch=2, block_size=8,
+                         gcfg, max_batch=2, block_size=8,
                          num_blocks=8, chunk=8)
     assert geng.ssm_state is None and geng.k_pools.shape[1] == 4
 
@@ -274,7 +274,7 @@ def test_positions_pass_no_table(params):
 
 
 @pytest.mark.parametrize("kw,word", [
-    (dict(ragged=False), "two-program"), (dict(int8=True), "int8"),
+    (dict(ragged=False), "PR 30"), (dict(int8=True), "int8"),
     (dict(prefix_share=True), "prefix_share"),
     (dict(spec_decode_k=2), "spec_decode_k"), (dict(mesh=True), "mesh"),
     (dict(chunk=16), "scan chunk")])

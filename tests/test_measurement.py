@@ -546,8 +546,7 @@ def test_merge_event_streams_training_plus_serving_engine(tmp_path):
                           num_heads=4, max_seq_len=64, dtype=jnp.float32)
         params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
         eng = ServingEngine(params, cfg, max_batch=2, block_size=16,
-                            num_blocks=16, chunk=8, decode_burst=2,
-                            adaptive_burst=False)
+                            num_blocks=16, chunk=8, decode_burst=2)
         eng.add_request(np.arange(4) % 64, max_new_tokens=3)
         eng.run(max_steps=20)
     finally:
@@ -569,15 +568,13 @@ def test_serving_steps_land_on_span_timeline(tmp_path):
                       num_heads=4, max_seq_len=64, dtype=jnp.float32)
     params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
     eng = ServingEngine(params, cfg, max_batch=2, block_size=16,
-                        num_blocks=16, chunk=8, decode_burst=2,
-                        adaptive_burst=False)
+                        num_blocks=16, chunk=8, decode_burst=2)
     eng.add_request(np.arange(4) % 64, max_new_tokens=3)
     with obs.capture_spans() as cap:
         eng.run(max_steps=20)
     names = {e.name for e in cap.events}
     assert "serving_step" in names
-    assert ("serving_decode_dispatch" in names
-            or "serving_prefill_dispatch" in names)
+    assert "serving_unified_dispatch" in names
     path = obs.write_chrome_trace(str(tmp_path / "t.json"), cap.events)
     trace = json.load(open(path))
     assert any(ev["name"] == "serving_step"

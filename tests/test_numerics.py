@@ -581,8 +581,7 @@ def test_serving_kv_scale_drift_gauges(tmp_path):
     try:
         eng = ServingEngine(params, scfg, max_batch=2, block_size=8,
                             num_blocks=24, max_blocks_per_seq=8, chunk=8,
-                            adaptive_mix=False, ragged=True,
-                            kv_cache_dtype="int8")
+                            adaptive_mix=False, kv_cache_dtype="int8")
         # long enough that generation spans several engine steps (the
         # fused burst emits ~8 tokens/step) so mid-run polls see LIVE
         # pages, then run past completion so the pool drains

@@ -2,9 +2,9 @@
 pool invariants (share, copy-on-write on divergence, free-at-zero,
 quantized-pool scale inheritance), n>1 fan-out sharing, the exact-match
 speculative verify path (bitwise-greedy under perfect / garbage / n-gram
-proposers, both engine paths), composition with deadlines, preemption,
-the crash-replay driver and the multi-replica router (zero leaked pages
-on failover), and the flags-off byte-identical-program contract.
+proposers), composition with deadlines, preemption, the crash-replay
+driver and the multi-replica router (zero leaked pages on failover), and
+the flags-off byte-identical-program contract.
 
 Every engine here runs with ``pool_audit=True``: the refcount /
 free-list / cached-free partition is re-verified on every slot release,
@@ -37,7 +37,7 @@ def params():
 def _restore_flags():
     keep = {k: flag(k) for k in ("serving_prefix_share",
                                  "serving_spec_decode_k",
-                                 "serving_pool_audit", "serving_ragged")}
+                                 "serving_pool_audit")}
     yield
     set_flags(keep)
     paddle.set_flags({"FLAGS_fault_inject": ""})
@@ -69,8 +69,7 @@ def drive(eng):
 # ---------------------------------------------------------------------------
 # refcounted pool: share, COW, free-at-zero
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("ragged", [False, True])
-def test_shared_system_prompt_pages_refcounted(params, ragged):
+def test_shared_system_prompt_pages_refcounted(params):
     """Three requests opening with the same 16-token (2-page) system
     prompt: after the first registers the pages, the others REFERENCE
     them (refcount > 1 observable mid-run), outputs stay golden, and the
@@ -81,7 +80,7 @@ def test_shared_system_prompt_pages_refcounted(params, ragged):
                for _ in range(3)]
     # burst=1: decode spans steps, so the shared refcounts are
     # observable at step boundaries (a full burst finishes in one)
-    eng = mk(params, ragged=ragged, max_batch=3, prefix_share=True,
+    eng = mk(params, max_batch=3, prefix_share=True,
              decode_burst=1)
     # prime: the first request registers the prompt's full pages
     r0 = eng.add_request(prompts[0], 4)
@@ -101,8 +100,7 @@ def test_shared_system_prompt_pages_refcounted(params, ragged):
     assert eng.load_stats()["kv_pages_shared"] == 0.0
 
 
-@pytest.mark.parametrize("ragged", [False, True])
-def test_fanout_identical_prompts_cow_on_divergence(params, ragged):
+def test_fanout_identical_prompts_cow_on_divergence(params):
     """n>1 fan-out: three IDENTICAL page-aligned prompts against a
     primed prefix cache. All three branches resume from the cached
     pages; the first claimant is the sole holder of the last page and
@@ -113,7 +111,7 @@ def test_fanout_identical_prompts_cow_on_divergence(params, ragged):
     rng = np.random.RandomState(1)
     prompt = rng.randint(0, 97, (16,))      # exactly 2 full pages
     g = golden(params, prompt, 6)
-    eng = mk(params, ragged=ragged, max_batch=3, prefix_share=True)
+    eng = mk(params, max_batch=3, prefix_share=True)
     r0 = eng.add_request(prompt, 6)
     assert eng.run()[r0] == g               # primes the 2-page cache
     rids = [eng.add_request(prompt, 6) for _ in range(3)]
@@ -131,7 +129,7 @@ def test_shared_pages_survive_first_finisher(params):
     common = rng.randint(0, 97, (16,))
     p_short = np.concatenate([common, rng.randint(0, 97, (4,))])
     p_long = np.concatenate([common, rng.randint(0, 97, (4,))])
-    eng = mk(params, ragged=True, max_batch=2, prefix_share=True,
+    eng = mk(params, max_batch=2, prefix_share=True,
              decode_burst=1)
     r0 = eng.add_request(p_short, 2)
     eng.run()
@@ -159,7 +157,7 @@ def test_prefix_cache_evicts_lru_under_pressure(params):
     workloads keep running golden through a pool sized below the total
     cache footprint, and nothing leaks."""
     rng = np.random.RandomState(3)
-    eng = mk(params, ragged=True, max_batch=1, num_blocks=9,
+    eng = mk(params, max_batch=1, num_blocks=9,
              prefix_share=True)
     for i in range(6):
         p = rng.randint(0, 97, (16,))       # 2 full pages cached each
@@ -179,7 +177,7 @@ def test_quantized_pool_sharing_and_cow_bitwise(params):
     news = [6, 5, 5]
 
     def run(share):
-        eng = mk(params, ragged=True, max_batch=2,
+        eng = mk(params, max_batch=2,
                  kv_cache_dtype="int8", prefix_share=share)
         r0 = eng.add_request(prompts[0], news[0])
         eng.run()
@@ -215,16 +213,15 @@ def _proposer_matrix(params, prompt, n):
                "ngram": ngram_propose}
 
 
-@pytest.mark.parametrize("ragged", [False, True])
 @pytest.mark.parametrize("kind", ["perfect", "garbage", "ngram"])
-def test_spec_greedy_bitwise_vs_plain(params, ragged, kind):
+def test_spec_greedy_bitwise_vs_plain(params, kind):
     """Exact-match acceptance makes the proposer a pure speed knob:
     brilliant, useless, or n-gram drafts all emit BITWISE the plain
-    greedy output, on both engine paths."""
+    greedy output."""
     rng = np.random.RandomState(5)
     prompt = rng.randint(0, 97, (9,))
     g, props = _proposer_matrix(params, prompt, 12)
-    eng = mk(params, ragged=ragged, spec_decode_k=3,
+    eng = mk(params, spec_decode_k=3,
              proposer=props[kind], decode_burst=1)
     rid = eng.add_request(prompt, 12)
     assert eng.run()[rid] == g
@@ -242,7 +239,7 @@ def test_spec_perfect_proposer_multiplies_tokens_per_step(params):
     g, props = _proposer_matrix(params, prompt, 16)
 
     def steps(**kw):
-        eng = mk(params, ragged=True, decode_burst=1, **kw)
+        eng = mk(params, decode_burst=1, **kw)
         rid = eng.add_request(prompt, 16)
         assert eng.run()[rid] == g
         return eng.engine_steps
@@ -258,7 +255,7 @@ def test_spec_replay_cache_proposer_accepts_repeat_traffic(params):
     rng = np.random.RandomState(7)
     prompts = [rng.randint(0, 97, (n,)) for n in (8, 11)]
     cache = ReplayCache()
-    eng = mk(params, ragged=True, spec_decode_k=3, proposer=cache,
+    eng = mk(params, spec_decode_k=3, proposer=cache,
              decode_burst=1)
     rids = [eng.add_request(p, 10) for p in prompts]
     res = eng.run()
@@ -277,7 +274,7 @@ def test_spec_one_dispatch_per_step_preserved(params):
     verify pass rides the ONE unified program (no extra dispatches), and
     every compiled entry is one of the engine's unified variants."""
     rng = np.random.RandomState(8)
-    eng = mk(params, ragged=True, spec_decode_k=3, decode_burst=1)
+    eng = mk(params, spec_decode_k=3, decode_burst=1)
     eng.add_request(rng.randint(0, 97, (9,)), 10)
     eng.run()
     assert eng.dispatches == eng.engine_steps > 0
@@ -288,7 +285,7 @@ def test_spec_counters_in_stats_and_metrics(params):
     rng = np.random.RandomState(9)
     # a constant proposer guarantees spec_proposed > 0 (n-gram on a
     # random prompt may legitimately never fire)
-    eng = mk(params, ragged=True, spec_decode_k=3, prefix_share=True,
+    eng = mk(params, spec_decode_k=3, prefix_share=True,
              decode_burst=1, proposer=lambda ctx, k: [1] * k)
     eng.add_request(rng.randint(0, 97, (9,)), 8)
     eng.run()
@@ -312,7 +309,7 @@ def test_spec_with_deadline_shed(params):
     speculatively to its golden."""
     rng = np.random.RandomState(10)
     p1, p2 = rng.randint(0, 97, (8,)), rng.randint(0, 97, (8,))
-    eng = mk(params, ragged=True, max_batch=1, spec_decode_k=3,
+    eng = mk(params, max_batch=1, spec_decode_k=3,
              decode_burst=1)
     r1 = eng.add_request(p1, 8)
     r2 = eng.add_request(p2, 8, deadline_s=0.0)
@@ -329,7 +326,7 @@ def test_spec_and_share_with_preempt_requeue(params):
     rng = np.random.RandomState(11)
     pv = rng.randint(0, 97, (8,))
     ph = rng.randint(0, 97, (8,))
-    eng = mk(params, ragged=True, max_batch=2, num_blocks=7,
+    eng = mk(params, max_batch=2, num_blocks=7,
              preempt=True, preempt_wait_steps=1, spec_decode_k=3,
              prefix_share=True, decode_burst=1)
     rv = eng.add_request(pv, 24)
@@ -358,7 +355,7 @@ def test_spec_and_share_with_crash_replay_bitwise(params):
              "on_token": lambda lid, t: seen[lid].append(t)}
             for p, n in zip(prompts, news)]
     results, info = run_serving_resilient(
-        lambda: mk(params, ragged=True, spec_decode_k=3,
+        lambda: mk(params, spec_decode_k=3,
                    prefix_share=True, decode_burst=1),
         reqs, retry_backoff_s=0.001)
     paddle.set_flags({"FLAGS_fault_inject": ""})
@@ -384,7 +381,7 @@ def test_router_failover_with_shared_pages_zero_leak(params):
                for i, (p, n) in enumerate(zip(prompts, news))}
 
     def make_engine():
-        return mk(params, ragged=True, decode_burst=2, prefix_share=True,
+        return mk(params, decode_burst=2, prefix_share=True,
                   spec_decode_k=2)
 
     router = Router(ReplicaSet.in_process(make_engine, n=2))
@@ -414,8 +411,8 @@ def test_flags_off_unified_program_byte_identical(params):
     tentpole is invisible until switched on."""
     assert flag("serving_prefix_share") is False
     assert int(flag("serving_spec_decode_k")) == 0
-    e_auto = mk(params, ragged=True)
-    e_off = mk(params, ragged=True, prefix_share=False, spec_decode_k=0)
+    e_auto = mk(params)
+    e_off = mk(params, prefix_share=False, spec_decode_k=0)
     assert e_auto.prefix_share is False and e_auto.spec_k == 0
     R, T = e_auto.max_batch, e_auto.token_budget
     nb = e_auto.tables.shape[1]
@@ -435,13 +432,13 @@ def test_flags_resolve_share_spec_audit(params):
                "serving_pool_audit": True})
     eng = ServingEngine(params, CFG, max_batch=2, block_size=8,
                         num_blocks=24, max_blocks_per_seq=8, chunk=8,
-                        adaptive_mix=False, ragged=True)
+                        adaptive_mix=False)
     assert eng.prefix_share is True
     assert eng.spec_k == 4
     assert eng.pool_audit is True
     set_flags({"serving_prefix_share": False, "serving_spec_decode_k": 0,
                "serving_pool_audit": False})
-    eng2 = mk(params, ragged=True, pool_audit=None)
+    eng2 = mk(params, pool_audit=None)
     assert eng2.prefix_share is False and eng2.spec_k == 0
     assert eng2.pool_audit is False
 
@@ -450,7 +447,7 @@ def test_pool_audit_detects_refcount_corruption(params):
     """The audit actually bites: a manufactured refcount mismatch fails
     the next release loudly instead of leaking."""
     rng = np.random.RandomState(14)
-    eng = mk(params, ragged=True, prefix_share=True)
+    eng = mk(params, prefix_share=True)
     eng.add_request(rng.randint(0, 97, (9,)), 4)
     eng.run()
     eng.refcount[3] += 1                    # corrupt

@@ -29,8 +29,7 @@ from paddle_tpu.models import gpt as G  # noqa: E402
 from paddle_tpu.models import falcon_h1 as FH  # noqa: E402
 from paddle_tpu.observability.trace import (DISPATCH_ATTRS, KERNELS,  # noqa: E402
                                             SCOPES, SERVING_SPANS,
-                                            SSM_DISPATCH_ATTRS,
-                                            TWO_PROGRAM_SPANS)
+                                            SSM_DISPATCH_ATTRS)
 from paddle_tpu.profiler.utils import RecordEvent, collector  # noqa: E402
 
 from chipbench import harness, program_trace  # noqa: E402
@@ -135,7 +134,7 @@ def _expected_dispatch(eng):
 def test_one_ragged_step_yields_every_serving_span_once():
     cfg = tiny_cfg()
     params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
-    eng = ServingEngine(params, cfg, ragged=True, max_batch=4,
+    eng = ServingEngine(params, cfg, max_batch=4,
                         block_size=16, num_blocks=16, chunk=8,
                         decode_burst=4)
     eng.add_request(np.arange(5) % 64, max_new_tokens=12)
@@ -184,7 +183,7 @@ def test_attn_pages_counts_the_row_page_pairs_of_a_hand_built_step():
     the kernel's (row, page) slots that carry work."""
     cfg = tiny_cfg()
     params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
-    eng = ServingEngine(params, cfg, ragged=True, max_batch=4,
+    eng = ServingEngine(params, cfg, max_batch=4,
                         block_size=16, num_blocks=16, chunk=8,
                         decode_burst=1)
     eng.add_request(np.arange(5) % 64, max_new_tokens=12)    # row A
@@ -201,31 +200,22 @@ def test_attn_pages_counts_the_row_page_pairs_of_a_hand_built_step():
     assert seen == [(12, 2), (21, 2), (27, 3), (29, 3)]
 
 
-def test_an_idle_step_and_the_two_program_path_keep_their_spans():
+def test_an_idle_step_keeps_its_spans():
     cfg = tiny_cfg()
     params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
-    eng = ServingEngine(params, cfg, ragged=True, max_batch=2,
+    eng = ServingEngine(params, cfg, max_batch=2,
                         block_size=16, num_blocks=16, chunk=8)
     with obs.capture_spans() as cap:
         eng.step()
     assert [e.name for e in cap.events] == [
         SERVING_SPANS.sweep, SERVING_SPANS.admission, SERVING_SPANS.pack,
         SERVING_SPANS.metrics, SERVING_SPANS.step]
-    two = ServingEngine(params, cfg, ragged=False, max_batch=2,
-                        block_size=16, num_blocks=16, chunk=8,
-                        decode_burst=2, adaptive_burst=False)
-    two.add_request(np.arange(4) % 64, max_new_tokens=3)
-    with obs.capture_spans() as cap:
-        two.run(max_steps=20)
-    names = {e.name for e in cap.events}
-    assert {SERVING_SPANS.step, SERVING_SPANS.sweep, SERVING_SPANS.metrics,
-            TWO_PROGRAM_SPANS.prefill, TWO_PROGRAM_SPANS.decode} <= names
 
 
 def test_the_two_unread_prom_counters_are_gone():
     cfg = tiny_cfg()
     params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
-    eng = ServingEngine(params, cfg, ragged=True, max_batch=2,
+    eng = ServingEngine(params, cfg, max_batch=2,
                         block_size=16, num_blocks=16, chunk=8)
     eng.add_request(np.arange(4) % 64, max_new_tokens=2)
     eng.run(max_steps=10)
@@ -288,7 +278,7 @@ def test_the_hybrid_step_carries_its_scopes_and_its_axes():
 def test_the_unified_serving_step_carries_its_scopes():
     cfg = tiny_cfg()
     params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
-    eng = ServingEngine(params, cfg, ragged=True, max_batch=2,
+    eng = ServingEngine(params, cfg, max_batch=2,
                         block_size=16, num_blocks=16, chunk=8,
                         decode_burst=4, prefix_share=True)
     eng.add_request(np.arange(6) % 64, max_new_tokens=8)
@@ -310,7 +300,7 @@ def test_the_hybrid_serving_step_carries_its_scopes_and_attributes():
         ssm_head_dim=8, ssm_groups=2, ssm_state=16, ssm_chunk=8,
         dtype=jnp.float32, param_dtype=jnp.float32)
     params = FH.init_params(cfg, jax.random.PRNGKey(0))
-    eng = ServingEngine(params, cfg, ragged=True, max_batch=2,
+    eng = ServingEngine(params, cfg, max_batch=2,
                         block_size=16, num_blocks=16, chunk=8,
                         decode_burst=4)
     eng.add_request(np.arange(6) % 64, max_new_tokens=8)
@@ -366,12 +356,10 @@ def test_no_scope_or_serving_span_is_a_free_string():
         REPO, "paddle_tpu", "inference", "serving.py")).read())
     spans = [n for n in ast.walk(serving) if isinstance(n, ast.Call)
              and isinstance(n.func, ast.Name) and n.func.id == "RecordEvent"]
-    assert len(spans) >= 12
+    assert len(spans) >= len(SERVING_SPANS)
     for call in spans:
         assert _from_tuple(call.args[0], "SERVING_SPANS",
-                           SERVING_SPANS._fields) or \
-            _from_tuple(call.args[0], "TWO_PROGRAM_SPANS",
-                        TWO_PROGRAM_SPANS._fields), call.lineno
+                           SERVING_SPANS._fields), call.lineno
 
 
 # -- the benchmark's readers ------------------------------------------------

@@ -1,8 +1,8 @@
 """Single-dispatch ragged serving (ISSUE 6): unified prefill+decode
 kernel parity vs the composed einsum path, the one-dispatch-per-step
-contract, flags-off bitwise baseline, pool-pressure scheduling, the
-quantized KV pool (capacity + determinism), TP int8 weights, and the
-telemetry-driven adaptive prefill/decode mix."""
+contract, the one engine however it is asked for, pool-pressure
+scheduling, the quantized KV pool (capacity + determinism), TP int8
+weights, and the telemetry-driven adaptive prefill/decode mix."""
 
 import jax
 import jax.numpy as jnp
@@ -25,7 +25,7 @@ def params():
 
 @pytest.fixture(autouse=True)
 def _restore_serving_flags():
-    keep = {k: flag(k) for k in ("serving_ragged", "serving_kv_cache_dtype",
+    keep = {k: flag(k) for k in ("serving_kv_cache_dtype",
                                  "serving_adaptive_mix")}
     yield
     set_flags(keep)
@@ -413,7 +413,7 @@ def test_step_writes_only_its_own_pages(params, kv_cache_dtype):
     block 0 — every other page of every layer is bit-identical
     afterwards. A row number that strays shows here."""
     rng = np.random.RandomState(12)
-    eng = mk(params, ragged=True, kv_cache_dtype=kv_cache_dtype)
+    eng = mk(params, kv_cache_dtype=kv_cache_dtype)
     shape = eng.k_pools.shape                   # [L, H, NB, bs, D]
     if kv_cache_dtype == "int8":
         noise = [rng.randint(-127, 128, shape).astype(np.int8)
@@ -454,7 +454,7 @@ def test_one_dispatch_per_step_and_program_cache(params):
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, CFG.vocab_size, (n,)) for n in (5, 13, 9, 16)]
     news = [6, 3, 9, 4]
-    eng = mk(params, ragged=True)
+    eng = mk(params)
     rids = [eng.add_request(p, n) for p, n in zip(prompts, news)]
     res = eng.run()
     # exactly ONE compiled dispatch per engine step
@@ -466,47 +466,22 @@ def test_one_dispatch_per_step_and_program_cache(params):
         assert res[rid] == golden(params, p, n), rid
 
 
-def test_two_program_path_dispatch_count(params):
+def test_the_engine_is_one_however_it_is_asked_for(params):
+    """`ragged` selects nothing (PR 30): left out, or True as the
+    benchmark's runners still pass it, the unified step lowers to the
+    same text; anything else raises at construction."""
     rng = np.random.RandomState(2)
-    eng = mk(params, ragged=False)
-    eng.add_request(rng.randint(0, CFG.vocab_size, (9,)), 6)
-    eng.run()
-    # the baseline really is the two-dispatch engine (prefill + decode
-    # steps overlap on the step a prompt completes)
-    assert eng.dispatches > eng.engine_steps
-
-
-def test_flags_off_engine_is_bitwise_two_program(params):
-    """FLAGS_serving_ragged off (default): the engine builds the
-    two-program path and compiles IDENTICAL HLO to an explicit
-    ragged=False engine — the same off-is-baseline pattern as
-    telemetry/mp_overlap."""
-    assert flag("serving_ragged") is False
-    e_auto = mk(params)             # flag-resolved
-    e_off = mk(params, ragged=False)
-    assert e_auto.ragged is False
-    P = e_auto.max_batch
-    key = jax.random.PRNGKey(0)
-    a_pre = (params, jnp.zeros((P, 8), jnp.int32),
-             jnp.zeros((P,), jnp.int32), jnp.zeros((P, 8), jnp.int32),
-             jnp.zeros((P,), jnp.int32), jnp.zeros((P,), jnp.float32),
-             key, e_auto.k_pools, e_auto.v_pools)
-    assert (e_auto._prefill.lower(*a_pre).as_text()
-            == e_off._prefill.lower(*a_pre).as_text())
-    a_dec = (params, jnp.zeros((P,), jnp.int32), e_auto.k_pools,
-             e_auto.v_pools, jnp.zeros((P, 8), jnp.int32),
-             jnp.zeros((P,), jnp.int32), jnp.zeros((P,), jnp.int32),
-             jnp.zeros((P,), jnp.int32), jnp.zeros((P,), jnp.float32), key)
-    assert (e_auto._decode_k[8].lower(*a_dec).as_text()
-            == e_off._decode_k[8].lower(*a_dec).as_text())
-
-
-def test_serving_ragged_flag_resolves(params):
-    set_flags({"serving_ragged": True})
-    eng = mk(params)
-    assert eng.ragged is True
-    set_flags({"serving_ragged": False})
-    assert mk(params).ragged is False
+    texts = []
+    for kw in ({}, {"ragged": True}):
+        eng = mk(params, **kw)
+        eng.add_request(rng.randint(0, CFG.vocab_size, (9,)), 6)
+        b = eng._pack_ragged(eng._admit())
+        texts.append(eng._build_unified(b.K).lower(
+            *eng._upload_ragged(b)).as_text())
+    assert texts[0] == texts[1]
+    for bad in (False, "auto", 0):
+        with pytest.raises(ValueError, match="PR 30"):
+            mk(params, ragged=bad)
 
 
 # ---------------------------------------------------------------------------
@@ -518,7 +493,7 @@ def test_ragged_streaming_and_eos(params):
     g = golden(params, prompt, 10)
     eos = g[3]
     seen = []
-    eng = mk(params, ragged=True, max_batch=1)
+    eng = mk(params, max_batch=1)
     rid = eng.add_request(prompt, 10, eos_id=eos,
                           on_token=lambda r, t: seen.append((r, t)))
     res = eng.run()
@@ -526,25 +501,10 @@ def test_ragged_streaming_and_eos(params):
     assert [t for _, t in seen] == res[rid]
 
 
-def test_ragged_matches_two_program_outputs(params):
-    rng = np.random.RandomState(5)
-    prompts = [rng.randint(0, CFG.vocab_size, (n,)) for n in (5, 13, 9, 16, 3)]
-    news = [6, 3, 9, 4, 8]
-
-    def run(ragged):
-        eng = mk(params, ragged=ragged)
-        rids = [eng.add_request(p, n) for p, n in zip(prompts, news)]
-        res = eng.run()
-        return [res[r] for r in rids]
-
-    assert run(True) == run(False)
-
-
 @pytest.mark.parametrize("seed", [0, 2 ** 31 - 1])
 def test_the_steps_one_call_key_split_is_the_eager_split(seed):
-    """The ragged step splits its key in one compiled call; the keys are
-    bit for bit those of `key, sub = jax.random.split(key)`, which the
-    two-program path still makes, so both paths sample alike."""
+    """The step splits its key in one compiled call; the keys are bit
+    for bit those of the eager `key, sub = jax.random.split(key)`."""
     from paddle_tpu.inference.serving import _split_key
     key = jax.random.PRNGKey(seed)
     for _ in range(3):
@@ -564,7 +524,7 @@ def test_admission_waits_when_pages_exhausted(params):
     # 9 blocks: scratch + 8 usable; each request needs 2 (8+4 over bs=8).
     # adaptive mix: under queue pressure bursts shorten, so no request
     # can finish inside step 1 — the full-pool wait is observable
-    eng = mk(params, ragged=True, max_batch=2, num_blocks=5,
+    eng = mk(params, max_batch=2, num_blocks=5,
              adaptive_mix=True)
     p1 = rng.randint(0, CFG.vocab_size, (8,))
     p2 = rng.randint(0, CFG.vocab_size, (8,))
@@ -585,7 +545,7 @@ def test_admission_waits_when_pages_exhausted(params):
 
 def test_blocks_freed_and_reused_after_finish(params):
     rng = np.random.RandomState(7)
-    eng = mk(params, ragged=True, num_blocks=9, max_blocks_per_seq=4)
+    eng = mk(params, num_blocks=9, max_blocks_per_seq=4)
     total_free = len(eng.free_blocks)
     prompts = [rng.randint(0, CFG.vocab_size, (8,)) for _ in range(6)]
     rids = [eng.add_request(p, 4) for p in prompts]
@@ -602,7 +562,7 @@ def test_request_larger_than_pool_refused(params):
     (ISSUE 13 satellite)."""
     rng = np.random.RandomState(22)
     sib = rng.randint(0, CFG.vocab_size, (8,))
-    eng = mk(params, ragged=True, num_blocks=3, max_blocks_per_seq=8)
+    eng = mk(params, num_blocks=3, max_blocks_per_seq=8)
     bad = eng.add_request(np.zeros(20, np.int32), 10)  # needs 4 > 2 usable
     good = eng.add_request(sib, 4)                     # needs 2: fits
     reported = {}
@@ -634,7 +594,7 @@ def test_int8_kv_admits_2x_sequences_at_fixed_budget():
     def admitted(kv):
         eng = ServingEngine(params, cfg, max_batch=16, block_size=16,
                             kv_pool_bytes=budget, max_blocks_per_seq=4,
-                            chunk=8, ragged=True, kv_cache_dtype=kv)
+                            chunk=8, kv_cache_dtype=kv)
         for _ in range(16):
             eng.add_request(rng.randint(0, cfg.vocab_size, (20,)), 8)
         eng._admit()
@@ -646,7 +606,7 @@ def test_int8_kv_admits_2x_sequences_at_fixed_budget():
 
 
 def _int8_run(params, prompts, news, kv):
-    eng = mk(params, ragged=True, kv_cache_dtype=kv)
+    eng = mk(params, kv_cache_dtype=kv)
     rids = [eng.add_request(p, n) for p, n in zip(prompts, news)]
     res = eng.run()
     return [res[r] for r in rids]
@@ -683,17 +643,12 @@ def test_int8_kv_outputs_close_to_float(params):
 def test_fp8_kv_pool_runs(params):
     rng = np.random.RandomState(10)
     prompt = rng.randint(0, CFG.vocab_size, (9,))
-    eng = mk(params, ragged=True, kv_cache_dtype="fp8_e4m3")
+    eng = mk(params, kv_cache_dtype="fp8_e4m3")
     rid = eng.add_request(prompt, 6)
     res = eng.run()
     g = golden(params, prompt, 6)
     assert len(res[rid]) == 6
     assert res[rid][0] == g[0]
-
-
-def test_quantized_kv_requires_ragged(params):
-    with pytest.raises(ValueError, match="ragged"):
-        mk(params, ragged=False, kv_cache_dtype="int8")
 
 
 def test_page_scale_reset_on_block_reuse(params):
@@ -703,12 +658,12 @@ def test_page_scale_reset_on_block_reuse(params):
     rng = np.random.RandomState(11)
     p1 = rng.randint(0, CFG.vocab_size, (8,))
     p2 = rng.randint(0, CFG.vocab_size, (8,))
-    eng = mk(params, ragged=True, kv_cache_dtype="int8", max_batch=1,
+    eng = mk(params, kv_cache_dtype="int8", max_batch=1,
              num_blocks=5)
     r1 = eng.add_request(p1, 4)
     r2 = eng.add_request(p2, 4)   # reuses r1's freed blocks
     res = eng.run()
-    clean = mk(params, ragged=True, kv_cache_dtype="int8", max_batch=1,
+    clean = mk(params, kv_cache_dtype="int8", max_batch=1,
                num_blocks=5)
     rc = clean.add_request(p2, 4)
     assert clean.run()[rc] == res[r2], (res[r1], res[r2])
@@ -726,7 +681,7 @@ def test_tp_ragged_matches_generate(params):
     rng = np.random.RandomState(12)
     prompts = [rng.randint(0, CFG.vocab_size, (n,)) for n in (9, 14, 5)]
     news = [6, 4, 8]
-    eng = mk(params, ragged=True, mesh=_mesh4())
+    eng = mk(params, mesh=_mesh4())
     rids = [eng.add_request(p, n) for p, n in zip(prompts, news)]
     res = eng.run()
     assert eng.dispatches == eng.engine_steps
@@ -736,21 +691,20 @@ def test_tp_ragged_matches_generate(params):
 
 def test_tp_int8_weights_parity_smoke(params):
     """Fast-tier satellite gate: int8 W8A8 weights under TP reproduce
-    the dense int8 engine exactly on the ragged path (one request; the
-    multi-request / two-program matrix runs in the slow tier)."""
+    the dense int8 engine exactly (one request; the multi-request run
+    is in the slow tier)."""
     rng = np.random.RandomState(18)
     prompt = rng.randint(0, CFG.vocab_size, (9,))
 
     def run(mesh):
-        eng = mk(params, int8=True, ragged=True, mesh=mesh)
+        eng = mk(params, int8=True, mesh=mesh)
         rid = eng.add_request(prompt, 5)
         return eng.run()[rid]
 
     assert run(None) == run(_mesh4())
 
 
-@pytest.mark.parametrize("ragged", [False, True])
-def test_tp_int8_weights_match_dense_int8_exactly(params, ragged):
+def test_tp_int8_weights_match_dense_int8_exactly(params):
     """Satellite: int8 weights under TP serving — per-output-channel
     scales shard with the weight shards; the row-parallel sites share
     the activation scale (pmax) and psum the INT32 accumulator, so the
@@ -760,7 +714,7 @@ def test_tp_int8_weights_match_dense_int8_exactly(params, ragged):
     news = [6, 5, 7]
 
     def run(mesh):
-        eng = mk(params, int8=True, ragged=ragged, mesh=mesh)
+        eng = mk(params, int8=True, mesh=mesh)
         rids = [eng.add_request(p, n) for p, n in zip(prompts, news)]
         res = eng.run()
         return [res[r] for r in rids]
@@ -772,9 +726,9 @@ def test_tp_int8_kv_pool(params):
     """int8 KV + TP compose on the ragged path (scales head-sharded)."""
     rng = np.random.RandomState(14)
     prompt = rng.randint(0, CFG.vocab_size, (9,))
-    dense = mk(params, ragged=True, kv_cache_dtype="int8")
+    dense = mk(params, kv_cache_dtype="int8")
     rd = dense.add_request(prompt, 6)
-    tp = mk(params, ragged=True, kv_cache_dtype="int8", mesh=_mesh4())
+    tp = mk(params, kv_cache_dtype="int8", mesh=_mesh4())
     rt = tp.add_request(prompt, 6)
     assert dense.run()[rd] == tp.run()[rt]
 
@@ -788,7 +742,7 @@ def test_adaptive_mix_shortens_bursts_under_pressure(params):
     news = [8] * 6
 
     def mean_burst(adaptive):
-        eng = mk(params, ragged=True, decode_burst=8,
+        eng = mk(params, decode_burst=8,
                  adaptive_mix=adaptive)
         rids = [eng.add_request(p, n) for p, n in zip(prompts, news)]
         res = eng.run()
@@ -802,7 +756,7 @@ def test_adaptive_mix_shortens_bursts_under_pressure(params):
 
 def test_adaptive_mix_full_burst_when_idle(params):
     rng = np.random.RandomState(16)
-    eng = mk(params, ragged=True, max_batch=2, decode_burst=8,
+    eng = mk(params, max_batch=2, decode_burst=8,
              adaptive_mix=True)
     prompt = rng.randint(0, CFG.vocab_size, (5,))
     rid = eng.add_request(prompt, 9)
@@ -814,52 +768,9 @@ def test_adaptive_mix_full_burst_when_idle(params):
     assert eng.decode_microsteps >= 8
 
 
-# ---------------------------------------------------------------------------
-# serving_bench CPU smoke (the tier-1 row: single-dispatch acceptance)
-# ---------------------------------------------------------------------------
-def test_serving_bench_cpu_smoke_single_dispatch():
-    """Acceptance (ISSUE 6): the serving_bench CPU smoke shows ragged
-    tokens/s no worse than the two-dispatch baseline with dispatches per
-    step halved (best-of-3 steady-state waves damp host noise), greedy
-    outputs identical, and the bytes/token model halving KV traffic."""
-    from benchmarks.serving_bench import (run_single_dispatch_comparison,
-                                          scenario)
-    cfg, n_req, plens, out_hi, mk = scenario(on_tpu=False)
-    bp = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
-    rng = np.random.RandomState(0)
-    prompts = [rng.randint(0, cfg.vocab_size, (int(rng.choice(plens)),))
-               for _ in range(n_req)]
-    news = rng.randint(8, out_hi + 1, (n_req,)).tolist()
-    # throughput comparisons on a shared CI host are noisy even with
-    # best-of-3 steady-state waves (CPU ratios near 1.0x on a quiet
-    # box, with occasional ~10% swings under load): one
-    # explicit retry before judging, and a 10% band on the float-pool
-    # ratio. The bands still trip on any structural regression — the
-    # pre-fix fresh-engine methodology measured 0.33x
-    for attempt in range(2):
-        r = run_single_dispatch_comparison(bp, cfg, prompts, news, mk,
-                                           batch=8)
-        tps = r["tokens_per_sec"]
-        if (tps["ragged"] >= 0.9 * tps["two_program"]
-                and tps["ragged_int8_kv"] >= 1.5 * tps["two_program"]):
-            break
-    # a CPU run: counts and parity, labelled as what it is
-    assert r["device"]["platform"] == "cpu"
-    dps = r["dispatches_per_step"]
-    assert dps["ragged"] == 1.0, dps
-    assert dps["two_program"] >= 1.5, dps  # the two-dispatch baseline
-    assert r["outputs_match_two_program"]
-    assert tps["ragged"] >= 0.9 * tps["two_program"], tps
-    # the int8-KV pool's bytes win is far outside noise (3.9-4.4x here:
-    # the scan carries 4x fewer pool bytes per micro-step)
-    assert tps["ragged_int8_kv"] >= 1.5 * tps["two_program"], tps
-    bpt = r["hbm_bytes_per_decoded_token"]
-    assert bpt["kv_int8"]["kv_read"] * 2 <= bpt["kv_float32"]["kv_read"]
-
-
 def test_dispatch_metrics_exported(params):
     rng = np.random.RandomState(17)
-    eng = mk(params, ragged=True)
+    eng = mk(params)
     eng.add_request(rng.randint(0, CFG.vocab_size, (5,)), 4)
     eng.run()
     text = eng.metrics_text()

@@ -70,13 +70,12 @@ def drive(eng):
 # ---------------------------------------------------------------------------
 # deadlines + cancellation
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("ragged", [False, True])
-def test_deadline_sheds_stale_queued(params, ragged):
+def test_deadline_sheds_stale_queued(params):
     """An expired deadline sheds a QUEUED request before it ever runs;
     the sibling is untouched and completes its golden output."""
     rng = np.random.RandomState(0)
     p1, p2 = rng.randint(0, 97, (9,)), rng.randint(0, 97, (8,))
-    eng = mk(params, ragged=ragged, max_batch=1)
+    eng = mk(params, max_batch=1)
     r1 = eng.add_request(p1, 5)
     r2 = eng.add_request(p2, 4, deadline_s=0.0)  # expired on arrival
     rep = drive(eng)
@@ -87,13 +86,12 @@ def test_deadline_sheds_stale_queued(params, ragged):
     assert eng.prom.get("requests_shed_total") == 1.0
 
 
-@pytest.mark.parametrize("ragged", [False, True])
-def test_deadline_cancels_inflight_and_frees_pages(params, ragged):
+def test_deadline_cancels_inflight_and_frees_pages(params):
     """Deadline expiry MID-GENERATION cancels the request: partial output
     kept, pages freed and re-admittable (no leak)."""
     rng = np.random.RandomState(1)
     prompt = rng.randint(0, 97, (8,))
-    eng = mk(params, ragged=ragged, max_batch=1)
+    eng = mk(params, max_batch=1)
     free0 = len(eng.free_blocks)
     rid = eng.add_request(prompt, 40, deadline_s=3600.0)
     # run until it has emitted at least one token, then force expiry
@@ -200,8 +198,7 @@ def test_no_shed_below_slo(params):
 # ---------------------------------------------------------------------------
 # preempt-and-requeue
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("ragged", [False, True])
-def test_preempt_decode_victim_and_requeue(params, ragged):
+def test_preempt_decode_victim_and_requeue(params):
     """Pool exhaustion with an urgent head: the decode victim is evicted
     (pages freed), re-enqueued with its emitted prefix, and BOTH requests
     finish with greedy outputs identical to their goldens (preempted
@@ -209,7 +206,7 @@ def test_preempt_decode_victim_and_requeue(params, ragged):
     rng = np.random.RandomState(7)
     pv = rng.randint(0, 97, (8,))       # victim: long decode, 4 blocks
     ph = rng.randint(0, 97, (8,))       # head: also needs 4 blocks
-    eng = mk(params, ragged=ragged, max_batch=2, num_blocks=7,
+    eng = mk(params, max_batch=2, num_blocks=7,
              preempt=True, preempt_wait_steps=1)
     free0 = len(eng.free_blocks)        # 6 usable
     rv = eng.add_request(pv, 24)        # (8+24)/8 = 4 blocks
@@ -241,15 +238,14 @@ def test_preempt_off_head_waits(params):
 # ---------------------------------------------------------------------------
 # satellite hardening: callback errors, leftover reporting
 # ---------------------------------------------------------------------------
-@pytest.mark.parametrize("ragged", [False, True])
-def test_on_token_callback_error_fails_only_that_request(params, ragged):
+def test_on_token_callback_error_fails_only_that_request(params):
     rng = np.random.RandomState(9)
     p1, p2 = rng.randint(0, 97, (9,)), rng.randint(0, 97, (8,))
 
     def boom(rid, tok):
         raise RuntimeError("user callback bug")
 
-    eng = mk(params, ragged=ragged)
+    eng = mk(params)
     free0 = len(eng.free_blocks)
     r1 = eng.add_request(p1, 6, on_token=boom)
     r2 = eng.add_request(p2, 5)
@@ -366,8 +362,7 @@ def _workload(rng_seed=0, n=4):
     return prompts, news
 
 
-@pytest.mark.parametrize("ragged", [False, True])
-def test_rebuild_and_replay_bitwise_exactly_once(params, ragged):
+def test_rebuild_and_replay_bitwise_exactly_once(params):
     """An injected step failure mid-workload: the driver rebuilds the
     engine, replays prompt+prefix, and greedy outputs are BITWISE equal
     to the uninterrupted run with every on_token delivered exactly once
@@ -380,7 +375,7 @@ def test_rebuild_and_replay_bitwise_exactly_once(params, ragged):
              "on_token": lambda lid, t: seen[lid].append(t)}
             for p, n in zip(prompts, news)]
     results, info = run_serving_resilient(
-        lambda: mk(params, ragged=ragged), reqs, retry_backoff_s=0.001)
+        lambda: mk(params), reqs, retry_backoff_s=0.001)
     paddle.set_flags({"FLAGS_fault_inject": ""})
     assert info["rebuilds"] == 1
     assert [results[i] for i in range(4)] == goldens
@@ -491,14 +486,7 @@ def test_spawned_kill_and_replay_bitwise(params, tmp_path):
     (os._exit — a real crash), respawned onto the same journal; outputs
     bitwise-identical to the uninterrupted spawn, exactly-once delivery
     across the process boundary, zero leaked KV pages."""
-    out = kill_replay_check(str(tmp_path), ragged=False)
-    assert out["tokens_pre_kill"] > 0
-    assert out["free_blocks"] == out["pool_blocks"]
-
-
-def test_spawned_kill_and_replay_ragged(params, tmp_path):
-    """The same acceptance on the single-dispatch ragged path."""
-    out = kill_replay_check(str(tmp_path), ragged=True)
+    out = kill_replay_check(str(tmp_path))
     assert out["tokens_pre_kill"] > 0
     assert out["free_blocks"] == out["pool_blocks"]
 
@@ -683,20 +671,16 @@ def test_flags_off_engine_is_bitwise_inert(params):
     e_def = mk(params)
     e_res = mk(params, queue_max=8, shed=True, preempt=True,
                ttft_slo_s=0.5)
-    P = e_def.max_batch
-    key = jax.random.PRNGKey(0)
-    a_pre = (params, jnp.zeros((P, 8), jnp.int32),
-             jnp.zeros((P,), jnp.int32), jnp.zeros((P, 8), jnp.int32),
-             jnp.zeros((P,), jnp.int32), jnp.zeros((P,), jnp.float32),
-             key, e_def.k_pools, e_def.v_pools)
-    assert (e_def._prefill.lower(*a_pre).as_text()
-            == e_res._prefill.lower(*a_pre).as_text())
-    a_dec = (params, jnp.zeros((P,), jnp.int32), e_def.k_pools,
-             e_def.v_pools, jnp.zeros((P, 8), jnp.int32),
-             jnp.zeros((P,), jnp.int32), jnp.zeros((P,), jnp.int32),
-             jnp.zeros((P,), jnp.int32), jnp.zeros((P,), jnp.float32), key)
-    assert (e_def._decode_k[8].lower(*a_dec).as_text()
-            == e_res._decode_k[8].lower(*a_dec).as_text())
+    rng = np.random.RandomState(14)
+    texts = []
+    for eng in (e_def, e_res):
+        eng.add_request(rng.randint(0, 97, (9,)), 4)
+        b = eng._pack_ragged(eng._admit())
+        texts.append(eng._build_unified(b.K).lower(
+            *eng._upload_ragged(b)).as_text())
+        eng.cancel_all()
+        eng.step()      # reports the cancellation; both engines start clean
+    assert texts[0] == texts[1]
     # byte-identical step behavior: same workload, same outputs, and the
     # resilience-enabled engine (nothing triggering) changes nothing
     rng = np.random.RandomState(15)
