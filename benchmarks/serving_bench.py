@@ -279,7 +279,9 @@ def run_prefix_spec_comparison(params, cfg, mk, batch, *, seed=0):
         while eng.has_work():
             for r in eng.step():
                 outs[r.rid] = r.output
-            peak = max(peak, sum(1 for s in eng.slots if s is not None))
+            # the engine's own gauge: reading `slots` would settle the
+            # step in flight every iteration
+            peak = max(peak, int(eng.prom.get("running_requests") or 0))
             shared_peak = max(shared_peak, int((eng.refcount > 1).sum()))
         return (peak, shared_peak, [outs[rid] for rid in rids],
                 usable - eng.free_pages())

@@ -16,7 +16,7 @@ token buffer with per-row ``(slot, q_len, kv_len)`` descriptors, so
     K tokens per dispatch, including the token that completes a prefill
     (better TTFT).
 
-Layout contract (host side, `ServingEngine._step_ragged`): the packed
+Layout contract (host side, `ServingEngine._pack_ragged`): the packed
 buffer holds each active row's tokens contiguously at ``starts[r]``;
 ``row_of/off_of`` map packed positions back to (row, chunk offset) and
 tail padding points past every row's ``q_len`` (masked everywhere).
@@ -156,8 +156,8 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
 
 
 def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
-                 tables, fresh, sample0, remaining, eos_ids, temps, key,
-                 kp, vp, ks, vs, cow_src=None, cow_dst=None,
+                 tables, fresh, sample0, remaining, eos_ids, temps,
+                 prev_tok, key, kp, vp, ks, vs, cow_src=None, cow_dst=None,
                  reset_tables=None, ssm_state=None, conv_tail=None, *, cfg,
                  bs, c_att, K, spec=False, mp_axis=None):
     """ONE compiled program per engine step: the ragged pass (prefill
@@ -169,6 +169,19 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
     this step); remaining: [R] tokens each row may still emit INCLUDING
     pass-1's (0 for mid-prefill rows); eos_ids: [R] (-1 = none);
     temps: [R] (0 = greedy).
+
+    The next token stays on the device (ISSUE 31): prev_tok [R] is the
+    last token each slot emitted, as the previous call returned it
+    (``last_tok``), never fetched. A packed position whose ``tokens``
+    entry is negative (the host's sentinel, -1: "this row's input is
+    what the step before sampled, and I have not seen it yet") takes
+    ``prev_tok[row_of]``; every other position keeps the token the host
+    wrote (prompt tokens, a first decode step after a settle, drafts),
+    so the same program serves a step packed behind one still in flight
+    and one packed from settled state. ``last_tok`` comes back after
+    ``lens``: per row the last token it emitted in this call (pass 1, or
+    the last burst pass it was active in), else its ``prev_tok`` carried
+    through. Both are R int32s that never leave the device.
 
     Prefix sharing (ISSUE 17) appends three OPTIONAL trailing args so the
     flags-off trace — and hence the compiled HLO — is byte-identical:
@@ -187,12 +200,13 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
     written in place by the mixer's kernels; a row whose pass starts at
     position 0 starts from zeros. Both come back after ``lens``.
 
-    Returns (toks [K, R], kp, vp, ks, vs, lens [R]); with ``spec=True``
-    (K must be 1) the return gains ``greedy_all [T]`` after toks — the
-    model's argmax at every packed position, from which the host accepts
-    the longest exactly-matching draft prefix."""
+    Returns (toks [K, R], kp, vp, ks, vs, lens [R], last_tok [R]); with
+    ``spec=True`` (K must be 1) the return gains ``greedy_all [T]`` after
+    toks — the model's argmax at every packed position, from which the
+    host accepts the longest exactly-matching draft prefix."""
     assert not (spec and K > 1), "spec verify subsumes the burst"
     R = pos0.shape[0]
+    tokens = jnp.where(tokens < 0, prev_tok[row_of], tokens)
     quantized = ks is not None
     if quantized:
         rt = tables if reset_tables is None else reset_tables
@@ -222,6 +236,7 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
     else:
         tok0, (kp, vp, ks, vs, ssm) = out
     tok0 = jnp.where(sample0, tok0, 0)
+    last_tok = jnp.where(sample0, tok0, prev_tok)
     lens = pos0 + q_lens
     rem = remaining - sample0.astype(remaining.dtype)
     alive = sample0 & ~(tok0 == eos_ids)
@@ -229,7 +244,7 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
     zero = jnp.zeros((R,), jnp.int32)
 
     def micro(carry, _):
-        tok, kp, vp, ks, vs, ssm, lens, rem, alive, key = carry
+        tok, last, kp, vp, ks, vs, ssm, lens, rem, alive, key = carry
         active = alive & (rem > 0)
         ql = active.astype(jnp.int32)
         key, sub = jax.random.split(key)
@@ -237,21 +252,23 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
             params, tok, ar, zero, ar, lens, ql, tables, temps, sub,
             kp, vp, ks, vs, ssm, cfg=cfg, bs=bs, c_att=1, mp_axis=mp_axis)
         tok2 = jnp.where(active, tok2, 0)
+        last = jnp.where(active, tok2, last)
         lens = lens + ql
         rem = rem - ql
         alive = alive & ~(active & (tok2 == eos_ids))
-        return (tok2, kp, vp, ks, vs, ssm, lens, rem, alive, key), tok2
+        return (tok2, last, kp, vp, ks, vs, ssm, lens, rem, alive,
+                key), tok2
 
     if K > 1:
-        carry = (tok0, kp, vp, ks, vs, ssm, lens, rem, alive, key)
+        carry = (tok0, last_tok, kp, vp, ks, vs, ssm, lens, rem, alive, key)
         with jax.named_scope(SCOPES.burst):
-            (_, kp, vp, ks, vs, ssm, lens, _, _, _), toks = lax.scan(
-                micro, carry, jnp.arange(K - 1))
+            (_, last_tok, kp, vp, ks, vs, ssm, lens, _, _, _), toks = \
+                lax.scan(micro, carry, jnp.arange(K - 1))
         all_toks = jnp.concatenate([tok0[None], toks], axis=0)
     else:
         all_toks = tok0[None]
     if spec:
-        return all_toks, greedy_all, kp, vp, ks, vs, lens
+        return all_toks, greedy_all, kp, vp, ks, vs, lens, last_tok
     if ssm is not None:
-        return (all_toks, kp, vp, ks, vs, lens) + tuple(ssm)
-    return all_toks, kp, vp, ks, vs, lens
+        return (all_toks, kp, vp, ks, vs, lens, last_tok) + tuple(ssm)
+    return all_toks, kp, vp, ks, vs, lens, last_tok

@@ -423,7 +423,7 @@ def kill_replay_check(workdir: str, *, timeout: float = 300.0
                       ) -> Dict[str, Any]:
     """Hard-kill-and-replay acceptance (ISSUE 13): spawn the replay
     worker three times — an uninterrupted golden run, a run hard-killed
-    by an armed ``serving/step:3:kill`` fault (os._exit, no cleanup), and
+    by an armed ``serving/step:4:kill`` fault (os._exit, no cleanup), and
     a respawn onto the SAME journal. Asserts the resumed outputs are
     bitwise-identical to the golden run, every token was delivered
     exactly once across the two processes, and the final engine leaked
@@ -473,7 +473,7 @@ def kill_replay_check(workdir: str, *, timeout: float = 300.0
     golden = result(out)
     assert golden["rebuilds"] == 0
 
-    rc, out_k, err_k = spawn(k_dir, fault="serving/step:3:kill")
+    rc, out_k, err_k = spawn(k_dir, fault="serving/step:4:kill")
     assert rc == FAULT_EXIT_CODE, (rc, out_k, err_k)
     pre = {}  # tokens the killed process delivered before dying
     with open(os.path.join(k_dir, "journal.jsonl")) as f:

@@ -33,6 +33,28 @@ prefill/decode mix driven by the queue-depth and TTFT series the
 Prometheus registry already exports. All cache state is functional jax
 arrays threaded through the program.
 
+ONE STEP IN FLIGHT (ISSUE 31): a call of `step()` admits, packs, uploads
+and dispatches step n+1 FIRST and only then fetches and walks step n, so
+the device goes from one step to the next without waiting for the host
+(spans, in order: sweep, admission, pack, upload, dispatch of n+1, fetch
+and walk of n, metrics). What makes that possible: a decode row's next
+input token never leaves the device (`unified_step`'s ``prev_tok`` /
+``last_tok``; the host packs the sentinel -1 in its place), and what step
+n+1 runs is decided by counts the host has — committed progress plus
+where the step in flight leaves each row if no EOS falls
+(`ServingEngine._advance`). An EOS is learnt one step late: the row rides
+one more step (its tokens dropped, counted in
+``overlap_wasted_rows_total``) and its slot and pages are released when
+that step has been walked. `Request.output`, `prefill_done`, `snapshot()`,
+`load_stats()` and `prom` show COMMITTED (walked) progress and never
+touch the device. `settle()` fetches and walks the step in flight; the
+engine calls it itself, by what it sees in its own input, wherever the
+next decision needs that result — speculative drafts, a preemption, a
+cancel or an expiry of a running row, a drain, nothing left to pack — and
+any outside read of `slots`, `lens`, the pools, their scales, `ssm_state`
+or `conv_tail` settles first (``overlap_settles_total{reason}``). One
+loop; the barrier sits before the pack or after the dispatch.
+
 The model behind the step is a seam (`serving_model`, ISSUE 28):
 the GPT block's answers are `GPTServing`; a configuration of another
 architecture brings its own (``cfg.serving_model``, today
@@ -172,9 +194,14 @@ class _PackedStep:
     attn_pages: int      # (row, page) pairs the K passes' attention walks
     starts: np.ndarray
     pos0: np.ndarray
+    q_lens: np.ndarray
+    emit: np.ndarray     # tokens each row emits if no EOS falls (`_advance`)
+    lens_after: np.ndarray   # ... and where that leaves its context
     arrays: tuple        # the host arrays, in the program's order
     ssm_attrs: dict = dataclasses.field(default_factory=dict)
     #                      a recurrent model's dispatch attributes
+    out: tuple = ()      # once dispatched: the program's (toks, greedy_all,
+    #                      lens), still on the device until the step lands
 
 
 class RunResult(dict):
@@ -349,6 +376,23 @@ def serving_model(cfg):
     return getattr(cfg, "serving_model", GPTServing)
 
 
+def _settled_view(name):
+    """`slots`, `lens` and the device state as an outsider reads (or
+    replaces) them: the step in flight owns the donated buffers and runs
+    ahead of `Request.output`, so an outside read settles first. The
+    engine's own code uses the private names."""
+    private = "_" + name
+
+    def get(self):
+        self.settle("observer")
+        return getattr(self, private)
+
+    def put(self, value):
+        self.settle("observer")
+        setattr(self, private, value)
+    return property(get, put, doc=_settled_view.__doc__)
+
+
 class ServingEngine:
     """Continuous-batching engine over a paged KV pool (see module doc)."""
 
@@ -436,15 +480,18 @@ class ServingEngine:
         self.bs, self.chunk = block_size, chunk
         self.max_batch = max_batch
         self.kv_quantized = kv_quantized
-        self.k_pools = jnp.zeros((L, Hkv, num_blocks, block_size, D),
-                                 pool_dtype)
-        self.v_pools = jnp.zeros_like(self.k_pools)
-        self.k_scales = self.v_scales = None
+        # device state and the slot list are private: the step in flight
+        # owns them (the buffers are donated to it), and an outsider reads
+        # them through the settling views at the end of the class
+        self._k_pools = jnp.zeros((L, Hkv, num_blocks, block_size, D),
+                                  pool_dtype)
+        self._v_pools = jnp.zeros_like(self._k_pools)
+        self._k_scales = self._v_scales = None
         if kv_quantized:
-            self.k_scales = jnp.zeros((L, Hkv, num_blocks), jnp.float32)
-            self.v_scales = jnp.zeros_like(self.k_scales)
+            self._k_scales = jnp.zeros((L, Hkv, num_blocks), jnp.float32)
+            self._v_scales = jnp.zeros_like(self._k_scales)
         self.tables = np.zeros((max_batch, max_blocks_per_seq), np.int32)
-        self.lens = np.zeros((max_batch,), np.int32)
+        self._lens = np.zeros((max_batch,), np.int32)   # COMMITTED (walked)
         # block 0 is the scratch block idle slots write into
         self.free_blocks = list(range(num_blocks - 1, 0, -1))
         # -- prefix page sharing + speculative decoding (ISSUE 17).
@@ -459,14 +506,14 @@ class ServingEngine:
         if pool_audit is None or pool_audit == "auto":
             pool_audit = bool(flag("serving_pool_audit"))
         self.pool_audit = bool(pool_audit)
-        self.ssm_state = self.conv_tail = None
+        self._ssm_state = self._conv_tail = None
         self.ssm_resets = self._ssm_resets_reported = 0
         if self.model.recurrent:
             state_shape, tail_shape = self.model.state_shapes(cfg,
                                                               max_batch)
-            self.ssm_state = jnp.zeros(state_shape,
-                                       jnp.dtype(ssm_state_dtype))
-            self.conv_tail = jnp.zeros(tail_shape, cfg.dtype)
+            self._ssm_state = jnp.zeros(state_shape,
+                                        jnp.dtype(ssm_state_dtype))
+            self._conv_tail = jnp.zeros(tail_shape, cfg.dtype)
         self.refcount = np.zeros((num_blocks,), np.int32)
         # page-granular prefix cache: chained page hash -> resident block
         # (and the reverse index). Pages whose last holder left stay
@@ -489,7 +536,7 @@ class ServingEngine:
         self._cow_reported = 0
         self._spec_prop_reported = 0
         self._spec_acc_reported = 0
-        self.slots: List[Optional[Request]] = [None] * max_batch
+        self._slots: List[Optional[Request]] = [None] * max_batch
         self.queue: List[Request] = []
         self._next_rid = 0
         self._key = jax.random.PRNGKey(seed)
@@ -541,12 +588,28 @@ class ServingEngine:
         self._jit_programs: List = []
         self.decode_microsteps = 0  # device decode steps issued (telemetry)
         self._pending_tok = np.zeros((max_batch,), np.int32)
+        # -- one step in flight (ISSUE 31): the step dispatched last and
+        # not yet fetched (a `_PackedStep` with its `out`), and the token
+        # each slot emitted last, which stays on the device between steps
+        self._flight: Optional[_PackedStep] = None
+        self._last_tok = jnp.zeros((max_batch,), jnp.int32)
         # -- observability: per-engine Prometheus registry (TTFT, tokens/s,
         # queue depth, KV-pool utilization, decode/prefill mix). Pure host
         # floats updated inside step() — a scrape never adds a dispatch.
         from ..observability import PromRegistry
         self._num_blocks = num_blocks
         self._prom = PromRegistry(namespace="paddle_tpu_serving")
+        for name, text in (
+                ("steps_overlapped_total",
+                 "steps dispatched while the one before was still in "
+                 "flight"),
+                ("overlap_settles_total",
+                 "steps fetched and walked before the next was packed, by "
+                 "what needed their result"),
+                ("overlap_wasted_rows_total",
+                 "rows that rode one more step after they had ended (an "
+                 "EOS is learnt a step late)")):
+            self._prom.counter_inc(name, 0, help=text)
         self._metrics_server = None
         self._t_first_step: Optional[float] = None
         self._tokens_total = 0
@@ -563,7 +626,7 @@ class ServingEngine:
         # program) — flags-off behavior stays byte-identical.
         from ..flags import flag as _flag
         self._numerics_kv = (bool(_flag("numerics"))
-                             and self.k_scales is not None)
+                             and self._k_scales is not None)
         self._numerics_kv_interval = max(int(_flag("telemetry_interval")),
                                          1)
         self._numerics_kv_prev: Optional[Dict[str, np.ndarray]] = None
@@ -636,15 +699,17 @@ class ServingEngine:
         self.params = jax.tree.map(
             lambda v, s: jax.device_put(v, NamedSharding(mesh, s)),
             self.params, pspec)
-        self.k_pools = jax.device_put(self.k_pools,
-                                      NamedSharding(mesh, pool_spec))
-        self.v_pools = jax.device_put(self.v_pools,
-                                      NamedSharding(mesh, pool_spec))
+        self._k_pools = jax.device_put(self._k_pools,
+                                       NamedSharding(mesh, pool_spec))
+        self._v_pools = jax.device_put(self._v_pools,
+                                       NamedSharding(mesh, pool_spec))
         if self.kv_quantized:
-            self.k_scales = jax.device_put(self.k_scales,
-                                           NamedSharding(mesh, pool_spec))
-            self.v_scales = jax.device_put(self.v_scales,
-                                           NamedSharding(mesh, pool_spec))
+            self._k_scales = jax.device_put(self._k_scales,
+                                            NamedSharding(mesh, pool_spec))
+            self._v_scales = jax.device_put(self._v_scales,
+                                            NamedSharding(mesh, pool_spec))
+        self._last_tok = jax.device_put(self._last_tok,
+                                        NamedSharding(mesh, P()))
         self._tp_pspec, self._tp_pool_spec = pspec, pool_spec
 
     # -- the unified program (ISSUE 6) ---------------------------------------
@@ -672,7 +737,7 @@ class ServingEngine:
             # pools, scales (None unless quantized), state, tail: donated
             jfn = jax.jit(functools.partial(
                 RS.unified_step, cfg=cfg, bs=bsz, c_att=c_att, K=K),
-                donate_argnums=(14, 15, 16, 17, 21, 22))
+                donate_argnums=(15, 16, 17, 18, 22, 23))
             self._jit_programs.append(jfn)
             return jfn
         if mesh is None:
@@ -682,32 +747,32 @@ class ServingEngine:
                 jfn = jax.jit(functools.partial(
                     RS.unified_step, cfg=cfg, bs=bsz, c_att=c_att, K=K,
                     spec=spec),
-                    donate_argnums=(14, 15, 16, 17))
+                    donate_argnums=(15, 16, 17, 18))
                 self._jit_programs.append(jfn)
                 return jfn
 
             if share:
                 def fn(params, tokens, row_of, off_of, starts, pos0,
                        q_lens, tables, fresh, sample0, remaining,
-                       eos_ids, temps, key, kp, vp, cow_src, cow_dst,
-                       reset_tables):
+                       eos_ids, temps, prev_tok, key, kp, vp, cow_src,
+                       cow_dst, reset_tables):
                     return RS.unified_step(
                         params, tokens, row_of, off_of, starts, pos0,
                         q_lens, tables, fresh, sample0, remaining,
-                        eos_ids, temps, key, kp, vp, None, None,
+                        eos_ids, temps, prev_tok, key, kp, vp, None, None,
                         cow_src, cow_dst, reset_tables, cfg=cfg, bs=bsz,
                         c_att=c_att, K=K, spec=spec)
             else:
                 def fn(params, tokens, row_of, off_of, starts, pos0,
                        q_lens, tables, fresh, sample0, remaining,
-                       eos_ids, temps, key, kp, vp):
+                       eos_ids, temps, prev_tok, key, kp, vp):
                     return RS.unified_step(
                         params, tokens, row_of, off_of, starts, pos0,
                         q_lens, tables, fresh, sample0, remaining,
-                        eos_ids, temps, key, kp, vp, None, None,
+                        eos_ids, temps, prev_tok, key, kp, vp, None, None,
                         cfg=cfg, bs=bsz, c_att=c_att, K=K, spec=spec)
 
-            jfn = jax.jit(fn, donate_argnums=(14, 15))
+            jfn = jax.jit(fn, donate_argnums=(15, 16))
             self._jit_programs.append(jfn)
             return jfn
 
@@ -722,36 +787,33 @@ class ServingEngine:
         if quant:
             def fn(params, tokens, row_of, off_of, starts, pos0, q_lens,
                    tables, fresh, sample0, remaining, eos_ids, temps,
-                   key_data, kp, vp, ks, vs, *extra):
+                   prev_tok, key_data, kp, vp, ks, vs, *extra):
                 return RS.unified_step(
                     params, tokens, row_of, off_of, starts, pos0, q_lens,
                     tables, fresh, sample0, remaining, eos_ids, temps,
-                    jax.random.wrap_key_data(key_data), kp, vp, ks, vs,
-                    *extra, cfg=cfg, bs=bsz, c_att=c_att, K=K, spec=spec,
-                    mp_axis=ax)
-            in_specs = (pspec,) + (rep,) * 13 + (pool_spec,) * 4
+                    prev_tok, jax.random.wrap_key_data(key_data), kp, vp,
+                    ks, vs, *extra, cfg=cfg, bs=bsz, c_att=c_att, K=K,
+                    spec=spec, mp_axis=ax)
+            in_specs = (pspec,) + (rep,) * 14 + (pool_spec,) * 4
             out_specs = ((rep,) * (2 if spec else 1)
-                         + (pool_spec,) * 4 + (rep,))
-            donate = (14, 15, 16, 17)
+                         + (pool_spec,) * 4 + (rep, rep))
+            donate = (15, 16, 17, 18)
         else:
             def fn(params, tokens, row_of, off_of, starts, pos0, q_lens,
                    tables, fresh, sample0, remaining, eos_ids, temps,
-                   key_data, kp, vp, *extra):
+                   prev_tok, key_data, kp, vp, *extra):
                 out = RS.unified_step(
                     params, tokens, row_of, off_of, starts, pos0, q_lens,
                     tables, fresh, sample0, remaining, eos_ids, temps,
-                    jax.random.wrap_key_data(key_data), kp, vp, None,
-                    None, *extra, cfg=cfg, bs=bsz, c_att=c_att, K=K,
+                    prev_tok, jax.random.wrap_key_data(key_data), kp, vp,
+                    None, None, *extra, cfg=cfg, bs=bsz, c_att=c_att, K=K,
                     spec=spec, mp_axis=ax)
-                if spec:
-                    toks, greedy_all, kp, vp, _, _, lens = out
-                    return toks, greedy_all, kp, vp, lens
-                toks, kp, vp, _, _, lens = out
-                return toks, kp, vp, lens
-            in_specs = (pspec,) + (rep,) * 13 + (pool_spec, pool_spec)
+                # the unquantized pool has no scales to shard
+                return tuple(o for o in out if o is not None)
+            in_specs = (pspec,) + (rep,) * 14 + (pool_spec, pool_spec)
             out_specs = ((rep,) * (2 if spec else 1)
-                         + (pool_spec, pool_spec, rep))
-            donate = (14, 15)
+                         + (pool_spec, pool_spec, rep, rep))
+            donate = (15, 16)
         if share:
             in_specs = in_specs + (rep, rep, rep)
 
@@ -760,20 +822,17 @@ class ServingEngine:
                       donate_argnums=donate)
         self._jit_programs.append(jfn)
 
-        if quant:
-            def call(*a):
-                a = list(a)
-                a[13] = jax.random.key_data(a[13])  # PRNG key position
-                return jfn(*a)
-        else:
-            def call(*a):
-                a = list(a)
-                a[13] = jax.random.key_data(a[13])
-                if spec:
-                    toks, greedy_all, kp, vp, lens = jfn(*a)
-                    return toks, greedy_all, kp, vp, None, None, lens
-                toks, kp, vp, lens = jfn(*a)
-                return toks, kp, vp, None, None, lens
+        n_head = 2 if spec else 1     # toks (and greedy_all) lead
+
+        def call(*a):
+            a = list(a)
+            a[14] = jax.random.key_data(a[14])  # PRNG key position
+            out = jfn(*a)
+            if quant:
+                return out
+            *head, kp, vp, lens, last_tok = out
+            assert len(head) == n_head
+            return (*head, kp, vp, None, None, lens, last_tok)
         return call
 
     def compiled_cache_entries(self) -> int:
@@ -847,7 +906,29 @@ class ServingEngine:
         return rid
 
     def has_work(self) -> bool:
-        return bool(self.queue) or any(s is not None for s in self.slots)
+        """Anything queued, running, or dispatched and not yet walked."""
+        return (bool(self.queue) or self._flight is not None
+                or any(s is not None for s in self._slots))
+
+    def settle(self, reason: str = "observer") -> None:
+        """Fetch and walk the step in flight; a no-op with nothing in
+        flight. Afterwards `Request.output`, `prefill_done`, `lens`, the
+        pool's accounting and the device state all describe the same,
+        last dispatched step. Tokens go through `_emit`/`on_token` as in
+        any walk; requests that reach a terminal state wait in `_notify`
+        for the next `step()`. The engine calls it itself wherever its
+        next decision needs the result (`reason`, counted in
+        ``overlap_settles_total``): `spec` (the proposer reads the
+        output), `preempt`, `cancel`, `expire`, `drain` (the host removes
+        or rewinds a running row), `tail` (nothing left to pack),
+        `observer` (an outside read of `slots` or of a device-state
+        view)."""
+        f, self._flight = self._flight, None
+        if f is None:
+            return
+        self._prom.counter_inc("overlap_settles_total",
+                               labels={"reason": reason})
+        self._notify.extend(self._land(f))
 
     def run(self, max_steps: int = 100000) -> "RunResult":
         """Drive to completion; returns {rid: output token ids} (a
@@ -867,9 +948,11 @@ class ServingEngine:
             if not self.has_work():
                 break
             take(self.step())
+        self.settle("tail")     # a budget that ran out mid-flight
+        take(self._take_notifications())
         if self.has_work():
             leftover = ([r.rid for r in self.queue]
-                        + [s.rid for s in self.slots if s is not None])
+                        + [s.rid for s in self._slots if s is not None])
             results.leftover = sorted(leftover)
             self._prom.counter_inc(
                 "run_steps_exhausted_total",
@@ -899,11 +982,12 @@ class ServingEngine:
         The resilient driver pairs this with :meth:`shed_queue` and, at
         grace expiry, :meth:`cancel_all`."""
         if not self.draining:
+            self.settle("drain")
             self.draining = True
             self._health = "draining"
             self._emit_event("serving_drain", queue_depth=len(self.queue),
                              running=sum(s is not None
-                                         for s in self.slots))
+                                         for s in self._slots))
 
     def shed_queue(self, reason: str = "draining") -> List[Request]:
         """Shed every queued (not yet started) request; returns them so a
@@ -925,7 +1009,9 @@ class ServingEngine:
                 self._shed(r, reason)
                 self._notify.append(r)
                 return r
-        for r in self.slots:
+        if any(r is not None and r.rid == rid for r in self._slots):
+            self.settle("cancel")   # it may end in the step in flight
+        for r in self._slots:
             if r is not None and r.rid == rid:
                 self._cancel(r, reason)
                 self._notify.append(r)
@@ -936,7 +1022,8 @@ class ServingEngine:
         """Cancel everything (queued + in-flight); returns the requests.
         The drain-deadline endgame: pages all return to the pool."""
         out = self.shed_queue(reason)
-        for r in list(self.slots):
+        self.settle("cancel")
+        for r in list(self._slots):
             if r is not None:
                 self._cancel(r, reason)
                 self._notify.append(r)
@@ -949,7 +1036,7 @@ class ServingEngine:
         utilization, straight off the engine's own prom registry — host
         floats only, the device is never touched."""
         pending = (len(self.queue)
-                   + sum(1 for s in self.slots if s is not None))
+                   + sum(1 for s in self._slots if s is not None))
         return {
             "pending": float(pending),
             "ttft_p95": float(self._prom.quantile("ttft_seconds", 0.95)
@@ -968,7 +1055,8 @@ class ServingEngine:
     def snapshot(self) -> Dict:
         """Host-state serving snapshot for flight-recorder bundles:
         slots, queue, pool utilization, health — cheap, never touches
-        the device."""
+        the device and never settles: `emitted` and `prefill_done` are
+        COMMITTED progress, one step behind what is dispatched."""
         total = self._num_blocks - 1
 
         def req(r):
@@ -992,7 +1080,7 @@ class ServingEngine:
             "kv_cow_copies_total": self.cow_copies,
             "spec_proposed_total": self.spec_proposed,
             "spec_accepted_total": self.spec_accepted,
-            "slots": [None if s is None else req(s) for s in self.slots],
+            "slots": [None if s is None else req(s) for s in self._slots],
             "queue": [req(r) for r in self.queue],
             # last KV page-scale drift poll (FLAGS_numerics, quantized
             # pools) — already-fetched host floats, device untouched
@@ -1113,7 +1201,7 @@ class ServingEngine:
         if not self.pool_audit:
             return
         expected = np.zeros_like(self.refcount)
-        for s in self.slots:
+        for s in self._slots:
             if s is None:
                 continue
             for b in self.tables[s.slot]:
@@ -1157,7 +1245,7 @@ class ServingEngine:
                                            is not None else big))
         while self.queue:
             try:
-                i = self.slots.index(None)
+                i = self._slots.index(None)
             except ValueError:
                 break  # no free slot
             r = self.queue[0]
@@ -1249,10 +1337,10 @@ class ServingEngine:
             self._reset_tables[i, :] = 0
             self._reset_tables[i, :need] = pages
             self._reset_tables[i, :n_inherit] = 0
-            self.lens[i] = start
+            self._lens[i] = start
             r.slot = i
             r.prefill_done = start
-            self.slots[i] = r
+            self._slots[i] = r
             fresh.append(i)
             if self.prefix_share:
                 chain = self._chain_of(r)
@@ -1280,8 +1368,15 @@ class ServingEngine:
             return False
         if self._hol_wait_steps < self.preempt_wait_steps:
             return False
+        if self._flight is not None:
+            # a victim is chosen, and its output folded into its prompt,
+            # from settled state; what the step in flight frees may
+            # already let the head in
+            self.settle("preempt")
+            self._hol_wait_steps -= 1   # the retry counts this wait again
+            return True
         big = float("inf")
-        victims = [r for r in self.slots
+        victims = [r for r in self._slots
                    if r is not None and r.prefill_done >= len(r.prompt)
                    and r.preemptions < 3]
         # urgency: with deadlines, only preempt a victim LESS urgent than
@@ -1342,8 +1437,8 @@ class ServingEngine:
             self._decref(b)
         self.tables[i, :] = 0
         self._reset_tables[i, :] = 0
-        self.lens[i] = 0
-        self.slots[i] = None
+        self._lens[i] = 0
+        self._slots[i] = None
         self._pending_tok[i] = 0
         r.slot = -1
         if self._prefix_pending:
@@ -1353,8 +1448,14 @@ class ServingEngine:
         self._audit_pool()
 
     def _finish(self, r: Request):
-        self._release_slot(r)
+        """A request ended in the step just walked. One that the host
+        could not see ending (an EOS, a raising callback) rides the step
+        already in flight: that step writes into its pages, so slot and
+        pages are released when IT has been walked (`_walk_ragged`)."""
         r.done = True
+        f = self._flight
+        if f is None or f.q_lens[r.slot] == 0:
+            self._release_slot(r)
 
     def _shed(self, r: Request, reason: str):
         """Drop a queued request. status='shed' means it NEVER delivered
@@ -1401,9 +1502,12 @@ class ServingEngine:
         each — behavior is untouched."""
         if (not self.queue or all(r.deadline is None for r in self.queue)) \
                 and all(s is None or s.deadline is None
-                        for s in self.slots):
+                        for s in self._slots):
             return []
         now = time.perf_counter()
+        if any(r is not None and r.deadline is not None and now > r.deadline
+               for r in self._slots):
+            self.settle("expire")   # cancel from settled state
         out: List[Request] = []
         keep: List[Request] = []
         for r in self.queue:
@@ -1413,7 +1517,7 @@ class ServingEngine:
             else:
                 keep.append(r)
         self.queue = keep
-        for r in list(self.slots):
+        for r in list(self._slots):
             if (r is not None and r.deadline is not None
                     and now > r.deadline):
                 self._cancel(r, "deadline")
@@ -1494,18 +1598,31 @@ class ServingEngine:
                 or (r.eos_id is not None and tok == r.eos_id))
 
     def step(self) -> List[Request]:
-        """One engine iteration: admit -> ONE compiled program (prefill
-        chunks + decode burst fused over a packed ragged batch). Returns
-        every request that reached a TERMINAL state this step —
-        finished, plus deadline-shed/cancelled, overload-shed, rejected,
-        and submit-time sheds queued since the last step (check
-        ``Request.status``).
+        """One engine iteration, with ONE STEP IN FLIGHT: admit, pack,
+        upload and dispatch step n+1 (ONE compiled program: prefill
+        chunks + decode burst fused over a packed ragged batch) FIRST,
+        then fetch and walk step n, which the device finished while the
+        host packed. Returns every request that reached a TERMINAL state
+        in what this call walked — finished, plus deadline-shed/
+        cancelled, overload-shed, rejected, and sheds or settles queued
+        since the last step (check ``Request.status``). After the call
+        step n+1 is still running: `Request.output`, `prefill_done` and
+        `snapshot()` show COMMITTED (walked) progress; `settle()` brings
+        them up to the dispatched step, and so does any outside read of
+        `slots`, `lens` or a device-state view (the pools, their scales,
+        `ssm_state`, `conv_tail`). `snapshot()`, `load_stats()`, `prom`
+        and the counters never settle and never touch the device. Where
+        the next pack depends on the result of the step in flight the
+        engine settles first, by what it sees in its own input (see
+        `settle`): the same loop, the barrier before the pack instead of
+        after the dispatch.
 
         The whole step runs inside a ``serving_step`` RecordEvent span,
         and the host work inside it is covered, without holes, by the
         child spans of ``observability.trace.SERVING_SPANS`` (sweep,
-        admission, pack, upload, unified dispatch, fetch, walk,
-        metrics). Every span is a
+        admission, pack, upload, unified dispatch of step n+1, then
+        fetch and walk of step n, metrics; `serving_fetch` is the host
+        blocked on the device). Every span is a
         ``jax.profiler.TraceAnnotation`` too, so serving lands on the SAME
         host timeline as training — Profiler summaries, chrome-trace
         exports, observability.capture_spans — and on the device trace's
@@ -1522,9 +1639,9 @@ class ServingEngine:
             out = self._step_ragged()
             if self._health == "loading":
                 self._health = "ready"
-            # admission-time rejections land in _notify DURING the step
-            # body — drain them now so a run that ends this step still
-            # reports them
+            # admission-time rejections and what a settle walked land in
+            # _notify DURING the step body — drain them now so a run that
+            # ends this step still reports them
             return terminal + out + self._take_notifications()
 
     def _numerics_kv_poll(self) -> None:
@@ -1541,7 +1658,7 @@ class ServingEngine:
                 or self.engine_steps % self._numerics_kv_interval):
             return
         import jax
-        ks, vs = jax.device_get((self.k_scales, self.v_scales))
+        ks, vs = jax.device_get((self._k_scales, self._v_scales))
         cur = np.maximum(np.max(np.asarray(ks, np.float32), axis=0),
                          np.max(np.asarray(vs, np.float32), axis=0))
         # page axis is last ([L/H, ..., NB] — reduce everything else)
@@ -1600,64 +1717,120 @@ class ServingEngine:
     def _step_ragged(self) -> List[Request]:
         """The single-dispatch step: admit, pack ONE ragged token batch
         (decode rows first — one token each, always granted — then
-        prefill chunks sharing the leftover token budget), run the ONE
-        unified program (K-token decode burst fused in), walk the [K, R]
-        token matrix on the host. One compiled dispatch, one fetch. Each
-        phase is a child span of ``serving_step`` (SERVING_SPANS)."""
+        prefill chunks sharing the leftover token budget) from what is
+        committed plus what is in flight, run the ONE unified program
+        (K-token decode burst fused in), THEN fetch the step before and
+        walk its [K, R] token matrix on the host. One compiled dispatch,
+        one fetch. Each phase is a child span of ``serving_step``
+        (SERVING_SPANS)."""
         t_step0 = time.perf_counter()
         if self._t_first_step is None:
             self._t_first_step = t_step0
         tokens_before = self._tokens_total
-        finished: List[Request] = []
+        if self.spec_k > 0:
+            self.settle("spec")     # the proposer reads Request.output
         with RecordEvent(SERVING_SPANS.admission):
             fresh_slots = self._admit()
             self._note_pool_peak()
         b = self._pack_ragged(fresh_slots)
         if b is None:
-            self._step_metrics(t_step0, tokens_before, 0, 0, finished)
-            return finished
+            self.settle("tail")     # a no-op with nothing in flight
+            self._step_metrics(t_step0, tokens_before, 0, 0)
+            return []
         args = self._upload_ragged(b)
         self.decode_microsteps += b.K
         self.dispatches += 1
-        greedy_all = None
+        prev = self._flight
+        if prev is not None:
+            self._prom.counter_inc("steps_overlapped_total")
         with RecordEvent(SERVING_SPANS.dispatch, step=self.engine_steps,
                          k=b.K, n_dec=len(b.dec), n_pre=len(b.pre),
                          q_tokens=b.q_tokens, kv_tokens=b.kv_tokens,
-                         attn_pages=b.attn_pages, **b.ssm_attrs):
+                         attn_pages=b.attn_pages,
+                         in_flight=int(prev is not None), **b.ssm_attrs):
             _faults().maybe_fail("serving/dispatch")
             out = self._unified(b.K, spec=b.use_spec)(*args)
         if self.model.recurrent:
-            *out, self.ssm_state, self.conv_tail = out
+            *out, self._ssm_state, self._conv_tail = out
+        toks, *out = out
+        greedy_all = out.pop(0) if b.use_spec else None
+        (self._k_pools, self._v_pools, self._k_scales, self._v_scales,
+         lens, self._last_tok) = out
+        b.out = (toks, greedy_all, lens)
+        self._flight = b
+        finished = [] if prev is None else self._land(prev)
+        if not self.queue and not any(self._schedulable()[:2]):
+            # the step just dispatched is the last one the host can
+            # schedule: nothing to pack behind it, so its result is not
+            # held back a call
+            self.settle("tail")
+        self._step_metrics(t_step0, tokens_before, len(b.pre), len(b.dec))
+        return finished
+
+    def _land(self, f) -> List[Request]:
+        """Fetch a dispatched step's tokens and walk them; returns the
+        requests that finished in it."""
         with RecordEvent(SERVING_SPANS.fetch):
             # ONE host fetch: the copies start together, then the host
             # waits (a fetch of its own for `lens` cost 0.4 ms a step)
-            if b.use_spec:
-                (toks, greedy_all, self.k_pools, self.v_pools,
-                 self.k_scales, self.v_scales, lens) = out
-                toks, greedy_all, lens = jax.device_get(
-                    (toks, greedy_all, lens))
-                greedy_all = np.asarray(greedy_all)      # [T]
-            else:
-                (toks, self.k_pools, self.v_pools, self.k_scales,
-                 self.v_scales, lens) = out
-                toks, lens = jax.device_get((toks, lens))
-            toks = np.asarray(toks)          # [K, R]
-        self._walk_ragged(b, toks, greedy_all, lens, finished)
-        self._step_metrics(t_step0, tokens_before, len(b.pre), len(b.dec),
-                           finished)
-        return finished
+            toks, greedy_all, lens = jax.device_get(f.out)   # toks [K, R]
+        return self._walk_ragged(f, toks, greedy_all, lens)
+
+    @staticmethod
+    def _advance(q_lens, pos0, sample0, remaining, K):
+        """Where a packed step leaves every row if no EOS falls: the ONE
+        place that knows how far a step moves a row (one token a pass).
+        A sampling row emits ``min(K, remaining)`` tokens — pass 1's
+        (whatever `remaining` says), and one a burst pass while it may
+        still emit — and its context grows
+        by its q_len and by one position a burst pass it is alive for.
+        The pack of the NEXT step schedules from this while the step is
+        in flight, the dispatch attributes count the KV positions from
+        it, and the walk holds the device's `lens` to it.
+        Returns (emit [R], lens_after [R])."""
+        emit = np.where(sample0, np.clip(remaining, 1, K), 0).astype(np.int32)
+        return emit, (pos0 + q_lens + np.maximum(emit - 1, 0)).astype(
+            np.int32)
+
+    def _schedulable(self):
+        """The rows the next step can run, from what is committed plus
+        where the step in flight will leave them (`_advance`): decode
+        rows, prefilling rows, and per slot the prefill cursor and the
+        tokens emitted by then. A row that step finishes by count is
+        not among them, nor one that had ended before it was
+        dispatched (an EOS row on its wasted step)."""
+        f = self._flight
+        dec, pre, done_pre, emitted = [], [], {}, {}
+        for r in self._slots:
+            if r is None or r.done:
+                continue
+            i = r.slot
+            flying = int(f.emit[i]) if f is not None else 0
+            emitted[i] = len(r.output) + flying
+            if flying and emitted[i] >= r.max_new_tokens:
+                continue
+            done_pre[i] = r.prefill_done + (f.grants.get(i, 0)
+                                            if f is not None else 0)
+            (dec if done_pre[i] >= len(r.prompt) else pre).append(r)
+        return dec, pre, done_pre, emitted
 
     @RecordEvent(SERVING_SPANS.pack)
     def _pack_ragged(self, fresh_slots):
         """The step's packed host arrays, burst size and row lists, or
-        None when no slot has work."""
+        None when no slot has work. A row is packed from where the step
+        in flight will leave it (`_advance`): its prefill cursor plus the
+        granted chunk, its tokens emitted, its context; a row that step
+        finishes by count is left out, and a decode row whose input token
+        that step is sampling gets the sentinel -1, which the program
+        fills from the token it kept (`unified_step`'s ``prev_tok``)."""
         R, T = self.max_batch, self.token_budget
-        dec = [r for r in self.slots
-               if r is not None and r.prefill_done >= len(r.prompt)]
-        pre = [r for r in self.slots
-               if r is not None and r.prefill_done < len(r.prompt)]
+        f = self._flight
+        dec, pre, done_pre, emitted = self._schedulable()
         if not dec and not pre:
             return None
+        lens = self._lens
+        if f is not None:
+            lens = np.where(f.q_lens > 0, f.lens_after, lens)
 
         tokens = np.zeros((T,), np.int32)
         row_of = np.zeros((T,), np.int32)
@@ -1683,6 +1856,7 @@ class ServingEngine:
                 # pre-allocated footprint (k <= remaining - 1 keeps
                 # every draft's KV write inside it), and the token
                 # budget after every later decode row's guaranteed 1
+                # (settled: `step` settles before a speculative pack)
                 room = T - cursor - (len(dec) - idx - 1) - 1
                 cap = min(self.spec_k,
                           r.max_new_tokens - len(r.output) - 1, room)
@@ -1695,13 +1869,15 @@ class ServingEngine:
                             break  # defensive: never embed out-of-vocab
                         props.append(int(t))
             q_lens[i] = 1 + len(props)
-            pos0[i] = self.lens[i]
+            pos0[i] = lens[i]
             sample0[i] = True
-            remaining[i] = r.max_new_tokens - len(r.output)
+            remaining[i] = r.max_new_tokens - emitted[i]
             if r.eos_id is not None:
                 eos_ids[i] = r.eos_id
             temps[i] = r.temperature
-            row_toks = [self._pending_tok[i]] + props
+            # its input token: known once walked, else still on the device
+            known = f is None or f.emit[i] == 0
+            row_toks = [self._pending_tok[i] if known else -1] + props
             tokens[cursor:cursor + len(row_toks)] = row_toks
             row_of[cursor:cursor + len(row_toks)] = i
             off_of[cursor:cursor + len(row_toks)] = np.arange(len(row_toks))
@@ -1713,7 +1889,7 @@ class ServingEngine:
         grants: Dict[int, int] = {}
         for r in pre:  # prefill chunks share the leftover budget
             i = r.slot
-            lo = r.prefill_done
+            lo = done_pre[i]
             pos0[i] = lo  # keeps device lens honest even at zero grant
             todo = len(r.prompt) - lo
             grant = min(self.chunk, todo, T - cursor)
@@ -1725,7 +1901,7 @@ class ServingEngine:
             sample0[i] = completing
             # remaining-to-EMIT: a preempted-and-requeued request's
             # emitted prefix lives in both prompt and output
-            remaining[i] = (r.max_new_tokens - len(r.output)
+            remaining[i] = (r.max_new_tokens - emitted[i]
                             if completing else 0)
             if r.eos_id is not None:
                 eos_ids[i] = r.eos_id
@@ -1749,6 +1925,7 @@ class ServingEngine:
                 # forward passes over all-zero q_lens. K=1 is an
                 # already-compiled size.
                 K = 1
+        emit, lens_after = self._advance(q_lens, pos0, sample0, remaining, K)
         # KV positions the step attends: each row of pass 1 its whole
         # context, then each sampling row one position more per burst
         # pass while it may still emit (an EOS inside the burst stops a
@@ -1762,7 +1939,7 @@ class ServingEngine:
         attn_pages = int((-(-kv_end[ran] // self.bs)).sum())
         burst_rows = 0      # rows the K-1 burst passes run, summed
         for j in range(1, K):
-            alive = sample0 & (remaining > j)
+            alive = emit > j
             kv_tokens += int((kv_end[alive] + j).sum())
             attn_pages += int((-(-(kv_end[alive] + j) // self.bs)).sum())
             burst_rows += int(alive.sum())
@@ -1777,26 +1954,32 @@ class ServingEngine:
             dec=dec, pre=pre, grants=grants, props_by_slot=props_by_slot,
             use_spec=use_spec, K=K, q_tokens=cursor, kv_tokens=kv_tokens,
             attn_pages=attn_pages,
-            starts=starts, pos0=pos0, ssm_attrs=ssm_attrs,
+            starts=starts, pos0=pos0, q_lens=q_lens, emit=emit,
+            lens_after=lens_after, ssm_attrs=ssm_attrs,
+            # the tables are the engine's own and change under the step
+            # in flight (a walk releases, an admission claims): the
+            # program gets a copy
             arrays=(tokens, row_of, off_of, starts, pos0, q_lens,
-                    self.tables, fresh, sample0, remaining, eos_ids, temps))
+                    self.tables.copy(), fresh, sample0, remaining, eos_ids,
+                    temps))
 
     @RecordEvent(SERVING_SPANS.upload)
     def _upload_ragged(self, b):
         """The unified program's arguments: the packed arrays on the
-        device, this step's PRNG key, the pools."""
+        device, the token each slot emitted last (never fetched), this
+        step's PRNG key, the pools."""
         self._key, sub = _split_key(self._key)
         # one transfer call for the twelve small arrays, not an asarray
         # each: the host's share of a step is what a short step feels
         args = ((self.params,) + tuple(jax.device_put(list(b.arrays)))
-                + (sub, self.k_pools, self.v_pools))
+                + (self._last_tok, sub, self._k_pools, self._v_pools))
         if self.model.recurrent:
             # unified_step's full argument list: scales (or None), no
             # copy-on-write, then the recurrent state and the conv tail
-            return args + (self.k_scales, self.v_scales, None, None, None,
-                           self.ssm_state, self.conv_tail)
+            return args + (self._k_scales, self._v_scales, None, None, None,
+                           self._ssm_state, self._conv_tail)
         if self.kv_quantized:
-            args = args + (self.k_scales, self.v_scales)
+            args = args + (self._k_scales, self._v_scales)
         if self.prefix_share:
             # pending COW pairs ride this dispatch (executed before any
             # append); idle lanes self-copy the scratch block — a no-op
@@ -1808,15 +1991,26 @@ class ServingEngine:
                 cow_dst[j] = d
             del self._cow_pairs[:R]
             args = args + tuple(jax.device_put(
-                [cow_src, cow_dst, self._reset_tables]))
+                [cow_src, cow_dst, self._reset_tables.copy()]))
         return args
 
     @RecordEvent(SERVING_SPANS.walk)
-    def _walk_ragged(self, b, toks, greedy_all, lens, finished):
-        """Commit the step on the host: lengths, prefix pages, draft
-        acceptance, then every emitted token through _emit/_finish."""
+    def _walk_ragged(self, b, toks, greedy_all, lens):
+        """Commit a fetched step on the host: lengths, prefix pages,
+        draft acceptance, then every emitted token through
+        _emit/_finish; returns the requests that finished in it. A row
+        that had ended before the step ran (its EOS was learnt a step
+        late) is only released: its tokens are dropped."""
+        from ..enforce import enforce
+        finished: List[Request] = []
         dec, pre, props_by_slot = b.dec, b.pre, b.props_by_slot
-        self.lens = np.array(lens)
+        ran = b.q_lens > 0
+        self._lens[ran] = lens[ran]
+        wasted = [r for r in dec if r.done]
+        for r in wasted:    # it rode this step; nothing writes its pages now
+            self._release_slot(r)
+        if wasted:
+            self._prom.counter_inc("overlap_wasted_rows_total", len(wasted))
         for r in pre:
             r.prefill_done += b.grants.get(r.slot, 0)
             self._register_pages(r)
@@ -1839,7 +2033,7 @@ class ServingEngine:
                 # returned lens for) all k+1 draft positions, but the
                 # block table simply forgets the rejected tail — those
                 # positions are past lens, never read, rewritten later
-                self.lens[i] = int(b.pos0[i]) + acc + 1
+                self._lens[i] = int(b.pos0[i]) + acc + 1
                 for tok in props[:acc] + [int(greedy_all[base + acc])]:
                     tok = self._check_tok(r, tok)
                     self._pending_tok[i] = tok
@@ -1849,17 +2043,28 @@ class ServingEngine:
                         break
         for r in dec + [r for r in pre
                         if r.prefill_done >= len(r.prompt)]:
-            if b.use_spec and props_by_slot.get(r.slot):
-                continue  # spec row: already emitted above
+            if r.done or (b.use_spec and props_by_slot.get(r.slot)):
+                continue  # ended before this step, or emitted above
+            i, n = r.slot, 0
             for t in range(toks.shape[0]):
                 if r.done:
                     break
-                tok = self._check_tok(r, int(toks[t, r.slot]))
-                self._pending_tok[r.slot] = tok
+                tok = self._check_tok(r, int(toks[t, i]))
+                self._pending_tok[i] = tok
+                n += 1
                 if self._emit(r, tok):
                     finished.append(r)
                     self._finish(r)
                     break
+            # the next step may already be packed from `_advance`'s word:
+            # a row that emitted all it was scheduled to (no EOS stopped
+            # it short) must stand where the host said it would
+            enforce(n < b.emit[i] or lens[i] == b.lens_after[i],
+                    f"slot {i} (request {r.rid}): the device left "
+                    f"{int(lens[i])} positions, the schedule said "
+                    f"{int(b.lens_after[i])}", op="ServingEngine")
+        self._note_completed(finished)
+        return finished
 
     # -- observability -------------------------------------------------------
     def _note_pool_peak(self):
@@ -1874,10 +2079,34 @@ class ServingEngine:
                 1.0 - self.free_pages() / total_blocks,
                 help="high-water allocated fraction of the KV pool")
 
+    def _note_completed(self, finished):
+        """Completion counters and events of the requests a walk
+        finished (inside the walk's span: a settle outside a step counts
+        them too)."""
+        # completed == finished SUCCESSFULLY: a request failed by its
+        # own callback rides `finished` for page accounting but must not
+        # count as a completion (it already counted in
+        # callback_errors_total / serving_callback_error)
+        ok = [r for r in finished if r.status == "ok"]
+        self._prom.counter_inc("requests_completed_total", len(ok),
+                               help="requests finished successfully")
+        if ok:
+            from ..observability import get_event_log
+            log = get_event_log()
+            for r in ok:
+                self._prom.summary_observe(
+                    "request_seconds",
+                    time.perf_counter() - r.submit_time,
+                    help="submit-to-completion latency")
+                if log is not None:
+                    log.emit("serving_complete", role="serving", rid=r.rid,
+                             tokens=len(r.output), ttft_s=r.ttft_s)
+
     @RecordEvent(SERVING_SPANS.metrics)
-    def _step_metrics(self, t_step0, tokens_before, n_pre, n_dec, finished):
-        """End-of-step telemetry: the prom gauges and counters, the
-        completion events, and the KV-scale poll."""
+    def _step_metrics(self, t_step0, tokens_before, n_pre, n_dec):
+        """End-of-step telemetry: the prom gauges and counters, and the
+        KV-scale poll. `n_pre`/`n_dec` are the dispatched step's rows,
+        the tokens those this call walked."""
         prom = self._prom
         dt = max(time.perf_counter() - t_step0, 1e-9)
         emitted = self._tokens_total - tokens_before
@@ -1907,9 +2136,9 @@ class ServingEngine:
                                   "speculation health rate")
             self._spec_prop_reported = self.spec_proposed
             self._spec_acc_reported = self.spec_accepted
-        if self.ssm_state is not None:
+        if self._ssm_state is not None:
             prom.gauge_set("ssm_state_bytes",
-                           self.ssm_state.nbytes + self.conv_tail.nbytes,
+                           self._ssm_state.nbytes + self._conv_tail.nbytes,
                            help="recurrent state and conv tail held for "
                                 "the slots")
             prom.counter_inc("ssm_state_resets_total",
@@ -1919,7 +2148,7 @@ class ServingEngine:
             self._ssm_resets_reported = self.ssm_resets
         prom.gauge_set("queue_depth", len(self.queue))
         prom.gauge_set("running_requests",
-                       sum(s is not None for s in self.slots),
+                       sum(s is not None for s in self._slots),
                        help="slots occupied this step")
         prom.counter_inc("engine_steps_total", help="engine iterations")
         prom.counter_inc("dispatches_total",
@@ -1942,24 +2171,6 @@ class ServingEngine:
         prom.gauge_set("tokens_per_sec", self._tokens_total / elapsed,
                        help="tokens emitted since the first engine step / "
                             "elapsed wall time")
-        # completed == finished SUCCESSFULLY: a request failed by its
-        # own callback rides `finished` for page accounting but must not
-        # count as a completion (it already counted in
-        # callback_errors_total / serving_callback_error)
-        ok = [r for r in finished if r.status == "ok"]
-        prom.counter_inc("requests_completed_total", len(ok),
-                         help="requests finished successfully")
-        if ok:
-            from ..observability import get_event_log
-            log = get_event_log()
-            for r in ok:
-                prom.summary_observe(
-                    "request_seconds",
-                    time.perf_counter() - r.submit_time,
-                    help="submit-to-completion latency")
-                if log is not None:
-                    log.emit("serving_complete", role="serving", rid=r.rid,
-                             tokens=len(r.output), ttft_s=r.ttft_s)
         self._numerics_kv_poll()
 
     def metrics_text(self) -> str:
@@ -1971,6 +2182,16 @@ class ServingEngine:
     @property
     def prom(self):
         return self._prom
+
+    # -- what an observer outside a step may read: a settled engine ---------
+    slots = _settled_view("slots")
+    lens = _settled_view("lens")
+    k_pools = _settled_view("k_pools")
+    v_pools = _settled_view("v_pools")
+    k_scales = _settled_view("k_scales")
+    v_scales = _settled_view("v_scales")
+    ssm_state = _settled_view("ssm_state")
+    conv_tail = _settled_view("conv_tail")
 
     def serve_metrics(self, port: Optional[int] = None):
         """Start (or return) the /metrics HTTP endpoint — which also

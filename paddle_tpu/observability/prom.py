@@ -54,7 +54,7 @@ DEFAULT_BUCKETS: Tuple[float, ...] = (
 
 class _Metric:
     __slots__ = ("name", "mtype", "help", "value", "sum", "count",
-                 "buckets", "bucket_counts", "window")
+                 "buckets", "bucket_counts", "window", "by_label")
 
     def __init__(self, name: str, mtype: str, help_: str,
                  buckets: Optional[Sequence[float]] = None,
@@ -62,7 +62,8 @@ class _Metric:
         self.name = name
         self.mtype = mtype
         self.help = help_
-        self.value = 0.0   # counter/gauge
+        self.value = 0.0   # counter/gauge (a labelled counter: the total)
+        self.by_label: Dict[Tuple[Tuple[str, str], ...], float] = {}
         self.sum = 0.0     # summary/histogram
         self.count = 0
         self.buckets: Tuple[float, ...] = ()
@@ -99,10 +100,17 @@ class PromRegistry:
             return m
 
     # -- update surface ------------------------------------------------------
-    def counter_inc(self, name: str, amount: float = 1.0, help: str = ""):
+    def counter_inc(self, name: str, amount: float = 1.0, help: str = "",
+                    labels: Optional[Dict[str, str]] = None):
+        """`labels` splits the counter into one series a label set
+        (``name{reason="spec"}``); the metric's own value stays the
+        total over them."""
         m = self._get(name, "counter", help)
         with self._lock:
             m.value += amount
+            if labels:
+                key = tuple(sorted(labels.items()))
+                m.by_label[key] = m.by_label.get(key, 0.0) + amount
 
     def gauge_set(self, name: str, value: float, help: str = ""):
         m = self._get(name, "gauge", help)
@@ -147,13 +155,17 @@ class PromRegistry:
             name = name[len(prefix):]
         return self._metrics.get(name)
 
-    def get(self, name: str) -> Optional[float]:
-        """Current value (summaries/histograms: mean of observations);
-        None if the metric was never touched. Accepts the bare or
-        namespaced name."""
+    def get(self, name: str,
+            labels: Optional[Dict[str, str]] = None) -> Optional[float]:
+        """Current value (summaries/histograms: mean of observations;
+        a labelled counter: its total, or with `labels` that series, 0
+        where it was never touched); None if the metric was never
+        touched. Accepts the bare or namespaced name."""
         m = self._metric(name)
         if m is None:
             return None
+        if labels:
+            return m.by_label.get(tuple(sorted(labels.items())), 0.0)
         if m.mtype in ("summary", "histogram"):
             return m.sum / m.count if m.count else None
         return m.value
@@ -216,6 +228,10 @@ class PromRegistry:
                 lines.append(f'{full}_bucket{{le="+Inf"}} {m.count}')
                 lines.append(f"{full}_sum {_fmt(m.sum)}")
                 lines.append(f"{full}_count {m.count}")
+            elif m.by_label:
+                for key, v in sorted(m.by_label.items()):
+                    tags = ",".join(f'{k}="{val}"' for k, val in key)
+                    lines.append(f"{full}{{{tags}}} {_fmt(v)}")
             else:
                 lines.append(f"{full} {_fmt(m.value)}")
         return "\n".join(lines) + "\n"

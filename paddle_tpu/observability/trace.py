@@ -49,7 +49,8 @@ def _names(typename, **names):
 
 
 # One engine step: `step` is the whole call, the others are its children
-# and cover it without holes.
+# and cover it without holes. With one step in flight the call dispatches
+# step n+1 (pack, upload, dispatch) and THEN fetches and walks step n.
 SERVING_SPANS = _names(
     "ServingSpans",
     step="serving_step",            # the whole ServingEngine.step()
@@ -66,9 +67,11 @@ SERVING_SPANS = _names(
 # size, decode and prefill rows, packed query tokens, KV positions
 # attended (summed over the rows that run and over the step's k passes),
 # and the (row, page) pairs those positions fill: what the attention
-# kernel walks, of k x max_batch x max_blocks_per_seq table slots.
+# kernel walks, of k x max_batch x max_blocks_per_seq table slots; and
+# `in_flight`, 1 when the step before had not been fetched at this
+# dispatch (the device goes from one to the next without the host).
 DISPATCH_ATTRS = ("step", "k", "n_dec", "n_pre", "q_tokens", "kv_tokens",
-                  "attn_pages")
+                  "attn_pages", "in_flight")
 # A model with a recurrent state adds: rows whose state the first pass read
 # and wrote (the chunk scan's), rows of the k - 1 burst passes (the state
 # update's, summed), and tokens through its mixer over all k passes.

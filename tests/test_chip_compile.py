@@ -284,13 +284,14 @@ def _compile_unified_step(one_chip, K, kv_dtype, pages, share=False):
     args = [params, i32(TOKENS), i32(TOKENS), i32(TOKENS), i32(ROWS),
             i32(ROWS), i32(ROWS), i32(ROWS, TABLE), flags(), flags(),
             i32(ROWS), i32(ROWS), _sds(one_chip, (ROWS,), jnp.float32),
+            i32(ROWS),      # prev_tok: the slots' last tokens, device-kept
             _sds(one_chip, (2,), jnp.uint32), pool, pool, scales, scales]
     if share:   # cow_src, cow_dst, reset_tables
         args += [i32(ROWS), i32(ROWS), i32(ROWS, TABLE)]
     step = functools.partial(RS.unified_step, cfg=cfg, bs=PAGE,
                              c_att=C_ATT, K=K)
-    return jax.jit(step, donate_argnums=(14, 15, 16, 17) if quant
-                   else (14, 15)).lower(*args).compile()
+    return jax.jit(step, donate_argnums=(15, 16, 17, 18) if quant
+                   else (15, 16)).lower(*args).compile()
 
 
 @pytest.mark.parametrize("K,kv_dtype,pages,share", [
@@ -360,13 +361,13 @@ def test_falcon_h1_step_keeps_state_and_pool_in_place(one_chip,
     args = [params, i32(tokens), i32(tokens), i32(tokens), i32(H1_ROWS),
             i32(H1_ROWS), i32(H1_ROWS), i32(H1_ROWS, H1_TABLE), flags(),
             flags(), i32(H1_ROWS), i32(H1_ROWS),
-            _sds(one_chip, (H1_ROWS,), jnp.float32),
+            _sds(one_chip, (H1_ROWS,), jnp.float32), i32(H1_ROWS),
             _sds(one_chip, (2,), jnp.uint32), pool, pool, None, None, None,
             None, None, _sds(one_chip, state_shape, jnp.float32),
             _sds(one_chip, tail_shape, jnp.bfloat16)]
     step = functools.partial(RS.unified_step, cfg=cfg, bs=PAGE,
                              c_att=cfg.ssm_chunk, K=K)
-    compiled = jax.jit(step, donate_argnums=(14, 15, 21, 22)
+    compiled = jax.jit(step, donate_argnums=(15, 16, 22, 23)
                        ).lower(*args).compile()
     text = compiled.as_text()
     for kernel in ("ssm_conv", "ssm_chunk_scan", "ragged_paged_attn",

@@ -79,7 +79,7 @@ class Logits:
     """Every emitted token's logits row, by request."""
 
     def __init__(self, monkeypatch):
-        self.passes, self.by_rid, self._walk = [], {}, {}
+        self.passes, self.by_rid, self._walk, self._packed = [], {}, {}, 0
         real = RS._sample
 
         def spy(logits, temps, key):
@@ -89,10 +89,19 @@ class Logits:
         monkeypatch.setattr(RS, "_sample", spy)
 
     def watch(self, eng):
-        walk, emit = eng._walk_ragged, eng._emit
+        walk, emit, pack = eng._walk_ragged, eng._emit, eng._pack_ragged
+        first = {}      # a packed step -> its first pass's place (a step
+        #                 is walked while the next one's passes arrive)
+
+        def _pack(fresh):
+            b = pack(fresh)
+            if b is not None:
+                first[id(b)] = self._packed
+                self._packed += b.K
+            return b
 
         def _walk(b, *a):
-            self._walk = {"base": len(self.passes) - b.K, "n": {}}
+            self._walk = {"base": first.pop(id(b)), "n": {}}
             return walk(b, *a)
 
         def _emit(r, tok):
@@ -101,7 +110,7 @@ class Logits:
             self.by_rid.setdefault(r.rid, []).append(
                 self.passes[self._walk["base"] + t][r.slot])
             return emit(r, tok)
-        eng._walk_ragged, eng._emit = _walk, _emit
+        eng._walk_ragged, eng._emit, eng._pack_ragged = _walk, _emit, _pack
         return eng
 
 
@@ -156,8 +165,9 @@ def test_mixed_steps_decode_rows_beside_prefill_chunks(params, logits):
     for p in ps:
         rids.append(eng.add_request(p, 9))
         for _ in range(2):
-            live = [r for r in eng.slots if r is not None]
-            dec = sum(r.prefill_done >= len(r.prompt) for r in live)
+            # committed progress, read without settling the step in flight
+            live = [r for r in eng.snapshot()["slots"] if r is not None]
+            dec = sum(r["prefill_done"] >= r["prompt_len"] for r in live)
             mixed += bool(eng.queue or dec < len(live)) and dec > 0
             for r in eng.step():
                 outs[r.rid] = r.output
@@ -285,6 +295,80 @@ def test_what_the_hybrid_cannot_be_served_with_raises_at_construction(
         kw = dict(mesh=Mesh(np.array(jax.devices()[:2]), ("mp",)))
     with pytest.raises(EnforceNotMet, match=word):
         ServingEngine(params, toy_cfg(), **dict(ENGINE, **kw))
+
+
+# -- one step in flight (ISSUE 31) --------------------------------------------
+def test_one_step_in_flight_is_the_synchronous_order(params):
+    """The same arrivals through `step()` alone and through `step();
+    settle()`: every output token for token, and at a settled checkpoint
+    the recurrent state and conv tail bit for bit beside lens, tables and
+    pools."""
+    from serving_overlap import assert_same_state, both
+    ps = prompts(4, seed=5)
+    news = [19, 30, 14, 23]
+    script = {}
+    for p, n, at in zip(ps, news, [0, 0, 2, 3]):
+        script.setdefault(at, []).append(dict(prompt=p, max_new_tokens=n))
+    flight, sync = both(lambda: ServingEngine(params, toy_cfg(), **ENGINE),
+                        script, checkpoint=6)
+    assert flight.outputs() == sync.outputs()
+    assert [len(out) for _, out in flight.outputs()] == news
+    assert_same_state(flight.state, sync.state)
+    assert np.abs(flight.state["ssm"]).max() > 0
+    assert flight.state["lens"].any()
+    eng = flight.eng
+    want = [1] * eng.dispatches
+    want[0] = want[6] = 0       # the first; the one after the checkpoint
+    assert flight.in_flight == want
+    assert eng.dispatches == eng.engine_steps
+    assert eng.compiled_cache_entries() == len(eng._unified_cache)
+    assert eng.prom.get("ssm_state_resets_total") == \
+        sync.eng.prom.get("ssm_state_resets_total") == 4
+
+
+def test_an_observer_between_steps_reads_a_settled_state(params,
+                                                         monkeypatch):
+    """The benchmark's runner (`serve_closed_h1.states_in_flight`, not this
+    repo's to edit) reads, after a `step()` and with no engine call
+    between: `slots`, then a request's `output`, `lens`, `ssm_state`.
+    With a step in flight the buffer is a burst ahead of the walked
+    tokens: the first of those reads settles, and the state covers
+    exactly `lens` tokens of prompt + output. `snapshot()`, which the
+    runner calls after every step of its window, fetches nothing and
+    reports committed progress."""
+    from chipbench.runners.serve_closed_h1 import (state_errors,
+                                                   states_in_flight)
+    eng = ServingEngine(params, toy_cfg(), **ENGINE)     # bursts of 4
+    rids = [eng.add_request(p, 40) for p in prompts(3, seed=6)]
+    fetches = []
+    real = jax.device_get
+    monkeypatch.setattr(jax, "device_get",
+                        lambda x: fetches.append(1) or real(x))
+    for _ in range(7):
+        eng.step()
+        n = len(fetches)
+        snap = eng.snapshot()
+        assert len(fetches) == n and eng._flight is not None
+        for s, r in zip(snap["slots"], eng._slots):
+            if r is not None:
+                assert (s["emitted"], s["prefill_done"]) == (
+                    len(r.output), r.prefill_done)
+    behind = {r.rid: len(r.output) for r in eng._slots if r is not None}
+    held = states_in_flight(eng, 8, seed=0, fresh=set(rids[-1:]))
+    assert eng._flight is None
+    assert eng.prom.get("overlap_settles_total",
+                        labels={"reason": "observer"}) == 1
+    assert len(held) == 3
+    for slot, tokens, _ in held:
+        r = eng._slots[slot]
+        assert len(r.output) == behind[r.rid] + 4       # the burst landed
+        assert len(tokens) == len(r.prompt) + len(r.output) - 1
+    errs = state_errors(params, {"widths": W, "multipliers": M}, held, 96)
+    assert max(errs) < 1e-5, errs
+    # a state a burst ahead of the tokens it is held against is not close
+    stale = [(slot, tokens[:-4], state) for slot, tokens, state in held]
+    assert min(state_errors(params, {"widths": W, "multipliers": M},
+                            stale, 96)) > 1e-2
 
 
 def test_the_dispatch_span_counts_state_rows_and_tokens(params):

@@ -422,6 +422,7 @@ def test_flags_off_unified_program_byte_identical(params):
             jnp.zeros((R, nb), jnp.int32), jnp.zeros((R,), bool),
             jnp.zeros((R,), bool), jnp.zeros((R,), jnp.int32),
             jnp.full((R,), -1, jnp.int32), jnp.zeros((R,), jnp.float32),
+            jnp.zeros((R,), jnp.int32),                      # prev_tok
             jax.random.PRNGKey(0), e_auto.k_pools, e_auto.v_pools)
     assert (e_auto._unified(1).lower(*args).as_text()
             == e_off._unified(1).lower(*args).as_text())

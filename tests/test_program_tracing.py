@@ -143,11 +143,19 @@ def test_one_ragged_step_yields_every_serving_span_once():
         eng.step()                      # both rows decode from here on
     eng.add_request(np.arange(20) % 64, max_new_tokens=4)
     eng.step()                          # the newcomer is admitted
+    # reading `slots` settles: the expectation is taken from settled state
     n_dec, n_pre, q_tokens, rows = _expected_dispatch(eng)
     assert n_dec == 2 and n_pre == 1
     micro0 = eng.decode_microsteps
+    with obs.capture_spans() as first:
+        eng.step()      # nothing in flight: dispatches, fetches nothing
+    k = eng.decode_microsteps - micro0
     with obs.capture_spans() as cap:
-        eng.step()
+        eng.step()      # dispatches the next step, THEN lands that one
+    assert [e.name for e in first.events] == [
+        SERVING_SPANS.sweep, SERVING_SPANS.admission, SERVING_SPANS.pack,
+        SERVING_SPANS.upload, SERVING_SPANS.dispatch, SERVING_SPANS.metrics,
+        SERVING_SPANS.step]
     by_name = {}
     for e in cap.events:
         by_name.setdefault(e.name, []).append(e)
@@ -160,20 +168,25 @@ def test_one_ragged_step_yields_every_serving_span_once():
     assert all(step.start <= c.start and c.end <= step.end
                for c in children)
     order = sorted(children, key=lambda c: c.start)
+    assert [c.name for c in order] == [
+        SERVING_SPANS.sweep, SERVING_SPANS.admission, SERVING_SPANS.pack,
+        SERVING_SPANS.upload, SERVING_SPANS.dispatch, SERVING_SPANS.fetch,
+        SERVING_SPANS.walk, SERVING_SPANS.metrics]
     assert all(a.end <= b.start for a, b in zip(order, order[1:]))
     assert sum(c.duration for c in children) >= 0.95 * step.duration
-    attrs = by_name[SERVING_SPANS.dispatch][0].attrs
+    assert by_name[SERVING_SPANS.dispatch][0].attrs["in_flight"] == 1
+    attrs = [e.attrs for e in first.events
+             if e.name == SERVING_SPANS.dispatch][0]
     assert tuple(attrs) == DISPATCH_ATTRS
-    k = eng.decode_microsteps - micro0
     kv = sum(end for end, _, _ in rows) + sum(
         end + j for j in range(1, k) for end, samples, left in rows
         if samples and left > j)
     pages = sum(-(-end // 16) for end, _, _ in rows) + sum(
         -(-(end + j) // 16) for j in range(1, k)
         for end, samples, left in rows if samples and left > j)
-    assert attrs == {"step": eng.engine_steps, "k": k, "n_dec": n_dec,
+    assert attrs == {"step": eng.engine_steps - 1, "k": k, "n_dec": n_dec,
                      "n_pre": n_pre, "q_tokens": q_tokens, "kv_tokens": kv,
-                     "attn_pages": pages}
+                     "attn_pages": pages, "in_flight": 0}
 
 
 def test_attn_pages_counts_the_row_page_pairs_of_a_hand_built_step():

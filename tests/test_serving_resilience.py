@@ -482,7 +482,7 @@ def test_sigterm_drain_finishes_inflight_requeues_queued(params, tmp_path):
 
 
 def test_spawned_kill_and_replay_bitwise(params, tmp_path):
-    """Acceptance (ISSUE 13): worker hard-killed by serving/step:3:kill
+    """Acceptance (ISSUE 13): worker hard-killed by serving/step:4:kill
     (os._exit — a real crash), respawned onto the same journal; outputs
     bitwise-identical to the uninterrupted spawn, exactly-once delivery
     across the process boundary, zero leaked KV pages."""
