@@ -20,8 +20,14 @@ Layout contract (host side, `ServingEngine._pack_ragged`): the packed
 buffer holds each active row's tokens contiguously at ``starts[r]``;
 ``row_of/off_of`` map packed positions back to (row, chunk offset) and
 tail padding points past every row's ``q_len`` (masked everywhere).
-Attention tiles are gathered per row to a static ``[R, c_att]`` window —
-the GEMM stages, where the FLOPs live, stay unpadded.
+Attention reads and writes the packed buffer itself: the kernel takes
+the ``[T, H_q, D]`` queries with ``starts`` and ``q_lens``, each row
+copies its own positions in and out, and padding comes back zero — no
+``[R, c_att]`` tile of queries or of outputs exists in the step. The
+per-row window ``tile_idx [R, c_att]`` remains for the two consumers that
+still want a row's tokens as a tile: the quantized append (which
+requantizes whole pages, `append_tokens_quantized`) and a recurrent
+mixer's chunk scan (``plan["tile_idx"]``, `models/falcon_h1.py`).
 
 The KV pool's contract (the one place it is stated; the kernels and
 `quantization.kv_cache` refer here). The K and the V pool are ONE buffer
@@ -99,8 +105,9 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
         tile = append_tile(kp.dtype, bs)
         work = tile_work(starts, pos0, q_lens, tables, bs=bs, tile=tile,
                          c_att=c_att, T=T)
-    # per-row attention tile gather (clamped duplicates are masked by the
-    # kernel's c < q_len predicate)
+    # a row's tokens as a [c_att] window of the packed buffer, for the
+    # quantized append and a recurrent mixer (clamped duplicates lie past
+    # the row's q_len, which both mask); attention takes the packed buffer
     tile_idx = jnp.clip(
         starts[:, None] + jnp.minimum(jnp.arange(c_att)[None, :],
                                       jnp.maximum(q_lens - 1, 0)[:, None]),
@@ -128,10 +135,9 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
             else:
                 kp, vp = kv_append(kp, vp, k[0], v[0], li, work, tile=tile)
         with jax.named_scope(SCOPES.ragged_attn):
-            attn_t = ragged_paged_attention(
-                q[0][tile_idx], kp, vp, tables, q_lens, kv_lens, scale,
-                ks, vs, li)                                  # [R,c_att,h,D]
-            attn_p = attn_t[row_of, jnp.minimum(off_of, c_att - 1)]
+            attn_p = ragged_paged_attention(
+                q[0], kp, vp, tables, starts, q_lens, kv_lens, scale,
+                ks, vs, li, c_att=c_att)                     # [T, h, D]
         x = model.block_math(p, x, attn_p[None], mixed, cfg, mp_axis)
         return (x, kp, vp, ks, vs, ssm), None
 

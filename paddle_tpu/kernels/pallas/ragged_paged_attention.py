@@ -9,9 +9,10 @@ ONE compiled dispatch instead of a prefill program plus a decode program
 (Ragged Paged Attention, arXiv:2604.15464; reference block kernels
 paddle/phi/kernels/fusion/gpu/block_multi_head_attention_kernel.cu).
 
-The kernel's cost follows ``(q_lens, kv_lens, tables)``, not the static
-shapes ``(R, H_kv, nb, C)`` (paged_attention.py is the decode-only
-kernel `models/generation.py` calls; the serving engine does not):
+The kernel's cost follows ``(starts, q_lens, kv_lens, tables)``, not the
+static shapes ``(R, H_kv, nb, c_att)`` (paged_attention.py is the
+decode-only kernel `models/generation.py` calls; the serving engine does
+not):
 
   * the pool is the serving engine's WHOLE buffer, layer-major then
     head-major: ``[L, H_kv, num_blocks, bs, D]`` (+ ``[L, H_kv,
@@ -33,18 +34,31 @@ kernel `models/generation.py` calls; the serving engine does not):
     stream does not drain between rows (grid steps run in order:
     ``dimension_semantics`` is ``arbitrary``). A table slot nobody owns
     costs nothing — no step, no fetch; an empty row (q_len = 0) costs its
-    grid step and the zeros it emits. Bytes and time both follow the
+    grid step and nothing else. Bytes and time both follow the
     descriptors;
-  * queries come and go as the caller has them, ``[C, H_q, D]`` a row
-    (no transposed copy of the ``[R, C, H_q, D]`` tiles in or out: 2 x 67
-    MB a layer at the GPT cells' shapes, 18% of a docs step once the
-    kernel itself was short). A row stages its queries head-major in VMEM
-    once, folding the GQA group into the rows of one ``[g * c, D]`` MXU
-    operand a KV head (row f is query head f // c of the group at chunk
-    position f % c), and un-folds its output the same way; in-kernel
-    masking applies BOTH raggedness (``c < q_len``) and causality
-    (``col_pos <= kv_len - q_len + c``), so decode rows and prefill chunks
-    share the grid with no inter-row padding;
+  * queries come and go PACKED, as the step has them: ``q`` and the
+    output are the ``[T, H_q, D]`` buffer that leaves `model.qkv` and
+    enters `model.block_math`, row r at positions ``[starts[r], starts[r]
+    + q_lens[r])``, rows in any order (the host lays decode rows by slot,
+    then prefill rows). Both stay in HBM beside the pool. A live row
+    copies its own positions into VMEM with ONE copy of static size (the
+    narrow arm its ``8 // g`` positions, the wide arm the chunk), begun
+    early enough to end inside the buffer and started where its first
+    page copy is started, and writes back exactly its ``q_len`` positions
+    in copies of static size (whole blocks of 8 positions, then single
+    ones: at most ``c_att // 8 + 7``, waited for when the next live row's
+    output is due). The output is aliased to a zeroed operand, so a position no
+    row owns reads zero. No ``[R, c_att, H_q, D]`` tile of queries or of
+    outputs exists on either side of the call (at the GPT cells' shapes
+    the gather that built them wrote 33.5 MB a layer and the kernel moved
+    0.5 MB a row in and out, live or not, for 0.8 MB of queries; PERF.md,
+    PR 34). A row stages its queries head-major in VMEM once, folding the
+    GQA group into the rows of one ``[g * c, D]`` MXU operand a KV head
+    (row f is query head f // c of the group at chunk position f % c),
+    and un-folds its output the same way; in-kernel masking applies BOTH
+    raggedness (``c < q_len``) and causality (``col_pos <= kv_len - q_len
+    + c``), so decode rows and prefill chunks share the grid with no
+    inter-row padding;
   * a row's arithmetic is sized by its own ``q_len``, in two arms chosen
     from the prefetched descriptor (``pl.when``, static slices in each):
     a row whose folded queries fit one sublane tile (``q_len <= 8 // g``:
@@ -53,7 +67,7 @@ kernel `models/generation.py` calls; the serving engine does not):
     ONE ``[H_kv, 8, bs]`` soft-max update (the heads' products are
     independent, so the MXU and the vector units pipeline across them);
     any other row runs the whole chunk, head by head. Both arms write the
-    same output tile; chunk positions past the arm's are zeros;
+    row's own ``q_len`` positions and no other;
   * optional int8 / fp8 KV: pools stored quantized with per-(head, page)
     scales in the module's absmax convention (quantization/: dequant =
     q·s/qmax), dequantized IN-KERNEL: the page's values widen exactly to
@@ -62,8 +76,7 @@ kernel `models/generation.py` calls; the serving engine does not):
     streams half the HBM bytes per step and a fixed pool budget admits
     ~2x the sequences;
   * online softmax (f32 scores, statistics and accumulator) in VMEM
-    scratch, exactly like the training flash kernel; empty rows emit
-    zeros.
+    scratch, exactly like the training flash kernel.
 
 Naming rule: every ``pallas_call`` that does attention for the ragged
 step is named ``KERNELS.ragged_paged_attn``. The benchmark's
@@ -96,23 +109,23 @@ _NEG_INF = -1e30
 _NARROW = 8
 
 
-def _ragged_kernel(*refs, scale, bs, g, quantized, qmax):
-    if quantized:
-        (tables_ref, qlens_ref, kvlens_ref, layer_ref, next_ref, ks_ref,
-         vs_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, stream,
-         qs, ot, m_sc, l_sc, acc_sc) = refs
-    else:
-        (tables_ref, qlens_ref, kvlens_ref, layer_ref, next_ref,
-         q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, stream,
-         qs, ot, m_sc, l_sc, acc_sc) = refs
-        ks_ref = vs_ref = None
+def _ragged_kernel(*refs, scale, bs, hq, C, quantized, qmax):
+    # scalar prefetch (the quantized pools' two scale tables last), the
+    # operands where they lie in HBM (the fourth is the zeroed buffer the
+    # output aliases), the output, scratch
+    (tables_ref, starts_ref, qlens_ref, kvlens_ref, layer_ref, next_ref,
+     *scales, q_hbm, k_hbm, v_hbm, _, o_hbm, kbuf, vbuf, sem, io_sem,
+     stream, qin, obuf, qs, ot, m_sc, l_sc, acc_sc) = refs
+    ks_ref, vs_ref = scales if quantized else (None, None)
     r = pl.program_id(0)
     R, nb = tables_ref.shape
-    C, hq = q_ref.shape[1], q_ref.shape[2]
+    T = q_hbm.shape[0]
     hkv = qs.shape[0]
+    g = hq // hkv
     ql = qlens_ref[r]
     kl = kvlens_ref[r]
     layer = layer_ref[0]
+    cn = min(_NARROW // g, C)   # chunk positions the narrow arm holds
 
     def page_copies(row, j, slot):
         """Every KV head's tile of `row`'s j-th page: K and V, one strided
@@ -123,26 +136,73 @@ def _ragged_kernel(*refs, scale, bs, g, quantized, qmax):
                 pltpu.make_async_copy(v_hbm.at[layer, :, page],
                                       vbuf.at[slot], sem.at[1, slot]))
 
+    def query_begin(row, ch):
+        """Where the copy of `row`'s `ch` positions begins: at the row's
+        start, or earlier where that keeps it inside the buffer."""
+        return jax.lax.min(starts_ref[row], T - ch)
+
+    def query_copy(row, ch):
+        """`row`'s first `ch` packed positions into `qin`: one copy of
+        static size (the row's positions sit `starts - query_begin` rows
+        down; what it over-reads is a neighbour's or padding, masked by
+        c < q_len)."""
+        return pltpu.make_async_copy(
+            q_hbm.at[pl.ds(query_begin(row, ch), ch)],
+            qin.at[pl.ds(0, ch)], io_sem.at[0])
+
     def start(row, j, slot):
         for copy in page_copies(row, j, slot):
             copy.start()
 
-    # `stream`: the buffer the next page copy lands in, and whether this
-    # row's first page is already in flight (started by the row before)
+    def start_row(row, slot):
+        """`row`'s first page and its queries, at the size its arm reads."""
+        start(row, 0, slot)
+        if 0 < cn < C:
+            narrow = qlens_ref[row] <= cn
+            pl.when(narrow)(lambda: query_copy(row, cn).start())
+            pl.when(jnp.logical_not(narrow))(
+                lambda: query_copy(row, C).start())
+        else:
+            query_copy(row, C).start()
+
+    def output_copies(n, at, act):
+        """`n` positions of `obuf` to packed positions [at, at + n) of the
+        output, in copies of static size: whole blocks of 8 positions,
+        then single ones. A row writes its own positions and no other
+        (rows are not packed in row order, so nothing repairs an
+        overshoot). Two loops, no conditional a size: every line of the
+        kernel is traced and lowered in every program's set-up."""
+        def piece(off, size):
+            act(pltpu.make_async_copy(
+                obuf.at[pl.ds(off, size)],
+                o_hbm.at[pl.ds(at + off, size)], io_sem.at[1]))
+
+        whole = jax.lax.div(n, _NARROW) if C >= _NARROW else 0
+        if C >= _NARROW:
+            jax.lax.fori_loop(
+                0, whole, lambda i, _: piece(i * _NARROW, _NARROW), None)
+        jax.lax.fori_loop(whole * _NARROW, n, lambda i, _: piece(i, 1), None)
+
+    def land_output():
+        """Wait for the writes still in flight (stream[2] positions at
+        stream[3]) before `obuf` is filled again or the call ends."""
+        output_copies(stream[2], stream[3], lambda copy: copy.wait())
+        stream[2] = 0
+
+    # `stream`: the buffer the next page copy lands in, whether this row's
+    # first page and queries are already in flight (started by the row
+    # before), and the output still being written (positions, start)
     @pl.when(r == 0)
     def _first_row():
         stream[0] = 0
         stream[1] = 0
-
-    # an empty row, and a live row's positions past its q_len, emit zeros
-    o_ref[...] = jnp.zeros_like(o_ref)
+        stream[2] = 0
 
     def arm(ch, nh):
         """The row's first `ch` chunk positions, folded group-major into
         `rows` = g * ch rows a KV head (row f is query head f // ch of the
         group at chunk position f % ch), `nh` heads a soft-max update."""
         rows = max(g * ch, _NARROW)
-        filled = jax.lax.min(ql, ch)    # chunk positions that hold a query
         # the row's queries, head-major: staged once, read every page. One
         # [H_q, D] tile a chunk position, a loop as long as the row's own
         # q_len (unrolled, the chunk's C x H_q single-row moves were most
@@ -150,13 +210,21 @@ def _ragged_kernel(*refs, scale, bs, g, quantized, qmax):
         if g * ch < rows:
             qs[:, :rows] = jnp.zeros((hkv, rows, qs.shape[2]), qs.dtype)
 
+        @pl.when(stream[1] == 0)
+        def _own_first_copies():
+            start(r, 0, stream[0])
+            query_copy(r, ch).start()
+
+        query_copy(r, ch).wait()
+        down = starts_ref[r] - query_begin(r, ch)
+
         def stage(c, carry):
-            tile = q_ref[0, c].astype(qs.dtype)
+            tile = qin[down + c].astype(qs.dtype)
             for j in range(hq):
                 qs[j // g, pl.ds(j % g * ch + c, 1), :] = tile[j:j + 1]
             return carry
 
-        jax.lax.fori_loop(0, filled, stage, None)
+        jax.lax.fori_loop(0, ql, stage, None)
         m_sc[:, :rows] = jnp.full((hkv, rows, _LANES), _NEG_INF, jnp.float32)
         l_sc[:, :rows] = jnp.zeros((hkv, rows, _LANES), jnp.float32)
         acc_sc[:, :rows] = jnp.zeros((hkv, rows) + acc_sc.shape[2:],
@@ -166,10 +234,6 @@ def _ragged_kernel(*refs, scale, bs, g, quantized, qmax):
         n = jax.lax.clamp(1, jax.lax.div(kl + bs - 1, bs), nb)
         base = stream[0]
         nxt = next_ref[r]
-
-        @pl.when(stream[1] == 0)
-        def _own_first_page():
-            start(r, 0, base)
 
         # row f of the folded tile is chunk position c = f % ch; its
         # absolute query position is kv_len - q_len + c (the chunk holds
@@ -188,7 +252,7 @@ def _ragged_kernel(*refs, scale, bs, g, quantized, qmax):
 
             @pl.when((j + 1 == n) & (nxt < R))
             def _next_row():
-                start(nxt, 0, 1 - slot)
+                start_row(nxt, 1 - slot)
 
             for copy in page_copies(r, j, slot):
                 copy.wait()
@@ -211,7 +275,7 @@ def _ragged_kernel(*refs, scale, bs, g, quantized, qmax):
                 """One soft-max update of heads [h0, h0 + nh): the heads
                 are the batch dimension of the two contractions."""
                 hs = pl.ds(h0, nh)
-                q = qs[hs, :rows, :].astype(q_ref.dtype)  # [nh, rows, D]
+                q = qs[hs, :rows, :].astype(qin.dtype)  # [nh, rows, D]
                 k = kbuf[slot, hs]                        # [nh, bs, D]
                 v = vbuf[slot, hs]
                 sk = scale
@@ -259,40 +323,71 @@ def _ragged_kernel(*refs, scale, bs, g, quantized, qmax):
         inv = jnp.where(dead, 0.0, 1.0 / jnp.maximum(l, 1e-37))
         acc_sc[:, :rows] = acc_sc[:, :rows] * inv
 
-        def emit(c, carry):     # un-fold: the [H_q, D] tile of position c
+    def write_back(ch):
+        """The row's output, un-folded from the arm's `ch`-position tiles
+        into `obuf`, on its way to the row's packed positions. One body
+        for both arms (`ch` is a scalar then): every line of the kernel is
+        traced and lowered in every program's set-up."""
+        land_output()
+
+        def emit(c, carry):     # the [H_q, D] tile of position c
             for j in range(hq):
                 ot[j:j + 1, :] = acc_sc[j // g, pl.ds(j % g * ch + c, 1), :]
-            o_ref[0, c] = ot[...].astype(o_ref.dtype)
+            obuf[c] = ot[...].astype(obuf.dtype)
             return carry
 
-        jax.lax.fori_loop(0, filled, emit, None)
+        jax.lax.fori_loop(0, ql, emit, None)
+        output_copies(ql, starts_ref[r], lambda copy: copy.start())
+        stream[2] = ql
+        stream[3] = starts_ref[r]
 
     # A row's arithmetic is sized by its own q_len: folded queries that
     # fit one sublane tile run on 8 rows, every head of a page in one
     # update; any other row on the whole chunk, a head an update (a
     # [g * C, bs] f32 score tile is 16 vregs or more already).
-    cn = min(_NARROW // g, C)   # chunk positions the narrow arm holds
     if 0 < cn < C:
         narrow = ql <= cn
         pl.when((ql > 0) & narrow)(lambda: arm(cn, hkv))
         pl.when((ql > 0) & jnp.logical_not(narrow))(lambda: arm(C, 1))
+        pl.when(ql > 0)(lambda: write_back(
+            jax.lax.select(narrow, jnp.int32(cn), jnp.int32(C))))
     else:
-        pl.when(ql > 0)(lambda: arm(C, hkv if g * C <= _NARROW else 1))
+        @pl.when(ql > 0)
+        def _one_arm():
+            arm(C, hkv if g * C <= _NARROW else 1)
+            write_back(C)
+    pl.when(r == R - 1)(land_output)
 
 
-def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_lens,
-                           kv_lens, scale: float,
-                           k_scales=None, v_scales=None, layer=0):
-    """q: [R, C, H_q, D] — row r's chunk occupies columns [0, q_lens[r]);
+def ragged_paged_attention(q, k_pool, v_pool, block_tables, starts, q_lens,
+                           kv_lens, scale: float, k_scales=None,
+                           v_scales=None, layer=0, *, c_att: int):
+    """q: [T, H_q, D], the step's PACKED queries — row r's chunk occupies
+    positions [starts[r], starts[r] + q_lens[r]), rows in any order, no two
+    overlapping; ``c_att`` (static) is the longest chunk a row may hold:
+    it sizes the kernel's VMEM and picks the arms;
     pools: [L, H_kv, num_blocks, bs, D] (float, or int8 with k_scales /
     v_scales: [L, H_kv, num_blocks] f32 per-page absmax scales) and
     ``layer`` the (traced) int32 index of the layer to attend over — or
     one layer's [H_kv, num_blocks, bs, D] pool (+ [H_kv, num_blocks]
     scales), which is the L = 1 form of the same call;
-    block_tables: [R, nb] int32; q_lens: [R] int32 (0 = inactive row);
-    kv_lens: [R] int32 — TOTAL kv length including this chunk (query c
-    sits at absolute position kv_lens - q_lens + c) → [R, C, H_q, D]."""
-    R, C, hq, D = q.shape
+    block_tables: [R, nb] int32; starts: [R] int32; q_lens: [R] int32
+    (0 = inactive row, whose ``starts`` is not read); kv_lens: [R] int32 —
+    TOTAL kv length including this chunk (query c sits at absolute
+    position kv_lens - q_lens + c) → [T, H_q, D], packed as q is: a row's
+    positions hold its output, every other position reads zero."""
+    T, hq, D = q.shape
+    R = block_tables.shape[0]
+    C = min(c_att, T)       # no row holds more positions than the buffer
+    # a position is copied whole, so its [H_q, D] must be whole tiles: a
+    # packed dtype tiles H_q by the power of two above it, up to 8 (Mosaic
+    # refuses to slice [T, 20, 128] bf16 by position); the heads added
+    # are copied with the others and never staged
+    tiled = (1 if q.dtype.itemsize >= 4
+             else min(8, 1 << max(hq - 1, 1).bit_length()))
+    hp = -(-hq // tiled) * tiled
+    if hp > hq:
+        q = jnp.pad(q, ((0, 0), (0, hp - hq), (0, 0)))
     if k_pool.ndim == 4:  # one layer's pool: a free leading-1 reshape
         k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
     elif k_scales is not None:
@@ -310,58 +405,58 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, q_lens,
     CG8 = max(_NARROW, -(-C * g // 8) * 8)  # a head's folded tile
     q_lens = q_lens.astype(jnp.int32)
     # the next row that has work (R past the last): a row starts that
-    # row's first page copy beside its own last page's arithmetic
+    # row's first copies beside its own last page's arithmetic
     rows = jnp.arange(R, dtype=jnp.int32)
     later_live = (rows[None, :] > rows[:, None]) & (q_lens > 0)[None, :]
     next_live = jnp.min(jnp.where(later_live, rows[None, :], R), axis=1)
 
-    def q_idx(r, *prefetch):
-        return (r, 0, 0, 0)
-
     # staged in 32 bits: a packed dtype has no single-row loads or stores
     stage = jnp.float32 if q.dtype.itemsize < 4 else q.dtype
 
-    prefetch = [block_tables.astype(jnp.int32), q_lens,
-                kv_lens.astype(jnp.int32),
+    prefetch = [block_tables.astype(jnp.int32), starts.astype(jnp.int32),
+                q_lens, kv_lens.astype(jnp.int32),
                 jnp.asarray(layer, jnp.int32).reshape(1), next_live]
     if quantized:
         prefetch += [k_scales.astype(jnp.float32),
                      v_scales.astype(jnp.float32)]
     tile = hkv * CG8 * D                    # a row's folded query tile
     page = 2 * hkv * bs * D * k_pool.dtype.itemsize     # two buffers
-    vmem = (2 * 2 * C * hq * D * q.dtype.itemsize + 2 * page
+    vmem = (2 * C * hp * D * q.dtype.itemsize + 2 * page
             + 4 * tile * (2 + 2 * _LANES // D))
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(prefetch),
         grid=(R,),
-        in_specs=[
-            pl.BlockSpec((1, C, hq, D), q_idx),
-            pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((1, C, hq, D), q_idx),
+        in_specs=[hbm, hbm, hbm, hbm],
+        out_specs=hbm,
         scratch_shapes=[
             pltpu.VMEM((2, hkv, bs, D), k_pool.dtype),
             pltpu.VMEM((2, hkv, bs, D), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
-            pltpu.SMEM((2,), jnp.int32),
+            pltpu.SemaphoreType.DMA((2,)),      # queries in, output out
+            pltpu.SMEM((4,), jnp.int32),
+            pltpu.VMEM((C, hp, D), q.dtype),
+            pltpu.VMEM((C, hp, D), q.dtype),
             pltpu.VMEM((hkv, CG8, D), stage),
-            pltpu.VMEM((hq, D), jnp.float32),
+            pltpu.VMEM((hp, D), jnp.float32),
             pltpu.VMEM((hkv, CG8, _LANES), jnp.float32),
             pltpu.VMEM((hkv, CG8, _LANES), jnp.float32),
             pltpu.VMEM((hkv, CG8, D), jnp.float32),
         ],
     )
+    # the output starts as zeros and is written in place: positions that
+    # belong to no row (the buffer's tail padding) read zero
     out = pl.pallas_call(
-        functools.partial(_ragged_kernel, scale=scale, bs=bs, g=g,
+        functools.partial(_ragged_kernel, scale=scale, bs=bs, hq=hq, C=C,
                           quantized=quantized, qmax=qmax),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, C, hq, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        input_output_aliases={len(prefetch) + 3: 0},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             # the buffers above and as much again for the score tiles
             vmem_limit_bytes=min(max(2 * vmem, 32 << 20), 96 << 20)),
         interpret=_interpret(),
         name=KERNELS.ragged_paged_attn,
-    )(*prefetch, q, k_pool, v_pool)
-    return out
+    )(*prefetch, q, k_pool, v_pool, jnp.zeros_like(q))
+    return out[:, :hq]

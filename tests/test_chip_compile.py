@@ -143,7 +143,7 @@ def test_ragged_paged_attention(one_chip, compiled_kernels, kv_dtype):
     from paddle_tpu.kernels.pallas.ragged_paged_attention import (
         ragged_paged_attention)
     rows, chunk = 8, 128
-    q = _sds(one_chip, (rows, chunk, HEADS, HEAD_DIM), jnp.bfloat16)
+    q = _sds(one_chip, (rows + chunk, HEADS, HEAD_DIM), jnp.bfloat16)
     pool = _sds(one_chip, (HEADS, NUM_PAGES, PAGE, HEAD_DIM),
                 jnp.int8 if kv_dtype == "int8" else jnp.bfloat16)
     tables = _sds(one_chip, (rows, PAGES_PER_SEQ), jnp.int32)
@@ -152,23 +152,29 @@ def test_ragged_paged_attention(one_chip, compiled_kernels, kv_dtype):
     if kv_dtype == "int8":
         scales = _sds(one_chip, (HEADS, NUM_PAGES), jnp.float32)
 
-        def fn(q, kp, vp, tables, q_lens, kv_lens, ks, vs):
-            return ragged_paged_attention(q, kp, vp, tables, q_lens,
-                                          kv_lens, scale, ks, vs)
-        _compile(fn, q, pool, pool, tables, lens, lens, scales, scales)
+        def fn(q, kp, vp, tables, starts, q_lens, kv_lens, ks, vs):
+            return ragged_paged_attention(q, kp, vp, tables, starts, q_lens,
+                                          kv_lens, scale, ks, vs,
+                                          c_att=chunk)
+        _compile(fn, q, pool, pool, tables, lens, lens, lens, scales, scales)
     else:
-        def fn(q, kp, vp, tables, q_lens, kv_lens):
-            return ragged_paged_attention(q, kp, vp, tables, q_lens,
-                                          kv_lens, scale)
-        _compile(fn, q, pool, pool, tables, lens, lens)
+        def fn(q, kp, vp, tables, starts, q_lens, kv_lens):
+            return ragged_paged_attention(q, kp, vp, tables, starts, q_lens,
+                                          kv_lens, scale, c_att=chunk)
+        _compile(fn, q, pool, pool, tables, lens, lens, lens)
 
 
-# the serving cells' attention shapes (PERF.md section 4): rows, chunk,
-# query heads, the whole pool [L, H_kv, pages, PAGE, HEAD_DIM], table width
+# the serving cells' attention shapes (PERF.md section 4): rows, packed
+# positions, chunk, query heads, the whole pool [L, H_kv, pages, PAGE,
+# HEAD_DIM], table width; and a GPT pass on a chip of the 4-way `mesh`
+# path, which holds a quarter of the heads (a [4, 128] position is less
+# than one bf16 tile)
 _CELL_ATTENTION = {
-    "gpt-pass1": (64, 128, 16, (24, 16, 256), 16),
-    "gpt-burst": (64, 1, 16, (24, 16, 256), 16),
-    "falconh1-pass1": (64, 128, 20, (6, 4, 640), 8),
+    "gpt-pass1": (64, 192, 128, 16, (24, 16, 256), 16),
+    "gpt-burst": (64, 64, 1, 16, (24, 16, 256), 16),
+    "gpt-pass1-mp4": (64, 192, 128, 4, (24, 4, 256), 16),
+    "falconh1-pass1": (64, 192, 128, 20, (6, 4, 640), 8),
+    "falconh1-burst": (64, 64, 1, 20, (6, 4, 640), 8),
 }
 
 
@@ -177,15 +183,17 @@ _CELL_ATTENTION = {
 def test_ragged_paged_attention_at_the_cells_shapes(one_chip,
                                                     compiled_kernels, cell,
                                                     kv_dtype):
-    """The kernel as the three serving cells call it: the whole pool, a
-    traced layer, every KV head of a page in one copy (16 x 32 KB for GPT,
-    4 x 32 KB for Falcon-H1 with its 640-row folded query tile). A VMEM
-    overrun or a refused slice shows here, without the chip."""
+    """The kernel as the three serving cells call it: the packed queries,
+    the whole pool, a traced layer, every KV head of a page in one copy
+    (16 x 32 KB for GPT, 4 x 32 KB for Falcon-H1 with its 640-row folded
+    query tile), a row's own positions copied in and out at any offset. A
+    VMEM overrun or a refused slice shows here, without the chip."""
     from paddle_tpu.kernels.pallas.ragged_paged_attention import (
         ragged_paged_attention)
-    rows, chunk, hq, (layers, hkv, pages), table = _CELL_ATTENTION[cell]
+    (rows, tokens, chunk, hq, (layers, hkv, pages),
+     table) = _CELL_ATTENTION[cell]
     quant = kv_dtype == "int8"
-    q = _sds(one_chip, (rows, chunk, hq, HEAD_DIM), jnp.bfloat16)
+    q = _sds(one_chip, (tokens, hq, HEAD_DIM), jnp.bfloat16)
     pool = _sds(one_chip, (layers, hkv, pages, PAGE, HEAD_DIM),
                 jnp.int8 if quant else jnp.bfloat16)
     scales = (_sds(one_chip, (layers, hkv, pages), jnp.float32)
@@ -194,12 +202,13 @@ def test_ragged_paged_attention_at_the_cells_shapes(one_chip,
     lens = _sds(one_chip, (rows,), jnp.int32)
     layer = _sds(one_chip, (), jnp.int32)
 
-    def fn(q, kp, vp, tables, q_lens, kv_lens, ks, vs, layer):
-        return ragged_paged_attention(q, kp, vp, tables, q_lens, kv_lens,
-                                      HEAD_DIM ** -0.5, ks, vs, layer)
+    def fn(q, kp, vp, tables, starts, q_lens, kv_lens, ks, vs, layer):
+        return ragged_paged_attention(q, kp, vp, tables, starts, q_lens,
+                                      kv_lens, HEAD_DIM ** -0.5, ks, vs,
+                                      layer, c_att=chunk)
 
-    compiled, text = _compile(fn, q, pool, pool, tables, lens, lens, scales,
-                              scales, layer)
+    compiled, text = _compile(fn, q, pool, pool, tables, lens, lens, lens,
+                              scales, scales, layer)
     assert "ragged_paged_attn" in text
     # the pool is read where it lies: nothing pool-sized is staged
     assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 26
@@ -248,6 +257,25 @@ def _moved(text, hit):
                        or op == "fusion" and "kind=kCustom" not in line):
             found.append(line.strip()[:160])
     return found
+
+
+_HLO_SHAPE = re.compile(r"\b[a-z]+\d*\[([\d,]+)\]")
+
+
+def _query_tiles(text, rows, c_att, heads):
+    """Every array of the compiled text, results and operands alike, that
+    holds `rows` x `c_att` positions of `heads` query heads: the per-row
+    query or output tiles the attention kernel no longer takes
+    ([R, c_att, H_q, D], or flat [R * c_att, H_q, D] as the gather made
+    them). The minor dimension tells them from a weight of as many
+    elements ([2048, 8192] is 64 x 128 x 16 x 128 too)."""
+    n = rows * c_att * heads * HEAD_DIM
+    found = set()
+    for dims in _HLO_SHAPE.findall(text):
+        dims = [int(d) for d in dims.split(",")]
+        if math.prod(dims) == n and dims[-1] == HEAD_DIM:
+            found.add(tuple(dims))
+    return sorted(found)
 
 
 def _pool_copies(text, pages):
@@ -303,7 +331,8 @@ def _compile_unified_step(one_chip, K, kv_dtype, pages, share=False):
         "k8-bf16-320pages", "k1-int8-512pages"])
 def test_unified_step_keeps_pool_in_place(one_chip, compiled_kernels, K,
                                           kv_dtype, pages, share):
-    """The guard against the pool copies coming back (PERF.md, PR 27):
+    """The guard against the pool copies coming back (PERF.md, PR 27), and
+    the per-row query tiles (PR 34):
     the parent's step at this geometry held ten pool-shaped copies,
     slices and updates, 7.0 GiB of temp at K = 1 and 7.6 GiB at K = 8,
     and did not compile at 320 pages. An int8 pool of the same bytes
@@ -312,6 +341,15 @@ def test_unified_step_keeps_pool_in_place(one_chip, compiled_kernels, K,
     text = compiled.as_text()
     assert "tpu_custom_call" in text, "kernel was not lowered for the chip"
     assert _pool_copies(text, pages) == []
+    # attention reads and writes the packed [T, H_q, D] buffer (PERF.md,
+    # PR 34): the parent's step gathered it to [64 x 128, 16, 128] a layer,
+    # the kernel moved a 0.5 MB tile in and out for each of the 64 rows,
+    # and a second gather picked 192 positions back. No such array is left
+    # — and the reader finds one where there is one: the packed buffer
+    # itself, and the tile of K and V the quantized append still takes
+    assert _query_tiles(text, 1, TOKENS, HEADS) != []
+    tiles = _query_tiles(text, ROWS, C_ATT, HEADS)
+    assert (tiles != []) == (kv_dtype == "int8"), tiles
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
     # both pools (and scales) alias their arguments: donated in, out in place
@@ -375,6 +413,10 @@ def test_falcon_h1_step_keeps_state_and_pool_in_place(one_chip,
         assert kernel in text, f"{kernel} was not lowered for the chip"
     assert _state_copies(text, [state_shape, state_shape[1:], pool_shape,
                                 pool_shape[1:], tail_shape]) == []
+    # nor a tile of queries a row: 64 x 128 positions of 20 heads (the
+    # packed buffer, padded to 24 heads for the kernel's copies, is there)
+    assert _query_tiles(text, H1_ROWS, cfg.ssm_chunk, cfg.num_heads) == []
+    assert _query_tiles(text, 1, tokens, 24) != []
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
     donated = (2 * math.prod(pool_shape) * 2 + math.prod(state_shape) * 4

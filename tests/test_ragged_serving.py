@@ -50,16 +50,44 @@ def mk(params, **kw):
 # ---------------------------------------------------------------------------
 # kernel: parity vs the composed (gather + masked softmax) reference
 # ---------------------------------------------------------------------------
-def _composed_reference(q, kp, vp, tables, q_lens, kv_lens, scale):
+@pytest.fixture(params=["copied", "aliased"])
+def interpreter(request, monkeypatch):
+    """The kernel under both interpreters. Its output is written in place
+    (aliased to a zeroed operand, a row's positions by its own copies):
+    plain ``interpret=True`` copies the aliased operand and runs a copy the
+    moment it is started; ``pltpu.InterpretParams()`` keeps a TPU's memory
+    and semaphores, so a copy that is never waited for, or a row that
+    writes beside its own positions, shows there (PERF.md, PR 28)."""
+    if request.param == "aliased":
+        from jax.experimental.pallas import tpu as pltpu
+        from paddle_tpu.kernels.pallas import ragged_paged_attention as mod
+        monkeypatch.setattr(mod, "_interpret", pltpu.InterpretParams)
+    return request.param
+
+
+def _pack(q_lens, order=None, tail=0, gap=0):
+    """Where each row's chunk starts in the packed buffer when the rows are
+    laid in `order` (row order unless given), `gap` unowned positions after
+    each, `tail` positions of padding at the end: (starts [R], T)."""
+    starts, cursor = np.zeros(len(q_lens), np.int32), 0
+    for r in (range(len(q_lens)) if order is None else order):
+        if q_lens[r]:
+            starts[r] = cursor
+            cursor += int(q_lens[r]) + gap
+    return starts, cursor - (gap if cursor else 0) + tail
+
+
+def _composed_reference(q, kp, vp, tables, starts, q_lens, kv_lens, scale):
     """Independent einsum re-derivation of the ragged kernel's contract:
-    per-row gather of referenced blocks, causal-within-chunk masking."""
-    R, C, hq, D = q.shape
+    per-row gather of referenced blocks, causal-within-chunk masking, a
+    row's output at its own packed positions and zero everywhere else."""
+    T, hq, D = q.shape
     hkv, _, bs, _ = kp.shape
     g = hq // hkv
-    out = np.zeros((R, C, hq, D), np.float32)
+    out = np.zeros((T, hq, D), np.float32)
     kp, vp, q = np.asarray(kp, np.float32), np.asarray(vp, np.float32), \
-        np.asarray(q)
-    for r in range(R):
+        np.asarray(q, np.float32)
+    for r in range(len(q_lens)):
         ql, kl = int(q_lens[r]), int(kv_lens[r])
         if ql == 0:
             continue
@@ -71,11 +99,19 @@ def _composed_reference(q, kp, vp, tables, q_lens, kv_lens, scale):
             qpos = kl - ql + c
             for h in range(hq):
                 kh = ks[h // g][:qpos + 1]
-                s = (q[r, c, h] @ kh.T) * scale
+                s = (q[starts[r] + c, h] @ kh.T) * scale
                 p = np.exp(s - s.max())
                 p /= p.sum()
-                out[r, c, h] = p @ vs[h // g][:qpos + 1]
+                out[starts[r] + c, h] = p @ vs[h // g][:qpos + 1]
     return out
+
+
+def _owned(starts, q_lens, T):
+    """[T] bool: the packed positions that belong to a row."""
+    own = np.zeros(T, bool)
+    for s, n in zip(starts, q_lens):
+        own[s:s + n] = True
+    return own
 
 
 # (q_lens, kv_lens) a batch; bs = 8, C = 6, five table slots (40 positions)
@@ -104,42 +140,48 @@ def _own_pages(kv_lens, bs, nb):
     return tables
 
 
-@pytest.mark.parametrize("batch", sorted(_KERNEL_BATCHES))
-@pytest.mark.parametrize("hq,hkv", [(4, 4), (8, 2), (20, 4)])
-def test_ragged_kernel_matches_composed_reference(hq, hkv, batch):
-    """MHA, GQA, and a 5-query group whose decode row (5 folded rows) takes
-    the 8-row arm while its 2-query row (10) takes the whole tile."""
+def _attend(q, kp, vp, tables, starts, q_lens, kv_lens, scale, *rest,
+            c_att):
     from paddle_tpu.kernels.pallas.ragged_paged_attention import (
         ragged_paged_attention)
+    return np.asarray(ragged_paged_attention(
+        q, kp, vp, jnp.asarray(tables), jnp.asarray(starts),
+        jnp.asarray(q_lens), jnp.asarray(kv_lens), scale, *rest,
+        c_att=c_att), np.float32)
+
+
+@pytest.mark.parametrize("batch", sorted(_KERNEL_BATCHES))
+@pytest.mark.parametrize("hq,hkv", [(4, 4), (8, 2), (20, 4)])
+def test_ragged_kernel_matches_composed_reference(hq, hkv, batch,
+                                                  interpreter):
+    """MHA, GQA, and a 5-query group whose decode row (5 folded rows) takes
+    the 8-row arm while its 2-query row (10) takes the whole tile; rows
+    packed in row order, three positions of padding behind them."""
     rng = np.random.RandomState(0)
     q_lens, kv_lens = (np.array(a, np.int32) for a in _KERNEL_BATCHES[batch])
-    R, C, D, bs, nb, NB = len(q_lens), 6, 16, 8, 5, 32
+    C, D, bs, nb, NB = 6, 16, 8, 5, 32
     kp = jnp.asarray(rng.randn(hkv, NB, bs, D).astype(np.float32))
     vp = jnp.asarray(rng.randn(hkv, NB, bs, D).astype(np.float32))
     tables = _own_pages(kv_lens, bs, nb)
-    q = jnp.asarray(rng.randn(R, C, hq, D).astype(np.float32))
+    starts, T = _pack(q_lens, tail=3)
+    q = jnp.asarray(rng.randn(T, hq, D).astype(np.float32))
     scale = 1.0 / np.sqrt(D)
-    out = ragged_paged_attention(q, kp, vp, jnp.asarray(tables),
-                                 jnp.asarray(q_lens), jnp.asarray(kv_lens),
-                                 scale)
-    ref = _composed_reference(q, kp, vp, tables, q_lens, kv_lens, scale)
-    rel = (np.abs(np.asarray(out) - ref).max()
-           / max(np.abs(ref).max(), 1e-9))
+    out = _attend(q, kp, vp, tables, starts, q_lens, kv_lens, scale, c_att=C)
+    ref = _composed_reference(q, kp, vp, tables, starts, q_lens, kv_lens,
+                              scale)
+    rel = np.abs(out - ref).max() / max(np.abs(ref).max(), 1e-9)
     assert rel <= 1e-2, rel  # acceptance: <=1e-2 rel (exceeds it: fp32)
-    assert np.abs(np.asarray(out) - ref).max() < 1e-5
-    # empty rows, and chunk positions past a row's q_len, emit zeros
-    for r in range(R):
-        assert (np.asarray(out)[r, q_lens[r]:] == 0).all()
+    assert np.abs(out - ref).max() < 1e-5
+    # positions that belong to no row (the tail padding) read zero
+    assert (out[~_owned(starts, q_lens, T)] == 0).all()
 
 
 @pytest.mark.parametrize("hq,hkv", [(4, 4), (20, 4)])
 def test_ragged_kernel_arms_agree_on_a_decode_row(hq, hkv):
-    """The same decode rows through a C = 1 call (the burst passes' form:
-    one arm, an 8-row tile) and as q_len = 1 rows of a C = 128 call beside
-    a full chunk (pass 1: the narrow arm of a 128 * g-row tile) give the
-    same output."""
-    from paddle_tpu.kernels.pallas.ragged_paged_attention import (
-        ragged_paged_attention)
+    """The same decode rows through a c_att = 1 call (the burst passes'
+    form: T = R, starts = arange(R), one arm, an 8-row tile) and as
+    q_len = 1 rows of a c_att = 128 call beside a full chunk (pass 1: the
+    narrow arm of a 128 * g-row tile) give the same output."""
     rng = np.random.RandomState(5)
     R, C, D, bs, nb, NB = 4, 128, 16, 16, 9, 24
     kp = jnp.asarray(rng.randn(hkv, NB, bs, D).astype(np.float32))
@@ -147,19 +189,23 @@ def test_ragged_kernel_arms_agree_on_a_decode_row(hq, hkv):
     q_lens = np.array([1, 128, 0, 1], np.int32)
     kv_lens = np.array([37, 140, 0, 16], np.int32)
     tables = _own_pages(kv_lens, bs, nb)
-    q = jnp.asarray(rng.randn(R, C, hq, D).astype(np.float32))
+    # decode rows by slot, then the chunk row: as `_pack_ragged` lays them
+    starts, T = _pack(q_lens, order=[0, 3, 1], tail=2)
+    q = jnp.asarray(rng.randn(T, hq, D).astype(np.float32))
     scale = 1.0 / np.sqrt(D)
-    wide = np.asarray(ragged_paged_attention(
-        q, kp, vp, jnp.asarray(tables), jnp.asarray(q_lens),
-        jnp.asarray(kv_lens), scale))
+    wide = _attend(q, kp, vp, tables, starts, q_lens, kv_lens, scale,
+                   c_att=C)
     decode = np.array([1, 0, 0, 1], np.int32)       # the chunk row sits out
-    one = np.asarray(ragged_paged_attention(
-        q[:, :1], kp, vp, jnp.asarray(tables), jnp.asarray(decode),
-        jnp.asarray(kv_lens * decode), scale))
+    q_one = jnp.zeros((R, hq, D), jnp.float32).at[jnp.asarray([0, 3])].set(
+        q[jnp.asarray(starts[[0, 3]])])
+    one = _attend(q_one, kp, vp, tables, np.arange(R, dtype=np.int32),
+                  decode, kv_lens * decode, scale, c_att=1)
     for r in (0, 3):
-        np.testing.assert_allclose(wide[r, 0], one[r, 0], rtol=0,
+        np.testing.assert_allclose(wide[starts[r]], one[r], rtol=0,
                                    atol=1e-6)
-    ref = _composed_reference(q, kp, vp, tables, q_lens, kv_lens, scale)
+    assert (one[[1, 2]] == 0).all()
+    ref = _composed_reference(q, kp, vp, tables, starts, q_lens, kv_lens,
+                              scale)
     assert np.abs(wide - ref).max() < 1e-5
 
 
@@ -172,7 +218,7 @@ def test_ragged_kernel_quantized_scales_are_per_head(kv_dtype):
     from paddle_tpu.kernels.pallas.ragged_paged_attention import (
         ragged_paged_attention)
     rng = np.random.RandomState(9)
-    L, hq, hkv, NB, bs, D, R, C, nb = 2, 8, 4, 12, 8, 16, 4, 12, 3
+    L, hq, hkv, NB, bs, D, C, nb = 2, 8, 4, 12, 8, 16, 12, 3
     if kv_dtype == "int8":
         qmax, store = 127.0, jnp.int8
         grid = rng.randint(-127, 128, (2, L, hkv, NB, bs, D))
@@ -186,54 +232,53 @@ def test_ragged_kernel_quantized_scales_are_per_head(kv_dtype):
     q_lens = np.array([1, 12, 0, 3], np.int32)      # narrow, wide, -, narrow
     kv_lens = np.array([20, 12, 0, 9], np.int32)
     tables = _own_pages(kv_lens, bs, nb)
-    q = jnp.asarray(rng.randn(R, C, hq, D).astype(np.float32))
+    starts, T = _pack(q_lens, order=[0, 3, 1], tail=1)
+    q = jnp.asarray(rng.randn(T, hq, D).astype(np.float32))
     scale = 1.0 / np.sqrt(D)
-    out = np.asarray(jax.jit(ragged_paged_attention, static_argnums=6)(
-        q, kp, vp, jnp.asarray(tables), jnp.asarray(q_lens),
-        jnp.asarray(kv_lens), scale, jnp.asarray(ks, jnp.float32),
-        jnp.asarray(vs, jnp.float32), jnp.int32(1)))
+    out = np.asarray(jax.jit(ragged_paged_attention,
+                             static_argnames=("scale", "c_att"))(
+        q, kp, vp, jnp.asarray(tables), jnp.asarray(starts),
+        jnp.asarray(q_lens), jnp.asarray(kv_lens), scale=scale,
+        k_scales=jnp.asarray(ks, jnp.float32),
+        v_scales=jnp.asarray(vs, jnp.float32), layer=jnp.int32(1), c_att=C))
 
     def reference(ks, vs):
         kf = np.asarray(kp[1], np.float32) * ks[1][..., None, None] / qmax
         vf = np.asarray(vp[1], np.float32) * vs[1][..., None, None] / qmax
-        return _composed_reference(q, kf, vf, tables, q_lens, kv_lens, scale)
+        return _composed_reference(q, kf, vf, tables, starts, q_lens,
+                                   kv_lens, scale)
 
     ref = reference(ks, vs)
-    live = np.zeros(out.shape, bool)
-    for r in range(R):
-        live[r, :q_lens[r]] = True
+    live = _owned(starts, q_lens, T)
     tol = 1e-4 * np.abs(ref).max()
     assert np.abs(out - ref)[live].max() < tol
+    assert (out[~live] == 0).all()
     for wrong in (reference(np.roll(ks, 1, axis=1), vs),
                   reference(ks, np.roll(vs, 1, axis=1))):
         assert np.abs(out - wrong)[live].max() > 100 * tol
 
 
-def test_ragged_kernel_bf16_rel_tolerance():
-    from paddle_tpu.kernels.pallas.ragged_paged_attention import (
-        ragged_paged_attention)
+def test_ragged_kernel_bf16_rel_tolerance(interpreter):
     rng = np.random.RandomState(3)
-    R, C, hq, hkv, D, bs, nb, NB = 3, 4, 4, 4, 16, 8, 4, 12
+    C, hq, hkv, D, bs, nb, NB = 4, 4, 4, 16, 8, 4, 12
     kp = jnp.asarray(rng.randn(hkv, NB, bs, D)).astype(jnp.bfloat16)
     vp = jnp.asarray(rng.randn(hkv, NB, bs, D)).astype(jnp.bfloat16)
     q_lens = np.array([1, 4, 2], np.int32)
     kv_lens = np.array([9, 12, 2], np.int32)
     tables = _own_pages(kv_lens, bs, nb)
-    q = jnp.asarray(rng.randn(R, C, hq, D)).astype(jnp.bfloat16)
+    starts, T = _pack(q_lens, tail=1)
+    q = jnp.asarray(rng.randn(T, hq, D)).astype(jnp.bfloat16)
     scale = 1.0 / np.sqrt(D)
-    out = np.asarray(ragged_paged_attention(
-        q, kp, vp, jnp.asarray(tables), jnp.asarray(q_lens),
-        jnp.asarray(kv_lens), scale), np.float32)
+    out = _attend(q, kp, vp, tables, starts, q_lens, kv_lens, scale, c_att=C)
     ref = _composed_reference(q.astype(jnp.float32), kp.astype(jnp.float32),
-                              vp.astype(jnp.float32), tables, q_lens,
+                              vp.astype(jnp.float32), tables, starts, q_lens,
                               kv_lens, scale)
     rel = np.abs(out - ref).max() / max(np.abs(ref).max(), 1e-9)
     assert rel <= 1e-2, rel  # acceptance bound, bf16
+    assert (out[-1] == 0).all()
 
 
 def test_ragged_kernel_int8_pool_close():
-    from paddle_tpu.kernels.pallas.ragged_paged_attention import (
-        ragged_paged_attention)
     from paddle_tpu.quantization.kv_cache import append_tokens_quantized
     rng = np.random.RandomState(1)
     hkv, NB, bs, D, R, C, nb = 2, 10, 8, 16, 2, 8, 4
@@ -253,11 +298,11 @@ def test_ragged_kernel_int8_pool_close():
     vp, vs = append_tokens_quantized(vp, vs, jnp.asarray(vf),
                                      jnp.asarray(pos0), jnp.asarray(q_lens),
                                      jnp.asarray(tables), bs)
-    q = jnp.asarray(rng.randn(R, C, hkv, D).astype(np.float32))
+    starts, T = _pack(q_lens)
+    q = jnp.asarray(rng.randn(T, hkv, D).astype(np.float32))
     scale = 1.0 / np.sqrt(D)
-    out = ragged_paged_attention(q, kp, vp, jnp.asarray(tables),
-                                 jnp.asarray(q_lens), jnp.asarray(q_lens),
-                                 scale, ks, vs)
+    out = _attend(q, kp, vp, tables, starts, q_lens, q_lens, scale, ks, vs,
+                  c_att=C)
     # reference over the EXACT float tokens: int8 storage error only
     kpf = jnp.zeros((hkv, NB, bs, D), jnp.float32)
     vpf = jnp.zeros_like(kpf)
@@ -266,8 +311,9 @@ def test_ragged_kernel_int8_pool_close():
             b, o = tables[r, t // bs], t % bs
             kpf = kpf.at[:, b, o].set(kf[r, t])
             vpf = vpf.at[:, b, o].set(vf[r, t])
-    ref = _composed_reference(q, kpf, vpf, tables, q_lens, q_lens, scale)
-    assert np.abs(np.asarray(out) - ref).max() < 0.08
+    ref = _composed_reference(q, kpf, vpf, tables, starts, q_lens, q_lens,
+                              scale)
+    assert np.abs(out - ref).max() < 0.08
 
 
 def test_quantized_append_into_last_table_page():
@@ -310,9 +356,11 @@ def test_ragged_kernel_whole_pool_layer_index(kv_dtype):
     tables = np.zeros((R, nb), np.int32)
     tables[0, :2], tables[1, :2], tables[2, :1] = [1, 2], [3, 4], [5]
     tables = jnp.asarray(tables)
+    starts, T = _pack([8, 5, 0], tail=2)
+    starts = jnp.asarray(starts)
     q_lens = jnp.asarray(np.array([8, 5, 0], np.int32))
     kv_lens = jnp.asarray(np.array([16, 5, 0], np.int32))
-    q = jnp.asarray(rng.randn(R, C, hkv, D).astype(np.float32))
+    q = jnp.asarray(rng.randn(T, hkv, D).astype(np.float32))
     scale = 1.0 / np.sqrt(D)
     if kv_dtype == "int8":
         kp = jnp.asarray(rng.randint(-127, 128, (L, hkv, NB, bs, D)),
@@ -341,19 +389,134 @@ def test_ragged_kernel_whole_pool_layer_index(kv_dtype):
 
     @jax.jit
     def whole(layer):
-        return ragged_paged_attention(q, kp, vp, tables, q_lens, kv_lens,
-                                      scale, ks, vs, layer)
+        return ragged_paged_attention(q, kp, vp, tables, starts, q_lens,
+                                      kv_lens, scale, ks, vs, layer, c_att=C)
 
     for layer in (1, 2):
         one = ragged_paged_attention(
-            q, kp[layer], vp[layer], tables, q_lens, kv_lens, scale,
+            q, kp[layer], vp[layer], tables, starts, q_lens, kv_lens, scale,
             None if ks is None else ks[layer],
-            None if vs is None else vs[layer])
+            None if vs is None else vs[layer], c_att=C)
         np.testing.assert_array_equal(
             np.asarray(whole(jnp.int32(layer)), np.float32),
             np.asarray(one, np.float32))
     assert not np.array_equal(np.asarray(whole(jnp.int32(1)), np.float32),
                               np.asarray(whole(jnp.int32(2)), np.float32))
+
+
+def _layout_case(g, kv_dtype, q_lens, kv_lens, order=None, tail=0, gap=0,
+                 c_att=12, seed=21):
+    """The kernel on a packed layout, for a query group of `g` (2 KV heads)
+    over a bf16 or an int8 pool, and what the reference makes of the same
+    pool: (run, reference, starts, T, tolerance), where run(q_lens) and
+    reference(q_lens) attend the SAME packed buffer (compiled once) with
+    some rows' q_len changed."""
+    from paddle_tpu.kernels.pallas.ragged_paged_attention import (
+        ragged_paged_attention)
+    rng = np.random.RandomState(seed)
+    hkv, D, bs, nb, NB = 2, 16, 8, 6, 40
+    q_lens, kv_lens = (np.asarray(a, np.int32) for a in (q_lens, kv_lens))
+    tables = _own_pages(kv_lens, bs, nb)
+    starts, T = _pack(q_lens, order=order, tail=tail, gap=gap)
+    q = jnp.asarray(rng.randn(T, g * hkv, D)).astype(jnp.bfloat16)
+    scale = 1.0 / np.sqrt(D)
+    if kv_dtype == "int8":
+        kp, vp = (jnp.asarray(rng.randint(-127, 128, (hkv, NB, bs, D)),
+                              jnp.int8) for _ in range(2))
+        ks, vs = (jnp.asarray(rng.rand(hkv, NB).astype(np.float32) + 0.5)
+                  for _ in range(2))
+        kf = np.asarray(kp, np.float32) * np.asarray(ks)[..., None, None] / 127
+        vf = np.asarray(vp, np.float32) * np.asarray(vs)[..., None, None] / 127
+    else:
+        kp, vp = (jnp.asarray(rng.randn(hkv, NB, bs, D)).astype(jnp.bfloat16)
+                  for _ in range(2))
+        ks = vs = None
+        kf, vf = np.asarray(kp, np.float32), np.asarray(vp, np.float32)
+    kernel = jax.jit(lambda n: ragged_paged_attention(
+        q, kp, vp, jnp.asarray(tables), jnp.asarray(starts), n,
+        jnp.asarray(kv_lens), scale, ks, vs, c_att=c_att))
+
+    def run(n=q_lens):
+        return np.asarray(kernel(jnp.asarray(n, jnp.int32)), np.float32)
+
+    def reference(n=q_lens):
+        return _composed_reference(q, kf, vf, tables, starts, n, kv_lens,
+                                   scale)
+
+    # bf16 queries and output
+    return run, reference, starts, T, 1e-2 * np.abs(reference()).max()
+
+
+# packed layouts the serving step makes (c_att = 12, pages of 8); the edge
+# of the narrow arm is 8 // g chunk positions: 8 for MHA, 1 for g = 5
+_LAYOUTS = {
+    # `_pack_ragged` lays decode rows by slot, then prefill rows: rows are
+    # NOT in row order and `starts` is not monotone
+    "out_of_row_order": lambda edge: dict(
+        q_lens=[1, 12, 1, 5, 1], kv_lens=[9, 30, 17, 5, 40],
+        order=[0, 2, 4, 3, 1], tail=2),
+    # finished slots between live ones cost their grid step and nothing
+    # else: no query copy, no output write
+    "empty_rows_between": lambda edge: dict(
+        q_lens=[0, 1, 0, 0, 7, 0, 1, 0], kv_lens=[0, 20, 0, 0, 15, 0, 8, 0],
+        tail=1),
+    # a short chunk row whose last position is the buffer's last: the
+    # static-size query copy must not leave the buffer
+    "chunk_ends_the_buffer": lambda edge: dict(
+        q_lens=[1, 12, 5], kv_lens=[11, 12, 29], order=[0, 1, 2]),
+    # ... and a decode row on the buffer's last position (the narrow copy)
+    "decode_ends_the_buffer": lambda edge: dict(
+        q_lens=[12, 5, 1], kv_lens=[36, 5, 33], order=[0, 1, 2]),
+    # a verify row at the narrow arm's edge and one past it
+    "verify_rows_at_the_arms_edge": lambda edge: dict(
+        q_lens=[edge, edge + 1, 1, edge + 1, edge],
+        kv_lens=[20, 21, 3, edge + 1, 40], order=[2, 0, 1, 4, 3], tail=3),
+    # positions between rows that belong to no row
+    "gaps_between_rows": lambda edge: dict(
+        q_lens=[3, 1, 12], kv_lens=[3, 25, 20], order=[1, 0, 2], gap=2,
+        tail=4),
+}
+
+
+@pytest.mark.parametrize("layout", sorted(_LAYOUTS))
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("g", [1, 5])
+def test_ragged_kernel_packed_layouts(g, kv_dtype, layout, interpreter):
+    """The kernel reads a row's queries from, and writes its output to,
+    the row's own positions of the packed buffer — wherever the host put
+    them — and every position no row owns reads back exactly zero."""
+    case = _LAYOUTS[layout](max(8 // g, 1))
+    run, reference, starts, T, tol = _layout_case(g, kv_dtype, **case)
+    out = run()
+    assert np.isfinite(out).all()
+    assert np.abs(out - reference()).max() < tol
+    own = _owned(starts, case["q_lens"], T)
+    assert own.sum() == sum(case["q_lens"])
+    assert (out[~own] == 0).all()
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("g", [1, 5])
+def test_ragged_kernel_row_writes_its_own_positions_only(g, kv_dtype,
+                                                         interpreter):
+    """Take one row out of the batch (q_len 0): its positions read zero
+    and every other row's output is bit for bit what it was — a row's
+    write touches no neighbour's position, before it or behind it, on
+    either arm."""
+    case = dict(q_lens=[1, 12, 1, 5, 1], kv_lens=[9, 30, 17, 5, 40],
+                order=[0, 2, 4, 3, 1], tail=2)
+    run, reference, starts, T, tol = _layout_case(g, kv_dtype, **case)
+    full = run()
+    for gone in (2, 3, 1):     # a decode row, a short chunk, a full chunk
+        q_lens = list(case["q_lens"])
+        n, q_lens[gone] = q_lens[gone], 0
+        out = run(q_lens)
+        assert np.abs(out - reference(q_lens)).max() < tol
+        s = int(starts[gone])
+        assert (out[s:s + n] == 0).all()
+        keep = np.ones(T, bool)
+        keep[s:s + n] = False
+        np.testing.assert_array_equal(out[keep], full[keep])
 
 
 @pytest.mark.parametrize("dtype,bs", [("float32", 8), ("float32", 16),
