@@ -53,9 +53,18 @@ result:
     fro, inside the layer loop (PERF.md, PR 27).
 
 A recurrent model's per-slot state (`unified_step`'s ``ssm_state`` and
-``conv_tail``; `kernels.pallas.ssm`) keeps the same contract: one donated
-buffer each, on both scans' carry, the layer by scalar prefetch, written
-in place by the mixer's kernels.
+``conv_tail``; `kernels.pallas.ssm`, `kernels.pallas.gdn`) keeps the same
+contract: one donated buffer each, on both scans' carry, the layer by
+scalar prefetch, written in place by the mixer's kernels.
+
+A model whose layers are not all of one kind gives its PATTERN
+(``serving_model(cfg).pattern``: one period as runs of "attention",
+"parallel" or "linear" layers). `ragged_pass` scans periods and, inside
+one, each run; the pool then holds one entry an ATTENTION layer and the
+state one a layer with a MIXER, each numbered in its own order, and a
+run's stacked expert weights are taken whole like the pool (the layer
+and the expert by scalar prefetch). A pattern of one layer (GPT: one
+"attention"; Falcon-H1: one "parallel") is the scan over layers it was.
 
 `tests/test_chip_compile.py` holds the compiled step to it: no
 pool-sized (or state-sized) copy, slice or update, temp under 1 GiB.
@@ -92,7 +101,9 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
     the head runs over EVERY packed position and the return gains a
     ``greedy_t [T]`` argmax vector between tok and the pools — the
     speculative-decoding verify signal (draft token i is accepted iff it
-    equals the model's own argmax one position earlier)."""
+    equals the model's own argmax one position earlier). A model with
+    routed experts adds, last, what its router chose: (ids [L, T, k],
+    stats [L, 3]) as `models.qwen3_next.moe_layer` gives them a layer."""
     T = tokens.shape[0]
     quantized = ks is not None
     model = serving_model(cfg)
@@ -119,30 +130,90 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
                 "q_lens": q_lens, "tile_idx": tile_idx,
                 "reset": (pos0 == 0) & (q_lens > 0)}
 
-    def body(carry, layer):
-        x, kp, vp, ks, vs, ssm = carry
-        p, li = layer
-        q, k, v, u = model.qkv(p, x, pos_t[None], cfg, mp_axis)  # [1,T,h,D]
-        mixed = None
-        if ssm is not None:
-            mixed, ssm = model.mixer(p, u, ssm, li, plan, cfg)
-        with jax.named_scope(SCOPES.kv_write):
-            if quantized:
-                kp, ks = append_tokens_quantized(
-                    kp, ks, k[0][tile_idx], pos0, q_lens, tables, bs, li)
-                vp, vs = append_tokens_quantized(
-                    vp, vs, v[0][tile_idx], pos0, q_lens, tables, bs, li)
-            else:
-                kp, vp = kv_append(kp, vp, k[0], v[0], li, work, tile=tile)
-        with jax.named_scope(SCOPES.ragged_attn):
-            attn_p = ragged_paged_attention(
-                q[0], kp, vp, tables, starts, q_lens, kv_lens, scale,
-                ks, vs, li, c_att=c_att)                     # [T, h, D]
-        x = model.block_math(p, x, attn_p[None], mixed, cfg, mp_axis)
-        return (x, kp, vp, ks, vs, ssm), None
+    # one period of the model's layer pattern, as runs of one kind of
+    # layer: "attention" (queries, the K/V append, paged attention; what
+    # `qkv` hands back fourth goes to `block_math`), "parallel" (attention
+    # with a recurrent mixer beside it on that fourth return) or "linear"
+    # (the mixer alone, on the residual stream). The scan is over PERIODS;
+    # the pool holds one entry an attention layer, the state one a layer
+    # with a mixer, each numbered in its own order
+    runs = model.pattern(cfg)
+    single = len(runs) == 1 and runs[0][1] == 1
+    n_att = sum(n for kind, n in runs if kind != "linear")
+    n_mix = sum(n for kind, n in runs if kind != "attention")
+    routed = model.routed
 
-    xs = (params["blocks"], jnp.arange(kp.shape[0], dtype=jnp.int32))
-    (x, *pools), _ = lax.scan(body, (x, kp, vp, ks, vs, ssm), xs)
+    def layer_body(kind, experts, flat, att, mix):
+        """One layer of `kind`; flat/att/mix: its number among all layers
+        of its run's kind (the experts' leading index), among the layers
+        with attention (the pool's), among those with a mixer (the
+        state's)."""
+        def body(carry, p):
+            x, kp, vp, ks, vs, ssm = carry
+            attn_p = None
+            if kind == "linear":
+                mixed, ssm = model.mixer(p, x, ssm, mix, plan, cfg)
+            else:
+                q, k, v, u = model.qkv(p, x, pos_t[None], cfg, mp_axis)
+                mixed = u                                    # [1,T,h,D]
+                if kind == "parallel":
+                    mixed, ssm = model.mixer(p, u, ssm, mix, plan, cfg)
+                with jax.named_scope(SCOPES.kv_write):
+                    if quantized:
+                        kp, ks = append_tokens_quantized(
+                            kp, ks, k[0][tile_idx], pos0, q_lens, tables,
+                            bs, att)
+                        vp, vs = append_tokens_quantized(
+                            vp, vs, v[0][tile_idx], pos0, q_lens, tables,
+                            bs, att)
+                    else:
+                        kp, vp = kv_append(kp, vp, k[0], v[0], att, work,
+                                           tile=tile)
+                with jax.named_scope(SCOPES.ragged_attn):
+                    attn_p = ragged_paged_attention(
+                        q[0], kp, vp, tables, starts, q_lens, kv_lens,
+                        scale, ks, vs, att, c_att=c_att)[None]  # [1,T,h,D]
+            route = None
+            if routed:
+                x, route = model.block_math(p, x, attn_p, mixed, cfg,
+                                            mp_axis, experts=experts,
+                                            layer=flat)
+            else:
+                x = model.block_math(p, x, attn_p, mixed, cfg, mp_axis)
+            return (x, kp, vp, ks, vs, ssm), route
+        return body
+
+    def period_body(carry, xs):
+        ps, period = xs
+        if single:      # one layer a period: the period IS the layer
+            return layer_body(runs[0][0], None, period, period, period)(
+                carry, ps)
+        routes, att0, mix0 = [], 0, 0
+        for r, (kind, n) in enumerate(runs):
+            def run_body(carry, pj, r=r, kind=kind, n=n, att0=att0,
+                         mix0=mix0):
+                p, j = pj
+                return layer_body(
+                    kind, params["experts"][r] if routed else None,
+                    period * n + j, period * n_att + att0 + j,
+                    period * n_mix + mix0 + j)(carry, p)
+            carry, route = lax.scan(
+                run_body, carry, (ps[r], jnp.arange(n, dtype=jnp.int32)))
+            routes.append(route)
+            att0 += n if kind != "linear" else 0
+            mix0 += n if kind != "attention" else 0
+        # the period's routing in layer order: (ids [n, T, k], stats [n, 3])
+        route = (jax.tree.map(lambda *a: jnp.concatenate(a), *routes)
+                 if routed else None)
+        return carry, route
+
+    blocks = params["blocks"]
+    periods = jax.tree.leaves(blocks)[0].shape[0]
+    xs = (blocks, jnp.arange(periods, dtype=jnp.int32))
+    (x, *pools), route = lax.scan(period_body, (x, kp, vp, ks, vs, ssm), xs)
+    if routed:      # [periods, layers a period, ...] -> [layers, ...]
+        route = jax.tree.map(
+            lambda a: a.reshape((-1,) + a.shape[2:]), route)
     with jax.named_scope(SCOPES.head):
         x = model.final_norm(params, x, cfg)
     last_idx = jnp.clip(starts + jnp.maximum(q_lens, 1) - 1, 0, T - 1)
@@ -158,6 +229,8 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
     tok = _sample(logits, temps, key)
     if all_greedy:
         return tok, greedy_t, pools
+    if routed:
+        return tok, pools, route
     return tok, pools
 
 
@@ -206,6 +279,13 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
     written in place by the mixer's kernels; a row whose pass starts at
     position 0 starts from zeros. Both come back after ``lens``.
 
+    A model with routed experts (``routed``) returns what its router
+    chose, last: ids0 [L, T, k] int16, the picks of every packed position
+    of pass 1 and layer; ids_burst [K-1, L, R, k], the burst passes' (row
+    r's one position); stats [K, L, 3] int32, per pass and layer the held
+    experts touched, the assignments to them and the largest number one
+    of them got. They are fetched with the tokens: no sync of their own.
+
     Returns (toks [K, R], kp, vp, ks, vs, lens [R], last_tok [R]); with
     ``spec=True`` (K must be 1) the return gains ``greedy_all [T]`` after
     toks — the model's argmax at every packed position, from which the
@@ -237,8 +317,11 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
                       pos0, q_lens, tables, temps, sub,
                       kp, vp, ks, vs, ssm, cfg=cfg, bs=bs,
                       c_att=c_att, mp_axis=mp_axis, all_greedy=spec)
+    routed = serving_model(cfg).routed
     if spec:
         tok0, greedy_all, (kp, vp, ks, vs, ssm) = out
+    elif routed:
+        tok0, (kp, vp, ks, vs, ssm), (ids0, stats0) = out
     else:
         tok0, (kp, vp, ks, vs, ssm) = out
     tok0 = jnp.where(sample0, tok0, 0)
@@ -254,7 +337,7 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
         active = alive & (rem > 0)
         ql = active.astype(jnp.int32)
         key, sub = jax.random.split(key)
-        tok2, (kp, vp, ks, vs, ssm) = ragged_pass(
+        tok2, (kp, vp, ks, vs, ssm), *route = ragged_pass(
             params, tok, ar, zero, ar, lens, ql, tables, temps, sub,
             kp, vp, ks, vs, ssm, cfg=cfg, bs=bs, c_att=1, mp_axis=mp_axis)
         tok2 = jnp.where(active, tok2, 0)
@@ -263,18 +346,28 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
         rem = rem - ql
         alive = alive & ~(active & (tok2 == eos_ids))
         return (tok2, last, kp, vp, ks, vs, ssm, lens, rem, alive,
-                key), tok2
+                key), (tok2, *route)
 
     if K > 1:
         carry = (tok0, last_tok, kp, vp, ks, vs, ssm, lens, rem, alive, key)
         with jax.named_scope(SCOPES.burst):
-            (_, last_tok, kp, vp, ks, vs, ssm, lens, _, _, _), toks = \
-                lax.scan(micro, carry, jnp.arange(K - 1))
+            (_, last_tok, kp, vp, ks, vs, ssm, lens, _, _, _), \
+                (toks, *route) = lax.scan(micro, carry, jnp.arange(K - 1))
         all_toks = jnp.concatenate([tok0[None], toks], axis=0)
     else:
         all_toks = tok0[None]
     if spec:
         return all_toks, greedy_all, kp, vp, ks, vs, lens, last_tok
+    out = (all_toks, kp, vp, ks, vs, lens, last_tok)
     if ssm is not None:
-        return (all_toks, kp, vp, ks, vs, lens, last_tok) + tuple(ssm)
-    return all_toks, kp, vp, ks, vs, lens, last_tok
+        out += tuple(ssm)
+    if routed:
+        if K > 1:
+            (ids_burst, stats_burst), = route
+            stats0 = jnp.concatenate([stats0[None], stats_burst])
+        else:
+            ids_burst = jnp.zeros((0, ids0.shape[0], R, ids0.shape[2]),
+                                  ids0.dtype)
+            stats0 = stats0[None]
+        out += (ids0, ids_burst, stats0)
+    return out

@@ -64,7 +64,16 @@ per-request device state beside the KV pages: one recurrent state and
 one conv tail a SLOT, zeroed in-program when a row starts at position 0,
 released with the slot, rebuilt by re-prefill after a preemption. What
 the engine cannot give such a model yet (a mesh, int8 weights, prefix
-sharing, speculative decoding) raises at construction.
+sharing, speculative decoding) raises at construction. A model whose
+layers are not all of one kind names its PATTERN (`pattern`: one period
+as runs of layers, `models.qwen3_next`: three linear-attention layers to
+one attention layer); the pool then holds one entry a layer with
+attention (`kv_layers`) and the state one a layer with a mixer, and the
+step scans periods (`ragged_step.ragged_pass`). A model with routed
+experts (`routed`) hands back what its router chose with each step's
+tokens, in the same fetch: a request that asks (`keep_routing`) keeps the
+picks of its positions, and the step's counts ride the `serving_fetch`
+span (`observability.trace.MOE_FETCH_ATTRS`).
 
 Resilience layer (ISSUE 13) — all host-side scheduler state, no compiled
 program changes (flags-off the step behavior is byte-identical and the
@@ -112,7 +121,7 @@ import numpy as np
 from jax import lax
 
 from ..models import gpt as G
-from ..observability.trace import (SCOPES, SERVING_SPANS,
+from ..observability.trace import (MOE_FETCH_ATTRS, SCOPES, SERVING_SPANS,
                                    SSM_DISPATCH_ATTRS)
 from ..profiler.utils import RecordEvent
 
@@ -178,6 +187,12 @@ class Request:
     # telemetry (observability): submit wall clock + time-to-first-token
     submit_time: float = 0.0
     ttft_s: Optional[float] = None
+    # a model with routed experts: the experts its router picked at every
+    # position the engine ran, [positions, layers, k] int16 by ABSOLUTE
+    # position (-1 where no pass has run yet; the token sampled last is
+    # never an input), kept when the request asks (rollout replay)
+    keep_routing: bool = False
+    routing: Optional[np.ndarray] = None
 
 
 @dataclasses.dataclass
@@ -201,7 +216,9 @@ class _PackedStep:
     ssm_attrs: dict = dataclasses.field(default_factory=dict)
     #                      a recurrent model's dispatch attributes
     out: tuple = ()      # once dispatched: the program's (toks, greedy_all,
-    #                      lens), still on the device until the step lands
+    #                      lens) and, of a model with routed experts, its
+    #                      (ids0, ids_burst, stats), still on the device
+    #                      until the step lands
 
 
 class RunResult(dict):
@@ -346,9 +363,22 @@ class GPTServing:
     half and the head. A configuration of another architecture names its
     own answers as ``cfg.serving_model`` (`models.falcon_h1.Serving`);
     one with ``recurrent = True`` also has a `mixer` over the packed rows
-    and a per-slot state that the engine keeps beside the KV pages."""
+    and a per-slot state that the engine keeps beside the KV pages; one
+    with ``routed = True`` has `block_math` take its run's experts whole
+    (``experts=``, ``layer=``) and return (x, (ids, stats))."""
 
     recurrent = False
+    routed = False      # no router: `block_math` returns the stream alone
+
+    @staticmethod
+    def pattern(cfg):
+        """One period of the layer pattern, as runs (kind, count)."""
+        return (("attention", 1),)
+
+    @staticmethod
+    def kv_layers(cfg):
+        """Layers that keep K and V in the paged pool."""
+        return cfg.num_layers
 
     @staticmethod
     def positions(pos, cfg):
@@ -435,9 +465,10 @@ class ServingEngine:
             pool_dtype, kv_quantized = cfg.dtype, False
         else:
             pool_dtype, kv_quantized = _kv_dtype(kv_cache_dtype)
-        L, D = cfg.num_layers, cfg.head_dim
-        Hkv = getattr(cfg, "num_kv_heads", cfg.num_heads)
         self.model = serving_model(cfg)
+        # the pool holds one entry a layer WITH attention
+        L, D = self.model.kv_layers(cfg), cfg.head_dim
+        Hkv = getattr(cfg, "num_kv_heads", cfg.num_heads)
         if prefix_share is None or prefix_share == "auto":
             prefix_share = bool(flag("serving_prefix_share"))
         self.prefix_share = bool(prefix_share)
@@ -508,6 +539,20 @@ class ServingEngine:
         self.pool_audit = bool(pool_audit)
         self._ssm_state = self._conv_tail = None
         self.ssm_resets = self._ssm_resets_reported = 0
+        # -- a model with routed experts: what the router chose comes back
+        # with each step's tokens. Totals since construction, the fetch
+        # span's attributes (MOE_FETCH_ATTRS: of the step landed last),
+        # and per layer the sum over passes of largest / mean assignments
+        # a held expert (`moe_passes` of them with any assignment)
+        self.moe_experts_touched = self.moe_assignments = 0
+        self.moe_passes = 0
+        self._moe_attrs = {}
+        self._moe_reported = (0, 0)
+        if self.model.routed:
+            self._moe_attrs = dict.fromkeys(MOE_FETCH_ATTRS, 0)
+            lo, hi = cfg.experts_held
+            self._moe_held = hi - lo
+            self._moe_load = np.zeros((cfg.num_layers,), np.float64)
         if self.model.recurrent:
             state_shape, tail_shape = self.model.state_shapes(cfg,
                                                               max_batch)
@@ -869,7 +914,8 @@ class ServingEngine:
     # -- public --------------------------------------------------------------
     def add_request(self, prompt, max_new_tokens: int, temperature=0.0,
                     eos_id=None, on_token=None,
-                    deadline_s: Optional[float] = None) -> int:
+                    deadline_s: Optional[float] = None,
+                    keep_routing: bool = False) -> int:
         """Submit a request. deadline_s: seconds from NOW the caller is
         willing to wait for completion — past it the scheduler sheds the
         request from the queue or cancels it mid-generation (pages
@@ -881,6 +927,14 @@ class ServingEngine:
         r = Request(rid, np.asarray(prompt, np.int32),
                     int(max_new_tokens), temperature, eos_id, on_token)
         r.submit_time = time.perf_counter()
+        if keep_routing:
+            from ..enforce import enforce
+            enforce(self.model.routed, "keep_routing: this model has no "
+                    "router", op="ServingEngine.add_request")
+            r.keep_routing = True
+            r.routing = np.full(
+                (len(r.prompt) + r.max_new_tokens, self.cfg.num_layers,
+                 self.cfg.experts_per_tok), -1, np.int16)
         if deadline_s is not None:
             r.deadline = r.submit_time + float(deadline_s)
         self._prom.counter_inc("requests_total",
@@ -1750,13 +1804,17 @@ class ServingEngine:
                          in_flight=int(prev is not None), **b.ssm_attrs):
             _faults().maybe_fail("serving/dispatch")
             out = self._unified(b.K, spec=b.use_spec)(*args)
+        route = ()
+        if self.model.routed:   # ids0, ids_burst, stats: fetched with toks
+            *out, ids0, ids_burst, stats = out
+            route = (ids0, ids_burst, stats)
         if self.model.recurrent:
             *out, self._ssm_state, self._conv_tail = out
         toks, *out = out
         greedy_all = out.pop(0) if b.use_spec else None
         (self._k_pools, self._v_pools, self._k_scales, self._v_scales,
          lens, self._last_tok) = out
-        b.out = (toks, greedy_all, lens)
+        b.out = (toks, greedy_all, lens) + route
         self._flight = b
         finished = [] if prev is None else self._land(prev)
         if not self.queue and not any(self._schedulable()[:2]):
@@ -1770,11 +1828,28 @@ class ServingEngine:
     def _land(self, f) -> List[Request]:
         """Fetch a dispatched step's tokens and walk them; returns the
         requests that finished in it."""
-        with RecordEvent(SERVING_SPANS.fetch):
+        with RecordEvent(SERVING_SPANS.fetch, **self._moe_attrs):
             # ONE host fetch: the copies start together, then the host
             # waits (a fetch of its own for `lens` cost 0.4 ms a step)
-            toks, greedy_all, lens = jax.device_get(f.out)   # toks [K, R]
-        return self._walk_ragged(f, toks, greedy_all, lens)
+            toks, greedy_all, lens, *route = jax.device_get(f.out)
+        if route:
+            self._note_routing(route[2])
+        return self._walk_ragged(f, toks, greedy_all, lens, *route[:2])
+
+    def _note_routing(self, stats):
+        """A landed step's router counts, stats [K, L, 3] (touched,
+        assignments, largest) of its K passes and L layers: the totals,
+        and the attributes the NEXT fetch span opens with."""
+        touched, assigned = int(stats[..., 0].sum()), int(stats[..., 1].sum())
+        self.moe_experts_touched += touched
+        self.moe_assignments += assigned
+        ran = stats[..., 1] > 0
+        self.moe_passes += int(ran.any(axis=1).sum())
+        self._moe_load += np.where(
+            ran, stats[..., 2] * self._moe_held
+            / np.maximum(stats[..., 1], 1), 0.0).sum(axis=0)
+        self._moe_attrs = dict(zip(MOE_FETCH_ATTRS, (
+            touched, assigned, int(stats[..., 2].max()))))
 
     @staticmethod
     def _advance(q_lens, pos0, sample0, remaining, K):
@@ -1995,7 +2070,8 @@ class ServingEngine:
         return args
 
     @RecordEvent(SERVING_SPANS.walk)
-    def _walk_ragged(self, b, toks, greedy_all, lens):
+    def _walk_ragged(self, b, toks, greedy_all, lens, ids0=None,
+                     ids_burst=None):
         """Commit a fetched step on the host: lengths, prefix pages,
         draft acceptance, then every emitted token through
         _emit/_finish; returns the requests that finished in it. A row
@@ -2014,6 +2090,12 @@ class ServingEngine:
         for r in pre:
             r.prefill_done += b.grants.get(r.slot, 0)
             self._register_pages(r)
+        for r in dec + pre:     # pass 1's positions of the rows that ran
+            i = r.slot
+            if r.keep_routing and not r.done and b.q_lens[i]:
+                at, n = int(b.starts[i]), int(b.q_lens[i])
+                r.routing[b.pos0[i]:b.pos0[i] + n] = \
+                    ids0[:, at:at + n].transpose(1, 0, 2)
         if b.use_spec:
             for r in dec:
                 i = r.slot
@@ -2056,6 +2138,10 @@ class ServingEngine:
                     finished.append(r)
                     self._finish(r)
                     break
+            if r.keep_routing and n > 1:
+                # burst pass j ran the row's position after pass j - 1's
+                at = int(b.pos0[i] + b.q_lens[i])
+                r.routing[at:at + n - 1] = ids_burst[:n - 1, :, i]
             # the next step may already be packed from `_advance`'s word:
             # a row that emitted all it was scheduled to (no EOS stopped
             # it short) must stand where the host said it would
@@ -2136,6 +2222,18 @@ class ServingEngine:
                                   "speculation health rate")
             self._spec_prop_reported = self.spec_proposed
             self._spec_acc_reported = self.spec_accepted
+        if self.model.routed:
+            was = self._moe_reported
+            self._moe_reported = (self.moe_assignments,
+                                  self.moe_experts_touched)
+            prom.counter_inc("moe_assignments_total",
+                             self._moe_reported[0] - was[0],
+                             help="token-expert assignments to the experts "
+                                  "held here")
+            prom.counter_inc("moe_experts_touched_total",
+                             self._moe_reported[1] - was[1],
+                             help="held experts a pass read, summed over "
+                                  "layers and passes")
         if self._ssm_state is not None:
             prom.gauge_set("ssm_state_bytes",
                            self._ssm_state.nbytes + self._conv_tail.nbytes,

@@ -1,0 +1,149 @@
+"""The routed experts of a sparse layer on the serving step's packed batch
+(Pallas): every token's assignments to the experts THIS chip holds, grouped
+by expert, through the gated feed-forward
+
+    y = (silu(x @ Wg_e) * (x @ Wu_e)) @ Wd_e
+
+one expert after another, so that an expert no token chose never leaves
+HBM (a decode pass of 64 rows touches ~183 of 256 held experts; each is
+6.3 MB at 2048 x 512 x 3 in bfloat16).
+
+`plan` sorts the pass's (token, pick) pairs by held expert and lays each
+expert's group out in whole TILES of `TM` rows (rows past a group's count
+are padding: zero inputs, outputs nobody reads). The kernel's grid walks
+the tiles: tile w is rows [w TM, (w + 1) TM) of the padded buffer and
+belongs to expert ``tile_expert[w]`` (scalar prefetch, dereferenced in the
+weights' index maps beside ``layer``: the stacked weights
+``[layers, experts, ., .]`` are never sliced, by layer or by expert;
+consecutive tiles of one expert do not fetch it again). The static grid
+is the bound `n_tiles_max`; a step past the last real tile stays on its
+blocks and does nothing. The token rows are gathered into the padded
+buffer before the call and the weighted outputs gathered back after it
+(`combine`), both in XLA: at most TM - 1 padding rows an expert.
+
+No assignment is ever dropped: there is no capacity.
+"""
+
+from __future__ import annotations
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ._common import interpret as _interpret
+from ...observability.trace import KERNELS
+
+__all__ = ["TM", "n_tiles_max", "plan", "grouped_ffn", "combine"]
+
+TM = 16     # rows of a tile: one bf16 sublane tile
+_F32 = jnp.float32
+
+
+def n_tiles_max(assignments, experts):
+    """The most tiles `assignments` pairs over `experts` groups can fill:
+    each group's last tile may be partial."""
+    return experts + -(-assignments // TM)
+
+
+def plan(ids, lo, hi):
+    """Group the picks ids: [T, k] (expert numbers over ALL the router's
+    experts) that fall on the held experts [lo, hi). Returns a dict:
+    ``held`` [T, k] bool; ``pos`` [T, k], the pick's row in the padded
+    buffer (0 where not held); ``token_of_row`` [G * TM] (T marks
+    padding); ``tile_expert`` [G] (held-expert index, the last real
+    tile's past the end) and ``n_tiles`` [1]; ``counts`` [hi - lo], the
+    assignments each held expert got."""
+    T, k = ids.shape
+    E, N = hi - lo, T * k
+    G = n_tiles_max(N, E)
+    held = (ids >= lo) & (ids < hi)
+    key = jnp.where(held, ids - lo, E).reshape(N).astype(jnp.int32)
+    counts = jnp.sum((key[:, None] == jnp.arange(E)[None, :])
+                     .astype(jnp.int32), axis=0)                    # [E]
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)          # [N]
+    group_end = jnp.cumsum(counts)
+    group_start = group_end - counts
+    tiles = (counts + TM - 1) // TM
+    tile_end = jnp.cumsum(tiles)
+    tile_start = tile_end - tiles
+    n_tiles = tile_end[-1]
+    # a sorted pair's row: its group's first tile, then its rank
+    sk = jnp.minimum(key[order], E - 1)
+    row_sorted = tile_start[sk] * TM + jnp.arange(N) - group_start[sk]
+    pos = row_sorted[jnp.argsort(order)].reshape(T, k)
+    # a padded row's token: its tile's expert, its rank in the group
+    w = jnp.arange(G, dtype=jnp.int32)
+    wc = jnp.minimum(w, jnp.maximum(n_tiles - 1, 0))
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(tile_end, wc, side="right"), E - 1
+    ).astype(jnp.int32)
+    rank = ((w - tile_start[tile_expert]) * TM)[:, None] \
+        + jnp.arange(TM)[None, :]                                   # [G, TM]
+    real = (w < n_tiles)[:, None] & (rank < counts[tile_expert][:, None])
+    at = jnp.clip(group_start[tile_expert][:, None] + rank, 0, N - 1)
+    token_of_row = jnp.where(real, order[at] // k, T).reshape(G * TM)
+    return {"held": held, "pos": jnp.where(held, pos, 0),
+            "token_of_row": token_of_row, "tile_expert": tile_expert,
+            "n_tiles": n_tiles.reshape(1).astype(jnp.int32),
+            "counts": counts}
+
+
+def _ffn_kernel(layer_ref, expert_ref, n_ref, x_ref, g_ref, u_ref, d_ref,
+                y_ref):
+    @pl.when(pl.program_id(0) < n_ref[0])
+    def _tile():
+        x = x_ref[...]
+        a = jnp.dot(x, g_ref[0, 0], preferred_element_type=_F32)
+        b = jnp.dot(x, u_ref[0, 0], preferred_element_type=_F32)
+        h = (a * jax.nn.sigmoid(a) * b).astype(x.dtype)
+        y_ref[...] = jnp.dot(h, d_ref[0, 0], preferred_element_type=_F32)
+
+
+def grouped_ffn(x, gate_w, up_w, down_w, layer, p):
+    """x: [T, H], the pass's normed tokens; gate_w, up_w:
+    [layers, E, H, F], down_w: [layers, E, F, H], the held experts of
+    every layer; p: `plan`'s dict. Returns y_pad [G * TM, H] float32: row
+    ``p['pos'][t, j]`` holds expert ``ids[t, j]``'s output for token t
+    (unweighted); rows of tiles past the last real one hold nothing
+    defined."""
+    T, H = x.shape
+    _, E, _, F = gate_w.shape
+    G = p["tile_expert"].shape[0]
+    x_pad = jnp.concatenate([x, jnp.zeros((1, H), x.dtype)])[
+        p["token_of_row"]]                                    # [G * TM, H]
+
+    def tile_idx(w, layer, expert, n):
+        return (jnp.minimum(w, jnp.maximum(n[0] - 1, 0)), 0)
+
+    def weight_idx(w, layer, expert, n):
+        return (layer[0], expert[w], 0, 0)
+
+    weights = 2 * 3 * H * F * gate_w.dtype.itemsize     # double-buffered
+    return pl.pallas_call(
+        _ffn_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(G,),
+            in_specs=[pl.BlockSpec((TM, H), tile_idx),
+                      pl.BlockSpec((1, 1, H, F), weight_idx),
+                      pl.BlockSpec((1, 1, H, F), weight_idx),
+                      pl.BlockSpec((1, 1, F, H), weight_idx)],
+            out_specs=pl.BlockSpec((TM, H), tile_idx)),
+        out_shape=jax.ShapeDtypeStruct((G * TM, H), _F32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=min(max(2 * weights, 32 << 20), 96 << 20)),
+        interpret=_interpret(),
+        name=KERNELS.moe_grouped_ffn,
+    )(jnp.asarray(layer, jnp.int32).reshape(1), p["tile_expert"],
+      p["n_tiles"], x_pad, gate_w, up_w, down_w)
+
+
+def combine(y_pad, weights, p):
+    """sum_j weights[t, j] * (expert ids[t, j]'s output for token t) over
+    the held picks; y_pad: `grouped_ffn`'s result, weights: [T, k] f32.
+    A pick that is not held adds nothing (and reads nothing: a row no
+    tile wrote may hold anything). Returns [T, H] float32."""
+    picked = y_pad[p["pos"]]                                  # [T, k, H]
+    return jnp.sum(jnp.where(p["held"][..., None],
+                             weights[..., None] * picked, 0.0), axis=1)

@@ -82,8 +82,9 @@ def _active_rows(q_lens):
 
 
 # -- the conv ---------------------------------------------------------------
-def _conv_kernel(layer_ref, x_ref, w_ref, b_ref, tok_ref, row_ref, tail_in,
-                 y_ref, tail_out, *, K):
+def _conv_kernel(layer_ref, x_ref, w_ref, *refs, K):
+    # the bias is an operand only where the conv has one
+    *b_ref, tok_ref, row_ref, tail_in, y_ref, tail_out = refs
     T, R = x_ref.shape[0], row_ref.shape[0]
     x = x_ref[...]
     row_of, off_of = tok_ref[:, 0:1], tok_ref[:, 1:2]            # [T, 1]
@@ -94,8 +95,9 @@ def _conv_kernel(layer_ref, x_ref, w_ref, b_ref, tok_ref, row_ref, tail_in,
     src = jnp.concatenate([x] + old, axis=0)             # [T + (K-1)R, cb]
     col = jax.lax.broadcasted_iota(jnp.int32, (T, src.shape[0]), 1)
     t = jax.lax.broadcasted_iota(jnp.int32, (T, src.shape[0]), 0)
-    acc = x.astype(_F32) * w_ref[K - 1:K, :].astype(_F32) + \
-        b_ref[...].astype(_F32)
+    acc = x.astype(_F32) * w_ref[K - 1:K, :].astype(_F32)
+    if b_ref:
+        acc = acc + b_ref[0][...].astype(_F32)
     for j in range(1, K):
         # the input j positions back: in the packed buffer when the row
         # has it this pass, else in the row's tail (plane K-1 + off - j)
@@ -122,7 +124,8 @@ def _conv_kernel(layer_ref, x_ref, w_ref, b_ref, tok_ref, row_ref, tail_in,
 def ssm_conv(x, w, b, tail, layer, row_of, off_of, starts, q_lens, reset,
              *, block=512):
     """silu(causal depthwise conv) of the packed rows x: [T, C] with taps
-    w: [K, C] (w[K-1] on the token itself) and bias b: [C]; a row's first
+    w: [K, C] (w[K-1] on the token itself) and bias b: [C], or None for a
+    conv without one; a row's first
     tokens read the K-1 inputs before them from ``tail[layer]``
     ([L, K-1, R, C], plane K-2 the newest; taken as 0 where ``reset``),
     which leaves holding the row's last K-1 inputs. row_of/off_of: [T]
@@ -140,25 +143,25 @@ def ssm_conv(x, w, b, tail, layer, row_of, off_of, starts, q_lens, reset,
         return (layer[0], 0, 0, c)
 
     tail_block = pl.BlockSpec((1, K - 1, R, cb), tail_idx)
+    has_bias = b is not None
     y, tail = pl.pallas_call(
         functools.partial(_conv_kernel, K=K),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1, grid=(C // cb,),
             in_specs=[pl.BlockSpec((T, cb), lambda c, *_: (0, c)),
-                      pl.BlockSpec((K, cb), lambda c, *_: (0, c)),
-                      pl.BlockSpec((1, cb), lambda c, *_: (0, c)),
-                      pl.BlockSpec((T, 2), lambda c, *_: (0, 0)),
-                      pl.BlockSpec((R, 3), lambda c, *_: (0, 0)),
-                      tail_block],
+                      pl.BlockSpec((K, cb), lambda c, *_: (0, c))]
+            + [pl.BlockSpec((1, cb), lambda c, *_: (0, c))] * has_bias
+            + [pl.BlockSpec((T, 2), lambda c, *_: (0, 0)),
+               pl.BlockSpec((R, 3), lambda c, *_: (0, 0)), tail_block],
             out_specs=[pl.BlockSpec((T, cb), lambda c, *_: (0, c)),
                        tail_block]),
         out_shape=[jax.ShapeDtypeStruct((T, C), x.dtype),
                    jax.ShapeDtypeStruct(tail.shape, tail.dtype)],
-        input_output_aliases={6: 1},
+        input_output_aliases={5 + has_bias: 1},
         interpret=_interpret(),
         name=KERNELS.ssm_conv,
-    )(jnp.asarray(layer, jnp.int32).reshape(1), x, w, b.reshape(1, C), tok,
-      row, tail)
+    )(jnp.asarray(layer, jnp.int32).reshape(1), x, w,
+      *([b.reshape(1, C)] if has_bias else []), tok, row, tail)
     return y, tail
 
 

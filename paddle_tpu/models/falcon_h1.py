@@ -189,7 +189,16 @@ class Serving:
     the per-slot state this model's `mixer` reads and writes."""
 
     recurrent = True
+    routed = False
     state_shapes = staticmethod(state_shapes)
+
+    @staticmethod
+    def pattern(cfg):
+        return (("parallel", 1),)   # attention and the mixer side by side
+
+    @staticmethod
+    def kv_layers(cfg):
+        return cfg.num_layers
 
     @staticmethod
     def positions(pos, cfg):

@@ -15,7 +15,8 @@ trace of one commit can be held against a trace of the next:
 * ``SERVING_SPANS`` — the host spans of one ``ServingEngine.step()``;
 * ``DISPATCH_ATTRS`` — the attributes of the ``serving_unified_dispatch``
   span (stats of the event in a profiler trace), and ``SSM_DISPATCH_ATTRS``
-  — the ones a model with a recurrent state adds;
+  — the ones a model with a recurrent state adds; ``MOE_FETCH_ATTRS`` —
+  the attributes a model with routed experts puts on ``serving_fetch``;
 * ``SCOPES`` — the ``jax.named_scope``s inside the compiled programs (the
   serving step, the dense train step, the hybrid train step). A device
   operation's ``op_name`` path carries them; an operation under none is
@@ -38,7 +39,8 @@ from typing import Iterable, Optional
 from ..profiler.utils import HostEvent, RecordEvent, collector
 
 __all__ = ["span", "capture_spans", "write_chrome_trace", "SERVING_SPANS",
-           "DISPATCH_ATTRS", "SSM_DISPATCH_ATTRS", "SCOPES", "KERNELS"]
+           "DISPATCH_ATTRS", "SSM_DISPATCH_ATTRS", "MOE_FETCH_ATTRS", "SCOPES",
+           "KERNELS"]
 
 span = RecordEvent
 
@@ -76,6 +78,14 @@ DISPATCH_ATTRS = ("step", "k", "n_dec", "n_pre", "q_tokens", "kv_tokens",
 # and wrote (the chunk scan's), rows of the k - 1 burst passes (the state
 # update's, summed), and tokens through its mixer over all k passes.
 SSM_DISPATCH_ATTRS = ("ssm_scan_rows", "ssm_update_rows", "ssm_tokens")
+# A model with routed experts learns what a step's router chose only from
+# the step's fetch, so these ride the `serving_fetch` span, and (a span's
+# attributes are fixed when it opens) each fetch carries the counts of the
+# step fetched BEFORE it: held experts whose weights the step read, summed
+# over its layers and passes; token-expert assignments to held experts;
+# and the largest number of assignments one held expert got in a layer of
+# a pass. Over a window the sums miss one step at either end.
+MOE_FETCH_ATTRS = ("moe_experts_touched", "moe_assignments", "moe_load_max")
 
 SCOPES = _names(
     "Scopes",
@@ -86,6 +96,11 @@ SCOPES = _names(
     # a hybrid block's recurrent mixer beside attention (models/falcon_h1.py)
     rope="rope", ssm_in="ssm_in", ssm_conv="ssm_conv", ssm_scan="ssm_scan",
     ssm_out="ssm_out",
+    # a layer pattern of linear-attention and gated-attention layers with
+    # routed experts in every layer (models/qwen3_next.py)
+    moe_route="moe_route", moe_experts="moe_experts", moe_shared="moe_shared",
+    gdn_in="gdn_in", gdn_conv="gdn_conv", gdn_scan="gdn_scan",
+    gdn_out="gdn_out",
     # train programs (models/gpt.py, optimizer/); embed and qkv as above
     attn="attn", flash="flash", attn_out="attn_out", mlp="mlp",
     head_loss="head_loss", optimizer="optimizer",
@@ -104,7 +119,11 @@ KERNELS = _names(
     prim_layer_norm_bwd="prim_layer_norm_bwd",
     # the recurrent-state path of a Mamba-2 mixer (kernels/pallas/ssm.py)
     ssm_conv="ssm_conv", ssm_chunk_scan="ssm_chunk_scan",
-    ssm_state_update="ssm_state_update")
+    ssm_state_update="ssm_state_update",
+    # the gated delta rule's state path (kernels/pallas/gdn.py) and the
+    # grouped expert product (kernels/pallas/moe.py)
+    gdn_chunk_scan="gdn_chunk_scan", gdn_state_update="gdn_state_update",
+    moe_grouped_ffn="moe_grouped_ffn")
 
 
 class capture_spans:

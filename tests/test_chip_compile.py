@@ -33,7 +33,8 @@ from jax.sharding import SingleDeviceSharding
 
 _KERNEL_MODULES = ("flash_attention", "layer_norm", "rms_norm", "rope",
                    "primitives", "fused_adam", "paged_attention",
-                   "ragged_paged_attention", "kv_append", "ssm")
+                   "ragged_paged_attention", "kv_append", "ssm", "gdn",
+                   "moe")
 
 # the serving smoke's pool geometry (chip_smoke.py): GPT-1.3B heads,
 # 128-token pages
@@ -241,9 +242,11 @@ _HLO_RESULT = re.compile(
 _POOL_MOVERS = {"copy", "copy-start", "dynamic-slice", "dynamic-update-slice"}
 
 
-def _moved(text, hit):
+def _moved(text, hit, by_shape=False):
     """Copies, slices, updates and loop fusions of the compiled text whose
-    result's element count `hit` accepts. Views (`bitcast`), the
+    result's element count `hit` accepts (with `by_shape`, whose result's
+    dimensions, leading 1s dropped: where an activation has as many
+    elements as a buffer, the count cannot tell them apart). Views (`bitcast`), the
     while/tuple plumbing, the in-place scatter and the copy-on-write page
     gather (`kCustom` fusions) may carry that size."""
     found = []
@@ -252,11 +255,20 @@ def _moved(text, hit):
         if not m or not m.group(1):
             continue
         n = math.prod(int(d) for d in m.group(1).split(","))
+        if by_shape:
+            n = _no_leading_ones(int(d) for d in m.group(1).split(","))
         op = m.group(2)
         if hit(n) and (op in _POOL_MOVERS
                        or op == "fusion" and "kind=kCustom" not in line):
             found.append(line.strip()[:160])
     return found
+
+
+def _no_leading_ones(dims):
+    dims = tuple(dims)
+    while len(dims) > 1 and dims[0] == 1:
+        dims = dims[1:]
+    return dims
 
 
 _HLO_SHAPE = re.compile(r"\b[a-z]+\d*\[([\d,]+)\]")
@@ -417,6 +429,77 @@ def test_falcon_h1_step_keeps_state_and_pool_in_place(one_chip,
     # packed buffer, padded to 24 heads for the kernel's copies, is there)
     assert _query_tiles(text, H1_ROWS, cfg.ssm_chunk, cfg.num_heads) == []
     assert _query_tiles(text, 1, tokens, 24) != []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
+    donated = (2 * math.prod(pool_shape) * 2 + math.prod(state_shape) * 4
+               + math.prod(tail_shape) * 2)
+    assert mem.alias_size_in_bytes >= donated
+
+
+# ---------------------------------------------------------------------------
+# Qwen3-Next-80B-A3B's serving step at its cell's geometry (ISSUE 36): one
+# period of the layer pattern (three Gated DeltaNet layers, one gated
+# attention layer) at the published widths, 256 of 512 experts held, 64
+# slots; the pool holds ONE layer [1, 2, 640, 128, 256] (D = 256), the
+# state three [3, 64, 32, 128, 128] f32, and the run's stacked expert
+# weights (1.6 GB a layer) are taken whole like the pool.
+Q3N_ROWS, Q3N_PAGES, Q3N_TABLE = 64, 640, 8
+
+
+@pytest.mark.parametrize("K", [1, 8], ids=["q3n-pass1", "q3n-burst"])
+def test_qwen3_next_step_keeps_state_pool_and_experts_in_place(
+        one_chip, compiled_kernels, K):
+    """The period scan keeps the contract: no copy, slice or update the
+    size of the state (one layer's slots or all three), of the pool, of
+    the conv tail or of a layer's expert matrices; temp under 1 GiB;
+    everything donated comes back aliased; `ragged_paged_attn` and
+    `kv_append` lower at D = 256 with 2 KV heads."""
+    from paddle_tpu.inference import ragged_step as RS
+    from paddle_tpu.models import qwen3_next as QN
+    cfg = QN.Qwen3NextConfig(vocab_size=75968, num_layers=4,
+                             experts_held=(0, 256))
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: QN.init_params(cfg, jax.random.PRNGKey(0))))
+    tokens = Q3N_ROWS + cfg.ssm_chunk
+
+    def i32(*shape):
+        return _sds(one_chip, shape, jnp.int32)
+
+    def flags():
+        return _sds(one_chip, (Q3N_ROWS,), jnp.bool_)
+
+    pool_shape = (1, cfg.num_kv_heads, Q3N_PAGES, PAGE, cfg.head_dim)
+    state_shape, tail_shape = QN.state_shapes(cfg, Q3N_ROWS)
+    assert state_shape == (3, 64, 32, 128, 128)
+    assert tail_shape == (3, 3, 64, 8192)
+    pool = _sds(one_chip, pool_shape, jnp.bfloat16)
+    args = [params, i32(tokens), i32(tokens), i32(tokens), i32(Q3N_ROWS),
+            i32(Q3N_ROWS), i32(Q3N_ROWS), i32(Q3N_ROWS, Q3N_TABLE), flags(),
+            flags(), i32(Q3N_ROWS), i32(Q3N_ROWS),
+            _sds(one_chip, (Q3N_ROWS,), jnp.float32), i32(Q3N_ROWS),
+            _sds(one_chip, (2,), jnp.uint32), pool, pool, None, None, None,
+            None, None, _sds(one_chip, state_shape, jnp.float32),
+            _sds(one_chip, tail_shape, jnp.bfloat16)]
+    step = functools.partial(RS.unified_step, cfg=cfg, bs=PAGE,
+                             c_att=cfg.ssm_chunk, K=K)
+    compiled = jax.jit(step, donate_argnums=(15, 16, 22, 23)
+                       ).lower(*args).compile()
+    text = compiled.as_text()
+    for kernel in ("ssm_conv", "gdn_chunk_scan", "moe_grouped_ffn",
+                   "ragged_paged_attn", "kv_append") + (
+                       ("gdn_state_update",) if K > 1 else ()):
+        assert kernel in text, f"{kernel} was not lowered for the chip"
+    # by shape: the chunk scan's [64, 128, 32, 128] tiles have as many
+    # elements as a layer's state, the packed [192, 16, 512] queries as a
+    # layer's conv tail. A layer's expert matrices: [256, 2048, 512]
+    # (gate, up), [256, 512, 2048] (down); a run's: three of them
+    buffers = [state_shape, state_shape[1:], pool_shape, tail_shape,
+               tail_shape[1:]]
+    for expert in ((256, 2048, 512), (256, 512, 2048)):
+        buffers += [expert, (3,) + expert]
+    assert _moved(text, {_no_leading_ones(b) for b in buffers}.__contains__,
+                  by_shape=True) == []
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
     donated = (2 * math.prod(pool_shape) * 2 + math.prod(state_shape) * 4

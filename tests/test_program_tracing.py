@@ -27,8 +27,10 @@ from paddle_tpu import observability as obs  # noqa: E402
 from paddle_tpu.inference.serving import ServingEngine  # noqa: E402
 from paddle_tpu.models import gpt as G  # noqa: E402
 from paddle_tpu.models import falcon_h1 as FH  # noqa: E402
+from paddle_tpu.models import qwen3_next as QN  # noqa: E402
 from paddle_tpu.observability.trace import (DISPATCH_ATTRS, KERNELS,  # noqa: E402
-                                            SCOPES, SERVING_SPANS,
+                                            MOE_FETCH_ATTRS, SCOPES,
+                                            SERVING_SPANS,
                                             SSM_DISPATCH_ATTRS)
 from paddle_tpu.profiler.utils import RecordEvent, collector  # noqa: E402
 
@@ -333,6 +335,47 @@ def test_the_hybrid_serving_step_carries_its_scopes_and_attributes():
             attrs["ssm_tokens"]) == (1, 0, 6)
 
 
+def test_the_pattern_serving_step_carries_its_scopes_and_attributes():
+    """Qwen3-Next through the same engine: the linear layers' four
+    scopes, the expert layer's three, attention's of the GPT step (no
+    `cow`); the dispatch span carries the state attributes, and the
+    fetch span the router's counts of the step landed before it."""
+    cfg = QN.Qwen3NextConfig(
+        vocab_size=64, hidden_size=32, num_layers=4, num_heads=4,
+        num_kv_heads=2, head_dim=16, linear_key_heads=2,
+        linear_value_heads=4, linear_key_dim=8, linear_value_dim=8,
+        num_experts=8, experts_per_tok=2, moe_ffn=16, shared_ffn=16,
+        experts_held=(0, 4), ssm_chunk=8, dtype=jnp.float32,
+        param_dtype=jnp.float32)
+    params = QN.init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(params, cfg, max_batch=2, block_size=16,
+                        num_blocks=16, chunk=8, decode_burst=4)
+    eng.add_request(np.arange(6) % 64, max_new_tokens=8)
+    batch = eng._pack_ragged(eng._admit())
+    lowered = eng._build_unified(2).lower(*eng._upload_ragged(batch))
+    assert _scopes_in(lowered) == {
+        SCOPES.embed, SCOPES.qkv, SCOPES.rope, SCOPES.kv_write,
+        SCOPES.ragged_attn, SCOPES.gdn_in, SCOPES.gdn_conv, SCOPES.gdn_scan,
+        SCOPES.gdn_out, SCOPES.moe_route, SCOPES.moe_experts,
+        SCOPES.moe_shared, SCOPES.proj_mlp, SCOPES.head, SCOPES.sample,
+        SCOPES.burst,
+        # not a scope here: the conv KERNEL's name, which follows
+        # `gdn_conv` on its path (the cell's shares do not list it)
+        SCOPES.ssm_conv}
+    with obs.capture_spans() as cap:
+        eng.run()
+    disp = [e.attrs for e in cap.events if e.name == SERVING_SPANS.dispatch]
+    assert tuple(disp[0]) == DISPATCH_ATTRS + SSM_DISPATCH_ATTRS
+    fetch = [e.attrs for e in cap.events if e.name == SERVING_SPANS.fetch]
+    assert all(tuple(a) == MOE_FETCH_ATTRS for a in fetch)
+    assert fetch[0] == dict.fromkeys(MOE_FETCH_ATTRS, 0)   # none landed yet
+    # every landed step's counts ride the next fetch; the last step's are
+    # in the totals only
+    assert 0 < sum(a["moe_assignments"] for a in fetch) < eng.moe_assignments
+    assert all(a["moe_experts_touched"] <= a["moe_assignments"]
+               and a["moe_load_max"] <= a["moe_assignments"] for a in fetch)
+
+
 def _calls(tree, attr):
     return [n for n in ast.walk(tree) if isinstance(n, ast.Call)
             and isinstance(n.func, ast.Attribute) and n.func.attr == attr]
@@ -389,11 +432,26 @@ def test_the_new_metrics_are_the_issues_and_two_more():
     # the pp axis's time in flight (PERF.md, PR 26: why they were needed);
     # ISSUE 28's cell reads sixteen through the same readers: chat's
     # eleven, two more shares (the mixer's projections, its state path)
-    # and three rooflines
-    assert len(NEW) == 48
+    # and three rooflines; ISSUE 36's reads nineteen: chat's eleven, four
+    # more shares (the experts, the routing, the delta rule's state path
+    # and its projections) and four rooflines, three of them named for
+    # kernels only this cell runs and so without a suffix
+    assert len(NEW) == 67
     assert len([n for n in NEW if n.endswith(".docs")]) == 11
     assert len([n for n in NEW if n.endswith(".chat")]) == 11
     assert len([n for n in NEW if n.endswith(".h1chat")]) == 16
+    assert len([n for n in NEW if _suffix(n) == ".q3nchat"]) == 19
+
+
+# a metric's cell group: its suffix, or the group of the only cell that
+# runs the kernel it is named for
+UNSUFFIXED = {"moe_grouped_ffn_roofline": ".q3nchat",
+              "gdn_state_update_roofline": ".q3nchat",
+              "gdn_chunk_scan_roofline": ".q3nchat"}
+
+
+def _suffix(name):
+    return UNSUFFIXED.get(name) or "." + name.rsplit(".", 1)[1]
 
 
 @pytest.mark.parametrize("name", NEW)
@@ -410,13 +468,14 @@ def test_metric_files_name_only_what_the_program_names(name):
     if "kernel" in params:
         assert params["kernel"] in KERNELS
     if "attr" in params:
-        assert params["attr"] in DISPATCH_ATTRS + SSM_DISPATCH_ATTRS
+        assert params["attr"] in (DISPATCH_ATTRS + SSM_DISPATCH_ATTRS
+                                  + MOE_FETCH_ATTRS)
 
 
 def test_the_share_metrics_of_a_cell_divide_its_program():
     """Each cell's shares name disjoint scopes that together are `of`, so
     the shares and the unscoped share sum to 100."""
-    for suffix in (".docs", ".chat", ".train", ".h1chat"):
+    for suffix in (".docs", ".chat", ".train", ".h1chat", ".q3nchat"):
         shares = [harness.load_json("metrics", n + ".json")["params"]
                   for n in NEW if n.endswith("_time_pct" + suffix)]
         of = shares[0]["of"]
@@ -451,12 +510,13 @@ def _run_of(pt):
 SLICES = {".docs": "ptrace-v5e-serve-docs-slice.json.gz",
           ".chat": "ptrace-v5e-serve-chat-slice.json.gz",
           ".train": "ptrace-v5e-train-hybrid-slice.json.gz",
-          ".h1chat": "ptrace-v5e-h1chat.json.gz"}
+          ".h1chat": "ptrace-v5e-h1chat.json.gz",
+          ".q3nchat": "ptrace-v5e-q3nchat.json.gz"}
 
 
 @pytest.mark.parametrize("name", NEW)
 def test_reader_on_a_slice_recorded_on_the_chip(name):
-    pt = _slice(SLICES["." + name.rsplit(".", 1)[1]])
+    pt = _slice(SLICES[_suffix(name)])
     value = harness.read_metric(name, _run_of(pt))
     assert value is not None and 0.0 <= value
     if name.split(".")[0].endswith(("_pct", "_roofline")):
@@ -476,7 +536,7 @@ def test_the_shares_of_a_recorded_slice_sum_to_100(suffix):
 @pytest.mark.parametrize("name", NEW)
 def test_reader_returns_nothing_without_the_programs_names(name):
     """The parent commit's program: no spans of its own, no scopes."""
-    pt = _slice(SLICES["." + name.rsplit(".", 1)[1]])
+    pt = _slice(SLICES[_suffix(name)])
     bare = {"devices": {k: [e[:3] + [re.sub(r"[^/()]+", "x", e[3])]
                             for e in v] for k, v in pt["devices"].items()},
             "async": {k: [e[:3] + [""] for e in v]
