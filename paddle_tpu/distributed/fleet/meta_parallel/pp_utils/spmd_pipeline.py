@@ -115,7 +115,13 @@ def spmd_pipeline(stage_fn: Callable, stage_params, x_microbatches,
         (the per-rank segment: typically a lax.scan over L/P stacked blocks).
     stage_params: this rank's local (already sharded-in) parameter pytree.
     x_microbatches: [M, mb, ...] — microbatch inputs, replicated over `axis`
-        (only stage 0 consumes them).
+        (only stage 0 consumes them). M microbatches take T = M + P - 1
+        ticks; with checkpoint_stages every tick's stage is run again in
+        its backward, so that M ticks' residuals are never alive at once.
+        At P = 1 that is M forwards, then M replays each with its backward:
+        nothing is pipelined, and the hybrid builders (models/gpt.py,
+        models/llama.py) accumulate their microbatches without this
+        function on such a mesh; what rides with_aux still comes here.
 
     Returns [M, mb, ...] — outputs of the LAST stage, valid on every rank
     (zeros elsewhere are summed into place with one psum at the end).

@@ -36,6 +36,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 from .. import nn
 from ..nn import functional as F
 from ..observability.trace import SCOPES
+from ..kernels.pallas.flash_attention import FLASH_REMAT_NAMES
 from ..quantization.fp8 import site_mm as _fp8_mm
 from ..distributed.fleet.meta_parallel.pp_utils.spmd_pipeline import (
     spmd_pipeline, spmd_pipeline_interleaved, spmd_pipeline_zero_bubble,
@@ -331,6 +332,7 @@ def _attn_sublayer(p, x, cfg: GPTConfig, mp_axis: str = "mp", fp8=None,
     heads_local = cfg.num_heads // mp
     B = x.shape[0]
     H = cfg.hidden_size
+    from jax.ad_checkpoint import checkpoint_name
     from ..distributed.fleet.layers.mpu import mp_ops
 
     with jax.named_scope(SCOPES.qkv):
@@ -348,6 +350,10 @@ def _attn_sublayer(p, x, cfg: GPTConfig, mp_axis: str = "mp", fp8=None,
                 ring=sp.ring,
                 mm=None if fp8 is None else _fp8_mm(fp8, "qkv"))
                 + p["qkv_b"].astype(cfg.dtype))  # [B, S, 3H/mp]
+        # checkpoint_name tags are inert under plain jax.checkpoint (the
+        # pipeline's stage checkpoint); hybrid_microbatch_share's policy
+        # keys on them (ONE_STAGE_SAVE)
+        qkv = checkpoint_name(qkv, "qkv")
         qkv = qkv.reshape(B, S, heads_local, 3, cfg.head_dim)
     # heads are fully local under TP, so per-shard attention is the whole
     # computation (over the FULL sequence under sp — only the
@@ -373,14 +379,15 @@ def _attn_sublayer(p, x, cfg: GPTConfig, mp_axis: str = "mp", fp8=None,
         attn = attn.reshape(B, S, H // mp)
         if sp is None:
             out = _fp8_mm(fp8, "proj")(attn, p["proj_w"].astype(cfg.dtype))
-            out = (mp_ops.mp_allreduce(out, mp_axis)
-                   + p["proj_b"].astype(cfg.dtype))
+            out = mp_ops.mp_allreduce(out, mp_axis)
         else:
-            out = (mp_ops.matmul_rs(
+            out = mp_ops.matmul_rs(
                 attn, p["proj_w"].astype(cfg.dtype), mp_axis, ring=sp.ring,
                 mm=None if fp8 is None else _fp8_mm(fp8, "proj"))
-                + p["proj_b"].astype(cfg.dtype))
-        return x + out
+        # tagged AFTER the collective: a policy that keeps it replays
+        # neither the GEMM nor the all-reduce
+        out = checkpoint_name(out, "proj")
+        return x + (out + p["proj_b"].astype(cfg.dtype))
 
 
 def _block_fn(p, x, cfg: GPTConfig, mp_axis: str = "mp", fp8=None, sp=None,
@@ -438,6 +445,7 @@ def _mlp_sublayer(p, x, cfg: GPTConfig, mp_axis: str = "mp", fp8=None,
     """ln2 + Megatron-TP MLP + residual — the second half of the dense
     hybrid block (column-parallel fc1, row-parallel fc2; see _block_fn
     for the sp forms)."""
+    from jax.ad_checkpoint import checkpoint_name
     from ..distributed.fleet.layers.mpu import mp_ops
     h = _ln(x, p["ln2_g"], p["ln2_b"])
     if sp is None:
@@ -451,6 +459,7 @@ def _mlp_sublayer(p, x, cfg: GPTConfig, mp_axis: str = "mp", fp8=None,
             ring=sp.ring,
             mm=None if fp8 is None else _fp8_mm(fp8, "fc1"))
             + p["fc1_b"].astype(cfg.dtype))
+    m = checkpoint_name(m, "fc1")
     m = jax.nn.gelu(m.astype(jnp.float32), approximate=True).astype(cfg.dtype)
     if sp is None:
         m = _fp8_mm(fp8, "fc2")(m, p["fc2_w"].astype(cfg.dtype))
@@ -1064,6 +1073,147 @@ def _note_moe_wire(cfg: GPTConfig, tokens, mp_axis, pp_axis, ep_axis,
         quantize=bool(mcfg is not None and mcfg.quantize)))
 
 
+def _hybrid_embed(params, tokens, cfg: GPTConfig, mp_axis, sp, sep_on,
+                  sep_axis):
+    """Vocabulary-parallel token embedding + positions of the hybrid loss:
+    [b, S, H], or this rank's [b, S/mp, H] sequence shard under sp."""
+    S = tokens.shape[1]
+    with jax.named_scope(SCOPES.embed):
+        x = _vocab_parallel_embed(params["wte"], tokens, mp_axis)
+        if sep_on:
+            # tokens are this rank's sequence shard: position embedding reads
+            # the rank's GLOBAL slice (causal masking inside ring/Ulysses
+            # likewise uses global positions). The GLOBAL length must fit the
+            # table — dynamic_slice CLAMPS an out-of-range start, so an
+            # oversized sequence would silently hand later ranks the first
+            # ranks' position rows instead of erroring
+            n_sep = lax.axis_size(sep_axis)
+            enforce(S * n_sep <= cfg.max_seq_len,
+                    "sep context parallelism: the global sequence "
+                    "(per-rank S x sep degree) must fit max_seq_len — the "
+                    "position table is sliced per rank",
+                    op="gpt.hybrid_loss_fn", seq_local=S, sep=n_sep,
+                    max_seq_len=cfg.max_seq_len)
+            off = lax.axis_index(sep_axis) * S
+            x = x + lax.dynamic_slice_in_dim(params["wpe"], off, S,
+                                             axis=0)[None]
+        else:
+            x = x + params["wpe"][None, :S]
+        x = x.astype(cfg.dtype)
+    if sp is not None:
+        from ..distributed.comm_overlap import collective_matmul as _cm
+        enforce(S % lax.axis_size(mp_axis) == 0,
+                "sequence parallelism needs S divisible by the mp degree",
+                op="gpt.hybrid_loss_fn", seq=S,
+                mp=lax.axis_size(mp_axis))
+        x = _cm.scatter_seq(x, mp_axis, dim=1)  # [b_local, S/mp, H]
+    return x
+
+
+def _head_logits(params, out, cfg: GPTConfig, mp_axis, sp):
+    """Final LayerNorm and the column-parallel head of the hybrid loss:
+    this mp rank's [b, S, V/mp] logits."""
+    from ..distributed.fleet.layers.mpu import mp_ops
+    lnf_g, lnf_b = params["lnf_g"], params["lnf_b"]
+    if sp is not None:
+        # final LN runs on the seq shard — its param grads are partial
+        # (see the _block_fn sp note)
+        lnf_g = mp_ops.c_identity(lnf_g, mp_axis)
+        lnf_b = mp_ops.c_identity(lnf_b, mp_axis)
+    out = _ln(out, lnf_g, lnf_b)
+    if sp is None:
+        # column-parallel head: identity fwd / allreduce bwd on its input
+        out = mp_ops.c_identity(out, mp_axis)
+        logits_local = (out.astype(cfg.dtype)
+                        @ params["head_w"].astype(cfg.dtype))
+    else:
+        # seq-sharded final LN, then AG -> column GEMM (bwd RS) — same
+        # wire as the allreduce-mode head boundary
+        logits_local = mp_ops.ag_matmul(
+            out.astype(cfg.dtype), params["head_w"].astype(cfg.dtype),
+            mp_axis, ring=sp.ring)
+    return logits_local
+
+
+# What a block of hybrid_microbatch_share keeps for its backward besides
+# its input: every GEMM's output (qkv and fc1 with their biases, the
+# attention projection AFTER its mp all-reduce) and the flash kernel's
+# (out, lse): ~107 MB a layer and microbatch at the 6.7B widths on mp 2.
+# No GEMM, kernel or collective runs again; the LayerNorms, the GELU and
+# the residual adds do, from those. Kept whole, AD's float32 residuals of
+# the GELU and the norms are 0.55 GB a layer (five [S, F/mp] and four
+# [S, H] float32 stacks): the compiler then re-materialises GEMMs of its
+# own choosing to fit them (PERF.md, PR 37).
+ONE_STAGE_SAVE = ("qkv", "proj", "fc1") + FLASH_REMAT_NAMES
+
+
+def hybrid_microbatch_share(params, tokens, labels, denom,
+                            cfg: GPTConfig, pp_axis="pp", mp_axis="mp",
+                            sp=None, flash=None, sep_axis="sep"):
+    """One microbatch's share of the per-device loss on a mesh with ONE
+    pipeline stage (runs inside shard_map): embedding, the block scan,
+    final norm, head and vocabulary-parallel loss of these rows, the token
+    losses summed and divided by `denom`, the valid labels of the WHOLE
+    local batch. No pipeline is built (no tick scan, no ppermute) and the
+    blocks carry no stage checkpoint: the engine differentiates one share
+    at a time (hybrid_engine.AccumulatedLoss), so one microbatch's
+    residuals are alive; each block keeps ONE_STAGE_SAVE and the head its
+    logits, so no GEMM, kernel or collective is run again. sp / flash /
+    sep_axis: as hybrid_loss_fn."""
+    sep_on = flash is not None and flash.sep is not None
+    x = _hybrid_embed(params, tokens, cfg, mp_axis, sp, sep_on, sep_axis)
+
+    # prevent_cse=False: the block runs in a scan's body, where the
+    # forward and its replay cannot be merged anyway, and the barriers it
+    # saves cost 3% of the cell's step (PERF.md, PR 37)
+    block = jax.checkpoint(
+        lambda p, x: _block_fn(p, x, cfg, mp_axis, sp=sp, flash=flash,
+                               sep_axis=sep_axis),
+        prevent_cse=False,
+        policy=jax.checkpoint_policies.save_only_these_names(
+            *ONE_STAGE_SAVE))
+
+    def body(carry, p):
+        return block(p, carry), None
+    out, _ = lax.scan(body, x, params["blocks"])
+    with jax.named_scope(SCOPES.head_loss):
+        logits_local = _head_logits(params, out, cfg, mp_axis, sp)
+        _note_mp_wire(cfg, tokens, sp, mp_axis, pp_axis, 1,
+                      jax.tree.leaves(params["blocks"])[0].shape[0])
+        return share_of_loss(logits_local, labels, denom, mp_axis)
+
+
+def share_of_loss(logits_local, labels, denom, mp_axis):
+    """A microbatch's token losses (vocabulary-parallel) summed over
+    `denom`. The loss reads the logits four times (their max, the sum of
+    exponentials, the label's, the backward's softmax) with an mp
+    all-reduce between the readers; in a loop's body the compiler runs the
+    head GEMM again for each reader rather than keep them (PERF.md, PR 37),
+    so they are made a value it cannot re-derive."""
+    loss, _ = _vocab_parallel_ce(lax.optimization_barrier(logits_local),
+                                 labels, mp_axis)
+    return jnp.sum(loss) / denom
+
+
+def one_stage_loss(loss_fn, num_microbatches: int, share, loss_axes):
+    """The hybrid_engine.AccumulatedLoss of a language-model loss, for the
+    gpt and llama builders: `loss_fn` is the builder's own (the pipeline
+    path, for an engine that does not accumulate);
+    `share(params, tokens, labels, denom)` is one microbatch's token
+    losses summed over `denom`, the local batch's valid labels
+    (_vocab_parallel_ce's ignore_index); the step reports the mean over
+    `loss_axes`, the data axes, as hybrid_loss_fn does."""
+    from .hybrid_engine import AccumulatedLoss
+
+    def reported(total):
+        with jax.named_scope(SCOPES.coll_dp):
+            return lax.pmean(total, loss_axes)
+    return AccumulatedLoss(
+        loss_fn, num_microbatches,
+        lambda labels: jnp.maximum(jnp.sum(labels != -100), 1),
+        share, reported)
+
+
 def hybrid_loss_fn(params, tokens, labels, cfg: GPTConfig,
                    num_microbatches: int, dp_axis="dp", pp_axis="pp",
                    mp_axis="mp", virtual_pp: int = 1,
@@ -1071,6 +1221,12 @@ def hybrid_loss_fn(params, tokens, labels, cfg: GPTConfig,
                    ep_axis="ep", moe=None, moe_ef=None, flash=None,
                    sep_axis="sep", z3=None, z3_ef=None, num=None):
     """Per-device loss of the full hybrid GPT (runs inside shard_map).
+
+    num_microbatches: the slices that fill spmd_pipeline's M + P - 1 ticks.
+    This function always builds the pipeline, at pp = 1 too (one stage,
+    M ticks); build_hybrid_train_step does not call it on a mesh with one
+    pipeline stage unless a side channel needs it, and differentiates
+    hybrid_microbatch_share one microbatch at a time instead.
 
     tokens/labels: this dp shard's batch [b_local, S]. virtual_pp > 1 runs
     the interleaved schedule (blocks must be stacked in
@@ -1158,7 +1314,6 @@ def hybrid_loss_fn(params, tokens, labels, cfg: GPTConfig,
                 "composed with mp sequence parallelism (which also "
                 "shards it) or the MoE batch layout",
                 op="gpt.hybrid_loss_fn")
-    from ..distributed.comm_overlap import collective_matmul as _cm
     if z3 is not None:
         from ..distributed.comm_overlap import zero3 as _z3g
         # analytic AG/RS wire deposit from the ORIGINAL (sharded) leaves
@@ -1172,34 +1327,7 @@ def hybrid_loss_fn(params, tokens, labels, cfg: GPTConfig,
             if zd_ >= 0:
                 params[name] = _z3g.all_gather_param(params[name], zd_,
                                                      z3["axis"])
-    with jax.named_scope(SCOPES.embed):
-        x = _vocab_parallel_embed(params["wte"], tokens, mp_axis)
-        if sep_on:
-            # tokens are this rank's sequence shard: position embedding reads
-            # the rank's GLOBAL slice (causal masking inside ring/Ulysses
-            # likewise uses global positions). The GLOBAL length must fit the
-            # table — dynamic_slice CLAMPS an out-of-range start, so an
-            # oversized sequence would silently hand later ranks the first
-            # ranks' position rows instead of erroring
-            n_sep = lax.axis_size(sep_axis)
-            enforce(S * n_sep <= cfg.max_seq_len,
-                    "sep context parallelism: the global sequence "
-                    "(per-rank S x sep degree) must fit max_seq_len — the "
-                    "position table is sliced per rank",
-                    op="gpt.hybrid_loss_fn", seq_local=S, sep=n_sep,
-                    max_seq_len=cfg.max_seq_len)
-            off = lax.axis_index(sep_axis) * S
-            x = x + lax.dynamic_slice_in_dim(params["wpe"], off, S,
-                                             axis=0)[None]
-        else:
-            x = x + params["wpe"][None, :S]
-        x = x.astype(cfg.dtype)
-    if sp is not None:
-        enforce(S % lax.axis_size(mp_axis) == 0,
-                "sequence parallelism needs S divisible by the mp degree",
-                op="gpt.hybrid_loss_fn", seq=S,
-                mp=lax.axis_size(mp_axis))
-        x = _cm.scatter_seq(x, mp_axis, dim=1)  # [b_local, S/mp, H]
+    x = _hybrid_embed(params, tokens, cfg, mp_axis, sp, sep_on, sep_axis)
     x_mb = x.reshape(M, b_local // M, x.shape[1], cfg.hidden_size)
 
     moe_stats = None
@@ -1301,25 +1429,7 @@ def hybrid_loss_fn(params, tokens, labels, cfg: GPTConfig,
             out = spmd_pipeline(stage_fn, stage_params, x_mb, axis=pp_axis)
     with jax.named_scope(SCOPES.head_loss):
         out = out.reshape(b_local, x.shape[1], cfg.hidden_size)
-        from ..distributed.fleet.layers.mpu import mp_ops
-        lnf_g, lnf_b = params["lnf_g"], params["lnf_b"]
-        if sp is not None:
-            # final LN runs on the seq shard — its param grads are partial
-            # (see the _block_fn sp note)
-            lnf_g = mp_ops.c_identity(lnf_g, mp_axis)
-            lnf_b = mp_ops.c_identity(lnf_b, mp_axis)
-        out = _ln(out, lnf_g, lnf_b)
-        if sp is None:
-            # column-parallel head: identity fwd / allreduce bwd on its input
-            out = mp_ops.c_identity(out, mp_axis)
-            logits_local = (out.astype(cfg.dtype)
-                            @ params["head_w"].astype(cfg.dtype))
-        else:
-            # seq-sharded final LN, then AG -> column GEMM (bwd RS) — same
-            # wire as the allreduce-mode head boundary
-            logits_local = mp_ops.ag_matmul(
-                out.astype(cfg.dtype), params["head_w"].astype(cfg.dtype),
-                mp_axis, ring=sp.ring)
+        logits_local = _head_logits(params, out, cfg, mp_axis, sp)
         if moe_on:
             _note_moe_wire(cfg, tokens, mp_axis, pp_axis, ep_axis, M,
                            jax.tree.leaves(params["blocks"]["dense"])[0]
@@ -1399,8 +1509,25 @@ def build_hybrid_train_step(cfg: GPTConfig, mesh: Mesh, optimizer,
                             flash_attention="auto", sep_axis="sep",
                             numerics="auto"):
     """Compile the full hybrid train step: one program containing embedding,
-    pipelined blocks, vocab-parallel loss, backward, dp grad sync and the
+    the blocks, vocab-parallel loss, backward, dp grad sync and the
     optimizer update. Returns (step_fn, shard_params_fn, init_state_fn).
+
+    num_microbatches: how many slices the per-dp-rank batch is cut into.
+    On a mesh with pp > 1 they fill the pipeline (spmd_pipeline: M + P - 1
+    ticks, every stage checkpointed and replayed in its backward). On a
+    mesh whose pp axis has ONE rank there is no pipeline to fill and they
+    are gradient accumulation: the step scans over the microbatches, each
+    iteration the forward AND the backward of one (embedding, the block
+    scan with no stage checkpoint, head, loss), adds the gradients on the
+    carry, and reduces over dp, clips and updates ONCE; the loss is still
+    sum(token losses) / valid labels of the whole batch. One microbatch's
+    residuals are alive at a time, as under the replay, and no pass runs
+    twice. The builder reads this off the mesh it is given
+    (mesh.shape[pp_axis] == 1); virtual_pp and schedule say nothing at
+    pp = 1. Builds whose loss rides the pipeline's side channels (fp8,
+    GPT-MoE, per-layer activation numerics, zero_stage 3) and an engine
+    with its own accumulation scan (comm_overlap) keep the pipeline path at
+    pp = 1 too.
 
     virtual_pp > 1 selects the interleaved schedule; shard_params then
     reorders the stacked blocks into the chunk-major layout (checkpoints
@@ -1726,6 +1853,20 @@ def build_hybrid_train_step(cfg: GPTConfig, mesh: Mesh, optimizer,
                                   sp=sp, ep_axis=ep_axis, moe=mcfg,
                                   flash=flash, sep_axis=sep_axis,
                                   z3=z3plan, num=ncfg)
+
+    # ONE pipeline stage is no pipeline: the engine runs the microbatches
+    # one after another, each with its own backward. Builds whose loss
+    # rides the pipeline's side channels (fp8 amax sums, the MoE aux loss
+    # and routing stats, per-layer activation stats, the ZeRO-3 gathers
+    # that count on the stage replay) keep the pipeline path they have.
+    if (int(mesh.shape[pp_axis]) == 1 and fp8_plan is None and not moe_on
+            and z3plan is None and not (ncfg is not None and ncfg.act)):
+        loss_fn = one_stage_loss(
+            loss_fn, num_microbatches,
+            lambda p, tokens, labels, denom: hybrid_microbatch_share(
+                p, tokens, labels, denom, cfg, pp_axis, mp_axis, sp=sp,
+                flash=flash, sep_axis=sep_axis),
+            (dp_axis, sep_axis) if sep_on else (dp_axis,))
 
     if moe_on:
         data_spec = P((dp_axis, ep_axis))

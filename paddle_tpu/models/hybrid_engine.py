@@ -11,7 +11,7 @@ collective over ICI.
 
 from __future__ import annotations
 
-from typing import Any, Callable, Dict
+from typing import Any, Callable, Dict, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -21,9 +21,28 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..observability.trace import SCOPES
 from ..utils import shard_map as _shard_map
 
-__all__ = ["build_train_step", "state_specs_for",
+__all__ = ["build_train_step", "AccumulatedLoss", "state_specs_for",
            "zero_dims", "zero_extend_spec", "zero_state_specs",
            "zero_param_specs", "zero1_state_specs"]
+
+
+class AccumulatedLoss(NamedTuple):
+    """The per-device loss of a mesh with ONE pipeline stage: callable like
+    any loss_fn (`whole`, the loss of the whole local batch), and also the
+    pieces build_train_step needs to run each microbatch's forward and
+    backward one after another (there is no pipeline to fill:
+    `microbatches` > 1 is gradient accumulation). The local loss is
+    sum(share over the microbatches) for ANY label mask, because every
+    share is divided by the whole local batch's `denom`, not by its own
+    count."""
+    whole: Callable     # loss_fn(params, tokens, labels, ...) of the batch
+    microbatches: int
+    denom: Callable     # labels [b_local, ...] -> scalar the shares divide by
+    share: Callable     # (params, tokens_mb, labels_mb, denom) -> local scalar
+    reported: Callable  # local loss -> the loss the step returns
+
+    def __call__(self, *args):
+        return self.whole(*args)
 
 
 def state_specs_for(optimizer, specs, example_params=None):
@@ -220,6 +239,17 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
                      donate: bool = False):
     """loss_fn(params, tokens, labels) -> scalar, running per-device inside
     shard_map. Returns (jitted_step, shard_params, init_state).
+
+    loss_fn may be an AccumulatedLoss: what a model builder hands over
+    when its mesh has ONE pipeline stage and nothing rides its pipeline's
+    side channels. The step then scans over the microbatches, each
+    iteration the forward AND the backward of one share (so one
+    microbatch's residuals are alive at a time and nothing is replayed),
+    adding the gradients in their own dtype on the carry; the dp
+    reduction, the clip and the optimizer follow ONCE, exactly as after a
+    plain loss_fn. Builds whose gradient path is not the plain one
+    (comm_overlap's own scan, fp8, the error-feedback carries) call it
+    whole, as any loss_fn.
 
     grad_reduce_dtype: cast gradients to this dtype for the dp reduction
     and back (the reference's fp16_allreduce meta-optimizer,
@@ -514,6 +544,10 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
                     "moe_quantize_a2a and zero3_quantize_ag both thread "
                     "their residuals as the loss's 4th argument — "
                     "disable one of the two", op="build_train_step")
+    accumulate = loss_fn if isinstance(loss_fn, AccumulatedLoss) else None
+    if (ocfg is not None or fp8_plan is not None or z3_ef is not None
+            or (moe_plan is not None and moe_plan.get("ef") is not None)):
+        accumulate = None   # these gradient paths call it whole (docstring)
     # -- in-program telemetry (observability) --------------------------------
     from .. import observability as _obs
     tcfg = _obs.telemetry_from_flags() if telemetry == "auto" else telemetry
@@ -964,6 +998,43 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
                 if ep_axis not in _spec_axes(sp))
         return jax.tree.map(one, grads, specs)
 
+    def _accumulated_grads(params, tokens, labels):
+        """(loss, grads, obs) of the AccumulatedLoss: value-and-grad one
+        microbatch inside lax.scan, sums on the carry (observe() series
+        are averaged over the microbatches, as the overlap scan does)."""
+        from ..enforce import enforce
+        M = int(accumulate.microbatches)
+        b = tokens.shape[0]
+        enforce(M >= 1 and b % M == 0,
+                "per-dp-rank batch must be divisible by num_microbatches",
+                op="build_train_step", batch_local=b, microbatches=M)
+        denom = accumulate.denom(labels)
+
+        def share(p, t, l):
+            if tcfg is None:
+                return accumulate.share(p, t, l, denom), {}
+            with _obs.collecting() as sink:
+                s = accumulate.share(p, t, l, denom)
+            return s, _obs.metrics.obs_dict(sink)
+        vg = jax.value_and_grad(share, has_aux=True)
+        if M == 1:
+            (loss, obs), grads = vg(params, tokens, labels)
+            return accumulate.reported(loss), grads, obs
+        mbs = tuple(a.reshape((M, b // M) + a.shape[1:])
+                    for a in (tokens, labels))
+        # the carry: the share's (loss, obs) from an ABSTRACT forward (no
+        # first microbatch is peeled: the fwd/bwd body compiles once), the
+        # gradients in the parameters' own structure and dtype
+        zeros = jax.tree.map(
+            lambda sd: jnp.zeros(sd.shape, sd.dtype),
+            (jax.eval_shape(share, params, *(a[0] for a in mbs)), params))
+
+        def body(carry, mb):
+            return jax.tree.map(jnp.add, carry, vg(params, *mb)), None
+        ((loss, obs), grads), _ = lax.scan(body, zeros, mbs)
+        return (accumulate.reported(loss), grads,
+                jax.tree.map(lambda o: o / M, obs))
+
     def _overlap_bytes(g_leaves, z_leaves, wire_dtype):
         """Trace-time dp wire bytes of ONE microbatch's overlap reduction
         (ring accounting, same tables as fleet.collective_perf)."""
@@ -1110,7 +1181,9 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
                 # mp/ep a2a bytes are per loss CALL — the overlap scan
                 # calls the loss once per comm microbatch on the split
                 # batch
-                mp_calls = ocfg.microbatches if ocfg is not None else 1
+                mp_calls = (ocfg.microbatches if ocfg is not None
+                            else accumulate.microbatches
+                            if accumulate is not None else 1)
                 vals["comms_bytes"] = ((tele_comms["reduce"] or 0.0)
                                        + (tele_comms["zero1"] or 0.0)
                                        + (tele_comms["ep"] or 0.0)
@@ -1239,7 +1312,10 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
                           tele=z1t, obs=obs)
         else:
             plain_loss = lambda p: loss_fn(p, tokens, labels)
-            if tcfg is not None:
+            if accumulate is not None:   # one pipeline stage: no loss_fn
+                loss, grads, obs = _accumulated_grads(params, tokens,
+                                                      labels)
+            elif tcfg is not None:
                 def plain_loss_obs(p):
                     with _obs.collecting() as sink:
                         l = plain_loss(p)

@@ -514,11 +514,88 @@ def dense_loss(params, tokens, labels, cfg: LlamaConfig, remat: bool = True,
     return lm_logsumexp_ce(logits, labels)
 
 
+def _hybrid_rope(cfg: LlamaConfig, S: int, sep_on: bool, sep_axis):
+    """(cos, sin) for this rank's S positions of the hybrid loss."""
+    if sep_on:
+        # this rank's slice of the GLOBAL rotation tables — K blocks
+        # carry their rotated values around the ring
+        n_sep = lax.axis_size(sep_axis)
+        cos_g, sin_g = rope_tables(cfg, S * n_sep)
+        off = lax.axis_index(sep_axis) * S
+        cos = lax.dynamic_slice_in_dim(cos_g, off, S, axis=0)
+        sin = lax.dynamic_slice_in_dim(sin_g, off, S, axis=0)
+    else:
+        cos, sin = rope_tables(cfg, S)
+    return cos, sin
+
+
+def _hybrid_embed(params, tokens, cfg: LlamaConfig, mp_axis, sp):
+    """Vocabulary-parallel token embedding of the hybrid loss: [b, S, H],
+    or this rank's [b, S/mp, H] sequence shard under sp."""
+    from ..distributed.comm_overlap import collective_matmul as _cm
+    S = tokens.shape[1]
+    x = _vocab_parallel_embed(params["wte"], tokens, mp_axis)
+    x = x.astype(cfg.dtype)
+    if sp is not None:
+        enforce(S % lax.axis_size(mp_axis) == 0,
+                "sequence parallelism needs S divisible by the mp degree",
+                op="llama.hybrid_loss_fn", seq=S,
+                mp=lax.axis_size(mp_axis))
+        x = _cm.scatter_seq(x, mp_axis, dim=1)  # [b_local, S/mp, H]
+    return x
+
+
+def _head_logits(params, out, cfg: LlamaConfig, mp_axis, sp):
+    """Final RMSNorm and the column-parallel head of the hybrid loss: this
+    mp rank's [b, S, V/mp] logits."""
+    from ..distributed.fleet.layers.mpu import mp_ops
+    lnf_g = params["lnf_g"]
+    if sp is not None:
+        # final RMSNorm runs on the seq shard — its gain grad is partial
+        # over mp (see gpt.hybrid_loss_fn)
+        lnf_g = mp_ops.c_identity(lnf_g, mp_axis)
+    out = _rms(out, lnf_g, cfg.rms_eps)
+    if sp is None:
+        out = mp_ops.c_identity(out, mp_axis)  # column-parallel head
+        logits_local = (out.astype(cfg.dtype)
+                        @ params["head_w"].astype(cfg.dtype))
+    else:
+        logits_local = mp_ops.ag_matmul(
+            out.astype(cfg.dtype), params["head_w"].astype(cfg.dtype),
+            mp_axis, ring=sp.ring)
+    return logits_local
+
+
+def hybrid_microbatch_share(params, tokens, labels, denom,
+                            cfg: LlamaConfig, pp_axis="pp", mp_axis="mp",
+                            sp=None, flash=None, sep_axis="sep"):
+    """One microbatch's share of the per-device loss on a mesh with ONE
+    pipeline stage: these rows' token losses summed and divided by
+    `denom`, the valid labels of the whole local batch; no pipeline, no
+    stage checkpoint (see gpt.hybrid_microbatch_share)."""
+    from .gpt import _note_mp_wire, share_of_loss
+    sep_on = flash is not None and flash.sep is not None
+    cos, sin = _hybrid_rope(cfg, tokens.shape[1], sep_on, sep_axis)
+    x = _hybrid_embed(params, tokens, cfg, mp_axis, sp)
+
+    def body(carry, p):
+        return _block_fn(p, carry, cos, sin, cfg, mp_axis, sp=sp,
+                         flash=flash, sep_axis=sep_axis), None
+    out, _ = lax.scan(body, x, params["blocks"])
+    logits_local = _head_logits(params, out, cfg, mp_axis, sp)
+    _note_mp_wire(cfg, tokens, sp, mp_axis, pp_axis, 1,
+                  jax.tree.leaves(params["blocks"])[0].shape[0])
+    return share_of_loss(logits_local, labels, denom, mp_axis)
+
+
 def hybrid_loss_fn(params, tokens, labels, cfg: LlamaConfig,
                    num_microbatches: int, dp_axis="dp", pp_axis="pp",
                    mp_axis="mp", virtual_pp: int = 1, fp8=None, sp=None,
                    flash=None, sep_axis="sep", z3=None, num=None):
-    """Per-device loss of the full hybrid Llama (inside shard_map). fp8:
+    """Per-device loss of the full hybrid Llama (inside shard_map).
+    num_microbatches fill spmd_pipeline's ticks, at pp = 1 too: the builder
+    does not call this on a one-stage mesh unless a side channel needs it
+    (see gpt.hybrid_loss_fn, hybrid_microbatch_share). fp8:
     this pp rank's stacked [L/pp] delayed scales (1F1B only — see
     gpt.hybrid_loss_fn). sp: None or comm_overlap.MpOverlapConfig —
     sequence-parallel TP over mp (see gpt.hybrid_loss_fn); RoPE tables
@@ -546,18 +623,7 @@ def hybrid_loss_fn(params, tokens, labels, cfg: LlamaConfig,
         enforce(sp is None,
                 "sep context parallelism and mp sequence parallelism "
                 "both shard the sequence dim", op="llama.hybrid_loss_fn")
-    from ..distributed.comm_overlap import collective_matmul as _cm
-    from ..distributed.fleet.layers.mpu import mp_ops
-    if sep_on:
-        # this rank's slice of the GLOBAL rotation tables — K blocks
-        # carry their rotated values around the ring
-        n_sep = lax.axis_size(sep_axis)
-        cos_g, sin_g = rope_tables(cfg, S * n_sep)
-        off = lax.axis_index(sep_axis) * S
-        cos = lax.dynamic_slice_in_dim(cos_g, off, S, axis=0)
-        sin = lax.dynamic_slice_in_dim(sin_g, off, S, axis=0)
-    else:
-        cos, sin = rope_tables(cfg, S)
+    cos, sin = _hybrid_rope(cfg, S, sep_on, sep_axis)
     if z3 is not None:
         from ..distributed.comm_overlap import zero3 as _z3g
         from .gpt import _note_zero3_wire
@@ -568,14 +634,7 @@ def hybrid_loss_fn(params, tokens, labels, cfg: LlamaConfig,
             if zd_ >= 0:
                 params[name] = _z3g.all_gather_param(params[name], zd_,
                                                      z3["axis"])
-    x = _vocab_parallel_embed(params["wte"], tokens, mp_axis)
-    x = x.astype(cfg.dtype)
-    if sp is not None:
-        enforce(S % lax.axis_size(mp_axis) == 0,
-                "sequence parallelism needs S divisible by the mp degree",
-                op="llama.hybrid_loss_fn", seq=S,
-                mp=lax.axis_size(mp_axis))
-        x = _cm.scatter_seq(x, mp_axis, dim=1)  # [b_local, S/mp, H]
+    x = _hybrid_embed(params, tokens, cfg, mp_axis, sp)
     x_mb = x.reshape(M, b_local // M, x.shape[1], cfg.hidden_size)
 
     num_act = num is not None and num.act
@@ -643,20 +702,7 @@ def hybrid_loss_fn(params, tokens, labels, cfg: LlamaConfig,
     else:
         out = spmd_pipeline(stage_fn, stage_params, x_mb, axis=pp_axis)
     out = out.reshape(b_local, x.shape[1], cfg.hidden_size)
-    lnf_g = params["lnf_g"]
-    if sp is not None:
-        # final RMSNorm runs on the seq shard — its gain grad is partial
-        # over mp (see gpt.hybrid_loss_fn)
-        lnf_g = mp_ops.c_identity(lnf_g, mp_axis)
-    out = _rms(out, lnf_g, cfg.rms_eps)
-    if sp is None:
-        out = mp_ops.c_identity(out, mp_axis)  # column-parallel head
-        logits_local = (out.astype(cfg.dtype)
-                        @ params["head_w"].astype(cfg.dtype))
-    else:
-        logits_local = mp_ops.ag_matmul(
-            out.astype(cfg.dtype), params["head_w"].astype(cfg.dtype),
-            mp_axis, ring=sp.ring)
+    logits_local = _head_logits(params, out, cfg, mp_axis, sp)
     from .gpt import _note_mp_wire
     _note_mp_wire(cfg, tokens, sp, mp_axis, pp_axis, M,
                   jax.tree.leaves(params["blocks"])[0].shape[0],
@@ -684,7 +730,12 @@ def build_hybrid_train_step(cfg: LlamaConfig, mesh: Mesh, optimizer,
                             telemetry="auto", mp_overlap="auto",
                             flash_attention="auto", sep_axis="sep",
                             numerics="auto"):
-    """mp_overlap: "auto" (FLAGS_mp_seq_parallel / FLAGS_mp_collective_
+    """num_microbatches: at pp > 1 the slices that fill the pipeline; on
+    a mesh whose pp axis has ONE rank, gradient accumulation (each
+    microbatch's forward and backward one after another, one dp
+    reduction, clip and update); see gpt.build_hybrid_train_step.
+
+    mp_overlap: "auto" (FLAGS_mp_seq_parallel / FLAGS_mp_collective_
     matmul) / None / mode string / MpOverlapConfig — sequence-parallel TP
     with optional ring collective matmul; see gpt.build_hybrid_train_step
     (off: the allreduce path is bitwise unchanged; collective_matmul
@@ -793,6 +844,17 @@ def build_hybrid_train_step(cfg: LlamaConfig, mesh: Mesh, optimizer,
                                   virtual_pp=virtual_pp, sp=sp,
                                   flash=flash, sep_axis=sep_axis,
                                   z3=z3plan, num=ncfg)
+
+    # ONE pipeline stage is no pipeline (see gpt.build_hybrid_train_step)
+    if (int(mesh.shape[pp_axis]) == 1 and fp8_plan is None
+            and z3plan is None and not (ncfg is not None and ncfg.act)):
+        from .gpt import one_stage_loss
+        loss_fn = one_stage_loss(
+            loss_fn, num_microbatches,
+            lambda p, tokens, labels, denom: hybrid_microbatch_share(
+                p, tokens, labels, denom, cfg, pp_axis, mp_axis, sp=sp,
+                flash=flash, sep_axis=sep_axis),
+            (dp_axis, sep_axis) if sep_on else (dp_axis,))
 
     step, shard_params, init_state = build_train_step(
         loss_fn, specs, mesh, optimizer, dp_axis=dp_axis,

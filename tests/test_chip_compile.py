@@ -505,3 +505,76 @@ def test_qwen3_next_step_keeps_state_pool_and_experts_in_place(
     donated = (2 * math.prod(pool_shape) * 2 + math.prod(state_shape) * 4
                + math.prod(tail_shape) * 2)
     assert mem.alias_size_in_bytes >= donated
+
+
+# ---------------------------------------------------------------------------
+# the hybrid training cell's step (GPT-3 6.7B widths, six layers, dp 2 x
+# pp 1 x mp 2, two microbatches): ONE pipeline stage is no pipeline
+# ---------------------------------------------------------------------------
+def _gemm_fusions(text):
+    """How many fusions of a compiled TPU program hold a GEMM (a loop's
+    body is in the text once, however often it runs)."""
+    bodies, name = set(), None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = line.split()[0].lstrip("%")
+        elif " convolution(" in line:
+            bodies.add(name)
+    return sum(c in bodies
+               for c in re.findall(r" fusion\(.*calls=%?([\w.\-]+)", text))
+
+
+def test_hybrid_cell_step_runs_no_pass_twice(topo, compiled_kernels,
+                                             monkeypatch):
+    """The compiled step of `train-6p7b-dp2mp2` runs no GEMM, kernel or
+    collective twice: fifteen GEMMs in its text (qkv, proj, fc1, fc2 and
+    the head, each once forward and twice backward; the pipeline form had
+    nineteen with its stage replay, and the compiler added the head's
+    three more times when the loss sat in a loop's body), the flash
+    forward kernel once, no `collective-permute`, and one dp all-reduce of
+    the stacked gradients after the microbatch scan, not one a microbatch;
+    and it needs less memory than the pipeline form did (8.34 GiB of temp
+    by the same analysis, PERF.md PR 37)."""
+    import paddle_tpu as paddle
+    import paddle_tpu.distributed as dist
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from paddle_tpu.models import gpt as G
+    from paddle_tpu.ops import registry
+    monkeypatch.setattr(registry, "_on_tpu", lambda: True)
+    cfg = G.GPTConfig(vocab_size=50304, hidden_size=4096, num_layers=6,
+                      num_heads=32, ffn_hidden=16384, max_seq_len=2048,
+                      dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    mesh = dist.build_mesh({"dp": 2, "pp": 1, "mp": 2},
+                           devices=list(topo.devices))
+    opt = paddle.optimizer.AdamW(1e-4, moment_dtype=jnp.bfloat16)
+    step, _, init = G.build_hybrid_train_step(cfg, mesh, opt,
+                                              num_microbatches=2)
+
+    def sharded(tree, specs):
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(
+                a.shape, a.dtype, sharding=NamedSharding(mesh, s)),
+            tree, specs)
+    ex = jax.eval_shape(
+        lambda: G.init_hybrid_params(cfg, jax.random.PRNGKey(0)))
+    tok = jax.ShapeDtypeStruct((4, 2048), jnp.int32,
+                               sharding=NamedSharding(mesh, P("dp")))
+    lr = jax.ShapeDtypeStruct((), jnp.float32,
+                              sharding=NamedSharding(mesh, P()))
+    compiled = step.lower(sharded(ex, init.param_specs),
+                          sharded(init.abstract(ex), init.state_specs),
+                          tok, tok, lr).compile()
+    text = compiled.as_text()
+    assert _gemm_fusions(text) == 3 * 5
+    assert len(re.findall(r"%flash_fwd[.\d]* = ", text)) == 1
+    assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+    assert "collective-permute" not in text
+    # four mp all-reduces of [1, 2048, 4096] a block pass (two forward,
+    # two backward), two around the embedding and the head
+    assert len(re.findall(r"= bf16\[1,2048,4096\]\S* all-reduce", text)) == 6
+    # the stacked fc1 gradient, [6, 4096, 8192] a chip, is reduced over dp
+    # once a step (1.64 GB of gradients on the wire, as the parent's)
+    stacked = [l for l in text.splitlines()
+               if re.search(r"= bf16\[6,4096,8192\]\S* all-reduce", l)]
+    assert len(stacked) == 1, stacked
+    assert compiled.memory_analysis().temp_size_in_bytes < 5.5 * 2 ** 30
