@@ -73,7 +73,20 @@ step scans periods (`ragged_step.ragged_pass`). A model with routed
 experts (`routed`) hands back what its router chose with each step's
 tokens, in the same fetch: a request that asks (`keep_routing`) keeps the
 picks of its positions, and the step's counts ride the `serving_fetch`
-span (`observability.trace.MOE_FETCH_ATTRS`).
+span that landed it (`observability.trace.MOE_FETCH_ATTRS`).
+
+A REQUEST'S LIFE is one record (ISSUE 38): `Request` keeps a mark where
+the engine passes each point on the way to the first token (submitted,
+admitted, first prompt tokens granted, last chunk dispatched, token handed
+over), so its TTFT splits into queue, wait, prefill and land
+(`Request.ttft_parts`). At the hand-over, inside `serving_walk`, the
+record reaches both trace paths under the request's `rid`: four back-dated
+collector events (`REQUEST_PHASES`) and one instant span
+`serving_first_token` with the durations as attributes; a request's end is
+the instant span `serving_request_end`. The scheduler's choices ride the
+step's own spans: `serving_admission` closes with what it admitted and why
+the head still waits, `serving_unified_dispatch` says how many resident
+prefilling rows the token budget starved.
 
 Resilience layer (ISSUE 13) — all host-side scheduler state, no compiled
 program changes (flags-off the step behavior is byte-identical and the
@@ -121,9 +134,12 @@ import numpy as np
 from jax import lax
 
 from ..models import gpt as G
-from ..observability.trace import (MOE_FETCH_ATTRS, SCOPES, SERVING_SPANS,
-                                   SSM_DISPATCH_ATTRS)
-from ..profiler.utils import RecordEvent
+from ..observability.trace import (ADMISSION_ATTRS, ADMIT_BLOCKED,
+                                   DISPATCH_ATTRS, FIRST_TOKEN_ATTRS,
+                                   MOE_FETCH_ATTRS, REQUEST_END_ATTRS,
+                                   REQUEST_PHASES, REQUEST_SPANS, SCOPES,
+                                   SERVING_SPANS, SSM_DISPATCH_ATTRS)
+from ..profiler.utils import RecordEvent, record_interval
 
 __all__ = ["Request", "ServingEngine", "RunResult", "NonFiniteSampleError",
            "generate_static_batch"]
@@ -184,9 +200,27 @@ class Request:
     preemptions: int = 0
     folded: int = 0                     # output tokens already folded
     #                                     into prompt by past preemptions
-    # telemetry (observability): submit wall clock + time-to-first-token
+    # telemetry (observability): the request's way to its first token, ONE
+    # record written where each phase ends. All time.perf_counter(), each
+    # set once (a preemption keeps them; `preemptions` counts the repeats):
+    # submitted; `_admit` gave it a slot and its pages; the first pack that
+    # granted it prompt tokens; the dispatch of the step that carries its
+    # last prompt chunk (and that engine step's number); the first token's
+    # hand-over in `_emit`. `ttft_s` is the last less the first, and
+    # `ttft_parts()` the four phases between them.
     submit_time: float = 0.0
+    admit_time: Optional[float] = None
+    first_grant_time: Optional[float] = None
+    prefill_end_time: Optional[float] = None
+    prefill_end_step: int = 0
+    first_token_time: Optional[float] = None
     ttft_s: Optional[float] = None
+    # engine steps, over the request's whole life (a re-prefill after a
+    # preemption counts again): granted prompt tokens; resident and
+    # prefilling with a zero grant; ridden as a decode row
+    prefill_steps: int = 0
+    starved_steps: int = 0
+    decode_steps: int = 0
     # a model with routed experts: the experts its router picked at every
     # position the engine ran, [positions, layers, k] int16 by ABSOLUTE
     # position (-1 where no pass has run yet; the token sampled last is
@@ -194,12 +228,28 @@ class Request:
     keep_routing: bool = False
     routing: Optional[np.ndarray] = None
 
+    def marks(self):
+        """The five marks on the way to the first token, in order."""
+        return (self.submit_time, self.admit_time, self.first_grant_time,
+                self.prefill_end_time, self.first_token_time)
+
+    def ttft_parts(self):
+        """(queue, wait, prefill, land) in seconds — submitted -> admitted
+        -> first grant -> dispatch of the last prompt chunk -> first token
+        handed over — or None before the first token. Each is >= 0 and
+        they sum to `ttft_s`."""
+        marks = self.marks()
+        if None in marks:
+            return None
+        return tuple(b - a for a, b in zip(marks, marks[1:]))
+
 
 @dataclasses.dataclass
 class _PackedStep:
     """What `_pack_ragged` hands the rest of one ragged step."""
     dec: list            # decode rows (Requests), packed first
     pre: list            # prefilling rows
+    ending: list         # ... of them, those whose last prompt chunk rides
     grants: dict         # slot -> prefill tokens granted this step
     props_by_slot: dict  # slot -> draft tokens riding this step
     use_spec: bool
@@ -540,16 +590,12 @@ class ServingEngine:
         self._ssm_state = self._conv_tail = None
         self.ssm_resets = self._ssm_resets_reported = 0
         # -- a model with routed experts: what the router chose comes back
-        # with each step's tokens. Totals since construction, the fetch
-        # span's attributes (MOE_FETCH_ATTRS: of the step landed last),
-        # and per layer the sum over passes of largest / mean assignments
-        # a held expert (`moe_passes` of them with any assignment)
+        # with each step's tokens. Totals since construction, and per
+        # layer the sum over passes of largest / mean assignments a held
+        # expert (`moe_passes` of them with any assignment)
         self.moe_experts_touched = self.moe_assignments = 0
         self.moe_passes = 0
-        self._moe_attrs = {}
-        self._moe_reported = (0, 0)
         if self.model.routed:
-            self._moe_attrs = dict.fromkeys(MOE_FETCH_ATTRS, 0)
             lo, hi = cfg.experts_held
             self._moe_held = hi - lo
             self._moe_load = np.zeros((cfg.num_layers,), np.float64)
@@ -613,6 +659,9 @@ class ServingEngine:
         self.preempt_wait_steps = max(int(preempt_wait_steps), 1)
         self._hol_wait_steps = 0   # consecutive steps the queue head was
         #                            pool-blocked (preemption trigger)
+        self._blocked = ADMIT_BLOCKED.none  # why the head waited at the
+        #                            last `_admit` (the admission span's)
+        self.preempted = 0         # decode victims evicted and requeued
         self.draining = False
         self._health = "loading"
         # terminal transitions that happen OUTSIDE a step (shed at submit)
@@ -629,7 +678,6 @@ class ServingEngine:
         # engine step; the benchmark reports dispatches/step)
         self.dispatches = 0
         self.engine_steps = 0
-        self._dispatches_reported = 0
         self._jit_programs: List = []
         self.decode_microsteps = 0  # device decode steps issued (telemetry)
         self._pending_tok = np.zeros((max_batch,), np.int32)
@@ -952,7 +1000,7 @@ class ServingEngine:
         self.queue.append(r)
         self._prom.gauge_set("queue_depth", len(self.queue),
                              help="requests waiting for a slot")
-        self._emit_event("serving_admit", rid=rid,
+        self._emit_event("serving_submit", rid=rid,
                          prompt_len=len(r.prompt),
                          max_new_tokens=r.max_new_tokens,
                          deadline_s=deadline_s,
@@ -1291,6 +1339,11 @@ class ServingEngine:
         requests keep FIFO order among themselves)."""
         fresh: List[int] = []
         usable = self._num_blocks - 1  # block 0 is reserved scratch
+        # why the head still waits when this returns (ADMIT_BLOCKED); the
+        # loop below ends on a `break` that says so, or on an empty queue
+        self._blocked = (ADMIT_BLOCKED.draining
+                         if self.draining and self.queue
+                         else ADMIT_BLOCKED.none)
         if self.draining or not self.queue:
             return fresh
         if any(r.deadline is not None for r in self.queue):
@@ -1301,7 +1354,8 @@ class ServingEngine:
             try:
                 i = self._slots.index(None)
             except ValueError:
-                break  # no free slot
+                self._blocked = ADMIT_BLOCKED.slot
+                break
             r = self.queue[0]
             need = self._blocks_needed(r)
             if need > self.tables.shape[1] or need > usable:
@@ -1335,6 +1389,7 @@ class ServingEngine:
                     # this sibling would recompute the pages it is about
                     # to be able to share; wait (entry clears when the
                     # owner's prefill completes or its slot releases)
+                    self._blocked = ADMIT_BLOCKED.prefix
                     break
                 for h in chain:
                     b = self._prefix_cache.get(h)
@@ -1368,9 +1423,16 @@ class ServingEngine:
                 self._hol_wait_steps += 1
                 if self._try_preempt(r, need_new):
                     continue  # retry the head against the freed pages
+                self._blocked = ADMIT_BLOCKED.pages
                 break  # head-of-line waits for finishes (no starvation)
             self.queue.pop(0)
             self._hol_wait_steps = 0
+            now = time.perf_counter()
+            if r.admit_time is None:
+                r.admit_time = now
+            self._emit_event("serving_admit", rid=r.rid, slot=i,
+                             queue_s=now - r.submit_time,
+                             preemptions=r.preemptions)
             blocks = self._alloc_blocks(need_new)
             pages = list(shared)
             if cow:
@@ -1470,6 +1532,7 @@ class ServingEngine:
         r.folded = len(r.output)
         r.prefill_done = 0
         r.preemptions += 1
+        self.preempted += 1
         self.queue.append(r)
         self._prom.counter_inc("requests_preempted_total",
                                help="decode victims evicted-and-requeued "
@@ -1627,14 +1690,7 @@ class ServingEngine:
         r.output.append(tok)
         self._tokens_total += 1
         if len(r.output) == 1:
-            r.ttft_s = time.perf_counter() - r.submit_time
-            self._prom.summary_observe(
-                "ttft_seconds", r.ttft_s,
-                help="submit-to-first-token latency",
-                window=self._ttft_window)
-            self._prom.histogram_observe(
-                "ttft_seconds_hist", r.ttft_s,
-                help="submit-to-first-token latency distribution")
+            self._note_first_token(r)
         if r.on_token is not None:
             try:
                 r.on_token(r.rid, tok)
@@ -1650,6 +1706,33 @@ class ServingEngine:
                 return True  # finish (and free) the poisoned request
         return (len(r.output) >= r.max_new_tokens
                 or (r.eos_id is not None and tok == r.eos_id))
+
+    def _note_first_token(self, r: Request):
+        """The first token's hand-over closes the request's record: TTFT
+        to prom, and (inside `serving_walk`) the four phases to the
+        collector, each with its own start and end, and the instant span
+        whose attributes carry them into a profiler session. The
+        microseconds are differences of the marks' rounded offsets from
+        submission, so they sum to the TTFT's own."""
+        r.first_token_time = now = time.perf_counter()
+        r.ttft_s = now - r.submit_time
+        self._prom.summary_observe(
+            "ttft_seconds", r.ttft_s,
+            help="submit-to-first-token latency",
+            window=self._ttft_window)
+        self._prom.histogram_observe(
+            "ttft_seconds_hist", r.ttft_s,
+            help="submit-to-first-token latency distribution")
+        marks = r.marks()
+        for name, a, b in zip(REQUEST_PHASES, marks, marks[1:]):
+            record_interval(name, a, b, rid=r.rid)
+        at = [round((t - r.submit_time) * 1e6) for t in marks]
+        with RecordEvent(REQUEST_SPANS.first_token, **dict(zip(
+                FIRST_TOKEN_ATTRS,
+                (r.rid, len(r.prompt), *(b - a for a, b in zip(at, at[1:])),
+                 r.prefill_steps, r.starved_steps,
+                 self.engine_steps - r.prefill_end_step, r.preemptions)))):
+            pass
 
     def step(self) -> List[Request]:
         """One engine iteration, with ONE STEP IN FLIGHT: admit, pack,
@@ -1783,9 +1866,13 @@ class ServingEngine:
         tokens_before = self._tokens_total
         if self.spec_k > 0:
             self.settle("spec")     # the proposer reads Request.output
-        with RecordEvent(SERVING_SPANS.admission):
+        with RecordEvent(SERVING_SPANS.admission) as admission:
+            preempted = self.preempted
             fresh_slots = self._admit()
             self._note_pool_peak()
+            admission.set(**dict(zip(ADMISSION_ATTRS, (
+                len(fresh_slots), len(self.queue), self._blocked,
+                self.preempted - preempted))))
         b = self._pack_ragged(fresh_slots)
         if b is None:
             self.settle("tail")     # a no-op with nothing in flight
@@ -1797,11 +1884,16 @@ class ServingEngine:
         prev = self._flight
         if prev is not None:
             self._prom.counter_inc("steps_overlapped_total")
-        with RecordEvent(SERVING_SPANS.dispatch, step=self.engine_steps,
-                         k=b.K, n_dec=len(b.dec), n_pre=len(b.pre),
-                         q_tokens=b.q_tokens, kv_tokens=b.kv_tokens,
-                         attn_pages=b.attn_pages,
-                         in_flight=int(prev is not None), **b.ssm_attrs):
+        now = time.perf_counter()
+        for r in b.ending:      # the prompt's last chunk goes out
+            if r.prefill_end_time is None:
+                r.prefill_end_time, r.prefill_end_step = (now,
+                                                          self.engine_steps)
+        with RecordEvent(SERVING_SPANS.dispatch, **dict(zip(DISPATCH_ATTRS, (
+                self.engine_steps, b.K, len(b.dec), len(b.pre), b.q_tokens,
+                b.kv_tokens, b.attn_pages, int(prev is not None),
+                len(b.pre) - len(b.grants), sum(b.grants.values()),
+                self.token_budget))), **b.ssm_attrs):
             _faults().maybe_fail("serving/dispatch")
             out = self._unified(b.K, spec=b.use_spec)(*args)
         route = ()
@@ -1828,18 +1920,18 @@ class ServingEngine:
     def _land(self, f) -> List[Request]:
         """Fetch a dispatched step's tokens and walk them; returns the
         requests that finished in it."""
-        with RecordEvent(SERVING_SPANS.fetch, **self._moe_attrs):
+        with RecordEvent(SERVING_SPANS.fetch) as fetch:
             # ONE host fetch: the copies start together, then the host
             # waits (a fetch of its own for `lens` cost 0.4 ms a step)
             toks, greedy_all, lens, *route = jax.device_get(f.out)
-        if route:
-            self._note_routing(route[2])
+            if route:   # the span closes with the counts of the step it
+                fetch.set(**self._note_routing(route[2]))   # landed
         return self._walk_ragged(f, toks, greedy_all, lens, *route[:2])
 
     def _note_routing(self, stats):
         """A landed step's router counts, stats [K, L, 3] (touched,
-        assignments, largest) of its K passes and L layers: the totals,
-        and the attributes the NEXT fetch span opens with."""
+        assignments, largest) of its K passes and L layers: added to the
+        totals, and returned as the fetch span's MOE_FETCH_ATTRS."""
         touched, assigned = int(stats[..., 0].sum()), int(stats[..., 1].sum())
         self.moe_experts_touched += touched
         self.moe_assignments += assigned
@@ -1848,7 +1940,7 @@ class ServingEngine:
         self._moe_load += np.where(
             ran, stats[..., 2] * self._moe_held
             / np.maximum(stats[..., 1], 1), 0.0).sum(axis=0)
-        self._moe_attrs = dict(zip(MOE_FETCH_ATTRS, (
+        return dict(zip(MOE_FETCH_ATTRS, (
             touched, assigned, int(stats[..., 2].max()))))
 
     @staticmethod
@@ -1962,6 +2054,8 @@ class ServingEngine:
                 props_by_slot[i] = props
         use_spec = bool(props_by_slot)
         grants: Dict[int, int] = {}
+        ending: List[Request] = []
+        now = time.perf_counter()
         for r in pre:  # prefill chunks share the leftover budget
             i = r.slot
             lo = done_pre[i]
@@ -1969,10 +2063,16 @@ class ServingEngine:
             todo = len(r.prompt) - lo
             grant = min(self.chunk, todo, T - cursor)
             if grant <= 0:
+                r.starved_steps += 1
                 continue
+            r.prefill_steps += 1
+            if r.first_grant_time is None:
+                r.first_grant_time = now
             grants[i] = grant
             q_lens[i] = grant
             completing = lo + grant >= len(r.prompt)
+            if completing:
+                ending.append(r)
             sample0[i] = completing
             # remaining-to-EMIT: a preempted-and-requeued request's
             # emitted prefix lives in both prompt and output
@@ -2026,7 +2126,8 @@ class ServingEngine:
             ssm_attrs = dict(zip(SSM_DISPATCH_ATTRS, (
                 int(ran.sum()), burst_rows, cursor + burst_rows)))
         return _PackedStep(
-            dec=dec, pre=pre, grants=grants, props_by_slot=props_by_slot,
+            dec=dec, pre=pre, ending=ending, grants=grants,
+            props_by_slot=props_by_slot,
             use_spec=use_spec, K=K, q_tokens=cursor, kv_tokens=kv_tokens,
             attn_pages=attn_pages,
             starts=starts, pos0=pos0, q_lens=q_lens, emit=emit,
@@ -2087,6 +2188,8 @@ class ServingEngine:
             self._release_slot(r)
         if wasted:
             self._prom.counter_inc("overlap_wasted_rows_total", len(wasted))
+        for r in dec:
+            r.decode_steps += 1
         for r in pre:
             r.prefill_done += b.grants.get(r.slot, 0)
             self._register_pages(r)
@@ -2166,27 +2269,36 @@ class ServingEngine:
                 help="high-water allocated fraction of the KV pool")
 
     def _note_completed(self, finished):
-        """Completion counters and events of the requests a walk
-        finished (inside the walk's span: a settle outside a step counts
-        them too)."""
+        """Completion counters, events and the `serving_request_end`
+        instant span of the requests a walk finished (inside the walk's
+        span: a settle outside a step counts them too)."""
         # completed == finished SUCCESSFULLY: a request failed by its
         # own callback rides `finished` for page accounting but must not
         # count as a completion (it already counted in
         # callback_errors_total / serving_callback_error)
-        ok = [r for r in finished if r.status == "ok"]
-        self._prom.counter_inc("requests_completed_total", len(ok),
-                               help="requests finished successfully")
-        if ok:
-            from ..observability import get_event_log
-            log = get_event_log()
-            for r in ok:
-                self._prom.summary_observe(
-                    "request_seconds",
-                    time.perf_counter() - r.submit_time,
-                    help="submit-to-completion latency")
-                if log is not None:
-                    log.emit("serving_complete", role="serving", rid=r.rid,
-                             tokens=len(r.output), ttft_s=r.ttft_s)
+        self._prom.counter_inc(
+            "requests_completed_total",
+            sum(r.status == "ok" for r in finished),
+            help="requests finished successfully")
+        for r in finished:
+            total_s = time.perf_counter() - r.submit_time
+            with RecordEvent(REQUEST_SPANS.end, **dict(zip(
+                    REQUEST_END_ATTRS,
+                    (r.rid, r.status, len(r.output), r.decode_steps,
+                     round(total_s * 1e6), r.preemptions)))):
+                pass
+            if r.status != "ok":
+                continue
+            self._prom.summary_observe(
+                "request_seconds", total_s,
+                help="submit-to-completion latency")
+            # the JSONL timeline says what the trace says, from the same
+            # record: the TTFT and the four phases it is made of
+            self._emit_event(
+                "serving_complete", rid=r.rid, tokens=len(r.output),
+                ttft_s=r.ttft_s, **dict(zip(
+                    ("queue_s", "wait_s", "prefill_s", "land_s"),
+                    r.ttft_parts())))
 
     @RecordEvent(SERVING_SPANS.metrics)
     def _step_metrics(self, t_step0, tokens_before, n_pre, n_dec):
@@ -2222,18 +2334,6 @@ class ServingEngine:
                                   "speculation health rate")
             self._spec_prop_reported = self.spec_proposed
             self._spec_acc_reported = self.spec_accepted
-        if self.model.routed:
-            was = self._moe_reported
-            self._moe_reported = (self.moe_assignments,
-                                  self.moe_experts_touched)
-            prom.counter_inc("moe_assignments_total",
-                             self._moe_reported[0] - was[0],
-                             help="token-expert assignments to the experts "
-                                  "held here")
-            prom.counter_inc("moe_experts_touched_total",
-                             self._moe_reported[1] - was[1],
-                             help="held experts a pass read, summed over "
-                                  "layers and passes")
         if self._ssm_state is not None:
             prom.gauge_set("ssm_state_bytes",
                            self._ssm_state.nbytes + self._conv_tail.nbytes,
@@ -2249,11 +2349,6 @@ class ServingEngine:
                        sum(s is not None for s in self._slots),
                        help="slots occupied this step")
         prom.counter_inc("engine_steps_total", help="engine iterations")
-        prom.counter_inc("dispatches_total",
-                         self.dispatches - self._dispatches_reported,
-                         help="compiled-program dispatches issued (the "
-                              "contract: one per step)")
-        self._dispatches_reported = self.dispatches
         prom.gauge_set("dispatches_per_step",
                        self.dispatches / max(self.engine_steps, 1),
                        help="mean compiled dispatches per engine step")

@@ -17,6 +17,12 @@ trace of one commit can be held against a trace of the next:
   span (stats of the event in a profiler trace), and ``SSM_DISPATCH_ATTRS``
   — the ones a model with a recurrent state adds; ``MOE_FETCH_ATTRS`` —
   the attributes a model with routed experts puts on ``serving_fetch``;
+  ``ADMISSION_ATTRS`` — what ``serving_admission`` closes with, and
+  ``ADMIT_BLOCKED``, the values of its ``blocked``;
+* ``REQUEST_SPANS`` — the two instant spans of a request's life inside
+  ``serving_walk``, with ``FIRST_TOKEN_ATTRS`` and ``REQUEST_END_ATTRS``;
+  ``REQUEST_PHASES`` — the four back-dated collector events that tile a
+  request's way from submission to its first token;
 * ``SCOPES`` — the ``jax.named_scope``s inside the compiled programs (the
   serving step, the dense train step, the hybrid train step). A device
   operation's ``op_name`` path carries them; an operation under none is
@@ -39,8 +45,10 @@ from typing import Iterable, Optional
 from ..profiler.utils import HostEvent, RecordEvent, collector
 
 __all__ = ["span", "capture_spans", "write_chrome_trace", "SERVING_SPANS",
-           "DISPATCH_ATTRS", "SSM_DISPATCH_ATTRS", "MOE_FETCH_ATTRS", "SCOPES",
-           "KERNELS"]
+           "DISPATCH_ATTRS", "SSM_DISPATCH_ATTRS", "MOE_FETCH_ATTRS",
+           "ADMISSION_ATTRS", "ADMIT_BLOCKED", "REQUEST_SPANS",
+           "REQUEST_PHASES", "FIRST_TOKEN_ATTRS", "REQUEST_END_ATTRS",
+           "SCOPES", "KERNELS"]
 
 span = RecordEvent
 
@@ -71,21 +79,63 @@ SERVING_SPANS = _names(
 # and the (row, page) pairs those positions fill: what the attention
 # kernel walks, of k x max_batch x max_blocks_per_seq table slots; and
 # `in_flight`, 1 when the step before had not been fetched at this
-# dispatch (the device goes from one to the next without the host).
+# dispatch (the device goes from one to the next without the host). What
+# the token budget did to the prefilling rows: `n_starved` of the `n_pre`
+# resident prefilling rows rode the step with no grant, the others shared
+# `pre_tokens` prompt tokens of a `budget` of packed tokens a step.
 DISPATCH_ATTRS = ("step", "k", "n_dec", "n_pre", "q_tokens", "kv_tokens",
-                  "attn_pages", "in_flight")
+                  "attn_pages", "in_flight", "n_starved", "pre_tokens",
+                  "budget")
 # A model with a recurrent state adds: rows whose state the first pass read
 # and wrote (the chunk scan's), rows of the k - 1 burst passes (the state
 # update's, summed), and tokens through its mixer over all k passes.
 SSM_DISPATCH_ATTRS = ("ssm_scan_rows", "ssm_update_rows", "ssm_tokens")
 # A model with routed experts learns what a step's router chose only from
-# the step's fetch, so these ride the `serving_fetch` span, and (a span's
-# attributes are fixed when it opens) each fetch carries the counts of the
-# step fetched BEFORE it: held experts whose weights the step read, summed
-# over its layers and passes; token-expert assignments to held experts;
-# and the largest number of assignments one held expert got in a layer of
-# a pass. Over a window the sums miss one step at either end.
+# the step's fetch, so these ride the `serving_fetch` span that LANDED the
+# step (set before it closes): held experts whose weights the step read,
+# summed over its layers and passes; token-expert assignments to held
+# experts; and the largest number of assignments one held expert got in a
+# layer of a pass.
 MOE_FETCH_ATTRS = ("moe_experts_touched", "moe_assignments", "moe_load_max")
+# What `serving_admission` closes with: requests admitted by this call,
+# the queue's depth after it, why the queue's head still waits (one of
+# ADMIT_BLOCKED), and decode victims this call evicted for it.
+ADMISSION_ATTRS = ("admitted", "queue", "blocked", "preempted")
+ADMIT_BLOCKED = _names(
+    "AdmitBlocked",
+    none=0,         # the queue is empty: nobody waits
+    slot=1,         # no free slot
+    pages=2,        # the pool has too few free pages for the head
+    prefix=3,       # an identical prefix is being prefilled by its owner
+    draining=4)     # the engine admits nothing any more
+
+# A request's way to its first token, from ONE record (`Request`'s marks):
+# four phases that tile [submission, first token] without holes. The engine
+# adds them to the collector when the token is handed over, each with its
+# own start and end and the request's `rid` (a chrome trace shows a request
+# as four bars); a profiler session gets the same durations as the stats of
+# the instant span below, which anchors the hand-over on the device's clock.
+REQUEST_PHASES = _names(
+    "RequestPhases",
+    queue="request_queue",      # submitted -> a slot and its pages
+    wait="request_wait",        # admitted -> the first prompt tokens granted
+    prefill="request_prefill",  # first grant -> dispatch of the last chunk
+    land="request_land")        # that dispatch -> the token handed over
+# Two instant spans inside `serving_walk`, one each a request.
+REQUEST_SPANS = _names(
+    "RequestSpans",
+    first_token="serving_first_token",  # the first token's hand-over
+    end="serving_request_end")          # the walk that finished the request
+# `*_us`: the four phases in whole microseconds (they sum to the request's
+# TTFT to the microsecond); `prefill_steps` / `starved_steps`: engine steps
+# it was granted prompt tokens / rode resident with none; `land_steps`:
+# engine steps from the dispatch of its last chunk to the call that handed
+# the token over (the pipeline's depth: 1 with a step in flight).
+FIRST_TOKEN_ATTRS = ("rid", "prompt_len", "queue_us", "wait_us", "prefill_us",
+                     "land_us", "prefill_steps", "starved_steps",
+                     "land_steps", "preemptions")
+REQUEST_END_ATTRS = ("rid", "status", "out_tokens", "decode_steps",
+                     "total_us", "preemptions")
 
 SCOPES = _names(
     "Scopes",

@@ -27,7 +27,7 @@ from jax.profiler import TraceAnnotation
 from ..flags import flag as _flag
 
 __all__ = ["RecordEvent", "HostEvent", "EventCollector", "collector", "Stat",
-           "active_spans"]
+           "active_spans", "record_interval"]
 
 
 class Stat:
@@ -137,6 +137,16 @@ def active_spans():
     return out
 
 
+def record_interval(name: str, start: float, end: float, **attrs):
+    """A span whose start and end (perf_counter seconds) the caller kept
+    itself and which has already ended: a phase of a request's life, known
+    only when the next one begins. It goes to the collector with no
+    parent; a TraceAnnotation cannot be back-dated, so a profiler session
+    does not see it."""
+    collector.add(HostEvent(name, start, end, threading.get_ident(),
+                            span_id=next(_SPAN_IDS), attrs=attrs))
+
+
 class RecordEvent:
     """Context manager/decorator recording one host span.
 
@@ -145,7 +155,8 @@ class RecordEvent:
     when it began (``HostEvent.parent``) and its keyword attributes
     (``HostEvent.attrs``; numbers or strings). It is a
     jax.profiler.TraceAnnotation too, so any profiler session shows it
-    beside the device operations, attributes as the event's stats."""
+    beside the device operations, attributes as the event's stats. What
+    is learnt only while the span is open joins them through ``set``."""
 
     def __init__(self, name: str, event_type: str = "UserDefined", **attrs):
         self.name = name
@@ -167,6 +178,15 @@ class RecordEvent:
                                      threading.get_ident(), self.event_type)
         self._jax_ctx = TraceAnnotation(self.name, **self.attrs)
         self._jax_ctx.__enter__()
+
+    def set(self, **attrs):
+        """Attributes learnt while the span is open: merged into its own,
+        and into the open annotation's stats. After ``end()`` the span is
+        recorded as it was, and this does nothing."""
+        if self._start is None:
+            return
+        self.attrs.update(attrs)
+        self._jax_ctx.set_metadata(**attrs)
 
     def end(self):
         if self._start is None:

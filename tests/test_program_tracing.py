@@ -2,7 +2,11 @@
 attributes that reach any jax.profiler trace, the spans inside one ragged
 serving step, `jax.named_scope`s inside the three compiled programs, a
 name on every Pallas kernel, and the benchmark's readers of all that on
-slices recorded on the TPU v5e."""
+slices recorded on the TPU v5e. Since ISSUE 38 also a request's life as one
+record (four parts that sum to its TTFT, two instant spans inside the walk,
+four back-dated collector events), the scheduler's choices on the
+admission and dispatch spans, a span that takes attributes until it
+closes, and the two readers of span attributes."""
 
 import ast
 import glob
@@ -28,9 +32,13 @@ from paddle_tpu.inference.serving import ServingEngine  # noqa: E402
 from paddle_tpu.models import gpt as G  # noqa: E402
 from paddle_tpu.models import falcon_h1 as FH  # noqa: E402
 from paddle_tpu.models import qwen3_next as QN  # noqa: E402
-from paddle_tpu.observability.trace import (DISPATCH_ATTRS, KERNELS,  # noqa: E402
-                                            MOE_FETCH_ATTRS, SCOPES,
-                                            SERVING_SPANS,
+from paddle_tpu.observability.trace import (ADMISSION_ATTRS,  # noqa: E402
+                                            ADMIT_BLOCKED, DISPATCH_ATTRS,
+                                            FIRST_TOKEN_ATTRS, KERNELS,
+                                            MOE_FETCH_ATTRS,
+                                            REQUEST_END_ATTRS,
+                                            REQUEST_PHASES, REQUEST_SPANS,
+                                            SCOPES, SERVING_SPANS,
                                             SSM_DISPATCH_ATTRS)
 from paddle_tpu.profiler.utils import RecordEvent, collector  # noqa: E402
 
@@ -188,7 +196,13 @@ def test_one_ragged_step_yields_every_serving_span_once():
         for end, samples, left in rows if samples and left > j)
     assert attrs == {"step": eng.engine_steps - 1, "k": k, "n_dec": n_dec,
                      "n_pre": n_pre, "q_tokens": q_tokens, "kv_tokens": kv,
-                     "attn_pages": pages, "in_flight": 0}
+                     "attn_pages": pages, "in_flight": 0, "n_starved": 0,
+                     "pre_tokens": q_tokens - n_dec,
+                     "budget": eng.token_budget}
+    # the admission span closes with what it did: nobody waited
+    adm = [e.attrs for e in cap.events if e.name == SERVING_SPANS.admission]
+    assert adm == [dict(zip(ADMISSION_ATTRS,
+                            (0, 0, ADMIT_BLOCKED.none, 0)))]
 
 
 def test_attn_pages_counts_the_row_page_pairs_of_a_hand_built_step():
@@ -368,12 +382,259 @@ def test_the_pattern_serving_step_carries_its_scopes_and_attributes():
     assert tuple(disp[0]) == DISPATCH_ATTRS + SSM_DISPATCH_ATTRS
     fetch = [e.attrs for e in cap.events if e.name == SERVING_SPANS.fetch]
     assert all(tuple(a) == MOE_FETCH_ATTRS for a in fetch)
-    assert fetch[0] == dict.fromkeys(MOE_FETCH_ATTRS, 0)   # none landed yet
-    # every landed step's counts ride the next fetch; the last step's are
-    # in the totals only
-    assert 0 < sum(a["moe_assignments"] for a in fetch) < eng.moe_assignments
+    # every landed step's counts ride the fetch that landed it: the first
+    # fetch has the first step's, and the fetches together are the totals
+    assert fetch[0]["moe_assignments"] > 0
+    assert sum(a["moe_assignments"] for a in fetch) == eng.moe_assignments
+    assert sum(a["moe_experts_touched"] for a in fetch) == \
+        eng.moe_experts_touched
     assert all(a["moe_experts_touched"] <= a["moe_assignments"]
                and a["moe_load_max"] <= a["moe_assignments"] for a in fetch)
+
+
+# -- a request's life, and the scheduler's choices (ISSUE 38) ----------------
+def test_a_span_takes_attributes_until_it_closes(tmp_path):
+    """`RecordEvent.set` merges into the span's attributes and reaches a
+    jax.profiler session's stats; after `end()` it does nothing."""
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with obs.capture_spans() as cap:
+            with RecordEvent("late_probe", step=3) as ev:
+                jnp.ones((4,)).block_until_ready()
+                ev.set(landed=7, why="pages")
+                ev.set(landed=8)
+            ev.set(after=1)
+    finally:
+        jax.profiler.stop_trace()
+    assert ev.attrs == {"step": 3, "landed": 8, "why": "pages"}
+    assert [e.attrs for e in cap.events] == [ev.attrs]
+    files = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                          / "*.xplane.pb"))
+    host = {h[0]: h for h in program_trace.from_xplane(files[0])["host"]}
+    assert host["late_probe"][3] == {"step": 3, "landed": 8, "why": "pages"}
+    idle = RecordEvent("never_begun")
+    idle.set(x=1)                       # not open: nothing to set
+    assert idle.attrs == {}
+
+
+@pytest.fixture(scope="module")
+def mixed_run():
+    """Four requests through three slots, nine pages of 8 and a budget of
+    ONE 8-token chunk a step, preemption on: `victim` decodes long and is
+    evicted for the queue's head; `starved` is resident from the start but
+    waits for budget behind the victim's chunk; `queued` arrives to a pool
+    with too few pages; `quick` (4 tokens in, 1 out) is done a step pair
+    after its chunk. Returns (collector events, {name: Request})."""
+    cfg = tiny_cfg()
+    params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(params, cfg, max_batch=3, block_size=8,
+                        num_blocks=10, chunk=8, token_budget=8,
+                        decode_burst=2, preempt=True, preempt_wait_steps=1)
+    names, done = {}, {}
+
+    def add(name, n_prompt, n_new):
+        names[eng.add_request(np.arange(n_prompt) % 64,
+                              max_new_tokens=n_new)] = name
+    with obs.capture_spans() as cap:
+        add("victim", 8, 24)
+        add("starved", 20, 4)
+        for _ in range(2):
+            eng.step()
+        add("queued", 20, 4)
+        add("quick", 4, 1)
+        for _ in range(200):
+            if not eng.has_work():
+                break
+            for r in eng.step():
+                done[names[r.rid]] = r
+    assert sorted(done) == sorted(names.values())
+    return cap.events, done
+
+
+@pytest.mark.parametrize("name", ["victim", "starved", "queued", "quick"])
+def test_the_four_parts_are_non_negative_and_sum_to_the_ttft(mixed_run,
+                                                              name):
+    events, done = mixed_run
+    r = done[name]
+    parts = r.ttft_parts()
+    assert len(parts) == 4 and all(p >= 0 for p in parts)
+    assert sum(parts) == pytest.approx(r.ttft_s, abs=1e-9)
+    assert r.ttft_s == r.first_token_time - r.submit_time
+    # what makes each of the four the case it stands for
+    if name == "victim":
+        assert r.preemptions == 1 and r.prefill_steps > 1   # re-prefilled
+    elif name == "starved":
+        assert r.starved_steps > 0
+        assert parts[1] > parts[0]          # waited for budget, not a slot
+    elif name == "queued":
+        assert parts[0] > 0 and r.starved_steps == 0
+    else:
+        assert (len(r.output), r.decode_steps, r.prefill_steps) == (1, 0, 1)
+    (ft,) = [e for e in events if e.name == REQUEST_SPANS.first_token
+             and e.attrs["rid"] == r.rid]
+    a = ft.attrs
+    assert tuple(a) == FIRST_TOKEN_ATTRS
+    us = [a["queue_us"], a["wait_us"], a["prefill_us"], a["land_us"]]
+    assert all(u >= 0 for u in us)
+    assert sum(us) == round(r.ttft_s * 1e6)         # to the microsecond
+    assert all(abs(u - p * 1e6) <= 1 for u, p in zip(us, parts))
+    assert a["prompt_len"] == len(r.prompt) - r.folded
+    assert a["land_steps"] == 1         # one step of pipeline depth
+    assert a["preemptions"] == 0        # nobody is evicted before a token
+
+
+def test_one_first_token_and_one_end_a_request_inside_the_walk(mixed_run):
+    events, done = mixed_run
+    walks = {e.span_id for e in events if e.name == SERVING_SPANS.walk}
+    for span, attrs in ((REQUEST_SPANS.first_token, FIRST_TOKEN_ATTRS),
+                        (REQUEST_SPANS.end, REQUEST_END_ATTRS)):
+        seen = [e for e in events if e.name == span]
+        assert sorted(e.attrs["rid"] for e in seen) == \
+            sorted(r.rid for r in done.values())
+        assert all(tuple(e.attrs) == attrs for e in seen)
+        assert all(e.parent in walks for e in seen)
+    for e in events:
+        if e.name != REQUEST_SPANS.end:
+            continue
+        (r,) = [r for r in done.values() if r.rid == e.attrs["rid"]]
+        assert e.attrs == dict(zip(REQUEST_END_ATTRS, (
+            r.rid, "ok", len(r.output), r.decode_steps,
+            e.attrs["total_us"], r.preemptions)))
+        assert e.attrs["total_us"] >= round(r.ttft_s * 1e6)
+    assert done["victim"].decode_steps >= 12    # 23 tokens, bursts of 2
+
+
+@pytest.mark.parametrize("name", ["victim", "starved", "queued", "quick"])
+def test_the_request_phases_share_rid_and_tile_the_way_to_the_token(
+        mixed_run, name):
+    events, done = mixed_run
+    r = done[name]
+    bars = [e for e in events if e.name in set(REQUEST_PHASES)
+            and e.attrs == {"rid": r.rid}]
+    assert [e.name for e in bars] == list(REQUEST_PHASES)
+    assert all(e.parent is None for e in bars)
+    assert bars[0].start == r.submit_time
+    assert bars[-1].end == r.first_token_time
+    assert all(a.end == b.start for a, b in zip(bars, bars[1:]))
+    assert [e.duration for e in bars] == list(r.ttft_parts())
+
+
+def test_nothing_is_recorded_of_a_request_while_the_collector_is_off():
+    cfg = tiny_cfg()
+    params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(params, cfg, max_batch=2, block_size=16,
+                        num_blocks=16, chunk=8)
+    rid = eng.add_request(np.arange(5) % 64, max_new_tokens=2)
+    collector.clear()
+    assert not collector.enabled
+    out = eng.run(max_steps=10)
+    assert len(out[rid]) == 2
+    assert collector.drain() == []
+
+
+def test_starved_rows_and_granted_tokens_of_a_hand_built_step():
+    """`n_starved` / `pre_tokens` / `budget` say what the token budget did
+    to the resident prefilling rows: three rows share 12 packed tokens a
+    step (4 slots + one chunk of 8), a decode row takes 1 first."""
+    cfg = tiny_cfg()
+    params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(params, cfg, max_batch=4, block_size=16,
+                        num_blocks=16, chunk=8, decode_burst=1)
+    eng.add_request(np.arange(5) % 64, max_new_tokens=12)    # row A
+    eng.add_request(np.arange(20) % 64, max_new_tokens=4)    # row B
+    eng.add_request(np.arange(20) % 64, max_new_tokens=4)    # row C
+    seen = []
+    for _ in range(4):
+        with obs.capture_spans() as cap:
+            eng.step()
+        seen += [tuple(e.attrs[k] for k in ("n_dec", "n_pre", "n_starved",
+                                            "pre_tokens", "budget"))
+                 for e in cap.events if e.name == SERVING_SPANS.dispatch]
+    # step 1: A's 5 and 7 of B's 8 fill the budget, C rides with nothing;
+    # step 2: A decodes (1), B gets a whole chunk, C the 3 left;
+    # step 3: B's last 5, C 6 of the 11 left; step 4: B decodes too, C 8
+    assert seen == [(0, 3, 1, 12, 12), (1, 2, 0, 11, 12), (1, 2, 0, 11, 12),
+                    (2, 1, 0, 8, 12)]
+    c = eng.slots[2]
+    assert (c.starved_steps, c.prefill_steps) == (1, 3)
+
+
+def _blocked_engine(why):
+    """An engine whose queue's head waits for the named reason."""
+    cfg = tiny_cfg()
+    params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
+    kw = dict(max_batch=2, block_size=8, num_blocks=16, chunk=8)
+    long = np.arange(16) % 64
+    if why == "none":
+        eng = ServingEngine(params, cfg, **kw)
+        eng.add_request(long, max_new_tokens=8)
+    elif why == "slot":
+        eng = ServingEngine(params, cfg, **kw)
+        for _ in range(3):
+            eng.add_request(long, max_new_tokens=8)
+    elif why == "pages":            # 5 usable pages, 3 a request
+        eng = ServingEngine(params, cfg, **dict(kw, num_blocks=6))
+        for _ in range(2):
+            eng.add_request(long, max_new_tokens=8)
+    elif why == "prefix":           # the twin waits for its owner's pages
+        eng = ServingEngine(params, cfg, prefix_share=True, **kw)
+        for _ in range(2):
+            eng.add_request(long, max_new_tokens=8)
+    else:
+        eng = ServingEngine(params, cfg, **kw)
+        for _ in range(3):
+            eng.add_request(long, max_new_tokens=8)
+        eng.step()
+        eng.drain()
+    return eng
+
+
+@pytest.mark.parametrize("why", ADMIT_BLOCKED._fields)
+def test_the_admission_span_says_why_the_head_waits(why):
+    eng = _blocked_engine(why)
+    with obs.capture_spans() as cap:
+        eng.step()
+    (adm,) = [e.attrs for e in cap.events
+              if e.name == SERVING_SPANS.admission]
+    assert tuple(adm) == ADMISSION_ATTRS
+    assert adm["blocked"] == getattr(ADMIT_BLOCKED, why)
+    assert adm["queue"] == len(eng.queue) == (0 if why == "none" else 1)
+    assert adm["admitted"] == (0 if why == "draining" else
+                               2 if why == "slot" else 1)
+    assert adm["preempted"] == 0
+
+
+def test_a_fetch_carries_the_router_counts_of_the_step_it_landed():
+    """Two steps with different routing: each `serving_fetch` closes with
+    the counts of the step IT landed, not of the one before."""
+    cfg = QN.Qwen3NextConfig(
+        vocab_size=64, hidden_size=32, num_layers=4, num_heads=4,
+        num_kv_heads=2, head_dim=16, linear_key_heads=2,
+        linear_value_heads=4, linear_key_dim=8, linear_value_dim=8,
+        num_experts=8, experts_per_tok=2, moe_ffn=16, shared_ffn=16,
+        experts_held=(0, 4), ssm_chunk=8, dtype=jnp.float32,
+        param_dtype=jnp.float32)
+    params = QN.init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(params, cfg, max_batch=2, block_size=16,
+                        num_blocks=16, chunk=8, decode_burst=1)
+    eng.add_request(np.arange(8) % 64, max_new_tokens=3)
+    landed = []
+    land = eng._land
+
+    def spy(f):     # the step's own stats, read before the engine does
+        stats = np.asarray(jax.device_get(f.out[-1]))
+        landed.append((int(stats[..., 0].sum()), int(stats[..., 1].sum()),
+                       int(stats[..., 2].max())))
+        return land(f)
+    eng._land = spy
+    with obs.capture_spans() as cap:
+        eng.run()
+    fetch = [tuple(e.attrs[k] for k in MOE_FETCH_ATTRS)
+             for e in cap.events if e.name == SERVING_SPANS.fetch]
+    assert len(fetch) >= 2 and fetch == landed
+    assert fetch[0] != fetch[1]     # 8 prompt tokens, then 1 decode token
 
 
 def _calls(tree, attr):
@@ -412,10 +673,11 @@ def test_no_scope_or_serving_span_is_a_free_string():
         REPO, "paddle_tpu", "inference", "serving.py")).read())
     spans = [n for n in ast.walk(serving) if isinstance(n, ast.Call)
              and isinstance(n.func, ast.Name) and n.func.id == "RecordEvent"]
-    assert len(spans) >= len(SERVING_SPANS)
+    assert len(spans) >= len(SERVING_SPANS) + len(REQUEST_SPANS)
     for call in spans:
         assert _from_tuple(call.args[0], "SERVING_SPANS",
-                           SERVING_SPANS._fields), call.lineno
+                           SERVING_SPANS._fields) or _from_tuple(
+            call.args[0], "REQUEST_SPANS", REQUEST_SPANS._fields), call.lineno
 
 
 # -- the benchmark's readers ------------------------------------------------
@@ -454,11 +716,26 @@ def _suffix(name):
     return UNSUFFIXED.get(name) or "." + name.rsplit(".", 1)[1]
 
 
-@pytest.mark.parametrize("name", NEW)
+# ISSUE 38's readers of span attributes, and the metrics that use them: a
+# metric shared by cells that move the same end-to-end metric is one entry
+SCHED_READERS = {"span_attr", "span_attr_ratio"}
+SCHED = [m for m in SPEC["per_layer"]
+         if harness.load_json("metrics", m["name"] + ".json")["reader"]
+         in SCHED_READERS]
+SCHED_CASES = [(m["name"], cell) for m in SCHED for cell in m["workloads"]]
+# what each span may carry, by the tuple the call site takes it from
+SPAN_ATTRS = {SERVING_SPANS.dispatch: DISPATCH_ATTRS + SSM_DISPATCH_ATTRS,
+              SERVING_SPANS.fetch: MOE_FETCH_ATTRS,
+              SERVING_SPANS.admission: ADMISSION_ATTRS,
+              REQUEST_SPANS.first_token: FIRST_TOKEN_ATTRS,
+              REQUEST_SPANS.end: REQUEST_END_ATTRS}
+
+
+@pytest.mark.parametrize("name", NEW + [m["name"] for m in SCHED])
 def test_metric_files_name_only_what_the_program_names(name):
     """A rename in the program fails here instead of reading 0 there."""
     params = harness.load_json("metrics", name + ".json")["params"]
-    spans = set(SERVING_SPANS)
+    spans = set(SERVING_SPANS) | set(REQUEST_SPANS)
     for key in ("spans", "span", "until", "per"):
         value = params.get(key, [])
         for s in [value] if isinstance(value, str) else value:
@@ -467,9 +744,26 @@ def test_metric_files_name_only_what_the_program_names(name):
         assert set(params.get(key, [])) <= set(SCOPES), name
     if "kernel" in params:
         assert params["kernel"] in KERNELS
-    if "attr" in params:
-        assert params["attr"] in (DISPATCH_ATTRS + SSM_DISPATCH_ATTRS
-                                  + MOE_FETCH_ATTRS)
+    carried = SPAN_ATTRS.get(params.get("span"), ())
+    named = [params[k] for k in ("attr", "num", "den") if params.get(k)]
+    assert set(named + params.get("attrs", [])) <= set(carried), name
+    if params.get("num") == "blocked":
+        assert params["num_equals"] in tuple(ADMIT_BLOCKED)
+
+
+def test_the_scheduler_metrics_are_the_seven_the_cap_left_room_for():
+    """ISSUE 38 asked for fourteen entries, a cell each; `per_layer` may
+    hold 128 and held 121, so docs and Falcon-H1 (both move `serve_tok_s`)
+    share an entry, and chat's queue and prefill parts and its slot fill
+    wait for the `benchmark` PR that retires duplicates (PERF.md)."""
+    assert len(SPEC["per_layer"]) == 128
+    assert sorted(m["name"] for m in SCHED) == [
+        "admit_blocked_pct.docs", "attn_slot_fill_pct.docs",
+        "prefill_starved_pct.serve", "ttft_land_p50_ms.chat",
+        "ttft_land_p50_ms.serve", "ttft_prefill_p50_ms.serve",
+        "ttft_queue_p50_ms.serve"]
+    assert all(m["source"] == "program_span" for m in SCHED)
+    assert len(SCHED_CASES) == 11
 
 
 def test_the_share_metrics_of_a_cell_divide_its_program():
@@ -547,6 +841,106 @@ def test_reader_returns_nothing_without_the_programs_names(name):
         assert value is not None    # reads the reduced form alone
     else:
         assert value is None
+
+
+SCHED_SLICES = {"serve-1p3b-docs": "ptrace-v5e-sched-docs.json.gz",
+                "serve-1p3b-chat": "ptrace-v5e-sched-chat.json.gz",
+                "serve-falconh1-34b-chat": "ptrace-v5e-sched-h1chat.json.gz"}
+
+
+@pytest.mark.parametrize("name,cell", SCHED_CASES)
+def test_scheduler_reader_on_a_slice_recorded_on_the_chip(name, cell):
+    pt = _slice(SCHED_SLICES[cell])
+    value = harness.read_metric(name, _run_of(pt))
+    assert value is not None and 0.0 <= value
+    if name.split(".")[0].endswith("_pct"):
+        assert value <= 100.0
+    assert value == pytest.approx(pt["note"]["expected"][name], rel=1e-6)
+
+
+@pytest.mark.parametrize("cell", sorted(SCHED_SLICES))
+def test_a_recorded_first_token_is_four_parts_and_one_landing_step(cell):
+    """On the chip as on the CPU: every first token of the slice has four
+    non-negative parts, was handed over one engine step after the
+    dispatch that sampled it (or in the same call, by a tail settle), and
+    the medians of the parts add up to about the median of their sum."""
+    pt = _slice(SCHED_SLICES[cell])
+    ft = [h[3] for h in pt["host"] if h[0] == REQUEST_SPANS.first_token]
+    assert len(ft) >= 5 and all(tuple(a) == FIRST_TOKEN_ATTRS for a in ft)
+    parts = ("queue_us", "wait_us", "prefill_us", "land_us")
+    assert all(a[k] >= 0 for a in ft for k in parts)
+    assert {a["land_steps"] for a in ft} <= {0, 1}
+    assert all(a["prefill_steps"] >= -(-a["prompt_len"] // 128) for a in ft)
+    walks = [(h[1], h[1] + h[2]) for h in pt["host"]
+             if h[0] == SERVING_SPANS.walk]
+    for h in pt["host"]:
+        if h[0] in set(REQUEST_SPANS):      # instants inside a walk
+            assert any(a <= h[1] and h[1] + h[2] <= b for a, b in walks)
+    ends = [h[3] for h in pt["host"] if h[0] == REQUEST_SPANS.end]
+    assert ends and all(tuple(a) == REQUEST_END_ATTRS for a in ends)
+    assert all(a["status"] == "ok" and a["out_tokens"] >= 1 for a in ends)
+
+
+@pytest.mark.parametrize("name,cell", SCHED_CASES)
+def test_scheduler_reader_returns_nothing_on_the_parents_trace(name, cell):
+    """The parent opens `serving_admission` and the dispatch span without
+    the new attributes and no request span at all: every new metric but
+    the slot fill (whose two attributes PR 29 brought) reads None there,
+    and all of them on a trace with none of the program's names."""
+    pt = _slice(SCHED_SLICES[cell])
+    old = set(DISPATCH_ATTRS) - {"n_starved", "pre_tokens", "budget"}
+    parent = dict(pt, host=[
+        [h[0], h[1], h[2], {k: v for k, v in h[3].items() if k in old}]
+        for h in pt["host"] if h[0] not in set(REQUEST_SPANS)])
+    value = harness.read_metric(name, _run_of(parent))
+    if name.startswith("attn_slot_fill_pct"):
+        assert value == pytest.approx(pt["note"]["expected"][name])
+    else:
+        assert value is None
+    bare = dict(pt, host=[h for h in pt["host"]
+                          if h[0] in harness.HOST_SPANS])
+    assert harness.read_metric(name, _run_of(bare)) is None
+
+
+def _hand_trace():
+    """Five spans of one name inside a window, one outside it, one
+    without the attributes."""
+    host = [["traced_window", 100, 1000, {}]]
+    for i, (a, b) in enumerate([(1, 10), (2, 20), (3, 30), (4, 40),
+                                (5, 50)]):
+        host.append(["s", 200 + 100 * i, 10, {"a": a, "b": b, "c": i % 2}])
+    host.append(["s", 50, 10, {"a": 999, "b": 999, "c": 1}])    # too early
+    host.append(["s", 900, 10, {"other": 1}])
+    return {"program_trace": {"devices": {}, "async": {}, "host": host}}
+
+
+@pytest.mark.parametrize("params,expected", [
+    (dict(attrs=["a"], stat="p50"), 3.0),
+    (dict(attrs=["a"], stat="p95"), 4.8),
+    (dict(attrs=["a", "b"], stat="p50", scale=0.001), 0.033),
+    (dict(attrs=["b"], stat="mean"), 30.0),
+    (dict(attrs=["a"], stat="sum", scale=2.0), 30.0),
+    (dict(attrs=["missing"], stat="p50"), None),
+])
+def test_span_attr_reads_a_statistic_of_attributes_added(params, expected):
+    from chipbench.readers import span_attr
+    assert span_attr.read(_hand_trace(), "s", **params) == \
+        (None if expected is None else pytest.approx(expected))
+    assert span_attr.read(_hand_trace(), "no_such_span", **params) is None
+
+
+@pytest.mark.parametrize("params,expected", [
+    (dict(num="a", den="b"), 10.0),                     # 15 / 150
+    (dict(num="a", den="b", den_times=0.5), 20.0),
+    (dict(num="c", num_equals=1), 40.0),                # 2 of 5 spans
+    (dict(num="a"), 300.0),                             # 15 / 5 spans
+    (dict(num="a", den="missing"), None),
+    (dict(num="missing"), None),
+])
+def test_span_attr_ratio_reads_sums_and_counts(params, expected):
+    from chipbench.readers import span_attr_ratio
+    assert span_attr_ratio.read(_hand_trace(), "s", **params) == \
+        (None if expected is None else pytest.approx(expected))
 
 
 def test_scope_of_reads_paths_as_the_profiler_writes_them():

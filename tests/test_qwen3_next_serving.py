@@ -125,7 +125,9 @@ def test_chunked_prefill_then_decode_on_logits_state_and_picks(
     assert eng.dispatches == eng.engine_steps      # one program a step
     assert eng.moe_assignments > 0 and 0 < eng.moe_experts_touched <= \
         eng.moe_passes * 8 * 4
-    assert eng.prom.get("moe_assignments_total") == eng.moe_assignments
+    # the totals are the engine's own; the per-step counts ride the fetch
+    # span that landed the step (no prom copy since PR 38)
+    assert eng.prom.get("moe_assignments_total") is None
 
 
 def test_mixed_steps_and_recycled_slots(params, logits):

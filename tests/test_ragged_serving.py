@@ -937,5 +937,7 @@ def test_dispatch_metrics_exported(params):
     eng.add_request(rng.randint(0, CFG.vocab_size, (5,)), 4)
     eng.run()
     text = eng.metrics_text()
-    assert "dispatches_total" in text
-    assert eng._prom.get("dispatches_total") == eng.dispatches
+    assert "dispatches_per_step" in text
+    assert "dispatches_total" not in text   # no reader: gone in PR 38
+    assert eng._prom.get("dispatches_per_step") == \
+        eng.dispatches / eng.engine_steps
