@@ -619,10 +619,11 @@ def _dense_attn(p, x, cfg: GPTConfig, fp8, flash):
         qkv = checkpoint_name(qkv, "qkv")
         qkv = qkv.reshape(B, S, cfg.num_heads, 3, cfg.head_dim)
     with jax.named_scope(SCOPES.flash):
+        # whichever arm runs the kernel, its (out, lse) residuals carry
+        # the FLASH_REMAT_NAMES tags, which dense_forward's policy keeps
+        # wherever the kernel runs (_dense_attn_is_flash)
         if flash is not None:
-            # direct fused path; its (out, lse) residuals carry the
-            # FLASH_REMAT_NAMES tags, so selective remat reuses the flash
-            # forward instead of re-running the kernel
+            # direct fused path
             from ..kernels.pallas import flash_training as _ft
             attn = _ft.attention(qkv[:, :, :, 0], qkv[:, :, :, 1],
                                  qkv[:, :, :, 2], flash)
@@ -675,6 +676,19 @@ def dense_head_loss(params, x, labels, cfg: GPTConfig):
     return lm_logsumexp_ce(logits, labels)
 
 
+def _dense_attn_is_flash(cfg: GPTConfig, batch: int, seq: int, flash) -> bool:
+    """Whether _dense_attn's attention lowers to the flash kernel for a
+    [batch, seq] step: always under a flash= plan (the kernel wired
+    directly), else exactly when the registry's dispatch takes its Pallas
+    arm for the block's q, k, v (asked of the op's own gate, on shapes)."""
+    if flash is not None:
+        return True
+    qkv = jax.ShapeDtypeStruct((batch, seq, cfg.num_heads, cfg.head_dim),
+                               cfg.dtype)
+    return F.scaled_dot_product_attention.__op_schema__.takes_pallas(
+        qkv, qkv, qkv, is_causal=True)
+
+
 def dense_forward(params, tokens, cfg: GPTConfig, remat: bool = True,
                   remat_save=("attn_out", "qkv"), fp8=None, flash=None):
     """Single-device forward over the stacked-parameter pytree (no
@@ -682,11 +696,25 @@ def dense_forward(params, tokens, cfg: GPTConfig, remat: bool = True,
     remat=True checkpoints each block (recompute in backward) — the memory/
     FLOPs trade that keeps long-sequence training inside HBM.
     remat_save: checkpoint_name'd intermediates kept instead of recomputed
-    (see dense_block tags). Saving attn_out+qkv measured fastest on the
-    1.3B flagship (578.6 vs 600.7 ms/step full-remat, one v5e, round 4:
-    skips recomputing the qkv projection and the flash forward at
-    ~128 MB/layer of saved activations); pass remat_save=() for the
-    minimum-memory full-remat form (bigger-than-HBM configs).
+    (see dense_block tags); pass remat_save=() for the minimum-memory
+    full-remat form (bigger-than-HBM configs), which replays the whole
+    block, the attention KERNEL included.
+
+    What a block keeps follows which attention runs in it, decided at
+    trace time (_dense_attn_is_flash). Where the flash kernel runs (a
+    flash= plan, or the registry op on the chip at a supported shape) the
+    kernel's (out, lse) residuals (FLASH_REMAT_NAMES) take "attn_out"'s
+    place: "attn_out" is a pure reshape of "flash_out", and without lse
+    (0.5 MB a layer) the backward kernels cannot start and the forward
+    kernel runs a second time. Where the composed XLA attention runs
+    (CPU, an unsupported shape) those tags do not exist and "attn_out" is
+    the only copy. An explicit remat_save= gets the same substitution.
+    So the default keeps qkv + (out, lse) on the chip and replays a
+    block's norms, GELU and its proj and fc1 GEMMs (GPT-3 1.3B, 4 x 2048,
+    one v5e: 608.8 ms a step against 629.5 with the kernel run twice,
+    PERF.md §6, PR 39); keeping "fc1" as well does not fit beside four rows
+    (the [24,4,2048,8192] stack is 3 GiB) and "proj" makes the compiler
+    clone the fc1 replay.
 
     fp8: per-layer delayed scales, stacked [L] like the block params (see
     quantization.fp8.init_fp8_meta) — they ride the same scan, so each
@@ -694,10 +722,8 @@ def dense_forward(params, tokens, cfg: GPTConfig, remat: bool = True,
     selective-remat policy additionally saves the quantized operands
     (FP8_REMAT_NAMES) so backward reuses them instead of re-quantizing.
 
-    flash: None or a FlashAttentionConfig — the fused attention kernel in
-    every block; selective remat then also saves the kernel's (out, lse)
-    residuals (FLASH_REMAT_NAMES) so the backward reuses the flash
-    forward, while full remat (remat_save=()) replays the KERNEL."""
+    flash: None or a FlashAttentionConfig — the fused attention kernel
+    wired directly into every block instead of the registry op."""
     x = dense_embed(params, tokens, cfg)
 
     def block(p, x, f=None):
@@ -707,13 +733,9 @@ def dense_forward(params, tokens, cfg: GPTConfig, remat: bool = True,
         if fp8 is not None:
             from ..quantization.fp8 import FP8_REMAT_NAMES
             remat_save = tuple(remat_save) + tuple(FP8_REMAT_NAMES)
-        if flash is not None:
-            from ..kernels.pallas.flash_attention import FLASH_REMAT_NAMES
-            # "attn_out" is a pure reshape of the kernel's "flash_out"
-            # residual — saving both would store the attention output
-            # twice per block and erode the O(S) win
+        if _dense_attn_is_flash(cfg, *tokens.shape, flash):
             remat_save = tuple(n for n in remat_save
-                               if n != "attn_out") + tuple(FLASH_REMAT_NAMES)
+                               if n != "attn_out") + FLASH_REMAT_NAMES
         blk = jax.checkpoint(
             block,
             policy=jax.checkpoint_policies.save_only_these_names(

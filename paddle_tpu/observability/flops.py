@@ -79,7 +79,9 @@ def attention_flops_per_token(*, num_layers: int, hidden_size: int,
     FlashAttention-2 backward re-derives the scores tile inside each
     kernel — dkv = {s, dp, dv, dk} (4 passes), dq = {s, dp, dq} (3) — so
     bwd is 7; full remat replays the fwd KERNEL (+2, still O(S) HBM),
-    selective (FLASH_REMAT_NAMES: out+lse saved) skips the replay.
+    selective (FLASH_REMAT_NAMES: out+lse saved) skips the replay —
+    dense_forward's default wherever the kernel runs, through the
+    registry op or a flash= plan alike.
     Flash thus EXECUTES more attention flops than the composed path
     (11 vs 8 passes under full remat) — the win is the O(S²)→O(S) HBM
     traffic and residency, which is why the planner scores it honestly

@@ -41,18 +41,24 @@ class OpSchema:
     pallas_supported: Optional[Callable[..., bool]] = None
     tags: List[str] = field(default_factory=list)
 
+    def takes_pallas(self, *args, **kwargs) -> bool:
+        """Whether `dispatch` would run the Pallas implementation on these
+        arguments (arrays or ShapeDtypeStructs: the gate reads shapes and
+        static options only). Callers whose trace depends on which arm
+        runs (dense_forward's remat policy) ask here, never a copy."""
+        return bool(
+            self.pallas_impl is not None
+            and flag("enable_pallas_kernels")
+            and _on_tpu()
+            and (self.pallas_supported is None
+                 or self.pallas_supported(*args, **kwargs)))
+
     def dispatch(self, *args, **kwargs):
-        from ..flags import flag
         count = flag("enable_dispatch_stats")
         stats = (DISPATCH_STATS.setdefault(
             self.name, {"pallas": 0, "reference": 0}) if count
             else {"pallas": 0, "reference": 0})
-        if (
-            self.pallas_impl is not None
-            and flag("enable_pallas_kernels")
-            and _on_tpu()
-            and (self.pallas_supported is None or self.pallas_supported(*args, **kwargs))
-        ):
+        if self.takes_pallas(*args, **kwargs):
             stats["pallas"] += 1
             out = self.pallas_impl(*args, **kwargs)
         else:

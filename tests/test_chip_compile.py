@@ -512,16 +512,18 @@ def test_qwen3_next_step_keeps_state_pool_and_experts_in_place(
 # pp 1 x mp 2, two microbatches): ONE pipeline stage is no pipeline
 # ---------------------------------------------------------------------------
 def _gemm_fusions(text):
-    """How many fusions of a compiled TPU program hold a GEMM (a loop's
-    body is in the text once, however often it runs)."""
+    """The fusions of a compiled TPU program that hold a GEMM, by
+    instruction name (a loop's body is in the text once, however often it
+    runs; a clone the compiler makes to re-materialise is `.rematN`)."""
     bodies, name = set(), None
     for line in text.splitlines():
         if line.endswith("{") and not line.startswith(" "):
             name = line.split()[0].lstrip("%")
         elif " convolution(" in line:
             bodies.add(name)
-    return sum(c in bodies
-               for c in re.findall(r" fusion\(.*calls=%?([\w.\-]+)", text))
+    return [inst for inst, callee in re.findall(
+        r"%?([\w.\-]+) = [^\n]* fusion\([^\n]*calls=%?([\w.\-]+)", text)
+        if callee in bodies]
 
 
 def test_hybrid_cell_step_runs_no_pass_twice(topo, compiled_kernels,
@@ -565,7 +567,7 @@ def test_hybrid_cell_step_runs_no_pass_twice(topo, compiled_kernels,
                           sharded(init.abstract(ex), init.state_specs),
                           tok, tok, lr).compile()
     text = compiled.as_text()
-    assert _gemm_fusions(text) == 3 * 5
+    assert len(_gemm_fusions(text)) == 3 * 5
     assert len(re.findall(r"%flash_fwd[.\d]* = ", text)) == 1
     assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
     assert "collective-permute" not in text
@@ -578,3 +580,51 @@ def test_hybrid_cell_step_runs_no_pass_twice(topo, compiled_kernels,
                if re.search(r"= bf16\[6,4096,8192\]\S* all-reduce", l)]
     assert len(stacked) == 1, stacked
     assert compiled.memory_analysis().temp_size_in_bytes < 5.5 * 2 ** 30
+
+
+# ---------------------------------------------------------------------------
+# the dense training cell's step (GPT-3 1.3B, 4 x 2048, one chip, donated)
+# ---------------------------------------------------------------------------
+def test_dense_cell_step_runs_flash_forward_once(one_chip, compiled_kernels,
+                                                 monkeypatch):
+    """The compiled step of `train-1p3b-seq2k` (the runner's `jit` of
+    `value_and_grad(G.dense_loss)` + `AdamW.apply`, every default) runs
+    flash attention's forward kernel ONCE a layer: a block keeps the
+    kernel's `(out, lse)` beside `qkv`, so the backward kernels start from
+    what was saved. Seventeen GEMMs in its text: the fifteen a step needs
+    (qkv, proj, fc1, fc2 and the head, each once forward and twice
+    backward) plus the replays of fc1 and proj under the block's
+    checkpoint, which memory does not allow keeping yet at four rows (the
+    `[24,4,2048,8192]` fc1 stack is 3 GiB: "Used 17.62G of 15.75G hbm";
+    keeping `proj` alone makes the compiler clone the fc1 replay as
+    `.rematN`, PERF.md PR 39). No GEMM may sit in such a clone."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models import gpt as G
+    from paddle_tpu.ops import registry
+    monkeypatch.setattr(registry, "_on_tpu", lambda: True)
+    cfg = G.GPTConfig(vocab_size=50304, hidden_size=2048, num_layers=24,
+                      num_heads=16, ffn_hidden=8192, max_seq_len=2048,
+                      dtype=jnp.bfloat16, param_dtype=jnp.bfloat16)
+    opt = paddle.optimizer.AdamW(1e-4, moment_dtype=jnp.bfloat16)
+
+    @functools.partial(jax.jit, donate_argnums=(0, 1))
+    def step(params, state, tokens, labels):
+        loss, grads = jax.value_and_grad(
+            lambda p: G.dense_loss(p, tokens, labels, cfg))(params)
+        params, state = opt.apply(params, grads, state, 1e-4)
+        return params, state, loss
+
+    def on_chip(tree):
+        return jax.tree.map(
+            lambda a: _sds(one_chip, a.shape, a.dtype), tree)
+    params = jax.eval_shape(
+        lambda: G.init_hybrid_params(cfg, jax.random.PRNGKey(0)))
+    state = jax.eval_shape(opt.init_state, params)
+    tok = _sds(one_chip, (4, 2048), jnp.int32)
+    text = step.lower(on_chip(params), on_chip(state), tok,
+                      tok).compile().as_text()
+    assert len(re.findall(r"%flash_fwd[.\d]* = ", text)) == 1
+    assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
+    gemms = _gemm_fusions(text)
+    assert len(gemms) == 3 * 5 + 2, gemms
+    assert not [g for g in gemms if ".remat" in g], gemms

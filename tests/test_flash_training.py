@@ -224,6 +224,58 @@ def test_remat_modes_bitwise_equal():
     assert n_sel < n_full, (n_sel, n_full)
 
 
+@pytest.mark.parametrize("attention", ["kernel", "composed"])
+def test_dense_block_keeps_what_its_attention_made(attention, monkeypatch,
+                                                   capsys):
+    """dense_loss with every default (no flash= plan, the benchmark's
+    call): a block keeps `qkv` and ONE copy of the attention output. Where
+    the registry op takes its Pallas arm (forced as test_chip_compile
+    forces it; the kernel runs in the interpreter here) that copy is the
+    kernel's `flash_out`, with `flash_lse` beside it, so the backward
+    kernels start without a second forward kernel; on the composed path
+    it is `attn_out` and there is no lse. Never both. Loss and gradients
+    are remat=False's, bitwise, either way, and the kernel path traces as
+    many kernels as remat=False does (forward, dq, dkv: no replay)."""
+    from paddle_tpu.ops import registry
+    monkeypatch.setattr(registry, "_on_tpu", lambda: attention == "kernel")
+    seq, batch = 128, 2             # the kernel's gate wants S % 128 == 0
+    cfg = G.GPTConfig(vocab_size=64, hidden_size=32, num_layers=2,
+                      num_heads=4, max_seq_len=seq, dtype=jnp.float32)
+    nl, nh, hd, H = (cfg.num_layers, cfg.num_heads, cfg.head_dim,
+                     cfg.hidden_size)
+    tokens, labels = _data(batch=batch, seq=seq, vocab=cfg.vocab_size)
+    p = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
+
+    def loss(p, **kw):
+        return G.dense_loss(p, tokens, labels, cfg, **kw)
+
+    jax.ad_checkpoint.print_saved_residuals(loss, p)
+    kept = [line.split()[0] for line in capsys.readouterr().out.splitlines()
+            if "output of scan" in line]
+    shapes = {"qkv": f"f32[{nl},{batch},{seq},{3 * H}]",
+              "attn_out": f"f32[{nl},{batch},{seq},{nh},{hd}]",
+              # head_dim 8 is not lane-native: the kernel's own layout
+              "flash_out": f"f32[{nl},{batch * nh},{seq},{hd}]",
+              "flash_lse": f"f32[{nl},{batch * nh},{seq}]",
+              "carry": f"f32[{nl},{batch},{seq},{H}]"}
+    want = (("qkv", "flash_out", "flash_lse") if attention == "kernel"
+            else ("qkv", "attn_out"))
+    assert sorted(kept) == sorted(shapes[n] for n in want + ("carry",)), kept
+
+    vg = jax.value_and_grad(loss)
+    vg_plain = jax.value_and_grad(lambda p: loss(p, remat=False))
+    n_kernels = pallas_call_count(vg, p)
+    assert n_kernels == (pallas_call_count(vg_plain, p)
+                         if attention == "kernel" else 0), n_kernels
+    # one primitive at a time: what the CPU compiler would fuse differently
+    # in the two programs is no part of the comparison
+    with jax.disable_jit():
+        (l, g), (l0, g0) = vg(p), vg_plain(p)
+    assert float(l) == float(l0)
+    eq = jax.tree.map(lambda a, b: bool((a == b).all()), g, g0)
+    assert all(jax.tree.leaves(eq)), eq
+
+
 # ---------------------------------------------------------------------------
 # Compose matrix: sp/ring × zero1 × {1F1B, ZBH1, VPP} × fp8
 # ---------------------------------------------------------------------------
