@@ -9,12 +9,16 @@ admission and dispatch spans, a span that takes attributes until it
 closes, and the two readers of span attributes."""
 
 import ast
+import functools
 import glob
 import gzip
+import importlib
 import json
 import os
 import re
+import shutil
 import sys
+import types
 
 import jax
 import jax.numpy as jnp
@@ -681,190 +685,233 @@ def test_no_scope_or_serving_span_is_a_free_string():
 
 
 # -- the benchmark's readers ------------------------------------------------
-SPEC = harness.load_spec()
-NEW_READERS = {"span_ms", "idle_unattributed", "scope_share", "kernel_hbm",
-               "exposed_collective", "scope_collective"}
-NEW = [m["name"] for m in SPEC["per_layer"]
-       if harness.load_json("metrics", m["name"] + ".json")["reader"]
-       in NEW_READERS]
-
-
-def test_the_new_metrics_are_the_issues_and_two_more():
-    # ISSUE 26's thirty, plus the hybrid cell's gradient-sync share and
-    # the pp axis's time in flight (PERF.md, PR 26: why they were needed);
-    # ISSUE 28's cell reads sixteen through the same readers: chat's
-    # eleven, two more shares (the mixer's projections, its state path)
-    # and three rooflines; ISSUE 36's reads nineteen: chat's eleven, four
-    # more shares (the experts, the routing, the delta rule's state path
-    # and its projections) and four rooflines, three of them named for
-    # kernels only this cell runs and so without a suffix
-    assert len(NEW) == 67
-    assert len([n for n in NEW if n.endswith(".docs")]) == 11
-    assert len([n for n in NEW if n.endswith(".chat")]) == 11
-    assert len([n for n in NEW if n.endswith(".h1chat")]) == 16
-    assert len([n for n in NEW if _suffix(n) == ".q3nchat"]) == 19
-
-
-# a metric's cell group: its suffix, or the group of the only cell that
-# runs the kernel it is named for
-UNSUFFIXED = {"moe_grouped_ffn_roofline": ".q3nchat",
-              "gdn_state_update_roofline": ".q3nchat",
-              "gdn_chunk_scan_roofline": ".q3nchat"}
-
-
-def _suffix(name):
-    return UNSUFFIXED.get(name) or "." + name.rsplit(".", 1)[1]
-
-
-# ISSUE 38's readers of span attributes, and the metrics that use them: a
-# metric shared by cells that move the same end-to-end metric is one entry
-SCHED_READERS = {"span_attr", "span_attr_ratio"}
-SCHED = [m for m in SPEC["per_layer"]
-         if harness.load_json("metrics", m["name"] + ".json")["reader"]
-         in SCHED_READERS]
-SCHED_CASES = [(m["name"], cell) for m in SCHED for cell in m["workloads"]]
+# Which per-layer entries exist, how many, what they are called and which
+# cells list them is BENCHMARK.json's alone. This section knows the RULES an
+# entry is held to and finds the entries as the harness does, through
+# `harness.metrics_of`. A slice recorded on the chip states its cell and
+# keys what it recorded by what was READ (`_signature`), so an entry that is
+# renamed, merged or shared by more cells finds its value again.
+SLICE_FILES = sorted(map(os.path.basename, glob.glob(
+    os.path.join(DATA, "ptrace-*.json.gz"))))
 # what each span may carry, by the tuple the call site takes it from
 SPAN_ATTRS = {SERVING_SPANS.dispatch: DISPATCH_ATTRS + SSM_DISPATCH_ATTRS,
               SERVING_SPANS.fetch: MOE_FETCH_ATTRS,
               SERVING_SPANS.admission: ADMISSION_ATTRS,
               REQUEST_SPANS.first_token: FIRST_TOKEN_ATTRS,
               REQUEST_SPANS.end: REQUEST_END_ATTRS}
+# PR 38 brought the request spans, and these attributes of older spans
+PR38_ATTRS = {"n_starved", "pre_tokens", "budget"} | set(ADMISSION_ATTRS)
 
 
-@pytest.mark.parametrize("name", NEW + [m["name"] for m in SCHED])
+@functools.lru_cache(maxsize=None)
+def _slice(name):
+    with gzip.open(os.path.join(DATA, name), "rt") as f:
+        return json.load(f)     # shared: nobody writes to it
+
+
+def _meta(name):
+    return harness.load_json("metrics", name + ".json")
+
+
+def _signature(name):
+    """What an entry reads, whatever it is called."""
+    meta = _meta(name)
+    return json.dumps([meta["reader"], meta.get("params", {})],
+                      sort_keys=True)
+
+
+def _form(name):
+    """The form of the trace an entry's reader reads, by what the reader's
+    module imports: "program_trace" (the program's own names), else
+    "trace_reduce" (the reduced form alone), else None (no trace)."""
+    reader = importlib.import_module(
+        f"chipbench.readers.{_meta(name)['reader']}")
+    return next((f for f in ("program_trace", "trace_reduce")
+                 if hasattr(reader, f)), None)
+
+
+def _named(params):
+    """The spans and the span attributes a metric file names."""
+    spans = [params.get(k, []) for k in ("spans", "span", "until", "per")]
+    return ({s for v in spans for s in ([v] if isinstance(v, str) else v)},
+            {params[k] for k in ("attr", "num", "den") if params.get(k)}
+            | set(params.get("attrs", [])))
+
+
+def traced(spec, cell):
+    """The entries a cell reports whose reader reads a trace."""
+    return [m for m in harness.metrics_of(spec, cell, "per_layer")
+            if _form(m["name"])]
+
+
+def slices_of(cell):
+    """The slices recorded for a cell: none, one or several."""
+    return [s for s in SLICE_FILES if _slice(s)["note"]["cell"] == cell]
+
+
+def cases_of(spec):
+    """(entry, cell, slice): every traced entry of every cell on every
+    slice of that cell. An entry without a list, one that three cells
+    share, a suffix nobody has seen and a cell without a slice (it has no
+    cases) are all legal."""
+    return [(m, cell["name"], s) for cell in spec["workloads"]
+            for s in slices_of(cell["name"]) for m in traced(spec, cell)]
+
+
+def _id(v):
+    return v["name"] if isinstance(v, dict) else v.split(".json")[0]
+
+
+SPEC = harness.load_spec()
+CASES = cases_of(SPEC)
+
+
+@functools.lru_cache(maxsize=None)
+def _run(slice_name, names="all"):
+    """A run as the readers see it: the slice as the program trace, and
+    its reduced form as the harness's own. With `names` "none" it is the
+    run of a program that names nothing: the benchmark's own spans, paths
+    without scopes. With "pr37" it is the run of PR 38's parent: no
+    request span, and no older span has the attributes PR 38 gave it."""
+    pt = _slice(slice_name)
+    if names == "none":
+        pt = {"devices": {k: [e[:3] + [re.sub(r"[^/()]+", "x", e[3])]
+                              for e in v] for k, v in pt["devices"].items()},
+              "async": {k: [e[:3] + [""] for e in v]
+                        for k, v in pt["async"].items()},
+              "host": [h for h in pt["host"] if h[0] in harness.HOST_SPANS]}
+    elif names == "pr37":
+        pt = dict(pt, host=[
+            h[:3] + [{k: v for k, v in h[3].items() if k not in PR38_ATTRS}]
+            for h in pt["host"] if h[0] not in set(REQUEST_SPANS)])
+    reduced = {"host": [h[:3] for h in pt["host"]
+                        if h[0] in harness.HOST_SPANS]}
+    for key in ("devices", "async"):
+        reduced[key] = {k: [e[:3] for e in v] for k, v in pt[key].items()}
+    return {"program_trace": pt, "trace": reduced, "facts": {}, "devices": [
+        types.SimpleNamespace(device_kind="TPU v5 lite")]}
+
+
+# The rules. The rehearsal at the end of the section asks them again of
+# tables that are not the benchmark's, by calling these same functions.
+@pytest.mark.parametrize("name", [m["name"] for m in SPEC["per_layer"]
+                                  if _form(m["name"]) == "program_trace"])
 def test_metric_files_name_only_what_the_program_names(name):
     """A rename in the program fails here instead of reading 0 there."""
-    params = harness.load_json("metrics", name + ".json")["params"]
-    spans = set(SERVING_SPANS) | set(REQUEST_SPANS)
-    for key in ("spans", "span", "until", "per"):
-        value = params.get(key, [])
-        for s in [value] if isinstance(value, str) else value:
-            assert s in spans, (name, s)
+    params = _meta(name).get("params", {})
+    spans, attrs = _named(params)
+    assert spans <= set(SERVING_SPANS) | set(REQUEST_SPANS)
     for key in ("scopes", "of"):
         assert set(params.get(key, [])) <= set(SCOPES), name
     if "kernel" in params:
         assert params["kernel"] in KERNELS
-    carried = SPAN_ATTRS.get(params.get("span"), ())
-    named = [params[k] for k in ("attr", "num", "den") if params.get(k)]
-    assert set(named + params.get("attrs", [])) <= set(carried), name
+    assert attrs <= set(SPAN_ATTRS.get(params.get("span"), ()))
     if params.get("num") == "blocked":
         assert params["num_equals"] in tuple(ADMIT_BLOCKED)
 
 
-def test_the_scheduler_metrics_are_the_seven_the_cap_left_room_for():
-    """ISSUE 38 asked for fourteen entries, a cell each; `per_layer` may
-    hold 128 and held 121, so docs and Falcon-H1 (both move `serve_tok_s`)
-    share an entry, and chat's queue and prefill parts and its slot fill
-    wait for the `benchmark` PR that retires duplicates (PERF.md)."""
-    assert len(SPEC["per_layer"]) == 128
-    assert sorted(m["name"] for m in SCHED) == [
-        "admit_blocked_pct.docs", "attn_slot_fill_pct.docs",
-        "prefill_starved_pct.serve", "ttft_land_p50_ms.chat",
-        "ttft_land_p50_ms.serve", "ttft_prefill_p50_ms.serve",
-        "ttft_queue_p50_ms.serve"]
-    assert all(m["source"] == "program_span" for m in SCHED)
-    assert len(SCHED_CASES) == 11
+@pytest.mark.parametrize("entry,cell,slice_name", CASES, ids=_id)
+def test_reader_on_a_slice_recorded_on_the_chip(entry, cell, slice_name):
+    """Where the slice recorded what this entry reads, the value the chip
+    read; in range whatever it reads (None where the slice holds nothing
+    of it: a slice of host spans has no device operations)."""
+    value = harness.read_metric(entry["name"], _run(slice_name))
+    recorded = _slice(slice_name)["note"]["expected"].get(
+        _signature(entry["name"]))
+    if recorded is not None:
+        assert value == pytest.approx(recorded, rel=1e-6)
+    if value is not None:
+        assert 0.0 <= value and (entry["unit"] != "%" or value <= 100.0)
 
 
-def test_the_share_metrics_of_a_cell_divide_its_program():
-    """Each cell's shares name disjoint scopes that together are `of`, so
-    the shares and the unscoped share sum to 100."""
-    for suffix in (".docs", ".chat", ".train", ".h1chat", ".q3nchat"):
-        shares = [harness.load_json("metrics", n + ".json")["params"]
-                  for n in NEW if n.endswith("_time_pct" + suffix)]
-        of = shares[0]["of"]
-        assert all(p["of"] == of for p in shares)
-        named = [s for p in shares for s in p["scopes"]]
-        assert sorted(named) == sorted(of)
-        assert sum(1 for p in shares if not p["scopes"]) == 1
+@pytest.mark.parametrize("entry,cell,slice_name", CASES, ids=_id)
+def test_reader_returns_nothing_without_the_programs_names(entry, cell,
+                                                           slice_name):
+    """Two parents. One names nothing: a reader of the program's names
+    reads None there, a reader of the reduced form alone (the exposed
+    collectives) what it reads anyway. The other is PR 38's: an entry that
+    names a request span or an attribute PR 38 brought reads None there,
+    any other (the slot fill, whose attributes PR 29 brought) what it
+    reads anyway."""
+    name = entry["name"]
+    spans, attrs = _named(_meta(name).get("params", {}))
+    whole = harness.read_metric(name, _run(slice_name))
+    assert harness.read_metric(name, _run(slice_name, "none")) == (
+        None if _form(name) == "program_trace" else whole)
+    assert harness.read_metric(name, _run(slice_name, "pr37")) == (
+        None if spans & set(REQUEST_SPANS) or attrs & PR38_ATTRS else whole)
 
 
-def _slice(name):
-    with gzip.open(os.path.join(DATA, name), "rt") as f:
-        return json.load(f)
+@pytest.mark.parametrize("cell", [c["name"] for c in SPEC["workloads"]])
+def test_the_share_metrics_of_a_cell_divide_its_program(cell, spec=SPEC):
+    """A cell's shares have one `of` and name disjoint scopes of it, one
+    of them none (the unscoped share); what they leave of `of` is named by
+    a share of another cell (the gradient sync's scopes, which the hybrid
+    cell alone runs). On a slice with device operations they sum to 100."""
+    def shares(entries):
+        metas = [_meta(m["name"]) for m in entries]
+        return [m for m in metas if m["reader"] == "scope_share"]
+    mine = shares(traced(spec, harness.cell_of(spec, cell)))
+    if not mine:
+        return
+    of = mine[0]["params"]["of"]
+    named = [s for m in mine for s in m["params"]["scopes"]]
+    assert all(m["params"]["of"] == of for m in mine)
+    assert len(set(named)) == len(named)
+    assert sum(1 for m in mine if not m["params"]["scopes"]) == 1
+    assert set(named) <= set(of) <= {
+        s for m in shares(spec["per_layer"]) if m["params"]["of"] == of
+        for s in m["params"]["scopes"]}
+    for s in slices_of(cell):
+        if _slice(s)["devices"]:
+            assert sum(harness.read_metric(m["name"], _run(s))
+                       for m in mine) == pytest.approx(100.0, abs=1.0), s
 
 
-class _Dev:
-    device_kind = "TPU v5 lite"
+def test_what_no_slice_has_recorded_yet():
+    """Fails on nothing: it prints (`-s`) what a later PR could record. An
+    entry without a recording is still read and range-checked above."""
+    for cell in SPEC["workloads"]:
+        slices = slices_of(cell["name"])
+        have = {sig for s in slices for sig in _slice(s)["note"]["expected"]}
+        print(f"{cell['name']}: {len(slices)} slices, no recording of",
+              [m["name"] for m in traced(SPEC, cell)
+               if _signature(m["name"]) not in have])
 
 
-def _run_of(pt):
-    """A run as the readers see it: the slice as the program trace, and
-    its reduced form as the harness's own."""
-    reduced = {"devices": {k: [e[:3] for e in v]
-                           for k, v in pt["devices"].items()},
-               "async": {k: [e[:3] for e in v]
-                         for k, v in pt["async"].items()},
-               "host": [h[:3] for h in pt["host"]
-                        if h[0] in harness.HOST_SPANS]}
-    return {"program_trace": pt, "trace": reduced, "facts": {},
-            "devices": [_Dev()]}
+def test_the_table_fits_its_cap_and_cells_and_trace_readers_meet():
+    """All that is pinned of the table."""
+    n = len(SPEC["per_layer"])
+    assert n <= 128, f"per_layer holds {n} of 128 entries: {128 - n} free"
+    reported = [{m["name"] for m in traced(SPEC, c)}
+                for c in SPEC["workloads"]]
+    assert all(reported)                    # every cell reads a trace
+    assert set().union(*reported) == {      # and every such entry has a cell
+        m["name"] for m in SPEC["per_layer"] if _form(m["name"])}
 
 
-SLICES = {".docs": "ptrace-v5e-serve-docs-slice.json.gz",
-          ".chat": "ptrace-v5e-serve-chat-slice.json.gz",
-          ".train": "ptrace-v5e-train-hybrid-slice.json.gz",
-          ".h1chat": "ptrace-v5e-h1chat.json.gz",
-          ".q3nchat": "ptrace-v5e-q3nchat.json.gz"}
+@pytest.mark.parametrize("slice_name", SLICE_FILES, ids=_id)
+def test_a_slice_keys_what_it_recorded_by_what_was_read(slice_name):
+    """In `_signature`'s form, never by an entry's name: `recorded_as`
+    keeps those for a reader of the file, and nobody looks them up."""
+    note = _slice(slice_name)["note"]
+    assert isinstance(note["cell"], str) and note["expected"]
+    for sig in note["expected"]:
+        reader, params = json.loads(sig)
+        assert sig == json.dumps([reader, params], sort_keys=True)
 
 
-@pytest.mark.parametrize("name", NEW)
-def test_reader_on_a_slice_recorded_on_the_chip(name):
-    pt = _slice(SLICES[_suffix(name)])
-    value = harness.read_metric(name, _run_of(pt))
-    assert value is not None and 0.0 <= value
-    if name.split(".")[0].endswith(("_pct", "_roofline")):
-        assert value <= 100.0
-    expected = pt["note"]["expected"]
-    assert value == pytest.approx(expected[name], rel=1e-6), name
-
-
-@pytest.mark.parametrize("suffix", sorted(SLICES))
-def test_the_shares_of_a_recorded_slice_sum_to_100(suffix):
-    run = _run_of(_slice(SLICES[suffix]))
-    shares = [harness.read_metric(n, run) for n in NEW
-              if n.endswith("_time_pct" + suffix)]
-    assert sum(shares) == pytest.approx(100.0, abs=1.0)
-
-
-@pytest.mark.parametrize("name", NEW)
-def test_reader_returns_nothing_without_the_programs_names(name):
-    """The parent commit's program: no spans of its own, no scopes."""
-    pt = _slice(SLICES[_suffix(name)])
-    bare = {"devices": {k: [e[:3] + [re.sub(r"[^/()]+", "x", e[3])]
-                            for e in v] for k, v in pt["devices"].items()},
-            "async": {k: [e[:3] + [""] for e in v]
-                      for k, v in pt["async"].items()},
-            "host": [h for h in pt["host"] if h[0] in harness.HOST_SPANS]}
-    value = harness.read_metric(name, _run_of(bare))
-    if name.startswith("exposed_collective_pct"):
-        assert value is not None    # reads the reduced form alone
-    else:
-        assert value is None
-
-
-SCHED_SLICES = {"serve-1p3b-docs": "ptrace-v5e-sched-docs.json.gz",
-                "serve-1p3b-chat": "ptrace-v5e-sched-chat.json.gz",
-                "serve-falconh1-34b-chat": "ptrace-v5e-sched-h1chat.json.gz"}
-
-
-@pytest.mark.parametrize("name,cell", SCHED_CASES)
-def test_scheduler_reader_on_a_slice_recorded_on_the_chip(name, cell):
-    pt = _slice(SCHED_SLICES[cell])
-    value = harness.read_metric(name, _run_of(pt))
-    assert value is not None and 0.0 <= value
-    if name.split(".")[0].endswith("_pct"):
-        assert value <= 100.0
-    assert value == pytest.approx(pt["note"]["expected"][name], rel=1e-6)
-
-
-@pytest.mark.parametrize("cell", sorted(SCHED_SLICES))
-def test_a_recorded_first_token_is_four_parts_and_one_landing_step(cell):
+@pytest.mark.parametrize("slice_name", [
+    s for s in SLICE_FILES
+    if any(h[0] == REQUEST_SPANS.first_token for h in _slice(s)["host"])],
+    ids=_id)
+def test_a_recorded_first_token_is_four_parts_and_one_landing_step(
+        slice_name):
     """On the chip as on the CPU: every first token of the slice has four
     non-negative parts, was handed over one engine step after the
     dispatch that sampled it (or in the same call, by a tail settle), and
     the medians of the parts add up to about the median of their sum."""
-    pt = _slice(SCHED_SLICES[cell])
+    pt = _slice(slice_name)
     ft = [h[3] for h in pt["host"] if h[0] == REQUEST_SPANS.first_token]
     assert len(ft) >= 5 and all(tuple(a) == FIRST_TOKEN_ATTRS for a in ft)
     parts = ("queue_us", "wait_us", "prefill_us", "land_us")
@@ -881,25 +928,102 @@ def test_a_recorded_first_token_is_four_parts_and_one_landing_step(cell):
     assert all(a["status"] == "ok" and a["out_tokens"] >= 1 for a in ends)
 
 
-@pytest.mark.parametrize("name,cell", SCHED_CASES)
-def test_scheduler_reader_returns_nothing_on_the_parents_trace(name, cell):
-    """The parent opens `serving_admission` and the dispatch span without
-    the new attributes and no request span at all: every new metric but
-    the slot fill (whose two attributes PR 29 brought) reads None there,
-    and all of them on a trace with none of the program's names."""
-    pt = _slice(SCHED_SLICES[cell])
-    old = set(DISPATCH_ATTRS) - {"n_starved", "pre_tokens", "budget"}
-    parent = dict(pt, host=[
-        [h[0], h[1], h[2], {k: v for k, v in h[3].items() if k in old}]
-        for h in pt["host"] if h[0] not in set(REQUEST_SPANS)])
-    value = harness.read_metric(name, _run_of(parent))
-    if name.startswith("attn_slot_fill_pct"):
-        assert value == pytest.approx(pt["note"]["expected"][name])
-    else:
-        assert value is None
-    bare = dict(pt, host=[h for h in pt["host"]
-                          if h[0] in harness.HOST_SPANS])
-    assert harness.read_metric(name, _run_of(bare)) is None
+# The rehearsal: the rules on the tables the next two PRs bring. PR 41's (a
+# `benchmark` PR: entries out, what reads the same for all the cells that
+# report what it moves merged into one entry without a list, a list
+# dropped) and a `model_config` PR's (an entry with a suffix and a metric
+# file nobody has seen). Each edit picks its entries by what they read, so
+# on the table those PRs leave it still finds some, or nothing to do.
+def _put(spec, entry, meta):
+    with open(os.path.join(harness.HERE, "metrics",
+                           entry["name"] + ".json"), "w") as f:
+        json.dump(dict(meta, name=entry["name"]), f)
+    spec["per_layer"].append(entry)
+
+
+def _out(spec, names):
+    spec["per_layer"] = [m for m in spec["per_layer"]
+                         if m["name"] not in names]
+    return names
+
+
+def _two_are_taken_out(spec):
+    return _out(spec, [m["name"] for c in spec["workloads"]
+                       for m in traced(spec, c)
+                       if _meta(m["name"])["reader"] != "scope_share"][:2])
+
+
+def _what_reads_the_same_becomes_one_listless_entry(spec):
+    groups = {}
+    for m in spec["per_layer"]:
+        if "workloads" in m and _form(m["name"]):
+            groups.setdefault((_signature(m["name"]), m["moves"]),
+                              []).append(m)
+    for (_, moves), ms in groups.items():
+        cells = {c["name"] for c in spec["workloads"] if moves in {
+            e["name"] for e in harness.metrics_of(spec, c, "end_to_end")}}
+        if len(ms) > 1 and {w for m in ms for w in m["workloads"]} == cells:
+            _out(spec, [m["name"] for m in ms])
+            entry = dict(ms[0], name=ms[0]["name"].split(".")[0] + ".merged")
+            del entry["workloads"]
+            _put(spec, entry, _meta(ms[0]["name"]))
+
+
+def _a_span_attr_entry_loses_its_list(spec):
+    next((m for m in spec["per_layer"] if "workloads" in m
+          and _meta(m["name"])["reader"] == "span_attr"),
+         {}).pop("workloads", None)
+
+
+def _a_new_suffix_and_file_are_added(spec):
+    cell = next(c for c in spec["workloads"] if slices_of(c["name"]))
+    shared = {"unit": "tokens", "layer": "serving engine", "moves":
+              harness.metrics_of(spec, cell, "end_to_end")[0]["name"]}
+    _put(spec, dict(shared, name="first_prompt_len_mean.mla", better="lower",
+                    source="program_span", workloads=[cell["name"]]),
+         dict(shared, reader="span_attr", params={
+             "span": REQUEST_SPANS.first_token, "attrs": ["prompt_len"],
+             "stat": "mean"}))
+
+
+EDITS = [_two_are_taken_out, _what_reads_the_same_becomes_one_listless_entry,
+         _a_span_attr_entry_loses_its_list, _a_new_suffix_and_file_are_added]
+
+
+@pytest.mark.parametrize("edits", [[e] for e in EDITS] + [EDITS], ids=[
+    e.__name__[1:] for e in EDITS] + ["all_four"])
+def test_the_rules_hold_on_the_tables_the_next_prs_bring(edits, tmp_path,
+                                                         monkeypatch):
+    """On a copy (the table as `load_spec` hands it out anew, the metric
+    files in `tmp_path` with `harness.HERE` pointed there; nothing of the
+    benchmark is edited): the cases are built without error, every new
+    entry has one, every rule holds of every case, and whatever a slice
+    recorded that was found before is found again, under whatever name,
+    but for what was taken out."""
+    shutil.copytree(os.path.join(harness.HERE, "metrics"),
+                    tmp_path / "metrics")
+    monkeypatch.setattr(harness, "HERE", str(tmp_path))
+
+    def found(spec):
+        return {(cell, s, _signature(m["name"])): m["name"]
+                for m, cell, s in cases_of(spec) if _signature(m["name"])
+                in _slice(s)["note"]["expected"]}
+    spec = harness.load_spec()
+    before, old = found(spec), {m["name"] for m in spec["per_layer"]}
+    gone = [n for edit in edits for n in edit(spec) or []]
+    cases = cases_of(spec)
+    assert {m["name"] for m in spec["per_layer"]} - old <= {
+        m["name"] for m, _, _ in cases}
+    for m, cell, s in cases:
+        if _form(m["name"]) == "program_trace":
+            test_metric_files_name_only_what_the_program_names(m["name"])
+        test_reader_on_a_slice_recorded_on_the_chip(m, cell, s)
+        test_reader_returns_nothing_without_the_programs_names(m, cell, s)
+    for cell in spec["workloads"]:
+        test_the_share_metrics_of_a_cell_divide_its_program(cell["name"],
+                                                            spec)
+    assert set(found(spec)) >= {k for k, name in before.items()
+                                if name not in gone}
 
 
 def _hand_trace():
