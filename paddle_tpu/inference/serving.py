@@ -357,14 +357,25 @@ def _block_math(p, x, attn, cfg, mp_axis=None):
 def _qkv(p, x, cfg, mp_axis=None):
     """Column-parallel under TP: the local qkv_w shard holds COMPLETE
     heads (head-major [H, heads*3*D] channel layout), so the reshape uses
-    the LOCAL head count."""
+    the LOCAL head count.
+
+    The barrier keeps the product a 2-D GEMM that reads its layer out of
+    the stacked weight in place. Without it the TPU compiler folds the
+    reshape below INTO the product, takes the weight transposed for that
+    ([heads, 3*D, H]), and so slices every layer's [H, 3H] out of the
+    stack and copies it to the other layout before each GEMM (at K > 1
+    the whole stack once a step): 12% of a GPT-1.3B step's device time.
+    q, k and v are lane slices of the result's [.., heads, 3*D] view; the
+    [.., heads, 3, D] view indexed along its 3 pads that axis to a tile
+    and cost 5% of the step again (PERF.md, PR 43)."""
     B, S, _ = x.shape
     h = G._ln(x, p["ln1_g"], p["ln1_b"])
-    qkv = (_mm(h.astype(cfg.dtype), p, "qkv_w", cfg)
-           + p["qkv_b"].astype(cfg.dtype))
-    heads = qkv.shape[-1] // (3 * cfg.head_dim)
-    qkv = qkv.reshape(B, S, heads, 3, cfg.head_dim)
-    return qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2]
+    qkv = lax.optimization_barrier(
+        _mm(h.astype(cfg.dtype), p, "qkv_w", cfg)
+        + p["qkv_b"].astype(cfg.dtype))
+    D = cfg.head_dim
+    qkv = qkv.reshape(B, S, -1, 3 * D)      # a head's columns: q | k | v
+    return qkv[..., :D], qkv[..., D:2 * D], qkv[..., 2 * D:]
 
 
 @jax.named_scope(SCOPES.head)

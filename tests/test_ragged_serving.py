@@ -4,6 +4,8 @@ contract, the one engine however it is asked for, pool-pressure
 scheduling, the quantized KV pool (capacity + determinism), TP int8
 weights, and the telemetry-driven adaptive prefill/decode mix."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -941,3 +943,96 @@ def test_dispatch_metrics_exported(params):
     assert "dispatches_total" not in text   # no reader: gone in PR 38
     assert eng._prom.get("dispatches_per_step") == \
         eng.dispatches / eng.engine_steps
+
+
+# ---------------------------------------------------------------------------
+# `_qkv` (ISSUE 43): the product stays a 2-D GEMM behind a barrier; what it
+# returns is what the parent's formula returned
+# ---------------------------------------------------------------------------
+def parent_qkv(p, x, cfg, mp_axis=None):
+    """`serving._qkv` as it stood before the barrier, written out: the
+    product reshaped to [B, S, heads, 3, D] and indexed."""
+    from paddle_tpu.inference.serving import _mm
+    B, S, _ = x.shape
+    h = G._ln(x, p["ln1_g"], p["ln1_b"])
+    qkv = (_mm(h.astype(cfg.dtype), p, "qkv_w", cfg)
+           + p["qkv_b"].astype(cfg.dtype))
+    heads = qkv.shape[-1] // (3 * cfg.head_dim)
+    qkv = qkv.reshape(B, S, heads, 3, cfg.head_dim)
+    return qkv[:, :, :, 0], qkv[:, :, :, 1], qkv[:, :, :, 2]
+
+
+@pytest.mark.parametrize("how", ["dense", "int8_weights", "mp2"])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_qkv_is_the_parents_formula(dtype, how):
+    from jax.sharding import Mesh, PartitionSpec as P
+    from paddle_tpu.inference.serving import _qkv, quantize_serving_params
+    from paddle_tpu.utils import shard_map
+    cfg = dataclasses.replace(CFG, dtype=jnp.dtype(dtype),
+                              param_dtype=jnp.dtype(dtype))
+    params = G.init_hybrid_params(cfg, jax.random.PRNGKey(3))
+    # a bias and gains that are not their initial 0 and 1
+    keys = jax.random.split(jax.random.PRNGKey(4), 3)
+    blocks = dict(params["blocks"])
+    for k, name in zip(keys, ("qkv_b", "ln1_g", "ln1_b")):
+        blocks[name] = (blocks[name] + 0.3 * jax.random.normal(
+            k, blocks[name].shape)).astype(blocks[name].dtype)
+    params = dict(params, blocks=blocks)
+    if how == "int8_weights":
+        params = quantize_serving_params(params)
+    p = jax.tree.map(lambda a: a[1], params["blocks"])      # one layer
+    x = jax.random.normal(jax.random.PRNGKey(5), (1, 24, cfg.hidden_size),
+                          jnp.float32).astype(cfg.dtype)
+    want = jax.jit(lambda p, x: parent_qkv(p, x, cfg))(p, x)
+    if how == "mp2":
+        # column-parallel: each rank holds two whole heads of the four
+        mesh = Mesh(np.array(jax.devices()[:2]), ("mp",))
+        cols = {"qkv_w": P(None, "mp"), "qkv_b": P("mp")}
+        heads = P(None, None, "mp", None)
+        got = jax.jit(shard_map(
+            lambda p, x: _qkv(p, x, cfg, "mp"), mesh=mesh,
+            in_specs=({k: cols.get(k, P()) for k in p}, P()),
+            out_specs=(heads, heads, heads)))(p, x)
+    else:
+        got = jax.jit(lambda p, x: _qkv(p, x, cfg))(p, x)
+    for name, g, w in zip("qkv", got, want):
+        assert g.shape == (1, 24, cfg.num_heads, cfg.head_dim), name
+        assert g.dtype == w.dtype == cfg.dtype, name
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      np.asarray(w, np.float32), name)
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["dense", "int8_weights"])
+def test_served_tokens_are_the_parents(params, monkeypatch, int8):
+    """Prompts longer and shorter than a chunk, decode bursts, sampling at
+    a temperature from a fixed seed: token for token what the engine
+    serves with the parent's formula in `_qkv`'s place. The weights are
+    sharpened until the tokens feel their attention: with k and v
+    exchanged the same engine serves other tokens."""
+    from paddle_tpu.inference import serving
+    blocks = dict(params["blocks"])
+    blocks["qkv_w"] = blocks["qkv_w"] * 8.0
+    blocks["qkv_b"] = 0.5 * jax.random.normal(jax.random.PRNGKey(1),
+                                              blocks["qkv_b"].shape)
+    sharp = dict(params, blocks=blocks)
+    rng = np.random.RandomState(43)
+    prompts = [rng.randint(0, CFG.vocab_size, (n,)) for n in (19, 5, 11)]
+    news, temps = [9, 12, 7], [0.0, 0.8, 1.3]
+
+    def served():
+        eng = mk(sharp, seed=43, decode_burst=4, int8=int8)
+        rids = [eng.add_request(p, n, temperature=t)
+                for p, n, t in zip(prompts, news, temps)]
+        res = eng.run()
+        return [res[r] for r in rids]
+
+    def exchanged(p, x, cfg, mp_axis=None):
+        q, k, v = parent_qkv(p, x, cfg)
+        return q, v, k
+
+    change = served()
+    assert [len(o) for o in change] == news
+    monkeypatch.setattr(serving, "_qkv", parent_qkv)
+    assert served() == change
+    monkeypatch.setattr(serving, "_qkv", exchanged)
+    assert served() != change
