@@ -51,9 +51,7 @@ def make_copy(dst):
             if m["name"] == moved:
                 m["workloads"].append(cell["name"])
         for m in spec["per_layer"]:
-            if m["name"].endswith(GROUP[cell["name"]]) and \
-                    (m["name"] != "collective_pct.train"
-                     or cell["chips"] == 4):
+            if m["name"].endswith(GROUP[cell["name"]]):
                 m["workloads"].append(cell["name"])
     with open(os.path.join(dst, "BENCHMARK.json"), "w") as f:
         json.dump(spec, f, indent=1)

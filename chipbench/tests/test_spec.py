@@ -7,11 +7,25 @@ import re
 import subprocess
 import sys
 
+import pytest
+
 from chipbench import harness
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SPEC = harness.load_spec()
+CELLS = [c["name"] for c in SPEC["workloads"]]
+EMPTY_RUN = {"facts": {}, "trace": None}
+
+
+def _meta(name):
+    return harness.load_json("metrics", name + ".json")
+
+
+def _reported(cell, group="per_layer"):
+    """The entries of a group that a cell reports, found as the harness
+    does."""
+    return harness.metrics_of(SPEC, harness.cell_of(SPEC, cell), group)
 
 
 def test_keys_names_and_units_are_legal():
@@ -85,27 +99,72 @@ def test_widths_are_the_published_ones():
 
 
 def test_every_per_layer_metric_has_its_file_and_reader():
+    """An entry's cells are the ones it lists or, without a list, every
+    cell that reports the end-to-end metric it moves; a listed cell
+    reports that metric too, and the harness hands the entry to exactly
+    those cells."""
     e2e = {m["name"]: m for m in SPEC["end_to_end"]}
-    cells = {c["name"] for c in SPEC["workloads"]}
+    reported = {c: _reported(c) for c in CELLS}
     for m in SPEC["per_layer"]:
-        meta = harness.load_json("metrics", m["name"] + ".json")
+        meta = _meta(m["name"])
         assert (meta["layer"], meta["unit"], meta["moves"]) == \
             (m["layer"], m["unit"], m["moves"])
         reader = importlib.import_module(
             f"chipbench.readers.{meta['reader']}")
         assert callable(reader.read)
-        assert set(m["workloads"]) <= cells
-        # each listed cell reports the end-to-end metric this one moves
-        moved = e2e[m["moves"]]
-        assert set(m["workloads"]) <= set(moved.get("workloads", cells))
+        report_moved = set(e2e[m["moves"]].get("workloads", CELLS))
+        cells = set(m.get("workloads", report_moved))
+        assert cells and cells <= report_moved <= set(CELLS), m["name"]
+        assert cells == {c for c in CELLS if m in reported[c]}, m["name"]
     files = {f[:-5] for f in os.listdir(os.path.join(harness.HERE, "metrics"))}
     assert files == {m["name"] for m in SPEC["per_layer"]}
 
 
 def test_a_reader_with_nothing_to_read_returns_nothing():
-    run = {"facts": {}, "trace": None}
     for m in SPEC["per_layer"]:
-        assert harness.read_metric(m["name"], run) is None
+        assert harness.read_metric(m["name"], EMPTY_RUN) is None
+
+
+# What keeps the table from filling up by copy: an entry that every cell
+# reporting what it moves can read carries no list, so a later cell gets it
+# unasked, and a copy of it under the new cell's own suffix is then the
+# second name of one reading in that cell. Rules over what a cell reports,
+# never over the table's names or size: an added entry trips none of them
+# unless it is such a copy.
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_cell_reads_nothing_under_two_names(cell):
+    """A cell's entries differ pairwise in what they read: reader and
+    params, whatever the entries are called."""
+    names = {}
+    for m in _reported(cell):
+        meta = _meta(m["name"])
+        names.setdefault(json.dumps([meta["reader"], meta.get("params", {})],
+                                    sort_keys=True), []).append(m["name"])
+    assert not [ns for ns in names.values() if len(ns) > 1]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_entry_a_cell_reports_has_its_file_and_reader(cell):
+    """As the result line needs them: the file by the entry's name, the
+    reader by the file's; and what the entry moves is an end-to-end metric
+    of this very cell."""
+    moved = {m["name"] for m in _reported(cell, "end_to_end")}
+    for m in _reported(cell):
+        meta = _meta(m["name"])
+        assert meta["name"] == m["name"] and m["moves"] in moved, m["name"]
+        reader = importlib.import_module(
+            f"chipbench.readers.{meta['reader']}")
+        assert callable(reader.read), m["name"]
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_a_cells_entries_read_nothing_from_an_empty_run(cell):
+    """A run that recorded nothing gives a result line without the
+    metric, never a 0 under a share's or a roofline's name."""
+    entries = _reported(cell)
+    assert entries
+    assert [m["name"] for m in entries
+            if harness.read_metric(m["name"], EMPTY_RUN) is not None] == []
 
 
 def test_run_refuses_any_platform_but_the_tpu():
