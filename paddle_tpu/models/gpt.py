@@ -1169,7 +1169,7 @@ def _head_logits(params, out, cfg: GPTConfig, mp_axis, sp):
 ONE_STAGE_SAVE = ("qkv", "proj", "fc1") + FLASH_REMAT_NAMES
 
 
-def hybrid_microbatch_share(params, tokens, labels, denom,
+def hybrid_microbatch_share(params, tokens, labels, denom, layers,
                             cfg: GPTConfig, pp_axis="pp", mp_axis="mp",
                             sp=None, flash=None, sep_axis="sep"):
     """One microbatch's share of the per-device loss on a mesh with ONE
@@ -1180,8 +1180,10 @@ def hybrid_microbatch_share(params, tokens, labels, denom,
     blocks carry no stage checkpoint: the engine differentiates one share
     at a time (hybrid_engine.AccumulatedLoss), so one microbatch's
     residuals are alive; each block keeps ONE_STAGE_SAVE and the head its
-    logits, so no GEMM, kernel or collective is run again. sp / flash /
-    sep_axis: as hybrid_loss_fn."""
+    logits, so no GEMM, kernel or collective is run again. `layers` runs
+    the blocks (the engine's: hybrid_engine.scan_layers, or the scan whose
+    backward adds a layer's gradient to the microbatches' sum where it is
+    made). sp / flash / sep_axis: as hybrid_loss_fn."""
     sep_on = flash is not None and flash.sep is not None
     x = _hybrid_embed(params, tokens, cfg, mp_axis, sp, sep_on, sep_axis)
 
@@ -1195,9 +1197,7 @@ def hybrid_microbatch_share(params, tokens, labels, denom,
         policy=jax.checkpoint_policies.save_only_these_names(
             *ONE_STAGE_SAVE))
 
-    def body(carry, p):
-        return block(p, carry), None
-    out, _ = lax.scan(body, x, params["blocks"])
+    out = layers(block, x, params["blocks"])
     with jax.named_scope(SCOPES.head_loss):
         logits_local = _head_logits(params, out, cfg, mp_axis, sp)
         _note_mp_wire(cfg, tokens, sp, mp_axis, pp_axis, 1,
@@ -1221,8 +1221,8 @@ def one_stage_loss(loss_fn, num_microbatches: int, share, loss_axes):
     """The hybrid_engine.AccumulatedLoss of a language-model loss, for the
     gpt and llama builders: `loss_fn` is the builder's own (the pipeline
     path, for an engine that does not accumulate);
-    `share(params, tokens, labels, denom)` is one microbatch's token
-    losses summed over `denom`, the local batch's valid labels
+    `share(params, tokens, labels, denom, layers)` is one microbatch's
+    token losses summed over `denom`, the local batch's valid labels
     (_vocab_parallel_ce's ignore_index); the step reports the mean over
     `loss_axes`, the data axes, as hybrid_loss_fn does."""
     from .hybrid_engine import AccumulatedLoss
@@ -1540,11 +1540,13 @@ def build_hybrid_train_step(cfg: GPTConfig, mesh: Mesh, optimizer,
     mesh whose pp axis has ONE rank there is no pipeline to fill and they
     are gradient accumulation: the step scans over the microbatches, each
     iteration the forward AND the backward of one (embedding, the block
-    scan with no stage checkpoint, head, loss), adds the gradients on the
-    carry, and reduces over dp, clips and updates ONCE; the loss is still
-    sum(token losses) / valid labels of the whole batch. One microbatch's
-    residuals are alive at a time, as under the replay, and no pass runs
-    twice. The builder reads this off the mesh it is given
+    scan with no stage checkpoint, head, loss), whose backward adds each
+    gradient to the carry's sum where it is made (a layer's inside the
+    block scan: hybrid_engine.build_train_step), and reduces over dp,
+    clips and updates ONCE; the loss is still sum(token losses) / valid
+    labels of the whole batch. One microbatch's residuals are alive at a
+    time, as under the replay, and no pass runs twice. The builder reads
+    this off the mesh it is given
     (mesh.shape[pp_axis] == 1); virtual_pp and schedule say nothing at
     pp = 1. Builds whose loss rides the pipeline's side channels (fp8,
     GPT-MoE, per-layer activation numerics, zero_stage 3) and an engine
@@ -1885,9 +1887,9 @@ def build_hybrid_train_step(cfg: GPTConfig, mesh: Mesh, optimizer,
             and z3plan is None and not (ncfg is not None and ncfg.act)):
         loss_fn = one_stage_loss(
             loss_fn, num_microbatches,
-            lambda p, tokens, labels, denom: hybrid_microbatch_share(
-                p, tokens, labels, denom, cfg, pp_axis, mp_axis, sp=sp,
-                flash=flash, sep_axis=sep_axis),
+            lambda p, tokens, labels, denom, layers: hybrid_microbatch_share(
+                p, tokens, labels, denom, layers, cfg, pp_axis, mp_axis,
+                sp=sp, flash=flash, sep_axis=sep_axis),
             (dp_axis, sep_axis) if sep_on else (dp_axis,))
 
     if moe_on:

@@ -11,6 +11,7 @@ collective over ICI.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Callable, Dict, NamedTuple
 
 import jax
@@ -21,7 +22,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from ..observability.trace import SCOPES
 from ..utils import shard_map as _shard_map
 
-__all__ = ["build_train_step", "AccumulatedLoss", "state_specs_for",
+__all__ = ["build_train_step", "AccumulatedLoss", "scan_layers",
+           "state_specs_for",
            "zero_dims", "zero_extend_spec", "zero_state_specs",
            "zero_param_specs", "zero1_state_specs"]
 
@@ -38,11 +40,26 @@ class AccumulatedLoss(NamedTuple):
     whole: Callable     # loss_fn(params, tokens, labels, ...) of the batch
     microbatches: int
     denom: Callable     # labels [b_local, ...] -> scalar the shares divide by
-    share: Callable     # (params, tokens_mb, labels_mb, denom) -> local scalar
+    # (params, tokens_mb, labels_mb, denom, layers) -> local scalar.
+    # `params` is a dict with the stacked layers under STACKED, and the
+    # share says where a layer's parameters enter its body by running them
+    # through `layers(body, x, params[STACKED])` (body(p, x) -> x;
+    # `scan_layers` is the plain form): the engine's `layers` adds a
+    # microbatch's gradient to the sum there (build_train_step)
+    share: Callable
     reported: Callable  # local loss -> the loss the step returns
 
     def __call__(self, *args):
         return self.whole(*args)
+
+
+STACKED = "blocks"   # an AccumulatedLoss's stacked layers in its `params`
+
+
+def scan_layers(body, x, stack):
+    """x through body(p, x) -> x for each layer p of `stack`, in order: the
+    `layers` of an AccumulatedLoss's share where nothing rides the scan."""
+    return lax.scan(lambda c, p: (body(p, c), None), x, stack)[0]
 
 
 def state_specs_for(optimizer, specs, example_params=None):
@@ -245,9 +262,15 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
     side channels. The step then scans over the microbatches, each
     iteration the forward AND the backward of one share (so one
     microbatch's residuals are alive at a time and nothing is replayed),
-    adding the gradients in their own dtype on the carry; the dp
-    reduction, the clip and the optimizer follow ONCE, exactly as after a
-    plain loss_fn. Builds whose gradient path is not the plain one
+    summing the gradients in their own dtype on the carry: a gradient
+    joins the sum where the backward makes it, a layer's inside the
+    share's layer scan (the sum is its weight-gradient GEMM's epilogue,
+    not a pass over the stack); the dp reduction, the clip and the
+    optimizer follow ONCE, as after a plain loss_fn. Where that reduction
+    is the plain pmean over ranks that are a power of two, the mean's
+    division is made as the gradient joins the sum, and the all-reduce
+    that follows is a psum (`folds`, below: the same bits, one pass over
+    the gradients less). Builds whose gradient path is not the plain one
     (comm_overlap's own scan, fp8, the error-feedback carries) call it
     whole, as any loss_fn.
 
@@ -548,6 +571,33 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
     if (ocfg is not None or fp8_plan is not None or z3_ef is not None
             or (moe_plan is not None and moe_plan.get("ef") is not None)):
         accumulate = None   # these gradient paths call it whole (docstring)
+    # the plain dp reduction (the EagerReducer equivalent: one pmean a
+    # leaf, after the last backward). Self-synchronizing optimizers
+    # (LocalSGD/DGC: _skips_grad_sync) own the dp axis but NOT the extra
+    # axes (sep/context-parallel partial grads must always be combined:
+    # skipping them would train on wrong gradients).
+    dp_axes = () if skips_dp else (dp_axis,)
+    extra_axes = tuple(extra_grad_axes)
+    ranks = math.prod(int(mesh.shape[a]) for a in dp_axes + extra_axes)
+    # an accumulating build divides by `ranks` as a microbatch's gradient
+    # joins the sum (_accumulated_grads) and reduces with psum, where that
+    # is pmean's result bit for bit: a scaling by 2**-k commutes with every
+    # rounding as long as nothing underflows, so for ranks that are a power
+    # of two and (mean_folds) dtypes of float32's exponent range. ZeRO-1/2
+    # and an ep rescale divide on their own ways.
+    folds = (accumulate is not None and int(accumulate.microbatches) > 1
+             and not zero_stage and not (moe_plan is not None and ep_n > 1)
+             and ranks > 1 and ranks & (ranks - 1) == 0)
+
+    def mean_folds(dtype):
+        """Whether a gradient of `dtype` was divided as it was summed: the
+        gradient's own dtype and the wire's (float16's would lose up to
+        log2(ranks) bits of a small g / ranks before the cast)."""
+        on_the_way = [dtype] + ([grad_reduce_dtype] if dp_axes and
+                                grad_reduce_dtype is not None else [])
+        return folds and all(
+            jnp.finfo(d).minexp <= jnp.finfo(jnp.float32).minexp
+            for d in on_the_way)
     # -- in-program telemetry (observability) --------------------------------
     from .. import observability as _obs
     tcfg = _obs.telemetry_from_flags() if telemetry == "auto" else telemetry
@@ -1001,7 +1051,11 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
     def _accumulated_grads(params, tokens, labels):
         """(loss, grads, obs) of the AccumulatedLoss: value-and-grad one
         microbatch inside lax.scan, sums on the carry (observe() series
-        are averaged over the microbatches, as the overlap scan does)."""
+        are averaged over the microbatches, as the overlap scan does).
+        The gradients are summed by the backward itself, each where it is
+        made (a layer's inside the share's layer scan: below), so what
+        value_and_grad returns is the new carry and no pass over the
+        gradients follows a microbatch."""
         from ..enforce import enforce
         M = int(accumulate.microbatches)
         b = tokens.shape[0]
@@ -1010,15 +1064,15 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
                 op="build_train_step", batch_local=b, microbatches=M)
         denom = accumulate.denom(labels)
 
-        def share(p, t, l):
+        def share(p, t, l, layers=scan_layers):
             if tcfg is None:
-                return accumulate.share(p, t, l, denom), {}
+                return accumulate.share(p, t, l, denom, layers), {}
             with _obs.collecting() as sink:
-                s = accumulate.share(p, t, l, denom)
+                s = accumulate.share(p, t, l, denom, layers)
             return s, _obs.metrics.obs_dict(sink)
-        vg = jax.value_and_grad(share, has_aux=True)
         if M == 1:
-            (loss, obs), grads = vg(params, tokens, labels)
+            (loss, obs), grads = jax.value_and_grad(share, has_aux=True)(
+                params, tokens, labels)
             return accumulate.reported(loss), grads, obs
         mbs = tuple(a.reshape((M, b // M) + a.shape[1:])
                     for a in (tokens, labels))
@@ -1029,9 +1083,59 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
             lambda sd: jnp.zeros(sd.shape, sd.dtype),
             (jax.eval_shape(share, params, *(a[0] for a in mbs)), params))
 
+        def join(acc, ct):
+            if mean_folds(ct.dtype):   # the mean's division (`folds`)
+                ct = ct * jnp.asarray(1 / ranks, ct.dtype)
+            return acc + ct
+        # an unstacked leaf: identity, whose backward adds the carry's
+        joined = jax.custom_vjp(lambda p, acc: p)
+        joined.defvjp(lambda p, acc: (p, acc), lambda acc, ct: (
+            jax.tree.map(join, acc, ct), None))
+        # the stack: its sum rides the BACKWARD layer scan's carry, so a
+        # layer's slice is updated where it lies (scanned in as xs and out
+        # as ys the compiler copies the stack, a pass a microbatch). The
+        # forward scan carries the sum `acc` through `layer_of`, identity;
+        # `seeded` makes acc itself the cotangent the backward starts
+        # from, layer_of's backward adds layer i's gradient to slice i, and
+        # what arrives as "d loss / d acc" is the new sum
+        def add_layer(i, cts):
+            ct_p, ct_acc = cts
+            return None, jax.tree.map(
+                lambda a, ct: lax.dynamic_update_index_in_dim(
+                    a, join(lax.dynamic_index_in_dim(a, i, keepdims=False),
+                            ct), i, 0), ct_acc, ct_p), None
+        layer_of = jax.custom_vjp(lambda p, acc, i: (p, acc))
+        layer_of.defvjp(lambda p, acc, i: ((p, acc), i), add_layer)
+        seeded = jax.custom_vjp(lambda x, acc: x)
+        seeded.defvjp(lambda x, acc: (x, acc), lambda acc, ct: (ct, acc))
+        rest = lambda tree: {k: v for k, v in tree.items() if k != STACKED}
+        seen = []   # the share did run its stack through `layers`
+
         def body(carry, mb):
-            return jax.tree.map(jnp.add, carry, vg(params, *mb)), None
+            sums, acc = carry
+
+            def summed_share(p, stack_acc):
+                def layers(block, x, stack):
+                    seen.append(True)
+
+                    def layer(c, xs):
+                        p_i, a = layer_of(xs[1], c[1], xs[0])
+                        return (block(p_i, c[0]), a), None
+                    n = jax.tree.leaves(stack)[0].shape[0]
+                    return seeded(*lax.scan(
+                        layer, (x, stack_acc), (jnp.arange(n), stack))[0])
+                return share({**joined(p, rest(acc)),
+                              STACKED: params[STACKED]}, *mb, layers)
+            out, (g, g_stack) = jax.value_and_grad(
+                summed_share, argnums=(0, 1), has_aux=True)(
+                    rest(params), acc[STACKED])
+            return (jax.tree.map(jnp.add, sums, out),
+                    {**g, STACKED: g_stack}), None
         ((loss, obs), grads), _ = lax.scan(body, zeros, mbs)
+        enforce(seen, "an AccumulatedLoss's share runs its stacked layers "
+                "through the `layers` it is given: that is where a "
+                "microbatch's gradient joins the sum",
+                op="build_train_step", stacked=STACKED)
         return (accumulate.reported(loss), grads,
                 jax.tree.map(lambda o: o / M, obs))
 
@@ -1330,13 +1434,8 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
                                                          opt_state, lr)
                 return rewrap(new_params, new_state, ef, fmeta, loss,
                               tele=z1t, obs=obs)
-        # dp gradient reduction (the EagerReducer equivalent — one pmean,
-        # fused and overlapped by XLA). Self-synchronizing optimizers
-        # (LocalSGD/DGC: _skips_grad_sync) own the dp axis but NOT the
-        # extra axes (sep/context-parallel partial grads must always be
-        # combined — skipping them would train on wrong gradients).
-        dp_axes = () if skips_dp else (dp_axis,)
-        extra_axes = tuple(extra_grad_axes)
+        # dp gradient reduction: one pmean a leaf (dp_axes, extra_axes and
+        # `folds`, above: a psum of what was divided as it was summed)
         if ocfg is None and (dp_axes or extra_axes):
             def reduce_one(g):
                 # extra axes (sep/context-parallel) combine genuinely
@@ -1344,14 +1443,15 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
                 # reduced-dtype compression applies only to the dp
                 # all-reduce of identical replicas, matching the reference
                 # fp16_allreduce scope (dp grad allreduce only).
+                mean = lax.psum if mean_folds(g.dtype) else lax.pmean
                 with jax.named_scope(SCOPES.coll_dp):
                     if extra_axes:
-                        g = lax.pmean(g, extra_axes)
+                        g = mean(g, extra_axes)
                     if dp_axes:
                         if grad_reduce_dtype is not None:
-                            return lax.pmean(g.astype(grad_reduce_dtype),
-                                             dp_axes).astype(g.dtype)
-                        return lax.pmean(g, dp_axes)
+                            return mean(g.astype(grad_reduce_dtype),
+                                        dp_axes).astype(g.dtype)
+                        return mean(g, dp_axes)
                 return g
 
             grads = jax.tree.map(reduce_one, grads)

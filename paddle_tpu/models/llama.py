@@ -566,7 +566,7 @@ def _head_logits(params, out, cfg: LlamaConfig, mp_axis, sp):
     return logits_local
 
 
-def hybrid_microbatch_share(params, tokens, labels, denom,
+def hybrid_microbatch_share(params, tokens, labels, denom, layers,
                             cfg: LlamaConfig, pp_axis="pp", mp_axis="mp",
                             sp=None, flash=None, sep_axis="sep"):
     """One microbatch's share of the per-device loss on a mesh with ONE
@@ -578,10 +578,10 @@ def hybrid_microbatch_share(params, tokens, labels, denom,
     cos, sin = _hybrid_rope(cfg, tokens.shape[1], sep_on, sep_axis)
     x = _hybrid_embed(params, tokens, cfg, mp_axis, sp)
 
-    def body(carry, p):
-        return _block_fn(p, carry, cos, sin, cfg, mp_axis, sp=sp,
-                         flash=flash, sep_axis=sep_axis), None
-    out, _ = lax.scan(body, x, params["blocks"])
+    out = layers(
+        lambda p, x: _block_fn(p, x, cos, sin, cfg, mp_axis, sp=sp,
+                               flash=flash, sep_axis=sep_axis),
+        x, params["blocks"])
     logits_local = _head_logits(params, out, cfg, mp_axis, sp)
     _note_mp_wire(cfg, tokens, sp, mp_axis, pp_axis, 1,
                   jax.tree.leaves(params["blocks"])[0].shape[0])
@@ -851,9 +851,9 @@ def build_hybrid_train_step(cfg: LlamaConfig, mesh: Mesh, optimizer,
         from .gpt import one_stage_loss
         loss_fn = one_stage_loss(
             loss_fn, num_microbatches,
-            lambda p, tokens, labels, denom: hybrid_microbatch_share(
-                p, tokens, labels, denom, cfg, pp_axis, mp_axis, sp=sp,
-                flash=flash, sep_axis=sep_axis),
+            lambda p, tokens, labels, denom, layers: hybrid_microbatch_share(
+                p, tokens, labels, denom, layers, cfg, pp_axis, mp_axis,
+                sp=sp, flash=flash, sep_axis=sep_axis),
             (dp_axis, sep_axis) if sep_on else (dp_axis,))
 
     step, shard_params, init_state = build_train_step(

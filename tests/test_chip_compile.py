@@ -643,6 +643,37 @@ def _gemm_fusions(text):
         if callee in bodies]
 
 
+def _stacked_gradient_gemms(text):
+    """{fused computation: it holds an add} of the GEMM fusions that write
+    a layer's weight gradient into its stacked `bf16[6, ., .]` buffer."""
+    out, name, body = {}, None, []
+    for line in text.splitlines() + ["}"]:
+        if line.endswith("{") and not line.startswith(" "):
+            name, body = line.split()[0].lstrip("%"), []
+        elif line.startswith("}") and name:
+            joined = "\n".join(body)
+            if (" convolution(" in joined and re.search(
+                    r"ROOT \S+ = bf16\[6,\d+,\d+\]\S* dynamic-update-slice\(",
+                    joined)):
+                out[name] = " add(" in joined
+            name = None
+        elif name:
+            body.append(line)
+    return out
+
+
+def _outside_entry(text):
+    """The instructions of every computation but ENTRY: the loops' bodies
+    and the fused computations."""
+    out, entry = [], False
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            entry = line.startswith("ENTRY")
+        elif not entry:
+            out.append(line)
+    return out
+
+
 def test_hybrid_cell_step_runs_no_pass_twice(topo, compiled_kernels,
                                              monkeypatch):
     """The compiled step of `train-6p7b-dp2mp2` runs no GEMM, kernel or
@@ -651,9 +682,19 @@ def test_hybrid_cell_step_runs_no_pass_twice(topo, compiled_kernels,
     nineteen with its stage replay, and the compiler added the head's
     three more times when the loss sat in a loop's body), the flash
     forward kernel once, no `collective-permute`, and one dp all-reduce of
-    the stacked gradients after the microbatch scan, not one a microbatch;
-    and it needs less memory than the pipeline form did (8.34 GiB of temp
-    by the same analysis, PERF.md PR 37)."""
+    the stacked gradients after the microbatch scan, not one a microbatch.
+    Since PR 47 a microbatch's gradient joins the sum where the backward
+    makes it: the four weight-gradient GEMMs that write a layer into a
+    stacked buffer add to what the buffer holds, divided by the dp ranks
+    already, and no loop body copies a whole stack or makes a pass over
+    one (the parent added on the microbatch scan's carry and divided
+    behind the all-reduce). No collective is asynchronous: nothing asks the
+    compiler for a switch (with the two that make an all-reduce
+    asynchronous the compiler clones GEMMs as carriers and the step loses
+    3.5% on the chip, PERF.md PR 47). And the summed stack and a
+    microbatch's gradient are ONE buffer: 2.61 GiB of temp where the
+    parent had 4.59 (the pipeline form 8.34 by the same analysis, PERF.md
+    PR 37)."""
     import paddle_tpu as paddle
     import paddle_tpu.distributed as dist
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -688,15 +729,24 @@ def test_hybrid_cell_step_runs_no_pass_twice(topo, compiled_kernels,
     assert len(re.findall(r"%flash_fwd[.\d]* = ", text)) == 1
     assert "flash_bwd_dq" in text and "flash_bwd_dkv" in text
     assert "collective-permute" not in text
+    assert "async-collective" not in text
     # four mp all-reduces of [1, 2048, 4096] a block pass (two forward,
     # two backward), two around the embedding and the head
     assert len(re.findall(r"= bf16\[1,2048,4096\]\S* all-reduce", text)) == 6
-    # the stacked fc1 gradient, [6, 4096, 8192] a chip, is reduced over dp
-    # once a step (1.64 GB of gradients on the wire, as the parent's)
-    stacked = [l for l in text.splitlines()
-               if re.search(r"= bf16\[6,4096,8192\]\S* all-reduce", l)]
+    # the stacked fc1 gradient, [6, 4096, 8192] a chip (the compiler may
+    # read it as [6 x 4096, 8192]), is reduced over dp once a step (1.64 GB
+    # of gradients on the wire, as the parent's)
+    stacked = [l for l in text.splitlines() if re.search(
+        r"= bf16\[(6,4096|24576),8192\]\S* all-reduce", l)]
     assert len(stacked) == 1, stacked
-    assert compiled.memory_analysis().temp_size_in_bytes < 5.5 * 2 ** 30
+    # fc1, fc2, proj and qkv write a layer of their stacked gradient once,
+    # adding to the sum as they write
+    gemms = _stacked_gradient_gemms(text)
+    assert list(gemms.values()) == [True] * 4, gemms
+    in_a_loop = [l for l in _outside_entry(text) if re.search(
+        r"= bf16\[6,\d{4},\d{4}\]\S* (copy\(|fusion\(.*kind=kLoop)", l)]
+    assert not in_a_loop, in_a_loop
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.25 * 2 ** 30
 
 
 # ---------------------------------------------------------------------------

@@ -83,6 +83,48 @@ def test_repo_jit_cache_dir_is_fixed_and_inside_checkout():
         assert ".jax_cache/" in f.read().split()
 
 
+@pytest.mark.parametrize("name,env,want", [
+    # the default asks the compiler for nothing
+    ("default", {}, None),
+    ("on", {"FLAGS_xla_latency_hiding_scheduler": "1"},
+     ["--xla_tpu_enable_latency_hiding_scheduler=true",
+      "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
+      "--xla_enable_async_all_gather=true"]),
+    # a name the operator set, either value, stays as they set it
+    ("operator_false", {"FLAGS_xla_latency_hiding_scheduler": "1",
+                        "LIBTPU_INIT_ARGS":
+                        "--xla_enable_async_all_gather=false"},
+     ["--xla_enable_async_all_gather=false",
+      "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true"]),
+])
+def test_what_import_leaves_in_libtpu_init_args(name, env, want):
+    """`import paddle_tpu` in a fresh process, before any backend starts.
+    The set holds no switch that makes an all-reduce asynchronous: on the
+    benchmark's hybrid step those cost 3.5% (PERF.md PR 47)."""
+    import os
+    import subprocess
+    import sys
+    clean = {k: v for k, v in os.environ.items()
+             if k not in ("LIBTPU_INIT_ARGS",
+                          "FLAGS_xla_latency_hiding_scheduler")}
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import os, paddle_tpu; "
+         "print(repr(os.environ.get('LIBTPU_INIT_ARGS')))"],
+        env={**clean, **env, "JAX_PLATFORMS": "cpu"}, capture_output=True,
+        text=True, check=True, cwd=os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))))
+    got = eval(out.stdout.strip().splitlines()[-1])
+    if want is None:
+        assert got is None
+        return
+    for token in want:
+        assert got.split().count(token) == 1, (token, got)
+    assert "--xla_enable_async_all_gather=true" not in got or \
+        "--xla_enable_async_all_gather=false" not in got
+    assert "all_reduce" not in got
+
+
 def test_matmul_precision_bound():
     old = flag("tpu_matmul_precision")
     try:

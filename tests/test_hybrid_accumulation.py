@@ -2,7 +2,8 @@
 
 At pp = 1 `num_microbatches > 1` is gradient accumulation: the hybrid step
 scans over the microbatches, each iteration the forward AND the backward of
-one, and reduces, clips and updates once. These tests hold that path to
+one, a gradient joining the sum where the backward makes it (ISSUE 47), and
+reduces, clips and updates once. These tests hold that path to
 (a) the same build at M = 1 on the whole batch and (b) the pipeline path of
 a pp = 2 mesh, for label masks that differ between microbatches; hold the
 lowered program's structure (no stage replay, no collective-permute); and
@@ -32,6 +33,7 @@ LLAMA = L.LlamaConfig(vocab_size=64, hidden_size=32, num_layers=4,
                       dtype=jnp.float32)
 PP1 = {"dp": 2, "pp": 1, "mp": 2}
 PP2 = {"dp": 2, "pp": 2, "mp": 2}
+DP1 = {"dp": 1, "pp": 1, "mp": 2}
 LR = 1e-2
 BETA1 = 0.9
 
@@ -78,16 +80,18 @@ def _train(model, cfg, dims, M, masks="ragged", steps=3, opt_kw=None, **kw):
     return losses, grad, jax.tree.map(np.asarray, params)
 
 
-def _assert_same(got, want, what):
-    """Losses and the first gradient to float32's accumulation order; the
+def _assert_same(got, want, what, rtol=2e-6):
+    """Losses and the first gradient to float32's accumulation order
+    (`rtol`: a build that reduces in bfloat16 rounds the sum of two
+    microbatches where the whole batch rounds one gradient); the
     parameters to 2% of one step's reach (AdamW divides a gradient by its
     own size, so an element whose gradient is rounding noise moves by a
     fraction of lr either way)."""
-    np.testing.assert_allclose(got[0], want[0], rtol=2e-6, err_msg=what)
+    np.testing.assert_allclose(got[0], want[0], rtol=rtol, err_msg=what)
     for name, k, tol in (("gradient", 1, None), ("parameters", 2, 0.02 * LR)):
         flat = jax.tree_util.tree_flatten_with_path(got[k])[0]
         for (path, a), b in zip(flat, jax.tree.leaves(want[k])):
-            limit = tol or 2e-6 * max(float(np.abs(b).max()), 1e-3)
+            limit = tol or rtol * max(float(np.abs(b).max()), 1e-3)
             assert float(np.abs(a - b).max()) <= limit, (
                 what, name, jax.tree_util.keystr(path))
 
@@ -139,13 +143,24 @@ def test_a_mean_of_microbatch_means_would_differ(whole_batch):
     ("clip", {}, dict(grad_clip=paddle.nn.ClipGradByGlobalNorm(0.05))),
     ("interleaved", dict(virtual_pp=2), None),
     ("zbh1", dict(schedule="ZBH1"), None),
+    # the reduction's wire dtype: the sum is cast where it is reduced;
+    # float16's keeps the parent's formula (cast, sum, THEN divide: below)
+    ("reduce_bf16", dict(grad_reduce_dtype=jnp.bfloat16), None),
+    ("reduce_fp16", dict(grad_reduce_dtype=jnp.float16), None),
+    # dp = 1: the same sum, nothing to reduce or divide
+    ("dp1_mp2", dict(dims=DP1), None),
 ])
 def test_accumulation_composes(name, kw, opt_kw):
     """What lives in the engine or inside a block takes the new path as it
     is: M = 2 follows the same build at M = 1."""
-    want = _train(G, CFG, PP1, 1, opt_kw=opt_kw, **kw)
-    got = _train(G, CFG, PP1, 2, opt_kw=opt_kw, **kw)
-    _assert_same(got, want, name)
+    kw = dict(kw)
+    dims = kw.pop("dims", PP1)
+    want = _train(G, CFG, dims, 1, opt_kw=opt_kw, **kw)
+    got = _train(G, CFG, dims, 2, opt_kw=opt_kw, **kw)
+    # bfloat16 keeps 8 bits, float16 11: M = 2 rounds g1 + g2 where M = 1
+    # rounds g
+    _assert_same(got, want, name, rtol={
+        "reduce_bf16": 2 ** -7, "reduce_fp16": 2 ** -10}.get(name, 2e-6))
 
 
 def test_llama_accumulates_too():
@@ -225,6 +240,109 @@ def test_one_stage_flash_block_runs_its_kernel_once():
             and e.params["name"] == "flash_fwd"))
     assert flash_forwards(PP1) == 1
     assert flash_forwards(PP2) == 2
+
+
+def _dp_psums(jaxpr, scans=()):
+    """(enclosing scans' lengths, operand avals, a division follows) of
+    every psum over dp."""
+    out = []
+    for e in jaxpr.eqns:
+        if (e.primitive.name.startswith("psum")
+                and "dp" in str(e.params.get("axes"))):
+            out.append((scans, [v.aval for v in e.invars], any(
+                u.primitive.name == "div" and e.outvars[0] in u.invars
+                for u in jaxpr.eqns)))
+        inner = (scans + (e.params["length"],)
+                 if e.primitive.name == "scan" else scans)
+        for sub in _subjaxprs(e):
+            out += _dp_psums(sub, inner)
+    return out
+
+
+def _scans(jaxpr, length):
+    out = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "scan" and e.params["length"] == length:
+            out.append(e)
+        for sub in _subjaxprs(e):
+            out += _scans(sub, length)
+    return out
+
+
+@pytest.mark.parametrize("name,dims,M,kw", [
+    ("M2", PP1, 2, {}), ("M4", PP1, 4, {}),
+    # ONE accumulation path: ZeRO-1 and dp = 1 sum the same way
+    ("zero1", PP1, 2, dict(zero_stage=1)), ("dp1", DP1, 2, {}),
+])
+def test_a_gradient_joins_the_sum_inside_the_backward(name, dims, M, kw):
+    """The microbatch scan's body adds no stacked gradient to its carry
+    (a pass over the whole stack a microbatch, as the parent had): the
+    stack's sum rides the BACKWARD layer scan's carry, a layer's slice
+    updated where it lies, and that scan hands out no stacked gradient."""
+    step, args = _lowered(G, CFG, dims, num_microbatches=M, **kw)
+    jaxpr = jax.make_jaxpr(step)(*args).jaxpr
+    stack = jax.tree.leaves(args[0]["blocks"])
+    micro = _scans(jaxpr, M)[0]           # the outermost of that length
+    body = micro.params["jaxpr"].jaxpr
+    n = micro.params["num_consts"]
+    carried_in = body.invars[n:n + micro.params["num_carry"]]
+    (forward,) = [e for e in body.eqns if e.primitive.name == "scan"
+                  and not e.params["reverse"]]
+    uses = lambda e, vs: any(v is u for v in vs for u in e.invars)
+    sums = [v for v in carried_in if uses(forward, [v])]
+    assert len(sums) == len(stack)   # the layer scan takes the stack's sum
+    added_outside = [e for e in body.eqns if uses(e, sums)
+                     and e.primitive.name.startswith("add")]
+    assert not added_outside, added_outside
+    (backward,) = [e for e in _scans(body, CFG.num_layers)
+                   if e.params["reverse"]]
+    assert backward.params["num_carry"] == 1 + len(stack)
+    assert len(backward.outvars) == backward.params["num_carry"]   # no ys
+    updates = [e.outvars[0].aval.shape[0]
+               for e in backward.params["jaxpr"].jaxpr.eqns
+               if e.primitive.name == "dynamic_update_slice"]
+    assert updates == [CFG.num_layers] * len(stack)   # each leaf's shard
+
+
+@pytest.mark.parametrize("name,dims,M,kw,plain", [
+    ("M1", PP1, 1, {}, True), ("M2", PP1, 2, {}, True),
+    ("M4", PP1, 4, {}, True), ("dp1", DP1, 2, {}, False),
+    ("zero1", PP1, 2, dict(zero_stage=1), False),
+    ("pipeline", PP2, 2, {}, False),
+])
+def test_no_psum_over_dp_inside_a_scan(name, dims, M, kw, plain):
+    """No scan holds a psum over dp (that would be M x the wire bytes);
+    where the reduction is the plain one every leaf meets ONE, of one
+    array, after the last backward."""
+    step, args = _lowered(G, CFG, dims, num_microbatches=M, **kw)
+    psums = _dp_psums(jax.make_jaxpr(step)(*args).jaxpr)
+    assert psums and not [p for p in psums if p[0]], psums
+    if plain:
+        assert all(len(avals) == 1 for _, avals, _ in psums)
+        assert len(psums) == len(jax.tree.leaves(args[0])) + 1   # the loss
+
+
+@pytest.mark.parametrize("name,M,kw,wire,divided_after", [
+    # ranks a power of two, float32's exponent range all the way: the
+    # mean's division is made as a microbatch's gradient joins the sum
+    # (the same bits), and the all-reduce is a psum
+    ("plain", 2, {}, jnp.float32, False),
+    ("reduce_bf16", 2, dict(grad_reduce_dtype=jnp.bfloat16), jnp.bfloat16,
+     False),
+    # float16 on the wire: a small g / ranks would lose bits before the
+    # cast, so the parent's formula stands: cast, sum, THEN divide
+    ("reduce_fp16", 2, dict(grad_reduce_dtype=jnp.float16), jnp.float16,
+     True),
+    # M = 1 sums nothing, so there is nowhere to fold a division into
+    ("M1", 1, {}, jnp.float32, True),
+])
+def test_where_the_mean_divides(name, M, kw, wire, divided_after):
+    step, args = _lowered(G, CFG, PP1, num_microbatches=M, **kw)
+    psums = [p for p in _dp_psums(jax.make_jaxpr(step)(*args).jaxpr)
+             if p[1][0].shape]          # the gradients', not the loss's
+    assert len(psums) == len(jax.tree.leaves(args[0]))
+    assert {p[1][0].dtype for p in psums} == {jnp.dtype(wire)}
+    assert {p[2] for p in psums} == {divided_after}
 
 
 # sha256 of the lowered step, recorded on the PARENT commit (ISSUE 37:
