@@ -43,8 +43,9 @@ result:
     dereferenced in the append's index maps and in the attention
     kernel's page copies, which read the pool where it lies in HBM);
   * it is written only in place and in the layout the kernel reads: new
-    rows by `kernels.pallas.kv_append` (aliased in/out, one aligned
-    sublane tile a grid step); the quantized append and the copy-on-write
+    rows by `kernels.pallas.kv_append` (aliased in/out; each aligned
+    sublane tile a pass writes comes in by one copy and goes back by
+    one); the quantized append and the copy-on-write
     copy through the page-flat view ``pool.reshape(L*H*NB, bs, D)``
     (`kv_cache.page_rows`) — collapsing leading dims is a bitcast in the
     tiled layout, so a scatter on it updates in place. A

@@ -133,6 +133,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from ..kernels.pallas.kv_append import append_tile
 from ..models import gpt as G
 from ..observability.trace import (ADMISSION_ATTRS, ADMIT_BLOCKED,
                                    DISPATCH_ATTRS, FIRST_TOKEN_ATTRS,
@@ -257,6 +258,7 @@ class _PackedStep:
     q_tokens: int        # packed query tokens (the cursor)
     kv_tokens: int       # KV positions attended over the K passes
     attn_pages: int      # (row, page) pairs the K passes' attention walks
+    kv_tiles: int        # (page, tile) pairs the K passes' appends write
     starts: np.ndarray
     pos0: np.ndarray
     q_lens: np.ndarray
@@ -572,6 +574,10 @@ class ServingEngine:
         self.bs, self.chunk = block_size, chunk
         self.max_batch = max_batch
         self.kv_quantized = kv_quantized
+        # rows of the tile the in-place append writes; a quantized pool
+        # requantizes whole pages instead
+        self._append_tile = (0 if kv_quantized
+                             else append_tile(pool_dtype, block_size))
         # device state and the slot list are private: the step in flight
         # owns them (the buffers are donated to it), and an outsider reads
         # them through the settling views at the end of the class
@@ -1902,7 +1908,7 @@ class ServingEngine:
                                                           self.engine_steps)
         with RecordEvent(SERVING_SPANS.dispatch, **dict(zip(DISPATCH_ATTRS, (
                 self.engine_steps, b.K, len(b.dec), len(b.pre), b.q_tokens,
-                b.kv_tokens, b.attn_pages, int(prev is not None),
+                b.kv_tokens, b.attn_pages, b.kv_tiles, int(prev is not None),
                 len(b.pre) - len(b.grants), sum(b.grants.values()),
                 self.token_budget))), **b.ssm_attrs):
             _faults().maybe_fail("serving/dispatch")
@@ -2129,6 +2135,14 @@ class ServingEngine:
             kv_tokens += int((kv_end[alive] + j).sum())
             attn_pages += int((-(-(kv_end[alive] + j) // self.bs)).sum())
             burst_rows += int(alive.sum())
+        # the tiles the in-place append walks
+        # (`kernels.pallas.kv_append.tile_work`'s n, summed over the
+        # passes): a row of pass 1 the tiles its new positions lie in, a
+        # row of a burst pass one; a quantized pool has none
+        tile, kv_tiles = self._append_tile, 0
+        if tile:
+            kv_tiles = burst_rows + int(((kv_end[ran] - 1) // tile
+                                         - pos0[ran] // tile + 1).sum())
         ssm_attrs = {}
         if self.model.recurrent:
             # a row that starts at position 0 has its state zeroed by the
@@ -2140,7 +2154,7 @@ class ServingEngine:
             dec=dec, pre=pre, ending=ending, grants=grants,
             props_by_slot=props_by_slot,
             use_spec=use_spec, K=K, q_tokens=cursor, kv_tokens=kv_tokens,
-            attn_pages=attn_pages,
+            attn_pages=attn_pages, kv_tiles=kv_tiles,
             starts=starts, pos0=pos0, q_lens=q_lens, emit=emit,
             lens_after=lens_after, ssm_attrs=ssm_attrs,
             # the tables are the engine's own and change under the step

@@ -77,15 +77,17 @@ SERVING_SPANS = _names(
 # size, decode and prefill rows, packed query tokens, KV positions
 # attended (summed over the rows that run and over the step's k passes),
 # and the (row, page) pairs those positions fill: what the attention
-# kernel walks, of k x max_batch x max_blocks_per_seq table slots; and
+# kernel walks, of k x max_batch x max_blocks_per_seq table slots;
+# `kv_tiles`, the (page, tile) pairs the in-place append writes over the k
+# passes (`kernels.pallas.kv_append`: its trip count, summed); and
 # `in_flight`, 1 when the step before had not been fetched at this
 # dispatch (the device goes from one to the next without the host). What
 # the token budget did to the prefilling rows: `n_starved` of the `n_pre`
 # resident prefilling rows rode the step with no grant, the others shared
 # `pre_tokens` prompt tokens of a `budget` of packed tokens a step.
 DISPATCH_ATTRS = ("step", "k", "n_dec", "n_pre", "q_tokens", "kv_tokens",
-                  "attn_pages", "in_flight", "n_starved", "pre_tokens",
-                  "budget")
+                  "attn_pages", "kv_tiles", "in_flight", "n_starved",
+                  "pre_tokens", "budget")
 # A model with a recurrent state adds: rows whose state the first pass read
 # and wrote (the chunk scan's), rows of the k - 1 burst passes (the state
 # update's, summed), and tokens through its mixer over all k passes.

@@ -625,6 +625,49 @@ def test_serving_step_takes_block_weights_in_place(one_chip,
 
 
 # ---------------------------------------------------------------------------
+# every serving step above with an unquantized pool: the append walks the
+# tiles its work list COUNTS (ISSUE 48)
+# ---------------------------------------------------------------------------
+# case -> (compile helper, its arguments, W of each pass's work list: the
+# bound on the tiles R rows of at most c_att of the pass's T tokens touch)
+_APPEND_STEPS = {
+    "gpt-k1": (_GPT, (1, "bf16", 256), {132}),
+    "gpt-k8": (_GPT, (8, "bf16", 256), {132, 64}),
+    "falcon-h1-k1": (_H1, (1,), {132}),
+    "falcon-h1-k8": (_H1, (8,), {132, 64}),
+    "q3n-pass1": (_Q3N, (1,), {132}),
+    "q3n-burst": (_Q3N, (8,), {132, 64}),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_APPEND_STEPS))
+def test_kv_append_takes_its_trip_count_as_an_operand(one_chip,
+                                                      compiled_kernels,
+                                                      case):
+    """Every `kv_append` of the compiled step takes, before its five
+    vectors of the bound's length W, the layer and the COUNT of the tiles
+    the pass writes, and hands the pools back aliased. The parent's call
+    had no count: it walked a grid of W = 132 (pass 1) or 64 (a burst
+    pass) steps whatever the pass wrote."""
+    build, args, bounds = _APPEND_STEPS[case]
+    text = build(one_chip, *args)[0].as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line
+             and "/kv_append/" in line]
+    assert calls, "kv_append was not lowered for the chip"
+    seen = set()
+    for line in calls:
+        operands = re.search(r"operand_layout_constraints=\{(.*?)\}, \w+=",
+                             line).group(1)
+        ints = re.findall(r"s32\[(\d+)\]", operands)
+        assert ints[:2] == ["1", "1"] and ints[2:] == [ints[2]] * 5, ints
+        seen.add(int(ints[2]))
+        assert "output_to_operand_aliasing={{0}: (8, {}), {1}: (9, {})}" \
+            in line
+    assert seen == bounds
+
+
+# ---------------------------------------------------------------------------
 # the hybrid training cell's step (GPT-3 6.7B widths, six layers, dp 2 x
 # pp 1 x mp 2, two microbatches): ONE pipeline stage is no pipeline
 # ---------------------------------------------------------------------------

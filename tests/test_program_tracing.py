@@ -127,22 +127,25 @@ def _expected_dispatch(eng):
     state before it (no speculation, budget never binds)."""
     n_dec = n_pre = q_tokens = 0
     rows = []       # (kv positions after pass 1, samples, may still emit)
+    new = []        # ... and how many of those positions pass 1 adds
     for r in eng.slots:
         if r is None:
             continue
         if r.prefill_done >= len(r.prompt):
             n_dec += 1
             q_tokens += 1
+            new.append(1)
             rows.append((int(eng.lens[r.slot]) + 1, True,
                          r.max_new_tokens - len(r.output)))
         else:
             n_pre += 1
             grant = min(eng.chunk, len(r.prompt) - r.prefill_done)
             q_tokens += grant
+            new.append(grant)
             done = r.prefill_done + grant >= len(r.prompt)
             rows.append((r.prefill_done + grant, done,
                          r.max_new_tokens - len(r.output) if done else 0))
-    return n_dec, n_pre, q_tokens, rows
+    return n_dec, n_pre, q_tokens, rows, new
 
 
 def test_one_ragged_step_yields_every_serving_span_once():
@@ -158,7 +161,7 @@ def test_one_ragged_step_yields_every_serving_span_once():
     eng.add_request(np.arange(20) % 64, max_new_tokens=4)
     eng.step()                          # the newcomer is admitted
     # reading `slots` settles: the expectation is taken from settled state
-    n_dec, n_pre, q_tokens, rows = _expected_dispatch(eng)
+    n_dec, n_pre, q_tokens, rows, new = _expected_dispatch(eng)
     assert n_dec == 2 and n_pre == 1
     micro0 = eng.decode_microsteps
     with obs.capture_spans() as first:
@@ -198,9 +201,16 @@ def test_one_ragged_step_yields_every_serving_span_once():
     pages = sum(-(-end // 16) for end, _, _ in rows) + sum(
         -(-(end + j) // 16) for j in range(1, k)
         for end, samples, left in rows if samples and left > j)
+    # the append's tiles (8 rows of f32 here): a row of pass 1 those its
+    # new positions lie in, a row alive in a burst pass one
+    tiles = sum((end - 1) // 8 - (end - q) // 8 + 1
+                for (end, _, _), q in zip(rows, new)) + sum(
+        1 for j in range(1, k) for _, samples, left in rows
+        if samples and left > j)
     assert attrs == {"step": eng.engine_steps - 1, "k": k, "n_dec": n_dec,
                      "n_pre": n_pre, "q_tokens": q_tokens, "kv_tokens": kv,
-                     "attn_pages": pages, "in_flight": 0, "n_starved": 0,
+                     "attn_pages": pages, "kv_tiles": tiles, "in_flight": 0,
+                     "n_starved": 0,
                      "pre_tokens": q_tokens - n_dec,
                      "budget": eng.token_budget}
     # the admission span closes with what it did: nobody waited
@@ -213,7 +223,9 @@ def test_attn_pages_counts_the_row_page_pairs_of_a_hand_built_step():
     """`attn_pages` is what the attention kernel walks: for every row that
     runs, the pages its context fills after the pass (16-token pages
     here). With `k`, the batch and the table width it gives the share of
-    the kernel's (row, page) slots that carry work."""
+    the kernel's (row, page) slots that carry work. `kv_tiles` is what
+    the in-place append walks: the 8-row tiles (a float32 pool's) a row's
+    NEW positions lie in."""
     cfg = tiny_cfg()
     params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
     eng = ServingEngine(params, cfg, max_batch=4,
@@ -225,12 +237,13 @@ def test_attn_pages_counts_the_row_page_pairs_of_a_hand_built_step():
     for _ in range(4):
         with obs.capture_spans() as cap:
             eng.step()
-        seen += [(e.attrs["kv_tokens"], e.attrs["attn_pages"])
+        seen += [(e.attrs["kv_tokens"], e.attrs["attn_pages"],
+                  e.attrs["kv_tiles"])
                  for e in cap.events if e.name == SERVING_SPANS.dispatch]
     # contexts after each pass, A / B: 5 / 7 (the token budget is 12),
     # 6 / 15, 7 / 20, 8 / 21 -- B crosses into its second page on the
-    # third step
-    assert seen == [(12, 2), (21, 2), (27, 3), (29, 3)]
+    # third step; its chunks 7..14 and 15..19 each lie in two tiles
+    assert seen == [(12, 2, 2), (21, 2, 3), (27, 3, 3), (29, 3, 2)]
 
 
 def test_an_idle_step_keeps_its_spans():

@@ -521,50 +521,141 @@ def test_ragged_kernel_row_writes_its_own_positions_only(g, kv_dtype,
         np.testing.assert_array_equal(out[keep], full[keep])
 
 
-@pytest.mark.parametrize("dtype,bs", [("float32", 8), ("float32", 16),
-                                      ("bfloat16", 16), ("bfloat16", 32)])
-def test_kv_append_writes_the_rows_and_nothing_else(dtype, bs):
+def _append_rows(case, bs, tile, rng):
+    """(R, c_att, T, q_lens, pos0) of one pass, by what it asks of the
+    append's work list (n tiles of at most W)."""
+    if case == "docs":      # the docs cell's pass: decode rows, two chunks
+        R, c_att, T = 64, 128, 192
+        q_lens = np.zeros(R, np.int32)
+        q_lens[:11] = 1
+        q_lens[20], q_lens[41] = 128, 53
+        pos0 = np.where(q_lens == 1, rng.randint(0, 2 * bs - 1, R), 0)
+        pos0[20], pos0[41] = bs - 5, 3
+        return R, c_att, T, q_lens, pos0.astype(np.int32)
+    if case == "full":      # the bound's own worst case, n = W: every row
+        R, c_att = 5, 2     # two tokens astride a tile edge
+        q_lens = np.full(R, 2, np.int32)
+        pos0 = tile * rng.randint(1, 3 * bs // tile, R) - 1
+        return R, c_att, 2 * R, q_lens, pos0.astype(np.int32)
+    R, c_att, T = 5, 12, 24
+    q_lens = {"empty": [0, 0, 0, 0, 0],     # n = 0: no row has a token
+              "one": [0, 0, 0, 1, 0],       # n = 1
+              # decode rows, a chunk crossing tiles and a page, an empty
+              # row, a decode row on a page's last position
+              "mixed": [1, 12, 0, 7, 1],
+              "poisoned": [1, 12, 0, 7, 1]}[case]
+    pos0 = [rng.randint(0, 3 * bs), rng.randint(0, 2 * bs), 0,
+            rng.randint(0, bs), bs - 1]
+    return R, c_att, T, np.array(q_lens, np.int32), np.array(pos0, np.int32)
+
+
+@pytest.fixture(params=["copied", "aliased"])
+def append_interpreter(request, monkeypatch):
+    """`kv_append` under both interpreters: its pools are aliased in and
+    out and written by its own copies, which only
+    ``pltpu.InterpretParams()`` runs as the chip does (see
+    `interpreter`)."""
+    if request.param == "aliased":
+        from jax.experimental.pallas import tpu as pltpu
+        from paddle_tpu.kernels.pallas import kv_append as mod
+        monkeypatch.setattr(mod, "_interpret", pltpu.InterpretParams)
+    return request.param
+
+
+@pytest.mark.parametrize("dtype,bs,D,case", [
+    (dtype, bs, D, case)
+    for dtype, bs, D in [("float32", 8, 16), ("float32", 16, 16),
+                         ("bfloat16", 16, 16), ("bfloat16", 32, 16),
+                         ("bfloat16", 16, 128), ("float32", 16, 256)]
+    for case in ("mixed", "empty", "one", "full", "poisoned")] + [
+        ("bfloat16", 128, 128, "docs"), ("float32", 128, 256, "docs")])
+def test_kv_append_writes_the_rows_and_nothing_else(dtype, bs, D, case,
+                                                    append_interpreter):
     """The in-place append (kernels/pallas/kv_append.py) against a plain
-    loop: decode rows, a prefill chunk crossing tiles and a page, an
-    empty row, a decode row on a page's last position — bit for bit,
-    every other element of every layer untouched."""
+    loop, bit for bit, every other element of every layer untouched: the
+    work list empty, of one tile, full to its bound W, of a pass's usual
+    mix, at the docs cell's shape (a chunk row beside decode rows), and
+    with every entry PAST the count n pointing at other live tiles, which
+    a walk that went on would overwrite."""
     from paddle_tpu.kernels.pallas.kv_append import (append_tile, kv_append,
                                                      tile_work)
     dtype = jnp.dtype(dtype)
-    L, H, NB, D, R, nb, c_att, T = 3, 2, 24, 16, 5, 4, 12, 24
     tile = append_tile(dtype, bs)
-    tables = np.arange(1, R * nb + 1, dtype=np.int32).reshape(R, nb)
-    q_lens = np.array([1, 12, 0, 7, 1], np.int32)
-    starts = np.concatenate([[0], np.cumsum(q_lens)[:-1]]).astype(np.int32)
-    append = jax.jit(
-        lambda kp, vp, k, v, layer, starts, pos0, q_lens: kv_append(
-            kp, vp, k, v, layer, tile_work(
-                starts, pos0, q_lens, jnp.asarray(tables), bs=bs, tile=tile,
-                c_att=c_att, T=T), tile=tile))
-    for seed in range(3):
+    L, H, nb = (2, 2, 2) if case == "docs" else (3, 2, 4)
+    for seed in range(3 if case == "mixed" else 1):
         rng = np.random.RandomState(seed)
-        pos0 = np.array([rng.randint(0, 3 * bs), rng.randint(0, 2 * bs), 0,
-                         rng.randint(0, bs), bs - 1], np.int32)
+        R, c_att, T, q_lens, pos0 = _append_rows(case, bs, tile, rng)
+        NB = R * nb + 1
+        tables = np.arange(1, NB, dtype=np.int32).reshape(R, nb)
+        starts = np.concatenate([[0], np.cumsum(q_lens)[:-1]]).astype(
+            np.int32)
+        n, *tiles = jax.jit(lambda *a: tile_work(
+            *a, jnp.asarray(tables), bs=bs, tile=tile, c_att=c_att, T=T))(
+                jnp.asarray(starts), jnp.asarray(pos0), jnp.asarray(q_lens))
+        n = int(n)
+        if case == "poisoned":
+            page, sub, tok0, lo, hi = (np.array(a) for a in tiles)
+            assert 0 < n < len(page)
+            # the tail lists tiles the pass wrote and ones it did not, all
+            # of their rows marked new
+            page[n:] = np.resize(np.concatenate([page[:n], tables[2]]),
+                                 len(page) - n)
+            sub[n:], tok0[n:], lo[n:], hi[n:] = 0, 0, 0, tile
+            tiles = [jnp.asarray(a) for a in (page, sub, tok0, lo, hi)]
         kp, vp = (jnp.asarray(rng.randn(L, H, NB, bs, D)).astype(dtype)
                   for _ in range(2))
         k, v = (jnp.asarray(rng.randn(T, H, D)).astype(dtype)
                 for _ in range(2))
         want = [np.asarray(a, np.float32).copy() for a in (kp, vp)]
+        seen = set()
         for r in range(R):
             for c in range(q_lens[r]):
                 page, off = tables[r, (pos0[r] + c) // bs], (pos0[r] + c) % bs
+                seen.add((int(page), int(off) // tile))
                 for pool, val in zip(want, (k, v)):
                     pool[1, :, page, off] = np.asarray(
                         val, np.float32)[starts[r] + c]
-        got = append(kp, vp, k, v, jnp.int32(1), jnp.asarray(starts),
-                     jnp.asarray(pos0), jnp.asarray(q_lens))
+        assert n == len(seen)
+        if case in ("empty", "one", "full"):
+            assert n == {"empty": 0, "one": 1, "full": len(tiles[0])}[case]
+        got = jax.jit(lambda kp, vp, k, v, *work: kv_append(
+            kp, vp, k, v, jnp.int32(1), work, tile=tile))(
+                kp, vp, k, v, jnp.int32(n), *tiles)
         for g, w in zip(got, want):
             np.testing.assert_array_equal(np.asarray(g, np.float32), w)
-    # a pass with no row at all leaves the pools as they were
-    got = append(kp, vp, k, v, jnp.int32(1), jnp.asarray(starts),
-                 jnp.asarray(pos0), jnp.zeros((R,), jnp.int32))
-    np.testing.assert_array_equal(np.asarray(got[0], np.float32),
-                                  np.asarray(kp, np.float32))
+
+
+@pytest.mark.parametrize("bs,tile", [(8, 8), (16, 8), (16, 16), (128, 16)])
+def test_tile_work_lists_each_tile_a_pass_writes_once(bs, tile):
+    """`tile_work` against a plain loop over the rows: its count n is the
+    number of distinct (page, tile) pairs the new positions lie in, and
+    its first n entries are those pairs, each once, with the rows of the
+    tile that are new and the packed index they come from."""
+    from paddle_tpu.kernels.pallas.kv_append import tile_work
+    R, nb, c_att, T = 9, 8, 40, 96
+    tables = np.arange(1, R * nb + 1, dtype=np.int32).reshape(R, nb)
+    work = jax.jit(lambda *a: tile_work(
+        *a, jnp.asarray(tables), bs=bs, tile=tile, c_att=c_att, T=T))
+    for seed in range(8):
+        rng = np.random.RandomState(seed)
+        q_lens = np.where(rng.rand(R) < 0.5, 1, rng.randint(0, c_att + 1, R))
+        while q_lens.sum() > T:
+            q_lens[np.argmax(q_lens)] //= 2
+        pos0 = np.array([rng.randint(0, nb * bs - q + 1) for q in q_lens])
+        starts = np.concatenate([[0], np.cumsum(q_lens)[:-1]])
+        want = {}       # (page, tile in page) -> {row of the tile: packed t}
+        for r in range(R):
+            for c in range(q_lens[r]):
+                p = pos0[r] + c
+                want.setdefault((tables[r, p // bs], p % bs // tile),
+                                {})[p % tile] = starts[r] + c
+        n, page, sub, tok0, lo, hi = (np.asarray(a) for a in work(
+            *(jnp.asarray(a, jnp.int32) for a in (starts, pos0, q_lens))))
+        assert n == len(want) <= len(page)
+        got = {(page[w], sub[w]): {i: tok0[w] + i
+                                   for i in range(lo[w], hi[w])}
+               for w in range(n)}
+        assert len(got) == n and got == want
 
 
 # ---------------------------------------------------------------------------
