@@ -20,8 +20,7 @@ __all__ = [
     "Place", "CPUPlace", "TPUPlace", "XPUPlace", "CUDAPlace", "CustomPlace",
     "set_device", "get_device", "get_all_device_type", "device_count",
     "is_compiled_with_cuda", "is_compiled_with_xpu", "is_compiled_with_tpu",
-    "get_default_device", "jax_device", "synchronize", "require_tpu",
-    "device_tag",
+    "get_default_device", "jax_device", "synchronize",
     "register_custom_device", "get_all_custom_device_type",
     "custom_device_count", "load_plugins",
 ]
@@ -158,28 +157,6 @@ def is_compiled_with_xpu() -> bool:
 @functools.lru_cache(maxsize=None)
 def is_compiled_with_tpu() -> bool:
     return any(d.platform == "tpu" for d in jax.devices())
-
-
-def require_tpu(what: str):
-    """First call of every chip entry (chip_smoke.py, bench.py,
-    benchmarks/*): exit unless jax's first device is a TPU. A measurement
-    path that finds no chip fails — it never shrinks to CPU shapes under
-    a device metric's name. Returns the device."""
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        raise SystemExit(
-            f"{what}: needs a TPU, but jax found platform "
-            f"{dev.platform!r} ({dev.device_kind}); refusing to run on it")
-    return dev
-
-
-def device_tag() -> dict:
-    """What every printed result says about where it ran: the first
-    device's platform and kind as jax reports them, and the device
-    count."""
-    dev = jax.devices()[0]
-    return {"platform": dev.platform, "kind": dev.device_kind,
-            "count": jax.device_count()}
 
 
 def synchronize(place=None):

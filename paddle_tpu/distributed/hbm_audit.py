@@ -217,7 +217,7 @@ def audit_stage3_compile(mesh: Mesh, *, seq: int = 2048, batch: int = 8,
     """Compile the ZeRO stage-3 (p_g_os) sharded train step at the real
     6.7B shape: params, grads and optimizer state all sharded over the
     axis; asserts per-device param bytes ~= total/n for the shardable
-    leaves (BASELINE config 4's layout)."""
+    leaves (the reference's GroupSharded stage-3 layout on GPT-3 6.7B)."""
     import time
 
     import paddle_tpu as paddle

@@ -21,8 +21,8 @@ exist only inside one jitted program and are never materialized for the
 whole model, so grad HBM is one block's, not L blocks'. PCIe traffic per
 step = params down twice (fwd + bwd recompute), new params up once,
 moments down+up once — the step is host-link-bound by design. The point is
-capability: the north-star 6.7B GPT-3 shape trains end-to-end on a single
-16 GB v5e (benchmarks/offload_bench.py --size 6.7b).
+capability: a model bigger than one chip's HBM (the GPT-3 6.7B shape on a
+16 GB v5e) can still take a step there; not measured on the chip.
 
 Five compiled programs in the unclipped step, each reused across all L
 blocks (identical shapes): embed fwd, block fwd, head vjp+update, block

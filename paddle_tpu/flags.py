@@ -102,13 +102,8 @@ def set_flags(flags_map: Dict[str, Any]) -> None:
 # Core flags (TPU-relevant subset of the reference's flag surface).
 # ---------------------------------------------------------------------------
 define_flag("check_nan_inf", False, "Check NaN/Inf after each op (debug mode).")
-define_flag("check_nan_inf_level", 0, "0: raise on nan/inf; higher: log only.")
-define_flag("benchmark", False, "Per-op timing instrumentation.")
-define_flag("seed", 0, "Global random seed (0 = nondeterministic).")
-define_flag("default_dtype", "float32", "Default floating point dtype.")
-define_flag("use_bf16_matmul", True, "Prefer bfloat16 matmul accumulation inputs on TPU.")
-define_flag("allocator_strategy", "xla", "Memory allocator strategy (XLA owns TPU HBM).")
-define_flag("fraction_of_gpu_memory_to_use", 0.92, "Compat flag; maps to XLA memory fraction.")
+
+
 def _bind_matmul_precision(v):
     import jax
     jax.config.update("jax_default_matmul_precision",
@@ -129,19 +124,22 @@ define_flag("log_level", "WARNING", "Framework log level (bound to the "
             "paddle_tpu logger).", on_set=_bind_log_level)
 define_flag("comm_timeout_s", 600, "Collective watchdog timeout in seconds.")
 define_flag("embedding_deterministic", False, "Deterministic (slower) embedding grad.")
-define_flag("cudnn_deterministic", False, "Compat: deterministic ops.")
-define_flag("low_precision_op_list", 0, "Collect AMP op statistics.")
 define_flag("flash_attn_block_q", 0, "Flash attention q tile (0 = auto; "
             "consumed by the Pallas dispatch).")
 define_flag("flash_attn_block_k", 0, "Flash attention k tile (0 = auto).")
 define_flag("flash_attention", False,
-            "Training-grade Pallas flash attention in the hybrid engines: "
-            "gpt/llama build_hybrid_train_step(flash_attention='auto') "
-            "wires the fused fwd + custom_vjp bwd kernel directly into "
-            "the block bodies (no op-registry hop inside the compiled "
-            "step), composing with mp seq-parallel/ring overlap, fp8 GEMM "
-            "sites, zero1 and every pipeline schedule. Off: the composed "
-            "einsum path compiles bitwise-identically. (consumed by "
+            "Which door a training block takes to the Pallas flash "
+            "kernel. On: gpt/llama build_hybrid_train_step("
+            "flash_attention='auto') calls the fused fwd + custom_vjp bwd "
+            "kernel directly under a FlashAttentionConfig (its tile "
+            "sizes, FLAGS_flash_sep's context-parallel mode), composing "
+            "with mp seq-parallel/ring overlap, fp8 GEMM sites, zero1 and "
+            "every pipeline schedule. Off: the registry op "
+            "F.scaled_dot_product_attention, which runs the SAME kernel "
+            "wherever OpSchema.takes_pallas admits the shape (on the "
+            "chip, both training cells of the benchmark) and the composed "
+            "attention elsewhere (the CPU, an unsupported shape). "
+            "(consumed by "
             "kernels.pallas.flash_training.flash_from_flags)")
 define_flag("flash_sep", "",
             "Context-parallel mode for the flash training path when the "
@@ -150,13 +148,6 @@ define_flag("flash_sep", "",
             "'ulysses' (all-to-all head<->sequence swap, flash on the "
             "gathered sequence). Needs FLAGS_flash_attention. (consumed "
             "by kernels.pallas.flash_training.flash_from_flags)")
-define_flag("use_autotune", False, "Compat (FLAGS_use_autotune): kernel "
-            "autotuning; TPU tiles are set by the measured defaults "
-            "above.")
-define_flag("sync_nccl_allreduce", True, "Compat: XLA collectives are "
-            "always in-program (no async NCCL stream to sync).")
-define_flag("max_inplace_grad_add", 0, "Compat: XLA fuses gradient "
-            "accumulation; no manual inplace-add threshold.")
 
 
 # ---------------------------------------------------------------------------
@@ -248,9 +239,6 @@ define_flag("deterministic", False,
             "Request fully deterministic execution: cascades to highest "
             "matmul precision, deterministic embedding grads and "
             "partitionable RNG.", on_set=_bind_deterministic)
-define_flag("conv_workspace_size_limit", 512,
-            "Compat (cudnn workspace MB): XLA owns conv scratch; recorded "
-            "for ported configs, consumed by nothing on TPU.")
 
 # --- profiler / dump -------------------------------------------------------
 define_flag("profiler_dir", "profiler_out",
@@ -268,8 +256,8 @@ define_flag("dump_dir", "",
 
 # The one in-checkout home of the persistent compile cache (git-ignored).
 # The path is part of the cache key, so it is FIXED: never built from
-# tempfile, a pid or the time. The chip entries chip_smoke.py and bench.py
-# set FLAGS_jit_cache_dir to it.
+# tempfile, a pid or the time. The benchmark (chipbench/harness.py) sets
+# FLAGS_jit_cache_dir to it.
 REPO_JIT_CACHE_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     ".jax_cache")
@@ -301,8 +289,6 @@ define_flag("jit_cache_min_compile_time_secs", 1.0,
             "Only cache executables that took at least this long to "
             "compile (bound to jax_persistent_cache_min_compile_time_secs).",
             on_set=_bind_cache_min_time)
-define_flag("max_compile_parallelism", 0,
-            "Compat: XLA picks compilation threads; recorded only.")
 
 # --- distributed -----------------------------------------------------------
 define_flag("tcp_store_timeout_s", 300,
@@ -683,9 +669,6 @@ define_flag("dataloader_num_workers", 0,
 define_flag("io_prefetch_factor", 2,
             "Default DataLoader prefetch depth per worker when none is "
             "passed (consumed by io.DataLoader).")
-define_flag("use_shm_cache", False,
-            "Compat (FLAGS_use_shm_cache): the native token loader maps "
-            "files directly; recorded only.")
 
 # --- kernels / attention ---------------------------------------------------
 define_flag("dropout_use_rbg", True,
@@ -802,12 +785,6 @@ define_flag("router_heartbeat_timeout_s", 10.0,
 define_flag("router_quarantine_backoff_s", 0.25,
             "Initial quarantine probe backoff for the fleet router; "
             "each failed probe doubles it (capped at 30s).")
-define_flag("flash_attn_version", 2,
-            "Compat (reference FLAGS_flash_attn_version): the Pallas "
-            "kernel implements the FA-2 recurrence; recorded only.")
-define_flag("gemm_use_half_precision_compute_type", False,
-            "Compat: TPU matmuls accumulate fp32 regardless; see "
-            "tpu_matmul_precision for the real knob.")
 
 # --- AMP / precision -------------------------------------------------------
 define_flag("amp_dtype", "bfloat16",
@@ -821,7 +798,7 @@ define_flag("fp8", False,
             "scales come from a rolling amax history riding "
             "opt_state['fp8_meta']. Equivalent to amp.auto_cast("
             "level='O3') (consumed by quantization.fp8.fp8_enabled via "
-            "models gpt/llama build_hybrid_train_step and bench.py).")
+            "models gpt/llama build_hybrid_train_step).")
 define_flag("fp8_amax_history", 16,
             "Rolling amax-history window length for fp8 delayed scaling "
             "(consumed by quantization.fp8.init_fp8_meta).")
@@ -836,17 +813,6 @@ define_flag("bf16_stochastic_rounding_moments", True,
             "beta2 EMA below bf16 ulp).")
 
 # --- executor / misc -------------------------------------------------------
-define_flag("new_executor_sequential_run", False,
-            "Compat: XLA programs are dataflow-scheduled; recorded only.")
 define_flag("enable_dispatch_stats", True,
             "Count registry pallas/reference dispatch hits (consumed by "
             "ops.dispatch_stats).")
-define_flag("print_sub_graph_dir", "",
-            "Compat: jaxprs/StableHLO are printable via jit lowering; "
-            "recorded only.")
-define_flag("eager_delete_tensor_gb", 0.0,
-            "Compat: XLA frees buffers by liveness; recorded only.")
-define_flag("init_allocated_mem", False,
-            "Compat: XLA zero-initializes nothing; use explicit inits.")
-define_flag("enable_cublas_tensor_op_math", True,
-            "Compat: the MXU is always on; see tpu_matmul_precision.")

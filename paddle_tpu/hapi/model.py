@@ -1,6 +1,6 @@
 """High-level Model API (reference: python/paddle/hapi/model.py —
-Model.prepare/fit/evaluate/predict at :1082,1808; drives the ResNet50
-BASELINE config).
+Model.prepare/fit/evaluate/predict at :1082,1808; what the reference's
+ResNet50 image classification runs through).
 
 TPU design: fit() compiles ONE jitted train step (value_and_grad over
 functional_call + optimizer.apply) and reuses it every batch; parameters,

@@ -91,8 +91,8 @@ def _capacity_dispatch_idx(expert_idx, gate_w, capacity, num_experts,
     Returns (slot [T] int32 = e*C + pos, or -1 when dropped;
     gate [T] f32 zeroed for dropped tokens; counts [E]). The MoE layer's
     gather/scatter dispatch consumes this: the dense [T,E,C] einsum costs
-    2·T·E·C·D MXU flops per dispatch/combine (measured 54% of a 1.3B-class
-    MoE step), where the reference's CUDA scatter
+    2·T·E·C·D MXU flops per dispatch/combine, where the reference's CUDA
+    scatter
     (fluid/operators/collective/global_scatter_op.cu.cc) is ~free —
     index routing is the TPU analogue of that zero-flop scatter.
     """

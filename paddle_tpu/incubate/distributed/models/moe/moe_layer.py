@@ -189,8 +189,8 @@ class MoELayer(Layer):
         Dispatch modes: "index" routes by slot ids with gather/scatter —
         the TPU analogue of the reference's zero-flop CUDA scatter
         (global_scatter_op.cu.cc); the dense "einsum" [T,E,C] form costs
-        2·T·E·C·D MXU flops EACH way (measured 54% of a 1.3B-class MoE
-        step, benchmarks/configs_bench.py bench_moe). "auto" uses index
+        2·T·E·C·D MXU flops EACH way, where the reference's scatter
+        costs none. "auto" uses index
         whenever the gate supports it: experts split over an ep mesh
         axis route through the explicit shard_map path internally
         (per-rank index routing + hand-placed all-to-alls,

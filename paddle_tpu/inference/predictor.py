@@ -103,9 +103,9 @@ class Config:
     def enable_int8(self):
         """Real effect: a live Layer callable gets its Linear sublayers
         converted to W8A8 QuantizedLinear (int8 MXU execution — the
-        reference's TensorRT-int8 deploy path, measured 229.8 TOPS vs
-        181.9 bf16 TFLOPS on v5e). jit.save artifacts must be re-exported
-        already-quantized."""
+        reference's TensorRT-int8 deploy path; its rate against bf16 is
+        not measured on the current installation). jit.save artifacts
+        must be re-exported already-quantized."""
         self._precision = "int8"
 
     def enable_profile(self):

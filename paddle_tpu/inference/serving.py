@@ -1673,8 +1673,8 @@ class ServingEngine:
         reacts. Hysteresis: trim only once the queue exceeds TWICE the
         slot horizon — the 16-sample window's p95 (its max) is sticky, so
         trimming on every step while it decays would shed far past the
-        overload fraction (measured 73% shed at 2x load without the depth
-        gate vs ~50% ideal)."""
+        overload fraction (at 2x load the ideal is to shed half the
+        arrivals)."""
         if (not self.shed_on_overload or self.ttft_slo_s is None
                 or len(self.queue) <= 2 * self.max_batch):
             return []

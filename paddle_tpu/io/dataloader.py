@@ -70,7 +70,7 @@ def prefetch_to_device(iterator, size: int = 2, sharding=None):
     (numpy / jax) are transferred, to `sharding` when given; non-array
     leaves (strings, python scalars) pass through untouched.
 
-    Used by hapi.Model.fit and bench.py; wrap any batch iterator:
+    Used by hapi.Model.fit; wrap any batch iterator:
         for batch in prefetch_to_device(loader, size=2): ...
     """
     import collections

@@ -76,10 +76,10 @@ _NEG_INF = -1e30
 
 
 def _pick_block(s: int, target: int = 1024) -> int:
-    """Largest power-of-two-ish divisor of s up to `target`. Measured on
-    v5e (GPT-268M, seq 1024): 1024 > 512 > 256 (47.4k vs 43.3k vs 34.2k
-    tok/s end-to-end) — bigger q/k tiles amortize the softmax rescale; the
-    fp32 scores tile at 1024x1024 (4 MB) still fits VMEM comfortably."""
+    """Largest power-of-two-ish divisor of s up to `target`. Bigger q/k
+    tiles amortize the softmax rescale over more columns; the fp32 scores
+    tile at 1024x1024 (4 MB) still fits VMEM comfortably (the order 1024 >
+    512 > 256 is not measured on the current installation)."""
     b = min(target, s)
     while s % b:
         b //= 2
@@ -88,9 +88,9 @@ def _pick_block(s: int, target: int = 1024) -> int:
 
 def _block_target(has_extras: bool) -> int:
     # 1024 tiles fit VMEM even WITH the extra per-tile inputs (bias 2 MB
-    # bf16 + dropout bits 4 MB beside the 4 MB fp32 scores) and measured
-    # ~1.8x faster than 512 on the masked paths (v5e: bias 72.7 vs 39.8
-    # TF/s, segments 71.0 vs 48.4 at B=8 H=16 S=1024 d=64)
+    # bf16 + dropout bits 4 MB beside the 4 MB fp32 scores); their speed
+    # against 512 on the masked paths is not measured on the current
+    # installation
     del has_extras
     return 1024
 
@@ -161,7 +161,7 @@ def _mask_scores(s, i, j, *, block_q, block_k, causal, offset, window,
         # qseg arrives as a [bq, 1] COLUMN (sublane-major, pre-broadcast
         # outside the kernel); kseg as a [bk] lane vector. A [bq]
         # lane-vector qseg here would need an in-tile cross-lane
-        # transpose — measured 8x slower bwd at BERT shapes.
+        # transpose in every tile of the backward.
         masked = _or(masked, qseg != kseg[None, :])
     if fm_start is not None:
         masked = _or(masked, (qpos >= fm_start[None, :])

@@ -10,7 +10,7 @@ gpt and llama so flag semantics can never drift).
 
 ``FlashAttentionConfig`` is that plan:
 
-* ``block_q``/``block_k`` — kernel tile sizes (0 = the kernel's measured
+* ``block_q``/``block_k`` — kernel tile sizes (0 = the kernel's own
   auto-pick, ``flash_attention._pick_block``);
 * ``sep`` — optional context parallelism over a ``sep`` mesh axis, with
   the flash kernel as the per-shard inner compute:
@@ -23,10 +23,10 @@ gpt and llama so flag semantics can never drift).
   heads_local, D]``).
 
 Flags-off (``resolve_flash_attention(None)``) leaves the model bodies on
-the composed einsum path — the builders compile bitwise-identical HLO,
-the established lowered-HLO-assert pattern. CPU tier-1 runs the kernels
-in interpreter mode (``_common.interpret``), so the whole compose matrix
-is testable off-TPU.
+the registry op: the same kernel on the chip wherever ``takes_pallas``
+admits the shape (both training cells), the composed einsum attention
+elsewhere. CPU tier-1 runs the kernels in interpreter mode
+(``_common.interpret``), so the whole compose matrix is testable off-TPU.
 """
 
 from __future__ import annotations
@@ -47,7 +47,7 @@ class FlashAttentionConfig:
     """Resolved flash-attention plan for the hybrid engines.
 
     block_q/block_k: kernel tile sizes (0 = auto-pick — 1024-target
-    power-of-two divisors of the sequence, the measured v5e optimum).
+    power-of-two divisors of the sequence).
     sep: None (attention runs on this rank's full local sequence) or
     "ring"/"ulysses" context parallelism over the mesh's 'sep' axis.
     """
@@ -68,8 +68,8 @@ class FlashAttentionConfig:
 
 
 def flash_from_flags() -> Optional[FlashAttentionConfig]:
-    """Flag-driven opt-in: None (the composed einsum path, bitwise
-    unchanged) unless FLAGS_flash_attention is set; FLAGS_flash_sep picks
+    """Flag-driven opt-in: None (the registry op: its Pallas arm on the
+    chip) unless FLAGS_flash_attention is set; FLAGS_flash_sep picks
     the context-parallel mode, FLAGS_flash_attn_block_q/_k the tiles."""
     from ...flags import flag
     sep = flag("flash_sep") or None

@@ -8,8 +8,8 @@ pattern, fusion/gpu/fused_attention_kernel.cu).
 One row-blocked pass: mean, variance, normalize, affine — x is read once
 and the [rows] statistics live in VMEM. The XLA-composed fallback
 (nn/functional/norm.py layer_norm) emits separate convert_reduce fusions
-for the stats that run at ~84 GB/s on bf16 rows (measured on the BERT-base
-step, round 4); this kernel removes that round trip. Backward fuses the dx
+for the stats, each a pass of its own over the bf16 rows; this kernel
+removes that round trip. Backward fuses the dx
 recurrence in a second row-blocked kernel; dw/db are cross-row reductions
 left to one fused XLA reduce (same split as rms_norm.py).
 

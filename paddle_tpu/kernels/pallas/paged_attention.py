@@ -25,11 +25,11 @@ Design:
 Decode is bandwidth-bound: the win is reading seq_len tokens of KV once,
 instead of gather-writing + re-reading max_len tokens.
 
-Page-size guidance (measured, v5e, B=4 H=16 D=128, capacity 8192, live
-2048): block_size=128 (the lane width) → 0.36 ms/step vs 0.48 ms dense
-cache at capacity and 2.15 ms for the round-1 XLA gather path. Tiny
-vLLM-style pages (16) drown in grid overhead on TPU (7.9 ms) — pick
-block_size ≥ 128.
+Page-size guidance: block_size=128 (the lane width) makes a page one
+full tile, streamed once, where a dense cache at capacity is read whole
+and an XLA gather reads AND writes it. Tiny vLLM-style pages (16) pay one
+grid step for an eighth of a tile each, so the grid's fixed cost
+dominates — pick block_size ≥ 128.
 """
 
 from __future__ import annotations

@@ -1,5 +1,5 @@
-"""BERT family (reference: BERT-base pretraining is BASELINE configs[1];
-in the reference it exercises fused_attention/fused_feedforward kernels —
+"""BERT family (reference: BERT-base pretraining, which in the reference
+exercises the fused_attention/fused_feedforward PHI kernels —
 here the equivalent fusion happens inside nn.TransformerEncoder, whose
 attention rides the registry scaled_dot_product_attention (Pallas flash
 kernel on TPU) and whose LN/FFN chains XLA fuses; the standalone

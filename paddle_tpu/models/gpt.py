@@ -1,6 +1,6 @@
 """GPT model family (reference: the GPT/GPT-3 configs exercised by Fleet
 hybrid-parallel — model definition test/legacy_test/auto_parallel_gpt_model.py,
-used via test/auto_parallel/get_gpt_model.py:18; BASELINE configs 2/4).
+used via test/auto_parallel/get_gpt_model.py:18; Fleet GPT-3 1.3B/6.7B).
 
 Two executions of the same architecture:
 
@@ -292,10 +292,10 @@ def hybrid_param_specs(cfg: GPTConfig) -> Dict[str, Any]:
 def _ln(x, g, b, eps=1e-5):
     # deliberately the COMPOSED form, not the Pallas fused LayerNorm the
     # registry dispatches for nn-level users: inside this model's
-    # scan-over-layers + remat structure the kernel's call overhead and
-    # saved-stats traffic cost more than the fusion saves (measured
-    # 597.7 vs 582.2 ms/step on the 1.3B flagship, one v5e, round 4 —
-    # the kernel wins on the unscanned BERT graph instead)
+    # scan-over-layers + remat structure the kernel's call and its
+    # saved-stats traffic are extra work beside what XLA already fuses
+    # here (which of the two is faster on the 1.3B step is not measured
+    # on the current installation)
     xf = x.astype(jnp.float32)
     mu = jnp.mean(xf, -1, keepdims=True)
     var = jnp.mean(jnp.square(xf - mu), -1, keepdims=True)
@@ -762,8 +762,8 @@ def dense_forward(params, tokens, cfg: GPTConfig, remat: bool = True,
 def dense_loss(params, tokens, labels, cfg: GPTConfig, remat: bool = True,
                remat_save=("attn_out", "qkv"), fp8=None, flash=None):
     """remat_save threads through to dense_forward — bigger-than-HBM
-    callers (benchmarks/offload_bench.py moments tier) pass () for the
-    minimum-memory full-remat form. fp8: per-layer delayed scales; flash:
+    callers (host-offloaded moments) pass () for the minimum-memory
+    full-remat form. fp8: per-layer delayed scales; flash:
     fused-attention plan (see dense_forward)."""
     logits = dense_forward(params, tokens, cfg, remat=remat,
                            remat_save=remat_save, fp8=fp8, flash=flash)

@@ -1518,8 +1518,8 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
             # per-leaf protocol never applies _grad_clip (clip lives in
             # apply()), so run it directly. NOTE: this also routes
             # use_multi_tensor=True through the per-leaf loop — fused
-            # multi-tensor Adam ships default-off (measured slower on
-            # TPU), so clip+mp/pp configs simply get the default path.
+            # multi-tensor Adam ships default-off, so clip+mp/pp configs
+            # simply get the default path.
             step_no = opt_state["step"] + 1
             with jax.named_scope(SCOPES.optimizer):
                 new_p, new_slots = optimizer._apply_leaves(

@@ -1,7 +1,7 @@
 """Llama model family (reference: the Llama model exercised by semi-auto
 parallel tests — test/auto_parallel/hybrid_strategy/
 semi_auto_parallel_llama_model.py:93 LlamaAttentionAuto/LlamaMLPAuto/
-LlamaRMSNormAuto; BASELINE config 5 Llama-2 7B).
+LlamaRMSNormAuto; the reference's Llama-2 7B, flash_attn + fused RoPE).
 
 Same two-execution design as gpt.py:
 

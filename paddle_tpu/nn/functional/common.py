@@ -51,8 +51,8 @@ def dropout(x, p=0.5, axis=None, training=True, mode="upscale_in_train", name=No
         axes = [axis] if isinstance(axis, int) else list(axis)
         shape = [s if i in axes else 1 for i, s in enumerate(shape)]
     keep = 1.0 - p
-    # rbg mask bits: threefry expansion measured ~30% of a BERT-base train
-    # step (see random.next_mask_key)
+    # rbg mask bits: threefry expansion costs ~10 ALU ops an element
+    # (see random.next_mask_key)
     mask = jax.random.bernoulli(next_mask_key(), keep, tuple(shape))
     if mode == "upscale_in_train":
         return jnp.where(mask, x / keep, 0.0).astype(x.dtype)

@@ -41,8 +41,8 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5, name=N
     # mixed-precision contract: output dtype == input dtype. The affine
     # params commonly stay fp32 next to bf16 activations; multiplying in
     # their dtype would silently re-promote every downstream activation
-    # (and the attention kernels) to fp32 — measured as the single biggest
-    # BERT-step cost before round 4.
+    # (and the attention kernels) to fp32, twice the bytes of every later
+    # pass.
     if weight is not None:
         out = out * jnp.asarray(weight).astype(x.dtype)
     if bias is not None:

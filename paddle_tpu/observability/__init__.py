@@ -37,8 +37,8 @@ makes the split measurable without leaving the compiled program:
   window).
 
 Entry points: ``models.hybrid_engine.build_train_step(telemetry=)``,
-``Model.fit``, ``distributed.resilience.run_resilient``,
-``inference.ServingEngine`` and ``bench.py``. See README "Observability".
+``Model.fit``, ``distributed.resilience.run_resilient`` and
+``inference.ServingEngine``. See README "Observability".
 """
 
 from .aggregate import TelemetryAggregator, detect_stragglers

@@ -1,7 +1,7 @@
 """Analytic FLOPs models for step accounting (MFU).
 
-One place for the math every bench/report needs (previously inlined in
-bench.py): per-token training FLOPs for the GPT and Llama families,
+One place for the math every report needs: per-token training FLOPs
+for the GPT and Llama families,
 fwd/bwd/remat-aware, plus the comms-time estimate that turns a
 comm_overlap bucket plan into an expected comms fraction.
 
@@ -115,7 +115,7 @@ def _llama_matmul_params(cfg) -> int:
 def gpt_flops_per_token(cfg, seq_len: int, *, params=None,
                         remat: str = "none") -> Dict[str, float]:
     """FLOPs/token for a GPTConfig. Pass the concrete param tree to count
-    N exactly (what bench.py does — keeps its frozen series bit-stable);
+    N exactly (embeddings excluded, as the accounting above says);
     otherwise N comes from the config analytically."""
     n = (param_count(params) if params is not None
          else _gpt_matmul_params(cfg))
@@ -136,8 +136,8 @@ def llama_flops_per_token(cfg, seq_len: int, *, params=None,
 def gpt_moe_flops_per_token(cfg, *, tokens_per_rank: int,
                             mp: int = 1) -> Dict[str, float]:
     """MoE flop accounting for a GPT-MoE config (cfg.moe_num_experts > 0),
-    the ONE copy of the math bench.py's `moe` section and the auto-parallel
-    planner both consume (tests assert the bench formulas bit-for-bit).
+    the ONE copy of the math the auto-parallel planner consumes
+    (tests/test_auto_tuner.py holds it to the frozen formulas bit-for-bit).
 
     tokens_per_rank: tokens one (dp, ep) rank routes per step (per-rank
     batch x seq — per MICROBATCH when pipelined, matching the capacity the

@@ -1,16 +1,16 @@
 """Step accounting: compile vs steady-state, per-phase breakdown, MFU.
 
-Replaces the ad-hoc timing math previously inlined in bench.py with one
-reusable instrument:
+One reusable instrument for a training loop's own clock (the benchmark
+has its own: ``chipbench/runners/_train.py``):
 
 * the FIRST completed step is recorded as ``compile_s`` (jit trace +
   XLA compile + the step itself), every later step as steady state;
 * named phases (``with timer.phase("data"): ...``) attribute wall time
-  inside or around the step — the per-phase ms breakdown the bench's
-  ``telemetry`` section reports;
+  inside or around the step — a per-phase ms breakdown of the host's
+  time;
 * ``report()`` derives tokens/s and MFU from an analytic FLOPs model
   (:mod:`.flops`) and carries a comms fraction either measured (the
-  no-sync probe bench strategy) or estimated from a comm_overlap bucket
+  no-sync probe) or estimated from a comm_overlap bucket
   plan + link bandwidth.
 
 The timer never touches the device: callers must end a step only after

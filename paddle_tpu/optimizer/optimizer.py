@@ -398,11 +398,11 @@ class Adam(Optimizer):
         self._lazy_mode = lazy_mode
         # reference API (python/paddle/optimizer/adam.py:210
         # use_multi_tensor): update all parameters in one fused pass.
-        # Default OFF like the reference — and measured SLOWER on TPU
-        # (110M-param tree, one v5e: per-leaf 4.1 ms vs concat-fused
-        # 12.4 ms; the concat/split copies swamp what per-fusion launch
-        # overhead they save, and on sharded params the concat would also
-        # discard per-leaf shardings). Kept for API parity + the rare
+        # Default OFF like the reference: the concat/split copies are two
+        # more passes over every leaf than the per-leaf update makes
+        # (what they cost against the launches they save is not measured
+        # on the current installation), and on sharded params the concat
+        # would also discard per-leaf shardings. Kept for API parity + the rare
         # many-tiny-leaves tree where launches dominate.
         self._use_multi_tensor = bool(use_multi_tensor)
         # low-precision EMA stores need stochastic rounding (see _sr_to_bf16)

@@ -293,8 +293,8 @@ def resolve_fp8_plan(fp8_arg, sites: Sequence[str], num_layers: int,
 
 
 # ---------------------------------------------------------------------------
-# Dense-path train step (bench.py + tests; the hybrid engine has its own
-# fp8_meta threading)
+# Dense-path train step (the benchmark's `fp8` control + tests; the hybrid
+# engine has its own fp8_meta threading)
 # ---------------------------------------------------------------------------
 def make_fp8_train_step(loss_fn, optimizer, donate: bool = True):
     """jitted step over a dense (single-program) fp8 loss.

@@ -118,8 +118,8 @@ def next_key() -> jax.Array:
 def next_mask_key() -> jax.Array:
     """Key for BULK mask generation (dropout): the threefry stream seeds an
     rbg key (XLA's hardware RngBitGenerator). Threefry costs ~10 ALU ops per
-    random element — measured ~30% of a BERT-base train step across its ~36
-    dropout sites — while rbg bits are effectively free on TPU. Key
+    random element, at every one of a BERT-base step's ~36 dropout
+    sites, while rbg bits come from the hardware generator. Key
     uniqueness/determinism still come from the threefry sequence; only the
     bit expansion changes engine."""
     k = next_key()

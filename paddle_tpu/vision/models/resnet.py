@@ -1,5 +1,5 @@
-"""ResNet family (reference: python/paddle/vision/models/resnet.py — the
-BASELINE.json config-0 model).
+"""ResNet family (reference: python/paddle/vision/models/resnet.py, the
+ResNet50 of paddle.vision.models + paddle.Model).
 
 Same architecture/API as the reference; compute lowers to
 lax.conv_general_dilated which XLA tiles onto the MXU as implicit GEMM. For

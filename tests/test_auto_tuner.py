@@ -4,7 +4,7 @@ semi-auto spmd_rules coverage).
 Covers: candidate generation over the REAL hybrid-engine surface (the old
 tuner's "sharding"/"sep" vocabulary is gone), engine_kwargs round-trips
 through build_hybrid_train_step for every family, the shared MoE flop math
-(bit-for-bit the bench.py formulas), cost-model rankings against this
+(bit-for-bit the frozen formulas), cost-model rankings against this
 repo's RECORDED ground truth (PR 2 bucketed-overlap and PR 5 mp-overlap
 directions on the TPU profile; the CPU-mesh op-count ordering
 allreduce < sp < ring on the CPU profile), analytic-OOM-vs-compiled
@@ -182,7 +182,7 @@ def test_gpt1p3b_topk_all_valid():
 
 
 # ---------------------------------------------------------------------------
-# The shared MoE flop math (bench.py's moe section, bit-for-bit).
+# The shared MoE flop math, held bit-for-bit to formulas frozen here.
 # ---------------------------------------------------------------------------
 def test_moe_flops_matches_bench_math_bit_for_bit():
     from paddle_tpu.incubate.distributed.models.moe.gate import \
@@ -197,7 +197,7 @@ def test_moe_flops_matches_bench_math_bit_for_bit():
         m = gpt_moe_flops_per_token(cfg, tokens_per_rank=T, mp=mp)
         C = compute_capacity(T, E, 1, cfg.moe_capacity_factor)
         assert m["capacity"] == C
-        # the bench.py inline formulas, frozen
+        # the formulas written out, frozen
         assert m["expert_gemm_flops_per_rank_step"] == \
             12.0 * E * C * H * (FF // mp) * L2
         assert m["dense_dispatch_flops_per_moe_layer"] == \

@@ -36,8 +36,7 @@ _KERNEL_MODULES = ("flash_attention", "layer_norm", "rms_norm", "rope",
                    "ragged_paged_attention", "kv_append", "ssm", "gdn",
                    "moe")
 
-# the serving smoke's pool geometry (chip_smoke.py): GPT-1.3B heads,
-# 128-token pages
+# a small serving pool's geometry: GPT-1.3B heads, 128-token pages
 HEADS, HEAD_DIM, PAGE, NUM_PAGES, PAGES_PER_SEQ = 16, 128, 128, 64, 8
 
 

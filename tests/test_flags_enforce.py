@@ -24,6 +24,63 @@ def test_catalogue_size_and_docs():
         assert f.help, f"flag {name} has no help text"
 
 
+def _flag_uses():
+    """Names some code outside a flag's own definition reads: the literal
+    first argument of a `flag(...)` / `_flag(...)` / `get_flags(...)`
+    call, or a `FLAGS_<name>` in any string, in `paddle_tpu/`,
+    `chipbench/` and `examples/`."""
+    import ast
+    import os
+    import re
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def literals(node):
+        if isinstance(node, ast.Constant) and isinstance(node.value, str):
+            yield node.value
+        elif isinstance(node, (ast.List, ast.Tuple, ast.Set)):
+            for e in node.elts:
+                yield from literals(e)
+
+    used = set()
+    for top in ("paddle_tpu", "chipbench", "examples"):
+        for dirpath, _, files in os.walk(os.path.join(repo, top)):
+            for name in files:
+                if not name.endswith(".py"):
+                    continue
+                with open(os.path.join(dirpath, name)) as f:
+                    tree = ast.parse(f.read())
+                own = set()     # nodes inside a define_flag(...) call
+                for node in ast.walk(tree):
+                    if not isinstance(node, ast.Call):
+                        continue
+                    fn = getattr(node.func, "id",
+                                 getattr(node.func, "attr", None))
+                    if fn == "define_flag":
+                        own.update(id(n) for n in ast.walk(node))
+                    elif fn in ("flag", "_flag", "get_flags") and node.args:
+                        used.update(s.removeprefix("FLAGS_")
+                                    for s in literals(node.args[0]))
+                for node in ast.walk(tree):
+                    if (isinstance(node, ast.Constant)
+                            and isinstance(node.value, str)
+                            and id(node) not in own):
+                        used.update(re.findall(r"FLAGS_(\w+)", node.value))
+    return used
+
+
+def test_every_flag_has_a_reader():
+    """A flag that is accepted and does nothing is worse than the
+    KeyError `set_flags` raises for an unknown name: every registered
+    flag is bound by an `on_set` hook or read somewhere outside its own
+    definition (PR 49 deleted the 22 that were not)."""
+    import paddle_tpu.distributed.check  # defines the comm-check flags
+    used = _flag_uses()
+    unread = sorted(n for n, f in _REGISTRY.items()
+                    if f.on_set is None
+                    and n.removeprefix("FLAGS_") not in used)
+    assert not unread, unread
+
+
 JAX_BOUND = {
     "FLAGS_debug_nans": ("jax_debug_nans", True, False),
     "FLAGS_debug_infs": ("jax_debug_infs", True, False),

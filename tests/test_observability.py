@@ -602,9 +602,3 @@ def test_unknown_device_kind_raises(dev):
         flops.peak_flops([dev])
     with pytest.raises(ValueError, match="CHIP_PEAKS"):
         PL.profile_for([dev])
-
-
-def test_require_tpu_refuses_cpu():
-    from paddle_tpu.device import require_tpu
-    with pytest.raises(SystemExit, match="needs a TPU"):
-        require_tpu("some_bench.py")
