@@ -58,6 +58,20 @@ A recurrent model's per-slot state (`unified_step`'s ``ssm_state`` and
 contract: one donated buffer each, on both scans' carry, the layer by
 scalar prefetch, written in place by the mixer's kernels.
 
+A LATENT cache (``serving_model(cfg).latent``, `models/deepseek_v2.py`)
+keeps the same contract on pages without heads: the two pools are the
+compressed vector's ``[L, 1, NB, bs, C]`` and the shared rotary key's
+``[L, 1, NB, bs, Rd]`` (512 wide, and the 64-wide key in a page of 128
+lanes: two pools so that each page is whole lane tiles of its own width;
+the head axis of 1 keeps the page-flat view, the copy-on-write above and
+the allocator as they are), written by
+`kernels.pallas.latent_append` on `kv_append`'s work list and read where
+they lie by `kernels.pallas.mla_attention`; a layer of kind "latent" takes
+the model's `latent_qkv` in place of `qkv`. A model's PROLOGUE
+(``prologue(cfg)``: a kind and a count) is a run of leading layers under
+``params["prologue"]``, scanned before the periods with no experts; the
+pool's entries count them first.
+
 A model whose layers are not all of one kind gives its PATTERN
 (``serving_model(cfg).pattern``: one period as runs of "attention",
 "parallel" or "linear" layers). `ragged_pass` scans periods and, inside
@@ -79,6 +93,8 @@ from jax import lax
 
 from ..observability.trace import SCOPES
 from ..kernels.pallas.kv_append import append_tile, kv_append, tile_work
+from ..kernels.pallas.latent_append import latent_append
+from ..kernels.pallas.mla_attention import mla_paged_attention
 from ..kernels.pallas.ragged_paged_attention import ragged_paged_attention
 from ..quantization.kv_cache import (append_tokens_quantized, page_rows,
                                      reset_page_scales)
@@ -104,7 +120,9 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
     speculative-decoding verify signal (draft token i is accepted iff it
     equals the model's own argmax one position earlier). A model with
     routed experts adds, last, what its router chose: (ids [L, T, k],
-    stats [L, 3]) as `models.qwen3_next.moe_layer` gives them a layer."""
+    stats [L, 3]) as `models.qwen3_next.moe_layer` gives them a layer (L:
+    the layers WITH a router; `models.deepseek_v2.moe_layer`'s stats are
+    5 wide)."""
     T = tokens.shape[0]
     quantized = ks is not None
     model = serving_model(cfg)
@@ -125,6 +143,9 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
                                       jnp.maximum(q_lens - 1, 0)[:, None]),
         0, T - 1)                                            # [R, c_att]
     scale = 1.0 / (cfg.head_dim ** 0.5)
+    extra = {}
+    if model.latent:                        # padding is not routed
+        extra = {"real": off_of < q_lens[row_of]}
     if ssm is not None:
         # a row that starts at position 0 starts from a zero state
         plan = {"row_of": row_of, "off_of": off_of, "starts": starts,
@@ -139,10 +160,10 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
     # the pool holds one entry an attention layer, the state one a layer
     # with a mixer, each numbered in its own order
     runs = model.pattern(cfg)
-    single = len(runs) == 1 and runs[0][1] == 1
-    n_att = sum(n for kind, n in runs if kind != "linear")
-    n_mix = sum(n for kind, n in runs if kind != "attention")
     routed = model.routed
+    single = len(runs) == 1 and runs[0][1] == 1 and not routed
+    n_att = sum(n for kind, n in runs if kind != "linear")
+    n_mix = sum(n for kind, n in runs if kind not in ("attention", "latent"))
 
     def layer_body(kind, experts, flat, att, mix):
         """One layer of `kind`; flat/att/mix: its number among all layers
@@ -154,6 +175,17 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
             attn_p = None
             if kind == "linear":
                 mixed, ssm = model.mixer(p, x, ssm, mix, plan, cfg)
+            elif kind == "latent":
+                qa, qr, c, k_r = model.latent_qkv(p, x, pos_t[None], cfg)
+                mixed = None
+                with jax.named_scope(SCOPES.kv_write):
+                    kp, vp = latent_append(kp, vp, c, k_r, att, work,
+                                           tile=tile)
+                with jax.named_scope(SCOPES.mla_attn):
+                    attn_p = mla_paged_attention(
+                        qa, qr, kp, vp, tables, starts, q_lens, kv_lens,
+                        model.attn_scale(cfg), att,
+                        c_att=c_att)[None]                   # [1,T,h,C]
             else:
                 q, k, v, u = model.qkv(p, x, pos_t[None], cfg, mp_axis)
                 mixed = u                                    # [1,T,h,D]
@@ -178,7 +210,7 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
             if routed:
                 x, route = model.block_math(p, x, attn_p, mixed, cfg,
                                             mp_axis, experts=experts,
-                                            layer=flat)
+                                            layer=flat, **extra)
             else:
                 x = model.block_math(p, x, attn_p, mixed, cfg, mp_axis)
             return (x, kp, vp, ks, vs, ssm), route
@@ -196,7 +228,7 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
                 p, j = pj
                 return layer_body(
                     kind, params["experts"][r] if routed else None,
-                    period * n + j, period * n_att + att0 + j,
+                    period * n + j, n_pro + period * n_att + att0 + j,
                     period * n_mix + mix0 + j)(carry, p)
             carry, route = lax.scan(
                 run_body, carry, (ps[r], jnp.arange(n, dtype=jnp.int32)))
@@ -208,10 +240,17 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
                  if routed else None)
         return carry, route
 
+    carry = (x, kp, vp, ks, vs, ssm)
+    kind, n_pro = model.prologue(cfg)
+    if n_pro:   # the leading layers, no experts; the pool's first entries
+        carry, _ = lax.scan(
+            lambda carry, pj: layer_body(kind, None, pj[1], pj[1], pj[1])(
+                carry, pj[0]),
+            carry, (params["prologue"], jnp.arange(n_pro, dtype=jnp.int32)))
     blocks = params["blocks"]
     periods = jax.tree.leaves(blocks)[0].shape[0]
     xs = (blocks, jnp.arange(periods, dtype=jnp.int32))
-    (x, *pools), route = lax.scan(period_body, (x, kp, vp, ks, vs, ssm), xs)
+    (x, *pools), route = lax.scan(period_body, carry, xs)
     if routed:      # [periods, layers a period, ...] -> [layers, ...]
         route = jax.tree.map(
             lambda a: a.reshape((-1,) + a.shape[2:]), route)

@@ -73,7 +73,14 @@ step scans periods (`ragged_step.ragged_pass`). A model with routed
 experts (`routed`) hands back what its router chose with each step's
 tokens, in the same fetch: a request that asks (`keep_routing`) keeps the
 picks of its positions, and the step's counts ride the `serving_fetch`
-span that landed it (`observability.trace.MOE_FETCH_ATTRS`).
+span that landed it (`observability.trace.MOE_FETCH_ATTRS`). A model with
+a LATENT cache (`latent`, `models.deepseek_v2`: one compressed vector and
+one shared rotary key a token and layer, no heads, no V pool) sizes the
+two pools by `pool_shapes`; pages, tables, refcounts, prefix sharing and
+copy-on-write are the ones K and V pages have, a leading run of layers
+that differs is its `prologue`, and what it cannot be given yet (a mesh,
+int8 weights, a quantized pool, speculative decoding) raises at
+construction.
 
 A REQUEST'S LIFE is one record (ISSUE 38): `Request` keeps a mark where
 the engine passes each point on the way to the first token (submitted,
@@ -137,7 +144,8 @@ from ..kernels.pallas.kv_append import append_tile
 from ..models import gpt as G
 from ..observability.trace import (ADMISSION_ATTRS, ADMIT_BLOCKED,
                                    DISPATCH_ATTRS, FIRST_TOKEN_ATTRS,
-                                   MOE_FETCH_ATTRS, REQUEST_END_ATTRS,
+                                   LATENT_DISPATCH_ATTRS, MOE_FETCH_ATTRS,
+                                   MOE_LOCAL_FETCH_ATTRS, REQUEST_END_ATTRS,
                                    REQUEST_PHASES, REQUEST_SPANS, SCOPES,
                                    SERVING_SPANS, SSM_DISPATCH_ATTRS)
 from ..profiler.utils import RecordEvent, record_interval
@@ -228,6 +236,9 @@ class Request:
     # never an input), kept when the request asks (rollout replay)
     keep_routing: bool = False
     routing: Optional[np.ndarray] = None
+    # prompt tokens the LAST admission found computed in shared prefix
+    # pages (never run for this request; 0 without prefix sharing)
+    prefix_hit_tokens: int = 0
 
     def marks(self):
         """The five marks on the way to the first token, in order."""
@@ -265,8 +276,8 @@ class _PackedStep:
     emit: np.ndarray     # tokens each row emits if no EOS falls (`_advance`)
     lens_after: np.ndarray   # ... and where that leaves its context
     arrays: tuple        # the host arrays, in the program's order
-    ssm_attrs: dict = dataclasses.field(default_factory=dict)
-    #                      a recurrent model's dispatch attributes
+    model_attrs: dict = dataclasses.field(default_factory=dict)
+    #                      a recurrent or latent model's dispatch attributes
     out: tuple = ()      # once dispatched: the program's (toks, greedy_all,
     #                      lens) and, of a model with routed experts, its
     #                      (ids0, ids_burst, stats), still on the device
@@ -428,10 +439,21 @@ class GPTServing:
     one with ``recurrent = True`` also has a `mixer` over the packed rows
     and a per-slot state that the engine keeps beside the KV pages; one
     with ``routed = True`` has `block_math` take its run's experts whole
-    (``experts=``, ``layer=``) and return (x, (ids, stats))."""
+    (``experts=``, ``layer=``) and return (x, (ids, stats)), and says
+    how many of its layers have a router (`routed_layers`); one with
+    ``latent = True`` (`models.deepseek_v2.Serving`) keeps a head-less
+    latent cache (`pool_shapes`, `latent_qkv`, `attn_scale`); `prologue`
+    is the run of leading layers (``params["prologue"]``, no experts)
+    that comes before the periods."""
 
     recurrent = False
     routed = False      # no router: `block_math` returns the stream alone
+    latent = False
+
+    @staticmethod
+    def prologue(cfg):
+        """(kind, count) of the layers before the first period."""
+        return ("attention", 0)
 
     @staticmethod
     def pattern(cfg):
@@ -559,7 +581,30 @@ class ServingEngine:
             enforce(chunk <= cfg.ssm_chunk,
                     f"chunk {chunk} must not pass the mixer's scan chunk "
                     f"{cfg.ssm_chunk}", op="ServingEngine")
-        if kv_pool_bytes is not None:
+        # -- a model with a latent cache: its pages have no heads and the
+        # two pools differ in width (`pool_shapes`); everything that deals
+        # in pages is as it was
+        self._latent = self.model.latent
+        pools = ((Hkv, D), (Hkv, D))
+        if self._latent:
+            for ok, what in (
+                    (mesh is None, "a mesh: a page has no heads to shard"),
+                    (not int8, "int8 weights: its leaves have no quantized "
+                               "form"),
+                    (not kv_quantized,
+                     f"kv_cache_dtype={kv_cache_dtype!r}: the latent "
+                     "append and its attention have no quantized page"),
+                    (self.spec_k == 0,
+                     "spec_decode_k > 0: the verify pass is not built for "
+                     "its attention")):
+                enforce(ok, "a model with a latent cache cannot be served "
+                            f"with {what}", op="ServingEngine")
+            pools = self.model.pool_shapes(cfg)
+        if kv_pool_bytes is not None and self._latent:
+            num_blocks = max(2, int(kv_pool_bytes // (
+                L * block_size * sum(h * d for h, d in pools)
+                * jnp.dtype(pool_dtype).itemsize)))
+        elif kv_pool_bytes is not None:
             # capacity from a fixed HBM byte budget: the int8-pool mode
             # admits ~2x the blocks of bf16 at the same budget
             num_blocks = max(2, kv_pool_blocks_for_budget(
@@ -581,9 +626,9 @@ class ServingEngine:
         # device state and the slot list are private: the step in flight
         # owns them (the buffers are donated to it), and an outsider reads
         # them through the settling views at the end of the class
-        self._k_pools = jnp.zeros((L, Hkv, num_blocks, block_size, D),
-                                  pool_dtype)
-        self._v_pools = jnp.zeros_like(self._k_pools)
+        self._k_pools, self._v_pools = (
+            jnp.zeros((L, h, num_blocks, block_size, d), pool_dtype)
+            for h, d in pools)
         self._k_scales = self._v_scales = None
         if kv_quantized:
             self._k_scales = jnp.zeros((L, Hkv, num_blocks), jnp.float32)
@@ -612,10 +657,15 @@ class ServingEngine:
         # expert (`moe_passes` of them with any assignment)
         self.moe_experts_touched = self.moe_assignments = 0
         self.moe_passes = 0
+        self.moe_local_tokens = self.moe_tokens = 0  # a group-limited router
+        self.prefix_hit_tokens = 0      # prompt tokens found in shared pages
+        self.cache_evictions = 0        # cached-free pages taken for new ones
         if self.model.routed:
             lo, hi = cfg.experts_held
             self._moe_held = hi - lo
-            self._moe_load = np.zeros((cfg.num_layers,), np.float64)
+            # the layers WITH a router (a prologue has none)
+            self._routed_layers = self.model.routed_layers(cfg)
+            self._moe_load = np.zeros((self._routed_layers,), np.float64)
         if self.model.recurrent:
             state_shape, tail_shape = self.model.state_shapes(cfg,
                                                               max_batch)
@@ -998,7 +1048,7 @@ class ServingEngine:
                     "router", op="ServingEngine.add_request")
             r.keep_routing = True
             r.routing = np.full(
-                (len(r.prompt) + r.max_new_tokens, self.cfg.num_layers,
+                (len(r.prompt) + r.max_new_tokens, self._routed_layers,
                  self.cfg.experts_per_tok), -1, np.int16)
         if deadline_s is not None:
             r.deadline = r.submit_time + float(deadline_s)
@@ -1246,6 +1296,10 @@ class ServingEngine:
             else:
                 b, _ = self._cached_free.popitem(last=False)
                 self._drop_cache_entry(b)
+                self.cache_evictions += 1
+                self._prom.counter_inc(
+                    "kv_prefix_evictions_total",
+                    help="cached-free prefix pages taken for new pages")
             self.refcount[b] = 1
             out.append(b)
         if self._numerics_kv and out:
@@ -1473,6 +1527,8 @@ class ServingEngine:
             self._lens[i] = start
             r.slot = i
             r.prefill_done = start
+            r.prefix_hit_tokens = start if matched else 0
+            self.prefix_hit_tokens += r.prefix_hit_tokens
             self._slots[i] = r
             fresh.append(i)
             if self.prefix_share:
@@ -1910,7 +1966,7 @@ class ServingEngine:
                 self.engine_steps, b.K, len(b.dec), len(b.pre), b.q_tokens,
                 b.kv_tokens, b.attn_pages, b.kv_tiles, int(prev is not None),
                 len(b.pre) - len(b.grants), sum(b.grants.values()),
-                self.token_budget))), **b.ssm_attrs):
+                self.token_budget))), **b.model_attrs):
             _faults().maybe_fail("serving/dispatch")
             out = self._unified(b.K, spec=b.use_spec)(*args)
         route = ()
@@ -1957,8 +2013,15 @@ class ServingEngine:
         self._moe_load += np.where(
             ran, stats[..., 2] * self._moe_held
             / np.maximum(stats[..., 1], 1), 0.0).sum(axis=0)
-        return dict(zip(MOE_FETCH_ATTRS, (
+        attrs = dict(zip(MOE_FETCH_ATTRS, (
             touched, assigned, int(stats[..., 2].max()))))
+        if stats.shape[-1] > 3:     # a group-limited router's two more
+            local, routed = (int(stats[..., 3].sum()),
+                             int(stats[..., 4].sum()))
+            self.moe_local_tokens += local
+            self.moe_tokens += routed
+            attrs.update(zip(MOE_LOCAL_FETCH_ATTRS, (local, routed)))
+        return attrs
 
     @staticmethod
     def _advance(q_lens, pos0, sample0, remaining, K):
@@ -2143,20 +2206,27 @@ class ServingEngine:
         if tile:
             kv_tiles = burst_rows + int(((kv_end[ran] - 1) // tile
                                          - pos0[ran] // tile + 1).sum())
-        ssm_attrs = {}
+        model_attrs = {}
         if self.model.recurrent:
             # a row that starts at position 0 has its state zeroed by the
             # program (admission, and re-prefill after a preemption)
             self.ssm_resets += int((ran & (pos0 == 0)).sum())
-            ssm_attrs = dict(zip(SSM_DISPATCH_ATTRS, (
+            model_attrs = dict(zip(SSM_DISPATCH_ATTRS, (
                 int(ran.sum()), burst_rows, cursor + burst_rows)))
+        if self._latent:
+            # the (query, key) pairs beyond the one a row `kv_tokens`
+            # counts: what a chunk against its prefix costs the attention
+            q, p = q_lens[ran].astype(np.int64), pos0[ran].astype(np.int64)
+            model_attrs = dict(zip(LATENT_DISPATCH_ATTRS, (
+                sum(self._slots[i].prefix_hit_tokens for i in fresh_slots),
+                int((q * p + q * (q + 1) // 2 - (p + q)).sum()))))
         return _PackedStep(
             dec=dec, pre=pre, ending=ending, grants=grants,
             props_by_slot=props_by_slot,
             use_spec=use_spec, K=K, q_tokens=cursor, kv_tokens=kv_tokens,
             attn_pages=attn_pages, kv_tiles=kv_tiles,
             starts=starts, pos0=pos0, q_lens=q_lens, emit=emit,
-            lens_after=lens_after, ssm_attrs=ssm_attrs,
+            lens_after=lens_after, model_attrs=model_attrs,
             # the tables are the engine's own and change under the step
             # in flight (a walk releases, an admission claims): the
             # program gets a copy

@@ -21,10 +21,21 @@ blocks and does nothing. The token rows are gathered into the padded
 buffer before the call and the weighted outputs gathered back after it
 (`combine`), both in XLA: at most TM - 1 padding rows an expert.
 
+An expert's WIDTH F is a second, inner grid axis: a step takes
+``[H, BLOCK_F]`` of the gate and the up matrix and ``[BLOCK_F, H]`` of the
+down matrix (at 5120 x 1536 an expert's three matrices are 47 MB, which no
+VMEM holds twice over; three 5120 x 512 blocks, double-buffered, are
+31 MB), the tile's float32 output stays where it is while the width's
+blocks add to it, and it is written back when the walk leaves the tile.
+An F of one block (2048 x 512) is the walk it was. A step past the last
+real tile stays on the LAST block of the width too.
+
 No assignment is ever dropped: there is no capacity.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +48,7 @@ from ...observability.trace import KERNELS
 __all__ = ["TM", "n_tiles_max", "plan", "grouped_ffn", "combine"]
 
 TM = 16     # rows of a tile: one bf16 sublane tile
+BLOCK_F = 512    # the most of an expert's width a grid step takes
 _F32 = jnp.float32
 
 
@@ -90,48 +102,70 @@ def plan(ids, lo, hi):
 
 
 def _ffn_kernel(layer_ref, expert_ref, n_ref, x_ref, g_ref, u_ref, d_ref,
-                y_ref):
+                y_ref, *, nf):
+    f = pl.program_id(1)
+
     @pl.when(pl.program_id(0) < n_ref[0])
     def _tile():
         x = x_ref[...]
         a = jnp.dot(x, g_ref[0, 0], preferred_element_type=_F32)
         b = jnp.dot(x, u_ref[0, 0], preferred_element_type=_F32)
         h = (a * jax.nn.sigmoid(a) * b).astype(x.dtype)
-        y_ref[...] = jnp.dot(h, d_ref[0, 0], preferred_element_type=_F32)
+        part = jnp.dot(h, d_ref[0, 0], preferred_element_type=_F32)
+        if nf == 1:
+            y_ref[...] = part
+        else:   # the output block stays in VMEM over the width's blocks
+            @pl.when(f == 0)
+            def _first():
+                y_ref[...] = part
+
+            @pl.when(f > 0)
+            def _add():
+                y_ref[...] += part
 
 
 def grouped_ffn(x, gate_w, up_w, down_w, layer, p):
     """x: [T, H], the pass's normed tokens; gate_w, up_w:
     [layers, E, H, F], down_w: [layers, E, F, H], the held experts of
-    every layer; p: `plan`'s dict. Returns y_pad [G * TM, H] float32: row
-    ``p['pos'][t, j]`` holds expert ``ids[t, j]``'s output for token t
-    (unweighted); rows of tiles past the last real one hold nothing
-    defined."""
+    every layer; p: `plan`'s dict. A grid step takes at most BLOCK_F of
+    an expert's width (F must be whole blocks). Returns y_pad
+    [G * TM, H] float32: row ``p['pos'][t, j]`` holds expert
+    ``ids[t, j]``'s output for token t (unweighted); rows of tiles past
+    the last real one hold nothing defined."""
     T, H = x.shape
     _, E, _, F = gate_w.shape
+    FB = min(BLOCK_F, F)
+    assert F % FB == 0, (F, FB)
+    nF = F // FB
     G = p["tile_expert"].shape[0]
     x_pad = jnp.concatenate([x, jnp.zeros((1, H), x.dtype)])[
         p["token_of_row"]]                                    # [G * TM, H]
 
-    def tile_idx(w, layer, expert, n):
+    def tile_idx(w, f, layer, expert, n):
         return (jnp.minimum(w, jnp.maximum(n[0] - 1, 0)), 0)
 
-    def weight_idx(w, layer, expert, n):
-        return (layer[0], expert[w], 0, 0)
+    def block_of(w, f, n):      # past the last real tile: stay put
+        return jnp.where(w < n[0], f, nF - 1)
 
-    weights = 2 * 3 * H * F * gate_w.dtype.itemsize     # double-buffered
+    def wide_idx(w, f, layer, expert, n):
+        return (layer[0], expert[w], 0, block_of(w, f, n))
+
+    def down_idx(w, f, layer, expert, n):
+        return (layer[0], expert[w], block_of(w, f, n), 0)
+
+    weights = 2 * 3 * H * FB * gate_w.dtype.itemsize    # double-buffered
     return pl.pallas_call(
-        _ffn_kernel,
+        functools.partial(_ffn_kernel, nf=nF),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3, grid=(G,),
+            num_scalar_prefetch=3, grid=(G, nF),
             in_specs=[pl.BlockSpec((TM, H), tile_idx),
-                      pl.BlockSpec((1, 1, H, F), weight_idx),
-                      pl.BlockSpec((1, 1, H, F), weight_idx),
-                      pl.BlockSpec((1, 1, F, H), weight_idx)],
+                      pl.BlockSpec((1, 1, H, FB), wide_idx),
+                      pl.BlockSpec((1, 1, H, FB), wide_idx),
+                      pl.BlockSpec((1, 1, FB, H), down_idx)],
             out_specs=pl.BlockSpec((TM, H), tile_idx)),
         out_shape=jax.ShapeDtypeStruct((G * TM, H), _F32),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=min(max(2 * weights, 32 << 20), 96 << 20)),
         interpret=_interpret(),
         name=KERNELS.moe_grouped_ffn,

@@ -190,7 +190,12 @@ class Serving:
 
     recurrent = True
     routed = False
+    latent = False
     state_shapes = staticmethod(state_shapes)
+
+    @staticmethod
+    def prologue(cfg):
+        return ("parallel", 0)      # no leading layers of another kind
 
     @staticmethod
     def pattern(cfg):

@@ -277,8 +277,17 @@ class Serving:
 
     recurrent = True
     routed = True
+    latent = False
     state_shapes = staticmethod(state_shapes)
     pattern = staticmethod(_runs)
+
+    @staticmethod
+    def prologue(cfg):
+        return ("attention", 0)     # no leading layers of another kind
+
+    @staticmethod
+    def routed_layers(cfg):
+        return cfg.num_layers           # every layer has a router
 
     @staticmethod
     def kv_layers(cfg):

@@ -45,7 +45,8 @@ from typing import Iterable, Optional
 from ..profiler.utils import HostEvent, RecordEvent, collector
 
 __all__ = ["span", "capture_spans", "write_chrome_trace", "SERVING_SPANS",
-           "DISPATCH_ATTRS", "SSM_DISPATCH_ATTRS", "MOE_FETCH_ATTRS",
+           "DISPATCH_ATTRS", "SSM_DISPATCH_ATTRS", "LATENT_DISPATCH_ATTRS",
+           "MOE_FETCH_ATTRS", "MOE_LOCAL_FETCH_ATTRS",
            "ADMISSION_ATTRS", "ADMIT_BLOCKED", "REQUEST_SPANS",
            "REQUEST_PHASES", "FIRST_TOKEN_ATTRS", "REQUEST_END_ATTRS",
            "SCOPES", "KERNELS"]
@@ -99,6 +100,19 @@ SSM_DISPATCH_ATTRS = ("ssm_scan_rows", "ssm_update_rows", "ssm_tokens")
 # experts; and the largest number of assignments one held expert got in a
 # layer of a pass.
 MOE_FETCH_ATTRS = ("moe_experts_touched", "moe_assignments", "moe_load_max")
+# A router that keeps a token to a few GROUPS of experts (a group lies on
+# one chip) adds: tokens with at least one pick among the held experts,
+# and the tokens routed (padding is not), both summed over the step's
+# expert layers and passes.
+MOE_LOCAL_FETCH_ATTRS = ("moe_local_tokens", "moe_tokens")
+# A model whose cache is a LATENT (one vector a token and layer, shared by
+# all heads; `kv_tokens`, `attn_pages` and `kv_tiles` above count its pages
+# alike) adds: prompt tokens of the rows admitted in this step that prefix
+# sharing found computed (never run again), and the (query, key) pairs its
+# attention computes beyond the one a row that `kv_tokens` counts: a row of
+# q tokens from position p adds q p + q (q + 1) / 2 - (p + q), so
+# `kv_tokens + chunk_ctx_tokens` is the causal pair count of the step.
+LATENT_DISPATCH_ATTRS = ("prefix_hit_tokens", "chunk_ctx_tokens")
 # What `serving_admission` closes with: requests admitted by this call,
 # the queue's depth after it, why the queue's head still waits (one of
 # ADMIT_BLOCKED), and decode victims this call evicted for it.
@@ -153,6 +167,10 @@ SCOPES = _names(
     moe_route="moe_route", moe_experts="moe_experts", moe_shared="moe_shared",
     gdn_in="gdn_in", gdn_conv="gdn_conv", gdn_scan="gdn_scan",
     gdn_out="gdn_out",
+    # latent attention (models/deepseek_v2.py): the kernel over latent
+    # pages, and every projection around it (down and up projections, both
+    # norms, rotary, the two absorptions, the output projection)
+    mla_attn="mla_attn", mla_proj="mla_proj",
     # train programs (models/gpt.py, optimizer/); embed and qkv as above
     attn="attn", flash="flash", attn_out="attn_out", mlp="mlp",
     head_loss="head_loss", optimizer="optimizer",
@@ -175,7 +193,10 @@ KERNELS = _names(
     # the gated delta rule's state path (kernels/pallas/gdn.py) and the
     # grouped expert product (kernels/pallas/moe.py)
     gdn_chunk_scan="gdn_chunk_scan", gdn_state_update="gdn_state_update",
-    moe_grouped_ffn="moe_grouped_ffn")
+    moe_grouped_ffn="moe_grouped_ffn",
+    # absorbed attention over latent pages and the latent's append
+    # (kernels/pallas/mla_attention.py, kernels/pallas/latent_append.py)
+    mla_paged_attn="mla_paged_attn", latent_append="latent_append")
 
 
 class capture_spans:

@@ -34,7 +34,7 @@ from jax.sharding import SingleDeviceSharding
 _KERNEL_MODULES = ("flash_attention", "layer_norm", "rms_norm", "rope",
                    "primitives", "fused_adam", "paged_attention",
                    "ragged_paged_attention", "kv_append", "ssm", "gdn",
-                   "moe")
+                   "moe", "mla_attention", "latent_append")
 
 # a small serving pool's geometry: GPT-1.3B heads, 128-token pages
 HEADS, HEAD_DIM, PAGE, NUM_PAGES, PAGES_PER_SEQ = 16, 128, 128, 64, 8
@@ -569,6 +569,85 @@ def test_qwen3_next_step_keeps_state_pool_and_experts_in_place(
     donated = (2 * math.prod(pool_shape) * 2 + math.prod(state_shape) * 4
                + math.prod(tail_shape) * 2)
     assert mem.alias_size_in_bytes >= donated
+
+
+# ---------------------------------------------------------------------------
+# the DeepSeek-V2 cell's step (ISSUE 51): published widths, layer 0 dense +
+# layers 1-5 with group 0's 20 experts, an eighth of the vocabulary, 64
+# slots, 2,816 latent pages, tables of 264, chunks of 256, prefix sharing on
+# ---------------------------------------------------------------------------
+DSV2_ROWS, DSV2_PAGES, DSV2_TABLE, DSV2_CHUNK = 64, 2816, 264, 256
+
+
+def _compile_deepseek_v2_step(one_chip, K):
+    """(compiled step, params, the two pools' shapes)."""
+    from paddle_tpu.inference import ragged_step as RS
+    from paddle_tpu.models import deepseek_v2 as DS
+    cfg = DS.DeepseekV2Config(vocab_size=12800, num_layers=6,
+                              experts_held=(0, 20))
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: DS.init_params(cfg, jax.random.PRNGKey(0))))
+    R, tokens = DSV2_ROWS, DSV2_ROWS + DSV2_CHUNK
+
+    def i32(*shape):
+        return _sds(one_chip, shape, jnp.int32)
+
+    def flags():
+        return _sds(one_chip, (R,), jnp.bool_)
+
+    shapes = [(6, h, DSV2_PAGES, PAGE, d)
+              for h, d in DS.Serving.pool_shapes(cfg)]
+    pools = [_sds(one_chip, shape, jnp.bfloat16) for shape in shapes]
+    args = [params, i32(tokens), i32(tokens), i32(tokens), i32(R), i32(R),
+            i32(R), i32(R, DSV2_TABLE), flags(), flags(), i32(R), i32(R),
+            _sds(one_chip, (R,), jnp.float32), i32(R),
+            _sds(one_chip, (2,), jnp.uint32), *pools, None, None,
+            i32(R), i32(R), i32(R, DSV2_TABLE)]     # the copy-on-write's
+    step = functools.partial(RS.unified_step, cfg=cfg, bs=PAGE,
+                             c_att=DSV2_CHUNK, K=K)
+    compiled = jax.jit(step, donate_argnums=(15, 16)).lower(*args).compile()
+    return compiled, params, shapes
+
+
+# what the step is KNOWN to move of a weight's shape, as found (PERF.md
+# section 7, PR 51), for the PR that repairs it to empty and so that nothing
+# joins it unseen. A layer's W_UK [128, 128, 512] and W_UV [128, 512, 128]
+# are NOT in it: the batched products that absorb them slice their layer out
+# of the stack inside their own fusions. At K > 1 the stacks of the two
+# matrices whose width is no whole lane tile (W_DKV's 576, the router's 160)
+# are copied once a step, before the loop over the K passes: 37.7 MB read
+# and written, ~0.09 ms of a step of 8 passes at the chip's 819 GB/s
+_DSV2_KNOWN = {1: set(), 8: {(5, 1, 5120, 576), (5, 1, 5120, 160)}}
+
+
+@pytest.mark.parametrize("K", [1, 8], ids=["dsv2-pass1", "dsv2-burst"])
+def test_deepseek_v2_step_keeps_pool_and_experts_in_place(
+        one_chip, compiled_kernels, K):
+    """The prologue and the period scan keep the contract on the latent
+    pools: no copy, slice or update the size of a pool (all layers' or one
+    layer's pages) or of a layer's expert matrices; temp under 1 GiB;
+    both pools come back aliased; `mla_paged_attn`, `latent_append` and
+    the width-tiled `moe_grouped_ffn` lower at the published widths. A
+    2-D GEMM takes its layer's weight where it lies (the barrier of
+    ISSUE 43 around W_UQ's and W_DKV's products), W_UK and W_UV where
+    they lie too; what is still moved of a weight's shape is
+    `_DSV2_KNOWN`, no more and no less."""
+    compiled, params, shapes = _compile_deepseek_v2_step(one_chip, K)
+    assert shapes == [(6, 1, 2816, 128, 512), (6, 1, 2816, 128, 128)]
+    text = compiled.as_text()
+    for kernel in ("mla_paged_attn", "latent_append", "moe_grouped_ffn"):
+        assert kernel in text, f"{kernel} was not lowered for the chip"
+    buffers = [s[i:] for s in shapes for i in (0, 2)]
+    for expert in ((20, 5120, 1536), (20, 1536, 5120)):
+        buffers += [expert, (5,) + expert]
+    assert _moved(text, {_no_leading_ones(b) for b in buffers}.__contains__,
+                  by_shape=True) == []
+    found = _weight_copies(text, (params["blocks"], params["prologue"]))
+    assert set(found) == _DSV2_KNOWN[K], found
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= sum(2 * math.prod(s) for s in shapes)
 
 
 # ---------------------------------------------------------------------------

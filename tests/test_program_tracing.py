@@ -36,10 +36,13 @@ from paddle_tpu.inference.serving import ServingEngine  # noqa: E402
 from paddle_tpu.models import gpt as G  # noqa: E402
 from paddle_tpu.models import falcon_h1 as FH  # noqa: E402
 from paddle_tpu.models import qwen3_next as QN  # noqa: E402
+from paddle_tpu.models import deepseek_v2 as DS  # noqa: E402
 from paddle_tpu.observability.trace import (ADMISSION_ATTRS,  # noqa: E402
                                             ADMIT_BLOCKED, DISPATCH_ATTRS,
                                             FIRST_TOKEN_ATTRS, KERNELS,
+                                            LATENT_DISPATCH_ATTRS,
                                             MOE_FETCH_ATTRS,
+                                            MOE_LOCAL_FETCH_ATTRS,
                                             REQUEST_END_ATTRS,
                                             REQUEST_PHASES, REQUEST_SPANS,
                                             SCOPES, SERVING_SPANS,
@@ -409,6 +412,52 @@ def test_the_pattern_serving_step_carries_its_scopes_and_attributes():
                and a["moe_load_max"] <= a["moe_assignments"] for a in fetch)
 
 
+def test_the_latent_serving_step_carries_its_scopes_and_attributes():
+    """DeepSeek-V2 through the same engine, prefix sharing on: latent
+    attention's two scopes in place of `qkv`, `rope` and `ragged_attn`,
+    the expert layer's three, `proj_mlp` for the leading dense layer's
+    FFN, `cow` for the copy-on-write; the dispatch span carries the prefix
+    hit and the chunk's (query, key) pairs, the fetch span the router's
+    counts and the group-limited router's two more."""
+    cfg = DS.DeepseekV2Config(
+        vocab_size=64, hidden_size=32, num_layers=3, num_heads=2,
+        q_lora_rank=16, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=8, intermediate_size=48, moe_ffn=16,
+        num_experts=8, experts_per_tok=2, shared_ffn=16, n_group=4,
+        topk_group=2, experts_held=(0, 2), rope_original_max=16,
+        dtype=jnp.float32, param_dtype=jnp.float32)
+    params = DS.init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(params, cfg, max_batch=2, block_size=16,
+                        num_blocks=16, chunk=8, decode_burst=2,
+                        prefix_share=True)
+    eng.add_request(np.arange(20) % 64, max_new_tokens=4)
+    batch = eng._pack_ragged(eng._admit())
+    lowered = eng._build_unified(2).lower(*eng._upload_ragged(batch))
+    assert _scopes_in(lowered) == {
+        SCOPES.embed, SCOPES.mla_proj, SCOPES.kv_write, SCOPES.mla_attn,
+        SCOPES.moe_route, SCOPES.moe_experts, SCOPES.moe_shared,
+        SCOPES.proj_mlp, SCOPES.head, SCOPES.sample, SCOPES.cow,
+        SCOPES.burst}
+    with obs.capture_spans() as cap:
+        eng.run()
+        eng.add_request(np.arange(24) % 64, max_new_tokens=3)   # a hit
+        eng.run()
+    disp = [e.attrs for e in cap.events if e.name == SERVING_SPANS.dispatch]
+    assert all(tuple(a) == DISPATCH_ATTRS + LATENT_DISPATCH_ATTRS
+               for a in disp)
+    assert sum(a["prefix_hit_tokens"] for a in disp) == 16 == \
+        eng.prefix_hit_tokens
+    # the first step ran 8 prompt tokens from position 0: 8 * 9 / 2 pairs,
+    # of which kv_tokens counts the row's 8
+    assert disp[0]["kv_tokens"] + disp[0]["chunk_ctx_tokens"] == 36
+    fetch = [e.attrs for e in cap.events if e.name == SERVING_SPANS.fetch]
+    assert all(tuple(a) == MOE_FETCH_ATTRS + MOE_LOCAL_FETCH_ATTRS
+               for a in fetch)
+    assert sum(a["moe_tokens"] for a in fetch) == eng.moe_tokens == \
+        2 * ((20 + 3) + (8 + 2))
+    assert sum(a["moe_local_tokens"] for a in fetch) == eng.moe_local_tokens
+
+
 # -- a request's life, and the scheduler's choices (ISSUE 38) ----------------
 def test_a_span_takes_attributes_until_it_closes(tmp_path):
     """`RecordEvent.set` merges into the span's attributes and reaches a
@@ -707,8 +756,9 @@ def test_no_scope_or_serving_span_is_a_free_string():
 SLICE_FILES = sorted(map(os.path.basename, glob.glob(
     os.path.join(DATA, "ptrace-*.json.gz"))))
 # what each span may carry, by the tuple the call site takes it from
-SPAN_ATTRS = {SERVING_SPANS.dispatch: DISPATCH_ATTRS + SSM_DISPATCH_ATTRS,
-              SERVING_SPANS.fetch: MOE_FETCH_ATTRS,
+SPAN_ATTRS = {SERVING_SPANS.dispatch: (DISPATCH_ATTRS + SSM_DISPATCH_ATTRS
+                                       + LATENT_DISPATCH_ATTRS),
+              SERVING_SPANS.fetch: MOE_FETCH_ATTRS + MOE_LOCAL_FETCH_ATTRS,
               SERVING_SPANS.admission: ADMISSION_ATTRS,
               REQUEST_SPANS.first_token: FIRST_TOKEN_ATTRS,
               REQUEST_SPANS.end: REQUEST_END_ATTRS}
@@ -748,7 +798,7 @@ def _named(params):
     spans = [params.get(k, []) for k in ("spans", "span", "until", "per")]
     return ({s for v in spans for s in ([v] if isinstance(v, str) else v)},
             {params[k] for k in ("attr", "num", "den") if params.get(k)}
-            | set(params.get("attrs", [])))
+            | set(params.get("attrs", [])) | set(params.get("per_unit", {})))
 
 
 def traced(spec, cell):
