@@ -64,9 +64,9 @@ compressed vector's ``[L, 1, NB, bs, C]`` and the shared rotary key's
 ``[L, 1, NB, bs, Rd]`` (512 wide, and the 64-wide key in a page of 128
 lanes: two pools so that each page is whole lane tiles of its own width;
 the head axis of 1 keeps the page-flat view, the copy-on-write above and
-the allocator as they are), written by
-`kernels.pallas.latent_append` on `kv_append`'s work list and read where
-they lie by `kernels.pallas.mla_attention`; a layer of kind "latent" takes
+the allocator as they are), written by `kernels.pallas.latent_append` on
+`kv_append`'s work list and read where they lie by `mla_attention` (its
+items once a pass too); a layer of kind "latent" takes
 the model's `latent_qkv` in place of `qkv`. A model's PROLOGUE
 (``prologue(cfg)``: a kind and a count) is a run of leading layers under
 ``params["prologue"]``, scanned before the periods with no experts; the
@@ -94,7 +94,7 @@ from jax import lax
 from ..observability.trace import SCOPES
 from ..kernels.pallas.kv_append import append_tile, kv_append, tile_work
 from ..kernels.pallas.latent_append import latent_append
-from ..kernels.pallas.mla_attention import mla_paged_attention
+from ..kernels.pallas.mla_attention import mla_items, mla_paged_attention
 from ..kernels.pallas.ragged_paged_attention import ragged_paged_attention
 from ..quantization.kv_cache import (append_tokens_quantized, page_rows,
                                      reset_page_scales)
@@ -135,17 +135,17 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
         tile = append_tile(kp.dtype, bs)
         work = tile_work(starts, pos0, q_lens, tables, bs=bs, tile=tile,
                          c_att=c_att, T=T)
-    # a row's tokens as a [c_att] window of the packed buffer, for the
-    # quantized append and a recurrent mixer (clamped duplicates lie past
-    # the row's q_len, which both mask); attention takes the packed buffer
+    # a row's tokens as a [c_att] window of the packed buffer, for the quantized
+    # append and a recurrent mixer (both mask the clamped duplicates past q_len)
     tile_idx = jnp.clip(
         starts[:, None] + jnp.minimum(jnp.arange(c_att)[None, :],
                                       jnp.maximum(q_lens - 1, 0)[:, None]),
         0, T - 1)                                            # [R, c_att]
     scale = 1.0 / (cfg.head_dim ** 0.5)
     extra = {}
-    if model.latent:                        # padding is not routed
+    if model.latent:    # padding is not routed; attention's items: once a pass
         extra = {"real": off_of < q_lens[row_of]}
+        items = mla_items(tables, q_lens, kv_lens, bs=bs, c_att=c_att, T=T)
     if ssm is not None:
         # a row that starts at position 0 starts from a zero state
         plan = {"row_of": row_of, "off_of": off_of, "starts": starts,
@@ -184,8 +184,8 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
                 with jax.named_scope(SCOPES.mla_attn):
                     attn_p = mla_paged_attention(
                         qa, qr, kp, vp, tables, starts, q_lens, kv_lens,
-                        model.attn_scale(cfg), att,
-                        c_att=c_att)[None]                   # [1,T,h,C]
+                        model.attn_scale(cfg), att, c_att=c_att,
+                        work=items)[None]                    # [1,T,h,C]
             else:
                 q, k, v, u = model.qkv(p, x, pos_t[None], cfg, mp_axis)
                 mixed = u                                    # [1,T,h,D]

@@ -141,6 +141,7 @@ import numpy as np
 from jax import lax
 
 from ..kernels.pallas.kv_append import append_tile
+from ..kernels.pallas.mla_attention import shared_pages
 from ..models import gpt as G
 from ..observability.trace import (ADMISSION_ATTRS, ADMIT_BLOCKED,
                                    DISPATCH_ATTRS, FIRST_TOKEN_ATTRS,
@@ -2193,11 +2194,19 @@ class ServingEngine:
         kv_tokens = int(kv_end[ran].sum())
         attn_pages = int((-(-kv_end[ran] // self.bs)).sum())
         burst_rows = 0      # rows the K-1 burst passes run, summed
+        # of `attn_pages`, the pairs that rows of one token on one prefix
+        # attend TOGETHER (a latent cache's kernel groups them by itself,
+        # from the tables: `mla_attention.decode_groups`)
+        shared = (shared_pages(self.tables, q_lens, kv_end, bs=self.bs)
+                  if self._latent else 0)
         for j in range(1, K):
             alive = emit > j
             kv_tokens += int((kv_end[alive] + j).sum())
             attn_pages += int((-(-(kv_end[alive] + j) // self.bs)).sum())
             burst_rows += int(alive.sum())
+            if self._latent:
+                shared += shared_pages(self.tables, alive.astype(np.int32),
+                                       kv_end + j, bs=self.bs)
         # the tiles the in-place append walks
         # (`kernels.pallas.kv_append.tile_work`'s n, summed over the
         # passes): a row of pass 1 the tiles its new positions lie in, a
@@ -2219,7 +2228,7 @@ class ServingEngine:
             q, p = q_lens[ran].astype(np.int64), pos0[ran].astype(np.int64)
             model_attrs = dict(zip(LATENT_DISPATCH_ATTRS, (
                 sum(self._slots[i].prefix_hit_tokens for i in fresh_slots),
-                int((q * p + q * (q + 1) // 2 - (p + q)).sum()))))
+                int((q * p + q * (q + 1) // 2 - (p + q)).sum()), shared)))
         return _PackedStep(
             dec=dec, pre=pre, ending=ending, grants=grants,
             props_by_slot=props_by_slot,

@@ -111,8 +111,13 @@ MOE_LOCAL_FETCH_ATTRS = ("moe_local_tokens", "moe_tokens")
 # sharing found computed (never run again), and the (query, key) pairs its
 # attention computes beyond the one a row that `kv_tokens` counts: a row of
 # q tokens from position p adds q p + q (q + 1) / 2 - (p + q), so
-# `kv_tokens + chunk_ctx_tokens` is the causal pair count of the step.
-LATENT_DISPATCH_ATTRS = ("prefix_hit_tokens", "chunk_ctx_tokens")
+# `kv_tokens + chunk_ctx_tokens` is the causal pair count of the step; and
+# `attn_shared_pages`: of the step's `attn_pages`, the (row, page) pairs
+# that rows of one token whose tables share leading pages attended
+# TOGETHER (phase A of a group of two or more rows in
+# `kernels.pallas.mla_attention`), summed over the k passes.
+LATENT_DISPATCH_ATTRS = ("prefix_hit_tokens", "chunk_ctx_tokens",
+                         "attn_shared_pages")
 # What `serving_admission` closes with: requests admitted by this call,
 # the queue's depth after it, why the queue's head still waits (one of
 # ADMIT_BLOCKED), and decode victims this call evicted for it.

@@ -195,9 +195,35 @@ def naive_attention(qa, qr, cp, rp, tables, starts, q_lens, kv_lens, scale,
     return out
 
 
+# c_att, q_lens, pos0, then for tables that SHARE leading pages: (rows,
+# pages) that take the first of the rows' leading table entries, the size
+# of the group each row's last token is then found in, and the pages that
+# group attends together (at one page a step of the page loop)
 ARMS = {"decode": (1, [1, 0, 1, 1], [5, 0, 16, 47]),
         "chunk": (12, [1, 12, 0, 5], [33, 20, 0, 0]),
-        "chunk-ragged-tail": (12, [7, 1, 9, 3], [0, 15, 30, 63])}
+        "chunk-ragged-tail": (12, [7, 1, 9, 3], [0, 15, 30, 63]),
+        # a group of 2 on three pages (one step and a page of its own at
+        # 2 a step) and a group of 3 on two, an off row between them,
+        # every context ending mid-page on a tail of its own
+        "group-2-and-3": (1, [1, 1, 1, 0, 1, 1], [50, 61, 40, 0, 33, 47],
+                          [((0, 1), 3), ((2, 4, 5), 2)],
+                          [2, 2, 3, 1, 3, 3], [3, 3, 2, 0, 2, 2]),
+        # six rows on one document, four to a group: 4 + 2
+        "group-splits": (1, [1, 1, 1, 1, 0, 1, 1],
+                         [35, 44, 70, 32, 0, 59, 63],
+                         [((0, 1, 2, 3, 5, 6), 2)], [4, 4, 4, 4, 1, 2, 2],
+                         [2, 2, 2, 2, 0, 2, 2]),
+        # row 2 wrote to its own copy of the third page: the group's
+        # members agree on two
+        "group-copy-on-write": (1, [1, 1, 1], [66, 52, 79],
+                                [((0, 1, 2), 2), ((0, 1), 3)], [3, 3, 3],
+                                [2, 2, 2]),
+        # a group beside a chunk of 9 (tiles of 4, 4 and its last token, a
+        # group of one) in one pass; row 4's only page is its own
+        "group-beside-chunk": (12, [1, 9, 1, 0, 1, 1],
+                               [37, 30, 45, 0, 34, 7],
+                               [((0, 2, 4), 2)], [3, 1, 3, 1, 3, 1],
+                               [2, 2, 2, 0, 2, 0])}
 
 
 @pytest.mark.parametrize("memory,kp", [("copied", 1), ("aliased", 1),
@@ -208,18 +234,26 @@ def test_absorbed_attention_and_the_append_over_ragged_tables(arm, memory,
     """`mla_paged_attention` against naive attention over the same pages
     (rows of 1 token on the decode arm, chunks in tiles of 4 tokens on the
     chunk arm, rows that are off, contexts that end mid-page, one page and
-    two pages a step of the page loop), and
+    two pages a step of the page loop; rows whose tables share their
+    leading pages, which the kernel attends together), and
     `latent_append` against a loop, layer 1 of 2."""
     if memory == "aliased":
         request.getfixturevalue("aliasing")
-    c_att, q_lens, pos0 = ARMS[arm]
+    c_att, q_lens, pos0, *shared = ARMS[arm]
     rng = np.random.default_rng(0)
-    L, bs, C, Rd, H, R, nb = 2, 16, 32, 8, 8, 4, 5
+    L, bs, C, Rd, H, R, nb = 2, 16, 32, 8, 8, len(q_lens), 5
     T = 24 if c_att > 1 else R
     q_lens, pos0 = np.array(q_lens), np.array(pos0)
     kv_lens = pos0 + q_lens
     starts = np.concatenate([[0], np.cumsum(q_lens)[:-1]])
     tables = rng.permutation(np.arange(1, 1 + R * nb)).reshape(R, nb)
+    if shared:
+        for rows, pages in shared[0]:
+            tables[list(rows), :pages] = tables[rows[0], :pages]
+        _, _, sizes, steps = MA.decode_groups(tables, q_lens, kv_lens, bs=bs,
+                                              kp=kp, cap=4, xp=np)
+        assert list(sizes) == shared[1]
+        assert list(steps) == [pages // kp for pages in shared[2]]
     cp = rng.standard_normal((L, 1, R * nb + 1, bs, C)).astype(np.float32)
     rp = rng.standard_normal((L, 1, R * nb + 1, bs, Rd)).astype(np.float32)
     qa = rng.standard_normal((T, H, C)).astype(np.float32)
@@ -245,6 +279,66 @@ def test_absorbed_attention_and_the_append_over_ragged_tables(arm, memory,
             cp[1, 0, tables[r, at // bs], at % bs] = c_new[starts[r] + c]
             rp[1, 0, tables[r, at // bs], at % bs] = r_new[starts[r] + c]
     assert (np.asarray(c2) == cp).all() and (np.asarray(r2) == rp).all()
+
+
+def test_the_hosts_count_of_shared_pages_is_the_work_lists():
+    """`attn_shared_pages` is counted on the host by the rule the device
+    groups by (`decode_groups`, numpy there and jax.numpy here): on random
+    tables whose rows share the leading pages of a few documents, some
+    after a page of their own, the host's count is the members x shared
+    pages of the work list's groups of two or more, and the list's groups
+    hold every row of one token once (and a chunk's last tile of one)."""
+    rng = np.random.default_rng(3)
+    bs, R, nb, kp = 16, 12, 12, MA.KP
+    seen = 0
+    for _ in range(24):
+        tables = rng.permutation(np.arange(1, 1 + R * nb)).reshape(R, nb)
+        for rows in np.array_split(rng.permutation(R), 3):   # a document
+            pages = rng.integers(0, nb)
+            tables[rows, :pages] = tables[rows[0], :pages]
+            if pages > 2:       # one row wrote to its copy of a page
+                tables[rows[-1], rng.integers(1, pages)] = R * nb + 1
+        q_lens = rng.choice([0, 1, 1, 1, 1, 5, 9], R)
+        kv_lens = np.where(q_lens > 0, rng.integers(q_lens, nb * bs + 1), 0)
+        c_att = int(max(q_lens.max(), 1))
+        n, row, c0, n_tok, size, steps, members = (
+            np.asarray(a) for a in MA.mla_items(
+                jnp.asarray(tables), jnp.asarray(q_lens),
+                jnp.asarray(kv_lens), bs=bs, c_att=c_att, T=64))
+        group = (np.arange(len(row)) < n[0]) & (n_tok == 1)
+        want = int((size * steps * kp)[group & (size > 1)].sum())
+        assert MA.shared_pages(tables, q_lens, kv_lens, bs=bs) == want
+        held = np.concatenate([members.reshape(len(row), -1)[w, :size[w]]
+                               for w in np.flatnonzero(group)] + [[]])
+        tile = min(MA.TQ, c_att)    # a chunk's last tile of ONE token
+        assert sorted(held) == sorted(np.flatnonzero(
+            (q_lens == 1) | ((q_lens > 1) & (q_lens % tile == 1))))
+        seen += want
+    assert seen > 0
+
+
+def test_the_work_list_is_made_once_a_pass_not_once_a_layer(params):
+    """`ragged_pass` lists latent attention's items before its layer scans
+    and every layer's call takes that list: in the step's jaxpr the
+    grouping (its one `cumprod`) stands once a PASS (pass 1 and the burst's
+    scan body), not once in each of the two layer scans of each."""
+    eng = ServingEngine(params, toy_cfg(), **ENGINE)
+    eng.add_request(prompt_of(20), 4)
+    args = eng._upload_ragged(eng._pack_ragged(eng._admit()))
+
+    def count(jaxpr, name):
+        n = 0
+        for eqn in jaxpr.eqns:
+            n += eqn.primitive.name == name
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else [v]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        n += count(sub, name)
+        return n
+
+    jaxpr = jax.make_jaxpr(eng._build_unified(4))(*args).jaxpr
+    assert count(jaxpr, "scan") >= 5 and count(jaxpr, "cumprod") == 2
 
 
 def test_the_blocked_reference_is_the_one_line_form(params):

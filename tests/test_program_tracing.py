@@ -249,6 +249,56 @@ def test_attn_pages_counts_the_row_page_pairs_of_a_hand_built_step():
     assert seen == [(12, 2, 2), (21, 2, 3), (27, 3, 3), (29, 3, 2)]
 
 
+def tiny_latent_cfg(num_layers):
+    return DS.DeepseekV2Config(
+        vocab_size=64, hidden_size=32, num_layers=num_layers, num_heads=2,
+        q_lora_rank=16, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=8, intermediate_size=48, moe_ffn=16,
+        num_experts=8, experts_per_tok=2, shared_ffn=16, n_group=4,
+        topk_group=2, experts_held=(0, 2), rope_original_max=16,
+        dtype=jnp.float32, param_dtype=jnp.float32)
+
+
+def test_attn_shared_pages_counts_what_two_rows_on_one_document_share():
+    """A latent cache's attention attends the leading pages that decode
+    rows' tables share ONCE for those rows (`mla_attention`'s group item),
+    and `attn_shared_pages` says how many of the step's `attn_pages` went
+    that way. By hand: a document of 4 pages (16-token pages, the kernel's
+    4 pages a step) and a bit; its owner decodes while a question about it
+    arrives, hits the 4 pages and decodes beside it: 2 rows x 4 pages a
+    pass that both run, and nothing while either is alone or prefilling."""
+    cfg = tiny_latent_cfg(num_layers=2)
+    params = DS.init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(params, cfg, max_batch=2, block_size=16,
+                        num_blocks=16, max_blocks_per_seq=8, chunk=40,
+                        decode_burst=2, prefix_share=True)
+    doc = np.arange(70) % 64
+    eng.add_request(doc, max_new_tokens=12)
+    seen = []
+
+    def steps(n):
+        for _ in range(n):
+            with obs.capture_spans() as cap:
+                eng.step()
+            eng.settle()
+            seen.extend((e.attrs["k"], e.attrs["n_dec"], e.attrs["attn_pages"],
+                         e.attrs["attn_shared_pages"]) for e in cap.events
+                        if e.name == SERVING_SPANS.dispatch)
+
+    steps(3)        # 40 + 30 prompt tokens, then the owner decodes alone
+    eng.add_request(np.concatenate([doc[:64], np.arange(5)]),
+                    max_new_tokens=6)
+    steps(4)
+    assert eng.prefix_hit_tokens == 64
+    # (k, decode rows, attn_pages, attn_shared_pages): the owner's chunks
+    # (3 and 5 pages), its two passes alone at 71 and 72 positions, the
+    # question's 5 tokens beside it, then both rows on their fifth page,
+    # two passes a step; in the last the question has one token left, so
+    # the second pass has the owner alone
+    assert seen == [(1, 0, 3, 0), (1, 0, 5, 0), (2, 1, 10, 0), (1, 1, 10, 0),
+                    (2, 2, 20, 16), (2, 2, 20, 16), (2, 2, 15, 8)]
+
+
 def test_an_idle_step_keeps_its_spans():
     cfg = tiny_cfg()
     params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
@@ -419,13 +469,7 @@ def test_the_latent_serving_step_carries_its_scopes_and_attributes():
     FFN, `cow` for the copy-on-write; the dispatch span carries the prefix
     hit and the chunk's (query, key) pairs, the fetch span the router's
     counts and the group-limited router's two more."""
-    cfg = DS.DeepseekV2Config(
-        vocab_size=64, hidden_size=32, num_layers=3, num_heads=2,
-        q_lora_rank=16, kv_lora_rank=16, qk_nope_head_dim=8,
-        qk_rope_head_dim=8, v_head_dim=8, intermediate_size=48, moe_ffn=16,
-        num_experts=8, experts_per_tok=2, shared_ffn=16, n_group=4,
-        topk_group=2, experts_held=(0, 2), rope_original_max=16,
-        dtype=jnp.float32, param_dtype=jnp.float32)
+    cfg = tiny_latent_cfg(num_layers=3)
     params = DS.init_params(cfg, jax.random.PRNGKey(0))
     eng = ServingEngine(params, cfg, max_batch=2, block_size=16,
                         num_blocks=16, chunk=8, decode_burst=2,
