@@ -118,8 +118,10 @@ def profile_candidate(cfg, cand: PlanCandidate, *, family: str = "gpt",
         else paddle.optimizer.AdamW(learning_rate=1e-4)
     kw = cand.engine_kwargs(family=family, global_batch=global_batch,
                             seq=seq)
+    # capture_step_profile calls the step with the SAME args every time:
+    # the window's step may not consume them
     step, shard_params, init_state = M.build_hybrid_train_step(
-        cfg, mesh, opt, **kw)
+        cfg, mesh, opt, donate=False, **kw)
     if host_params is None:
         host_params = M.init_hybrid_params(cfg, jax.random.PRNGKey(0))
     with mesh:

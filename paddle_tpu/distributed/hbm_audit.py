@@ -52,11 +52,18 @@ def per_device_bytes(shapes, specs, mesh: Mesh) -> int:
 
 
 def _mem_stats(compiled) -> Dict[str, int]:
+    """The compiler's byte accounting of one step. `resident_bytes` is
+    what the step holds at once: arguments, temp, and the outputs that
+    alias no argument (a hybrid step donates params and optimizer state,
+    so they count once; built with donate=False they count twice)."""
     try:
         ma = compiled.memory_analysis()
-        return {"argument_bytes": int(ma.argument_size_in_bytes),
-                "output_bytes": int(ma.output_size_in_bytes),
-                "temp_bytes": int(ma.temp_size_in_bytes)}
+        arg, out, alias, temp = (
+            int(ma.argument_size_in_bytes), int(ma.output_size_in_bytes),
+            int(ma.alias_size_in_bytes), int(ma.temp_size_in_bytes))
+        return {"argument_bytes": arg, "output_bytes": out,
+                "alias_bytes": alias, "temp_bytes": temp,
+                "resident_bytes": arg + out - alias + temp}
     except Exception:  # backend without memory analysis
         return {}
 

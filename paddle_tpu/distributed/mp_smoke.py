@@ -213,8 +213,9 @@ def run_training_resilient(mesh, steps: int, ckpt_dir: str):
                       num_heads=4, max_seq_len=16, dtype=jnp.float32)
     params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
     opt = paddle.optimizer.AdamW(learning_rate=1e-2)
+    # run_resilient falls back to the state a rejected step was given
     step, shard_params, init_state = G.build_hybrid_train_step(
-        cfg, mesh, opt)
+        cfg, mesh, opt, donate=False)
     params = shard_params(params)
     state = {"params": params, "opt": init_state(params)}
     rng = np.random.RandomState(0)
@@ -257,8 +258,9 @@ def run_training_fleet(mesh, steps: int, store, rank: int, world: int,
                       num_heads=4, max_seq_len=16, dtype=jnp.float32)
     params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
     opt = paddle.optimizer.AdamW(learning_rate=1e-2)
+    # run_resilient falls back to the state a rejected step was given
     step, shard_params, init_state = G.build_hybrid_train_step(
-        cfg, mesh, opt)
+        cfg, mesh, opt, donate=False)
     params = shard_params(params)
     state = {"params": params, "opt": init_state(params)}
     rng = np.random.RandomState(0)

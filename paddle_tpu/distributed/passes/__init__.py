@@ -323,7 +323,10 @@ def build_train_step(spec: TrainSpec, vpp_layers: Optional[int] = None):
     Returns (step, shard_params, init_state) from
     models.hybrid_engine.build_train_step. `vpp_layers` (total block count)
     re-layouts stacked block params chunk-major when the spec's schedule is
-    VPP with virtual_pp > 1.
+    VPP with virtual_pp > 1. The step donates (params, opt_state), as the
+    engine's does by default: rebind its outputs (a parity run that feeds
+    the same trees to both sides builds through TrainSpec.build(
+    donate=False)).
     """
     import jax
 

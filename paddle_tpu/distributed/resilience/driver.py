@@ -91,6 +91,21 @@ def _loss_value(loss) -> Optional[float]:
         return None
 
 
+def _kept(state: Dict, why: str) -> Dict:
+    """The state a rejected step falls back to, if it is still there: a
+    step_fn that DONATED it (the hybrid builders' default) left nothing to
+    keep."""
+    import jax
+    from ...enforce import PreconditionNotMetError, enforce
+    enforce(not any(isinstance(v, jax.Array) and v.is_deleted()
+                    for v in jax.tree.leaves(state)),
+            f"{why}: the loop keeps the state the step was given, but "
+            "step_fn consumed it (a donated train step). Build the step "
+            "with donate=False under run_resilient",
+            op="run_resilient", error=PreconditionNotMetError)
+    return state
+
+
 def drain_then_commit(wd: CommWatchdog, grace_s: float, commit_fn
                       ) -> Optional[BaseException]:
     """The shared preemption endgame (driver loop + FitResilience): inside
@@ -134,7 +149,10 @@ def run_resilient(step_fn: Callable[[Dict, int], Tuple[Dict, Any]],
     steps with checkpoint-restart fault tolerance. Returns
     ``(final_state, info)``; info records resume/preemption/watchdog
     details. `state` must be a (nested) dict of arrays/scalars — the same
-    contract as ``save_state_dict``.
+    contract as ``save_state_dict``. step_fn must leave the state it was
+    given alive: a rejected step (non-finite loss, a numerics skip) falls
+    back to it. A hybrid train step donates its inputs by default, so
+    build it with ``donate=False`` for this loop.
 
     aggregator: a fleet :class:`observability.TelemetryAggregator` — the
     loop feeds it every step's wall time (loss forced, so it measures
@@ -294,6 +312,7 @@ def run_resilient(step_fn: Callable[[Dict, int], Tuple[Dict, Any]],
             if loss_val is not None and not math.isfinite(loss_val):
                 # found_inf discipline at loop level: reject the step,
                 # keep the last good state
+                state = _kept(state, "non-finite loss")
                 progress["nonfinite"] += 1
                 info["nonfinite_skips"] += 1
                 _emit("resilience_nonfinite_skip", step=i, loss=loss_val,
@@ -313,6 +332,7 @@ def run_resilient(step_fn: Callable[[Dict, int], Tuple[Dict, Any]],
                 if guard_action == "skip":
                     # confirmed-divergence skip: keep the last good state
                     # (the found_inf discipline at episode level)
+                    state = _kept(state, "numerics skip")
                     info["numerics_skips"] += 1
                     _emit("resilience_numerics_skip", step=i,
                           loss=loss_val)

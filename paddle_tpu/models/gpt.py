@@ -1529,10 +1529,13 @@ def build_hybrid_train_step(cfg: GPTConfig, mesh: Mesh, optimizer,
                             mp_overlap="auto", ep_axis="ep",
                             moe_dispatch="auto", moe_ef_tokens=None,
                             flash_attention="auto", sep_axis="sep",
-                            numerics="auto"):
+                            numerics="auto", donate: bool = True):
     """Compile the full hybrid train step: one program containing embedding,
     the blocks, vocab-parallel loss, backward, dp grad sync and the
     optimizer update. Returns (step_fn, shard_params_fn, init_state_fn).
+    The step owns (params, opt_state): it donates them, so rebind all of
+    its outputs; donate=False keeps a caller's inputs alive (see
+    hybrid_engine.build_train_step).
 
     num_microbatches: how many slices the per-dp-rank batch is cut into.
     On a mesh with pp > 1 they fill the pipeline (spmd_pipeline: M + P - 1
@@ -1906,7 +1909,8 @@ def build_hybrid_train_step(cfg: GPTConfig, mesh: Mesh, optimizer,
         grad_reduce_dtype=grad_reduce_dtype, zero_stage=stage,
         zero3=z3_engine,
         comm_overlap=comm_overlap, fp8=fp8_plan, telemetry=telemetry,
-        mp_overlap=sp, moe=moe_plan, flash=flash, numerics=ncfg)
+        mp_overlap=sp, moe=moe_plan, flash=flash, numerics=ncfg,
+        donate=donate)
     # elastic-checkpoint hint (checkpoint.reshard): the stacked-[L] block
     # leaves' STORAGE order is (pp, vpp)-dependent under the interleaved
     # schedule; resume onto a different layout permutes them (fp8_meta's

@@ -246,6 +246,16 @@ def _global_clip_scale(red, leaves_spec, leaves_z, mesh: Mesh, dp_axis,
     return clip.scale_from_norm(jnp.sqrt(n2))
 
 
+def _shares_buffer(a, b) -> bool:
+    """Whether two arrays hold a device buffer in common (donating one
+    then deletes the other)."""
+    if not (isinstance(a, jax.Array) and isinstance(b, jax.Array)):
+        return False
+    held = {s.data.unsafe_buffer_pointer() for s in b.addressable_shards}
+    return any(s.data.unsafe_buffer_pointer() in held
+               for s in a.addressable_shards)
+
+
 def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
                      optimizer, data_spec: P = None, dp_axis: str = "dp",
                      extra_grad_axes=(), example_params=None,
@@ -253,7 +263,7 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
                      zero_stage=None, zero3=None,
                      comm_overlap="auto", fp8=None, telemetry="auto",
                      mp_overlap=None, moe=None, flash=None, numerics=None,
-                     donate: bool = False):
+                     donate: bool = True):
     """loss_fn(params, tokens, labels) -> scalar, running per-device inside
     shard_map. Returns (jitted_step, shard_params, init_state).
 
@@ -357,10 +367,23 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
     fetch per interval, zero extra dispatches. When resolved off this is
     a STRICT no-op — the compiled program is bitwise identical.
 
-    donate=True donates (params, opt_state) to the jitted step — the
-    telemetry/fp8/EF carries are donated with the rest, so none of the
-    bookkeeping costs a second resident copy. Off by default because a
-    donated carry must not be reused by the caller.
+    donate: the compiled step OWNS the state it updates (the default,
+    as every other train-step builder of the library): params and
+    opt_state are donated to it (jit's donate_argnums=(0, 1)), so
+    fused_adam overwrites a leaf where it lies, with no copy of it in
+    front of the kernel, and the state is resident once. Everything that
+    rides opt_state goes with it: the moments, ZeRO's dp-sharded slots,
+    the `step` counter, and the telemetry ring / fp8 meta / error-feedback
+    carries, so none of the bookkeeping costs a second resident copy. The
+    caller REBINDS: `params, state, loss = step(params, state, ...)`; the
+    trees it passed in are deleted (reading one raises "Array has been
+    deleted"), and so is whatever shares their buffers (device_put onto a
+    sharding an array already has returns the same buffers: shard_params
+    copies such a leaf, so the tree it was given stays the caller's). A
+    caller that keeps its inputs (the same trees fed to two builds, a
+    sweep that times one state again and again, a host that rolls back to
+    the state before a bad step) passes donate=False: the same program
+    text but for the aliasing, and the copies in front of the update.
 
     fp8: a quantization.fp8.fp8_plan dict (models build it) enabling
     delayed-scaling fp8 GEMMs in the loss: loss_fn then takes a fourth
@@ -716,9 +739,17 @@ def build_train_step(loss_fn: Callable, specs: Dict[str, Any], mesh: Mesh,
         sspec = {"opt": opt_sspec, **wrap_specs}
 
     def shard_params(params):
-        return jax.tree.map(
-            lambda v, s: jax.device_put(v, NamedSharding(mesh, s)),
-            params, pspecs)
+        def put(v, s):
+            out = jax.device_put(v, NamedSharding(mesh, s))
+            # the step donates what this returns, and device_put hands
+            # back the caller's own buffer where it can (an array that has
+            # the sharding already; a replicated leaf's shard on the
+            # device the array came from): such a leaf gets its own, so
+            # the tree the caller passed stays the caller's
+            if donate and _shares_buffer(out, v):
+                out = jnp.copy(out)
+            return out
+        return jax.tree.map(put, params, pspecs)
 
     # Elastic-checkpoint hints (checkpoint.reshard): everything about this
     # build's topology that the saved arrays' shardings cannot express —

@@ -729,8 +729,12 @@ def build_hybrid_train_step(cfg: LlamaConfig, mesh: Mesh, optimizer,
                             zero3="auto", fp8="auto",
                             telemetry="auto", mp_overlap="auto",
                             flash_attention="auto", sep_axis="sep",
-                            numerics="auto"):
-    """num_microbatches: at pp > 1 the slices that fill the pipeline; on
+                            numerics="auto", donate: bool = True):
+    """The step owns (params, opt_state): it donates them, so rebind all
+    of its outputs; donate=False keeps a caller's inputs alive (see
+    hybrid_engine.build_train_step).
+
+    num_microbatches: at pp > 1 the slices that fill the pipeline; on
     a mesh whose pp axis has ONE rank, gradient accumulation (each
     microbatch's forward and backward one after another, one dp
     reduction, clip and update); see gpt.build_hybrid_train_step.
@@ -863,7 +867,7 @@ def build_hybrid_train_step(cfg: LlamaConfig, mesh: Mesh, optimizer,
         grad_reduce_dtype=grad_reduce_dtype, zero_stage=stage,
         zero3=z3_engine,
         fp8=fp8_plan, telemetry=telemetry, mp_overlap=sp, flash=flash,
-        numerics=ncfg)
+        numerics=ncfg, donate=donate)
     # elastic-checkpoint hint: see gpt.build_hybrid_train_step
     init_state.layout_extra["pp"] = {
         "num_layers": int(cfg.num_layers), "pp": int(mesh.shape[pp_axis]),

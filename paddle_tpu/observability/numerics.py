@@ -570,9 +570,10 @@ def numerics_spike_check(workdir: str, *, steps: int = 20,
                       param_dtype=jnp.float32)
     tcfg = TelemetryConfig(interval=4, strict=False)
     opt = paddle.optimizer.AdamW(learning_rate=1e-3)
+    # run_resilient falls back to the state a rejected step was given
     step, shard_params, init_state = G.build_hybrid_train_step(
         cfg, mesh, opt, num_microbatches=1, telemetry=tcfg,
-        numerics=True)
+        numerics=True, donate=False)
     p = shard_params(G.init_hybrid_params(cfg, jax.random.PRNGKey(0)))
     s = init_state(p)
 

@@ -783,14 +783,14 @@ def _stacked_gradient_gemms(text):
     return out
 
 
-def _outside_entry(text):
-    """The instructions of every computation but ENTRY: the loops' bodies
-    and the fused computations."""
-    out, entry = [], False
+def _by_computation(text, entry):
+    """The instructions of ENTRY (`entry`), or of every other computation
+    (the loops' bodies and the fused computations)."""
+    out, inside = [], False
     for line in text.splitlines():
         if line.endswith("{") and not line.startswith(" "):
-            entry = line.startswith("ENTRY")
-        elif not entry:
+            inside = line.startswith("ENTRY")
+        elif inside == entry:
             out.append(line)
     return out
 
@@ -813,9 +813,15 @@ def test_hybrid_cell_step_runs_no_pass_twice(topo, compiled_kernels,
     compiler for a switch (with the two that make an all-reduce
     asynchronous the compiler clones GEMMs as carriers and the step loses
     3.5% on the chip, PERF.md PR 47). And the summed stack and a
-    microbatch's gradient are ONE buffer: 2.61 GiB of temp where the
-    parent had 4.59 (the pipeline form 8.34 by the same analysis, PERF.md
-    PR 37)."""
+    microbatch's gradient are ONE buffer (PR 47). Since PR 53 the step,
+    built as the cell builds it (every default), OWNS its state: all of
+    the argument state aliases an output (4.575 GiB a chip), and no
+    parameter or moment is copied whole in front of `fused_adam`, which
+    updates a leaf in place (without donation the compiler copied the 17
+    stacks and the `wte` leaf, 3.98 GiB a chip and a step, PERF.md PR 53).
+    Arguments + temp are 8.13 GiB where arguments + outputs + temp were
+    11.76 (temp alone reads 3.55 with aliasing, 2.61 without: the sum is
+    what fell)."""
     import paddle_tpu as paddle
     import paddle_tpu.distributed as dist
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -864,10 +870,21 @@ def test_hybrid_cell_step_runs_no_pass_twice(topo, compiled_kernels,
     # adding to the sum as they write
     gemms = _stacked_gradient_gemms(text)
     assert list(gemms.values()) == [True] * 4, gemms
-    in_a_loop = [l for l in _outside_entry(text) if re.search(
+    in_a_loop = [l for l in _by_computation(text, entry=False) if re.search(
         r"= bf16\[6,\d{4},\d{4}\]\S* (copy\(|fusion\(.*kind=kLoop)", l)]
     assert not in_a_loop, in_a_loop
-    assert compiled.memory_analysis().temp_size_in_bytes < 3.25 * 2 ** 30
+    # the step owns its state: every argument but the batch and the
+    # learning rate (a dp rank's tokens and labels, [2, 2048] int32 each,
+    # and a scalar) aliases an output, and no entry parameter that is a
+    # layer stack or the `wte` leaf is copied
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= ma.argument_size_in_bytes - 64 * 1024
+    assert ma.alias_size_in_bytes > 4.5 * 2 ** 30
+    whole_leaves = [l for l in _by_computation(text, entry=True) if re.search(
+        r"= bf16\[(6,\d+,\d+|25152,4096)\]\S* copy\(%param", l)]
+    assert not whole_leaves, whole_leaves
+    assert (ma.argument_size_in_bytes + ma.temp_size_in_bytes
+            < 8.5 * 2 ** 30)
 
 
 # ---------------------------------------------------------------------------

@@ -8,9 +8,12 @@ Asserted via the compiled executable's input/output aliasing (the
 compiled-HLO form of jit's donate_argnums) rather than donation warnings,
 which the CPU backend does not always emit."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 from jax.sharding import PartitionSpec as P
 
 import paddle_tpu as paddle
@@ -143,7 +146,7 @@ def test_hybrid_mp_overlap_steps_donate():
             lf, hybrid_param_specs(cfg), mesh, opt,
             example_params=jax.eval_shape(
                 lambda: init_hybrid_params(cfg, jax.random.PRNGKey(0))),
-            mp_overlap=sp, donate=True)
+            mp_overlap=sp)
         p = shard(init_hybrid_params(cfg, jax.random.PRNGKey(0)))
         st = init(p)
         compiled = step.lower(p, st, tokens, labels,
@@ -177,3 +180,165 @@ def test_hybrid_overlap_step_memory_sane():
         pytest.skip("backend exposes no memory analysis")
     budget = 8 * _param_state_bytes(p, st) + xs.nbytes + ys.nbytes
     assert ma.temp_size_in_bytes + ma.output_size_in_bytes < 4 * budget
+
+
+# ---------------------------------------------------------------------------
+# the hybrid train step owns its state (PR 53): the model builders donate
+# (params, opt_state) unless the caller keeps its inputs
+# ---------------------------------------------------------------------------
+def _hybrid_job(family):
+    if family == "gpt":
+        from paddle_tpu.models import gpt as M
+        cfg = M.GPTConfig(vocab_size=64, hidden_size=32, num_layers=4,
+                          num_heads=4, max_seq_len=16, dtype=jnp.float32)
+    else:
+        from paddle_tpu.models import llama as M
+        cfg = M.LlamaConfig(vocab_size=64, hidden_size=32, num_layers=4,
+                            num_heads=4, num_kv_heads=2,
+                            intermediate_size=64, max_seq_len=16,
+                            dtype=jnp.float32)
+    rng = np.random.RandomState(0)
+    tokens = jnp.asarray(rng.randint(0, 64, (8, 16)))
+    labels = jnp.asarray(rng.randint(0, 64, (8, 16)))
+    return M, cfg, tokens, labels
+
+
+def _hybrid_build(M, cfg, **kw):
+    """(step, the host tree, its sharded params, the state) on the dp2 x
+    pp2 x mp2 CPU mesh, with the extras that ride opt_state when `kw` asks
+    for them."""
+    mesh = dist.build_mesh({"dp": 2, "pp": 2, "mp": 2})
+    opt = paddle.optimizer.AdamW(1e-3)
+    step, shard, init = M.build_hybrid_train_step(
+        cfg, mesh, opt, num_microbatches=2, **kw)
+    host = M.init_hybrid_params(cfg, jax.random.PRNGKey(0))
+    params = shard(host)
+    return step, host, params, init(params)
+
+
+def _buffers(tree):
+    return [s.data.unsafe_buffer_pointer() for x in jax.tree.leaves(tree)
+            for s in x.addressable_shards]
+
+
+def _aliased_args(compiled):
+    """The flat argument numbers that the compiled text aliases to an
+    output (`input_output_alias` of the module's first line)."""
+    header = compiled.as_text().split("\n", 1)[0]
+    return sorted(int(i) for i in re.findall(
+        r"\{\d+\}: \((\d+), \{\}, (?:may|must)-alias\)", header))
+
+
+@pytest.mark.parametrize("family", ["gpt", "llama"])
+def test_hybrid_step_owns_its_state(family):
+    """A default-built hybrid step consumes the trees it is given and the
+    compiled text aliases EVERY parameter and slot leaf to an output; the
+    tree shard_params was given stays the caller's."""
+    M, cfg, tokens, labels = _hybrid_job(family)
+    step, host, params, state = _hybrid_build(M, cfg)
+    # no buffer is named twice (jit would refuse the donation), and none
+    # is the host tree's (a replicated leaf's shard on the device the
+    # host array lives on is that array's buffer unless shard_params
+    # copies it)
+    ours = _buffers((params, state))
+    assert len(set(ours)) == len(ours)
+    assert not set(ours) & set(_buffers(host))
+    lr = jnp.float32(1e-3)
+    compiled = step.lower(params, state, tokens, labels, lr).compile()
+    assert _aliased_args(compiled) == list(
+        range(len(jax.tree.leaves((params, state)))))
+    # and the aliased bytes are a device's whole share of the state
+    first = jax.tree.leaves(params)[0].sharding.mesh.devices.flat[0]
+    assert compiled.memory_analysis().alias_size_in_bytes == sum(
+        s.data.nbytes for x in jax.tree.leaves((params, state))
+        for s in x.addressable_shards if s.device == first)
+    out = step(params, state, tokens, labels, lr)
+    jax.block_until_ready(out)
+    assert all(x.is_deleted() for x in jax.tree.leaves((params, state)))
+    assert not any(x.is_deleted() for x in jax.tree.leaves(host))
+
+
+@pytest.mark.parametrize("family", ["gpt", "llama"])
+def test_hybrid_step_keeps_inputs_when_asked(family):
+    """donate=False: no aliasing in the compiled text and the same trees
+    feed the step again."""
+    M, cfg, tokens, labels = _hybrid_job(family)
+    step, _, params, state = _hybrid_build(M, cfg, donate=False)
+    lr = jnp.float32(1e-3)
+    compiled = step.lower(params, state, tokens, labels, lr).compile()
+    assert "input_output_alias" not in compiled.as_text()
+    _, _, a = step(params, state, tokens, labels, lr)
+    _, _, b = step(params, state, tokens, labels, lr)
+    assert not any(x.is_deleted() for x in jax.tree.leaves((params, state)))
+    assert float(a) == float(b)
+
+
+@pytest.mark.parametrize("family", ["gpt", "llama"])
+def test_hybrid_donated_and_kept_builds_train_bitwise_alike(family):
+    """One program text but for the aliasing: three rebinding steps of the
+    donated build give the bits of the undonated build."""
+    M, cfg, tokens, labels = _hybrid_job(family)
+    lr = jnp.float32(1e-3)
+    runs, texts = {}, {}
+    for donate in (True, False):
+        step, _, params, state = _hybrid_build(M, cfg, donate=donate)
+        texts[donate] = step.lower(params, state, tokens, labels,
+                                   lr).as_text()
+        losses = []
+        for _ in range(3):
+            params, state, loss = step(params, state, tokens, labels, lr)
+            losses.append(np.asarray(loss))
+        runs[donate] = (losses, jax.tree.map(np.asarray, (params, state)))
+    assert "jax.buffer_donor = true, " in texts[True]
+    assert texts[True].replace("jax.buffer_donor = true, ", "") == \
+        texts[False]
+    assert [l.tobytes() for l in runs[True][0]] == \
+        [l.tobytes() for l in runs[False][0]]
+    for a, b in zip(jax.tree.leaves(runs[True][1]),
+                    jax.tree.leaves(runs[False][1])):
+        assert a.tobytes() == b.tobytes()
+
+
+@pytest.mark.parametrize("rides", ["telemetry", "fp8", "zero1", "comm_ef"])
+def test_what_rides_opt_state_is_donated_with_it(rides):
+    """The telemetry ring, the fp8 meta, ZeRO's dp-sharded slots and the
+    error-feedback residuals go where opt_state goes: every leaf of the
+    carry aliases an output and is consumed by the step."""
+    from paddle_tpu.models import gpt as M
+    _, cfg, tokens, labels = _hybrid_job("gpt")
+    from paddle_tpu.observability import TelemetryConfig
+    kw = {"telemetry": {"telemetry": TelemetryConfig(interval=4)},
+          "fp8": {"fp8": True},
+          "zero1": {"zero_stage": 1},
+          "comm_ef": {"comm_overlap": co.CommOverlapConfig(
+              bucket_mb=1e-4, quantize="int8")}}[rides]
+    step, _, params, state = _hybrid_build(M, cfg, **kw)
+    ours = _buffers((params, state))
+    assert len(set(ours)) == len(ours)
+    lr = jnp.float32(1e-3)
+    compiled = step.lower(params, state, tokens, labels, lr).compile()
+    assert _aliased_args(compiled) == list(
+        range(len(jax.tree.leaves((params, state)))))
+    new_params, new_state, loss = step(params, state, tokens, labels, lr)
+    assert np.isfinite(float(loss))
+    assert all(x.is_deleted() for x in jax.tree.leaves((params, state)))
+    # and the step after takes what this one gave back
+    _, _, loss = step(new_params, new_state, tokens, labels, lr)
+    assert np.isfinite(float(loss))
+
+
+def test_run_resilient_refuses_to_keep_a_consumed_state(tmp_path):
+    """The resilient loop falls back to the state a rejected step was
+    given; a step that donated it is told so, by name, at the rejection
+    (not by a deleted-array error one step later)."""
+    from paddle_tpu.distributed.resilience import run_resilient
+    from paddle_tpu.enforce import PreconditionNotMetError
+    poison = jax.jit(lambda w: w * jnp.nan, donate_argnums=0)
+
+    def step_fn(st, i):
+        w = poison(st["w"])
+        return {"w": w}, jnp.sum(w)
+
+    with pytest.raises(PreconditionNotMetError, match="donate=False"):
+        run_resilient(step_fn, {"w": jnp.ones((4,))}, steps=2,
+                      ckpt_dir=str(tmp_path), ckpt_every=0, resume=False)

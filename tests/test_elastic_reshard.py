@@ -602,7 +602,7 @@ def _hybrid_setup(mesh, zero1=False, fp8=False, num_microbatches=2):
     opt = paddle.optimizer.AdamW(learning_rate=1e-2)
     step, shard_params, init_state = G.build_hybrid_train_step(
         cfg, mesh, opt, num_microbatches=num_microbatches, zero1_dp=zero1,
-        fp8=fp8)
+        fp8=fp8, donate=False)   # run_resilient keeps a step's input state
     params = shard_params(G.init_hybrid_params(cfg, jax.random.PRNGKey(0)))
     state = {"params": params, "opt": init_state(params)}
     rng = np.random.RandomState(0)

@@ -122,7 +122,8 @@ def test_a_mean_of_microbatch_means_would_differ(whole_batch):
     not the mean of its microbatches' mean losses."""
     tokens, labels = _batch("ragged")
     opt = paddle.optimizer.AdamW(LR, beta1=BETA1)
-    step, shard, init = G.build_hybrid_train_step(CFG, _mesh(PP1), opt)
+    step, shard, init = G.build_hybrid_train_step(
+        CFG, _mesh(PP1), opt, donate=False)   # both halves start from params
     params = shard(G.init_hybrid_params(CFG, jax.random.PRNGKey(0)))
     means = []
     for half in (slice(0, 2), slice(2, 4)):   # M = 2 on each dp rank
@@ -378,7 +379,9 @@ PIPELINE_PATH = {
 @pytest.mark.parametrize("name", sorted(PIPELINE_PATH))
 def test_pipeline_path_lowers_to_the_parents_text(name):
     model, cfg, dims, kw = PIPELINE_PATH[name]
-    step, args = _lowered(model, cfg, dims, **kw)
+    # the recorded text is the parent's, whose builders did not donate:
+    # donation adds the arguments' aliasing attributes and nothing else
+    step, args = _lowered(model, cfg, dims, donate=False, **kw)
     text = step.lower(*args).as_text()
     assert "collective_permute" in text   # the pipeline is still built
     seen = {}

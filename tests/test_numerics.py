@@ -166,7 +166,8 @@ def test_per_layer_gnorm_matches_independent_grads():
     tcfg = obs.TelemetryConfig(interval=1, strict=False)
     step, sh, ini = G.build_hybrid_train_step(
         CFG, mesh, paddle.optimizer.AdamW(1e-3), num_microbatches=2,
-        telemetry=tcfg, numerics=True)
+        telemetry=tcfg, numerics=True,
+        donate=False)   # p0 feeds the independent grad after the step
     p0 = sh(G.init_hybrid_params(CFG, jax.random.PRNGKey(0)))
     s0 = ini(p0)
     host = obs.TelemetryHost(tcfg)
@@ -679,7 +680,8 @@ def test_host_watermark_survives_skipped_steps():
     tcfg = obs.TelemetryConfig(interval=2)
     step, sh, ini = build_train_step(
         lambda p, x, y: jnp.mean((x @ p["w"] - y) ** 2), specs, mesh,
-        paddle.optimizer.AdamW(1e-3), telemetry=tcfg)
+        paddle.optimizer.AdamW(1e-3), telemetry=tcfg,
+        donate=False)   # a skipped step's input carry feeds the next one
     host = obs.TelemetryHost(tcfg)
     p = sh(params)
     st = ini(p)
