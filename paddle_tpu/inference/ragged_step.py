@@ -72,9 +72,23 @@ the model's `latent_qkv` in place of `qkv`. A model's PROLOGUE
 ``params["prologue"]``, scanned before the periods with no experts; the
 pool's entries count them first.
 
+A WINDOWED attention layer (kind "window", `models/trinity_mini.py`:
+query i attends the keys j with ``i - window < j <= i``) keeps its K and V
+in a SECOND pair of pools with a table and a LIFETIME of their own
+(``win`` of `ragged_pass` and `unified_step`: ``(wtables, wkp, wvp)``):
+the pages of a layer that attends everything live as long as the request,
+a window layer's are given back by the host as the window slides
+(`inference.serving`), so its table is a RING of ``nbw`` entries, page j of
+a row in entry ``j % nbw``. The window pools keep the contract above
+(donated, on both scans' carry, the layer by scalar prefetch, one entry a
+window layer in its own numbering); `kv_append` writes each layer through
+its own lifetime's table, the work list built once a lifetime and pass; the
+attention kernel takes ``window=`` and reads no page that lies wholly
+behind every query's window.
+
 A model whose layers are not all of one kind gives its PATTERN
 (``serving_model(cfg).pattern``: one period as runs of "attention",
-"parallel" or "linear" layers). `ragged_pass` scans periods and, inside
+"window", "parallel", "linear" or "latent" layers). `ragged_pass` scans periods and, inside
 one, each run; the pool then holds one entry an ATTENTION layer and the
 state one a layer with a MIXER, each numbered in its own order, and a
 run's stacked expert weights are taken whole like the pool (the layer
@@ -104,16 +118,20 @@ __all__ = ["ragged_pass", "unified_step"]
 
 
 def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
-                tables, temps, key, kp, vp, ks, vs, ssm=None, *, cfg, bs,
-                c_att, mp_axis=None, all_greedy=False):
+                tables, temps, key, kp, vp, ks, vs, ssm=None, win=None, *,
+                cfg, bs, c_att, mp_axis=None, all_greedy=False):
     """One transformer forward over the packed ragged batch + per-row
     sampling. tokens/row_of/off_of: [T] packed (off_of >= q_len marks
     padding); starts/pos0/q_lens/temps: [R]; tables: [R, nb]; pools:
     [L, H_kv, NB, bs, D] (+ [L, H_kv, NB] scales when quantized).
     ssm: a recurrent model's (state, tail) or None; it rides the layer
-    scan's carry beside the pools and is the last entry of the returned
-    pools tuple.
-    Returns (tok [R], (kp, vp, ks, vs, ssm) updated — ks, vs None when
+    scan's carry beside the pools and is the fifth entry of the returned
+    pools tuple. win: a model with windowed layers' (wtables [R, nbw],
+    wkp, wvp): the ring tables and the two pools of the window layers'
+    lifetime ([L_win, H_kv, NBw, bs, D]), or None; the pools ride the
+    carry too and (wkp, wvp) is the sixth entry of the returned tuple
+    (None without them).
+    Returns (tok [R], (kp, vp, ks, vs, ssm, win) updated — ks, vs None when
     the pool is not quantized); with ``all_greedy``
     the head runs over EVERY packed position and the return gains a
     ``greedy_t [T]`` argmax vector between tok and the pools — the
@@ -135,6 +153,9 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
         tile = append_tile(kp.dtype, bs)
         work = tile_work(starts, pos0, q_lens, tables, bs=bs, tile=tile,
                          c_att=c_att, T=T)
+        if win is not None:     # the second lifetime's list, from its ring
+            work_w = tile_work(starts, pos0, q_lens, win[0], bs=bs,
+                               tile=tile, c_att=c_att, T=T, ring=True)
     # a row's tokens as a [c_att] window of the packed buffer, for the quantized
     # append and a recurrent mixer (both mask the clamped duplicates past q_len)
     tile_idx = jnp.clip(
@@ -143,8 +164,9 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
         0, T - 1)                                            # [R, c_att]
     scale = 1.0 / (cfg.head_dim ** 0.5)
     extra = {}
-    if model.latent:    # padding is not routed; attention's items: once a pass
-        extra = {"real": off_of < q_lens[row_of]}
+    if model.latent or getattr(model, "mask_padding", False):
+        extra = {"real": off_of < q_lens[row_of]}   # padding is not routed
+    if model.latent:    # attention's items: once a pass
         items = mla_items(tables, q_lens, kv_lens, bs=bs, c_att=c_att, T=T)
     if ssm is not None:
         # a row that starts at position 0 starts from a zero state
@@ -162,19 +184,33 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
     runs = model.pattern(cfg)
     routed = model.routed
     single = len(runs) == 1 and runs[0][1] == 1 and not routed
-    n_att = sum(n for kind, n in runs if kind != "linear")
-    n_mix = sum(n for kind, n in runs if kind not in ("attention", "latent"))
+    n_att = sum(n for kind, n in runs if kind not in ("linear", "window"))
+    n_mix = sum(n for kind, n in runs
+                if kind not in ("attention", "latent", "window"))
+    n_win = sum(n for kind, n in runs if kind == "window")
+    wtables = None if win is None else win[0]
 
     def layer_body(kind, experts, flat, att, mix):
         """One layer of `kind`; flat/att/mix: its number among all layers
         of its run's kind (the experts' leading index), among the layers
-        with attention (the pool's), among those with a mixer (the
+        with attention (the pool's; a window layer's among the window
+        layers, its own pools'), among those with a mixer (the
         state's)."""
         def body(carry, p):
-            x, kp, vp, ks, vs, ssm = carry
+            x, kp, vp, ks, vs, ssm, wp = carry
             attn_p = None
             if kind == "linear":
                 mixed, ssm = model.mixer(p, x, ssm, mix, plan, cfg)
+            elif kind == "window":
+                q, k, v, mixed = model.qkv(p, x, pos_t[None], cfg, mp_axis,
+                                           kind=kind)
+                with jax.named_scope(SCOPES.kv_write):
+                    wp = kv_append(*wp, k[0], v[0], att, work_w, tile=tile)
+                with jax.named_scope(SCOPES.window_attn):
+                    attn_p = ragged_paged_attention(
+                        q[0], *wp, wtables, starts, q_lens, kv_lens, scale,
+                        None, None, att, c_att=c_att,
+                        window=model.window(cfg))[None]      # [1,T,h,D]
             elif kind == "latent":
                 qa, qr, c, k_r = model.latent_qkv(p, x, pos_t[None], cfg)
                 mixed = None
@@ -213,7 +249,7 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
                                             layer=flat, **extra)
             else:
                 x = model.block_math(p, x, attn_p, mixed, cfg, mp_axis)
-            return (x, kp, vp, ks, vs, ssm), route
+            return (x, kp, vp, ks, vs, ssm, wp), route
         return body
 
     def period_body(carry, xs):
@@ -221,27 +257,32 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
         if single:      # one layer a period: the period IS the layer
             return layer_body(runs[0][0], None, period, period, period)(
                 carry, ps)
-        routes, att0, mix0 = [], 0, 0
+        routes, att0, mix0, win0 = [], 0, 0, 0
         for r, (kind, n) in enumerate(runs):
             def run_body(carry, pj, r=r, kind=kind, n=n, att0=att0,
-                         mix0=mix0):
+                         mix0=mix0, win0=win0):
                 p, j = pj
                 return layer_body(
                     kind, params["experts"][r] if routed else None,
-                    period * n + j, n_pro + period * n_att + att0 + j,
+                    period * n + j,
+                    (pro_win + period * n_win + win0 + j if kind == "window"
+                     else pro_att + period * n_att + att0 + j),
                     period * n_mix + mix0 + j)(carry, p)
             carry, route = lax.scan(
                 run_body, carry, (ps[r], jnp.arange(n, dtype=jnp.int32)))
             routes.append(route)
-            att0 += n if kind != "linear" else 0
+            att0 += n if kind not in ("linear", "window") else 0
             mix0 += n if kind != "attention" else 0
+            win0 += n if kind == "window" else 0
         # the period's routing in layer order: (ids [n, T, k], stats [n, 3])
         route = (jax.tree.map(lambda *a: jnp.concatenate(a), *routes)
                  if routed else None)
         return carry, route
 
-    carry = (x, kp, vp, ks, vs, ssm)
+    carry = (x, kp, vp, ks, vs, ssm, None if win is None else win[1:])
     kind, n_pro = model.prologue(cfg)
+    # the prologue's layers are the first entries of their lifetime's pool
+    pro_att, pro_win = (0, n_pro) if kind == "window" else (n_pro, 0)
     if n_pro:   # the leading layers, no experts; the pool's first entries
         carry, _ = lax.scan(
             lambda carry, pj: layer_body(kind, None, pj[1], pj[1], pj[1])(
@@ -277,8 +318,9 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
 def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
                  tables, fresh, sample0, remaining, eos_ids, temps,
                  prev_tok, key, kp, vp, ks, vs, cow_src=None, cow_dst=None,
-                 reset_tables=None, ssm_state=None, conv_tail=None, *, cfg,
-                 bs, c_att, K, spec=False, mp_axis=None):
+                 reset_tables=None, ssm_state=None, conv_tail=None,
+                 wtables=None, wkp=None, wvp=None, *, cfg, bs, c_att, K,
+                 spec=False, mp_axis=None):
     """ONE compiled program per engine step: the ragged pass (prefill
     chunks + first decode token for every row) followed by K-1 decode
     micro-steps for every sampling row. fresh: [R] bool — slots admitted
@@ -319,6 +361,12 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
     written in place by the mixer's kernels; a row whose pass starts at
     position 0 starts from zeros. Both come back after ``lens``.
 
+    A model with windowed layers appends three more: wtables [R, nbw],
+    the ring tables of the window layers' lifetime, and that lifetime's
+    two pools wkp, wvp [L_win, H_kv, NBw, bs, D], donated and aliased like
+    the others; both pools come back after ``last_tok`` (after the
+    recurrent state, where there is one).
+
     A model with routed experts (``routed``) returns what its router
     chose, last: ids0 [L, T, k] int16, the picks of every packed position
     of pass 1 and layer; ids_burst [K-1, L, R, k], the burst passes' (row
@@ -352,18 +400,20 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
             if quantized:
                 ks, vs = copy_pages(ks), copy_pages(vs)
     ssm = None if ssm_state is None else (ssm_state, conv_tail)
+    wp = None if wtables is None else (wkp, wvp)
     key, sub = jax.random.split(key)
     out = ragged_pass(params, tokens, row_of, off_of, starts,
                       pos0, q_lens, tables, temps, sub,
-                      kp, vp, ks, vs, ssm, cfg=cfg, bs=bs,
+                      kp, vp, ks, vs, ssm,
+                      None if wp is None else (wtables, *wp), cfg=cfg, bs=bs,
                       c_att=c_att, mp_axis=mp_axis, all_greedy=spec)
     routed = serving_model(cfg).routed
     if spec:
-        tok0, greedy_all, (kp, vp, ks, vs, ssm) = out
+        tok0, greedy_all, (kp, vp, ks, vs, ssm, wp) = out
     elif routed:
-        tok0, (kp, vp, ks, vs, ssm), (ids0, stats0) = out
+        tok0, (kp, vp, ks, vs, ssm, wp), (ids0, stats0) = out
     else:
-        tok0, (kp, vp, ks, vs, ssm) = out
+        tok0, (kp, vp, ks, vs, ssm, wp) = out
     tok0 = jnp.where(sample0, tok0, 0)
     last_tok = jnp.where(sample0, tok0, prev_tok)
     lens = pos0 + q_lens
@@ -373,25 +423,28 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
     zero = jnp.zeros((R,), jnp.int32)
 
     def micro(carry, _):
-        tok, last, kp, vp, ks, vs, ssm, lens, rem, alive, key = carry
+        tok, last, kp, vp, ks, vs, ssm, wp, lens, rem, alive, key = carry
         active = alive & (rem > 0)
         ql = active.astype(jnp.int32)
         key, sub = jax.random.split(key)
-        tok2, (kp, vp, ks, vs, ssm), *route = ragged_pass(
+        tok2, (kp, vp, ks, vs, ssm, wp), *route = ragged_pass(
             params, tok, ar, zero, ar, lens, ql, tables, temps, sub,
-            kp, vp, ks, vs, ssm, cfg=cfg, bs=bs, c_att=1, mp_axis=mp_axis)
+            kp, vp, ks, vs, ssm,
+            None if wp is None else (wtables, *wp), cfg=cfg, bs=bs, c_att=1,
+            mp_axis=mp_axis)
         tok2 = jnp.where(active, tok2, 0)
         last = jnp.where(active, tok2, last)
         lens = lens + ql
         rem = rem - ql
         alive = alive & ~(active & (tok2 == eos_ids))
-        return (tok2, last, kp, vp, ks, vs, ssm, lens, rem, alive,
+        return (tok2, last, kp, vp, ks, vs, ssm, wp, lens, rem, alive,
                 key), (tok2, *route)
 
     if K > 1:
-        carry = (tok0, last_tok, kp, vp, ks, vs, ssm, lens, rem, alive, key)
+        carry = (tok0, last_tok, kp, vp, ks, vs, ssm, wp, lens, rem, alive,
+                 key)
         with jax.named_scope(SCOPES.burst):
-            (_, last_tok, kp, vp, ks, vs, ssm, lens, _, _, _), \
+            (_, last_tok, kp, vp, ks, vs, ssm, wp, lens, _, _, _), \
                 (toks, *route) = lax.scan(micro, carry, jnp.arange(K - 1))
         all_toks = jnp.concatenate([tok0[None], toks], axis=0)
     else:
@@ -401,6 +454,8 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
     out = (all_toks, kp, vp, ks, vs, lens, last_tok)
     if ssm is not None:
         out += tuple(ssm)
+    if wp is not None:
+        out += tuple(wp)
     if routed:
         if K > 1:
             (ids_burst, stats_burst), = route

@@ -80,7 +80,23 @@ two pools by `pool_shapes`; pages, tables, refcounts, prefix sharing and
 copy-on-write are the ones K and V pages have, a leading run of layers
 that differs is its `prologue`, and what it cannot be given yet (a mesh,
 int8 weights, a quantized pool, speculative decoding) raises at
-construction.
+construction. A model with WINDOWED attention layers (`windowed`,
+`models.trinity_mini`: a layer attends the last `window` positions) gets a
+SECOND PAGE LIFETIME: its window layers' K and V live in a pool of their
+own, one ring table a row (`wtables [max_batch, nbw]`, page j in entry
+j % nbw, nbw = ceil(window / bs) + ceil(chunk / bs) + 2), whose pages are
+claimed at the pack of the step that first writes them and given back
+once every position of theirs lies more than window - 1 behind the row's
+next query AND the step that last read them has been walked (they wait on
+that step's `_PackedStep.wfree`), so a page freed by step n's walk is
+handed out again from step n+2. Admission RESERVES a row's most window
+pages (min(nbw, its full pages)), so a claim never fails: if the free
+list cannot cover a step's worst case the engine settles first
+(``overlap_settles_total{reason="window"}``), which brings the pages back
+that wait on the step in flight. The full layers' pages live as long as
+the request, as every other model's do. What such a model cannot be given
+(prefix sharing: a page given back cannot be shared; speculative decoding;
+a mesh; int8 weights; a quantized pool) raises at construction.
 
 A REQUEST'S LIFE is one record (ISSUE 38): `Request` keeps a mark where
 the engine passes each point on the way to the first token (submitted,
@@ -148,7 +164,8 @@ from ..observability.trace import (ADMISSION_ATTRS, ADMIT_BLOCKED,
                                    LATENT_DISPATCH_ATTRS, MOE_FETCH_ATTRS,
                                    MOE_LOCAL_FETCH_ATTRS, REQUEST_END_ATTRS,
                                    REQUEST_PHASES, REQUEST_SPANS, SCOPES,
-                                   SERVING_SPANS, SSM_DISPATCH_ATTRS)
+                                   SERVING_SPANS, SSM_DISPATCH_ATTRS,
+                                   WINDOW_DISPATCH_ATTRS)
 from ..profiler.utils import RecordEvent, record_interval
 
 __all__ = ["Request", "ServingEngine", "RunResult", "NonFiniteSampleError",
@@ -279,6 +296,11 @@ class _PackedStep:
     arrays: tuple        # the host arrays, in the program's order
     model_attrs: dict = dataclasses.field(default_factory=dict)
     #                      a recurrent or latent model's dispatch attributes
+    wtables: Optional[np.ndarray] = None    # the window lifetime's ring
+    #                      tables as this step reads them (a copy)
+    wfree: list = dataclasses.field(default_factory=list)
+    #                      window pages this step was the last to read:
+    #                      given back when it has been walked
     out: tuple = ()      # once dispatched: the program's (toks, greedy_all,
     #                      lens) and, of a model with routed experts, its
     #                      (ids0, ids_burst, stats), still on the device
@@ -445,7 +467,12 @@ class GPTServing:
     ``latent = True`` (`models.deepseek_v2.Serving`) keeps a head-less
     latent cache (`pool_shapes`, `latent_qkv`, `attn_scale`); `prologue`
     is the run of leading layers (``params["prologue"]``, no experts)
-    that comes before the periods."""
+    that comes before the periods; one with ``windowed = True``
+    (`models.trinity_mini.Serving`) has runs of kind "window" beside
+    "attention" (`qkv` is then told the ``kind``), whose K and V pages
+    live in a second pool with a lifetime of their own (`window`,
+    `window_layers`). The kinds of run: "attention", "window", "parallel",
+    "linear", "latent"."""
 
     recurrent = False
     routed = False      # no router: `block_math` returns the stream alone
@@ -524,7 +551,8 @@ class ServingEngine:
                  shed=None, shed_headroom: float = 0.5, preempt=None,
                  preempt_wait_steps: int = 2, prefix_share=None,
                  spec_decode_k=None, proposer=None, pool_audit=None,
-                 ssm_state_dtype="float32"):
+                 ssm_state_dtype="float32",
+                 num_window_blocks: Optional[int] = None):
         from ..flags import flag
         from ..enforce import enforce
         block_size = (int(flag("paged_block_size")) if block_size is None
@@ -601,6 +629,29 @@ class ServingEngine:
                 enforce(ok, "a model with a latent cache cannot be served "
                             f"with {what}", op="ServingEngine")
             pools = self.model.pool_shapes(cfg)
+        # -- a model with windowed layers: a second page lifetime (module
+        # doc). What the engine cannot give it is refused here
+        self._windowed = bool(getattr(self.model, "windowed", False))
+        if self._windowed:
+            for ok, what in (
+                    (mesh is None, "a mesh: the window pool is not sharded"),
+                    (not int8, "int8 weights: its leaves have no quantized "
+                               "form"),
+                    (not self.prefix_share,
+                     "prefix_share: a page that was given back cannot be "
+                     "shared"),
+                    (self.spec_k == 0,
+                     "spec_decode_k > 0: a rejected draft would have to "
+                     "take back pages the window gave up"),
+                    (not kv_quantized,
+                     f"kv_cache_dtype={kv_cache_dtype!r}: the window "
+                     "lifetime's append has no quantized page")):
+                enforce(ok, "a model with windowed layers cannot be served "
+                            f"with {what}", op="ServingEngine")
+            enforce(decode_burst <= block_size,
+                    f"decode_burst {decode_burst} must not pass a page's "
+                    f"{block_size} positions: the window ring has one page "
+                    "of room for a step's burst", op="ServingEngine")
         if kv_pool_bytes is not None and self._latent:
             num_blocks = max(2, int(kv_pool_bytes // (
                 L * block_size * sum(h * d for h, d in pools)
@@ -630,6 +681,28 @@ class ServingEngine:
         self._k_pools, self._v_pools = (
             jnp.zeros((L, h, num_blocks, block_size, d), pool_dtype)
             for h, d in pools)
+        self._wk_pools = self._wv_pools = self.wtables = None
+        self._nbw = self._num_wblocks = 0
+        self.window_pages_freed = self._wfreed_reported = 0
+        if self._windowed:
+            # the second lifetime: one ring table a row, block 0 scratch
+            self._window = int(self.model.window(cfg))
+            self._nbw = (-(-self._window // block_size)
+                         + -(-chunk // block_size) + 2)
+            if num_window_blocks is None:
+                num_window_blocks = max_batch * self._nbw + 1
+            self._num_wblocks = int(num_window_blocks)
+            self._wk_pools, self._wv_pools = (
+                jnp.zeros((self.model.window_layers(cfg), Hkv,
+                           self._num_wblocks, block_size, D), pool_dtype)
+                for _ in range(2))
+            self.wtables = np.zeros((max_batch, self._nbw), np.int32)
+            self.wfree_blocks = list(range(self._num_wblocks - 1, 0, -1))
+            # per slot: the logical pages [lo, hi) its ring holds, and the
+            # pages admission reserved for it
+            self._wlo = np.zeros((max_batch,), np.int64)
+            self._whi = np.zeros((max_batch,), np.int64)
+            self._wreserved = np.zeros((max_batch,), np.int64)
         self._k_scales = self._v_scales = None
         if kv_quantized:
             self._k_scales = jnp.zeros((L, Hkv, num_blocks), jnp.float32)
@@ -899,6 +972,14 @@ class ServingEngine:
             jfn = jax.jit(functools.partial(
                 RS.unified_step, cfg=cfg, bs=bsz, c_att=c_att, K=K),
                 donate_argnums=(15, 16, 17, 18, 22, 23))
+            self._jit_programs.append(jfn)
+            return jfn
+        if self._windowed:
+            # both lifetimes' pools donated (unified_step's full argument
+            # list: no scales, no copy-on-write, no recurrent state)
+            jfn = jax.jit(functools.partial(
+                RS.unified_step, cfg=cfg, bs=bsz, c_att=c_att, K=K),
+                donate_argnums=(15, 16, 25, 26))
             self._jit_programs.append(jfn)
             return jfn
         if mesh is None:
@@ -1213,6 +1294,9 @@ class ServingEngine:
                               or 0.0),
             "pool_utilization": float(
                 self._prom.get("kv_pool_utilization") or 0.0),
+            # the window layers' pool, claimed and given back as the
+            # windows slide (0.0 for a model without windowed layers)
+            "window_pool_utilization": self._window_utilization(),
             # sharing/speculation health (ISSUE 17): pages referenced by
             # >1 block table, COW copies, and the spec acceptance pair —
             # acceptance/proposed IS the speculation health metric
@@ -1246,6 +1330,10 @@ class ServingEngine:
             "free_blocks": self.free_pages(),
             "pool_utilization": (1.0 - self.free_pages() / total
                                  if total else 0.0),
+            # the window layers' lifetime (0 / 0.0 without windowed layers)
+            "free_window_blocks": self.free_pages(window=True),
+            "window_pool_utilization": self._window_utilization(),
+            "window_pages_freed_total": self.window_pages_freed,
             "kv_pages_shared": int((self.refcount > 1).sum()),
             "kv_cow_copies_total": self.cow_copies,
             "spec_proposed_total": self.spec_proposed,
@@ -1279,12 +1367,62 @@ class ServingEngine:
                  // self.bs)
 
     # -- refcounted pool + prefix cache (ISSUE 17) ---------------------------
-    def free_pages(self) -> int:
+    def free_pages(self, window: bool = False) -> int:
         """Reclaimable pages: the free list PLUS cached-free pages
         (refcount 0 but still addressable through the prefix cache until
         evicted for allocation). This is the number pool-leak gates and
-        utilization gauges must use — a cached-free page is not leaked."""
+        utilization gauges must use — a cached-free page is not leaked.
+        ``window=True``: the free pages of the window layers' pool (0
+        without one); pages that wait on the step in flight are not free
+        yet."""
+        if window:
+            return len(self.wfree_blocks) if self._windowed else 0
         return len(self.free_blocks) + len(self._cached_free)
+
+    def _window_utilization(self) -> float:
+        total = self._num_wblocks - 1
+        return (1.0 - len(self.wfree_blocks) / total
+                if self._windowed and total else 0.0)
+
+    def _slide_windows(self, q_lens, pos0, lens_after):
+        """The window lifetime at the pack of a step: each running row
+        gives back the pages wholly behind its window (they wait on the
+        step in flight, which may still read them; with none in flight
+        they are free at once) and claims the pages its new positions
+        enter. Returns the pages given back."""
+        from ..enforce import enforce
+        bs, nbw, f = self.bs, self._nbw, self._flight
+        freed = 0
+        for i in np.nonzero(q_lens > 0)[0]:
+            lo = max(int(pos0[i]) - (self._window - 1), 0) // bs
+            hi = (int(lens_after[i]) - 1) // bs + 1
+            gone = range(int(self._wlo[i]), min(lo, int(self._whi[i])))
+            for j in gone:
+                page = int(self.wtables[i, j % nbw])
+                self.wtables[i, j % nbw] = 0
+                (self.wfree_blocks if f is None else f.wfree).append(page)
+            freed += len(gone)
+            self._wlo[i] = max(lo, self._wlo[i])
+            first = max(int(self._whi[i]), lo)
+            enforce(hi - self._wlo[i] <= self._wreserved[i]
+                    and hi - first <= len(self.wfree_blocks),
+                    f"slot {i}: the window ring would hold "
+                    f"{hi - self._wlo[i]} pages of {self._wreserved[i]} "
+                    f"reserved ({len(self.wfree_blocks)} free)",
+                    op="ServingEngine")
+            for j in range(first, hi):
+                self.wtables[i, j % nbw] = self.wfree_blocks.pop()
+            self._whi[i] = max(hi, self._whi[i])
+        self.window_pages_freed += freed
+        return freed
+
+    def _release_window(self, i: int) -> None:
+        """A released slot's ring goes back whole (nothing in flight reads
+        it: `_release_slot`'s callers have seen to that)."""
+        for j in range(int(self._wlo[i]), int(self._whi[i])):
+            self.wfree_blocks.append(int(self.wtables[i, j % self._nbw]))
+        self.wtables[i, :] = 0
+        self._wlo[i] = self._whi[i] = self._wreserved[i] = 0
 
     def _alloc_blocks(self, n: int) -> List[int]:
         """Allocate n private pages (refcount 1): the free list first,
@@ -1397,6 +1535,20 @@ class ServingEngine:
             raise RuntimeError(
                 f"pool audit: {len(free)} free + {len(cached)} cached + "
                 f"{len(live)} live != {self._num_blocks - 1} pool pages")
+        if self._windowed:
+            # free, held in a ring and waiting on the step in flight
+            # partition the window pool
+            held = [int(self.wtables[i, j % self._nbw])
+                    for i in range(self.max_batch)
+                    for j in range(int(self._wlo[i]), int(self._whi[i]))]
+            waiting = list(self._flight.wfree) if self._flight else []
+            pages = self.wfree_blocks + held + waiting
+            if (len(set(pages)) != len(pages) or 0 in pages
+                    or len(pages) != self._num_wblocks - 1):
+                raise RuntimeError(
+                    f"window pool audit: {len(self.wfree_blocks)} free + "
+                    f"{len(held)} held + {len(waiting)} waiting != "
+                    f"{self._num_wblocks - 1} pages, or a page twice")
 
     def _admit(self) -> List[int]:
         """Admit queued requests into free slots while the pool has
@@ -1430,14 +1582,19 @@ class ServingEngine:
                 break
             r = self.queue[0]
             need = self._blocks_needed(r)
-            if need > self.tables.shape[1] or need > usable:
+            # the most window pages the row's ring ever holds: reserved
+            wneed = min(self._nbw, need) if self._windowed else 0
+            if (need > self.tables.shape[1] or need > usable
+                    or (self._windowed
+                        and wneed > self._num_wblocks - 1)):
                 # can never fit, even in an empty pool: reject THIS
                 # request and keep admitting — raising here aborted the
                 # whole engine step and stranded every sibling
                 self.queue.pop(0)
                 cap = (f"max_blocks_per_seq {self.tables.shape[1]}"
                        if need > self.tables.shape[1]
-                       else f"pool capacity {usable}")
+                       else f"pool capacity {usable}" if need > usable
+                       else f"window pool capacity {self._num_wblocks - 1}")
                 r.done = True
                 r.status = "failed"
                 r.error = (f"needs {need} blocks > {cap} — can never be "
@@ -1483,7 +1640,10 @@ class ServingEngine:
                 start = S - 1
                 cow = self.refcount[shared[-1]] >= 2
             need_new = need - matched + (1 if cow else 0)
-            if need_new > self.free_pages():
+            # both pools count: the full pages now, the window pages as a
+            # reservation against the pool's size
+            if need_new > self.free_pages() or (wneed and wneed > (
+                    self._num_wblocks - 1 - int(self._wreserved.sum()))):
                 # pool exhaustion: the injected-fault site the resilience
                 # tests arm, then either preempt a decode victim or wait.
                 # Hand back this attempt's claims first (cached pages
@@ -1493,7 +1653,7 @@ class ServingEngine:
                     self._decref(b)
                 _faults().maybe_fail("serving/pool_exhausted")
                 self._hol_wait_steps += 1
-                if self._try_preempt(r, need_new):
+                if self._try_preempt(r, need_new, wneed):
                     continue  # retry the head against the freed pages
                 self._blocked = ADMIT_BLOCKED.pages
                 break  # head-of-line waits for finishes (no starvation)
@@ -1526,6 +1686,8 @@ class ServingEngine:
             self._reset_tables[i, :need] = pages
             self._reset_tables[i, :n_inherit] = 0
             self._lens[i] = start
+            if self._windowed:
+                self._wreserved[i] = wneed
             r.slot = i
             r.prefill_done = start
             r.prefix_hit_tokens = start if matched else 0
@@ -1544,7 +1706,7 @@ class ServingEngine:
                         help="admissions that reused cached prefix pages")
         return fresh
 
-    def _try_preempt(self, head: Request, need: int) -> bool:
+    def _try_preempt(self, head: Request, need: int, wneed: int = 0) -> bool:
         """Preempt-and-requeue (ISSUE 13c): evict a decode-phase victim so
         the pool-blocked queue head can make progress — its pages free,
         and the victim re-enqueues with prompt+generated-prefix for
@@ -1584,7 +1746,9 @@ class ServingEngine:
             # mostly shared frees almost nothing
             held = sum(1 for b in self.tables[v.slot]
                        if b != 0 and self.refcount[int(b)] == 1)
-            if need <= self.free_pages() + held:
+            wroom = (self._num_wblocks - 1 - int(self._wreserved.sum())
+                     + int(self._wreserved[v.slot])) if wneed else 0
+            if need <= self.free_pages() + held and wneed <= wroom:
                 self._preempt(v)
                 return True
         return False
@@ -1628,6 +1792,8 @@ class ServingEngine:
             self._decref(b)
         self.tables[i, :] = 0
         self._reset_tables[i, :] = 0
+        if self._windowed:
+            self._release_window(i)
         self._lens[i] = 0
         self._slots[i] = None
         self._pending_tok[i] = 0
@@ -1940,6 +2106,17 @@ class ServingEngine:
         tokens_before = self._tokens_total
         if self.spec_k > 0:
             self.settle("spec")     # the proposer reads Request.output
+        if self._windowed and self._flight is not None:
+            # the most window pages this step's rows can claim: a page a
+            # `bs` positions entered, and a row's ring never passes its
+            # reservation, so only pages that wait on the step in flight
+            # can be missing
+            dec, pre, _, _ = self._schedulable()
+            grow = self.chunk + self.decode_burst - 1
+            if len(self.wfree_blocks) < (
+                    len(dec) * -(-self.decode_burst // self.bs)
+                    + len(pre) * -(-grow // self.bs)):
+                self.settle("window")
         with RecordEvent(SERVING_SPANS.admission) as admission:
             preempted = self.preempted
             fresh_slots = self._admit()
@@ -1974,6 +2151,8 @@ class ServingEngine:
         if self.model.routed:   # ids0, ids_burst, stats: fetched with toks
             *out, ids0, ids_burst, stats = out
             route = (ids0, ids_burst, stats)
+        if self._windowed:
+            *out, self._wk_pools, self._wv_pools = out
         if self.model.recurrent:
             *out, self._ssm_state, self._conv_tail = out
         toks, *out = out
@@ -2216,6 +2395,26 @@ class ServingEngine:
             kv_tiles = burst_rows + int(((kv_end[ran] - 1) // tile
                                          - pos0[ran] // tile + 1).sum())
         model_attrs = {}
+        wtables = None
+        if self._windowed:
+            # what a window layer reads of a row in a pass: the positions
+            # its window lets the row's queries see, and their pages
+            W, bs = self._window, self.bs
+            n_full = self.model.kv_layers(self.cfg)
+            n_win = self.model.window_layers(self.cfg)
+            q, end = q_lens[ran].astype(np.int64), kv_end[ran]
+            win_tokens = int(np.minimum(end, W - 1 + q).sum())
+            win_pages = int((-(-end // bs)
+                             - np.maximum(end - q - (W - 1), 0) // bs).sum())
+            for j in range(1, K):
+                end = kv_end[emit > j] + j
+                win_tokens += int(np.minimum(end, W).sum())
+                win_pages += int((-(-end // bs)
+                                  - np.maximum(end - W, 0) // bs).sum())
+            freed = self._slide_windows(q_lens, pos0, lens_after)
+            wtables = self.wtables.copy()
+            model_attrs = dict(zip(WINDOW_DISPATCH_ATTRS, (
+                n_full * kv_tokens + n_win * win_tokens, win_pages, freed)))
         if self.model.recurrent:
             # a row that starts at position 0 has its state zeroed by the
             # program (admission, and re-prefill after a preemption)
@@ -2235,7 +2434,7 @@ class ServingEngine:
             use_spec=use_spec, K=K, q_tokens=cursor, kv_tokens=kv_tokens,
             attn_pages=attn_pages, kv_tiles=kv_tiles,
             starts=starts, pos0=pos0, q_lens=q_lens, emit=emit,
-            lens_after=lens_after, model_attrs=model_attrs,
+            lens_after=lens_after, model_attrs=model_attrs, wtables=wtables,
             # the tables are the engine's own and change under the step
             # in flight (a walk releases, an admission claims): the
             # program gets a copy
@@ -2251,6 +2450,14 @@ class ServingEngine:
         self._key, sub = _split_key(self._key)
         # one transfer call for the twelve small arrays, not an asarray
         # each: the host's share of a step is what a short step feels
+        if self._windowed:
+            # unified_step's full argument list: no scales, no
+            # copy-on-write, no recurrent state, then the second lifetime
+            *arrays, wtables = jax.device_put(list(b.arrays) + [b.wtables])
+            return ((self.params,) + tuple(arrays)
+                    + (self._last_tok, sub, self._k_pools, self._v_pools)
+                    + (None,) * 7
+                    + (wtables, self._wk_pools, self._wv_pools))
         args = ((self.params,) + tuple(jax.device_put(list(b.arrays)))
                 + (self._last_tok, sub, self._k_pools, self._v_pools))
         if self.model.recurrent:
@@ -2285,6 +2492,9 @@ class ServingEngine:
         from ..enforce import enforce
         finished: List[Request] = []
         dec, pre, props_by_slot = b.dec, b.pre, b.props_by_slot
+        if b.wfree:     # window pages this step was the last to read
+            self.wfree_blocks.extend(b.wfree)
+            b.wfree = []
         ran = b.q_lens > 0
         self._lens[ran] = lens[ran]
         wasted = [r for r in dec if r.done]
@@ -2371,6 +2581,13 @@ class ServingEngine:
                 "kv_pool_utilization_peak",
                 1.0 - self.free_pages() / total_blocks,
                 help="high-water allocated fraction of the KV pool")
+        if self._windowed:
+            self._prom.gauge_max(
+                "kv_window_pool_utilization_peak",
+                self._window_utilization(),
+                help="high-water allocated fraction of the window layers' "
+                     "pool (pages that wait on the step in flight count "
+                     "as allocated)")
 
     def _note_completed(self, finished):
         """Completion counters, events and the `serving_request_end`
@@ -2419,6 +2636,17 @@ class ServingEngine:
         prom.gauge_set("kv_pool_utilization", util,
                        help="allocated fraction of the paged KV pool")
         prom.gauge_max("kv_pool_utilization_peak", util)
+        if self._windowed:
+            wutil = self._window_utilization()
+            prom.gauge_set("kv_window_pool_utilization", wutil,
+                           help="allocated fraction of the window layers' "
+                                "pool")
+            prom.gauge_max("kv_window_pool_utilization_peak", wutil)
+            prom.counter_inc("window_pages_freed_total",
+                             self.window_pages_freed - self._wfreed_reported,
+                             help="window-lifetime pages given back behind "
+                                  "the rows' windows")
+            self._wfreed_reported = self.window_pages_freed
         if self.prefix_share:
             prom.gauge_set("kv_pages_shared",
                            int((self.refcount > 1).sum()),
@@ -2489,6 +2717,8 @@ class ServingEngine:
     v_scales = _settled_view("v_scales")
     ssm_state = _settled_view("ssm_state")
     conv_tail = _settled_view("conv_tail")
+    wk_pools = _settled_view("wk_pools")
+    wv_pools = _settled_view("wv_pools")
 
     def serve_metrics(self, port: Optional[int] = None):
         """Start (or return) the /metrics HTTP endpoint — which also

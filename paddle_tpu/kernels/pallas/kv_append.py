@@ -54,14 +54,17 @@ def append_tile(dtype, bs):
     return min(bs, 32 // jnp.dtype(dtype).itemsize)
 
 
-def tile_work(starts, pos0, q_lens, tables, *, bs, tile, c_att, T):
+def tile_work(starts, pos0, q_lens, tables, *, bs, tile, c_att, T,
+              ring=False):
     """The tiles this pass's rows touch: their count n, then scalar-
     prefetch vectors [W] of (page, tile-in-page, packed index of the
     tile's row 0, first and one-past-last row of the tile that is new).
     starts/pos0/q_lens: [R] (row r's ``q_lens[r] <= c_att`` tokens sit at
     packed ``starts[r]..`` and land at positions ``pos0[r]..``); tables:
-    [R, nb]. W is the static bound on n; the walk ends at n, so what the
-    entries past it hold is never read."""
+    [R, nb] (``ring``: page j of a row is entry ``j % nb``, the table of
+    a lifetime whose pages are given back behind a window). W is the
+    static bound on n; the walk ends at n, so what the entries past it
+    hold is never read."""
     R, nb = tables.shape
     W = min(R * (1 + (c_att + tile - 2) // tile),
             R + (T + (tile - 2) * R) // tile)
@@ -71,7 +74,8 @@ def tile_work(starts, pos0, q_lens, tables, *, bs, tile, c_att, T):
     w = jnp.arange(W, dtype=jnp.int32)
     row = jnp.minimum(jnp.searchsorted(ends, w, side="right"), R - 1)
     pos = (first[row] + w - (ends[row] - count[row])) * tile
-    page = tables[row, jnp.clip(pos // bs, 0, nb - 1)]
+    page = tables[row, (pos // bs) % nb if ring
+                  else jnp.clip(pos // bs, 0, nb - 1)]
     lo = jnp.clip(pos0[row] - pos, 0, tile)
     hi = jnp.clip(pos0[row] + q_lens[row] - pos, 0, tile)
     tok0 = starts[row] + pos - pos0[row]

@@ -27,8 +27,10 @@ down matrix (at 5120 x 1536 an expert's three matrices are 47 MB, which no
 VMEM holds twice over; three 5120 x 512 blocks, double-buffered, are
 31 MB), the tile's float32 output stays where it is while the width's
 blocks add to it, and it is written back when the walk leaves the tile.
-An F of one block (2048 x 512) is the walk it was. A step past the last
-real tile stays on the LAST block of the width too.
+An F of one block (2048 x 512) is the walk it was, and so is a wider
+expert whose three matrices still fit VMEM twice over (2048 x 1024, 25 MB:
+one block, so an expert's consecutive tiles fetch it once). A step past
+the last real tile stays on the LAST block of the width too.
 
 No assignment is ever dropped: there is no capacity.
 """
@@ -48,7 +50,9 @@ from ...observability.trace import KERNELS
 __all__ = ["TM", "n_tiles_max", "plan", "grouped_ffn", "combine"]
 
 TM = 16     # rows of a tile: one bf16 sublane tile
-BLOCK_F = 512    # the most of an expert's width a grid step takes
+BLOCK_F = 512    # the most of an expert's width a grid step takes, unless
+#                  the whole width fits `WHOLE_F_BYTES` double-buffered
+WHOLE_F_BYTES = 32 << 20
 _F32 = jnp.float32
 
 
@@ -134,7 +138,12 @@ def grouped_ffn(x, gate_w, up_w, down_w, layer, p):
     the last real one hold nothing defined."""
     T, H = x.shape
     _, E, _, F = gate_w.shape
-    FB = min(BLOCK_F, F)
+    # an expert's whole width where its three matrices fit VMEM twice over
+    # (2048 x 1024: 25 MB): consecutive tiles of one expert then stay on
+    # its blocks and fetch nothing, where a width in blocks walks the
+    # expert's blocks again for every tile
+    whole = 2 * 3 * H * F * gate_w.dtype.itemsize <= WHOLE_F_BYTES
+    FB = F if whole else min(BLOCK_F, F)
     assert F % FB == 0, (F, FB)
     nF = F // FB
     G = p["tile_expert"].shape[0]

@@ -109,7 +109,7 @@ _NEG_INF = -1e30
 _NARROW = 8
 
 
-def _ragged_kernel(*refs, scale, bs, hq, C, quantized, qmax):
+def _ragged_kernel(*refs, scale, bs, hq, C, quantized, qmax, window):
     # scalar prefetch (the quantized pools' two scale tables last), the
     # operands where they lie in HBM (the fourth is the zeroed buffer the
     # output aliases), the output, scratch
@@ -127,10 +127,26 @@ def _ragged_kernel(*refs, scale, bs, hq, C, quantized, qmax):
     layer = layer_ref[0]
     cn = min(_NARROW // g, C)   # chunk positions the narrow arm holds
 
+    def first_page(row):
+        """The first page `row` reads: 0, or under a window the page that
+        holds the oldest position its first query attends (the pages
+        before it are neither copied nor waited on)."""
+        if window is None:
+            return 0
+        behind = kvlens_ref[row] - qlens_ref[row] - (window - 1)
+        return jax.lax.div(jax.lax.max(behind, 0), bs)
+
+    def page_of(row, j):
+        """`row`'s j-th page; under a window its table is a RING, page j
+        in entry j % nb (the pages behind the window were given back)."""
+        if window is None:
+            return tables_ref[row, j]
+        return tables_ref[row, jax.lax.rem(j, nb)]
+
     def page_copies(row, j, slot):
         """Every KV head's tile of `row`'s j-th page: K and V, one strided
         copy each into buffer `slot`."""
-        page = tables_ref[row, j]
+        page = page_of(row, j)
         return (pltpu.make_async_copy(k_hbm.at[layer, :, page],
                                       kbuf.at[slot], sem.at[0, slot]),
                 pltpu.make_async_copy(v_hbm.at[layer, :, page],
@@ -156,7 +172,7 @@ def _ragged_kernel(*refs, scale, bs, hq, C, quantized, qmax):
 
     def start_row(row, slot):
         """`row`'s first page and its queries, at the size its arm reads."""
-        start(row, 0, slot)
+        start(row, first_page(row), slot)
         if 0 < cn < C:
             narrow = qlens_ref[row] <= cn
             pl.when(narrow)(lambda: query_copy(row, cn).start())
@@ -212,7 +228,7 @@ def _ragged_kernel(*refs, scale, bs, hq, C, quantized, qmax):
 
         @pl.when(stream[1] == 0)
         def _own_first_copies():
-            start(r, 0, stream[0])
+            start(r, first_page(r), stream[0])
             query_copy(r, ch).start()
 
         query_copy(r, ch).wait()
@@ -231,8 +247,13 @@ def _ragged_kernel(*refs, scale, bs, hq, C, quantized, qmax):
                                      jnp.float32)
         # the row's own pages (scalars go through lax, not the jitted jnp
         # helpers: non-negative operands need no sign fix-up)
-        n = jax.lax.clamp(1, jax.lax.div(kl + bs - 1, bs), nb)
-        base = stream[0]
+        if window is None:
+            n, j0 = jax.lax.clamp(1, jax.lax.div(kl + bs - 1, bs), nb), 0
+        else:   # a ring's pages are counted past its width
+            n = jax.lax.max(jax.lax.div(kl + bs - 1, bs), 1)
+            j0 = first_page(r)
+        # page j0 lands in buffer stream[0]
+        base = stream[0] if window is None else stream[0] - j0
         nxt = next_ref[r]
 
         # row f of the folded tile is chunk position c = f % ch; its
@@ -244,7 +265,7 @@ def _ragged_kernel(*refs, scale, bs, hq, C, quantized, qmax):
         last_col = kl - ql + c
 
         def page_step(j, carry):
-            slot = jax.lax.rem(base + j, 2)
+            slot = jax.lax.rem(base + j, 2)     # base + j >= 0
 
             @pl.when(j + 1 < n)
             def _next_page():
@@ -256,8 +277,11 @@ def _ragged_kernel(*refs, scale, bs, hq, C, quantized, qmax):
 
             for copy in page_copies(r, j, slot):
                 copy.wait()
-            ok = ((c < ql) & (j * bs + col <= last_col))[None]
-            page = tables_ref[r, j]
+            ok = (c < ql) & (j * bs + col <= last_col)
+            if window is not None:  # query i attends i - window < j <= i
+                ok = ok & (j * bs + col > last_col - window)
+            ok = ok[None]
+            page = page_of(r, j)
 
             def head_scales(ref, h0):
                 """ref[h, page] / qmax for the update's heads: a scalar
@@ -315,7 +339,7 @@ def _ragged_kernel(*refs, scale, bs, hq, C, quantized, qmax):
                 jax.lax.fori_loop(0, hkv // nh, group, None, unroll=True)
             return carry
 
-        jax.lax.fori_loop(0, n, page_step, None)
+        jax.lax.fori_loop(j0, n, page_step, None)
         stream[0] = jax.lax.rem(base + n, 2)
         stream[1] = (nxt < R).astype(jnp.int32)
         l = l_sc[:, :rows, :1]
@@ -361,7 +385,8 @@ def _ragged_kernel(*refs, scale, bs, hq, C, quantized, qmax):
 
 def ragged_paged_attention(q, k_pool, v_pool, block_tables, starts, q_lens,
                            kv_lens, scale: float, k_scales=None,
-                           v_scales=None, layer=0, *, c_att: int):
+                           v_scales=None, layer=0, *, c_att: int,
+                           window=None):
     """q: [T, H_q, D], the step's PACKED queries — row r's chunk occupies
     positions [starts[r], starts[r] + q_lens[r]), rows in any order, no two
     overlapping; ``c_att`` (static) is the longest chunk a row may hold:
@@ -375,7 +400,13 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, starts, q_lens,
     (0 = inactive row, whose ``starts`` is not read); kv_lens: [R] int32 —
     TOTAL kv length including this chunk (query c sits at absolute
     position kv_lens - q_lens + c) → [T, H_q, D], packed as q is: a row's
-    positions hold its output, every other position reads zero."""
+    positions hold its output, every other position reads zero.
+    ``window`` (static; None: causal only): query i attends the keys j with
+    ``i - window < j <= i``. ``block_tables`` is then a RING: page j of a
+    row is entry ``j % nb`` (`inference.serving` gives the pages behind the
+    window back), and a row reads from the page that holds position
+    ``kv_lens - q_lens - (window - 1)`` on; earlier pages are neither
+    copied nor waited on, so their entries may hold anything."""
     T, hq, D = q.shape
     R = block_tables.shape[0]
     C = min(c_att, T)       # no row holds more positions than the buffer
@@ -448,7 +479,8 @@ def ragged_paged_attention(q, k_pool, v_pool, block_tables, starts, q_lens,
     # belong to no row (the buffer's tail padding) read zero
     out = pl.pallas_call(
         functools.partial(_ragged_kernel, scale=scale, bs=bs, hq=hq, C=C,
-                          quantized=quantized, qmax=qmax),
+                          quantized=quantized, qmax=qmax,
+                          window=None if window is None else int(window)),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         input_output_aliases={len(prefetch) + 3: 0},

@@ -15,7 +15,8 @@ trace of one commit can be held against a trace of the next:
 * ``SERVING_SPANS`` — the host spans of one ``ServingEngine.step()``;
 * ``DISPATCH_ATTRS`` — the attributes of the ``serving_unified_dispatch``
   span (stats of the event in a profiler trace), and ``SSM_DISPATCH_ATTRS``
-  — the ones a model with a recurrent state adds; ``MOE_FETCH_ATTRS`` —
+  — the ones a model with a recurrent state adds, ``WINDOW_DISPATCH_ATTRS``
+  — a model with windowed layers'; ``MOE_FETCH_ATTRS`` —
   the attributes a model with routed experts puts on ``serving_fetch``;
   ``ADMISSION_ATTRS`` — what ``serving_admission`` closes with, and
   ``ADMIT_BLOCKED``, the values of its ``blocked``;
@@ -46,7 +47,8 @@ from ..profiler.utils import HostEvent, RecordEvent, collector
 
 __all__ = ["span", "capture_spans", "write_chrome_trace", "SERVING_SPANS",
            "DISPATCH_ATTRS", "SSM_DISPATCH_ATTRS", "LATENT_DISPATCH_ATTRS",
-           "MOE_FETCH_ATTRS", "MOE_LOCAL_FETCH_ATTRS",
+           "WINDOW_DISPATCH_ATTRS", "MOE_FETCH_ATTRS",
+           "MOE_LOCAL_FETCH_ATTRS",
            "ADMISSION_ATTRS", "ADMIT_BLOCKED", "REQUEST_SPANS",
            "REQUEST_PHASES", "FIRST_TOKEN_ATTRS", "REQUEST_END_ATTRS",
            "SCOPES", "KERNELS"]
@@ -118,6 +120,16 @@ MOE_LOCAL_FETCH_ATTRS = ("moe_local_tokens", "moe_tokens")
 # `kernels.pallas.mla_attention`), summed over the k passes.
 LATENT_DISPATCH_ATTRS = ("prefix_hit_tokens", "chunk_ctx_tokens",
                          "attn_shared_pages")
+# A model with WINDOWED attention layers (two page lifetimes) adds:
+# `kv_layer_tokens`, the positions the step's attention kernels attend
+# summed over the LAYERS too (`kv_tokens` counts a row's whole context once,
+# as a layer that attends everything reads it; a window layer reads
+# min(kv_len, window - 1 + q_len) of a row a pass), `win_attn_pages`, the
+# (row, page) pairs a window layer's kernel walks over the k passes, and
+# `win_pages_freed`, the window-lifetime pages this pack gave back behind
+# the rows' windows.
+WINDOW_DISPATCH_ATTRS = ("kv_layer_tokens", "win_attn_pages",
+                         "win_pages_freed")
 # What `serving_admission` closes with: requests admitted by this call,
 # the queue's depth after it, why the queue's head still waits (one of
 # ADMIT_BLOCKED), and decode victims this call evicted for it.
@@ -164,6 +176,9 @@ SCOPES = _names(
     embed="embed", qkv="qkv", kv_write="kv_write", ragged_attn="ragged_attn",
     proj_mlp="proj_mlp", head="head", sample="sample", cow="cow",
     burst="burst",
+    # the ragged kernel under a window, over the second lifetime's pools
+    # (a layer of kind "window", models/trinity_mini.py)
+    window_attn="window_attn",
     # a hybrid block's recurrent mixer beside attention (models/falcon_h1.py)
     rope="rope", ssm_in="ssm_in", ssm_conv="ssm_conv", ssm_scan="ssm_scan",
     ssm_out="ssm_out",

@@ -651,6 +651,73 @@ def test_deepseek_v2_step_keeps_pool_and_experts_in_place(
 
 
 # ---------------------------------------------------------------------------
+# the Trinity-Mini cell's step (ISSUE 54): published widths, one dense window
+# layer + window, window, window, full, all 128 experts and the whole
+# vocabulary, 64 slots, 4,251 full-lifetime pages with tables of 262, 1,281
+# window-lifetime pages with rings of 20, chunks of 256 under a budget of
+# 1,088 packed tokens
+# ---------------------------------------------------------------------------
+TRIN_ROWS, TRIN_TOKENS, TRIN_CHUNK = 64, 1088, 256
+TRIN_PAGES, TRIN_TABLE, TRIN_WPAGES, TRIN_RING = 4251, 262, 1281, 20
+
+
+def _compile_trinity_step(one_chip, K):
+    """(compiled step, params, the two lifetimes' pool shapes)."""
+    from paddle_tpu.inference import ragged_step as RS
+    from paddle_tpu.models import trinity_mini as TM
+    cfg = TM.TrinityMiniConfig(num_layers=5, num_dense_layers=1)
+    params = jax.tree.map(
+        lambda a: _sds(one_chip, a.shape, a.dtype),
+        jax.eval_shape(lambda: TM.init_params(cfg, jax.random.PRNGKey(0))))
+    R, tokens = TRIN_ROWS, TRIN_TOKENS
+
+    def i32(*shape):
+        return _sds(one_chip, shape, jnp.int32)
+
+    def flags():
+        return _sds(one_chip, (R,), jnp.bool_)
+
+    shapes = [(1, cfg.num_kv_heads, TRIN_PAGES, PAGE, cfg.head_dim),
+              (4, cfg.num_kv_heads, TRIN_WPAGES, PAGE, cfg.head_dim)]
+    full, win = (_sds(one_chip, shape, jnp.bfloat16) for shape in shapes)
+    args = [params, i32(tokens), i32(tokens), i32(tokens), i32(R), i32(R),
+            i32(R), i32(R, TRIN_TABLE), flags(), flags(), i32(R), i32(R),
+            _sds(one_chip, (R,), jnp.float32), i32(R),
+            _sds(one_chip, (2,), jnp.uint32), full, full] + [None] * 7 + [
+            i32(R, TRIN_RING), win, win]
+    step = functools.partial(RS.unified_step, cfg=cfg, bs=PAGE,
+                             c_att=TRIN_CHUNK, K=K)
+    compiled = jax.jit(step, donate_argnums=(15, 16, 25, 26)
+                       ).lower(*args).compile()
+    return compiled, params, shapes
+
+
+@pytest.mark.parametrize("K", [1, 8], ids=["trin-pass1", "trin-burst"])
+def test_trinity_step_keeps_both_lifetimes_pools_and_experts_in_place(
+        one_chip, compiled_kernels, K):
+    """The prologue and the period scan keep the contract on BOTH
+    lifetimes' pools: no copy, slice or update the size of a pool (all its
+    layers' or one layer's pages) or of a layer's expert matrices; temp
+    under 1 GiB; all four pools come back aliased; `ragged_paged_attn`
+    lowers under a window (a ring table of 20) and without one, and
+    `moe_grouped_ffn` with an expert's whole width of 1,024 in one
+    block."""
+    compiled, params, shapes = _compile_trinity_step(one_chip, K)
+    text = compiled.as_text()
+    for kernel in ("ragged_paged_attn", "kv_append", "moe_grouped_ffn"):
+        assert kernel in text, f"{kernel} was not lowered for the chip"
+    buffers = [s[i:] for s in shapes for i in (0, 1)]
+    for expert in ((128, 2048, 1024), (128, 1024, 2048)):
+        buffers += [expert, (3,) + expert]
+    assert _moved(text, {_no_leading_ones(b) for b in buffers}.__contains__,
+                  by_shape=True) == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
+    assert mem.alias_size_in_bytes >= sum(2 * math.prod(s) * 2
+                                          for s in shapes)
+
+
+# ---------------------------------------------------------------------------
 # every serving step above: a GEMM takes its layer's weight where it lies in
 # the scan's stack (ISSUE 43)
 # ---------------------------------------------------------------------------

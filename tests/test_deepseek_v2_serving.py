@@ -67,6 +67,16 @@ def params():
     return WD.make_params(W, 3, jnp.float32)
 
 
+@pytest.fixture(autouse=True)
+def width_in_blocks(monkeypatch):
+    """The toy expert (64 x 1024 in float32) would fit VMEM twice over and
+    be taken whole (`kernels.pallas.moe.WHOLE_F_BYTES`, PR 54); the
+    published one (5120 x 1536) does not: keep the toy on the width's two
+    blocks, the walk this file is about."""
+    from paddle_tpu.kernels.pallas import moe
+    monkeypatch.setattr(moe, "WHOLE_F_BYTES", 0)
+
+
 @pytest.fixture
 def logits(monkeypatch):
     return Logits(monkeypatch)

@@ -46,7 +46,8 @@ from paddle_tpu.observability.trace import (ADMISSION_ATTRS,  # noqa: E402
                                             REQUEST_END_ATTRS,
                                             REQUEST_PHASES, REQUEST_SPANS,
                                             SCOPES, SERVING_SPANS,
-                                            SSM_DISPATCH_ATTRS)
+                                            SSM_DISPATCH_ATTRS,
+                                            WINDOW_DISPATCH_ATTRS)
 from paddle_tpu.profiler.utils import RecordEvent, collector  # noqa: E402
 
 from chipbench import harness, program_trace  # noqa: E402
@@ -502,6 +503,53 @@ def test_the_latent_serving_step_carries_its_scopes_and_attributes():
     assert sum(a["moe_local_tokens"] for a in fetch) == eng.moe_local_tokens
 
 
+def test_the_windowed_serving_step_carries_its_scopes_and_attributes():
+    """Trinity-Mini through the same engine: `window_attn` beside
+    `ragged_attn` (the one kernel under a window and without), `qkv` and
+    `rope`, the expert layer's three, `proj_mlp`; the dispatch span
+    carries what the window layers read and the pages the pack gave back,
+    the fetch span the router's counts."""
+    from paddle_tpu.models import trinity_mini as TM
+    cfg = TM.TrinityMiniConfig(
+        vocab_size=64, hidden_size=32, num_layers=5, num_dense_layers=1,
+        num_heads=4, num_kv_heads=2, head_dim=8, sliding_window=8,
+        intermediate_size=48, num_experts=8, experts_per_tok=2, moe_ffn=16,
+        shared_ffn=16, experts_held=(0, 8), dtype=jnp.float32,
+        param_dtype=jnp.float32)
+    params = TM.init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServingEngine(params, cfg, max_batch=2, block_size=4,
+                        num_blocks=16, chunk=8, decode_burst=2)
+    eng.add_request(np.arange(21) % 64, max_new_tokens=4)
+    batch = eng._pack_ragged(eng._admit())
+    lowered = eng._build_unified(2).lower(*eng._upload_ragged(batch))
+    assert _scopes_in(lowered) == {
+        SCOPES.embed, SCOPES.qkv, SCOPES.rope, SCOPES.kv_write,
+        SCOPES.ragged_attn, SCOPES.window_attn, SCOPES.moe_route,
+        SCOPES.moe_experts, SCOPES.moe_shared, SCOPES.proj_mlp, SCOPES.head,
+        SCOPES.sample, SCOPES.burst}
+    eng._release_slot(eng._slots[0])    # the hand-packed step never ran
+    eng.add_request(np.arange(21) % 64, max_new_tokens=4)
+    with obs.capture_spans() as cap:
+        eng.run()
+    disp = [e.attrs for e in cap.events if e.name == SERVING_SPANS.dispatch]
+    assert all(tuple(a) == DISPATCH_ATTRS + WINDOW_DISPATCH_ATTRS
+               for a in disp)
+    # the first step ran 8 prompt tokens from position 0: every layer
+    # reads the row's 8 positions (1 full + 4 window layers), 2 pages
+    assert disp[0]["kv_tokens"] == 8 and disp[0]["kv_layer_tokens"] == 40
+    assert disp[0]["win_attn_pages"] == 2 and disp[0]["win_pages_freed"] == 0
+    # the third (positions 16-20) sees 7 + 5 positions under the window
+    # and 21 without; the page behind the window went back at its pack
+    assert disp[2]["kv_tokens"] == 21 and disp[2]["win_pages_freed"] >= 1
+    assert disp[2]["k"] == 1 and disp[2]["kv_layer_tokens"] == 21 + 4 * 12
+    assert sum(a["win_pages_freed"] for a in disp) == eng.window_pages_freed
+    fetch = [e.attrs for e in cap.events if e.name == SERVING_SPANS.fetch]
+    assert all(tuple(a) == MOE_FETCH_ATTRS for a in fetch)
+    assert sum(a["moe_experts_touched"] for a in fetch) == \
+        eng.moe_experts_touched
+    assert eng.free_pages(window=True) == eng._num_wblocks - 1
+
+
 # -- a request's life, and the scheduler's choices (ISSUE 38) ----------------
 def test_a_span_takes_attributes_until_it_closes(tmp_path):
     """`RecordEvent.set` merges into the span's attributes and reaches a
@@ -801,7 +849,8 @@ SLICE_FILES = sorted(map(os.path.basename, glob.glob(
     os.path.join(DATA, "ptrace-*.json.gz"))))
 # what each span may carry, by the tuple the call site takes it from
 SPAN_ATTRS = {SERVING_SPANS.dispatch: (DISPATCH_ATTRS + SSM_DISPATCH_ATTRS
-                                       + LATENT_DISPATCH_ATTRS),
+                                       + LATENT_DISPATCH_ATTRS
+                                       + WINDOW_DISPATCH_ATTRS),
               SERVING_SPANS.fetch: MOE_FETCH_ATTRS + MOE_LOCAL_FETCH_ATTRS,
               SERVING_SPANS.admission: ADMISSION_ATTRS,
               REQUEST_SPANS.first_token: FIRST_TOKEN_ATTRS,
