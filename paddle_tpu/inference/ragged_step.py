@@ -138,9 +138,9 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
     speculative-decoding verify signal (draft token i is accepted iff it
     equals the model's own argmax one position earlier). A model with
     routed experts adds, last, what its router chose: (ids [L, T, k],
-    stats [L, 3]) as `models.qwen3_next.moe_layer` gives them a layer (L:
-    the layers WITH a router; `models.deepseek_v2.moe_layer`'s stats are
-    5 wide)."""
+    stats [L, columns]) as the model's `moe_layer` gives them a layer (L:
+    the layers WITH a router; the model's `route_stats` names the
+    columns)."""
     T = tokens.shape[0]
     quantized = ks is not None
     model = serving_model(cfg)
@@ -274,7 +274,7 @@ def ragged_pass(params, tokens, row_of, off_of, starts, pos0, q_lens,
             att0 += n if kind not in ("linear", "window") else 0
             mix0 += n if kind != "attention" else 0
             win0 += n if kind == "window" else 0
-        # the period's routing in layer order: (ids [n, T, k], stats [n, 3])
+        # the period's routing in layer order: (ids [n, T, k], stats [n, .])
         route = (jax.tree.map(lambda *a: jnp.concatenate(a), *routes)
                  if routed else None)
         return carry, route
@@ -370,9 +370,11 @@ def unified_step(params, tokens, row_of, off_of, starts, pos0, q_lens,
     A model with routed experts (``routed``) returns what its router
     chose, last: ids0 [L, T, k] int16, the picks of every packed position
     of pass 1 and layer; ids_burst [K-1, L, R, k], the burst passes' (row
-    r's one position); stats [K, L, 3] int32, per pass and layer the held
-    experts touched, the assignments to them and the largest number one
-    of them got. They are fetched with the tokens: no sync of their own.
+    r's one position); stats [K, L, columns] int32, per pass and layer
+    what the model's `route_stats` names (`kernels.pallas.moe.PASS_STATS`:
+    held experts touched, the assignments to them, the largest number one
+    of them got, tiles walked and their height). They are fetched with the
+    tokens: no sync of their own.
 
     Returns (toks [K, R], kp, vp, ks, vs, lens [R], last_tok [R]); with
     ``spec=True`` (K must be 1) the return gains ``greedy_all [T]`` after

@@ -463,7 +463,8 @@ class GPTServing:
     and a per-slot state that the engine keeps beside the KV pages; one
     with ``routed = True`` has `block_math` take its run's experts whole
     (``experts=``, ``layer=``) and return (x, (ids, stats)), and says
-    how many of its layers have a router (`routed_layers`); one with
+    how many of its layers have a router (`routed_layers`) and what the
+    columns of `stats` are (`route_stats`); one with
     ``latent = True`` (`models.deepseek_v2.Serving`) keeps a head-less
     latent cache (`pool_shapes`, `latent_qkv`, `attn_scale`); `prologue`
     is the run of leading layers (``params["prologue"]``, no experts)
@@ -2182,22 +2183,28 @@ class ServingEngine:
         return self._walk_ragged(f, toks, greedy_all, lens, *route[:2])
 
     def _note_routing(self, stats):
-        """A landed step's router counts, stats [K, L, 3] (touched,
-        assignments, largest) of its K passes and L layers: added to the
-        totals, and returned as the fetch span's MOE_FETCH_ATTRS."""
-        touched, assigned = int(stats[..., 0].sum()), int(stats[..., 1].sum())
+        """A landed step's router counts, stats [K, L, columns] of its K
+        passes and L layers, the columns named by the model's
+        `route_stats`: added to the totals, and returned as the fetch
+        span's MOE_FETCH_ATTRS (sums, but the largest load and the tile
+        height: the tallest of the step's passes)."""
+        col = {name: stats[..., i]
+               for i, name in enumerate(self.model.route_stats)}
+        touched, assigned = (int(col["touched"].sum()),
+                             int(col["assignments"].sum()))
         self.moe_experts_touched += touched
         self.moe_assignments += assigned
-        ran = stats[..., 1] > 0
+        ran = col["assignments"] > 0
         self.moe_passes += int(ran.any(axis=1).sum())
         self._moe_load += np.where(
-            ran, stats[..., 2] * self._moe_held
-            / np.maximum(stats[..., 1], 1), 0.0).sum(axis=0)
+            ran, col["load_max"] * self._moe_held
+            / np.maximum(col["assignments"], 1), 0.0).sum(axis=0)
         attrs = dict(zip(MOE_FETCH_ATTRS, (
-            touched, assigned, int(stats[..., 2].max()))))
-        if stats.shape[-1] > 3:     # a group-limited router's two more
-            local, routed = (int(stats[..., 3].sum()),
-                             int(stats[..., 4].sum()))
+            touched, assigned, int(col["load_max"].max()),
+            int(col["tiles"].sum()), int(col["tile_rows"].max()))))
+        if "local_tokens" in col:   # a group-limited router's two more
+            local, routed = (int(col["local_tokens"].sum()),
+                             int(col["tokens"].sum()))
             self.moe_local_tokens += local
             self.moe_tokens += routed
             attrs.update(zip(MOE_LOCAL_FETCH_ATTRS, (local, routed)))

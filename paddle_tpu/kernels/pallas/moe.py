@@ -8,10 +8,10 @@ one expert after another, so that an expert no token chose never leaves
 HBM (a decode pass of 64 rows touches ~183 of 256 held experts; each is
 6.3 MB at 2048 x 512 x 3 in bfloat16).
 
-`plan` sorts the pass's (token, pick) pairs by held expert and lays each
-expert's group out in whole TILES of `TM` rows (rows past a group's count
+`plan` sorts the pass's (token, pick) pairs by held expert and `tiles` lays
+each expert's group out in whole TILES of `tm` rows (rows past a group's count
 are padding: zero inputs, outputs nobody reads). The kernel's grid walks
-the tiles: tile w is rows [w TM, (w + 1) TM) of the padded buffer and
+the tiles: tile w is rows [w tm, (w + 1) tm) of the padded buffer and
 belongs to expert ``tile_expert[w]`` (scalar prefetch, dereferenced in the
 weights' index maps beside ``layer``: the stacked weights
 ``[layers, experts, ., .]`` are never sliced, by layer or by expert;
@@ -19,7 +19,18 @@ consecutive tiles of one expert do not fetch it again). The static grid
 is the bound `n_tiles_max`; a step past the last real tile stays on its
 blocks and does nothing. The token rows are gathered into the padded
 buffer before the call and the weighted outputs gathered back after it
-(`combine`), both in XLA: at most TM - 1 padding rows an expert.
+(`combine`), both in XLA: at most tm - 1 padding rows an expert.
+
+The tile's HEIGHT `tm` is the plan's, and it follows the pass
+(`tile_rows`): the pipeline fetches one grid step ahead, so the next
+expert's weights stream in only behind an expert's LAST tile and every
+tile before it is exposed, at a cost that grows slowly with its rows (the
+weights it latches are the same). Where a pass gives an expert many rows
+(a prefill pass of 1,088 tokens x top-8 over 128 experts: 68) tiles of 16
+walk every expert four or five times and leave the kernel at half its
+HBM roofline, and where it gives an expert a few (a decode pass) a taller
+tile is only padding. The height is a function of the pass's static
+shapes alone: the rows an expert can expect, tokens x picks / experts.
 
 An expert's WIDTH F is a second, inner grid axis: a step takes
 ``[H, BLOCK_F]`` of the gate and the up matrix and ``[BLOCK_F, H]`` of the
@@ -47,46 +58,78 @@ from jax.experimental.pallas import tpu as pltpu
 from ._common import interpret as _interpret
 from ...observability.trace import KERNELS
 
-__all__ = ["TM", "n_tiles_max", "plan", "grouped_ffn", "combine"]
+__all__ = ["TM_MIN", "TM_MAX", "PASS_STATS", "tile_rows", "tile_rows_of",
+           "n_tiles_max", "plan", "tiles", "pass_stats", "grouped_ffn",
+           "combine"]
 
-TM = 16     # rows of a tile: one bf16 sublane tile
+TM_MIN = 16     # the least rows of a tile: one bf16 sublane tile
+TM_MAX = 128    # the most: the MXU's rows
 BLOCK_F = 512    # the most of an expert's width a grid step takes, unless
 #                  the whole width fits `WHOLE_F_BYTES` double-buffered
 WHOLE_F_BYTES = 32 << 20
 _F32 = jnp.float32
 
 
-def n_tiles_max(assignments, experts):
-    """The most tiles `assignments` pairs over `experts` groups can fill:
-    each group's last tile may be partial."""
-    return experts + -(-assignments // TM)
+def tile_rows(tokens, picks, experts):
+    """The tile height for a pass of `tokens` positions with `picks`
+    experts each out of the router's `experts`: the rows an expert can
+    expect, r = tokens x picks / experts, down to a whole number of
+    sublane tiles, between TM_MIN and TM_MAX. All three are static, so the
+    height is the traced program's and nothing chooses it at run time."""
+    r = tokens * picks // experts
+    return max(TM_MIN, min(TM_MAX, r // TM_MIN * TM_MIN))
+
+
+def n_tiles_max(assignments, experts, tm):
+    """The most tiles of `tm` rows `assignments` pairs over `experts`
+    groups can fill: each group's last tile may be partial."""
+    return experts + -(-assignments // tm)
+
+
+def tile_rows_of(p):
+    """The tile height `tiles` laid the plan `p` out with (its shapes
+    say)."""
+    return p["token_of_row"].shape[0] // p["tile_expert"].shape[0]
 
 
 def plan(ids, lo, hi):
     """Group the picks ids: [T, k] (expert numbers over ALL the router's
-    experts) that fall on the held experts [lo, hi). Returns a dict:
-    ``held`` [T, k] bool; ``pos`` [T, k], the pick's row in the padded
-    buffer (0 where not held); ``token_of_row`` [G * TM] (T marks
-    padding); ``tile_expert`` [G] (held-expert index, the last real
-    tile's past the end) and ``n_tiles`` [1]; ``counts`` [hi - lo], the
-    assignments each held expert got."""
+    experts) that fall on the held experts [lo, hi). Returns the groups,
+    for `tiles` to lay out: ``held`` [T, k] bool; ``key`` [T k], a pair's
+    held-expert index (hi - lo where not held); ``order`` [T k], the pairs
+    sorted by it; ``counts`` [hi - lo], the assignments each held expert
+    got."""
     T, k = ids.shape
     E, N = hi - lo, T * k
-    G = n_tiles_max(N, E)
     held = (ids >= lo) & (ids < hi)
     key = jnp.where(held, ids - lo, E).reshape(N).astype(jnp.int32)
     counts = jnp.sum((key[:, None] == jnp.arange(E)[None, :])
                      .astype(jnp.int32), axis=0)                    # [E]
     order = jnp.argsort(key, stable=True).astype(jnp.int32)          # [N]
+    return {"held": held, "key": key, "order": order, "counts": counts}
+
+
+def tiles(groups, tm):
+    """Lay `plan`'s groups out in tiles of `tm` rows (`tile_rows`).
+    Returns the plan `grouped_ffn` and `combine` take, a dict: ``held``
+    and ``counts`` as they were; ``pos`` [T, k], the pick's row in the
+    padded buffer (0 where not held); ``token_of_row`` [G * tm] (T marks
+    padding); ``tile_expert`` [G] (held-expert index, the last real
+    tile's past the end) and ``n_tiles`` [1]."""
+    assert TM_MIN <= tm <= TM_MAX and tm % TM_MIN == 0, tm
+    held, key, order, counts = (groups[n] for n in
+                                ("held", "key", "order", "counts"))
+    (T, k), E, N = held.shape, counts.shape[0], key.shape[0]
+    G = n_tiles_max(N, E, tm)
     group_end = jnp.cumsum(counts)
     group_start = group_end - counts
-    tiles = (counts + TM - 1) // TM
-    tile_end = jnp.cumsum(tiles)
-    tile_start = tile_end - tiles
+    group_tiles = (counts + tm - 1) // tm
+    tile_end = jnp.cumsum(group_tiles)
+    tile_start = tile_end - group_tiles
     n_tiles = tile_end[-1]
     # a sorted pair's row: its group's first tile, then its rank
     sk = jnp.minimum(key[order], E - 1)
-    row_sorted = tile_start[sk] * TM + jnp.arange(N) - group_start[sk]
+    row_sorted = tile_start[sk] * tm + jnp.arange(N) - group_start[sk]
     pos = row_sorted[jnp.argsort(order)].reshape(T, k)
     # a padded row's token: its tile's expert, its rank in the group
     w = jnp.arange(G, dtype=jnp.int32)
@@ -94,15 +137,30 @@ def plan(ids, lo, hi):
     tile_expert = jnp.minimum(
         jnp.searchsorted(tile_end, wc, side="right"), E - 1
     ).astype(jnp.int32)
-    rank = ((w - tile_start[tile_expert]) * TM)[:, None] \
-        + jnp.arange(TM)[None, :]                                   # [G, TM]
+    rank = ((w - tile_start[tile_expert]) * tm)[:, None] \
+        + jnp.arange(tm)[None, :]                                   # [G, tm]
     real = (w < n_tiles)[:, None] & (rank < counts[tile_expert][:, None])
     at = jnp.clip(group_start[tile_expert][:, None] + rank, 0, N - 1)
-    token_of_row = jnp.where(real, order[at] // k, T).reshape(G * TM)
+    token_of_row = jnp.where(real, order[at] // k, T).reshape(G * tm)
     return {"held": held, "pos": jnp.where(held, pos, 0),
             "token_of_row": token_of_row, "tile_expert": tile_expert,
             "n_tiles": n_tiles.reshape(1).astype(jnp.int32),
             "counts": counts}
+
+
+# what `pass_stats` says of a pass and layer, in column order: held experts
+# with an assignment, assignments to held experts, the most one held expert
+# got, real tiles walked, and the tiles' height
+PASS_STATS = ("touched", "assignments", "load_max", "tiles", "tile_rows")
+
+
+def pass_stats(p):
+    """The counts of `tiles`' plan `p`, [len(PASS_STATS)] int32 in that
+    order."""
+    counts = p["counts"]
+    return jnp.stack([jnp.sum((counts > 0).astype(jnp.int32)),
+                      jnp.sum(counts), jnp.max(counts), p["n_tiles"][0],
+                      tile_rows_of(p)]).astype(jnp.int32)
 
 
 def _ffn_kernel(layer_ref, expert_ref, n_ref, x_ref, g_ref, u_ref, d_ref,
@@ -131,11 +189,12 @@ def _ffn_kernel(layer_ref, expert_ref, n_ref, x_ref, g_ref, u_ref, d_ref,
 def grouped_ffn(x, gate_w, up_w, down_w, layer, p):
     """x: [T, H], the pass's normed tokens; gate_w, up_w:
     [layers, E, H, F], down_w: [layers, E, F, H], the held experts of
-    every layer; p: `plan`'s dict. A grid step takes at most BLOCK_F of
+    every layer; p: `tiles`' dict. A grid step takes at most BLOCK_F of
     an expert's width (F must be whole blocks). Returns y_pad
-    [G * TM, H] float32: row ``p['pos'][t, j]`` holds expert
-    ``ids[t, j]``'s output for token t (unweighted); rows of tiles past
-    the last real one hold nothing defined."""
+    [G * tm, H] float32 (tm: the plan's tile height): row
+    ``p['pos'][t, j]`` holds expert ``ids[t, j]``'s output for token t
+    (unweighted); rows of tiles past the last real one hold nothing
+    defined."""
     T, H = x.shape
     _, E, _, F = gate_w.shape
     # an expert's whole width where its three matrices fit VMEM twice over
@@ -146,9 +205,9 @@ def grouped_ffn(x, gate_w, up_w, down_w, layer, p):
     FB = F if whole else min(BLOCK_F, F)
     assert F % FB == 0, (F, FB)
     nF = F // FB
-    G = p["tile_expert"].shape[0]
+    G, tm = p["tile_expert"].shape[0], tile_rows_of(p)
     x_pad = jnp.concatenate([x, jnp.zeros((1, H), x.dtype)])[
-        p["token_of_row"]]                                    # [G * TM, H]
+        p["token_of_row"]]                                    # [G * tm, H]
 
     def tile_idx(w, f, layer, expert, n):
         return (jnp.minimum(w, jnp.maximum(n[0] - 1, 0)), 0)
@@ -167,12 +226,12 @@ def grouped_ffn(x, gate_w, up_w, down_w, layer, p):
         functools.partial(_ffn_kernel, nf=nF),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(G, nF),
-            in_specs=[pl.BlockSpec((TM, H), tile_idx),
+            in_specs=[pl.BlockSpec((tm, H), tile_idx),
                       pl.BlockSpec((1, 1, H, FB), wide_idx),
                       pl.BlockSpec((1, 1, H, FB), wide_idx),
                       pl.BlockSpec((1, 1, FB, H), down_idx)],
-            out_specs=pl.BlockSpec((TM, H), tile_idx)),
-        out_shape=jax.ShapeDtypeStruct((G * TM, H), _F32),
+            out_specs=pl.BlockSpec((tm, H), tile_idx)),
+        out_shape=jax.ShapeDtypeStruct((G * tm, H), _F32),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=min(max(2 * weights, 32 << 20), 96 << 20)),

@@ -256,10 +256,9 @@ def moe_layer(p, f, experts, layer, cfg, real=None):
     the positions that carry a token (padding is not routed: its picks
     are moved past the router's width, so it reads no expert and counts
     nowhere). Returns (y [T, H] in cfg.dtype, ids [T, k] int16 — the
-    router's picks over ALL experts —, stats [5] int32: held experts
-    touched, assignments to held experts, the largest number one held
-    expert got, tokens with a pick among the held experts, tokens
-    routed)."""
+    router's picks over ALL experts —, stats int32: `Serving.route_stats`
+    names its columns; the group-limited router's two are the tokens with
+    a pick among the held experts and the tokens routed)."""
     lo, hi = cfg.experts_held
     dt = cfg.dtype
     with jax.named_scope(SCOPES.moe_route):
@@ -269,14 +268,12 @@ def moe_layer(p, f, experts, layer, cfg, real=None):
         weights, ids = route(probs, cfg)
         if real is not None:
             ids = jnp.where(real[:, None], ids, cfg.num_experts)
-        plan = M.plan(ids, lo, hi)
-        counts = plan["counts"]
-        stats = jnp.stack([
-            jnp.sum((counts > 0).astype(jnp.int32)), jnp.sum(counts),
-            jnp.max(counts),
+        plan = M.tiles(M.plan(ids, lo, hi),
+                       M.tile_rows(*ids.shape, cfg.num_experts))
+        stats = jnp.concatenate([M.pass_stats(plan), jnp.stack([
             jnp.sum(jnp.any(plan["held"], axis=1).astype(jnp.int32)),
             (f.shape[0] if real is None
-             else jnp.sum(real.astype(jnp.int32)))]).astype(jnp.int32)
+             else jnp.sum(real.astype(jnp.int32)))]).astype(jnp.int32)])
     with jax.named_scope(SCOPES.moe_experts):
         y_pad = M.grouped_ffn(f, experts["gate_w"], experts["up_w"],
                               experts["down_w"], layer, plan)
@@ -299,6 +296,8 @@ class Serving:
 
     recurrent = False
     routed = True
+    # `moe_layer`'s stats, by column
+    route_stats = M.PASS_STATS + ("local_tokens", "tokens")
     latent = True
 
     @staticmethod

@@ -246,8 +246,7 @@ def shared_expert(p, f, cfg):
 def moe_layer(p, f, experts, layer, cfg):
     """The expert layer on the normed tokens f: [T, H]. Returns (y [T, H]
     in cfg.dtype, ids [T, k] int16 — the router's picks over ALL experts
-    —, stats [3] int32: held experts touched, assignments to held
-    experts, the largest number one held expert got)."""
+    —, stats int32: `Serving.route_stats` names its columns)."""
     lo, hi = cfg.experts_held
     with jax.named_scope(SCOPES.moe_route):
         logits = jnp.dot(f, p["router_w"].astype(cfg.dtype),
@@ -255,10 +254,9 @@ def moe_layer(p, f, experts, layer, cfg):
         probs = jax.nn.softmax(logits, axis=-1).astype(_F32)
         top, ids = jax.lax.top_k(probs, cfg.experts_per_tok)
         weights = top / jnp.sum(top, -1, keepdims=True)
-        plan = M.plan(ids, lo, hi)
-        counts = plan["counts"]
-        stats = jnp.stack([jnp.sum((counts > 0).astype(jnp.int32)),
-                           jnp.sum(counts), jnp.max(counts)])
+        plan = M.tiles(M.plan(ids, lo, hi),
+                       M.tile_rows(*ids.shape, cfg.num_experts))
+        stats = M.pass_stats(plan)
     with jax.named_scope(SCOPES.moe_experts):
         y_pad = M.grouped_ffn(f, experts["gate_w"], experts["up_w"],
                               experts["down_w"], layer, plan)
@@ -277,6 +275,7 @@ class Serving:
 
     recurrent = True
     routed = True
+    route_stats = M.PASS_STATS   # `moe_layer`'s stats, by column
     latent = False
     state_shapes = staticmethod(state_shapes)
     pattern = staticmethod(_runs)

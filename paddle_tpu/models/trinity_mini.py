@@ -229,9 +229,8 @@ def moe_layer(p, f, experts, layer, cfg, real=None):
     the positions that carry a token (padding is not routed: its picks are
     moved past the router's width, so it reads no expert and counts
     nowhere). Returns (y [T, H] float32, ids [T, k] int16 — the router's
-    picks over ALL experts —, stats [3] int32: held experts touched,
-    assignments to held experts, the largest number one held expert
-    got)."""
+    picks over ALL experts —, stats int32: `Serving.route_stats` names
+    its columns)."""
     lo, hi = cfg.experts_held
     dt = cfg.dtype
     with jax.named_scope(SCOPES.moe_route):
@@ -240,11 +239,9 @@ def moe_layer(p, f, experts, layer, cfg, real=None):
         weights, ids = route(logits, p["expert_bias"], cfg)
         if real is not None:
             ids = jnp.where(real[:, None], ids, cfg.num_experts)
-        plan = M.plan(ids, lo, hi)
-        counts = plan["counts"]
-        stats = jnp.stack([jnp.sum((counts > 0).astype(jnp.int32)),
-                           jnp.sum(counts), jnp.max(counts)]
-                          ).astype(jnp.int32)
+        plan = M.tiles(M.plan(ids, lo, hi),
+                       M.tile_rows(*ids.shape, cfg.num_experts))
+        stats = M.pass_stats(plan)
     with jax.named_scope(SCOPES.moe_experts):
         y_pad = M.grouped_ffn(f, experts["gate_w"], experts["up_w"],
                               experts["down_w"], layer, plan)
@@ -267,6 +264,7 @@ class Serving:
 
     recurrent = False
     routed = True
+    route_stats = M.PASS_STATS   # `moe_layer`'s stats, by column
     latent = False
     windowed = True
     mask_padding = True

@@ -99,9 +99,13 @@ SSM_DISPATCH_ATTRS = ("ssm_scan_rows", "ssm_update_rows", "ssm_tokens")
 # the step's fetch, so these ride the `serving_fetch` span that LANDED the
 # step (set before it closes): held experts whose weights the step read,
 # summed over its layers and passes; token-expert assignments to held
-# experts; and the largest number of assignments one held expert got in a
-# layer of a pass.
-MOE_FETCH_ATTRS = ("moe_experts_touched", "moe_assignments", "moe_load_max")
+# experts; the largest number of assignments one held expert got in a
+# layer of a pass; the real tiles the grouped expert product walked, summed
+# alike; and the rows of a tile (the tallest of the step's passes: the
+# height follows the pass, `kernels.pallas.moe.tile_rows`). Rows a tile =
+# assignments / tiles, and that over the height is the tiles' fill.
+MOE_FETCH_ATTRS = ("moe_experts_touched", "moe_assignments", "moe_load_max",
+                   "moe_tiles", "moe_tile_rows")
 # A router that keeps a token to a few GROUPS of experts (a group lies on
 # one chip) adds: tokens with at least one pick among the held experts,
 # and the tokens routed (padding is not), both summed over the step's

@@ -332,6 +332,23 @@ def _pool_copies(text, pages):
                   and n <= LAYERS * layer_set)
 
 
+def _expert_tiles(text):
+    """{(tile height, tiles of the static grid)} of the compiled step's
+    `moe_grouped_ffn` calls, one pair a distinct pass: a call's result is
+    the padded buffer f32[G tm, H], its second operand `tile_expert`
+    s32[G], G = held experts + ceil((token, pick) pairs / tm)."""
+    found = set()
+    for line in text.splitlines():
+        if "moe_grouped_ffn" in line and "custom-call(" in line:
+            rows = int(re.search(r"= f32\[(\d+),\d+\]", line).group(1))
+            grid = int(re.search(
+                r"operand_layout_constraints=\{s32\[1\]\{0\}, "
+                r"s32\[(\d+)\]", line).group(1))
+            assert rows % grid == 0, (rows, grid)
+            found.add((rows // grid, grid))
+    return found
+
+
 def _gpt_serving_params(one_chip, int8_weights=False):
     """(cfg, params as shapes on the described chip) of GPT-3 1.3B, with
     `int8_weights` as `ServingEngine(int8=True)` holds them."""
@@ -564,6 +581,10 @@ def test_qwen3_next_step_keeps_state_pool_and_experts_in_place(
         buffers += [expert, (3,) + expert]
     assert _moved(text, {_no_leading_ones(b) for b in buffers}.__contains__,
                   by_shape=True) == []
+    # a pass gives an expert 1.25-3.75 rows: tiles of 16 (192 and 64
+    # tokens x top-10 over the 256 held of 512)
+    assert _expert_tiles(text) == {(16, 256 + 120)} | (
+        {(16, 256 + 40)} if K > 1 else set())
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
     donated = (2 * math.prod(pool_shape) * 2 + math.prod(state_shape) * 4
@@ -645,6 +666,10 @@ def test_deepseek_v2_step_keeps_pool_and_experts_in_place(
                   by_shape=True) == []
     found = _weight_copies(text, (params["blocks"], params["prologue"]))
     assert set(found) == _DSV2_KNOWN[K], found
+    # a pass gives an expert 2.4-12 rows: tiles of 16 (320 and 64 tokens
+    # x top-6 over the 20 held of 160)
+    assert _expert_tiles(text) == {(16, 20 + 120)} | (
+        {(16, 20 + 24)} if K > 1 else set())
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 ** 30, mem.temp_size_in_bytes
     assert mem.alias_size_in_bytes >= sum(2 * math.prod(s) for s in shapes)
@@ -701,11 +726,14 @@ def test_trinity_step_keeps_both_lifetimes_pools_and_experts_in_place(
     under 1 GiB; all four pools come back aliased; `ragged_paged_attn`
     lowers under a window (a ring table of 20) and without one, and
     `moe_grouped_ffn` with an expert's whole width of 1,024 in one
-    block."""
+    block, in tiles of 64 rows where pass 1 gives an expert 68 (1,088
+    tokens x top-8 over 128) and of 16 where a burst pass gives it 4."""
     compiled, params, shapes = _compile_trinity_step(one_chip, K)
     text = compiled.as_text()
     for kernel in ("ragged_paged_attn", "kv_append", "moe_grouped_ffn"):
         assert kernel in text, f"{kernel} was not lowered for the chip"
+    assert _expert_tiles(text) == {(64, 128 + 136)} | (
+        {(16, 128 + 32)} if K > 1 else set())
     buffers = [s[i:] for s in shapes for i in (0, 1)]
     for expert in ((128, 2048, 1024), (128, 1024, 2048)):
         buffers += [expert, (3,) + expert]

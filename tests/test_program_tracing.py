@@ -250,6 +250,16 @@ def test_attn_pages_counts_the_row_page_pairs_of_a_hand_built_step():
     assert seen == [(12, 2, 2), (21, 2, 3), (27, 3, 3), (29, 3, 2)]
 
 
+def tiny_pattern_cfg():
+    return QN.Qwen3NextConfig(
+        vocab_size=64, hidden_size=32, num_layers=4, num_heads=4,
+        num_kv_heads=2, head_dim=16, linear_key_heads=2,
+        linear_value_heads=4, linear_key_dim=8, linear_value_dim=8,
+        num_experts=8, experts_per_tok=2, moe_ffn=16, shared_ffn=16,
+        experts_held=(0, 4), ssm_chunk=8, dtype=jnp.float32,
+        param_dtype=jnp.float32)
+
+
 def tiny_latent_cfg(num_layers):
     return DS.DeepseekV2Config(
         vocab_size=64, hidden_size=32, num_layers=num_layers, num_heads=2,
@@ -425,13 +435,7 @@ def test_the_pattern_serving_step_carries_its_scopes_and_attributes():
     scopes, the expert layer's three, attention's of the GPT step (no
     `cow`); the dispatch span carries the state attributes, and the
     fetch span the router's counts of the step landed before it."""
-    cfg = QN.Qwen3NextConfig(
-        vocab_size=64, hidden_size=32, num_layers=4, num_heads=4,
-        num_kv_heads=2, head_dim=16, linear_key_heads=2,
-        linear_value_heads=4, linear_key_dim=8, linear_value_dim=8,
-        num_experts=8, experts_per_tok=2, moe_ffn=16, shared_ffn=16,
-        experts_held=(0, 4), ssm_chunk=8, dtype=jnp.float32,
-        param_dtype=jnp.float32)
+    cfg = tiny_pattern_cfg()
     params = QN.init_params(cfg, jax.random.PRNGKey(0))
     eng = ServingEngine(params, cfg, max_batch=2, block_size=16,
                         num_blocks=16, chunk=8, decode_burst=4)
@@ -461,6 +465,7 @@ def test_the_pattern_serving_step_carries_its_scopes_and_attributes():
         eng.moe_experts_touched
     assert all(a["moe_experts_touched"] <= a["moe_assignments"]
                and a["moe_load_max"] <= a["moe_assignments"] for a in fetch)
+    assert tiles_hold_their_rows(fetch) == {16}
 
 
 def test_the_latent_serving_step_carries_its_scopes_and_attributes():
@@ -501,6 +506,7 @@ def test_the_latent_serving_step_carries_its_scopes_and_attributes():
     assert sum(a["moe_tokens"] for a in fetch) == eng.moe_tokens == \
         2 * ((20 + 3) + (8 + 2))
     assert sum(a["moe_local_tokens"] for a in fetch) == eng.moe_local_tokens
+    assert tiles_hold_their_rows(fetch) == {16}
 
 
 def test_the_windowed_serving_step_carries_its_scopes_and_attributes():
@@ -547,7 +553,86 @@ def test_the_windowed_serving_step_carries_its_scopes_and_attributes():
     assert all(tuple(a) == MOE_FETCH_ATTRS for a in fetch)
     assert sum(a["moe_experts_touched"] for a in fetch) == \
         eng.moe_experts_touched
+    assert tiles_hold_their_rows(fetch) == {16}
     assert eng.free_pages(window=True) == eng._num_wblocks - 1
+
+
+def _toy_routed_engine(model, **kw):
+    """A toy engine of the plain-routed or the group-limited model."""
+    if model == "q3n":
+        cfg = tiny_pattern_cfg()
+        params = QN.init_params(cfg, jax.random.PRNGKey(0))
+    else:
+        cfg = tiny_latent_cfg(num_layers=3)
+        params = DS.init_params(cfg, jax.random.PRNGKey(0))
+    return ServingEngine(params, cfg, max_batch=2, block_size=16,
+                         num_blocks=16, **kw)
+
+
+def tiles_hold_their_rows(fetch):
+    """What the fetch spans' tile attributes must satisfy whatever was
+    routed: a real tile holds one expert's rows, at least one and at most
+    the height, and the height is whole sublane tiles up to the MXU's
+    rows. Returns the heights."""
+    for a in fetch:
+        assert a["moe_experts_touched"] <= a["moe_tiles"] \
+            <= a["moe_assignments"] <= a["moe_tiles"] * a["moe_tile_rows"]
+        assert a["moe_tile_rows"] in range(16, 129, 16)
+    return {a["moe_tile_rows"] for a in fetch}
+
+
+def test_the_fetch_span_carries_the_tiles_walked():
+    """A routed model's `serving_fetch` span says how many real tiles the
+    grouped expert product walked in the step it landed and how tall they
+    were, so rows a tile (`moe_assignments / moe_tiles`) and the tiles'
+    fill (that over `moe_tile_rows`) can be read from any trace. The
+    height follows the pass's static shape: 32 where pass 1 packs 128
+    positions x top-2 over 8 experts (the toys of the three
+    `..._carries_its_scopes_and_attributes` tests read 16). The
+    group-limited router's two counts still come from their own columns:
+    every position is routed in each of the two expert layers."""
+    from paddle_tpu.kernels.pallas import moe
+    eng = _toy_routed_engine("dsv2", max_blocks_per_seq=12, chunk=126,
+                             decode_burst=1)
+    assert moe.tile_rows(eng.token_budget, 2, 8) == 32
+    eng.add_request(np.arange(140) % 64, max_new_tokens=3)
+    with obs.capture_spans() as cap:
+        eng.run()
+    fetch = [e.attrs for e in cap.events if e.name == SERVING_SPANS.fetch]
+    assert all(tuple(a) == MOE_FETCH_ATTRS + MOE_LOCAL_FETCH_ATTRS
+               for a in fetch)
+    assert tiles_hold_their_rows(fetch) == {32}
+    # the held group's two experts cannot fill more than their tiles
+    assert fetch[0]["moe_tiles"] <= 2 * (2 + 128 * 2 // 32)
+    assert sum(a["moe_tokens"] for a in fetch) == 2 * (140 + 2)
+    assert 0 < sum(a["moe_local_tokens"] for a in fetch) == \
+        eng.moe_local_tokens <= eng.moe_tokens
+
+
+def test_the_router_counts_are_read_by_column_name():
+    """`_note_routing` finds a count by the name the model's
+    `route_stats` gives its column, never by the vector's width: the five
+    columns of `moe.pass_stats` are no group-limited router's, and the
+    group-limited router's two come after them."""
+    plain = _toy_routed_engine("q3n", chunk=8)
+    grouped = _toy_routed_engine("dsv2", chunk=8)
+    assert grouped.model.route_stats[-2:] == ("local_tokens", "tokens")
+    # [K = 2 passes, L layers, columns]: touched, assignments, load_max,
+    # tiles, tile_rows (, local_tokens, tokens); the second pass is idle
+    row = np.asarray([3, 40, 20, 5, 16, 7, 9], np.int32)
+    for eng in (plain, grouped):
+        L, width = eng._routed_layers, len(eng.model.route_stats)
+        stats = np.zeros((2, L, width), np.int32)
+        stats[0] = row[:width]
+        attrs = eng._note_routing(stats)
+        want = dict(zip(MOE_FETCH_ATTRS, (3 * L, 40 * L, 20, 5 * L, 16)))
+        if eng is grouped:
+            want.update(zip(MOE_LOCAL_FETCH_ATTRS, (7 * L, 9 * L)))
+        assert attrs == want and eng.moe_passes == 1
+        assert (eng.moe_experts_touched, eng.moe_assignments) == (3 * L,
+                                                                  40 * L)
+    assert (grouped.moe_local_tokens, grouped.moe_tokens) == (
+        7 * grouped._routed_layers, 9 * grouped._routed_layers)
 
 
 # -- a request's life, and the scheduler's choices (ISSUE 38) ----------------
@@ -767,13 +852,7 @@ def test_the_admission_span_says_why_the_head_waits(why):
 def test_a_fetch_carries_the_router_counts_of_the_step_it_landed():
     """Two steps with different routing: each `serving_fetch` closes with
     the counts of the step IT landed, not of the one before."""
-    cfg = QN.Qwen3NextConfig(
-        vocab_size=64, hidden_size=32, num_layers=4, num_heads=4,
-        num_kv_heads=2, head_dim=16, linear_key_heads=2,
-        linear_value_heads=4, linear_key_dim=8, linear_value_dim=8,
-        num_experts=8, experts_per_tok=2, moe_ffn=16, shared_ffn=16,
-        experts_held=(0, 4), ssm_chunk=8, dtype=jnp.float32,
-        param_dtype=jnp.float32)
+    cfg = tiny_pattern_cfg()
     params = QN.init_params(cfg, jax.random.PRNGKey(0))
     eng = ServingEngine(params, cfg, max_batch=2, block_size=16,
                         num_blocks=16, chunk=8, decode_burst=1)
@@ -784,7 +863,8 @@ def test_a_fetch_carries_the_router_counts_of_the_step_it_landed():
     def spy(f):     # the step's own stats, read before the engine does
         stats = np.asarray(jax.device_get(f.out[-1]))
         landed.append((int(stats[..., 0].sum()), int(stats[..., 1].sum()),
-                       int(stats[..., 2].max())))
+                       int(stats[..., 2].max()), int(stats[..., 3].sum()),
+                       int(stats[..., 4].max())))
         return land(f)
     eng._land = spy
     with obs.capture_spans() as cap:

@@ -404,9 +404,10 @@ def test_the_conv_kernel_without_a_bias(memory, request):
     assert (np.asarray(new)[0] == tail[0]).all()
 
 
-def grouped_against_a_loop(ids, lo, hi, memory_seed=0):
-    """`plan` + `grouped_ffn` + `combine` against a loop over the held
-    experts with masks, on float32 weights of two layers."""
+def grouped_against_a_loop(ids, lo, hi, tm, memory_seed=0):
+    """`plan` + `tiles` + `grouped_ffn` + `combine` at `tm` rows against a
+    loop over the held experts with masks, on float32 weights of two
+    layers."""
     ids = np.asarray(ids, np.int32)
     T, k = ids.shape
     H, F, E = 32, 16, hi - lo
@@ -416,9 +417,11 @@ def grouped_against_a_loop(ids, lo, hi, memory_seed=0):
                 for _ in range(2))
     down = rng.normal(size=(2, E, F, H)).astype(np.float32) * 0.3
     weights = rng.uniform(0.1, 1.0, size=(T, k)).astype(np.float32)
-    p = moe.plan(jnp.asarray(ids), lo, hi)
+    p = moe.tiles(moe.plan(jnp.asarray(ids), lo, hi), tm)
+    assert moe.tile_rows_of(p) == tm
     y_pad = moe.grouped_ffn(jnp.asarray(x), jnp.asarray(gate),
                             jnp.asarray(up), jnp.asarray(down), 1, p)
+    assert y_pad.shape == (moe.n_tiles_max(T * k, E, tm) * tm, H)
     got = np.asarray(moe.combine(y_pad, jnp.asarray(weights), p))
     want = np.zeros((T, H), np.float64)
     for e in range(lo, hi):
@@ -428,37 +431,65 @@ def grouped_against_a_loop(ids, lo, hi, memory_seed=0):
     np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
     counts = np.asarray(p["counts"])
     assert (counts == [(ids == e).sum() for e in range(lo, hi)]).all()
-    tiles = -(-counts // moe.TM)
+    tiles = -(-counts // tm)
     assert int(p["n_tiles"][0]) == tiles.sum()
-    assert p["tile_expert"].shape[0] == moe.n_tiles_max(T * k, E)
+    assert p["tile_expert"].shape[0] == moe.n_tiles_max(T * k, E, tm)
+    assert (np.asarray(moe.pass_stats(p)) == [
+        (counts > 0).sum(), counts.sum(), counts.max(), tiles.sum(), tm]).all()
     return p
 
 
+@pytest.mark.parametrize("tm", [16, 64, 128])
 @pytest.mark.parametrize("memory", ["copied", "aliased"])
 @pytest.mark.parametrize("case", ["spread", "one-expert-takes-all",
-                                  "single-token", "none-held", "over-a-tile"])
-def test_the_grouped_expert_product_against_a_loop(memory, request, case):
-    """Empty groups, a single token, every token on one expert (more than
-    one tile of 16), no pick on a held expert at all; experts [4, 12) of
-    16 held, so picks below and above the share are left out."""
+                                  "single-token", "none-held", "over-a-tile",
+                                  "ends-on-a-tile"])
+def test_the_grouped_expert_product_against_a_loop(memory, request, case, tm):
+    """At every height the rule can give: empty groups, a single token,
+    every token on one expert (more than one tile at that height), a
+    group that ends exactly on a tile, no pick on a held expert at all;
+    experts [4, 12) of 16 held, so picks below and above the share are
+    left out."""
     if memory == "aliased":
         request.getfixturevalue("aliasing")
     rng = np.random.default_rng(3)
     if case == "spread":
         ids = np.stack([rng.permutation(16)[:4] for _ in range(9)])
     elif case == "one-expert-takes-all":
-        ids = np.stack([[5, 0, 1, 15]] * 40)
+        ids = np.stack([[5, 0, 1, 15]] * (2 * tm + 8))
     elif case == "single-token":
         ids = np.asarray([[11, 4, 2, 14]])
     elif case == "none-held":
         ids = np.stack([[0, 1, 2, 13]] * 5)
+    elif case == "ends-on-a-tile":     # expert 6: one whole tile; 9: two
+        ids = np.stack([[6 if t < tm else 2, 9, 13, 3]
+                        for t in range(2 * tm)])
     else:
-        ids = np.stack([[4 + (t % 2), 12, 13, 3] for t in range(37)])
-    p = grouped_against_a_loop(ids, 4, 12)
+        ids = np.stack([[4 + (t % 2), 12, 13, 3]
+                        for t in range(2 * tm + 5)])
+    p = grouped_against_a_loop(ids, 4, 12, tm)
     if case == "none-held":
         assert int(p["n_tiles"][0]) == 0
     if case == "one-expert-takes-all":
-        assert int(p["n_tiles"][0]) == 3 and int(p["counts"][1]) == 40
+        assert int(p["n_tiles"][0]) == 3 \
+            and int(p["counts"][1]) == 2 * tm + 8
+    if case == "ends-on-a-tile":
+        assert int(p["n_tiles"][0]) == 3 and \
+            (np.asarray(p["counts"])[[2, 5]] == [tm, 2 * tm]).all()
+
+
+@pytest.mark.parametrize("shape, tm", [
+    ((1088, 8, 128), 64),       # Trinity-Mini, pass 1: 68 rows an expert
+    ((64, 8, 128), 16),         # Trinity-Mini, a burst pass: 4
+    ((64, 10, 512), 16), ((192, 10, 512), 16),    # Qwen3-Next: 1.25, 3.75
+    ((64, 6, 160), 16), ((320, 6, 160), 16),      # DeepSeek-V2: 2.4, 12
+    ((4096, 8, 128), 128), ((512, 8, 128), 32),   # the ends of the rule
+], ids=lambda v: "x".join(map(str, v)) if isinstance(v, tuple) else str(v))
+def test_the_tile_height_follows_the_pass(shape, tm):
+    """`tile_rows` at the shapes the cells run (tokens, picks, the
+    router's experts): whole sublane tiles of what an expert can expect,
+    16 where that is less, never above the MXU's 128 rows."""
+    assert moe.tile_rows(*shape) == tm
 
 
 # -- the models that were there ---------------------------------------------------
