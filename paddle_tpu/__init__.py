@@ -13,7 +13,11 @@ Brand-new framework with the capabilities of the PaddlePaddle reference
 The public API mirrors the reference's `paddle.*` surface so users can port.
 """
 
-from . import utils  # noqa: F401
+import time as _time
+
+_IMPORT_T0 = _time.perf_counter()   # `startup_import` opens (see the end)
+
+from . import utils  # noqa: F401,E402
 from . import dtypes  # noqa: F401
 from .dtypes import *  # noqa: F401,F403
 from . import flags as _flags_mod  # noqa: F401
@@ -114,3 +118,10 @@ def load(path, **kwargs):
 def summary(net, input_size=None, dtypes=None):
     from .hapi.summary import summary as _summary
     return _summary(net, input_size, dtypes)
+
+
+# `startup_import` closes: this file's first line to here, on the clock of
+# every span (observability/startup.py keeps the record)
+from .observability import startup as _startup  # noqa: E402
+
+_startup.note_import(_time.perf_counter())

@@ -159,13 +159,13 @@ from jax import lax
 from ..kernels.pallas.kv_append import append_tile
 from ..kernels.pallas.mla_attention import shared_pages
 from ..models import gpt as G
-from ..observability.trace import (ADMISSION_ATTRS, ADMIT_BLOCKED,
-                                   DISPATCH_ATTRS, FIRST_TOKEN_ATTRS,
-                                   LATENT_DISPATCH_ATTRS, MOE_FETCH_ATTRS,
-                                   MOE_LOCAL_FETCH_ATTRS, REQUEST_END_ATTRS,
-                                   REQUEST_PHASES, REQUEST_SPANS, SCOPES,
-                                   SERVING_SPANS, SSM_DISPATCH_ATTRS,
-                                   WINDOW_DISPATCH_ATTRS)
+from ..observability import startup as _startup
+from ..observability.trace import (
+    ADMISSION_ATTRS, ADMIT_BLOCKED, DISPATCH_ATTRS, FIRST_CALL_ATTRS,
+    FIRST_TOKEN_ATTRS, LATENT_DISPATCH_ATTRS, MOE_FETCH_ATTRS,
+    MOE_LOCAL_FETCH_ATTRS, REQUEST_END_ATTRS, REQUEST_PHASES, REQUEST_SPANS,
+    SCOPES, SERVING_SPANS, SSM_DISPATCH_ATTRS, STARTUP_SPANS,
+    WINDOW_DISPATCH_ATTRS)
 from ..profiler.utils import RecordEvent, record_interval
 
 __all__ = ["Request", "ServingEngine", "RunResult", "NonFiniteSampleError",
@@ -539,7 +539,7 @@ def _settled_view(name):
 
 class ServingEngine:
     """Continuous-batching engine over a paged KV pool (see module doc)."""
-
+    @RecordEvent(STARTUP_SPANS.engine, "Startup")   # see PERF.md, PR 56
     def __init__(self, params, cfg: G.GPTConfig, *, max_batch: int = 4,
                  block_size: int = None, num_blocks: int = 256,
                  max_blocks_per_seq: int = 32, chunk: int = None,
@@ -2145,9 +2145,12 @@ class ServingEngine:
                 self.engine_steps, b.K, len(b.dec), len(b.pre), b.q_tokens,
                 b.kv_tokens, b.attn_pages, b.kv_tiles, int(prev is not None),
                 len(b.pre) - len(b.grants), sum(b.grants.values()),
-                self.token_budget))), **b.model_attrs):
+                self.token_budget))), **b.model_attrs), self._first_call(b):
             _faults().maybe_fail("serving/dispatch")
             out = self._unified(b.K, spec=b.use_spec)(*args)
+        if _startup.RECOMPILES and _startup.take_recompiles():
+            # a variant that had run before was compiled again
+            self._note_compiled(b.K, b.use_spec, now, recompile=1)
         route = ()
         if self.model.routed:   # ids0, ids_burst, stats: fetched with toks
             *out, ids0, ids_burst, stats = out
@@ -2170,6 +2173,38 @@ class ServingEngine:
             self.settle("tail")
         self._step_metrics(t_step0, tokens_before, len(b.pre), len(b.dec))
         return finished
+
+    def _first_call(self, b):
+        """Around a dispatch: the `startup_program_first_call` span where
+        the step's variant has never been built (its first call traces,
+        lowers and compiles or loads it), else nothing."""
+        if (b.K, b.use_spec) in self._unified_cache:
+            return _startup.NO_SPAN
+        return _startup.FirstCall(self._note_compiled, b.K, b.use_spec)
+
+    def _note_compiled(self, k, spec, since, recompile=0):
+        """The operator's view of the compile events this thread ended
+        since `since`: the two prom series and one `program_compiled`
+        line. Written where a program was built, never by a step that
+        built none."""
+        done = _startup.compiled_since(since)
+        seconds = 0.0
+        for stage in ("trace", "lower", "backend"):
+            seconds += done[stage + "_us"] / 1e6
+            self._prom.counter_inc(
+                "compile_seconds_total", done[stage + "_us"] / 1e6,
+                labels={"stage": stage},
+                help="seconds the serving step's programs took to trace, "
+                     "lower, and compile or load from the persistent cache")
+        for result, n in done["results"].items():
+            self._prom.counter_inc(
+                "compile_cache_total", n, labels={"result": result},
+                help="backend compilations of the serving step's programs "
+                     "by what the persistent cache did")
+        self._emit_event("program_compiled", fun=done["fun"], k=k,
+                         spec=int(spec), seconds=round(seconds, 6),
+                         cache=done["cache"], recompile=recompile)
+        return done
 
     def _land(self, f) -> List[Request]:
         """Fetch a dispatched step's tokens and walk them; returns the

@@ -20,6 +20,10 @@ makes the split measurable without leaving the compiled program:
   resumes/skips/commits/SIGTERM through it) + ``merge_event_streams``
   for one role-tagged timeline over trainer + serving logs.
 * :mod:`.trace` — chrome-trace spans unified with ``paddle_tpu.profiler``.
+* :mod:`.startup` — the program's record of its own start-up: jax's
+  traces, lowerings, compilations and cache loads by name, and the three
+  spans of the program's own start-up work, kept without a profiler
+  session; ``startup_record()`` reads it.
 * :mod:`.prom` — Prometheus text-format scrape surface (counters,
   gauges, summaries, bucketed histograms, recent-window p50/p95
   quantiles) for the serving engine and the fleet view.
@@ -66,6 +70,8 @@ from .profile_reader import (MeasuredRates, ProfileWindow,
                              measure_collective_rates, measure_compute_rate,
                              save_profile_json)
 from .prom import MetricsServer, PromRegistry, serve_registry
+from . import startup
+from .startup import startup_record
 from .step_timer import StepTimer
 from .trace import capture_spans, span, write_chrome_trace
 
@@ -84,6 +90,7 @@ __all__ = [
     "merge_event_streams",
     "PromRegistry", "MetricsServer", "serve_registry",
     "span", "capture_spans", "write_chrome_trace",
+    "startup", "startup_record",
     "hlo_census", "capture_step_profile", "derive_hardware_profile",
     "save_profile_json", "load_profile_json", "measure_compute_rate",
     "measure_collective_rates", "MeasuredRates", "ProfileWindow",

@@ -5,6 +5,8 @@ has its own: ``chipbench/runners/_train.py``):
 
 * the FIRST completed step is recorded as ``compile_s`` (jit trace +
   XLA compile + the step itself), every later step as steady state;
+  ``report()`` puts the process's own figures beside it
+  (``compile_breakdown``, from :func:`.startup.startup_record`);
 * named phases (``with timer.phase("data"): ...``) attribute wall time
   inside or around the step — a per-phase ms breakdown of the host's
   time;
@@ -100,9 +102,15 @@ class StepTimer:
         return _mfu(tps, self.flops_per_token, self.peak)
 
     def report(self) -> Dict[str, Any]:
+        from .startup import BREAKDOWN_KEYS, startup_record
+        record = startup_record()
         out: Dict[str, Any] = {
             "compile_s": (round(self.compile_s, 3)
                           if self.compile_s is not None else None),
+            # the process's traces, lowerings, cache loads and compilations
+            # so far: the inside of the first step's outside timing
+            "compile_breakdown": {k: round(record[k], 3)
+                                  for k in BREAKDOWN_KEYS},
             "steady_steps": self.steady.count,
             "step_ms": {
                 "avg": round(self.steady.avg * 1e3, 3),

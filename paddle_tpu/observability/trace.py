@@ -24,6 +24,12 @@ trace of one commit can be held against a trace of the next:
   ``serving_walk``, with ``FIRST_TOKEN_ATTRS`` and ``REQUEST_END_ATTRS``;
   ``REQUEST_PHASES`` — the four back-dated collector events that tile a
   request's way from submission to its first token;
+* ``STARTUP_SPANS`` — the three live spans of the program's own start-up
+  work (``event_type="Startup"``), and ``FIRST_CALL_ATTRS``, what a
+  ``startup_program_first_call`` carries; ``COMPILE_SPANS`` — the three
+  back-dated events jax's own compile events become
+  (``event_type="Compile"``), with ``COMPILE_ATTRS`` and ``COMPILE_CACHE``,
+  the values of their ``cache`` (:mod:`.startup` makes and reads them);
 * ``SCOPES`` — the ``jax.named_scope``s inside the compiled programs (the
   serving step, the dense train step, the hybrid train step). A device
   operation's ``op_name`` path carries them; an operation under none is
@@ -51,7 +57,8 @@ __all__ = ["span", "capture_spans", "write_chrome_trace", "SERVING_SPANS",
            "MOE_LOCAL_FETCH_ATTRS",
            "ADMISSION_ATTRS", "ADMIT_BLOCKED", "REQUEST_SPANS",
            "REQUEST_PHASES", "FIRST_TOKEN_ATTRS", "REQUEST_END_ATTRS",
-           "SCOPES", "KERNELS"]
+           "STARTUP_SPANS", "FIRST_CALL_ATTRS", "COMPILE_SPANS",
+           "COMPILE_ATTRS", "COMPILE_CACHE", "SCOPES", "KERNELS"]
 
 span = RecordEvent
 
@@ -173,6 +180,42 @@ FIRST_TOKEN_ATTRS = ("rid", "prompt_len", "queue_us", "wait_us", "prefill_us",
                      "land_steps", "preemptions")
 REQUEST_END_ATTRS = ("rid", "status", "out_tokens", "decode_steps",
                      "total_us", "preemptions")
+
+# What happens before the first useful step. Three live spans where the
+# program itself does start-up work; `observability.startup` keeps them,
+# and the compile events below, in a ring of their own whether or not a
+# profiler session is on.
+STARTUP_SPANS = _names(
+    "StartupSpans",
+    import_="startup_import",       # first to last line of paddle_tpu/__init__
+    engine="startup_engine_build",  # ServingEngine.__init__
+    program="startup_program_first_call")   # a _unified(K, spec)'s first call
+# The first call's burst size and spec-verify flag and, at its close, the
+# compile events that ended inside it: their seconds by stage in whole
+# microseconds, and the `fun` and `cache` of the longest backend compile
+# (the program's own).
+FIRST_CALL_ATTRS = ("k", "spec", "trace_us", "lower_us", "backend_us", "fun",
+                    "cache")
+# jax's three compile durations, one back-dated event each (start = end -
+# duration): a function traced to a jaxpr, a jaxpr lowered to a module, a
+# module compiled by the backend or loaded from the persistent cache.
+COMPILE_SPANS = _names(
+    "CompileSpans",
+    trace="compile_trace", lower="compile_lower", backend="compile_backend")
+# `fun`: jax's name of the function; on `compile_backend`, `cache` (one of
+# COMPILE_CACHE) and, on a hit, jax's two figures in whole microseconds:
+# the read's time and the compile time the entry saved; `recompile`: 1 on
+# a backend compile that fired inside the dispatch of a variant that had
+# run before; `nested`: 1 on an event with another stage open around it on
+# its thread (a kernel's body traced inside a lowering), whose seconds are
+# that stage's.
+COMPILE_ATTRS = ("fun", "cache", "retrieval_us", "saved_us", "recompile",
+                 "nested")
+COMPILE_CACHE = _names(
+    "CompileCache",
+    hit="hit",              # the persistent cache held the program
+    compiled="compiled",    # the cache was asked and had it not
+    off="off")              # the cache was never asked for this program
 
 SCOPES = _names(
     "Scopes",

@@ -15,6 +15,7 @@ flag check.
 
 from __future__ import annotations
 
+import collections
 import itertools
 import os
 import threading
@@ -27,7 +28,12 @@ from jax.profiler import TraceAnnotation
 from ..flags import flag as _flag
 
 __all__ = ["RecordEvent", "HostEvent", "EventCollector", "collector", "Stat",
-           "active_spans", "record_interval"]
+           "active_spans", "open_span_names", "record_interval",
+           "KEPT_TYPES"]
+
+# Event types the collector keeps whether or not a session is on: the
+# program's start-up and jax's compilations (observability/startup.py).
+KEPT_TYPES = ("Compile", "Startup")
 
 
 class Stat:
@@ -77,14 +83,27 @@ class HostEvent:
 
 
 class EventCollector:
-    """Process-global host event sink; enabled by an active Profiler."""
+    """Process-global host event sink; enabled by an active Profiler.
+    Events of a type in KEPT_TYPES are also kept in a ring of their own,
+    session or none: the newest `keep` of them, `kept_total` counted."""
 
-    def __init__(self):
+    def __init__(self, keep: int = 4096):
         self._events: List[HostEvent] = []
         self._lock = threading.Lock()
         self.enabled = False
+        self._kept: collections.deque = collections.deque(maxlen=keep)
+        self.kept_total = 0
+
+    def kept(self) -> List[HostEvent]:
+        """The ring, oldest first."""
+        with self._lock:
+            return list(self._kept)
 
     def add(self, ev: HostEvent):
+        if ev.event_type in KEPT_TYPES:
+            with self._lock:
+                self._kept.append(ev)
+                self.kept_total += 1
         if not self.enabled:
             if _flag("enable_host_event_recorder_hook"):
                 with self._lock:
@@ -137,14 +156,24 @@ def active_spans():
     return out
 
 
-def record_interval(name: str, start: float, end: float, **attrs):
+def open_span_names() -> List[str]:
+    """Names of the spans open on the calling thread."""
+    tid = threading.get_ident()
+    with _OPEN_LOCK:
+        return [s[0] for s in _OPEN_SPANS.values() if s[2] == tid]
+
+
+def record_interval(name: str, start: float, end: float,
+                    event_type: str = "UserDefined", **attrs) -> HostEvent:
     """A span whose start and end (perf_counter seconds) the caller kept
     itself and which has already ended: a phase of a request's life, known
     only when the next one begins. It goes to the collector with no
     parent; a TraceAnnotation cannot be back-dated, so a profiler session
     does not see it."""
-    collector.add(HostEvent(name, start, end, threading.get_ident(),
-                            span_id=next(_SPAN_IDS), attrs=attrs))
+    ev = HostEvent(name, start, end, threading.get_ident(), event_type,
+                   span_id=next(_SPAN_IDS), attrs=attrs)
+    collector.add(ev)
+    return ev
 
 
 class RecordEvent:

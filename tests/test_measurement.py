@@ -579,6 +579,18 @@ def test_serving_steps_land_on_span_timeline(tmp_path):
     trace = json.load(open(path))
     assert any(ev["name"] == "serving_step"
                for ev in trace["traceEvents"])
+    # the engine's first steps show their start-up work: a first-call bar
+    # with its burst size around the three compile bars, each named
+    first = [ev for ev in trace["traceEvents"]
+             if ev["name"] == "startup_program_first_call"]
+    assert first and all(ev["cat"] == "Startup" and ev["args"]["k"] >= 1
+                         for ev in first)
+    inside = [ev for ev in trace["traceEvents"] if ev["cat"] == "Compile"
+              and first[0]["ts"] <= ev["ts"]
+              and ev["ts"] + ev["dur"] <= first[0]["ts"] + first[0]["dur"]]
+    assert {ev["name"] for ev in inside} == {
+        "compile_trace", "compile_lower", "compile_backend"}
+    assert all(ev["args"]["fun"] for ev in inside)
 
 
 # ---------------------------------------------------------------------------

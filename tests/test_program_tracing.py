@@ -38,7 +38,9 @@ from paddle_tpu.models import falcon_h1 as FH  # noqa: E402
 from paddle_tpu.models import qwen3_next as QN  # noqa: E402
 from paddle_tpu.models import deepseek_v2 as DS  # noqa: E402
 from paddle_tpu.observability.trace import (ADMISSION_ATTRS,  # noqa: E402
-                                            ADMIT_BLOCKED, DISPATCH_ATTRS,
+                                            ADMIT_BLOCKED, COMPILE_CACHE,
+                                            COMPILE_SPANS, DISPATCH_ATTRS,
+                                            FIRST_CALL_ATTRS,
                                             FIRST_TOKEN_ATTRS, KERNELS,
                                             LATENT_DISPATCH_ATTRS,
                                             MOE_FETCH_ATTRS,
@@ -47,6 +49,7 @@ from paddle_tpu.observability.trace import (ADMISSION_ATTRS,  # noqa: E402
                                             REQUEST_PHASES, REQUEST_SPANS,
                                             SCOPES, SERVING_SPANS,
                                             SSM_DISPATCH_ATTRS,
+                                            STARTUP_SPANS,
                                             WINDOW_DISPATCH_ATTRS)
 from paddle_tpu.profiler.utils import RecordEvent, collector  # noqa: E402
 
@@ -173,7 +176,12 @@ def test_one_ragged_step_yields_every_serving_span_once():
     k = eng.decode_microsteps - micro0
     with obs.capture_spans() as cap:
         eng.step()      # dispatches the next step, THEN lands that one
-    assert [e.name for e in first.events] == [
+    # (a step that builds a variant also hands the session that start-up
+    # work: the compile events and the first-call span)
+    assert {e.event_type for e in first.events} <= {"UserDefined", "Compile",
+                                                    "Startup"}
+    assert [e.name for e in first.events
+            if e.event_type == "UserDefined"] == [
         SERVING_SPANS.sweep, SERVING_SPANS.admission, SERVING_SPANS.pack,
         SERVING_SPANS.upload, SERVING_SPANS.dispatch, SERVING_SPANS.metrics,
         SERVING_SPANS.step]
@@ -652,7 +660,8 @@ def test_a_span_takes_attributes_until_it_closes(tmp_path):
     finally:
         jax.profiler.stop_trace()
     assert ev.attrs == {"step": 3, "landed": 8, "why": "pages"}
-    assert [e.attrs for e in cap.events] == [ev.attrs]
+    assert [e.attrs for e in cap.events
+            if e.event_type != "Compile"] == [ev.attrs]
     files = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
                           / "*.xplane.pb"))
     host = {h[0]: h for h in program_trace.from_xplane(files[0])["host"]}
@@ -663,18 +672,36 @@ def test_a_span_takes_attributes_until_it_closes(tmp_path):
 
 
 @pytest.fixture(scope="module")
-def mixed_run():
+def mixed_engine(tmp_path_factory):
     """Four requests through three slots, nine pages of 8 and a budget of
     ONE 8-token chunk a step, preemption on: `victim` decodes long and is
     evicted for the queue's head; `starved` is resident from the start but
     waits for budget behind the victim's chunk; `queued` arrives to a pool
     with too few pages; `quick` (4 tokens in, 1 out) is done a step pair
-    after its chunk. Returns (collector events, {name: Request})."""
+    after its chunk. Returns (collector events, {name: Request}, the
+    engine, the path of the event log it wrote to)."""
     cfg = tiny_cfg()
     params = G.init_hybrid_params(cfg, jax.random.PRNGKey(0))
-    eng = ServingEngine(params, cfg, max_batch=3, block_size=8,
-                        num_blocks=10, chunk=8, token_budget=8,
-                        decode_burst=2, preempt=True, preempt_wait_steps=1)
+    log = obs.EventLog(str(tmp_path_factory.mktemp("mixed") / "ev.jsonl"))
+    before = obs.set_event_log(log)
+    try:
+        with obs.capture_spans() as build:
+            eng = ServingEngine(
+                params, cfg, max_batch=3, block_size=8, num_blocks=10,
+                chunk=8, token_budget=8, decode_burst=2, preempt=True,
+                preempt_wait_steps=1)
+        events, done = _mixed_arrivals(eng)
+    finally:
+        obs.set_event_log(before)
+    return build.events + events, done, eng, log.path
+
+
+@pytest.fixture(scope="module")
+def mixed_run(mixed_engine):
+    return mixed_engine[:2]
+
+
+def _mixed_arrivals(eng):
     names, done = {}, {}
 
     def add(name, n_prompt, n_new):
@@ -762,6 +789,101 @@ def test_the_request_phases_share_rid_and_tile_the_way_to_the_token(
     assert bars[-1].end == r.first_token_time
     assert all(a.end == b.start for a, b in zip(bars, bars[1:]))
     assert [e.duration for e in bars] == list(r.ttft_parts())
+
+
+def _by_id(events):
+    return {e.span_id: e for e in events}
+
+
+def test_the_first_dispatch_of_a_variant_is_one_first_call_span(mixed_engine):
+    """The engine's construction is one `startup_engine_build`; every
+    variant the scheduler chose opens ONE `startup_program_first_call`,
+    inside the dispatch span of its first step, with its burst size and
+    the trace, lowering and backend compile that ended inside it; no
+    later dispatch opens one."""
+    events, _, eng, _ = mixed_engine
+    assert [e.event_type for e in events
+            if e.name == STARTUP_SPANS.engine] == ["Startup"]
+    first = [e for e in events if e.name == STARTUP_SPANS.program]
+    assert sorted((e.attrs["k"], bool(e.attrs["spec"])) for e in first) == \
+        sorted(eng._unified_cache)
+    dispatches = [e for e in events if e.name == SERVING_SPANS.dispatch]
+    assert len(dispatches) > len(first) >= 2
+    by_id = _by_id(events)
+    for e in first:
+        assert e.event_type == "Startup" and set(e.attrs) == set(
+            FIRST_CALL_ATTRS)
+        parent = by_id[e.parent]
+        assert parent.name == SERVING_SPANS.dispatch
+        assert parent.attrs["k"] == e.attrs["k"]
+        assert min(e.attrs[a] for a in ("trace_us", "lower_us",
+                                        "backend_us")) > 0
+        assert sum(e.attrs[a] for a in ("trace_us", "lower_us",
+                                        "backend_us")) <= e.duration * 1e6
+        assert e.attrs["fun"].startswith("jit(")
+        assert e.attrs["cache"] in COMPILE_CACHE
+        inside = [c for c in events if c.event_type == "Compile"
+                  and e.start <= c.end <= e.end and c.tid == e.tid]
+        assert {c.name for c in inside} == set(COMPILE_SPANS)
+    assert not [c for c in events if c.attrs.get("recompile")]
+
+
+def test_the_operator_sees_what_each_first_call_cost(mixed_engine):
+    """Two prom series and one JSONL line a first call, from the span's
+    own sums."""
+    events, _, eng, log_path = mixed_engine
+    first = [e for e in events if e.name == STARTUP_SPANS.program]
+    text = eng._prom.render()
+    for stage in ("trace", "lower", "backend"):
+        line, = [ln for ln in text.splitlines() if ln.startswith(
+            f'paddle_tpu_serving_compile_seconds_total{{stage="{stage}"}}')]
+        assert float(line.split()[-1]) == pytest.approx(
+            sum(e.attrs[stage + "_us"] for e in first) / 1e6)
+    counted = [ln for ln in text.splitlines() if ln.startswith(
+        "paddle_tpu_serving_compile_cache_total{")]
+    assert sum(float(ln.split()[-1]) for ln in counted) >= len(first)
+    assert all(ln.split('result="')[1].split('"')[0] in COMPILE_CACHE
+               for ln in counted)
+    with open(log_path) as f:
+        lines = [ev for ev in map(json.loads, f)
+                 if ev["event"] == "program_compiled"]
+    assert [(ev["k"], ev["spec"], ev["cache"], ev["recompile"], ev["fun"])
+            for ev in lines] == [
+        (e.attrs["k"], e.attrs["spec"], e.attrs["cache"], 0, e.attrs["fun"])
+        for e in first]
+    assert all(ev["role"] == "serving" and ev["seconds"] > 0
+               for ev in lines)
+
+
+def test_a_known_variant_compiled_again_is_a_recompilation(mixed_engine,
+                                                           tmp_path):
+    """A variant that has run loses its compiled program (here: its
+    function's own cache is cleared; in a deployment: a new input shape or
+    dtype): the dispatch that compiles it again opens no first-call span,
+    the backend event carries `recompile=1`, and the operator gets the
+    series and a `program_compiled` line that says so."""
+    _, _, eng, _ = mixed_engine
+    log = obs.EventLog(str(tmp_path / "ev.jsonl"))
+    before = obs.set_event_log(log)
+    try:
+        for fn in eng._unified_cache.values():
+            fn.clear_cache()
+        eng.add_request(np.arange(4) % 64, max_new_tokens=1)
+        with obs.capture_spans() as cap:
+            while eng.has_work():
+                eng.step()
+    finally:
+        obs.set_event_log(before)
+    assert not [e for e in cap.events if e.name == STARTUP_SPANS.program]
+    again = [e for e in cap.events if e.attrs.get("recompile")]
+    assert again and {e.name for e in again} == {COMPILE_SPANS.backend}
+    assert not obs.startup.RECOMPILES     # the engine took what was its own
+    with open(log.path) as f:
+        lines = [ev for ev in map(json.loads, f)
+                 if ev["event"] == "program_compiled"]
+    assert len(lines) == len(again)
+    assert all(ev["recompile"] == 1 and ev["seconds"] > 0 for ev in lines)
+    assert obs.startup_record()["recompiles"] >= len(again)
 
 
 def test_nothing_is_recorded_of_a_request_while_the_collector_is_off():
@@ -913,9 +1035,18 @@ def test_no_scope_or_serving_span_is_a_free_string():
              and isinstance(n.func, ast.Name) and n.func.id == "RecordEvent"]
     assert len(spans) >= len(SERVING_SPANS) + len(REQUEST_SPANS)
     for call in spans:
-        assert _from_tuple(call.args[0], "SERVING_SPANS",
-                           SERVING_SPANS._fields) or _from_tuple(
-            call.args[0], "REQUEST_SPANS", REQUEST_SPANS._fields), call.lineno
+        assert any(_from_tuple(call.args[0], name, tup._fields)
+                   for name, tup in (("SERVING_SPANS", SERVING_SPANS),
+                                     ("REQUEST_SPANS", REQUEST_SPANS),
+                                     ("STARTUP_SPANS", STARTUP_SPANS))), \
+            call.lineno
+    # the start-up record's names: its module takes them from the tuples too
+    startup = ast.parse(open(os.path.join(
+        REPO, "paddle_tpu", "observability", "startup.py")).read())
+    free = [n.value for n in ast.walk(startup) if isinstance(n, ast.Constant)
+            and isinstance(n.value, str)
+            and n.value in set(STARTUP_SPANS) | set(COMPILE_SPANS)]
+    assert free == []
 
 
 # -- the benchmark's readers ------------------------------------------------
@@ -1044,6 +1175,17 @@ def test_metric_files_name_only_what_the_program_names(name):
     assert attrs <= set(SPAN_ATTRS.get(params.get("span"), ()))
     if params.get("num") == "blocked":
         assert params["num_equals"] in tuple(ADMIT_BLOCKED)
+
+
+@pytest.mark.parametrize("name", [m["name"] for m in SPEC["per_layer"]
+                                  if _meta(m["name"])["reader"]
+                                  == "startup_record"])
+def test_metric_files_name_only_keys_of_the_startup_record(name):
+    """As the scope and span names are held: a key the record drops or
+    renames fails here instead of reading nothing there."""
+    record = obs.startup_record()
+    assert isinstance(record[_meta(name)["params"]["key"]], float)
+    assert set(_meta(name)["params"]) == {"key"}
 
 
 @pytest.mark.parametrize("entry,cell,slice_name", CASES, ids=_id)
