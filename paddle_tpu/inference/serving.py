@@ -161,10 +161,10 @@ from ..kernels.pallas.mla_attention import shared_pages
 from ..models import gpt as G
 from ..observability import startup as _startup
 from ..observability.trace import (
-    ADMISSION_ATTRS, ADMIT_BLOCKED, DISPATCH_ATTRS, FIRST_CALL_ATTRS,
-    FIRST_TOKEN_ATTRS, LATENT_DISPATCH_ATTRS, MOE_FETCH_ATTRS,
-    MOE_LOCAL_FETCH_ATTRS, REQUEST_END_ATTRS, REQUEST_PHASES, REQUEST_SPANS,
-    SCOPES, SERVING_SPANS, SSM_DISPATCH_ATTRS, STARTUP_SPANS,
+    ADMISSION_ATTRS, ADMIT_BLOCKED, CHUNK_DISPATCH_ATTRS, DISPATCH_ATTRS,
+    FIRST_CALL_ATTRS, FIRST_TOKEN_ATTRS, LATENT_DISPATCH_ATTRS,
+    MOE_FETCH_ATTRS, MOE_LOCAL_FETCH_ATTRS, REQUEST_END_ATTRS, REQUEST_PHASES,
+    REQUEST_SPANS, SCOPES, SERVING_SPANS, SSM_DISPATCH_ATTRS, STARTUP_SPANS,
     WINDOW_DISPATCH_ATTRS)
 from ..profiler.utils import RecordEvent, record_interval
 
@@ -2470,6 +2470,10 @@ class ServingEngine:
             model_attrs = dict(zip(LATENT_DISPATCH_ATTRS, (
                 sum(self._slots[i].prefix_hit_tokens for i in fresh_slots),
                 int((q * p + q * (q + 1) // 2 - (p + q)).sum()), shared)))
+        if not self._latent:    # its attention is another kernel
+            chunk = self._chunk_pages(q_lens, kv_end, emit, K)
+            model_attrs = {**dict(zip(CHUNK_DISPATCH_ATTRS, chunk.tolist())),
+                           **model_attrs}
         return _PackedStep(
             dec=dec, pre=pre, ending=ending, grants=grants,
             props_by_slot=props_by_slot,
@@ -2483,6 +2487,35 @@ class ServingEngine:
             arrays=(tokens, row_of, off_of, starts, pos0, q_lens,
                     self.tables.copy(), fresh, sample0, remaining, eos_ids,
                     temps))
+
+    @functools.cached_property
+    def _wide_arm_pages(self):
+        """`wide_arm_pages` at this engine's geometry: what one call of the
+        attention kernel walks on its wide arm, and masks there."""
+        from ..kernels.pallas.ragged_paged_attention import wide_arm_pages
+        _, hkv, _, bs, D = self._k_pools.shape
+        tp = self._mesh.shape[self._mp_axis] if self._mesh else 1
+        return functools.partial(
+            wide_arm_pages, hq=self.cfg.num_heads // tp, hkv=hkv // tp,
+            bs=bs, D=D, itemsize=self._k_pools.dtype.itemsize)
+
+    def _chunk_pages(self, q_lens, kv_end, emit, K):
+        """[pages, masked pages] of the step's wide-arm rows, over the K
+        passes (a burst pass's rows hold one token: the narrow arm's
+        unless a query group is wider than a tile) and over the layers, a
+        window layer counted under its window."""
+        def layer(window=None):
+            count = functools.partial(self._wide_arm_pages, window=window)
+            ran = q_lens > 0
+            return np.sum(
+                [count(q_lens[ran], kv_end[ran], c_att=self._c_att)]
+                + [count(np.ones(int((emit > j).sum()), np.int64),
+                         kv_end[emit > j] + j, c_att=1)
+                   for j in range(1, K)], axis=0)
+        total = self.model.kv_layers(self.cfg) * layer()
+        if self._windowed:
+            total += self.model.window_layers(self.cfg) * layer(self._window)
+        return total
 
     @RecordEvent(SERVING_SPANS.upload)
     def _upload_ragged(self, b):

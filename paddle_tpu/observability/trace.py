@@ -141,6 +141,16 @@ LATENT_DISPATCH_ATTRS = ("prefix_hit_tokens", "chunk_ctx_tokens",
 # the rows' windows.
 WINDOW_DISPATCH_ATTRS = ("kv_layer_tokens", "win_attn_pages",
                          "win_pages_freed")
+# A model whose attention is `ragged_paged_attn` adds what the kernel's WIDE
+# arm walks (`kernels.pallas.ragged_paged_attention.wide_arm_pages`, the
+# kernel's own rules on the host): `chunk_pages`, the (row, page) pairs of
+# the rows whose folded queries pass one sublane tile (the prefill chunks),
+# summed over the passes and over the LAYERS as `kv_layer_tokens` is, a
+# window layer counting the pages its window lets the row read; and
+# `chunk_masked_pages`, those of them in a block of pages that takes the
+# masked soft-max update (an edge crosses it). Their ratio is how often the
+# unmasked update runs.
+CHUNK_DISPATCH_ATTRS = ("chunk_pages", "chunk_masked_pages")
 # What `serving_admission` closes with: requests admitted by this call,
 # the queue's depth after it, why the queue's head still waits (one of
 # ADMIT_BLOCKED), and decode victims this call evicted for it.

@@ -175,6 +175,14 @@ _CELL_ATTENTION = {
     "gpt-pass1-mp4": (64, 192, 128, 4, (24, 4, 256), 16),
     "falconh1-pass1": (64, 192, 128, 20, (6, 4, 640), 8),
     "falconh1-burst": (64, 64, 1, 20, (6, 4, 640), 8),
+    # Trinity-Mini's pass 1 (PR 57): 32 query heads on 4 KV heads fold a
+    # chunk of 256 into 2,048 rows a KV head, attended a block of pages an
+    # update; the full layer's pool and table, then a window layer's ring
+    "trinity-pass1-full": (64, 1088, 256, 32, (1, 4, 4251), 262),
+    "trinity-pass1-window": (64, 1088, 256, 32, (4, 4, 1281), 20,
+                             {"window": 2048}),
+    # Qwen3-Next's: 16 query heads on 2 KV heads of 256
+    "q3n-pass1": (64, 192, 128, 16, (1, 2, 640), 8, {"head_dim": 256}),
 }
 
 
@@ -183,18 +191,22 @@ _CELL_ATTENTION = {
 def test_ragged_paged_attention_at_the_cells_shapes(one_chip,
                                                     compiled_kernels, cell,
                                                     kv_dtype):
-    """The kernel as the three serving cells call it: the packed queries,
-    the whole pool, a traced layer, every KV head of a page in one copy
-    (16 x 32 KB for GPT, 4 x 32 KB for Falcon-H1 with its 640-row folded
-    query tile), a row's own positions copied in and out at any offset. A
+    """The kernel as the serving cells call it: the packed queries, the
+    whole pool, a traced layer, every KV head of a page in one copy (16 x
+    32 KB for GPT, 4 x 32 KB for Falcon-H1 with its 640-row folded query
+    tile), the wide arm's blocks of pages (8 MB of block buffers for GPT's
+    16 KV heads, Trinity-Mini's 2,048-row folded tile in 256-row
+    sub-tiles), a row's own positions copied in and out at any offset. A
     VMEM overrun or a refused slice shows here, without the chip."""
     from paddle_tpu.kernels.pallas.ragged_paged_attention import (
         ragged_paged_attention)
-    (rows, tokens, chunk, hq, (layers, hkv, pages),
-     table) = _CELL_ATTENTION[cell]
+    (rows, tokens, chunk, hq, (layers, hkv, pages), table,
+     *other) = _CELL_ATTENTION[cell]
+    other = other[0] if other else {}
+    head_dim, window = other.get("head_dim", HEAD_DIM), other.get("window")
     quant = kv_dtype == "int8"
-    q = _sds(one_chip, (tokens, hq, HEAD_DIM), jnp.bfloat16)
-    pool = _sds(one_chip, (layers, hkv, pages, PAGE, HEAD_DIM),
+    q = _sds(one_chip, (tokens, hq, head_dim), jnp.bfloat16)
+    pool = _sds(one_chip, (layers, hkv, pages, PAGE, head_dim),
                 jnp.int8 if quant else jnp.bfloat16)
     scales = (_sds(one_chip, (layers, hkv, pages), jnp.float32)
               if quant else None)
@@ -204,8 +216,8 @@ def test_ragged_paged_attention_at_the_cells_shapes(one_chip,
 
     def fn(q, kp, vp, tables, starts, q_lens, kv_lens, ks, vs, layer):
         return ragged_paged_attention(q, kp, vp, tables, starts, q_lens,
-                                      kv_lens, HEAD_DIM ** -0.5, ks, vs,
-                                      layer, c_att=chunk)
+                                      kv_lens, head_dim ** -0.5, ks, vs,
+                                      layer, c_att=chunk, window=window)
 
     compiled, text = _compile(fn, q, pool, pool, tables, lens, lens, lens,
                               scales, scales, layer)

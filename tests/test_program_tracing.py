@@ -40,6 +40,7 @@ from paddle_tpu.models import deepseek_v2 as DS  # noqa: E402
 from paddle_tpu.observability.trace import (ADMISSION_ATTRS,  # noqa: E402
                                             ADMIT_BLOCKED, COMPILE_CACHE,
                                             COMPILE_SPANS, DISPATCH_ATTRS,
+                                            CHUNK_DISPATCH_ATTRS,
                                             FIRST_CALL_ATTRS,
                                             FIRST_TOKEN_ATTRS, KERNELS,
                                             LATENT_DISPATCH_ATTRS,
@@ -206,7 +207,7 @@ def test_one_ragged_step_yields_every_serving_span_once():
     assert by_name[SERVING_SPANS.dispatch][0].attrs["in_flight"] == 1
     attrs = [e.attrs for e in first.events
              if e.name == SERVING_SPANS.dispatch][0]
-    assert tuple(attrs) == DISPATCH_ATTRS
+    assert tuple(attrs) == DISPATCH_ATTRS + CHUNK_DISPATCH_ATTRS
     kv = sum(end for end, _, _ in rows) + sum(
         end + j for j in range(1, k) for end, samples, left in rows
         if samples and left > j)
@@ -224,7 +225,10 @@ def test_one_ragged_step_yields_every_serving_span_once():
                      "attn_pages": pages, "kv_tiles": tiles, "in_flight": 0,
                      "n_starved": 0,
                      "pre_tokens": q_tokens - n_dec,
-                     "budget": eng.token_budget}
+                     "budget": eng.token_budget,
+                     # MHA and a chunk of 8: every row fits the kernel's
+                     # 8-row arm, nothing takes the wide one
+                     "chunk_pages": 0, "chunk_masked_pages": 0}
     # the admission span closes with what it did: nobody waited
     adm = [e.attrs for e in cap.events if e.name == SERVING_SPANS.admission]
     assert adm == [dict(zip(ADMISSION_ATTRS,
@@ -433,7 +437,8 @@ def test_the_hybrid_serving_step_carries_its_scopes_and_attributes():
         eng.step()
     attrs = [e.attrs for e in cap.events
              if e.name == SERVING_SPANS.dispatch][0]
-    assert tuple(attrs) == DISPATCH_ATTRS + SSM_DISPATCH_ATTRS
+    assert tuple(attrs) == (DISPATCH_ATTRS + CHUNK_DISPATCH_ATTRS
+                            + SSM_DISPATCH_ATTRS)
     assert (attrs["ssm_scan_rows"], attrs["ssm_update_rows"],
             attrs["ssm_tokens"]) == (1, 0, 6)
 
@@ -462,7 +467,8 @@ def test_the_pattern_serving_step_carries_its_scopes_and_attributes():
     with obs.capture_spans() as cap:
         eng.run()
     disp = [e.attrs for e in cap.events if e.name == SERVING_SPANS.dispatch]
-    assert tuple(disp[0]) == DISPATCH_ATTRS + SSM_DISPATCH_ATTRS
+    assert tuple(disp[0]) == (DISPATCH_ATTRS + CHUNK_DISPATCH_ATTRS
+                              + SSM_DISPATCH_ATTRS)
     fetch = [e.attrs for e in cap.events if e.name == SERVING_SPANS.fetch]
     assert all(tuple(a) == MOE_FETCH_ATTRS for a in fetch)
     # every landed step's counts ride the fetch that landed it: the first
@@ -546,16 +552,24 @@ def test_the_windowed_serving_step_carries_its_scopes_and_attributes():
     with obs.capture_spans() as cap:
         eng.run()
     disp = [e.attrs for e in cap.events if e.name == SERVING_SPANS.dispatch]
-    assert all(tuple(a) == DISPATCH_ATTRS + WINDOW_DISPATCH_ATTRS
-               for a in disp)
+    assert all(tuple(a) == (DISPATCH_ATTRS + CHUNK_DISPATCH_ATTRS
+                            + WINDOW_DISPATCH_ATTRS) for a in disp)
     # the first step ran 8 prompt tokens from position 0: every layer
     # reads the row's 8 positions (1 full + 4 window layers), 2 pages
     assert disp[0]["kv_tokens"] == 8 and disp[0]["kv_layer_tokens"] == 40
     assert disp[0]["win_attn_pages"] == 2 and disp[0]["win_pages_freed"] == 0
+    # a chunk of 8 on two query heads a KV head takes the kernel's wide arm
+    # (a decode row its 8-row arm), and at these toy pages one block of
+    # pages holds a row's whole context, so every page it walks is in a
+    # masked block: 2 pages in each of the 5 layers
+    assert (disp[0]["chunk_pages"], disp[0]["chunk_masked_pages"]) == (10, 10)
     # the third (positions 16-20) sees 7 + 5 positions under the window
     # and 21 without; the page behind the window went back at its pack
     assert disp[2]["kv_tokens"] == 21 and disp[2]["win_pages_freed"] >= 1
     assert disp[2]["k"] == 1 and disp[2]["kv_layer_tokens"] == 21 + 4 * 12
+    # ... 6 pages in the full layer, pages 2-5 in each window layer
+    assert disp[2]["chunk_pages"] == 6 + 4 * 4 == disp[2]["chunk_masked_pages"]
+    assert all(a["chunk_pages"] == 0 for a in disp if a["n_pre"] == 0)
     assert sum(a["win_pages_freed"] for a in disp) == eng.window_pages_freed
     fetch = [e.attrs for e in cap.events if e.name == SERVING_SPANS.fetch]
     assert all(tuple(a) == MOE_FETCH_ATTRS for a in fetch)
@@ -1059,7 +1073,8 @@ def test_no_scope_or_serving_span_is_a_free_string():
 SLICE_FILES = sorted(map(os.path.basename, glob.glob(
     os.path.join(DATA, "ptrace-*.json.gz"))))
 # what each span may carry, by the tuple the call site takes it from
-SPAN_ATTRS = {SERVING_SPANS.dispatch: (DISPATCH_ATTRS + SSM_DISPATCH_ATTRS
+SPAN_ATTRS = {SERVING_SPANS.dispatch: (DISPATCH_ATTRS + CHUNK_DISPATCH_ATTRS
+                                       + SSM_DISPATCH_ATTRS
                                        + LATENT_DISPATCH_ATTRS
                                        + WINDOW_DISPATCH_ATTRS),
               SERVING_SPANS.fetch: MOE_FETCH_ATTRS + MOE_LOCAL_FETCH_ATTRS,

@@ -5,6 +5,7 @@ scheduling, the quantized KV pool (capacity + determinism), TP int8
 weights, and the telemetry-driven adaptive prefill/decode mix."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -47,6 +48,35 @@ def mk(params, **kw):
                 max_blocks_per_seq=8, chunk=8, adaptive_mix=False)
     base.update(kw)
     return ServingEngine(params, CFG, **base)
+
+
+# what `mk` builds when it is told nothing else (the flags' defaults)
+_MK_DEFAULTS = dict(decode_burst=8, kv_cache_dtype="auto", max_batch=2,
+                    adaptive_mix=False, mesh=None, int8=False)
+
+
+@pytest.fixture(scope="module")
+def engines(params):
+    """`engines(**kw)` is `mk(params, **kw)`, built ONCE a geometry and
+    handed to every test that asks for the same one: most of a serving test
+    is tracing and compiling the same unified step again (ROADMAP Queue 3
+    item 1). A test takes an engine with no work in it and leaves it so
+    (`run()` drains it): its pages are free again, its compiled steps stay.
+    What it counts over its life (steps, dispatches, micro-steps) a test
+    reads as a difference. A test that needs a FRESH engine (new pools, new
+    compiles, a patched program) still calls `mk`."""
+    built = {}
+
+    def get(**kw):
+        kw = {k: v for k, v in kw.items()
+              if k not in _MK_DEFAULTS or _MK_DEFAULTS[k] != v}
+        key = tuple(sorted(kw.items()))
+        if key not in built:
+            built[key] = mk(params, **kw)
+        eng = built[key]
+        assert not eng.has_work() and not eng.queue
+        return eng
+    return get
 
 
 # ---------------------------------------------------------------------------
@@ -117,6 +147,7 @@ def _owned(starts, q_lens, T):
 
 
 # (q_lens, kv_lens) a batch; bs = 8, C = 6, five table slots (40 positions)
+# or as many as the longest row fills
 _KERNEL_BATCHES = {
     # row 0 decode, row 1 prefill chunk mid-sequence, row 2 EMPTY
     # (finished slot), row 3 fresh prefill
@@ -128,6 +159,13 @@ _KERNEL_BATCHES = {
     # kv_len at 1, bs, bs + 1, and into the table's last page (its first
     # position, its last)
     "edges": ([1, 1, 1, 1, 6, 3], [1, 8, 9, 33, 40, 40]),
+    # the wide arm's BLOCKS (8 pages = 64 positions an update at these
+    # sizes; a table of 19 pages): a chunk whose context is three blocks
+    # with a ragged last one (19 pages: 8 + 8 + 3), one that ends exactly
+    # on a block's edge (16 pages), one shorter than a block, each between
+    # decode rows: the stream's hand-over from a paged row to a blocked one
+    # and back
+    "blocks": ([1, 6, 1, 6, 6, 1], [70, 150, 9, 128, 20, 130]),
 }
 
 
@@ -161,7 +199,9 @@ def test_ragged_kernel_matches_composed_reference(hq, hkv, batch,
     packed in row order, three positions of padding behind them."""
     rng = np.random.RandomState(0)
     q_lens, kv_lens = (np.array(a, np.int32) for a in _KERNEL_BATCHES[batch])
-    C, D, bs, nb, NB = 6, 16, 8, 5, 32
+    C, D, bs = 6, 16, 8
+    pages = -(-kv_lens // bs)       # the table as wide as the longest row
+    nb, NB = max(5, int(pages.max())), max(32, int(pages.sum()) + 1)
     kp = jnp.asarray(rng.randn(hkv, NB, bs, D).astype(np.float32))
     vp = jnp.asarray(rng.randn(hkv, NB, bs, D).astype(np.float32))
     tables = _own_pages(kv_lens, bs, nb)
@@ -178,12 +218,21 @@ def test_ragged_kernel_matches_composed_reference(hq, hkv, batch,
     assert (out[~_owned(starts, q_lens, T)] == 0).all()
 
 
-@pytest.mark.parametrize("hq,hkv", [(4, 4), (20, 4)])
-def test_ragged_kernel_arms_agree_on_a_decode_row(hq, hkv):
+@pytest.mark.parametrize("hq,hkv,tile_rows", [(4, 4, None), (20, 4, None),
+                                              (20, 4, 64)])
+def test_ragged_kernel_arms_agree_on_a_decode_row(hq, hkv, tile_rows,
+                                                  monkeypatch):
     """The same decode rows through a c_att = 1 call (the burst passes'
     form: T = R, starts = arange(R), one arm, an 8-row tile) and as
     q_len = 1 rows of a c_att = 128 call beside a full chunk (pass 1: the
-    narrow arm of a 128 * g-row tile) give the same output."""
+    narrow arm of a 128 * g-row tile) give the same output. With sub-tiles
+    of 64 rows the chunk row's 640 folded rows are walked in ten, each
+    half a chunk of one query head: a sub-tile's first chunk position is
+    in its mask."""
+    if tile_rows:
+        from paddle_tpu.kernels.pallas import ragged_paged_attention as mod
+        monkeypatch.setattr(mod, "_TILE_ROWS", tile_rows)
+        assert mod._tile_rows(hq // hkv, 128) == tile_rows
     rng = np.random.RandomState(5)
     R, C, D, bs, nb, NB = 4, 128, 16, 16, 9, 24
     kp = jnp.asarray(rng.randn(hkv, NB, bs, D).astype(np.float32))
@@ -220,7 +269,7 @@ def test_ragged_kernel_quantized_scales_are_per_head(kv_dtype):
     from paddle_tpu.kernels.pallas.ragged_paged_attention import (
         ragged_paged_attention)
     rng = np.random.RandomState(9)
-    L, hq, hkv, NB, bs, D, C, nb = 2, 8, 4, 12, 8, 16, 12, 3
+    L, hq, hkv, NB, bs, D, C, nb = 2, 8, 4, 20, 8, 16, 12, 10
     if kv_dtype == "int8":
         qmax, store = 127.0, jnp.int8
         grid = rng.randint(-127, 128, (2, L, hkv, NB, bs, D))
@@ -232,7 +281,9 @@ def test_ragged_kernel_quantized_scales_are_per_head(kv_dtype):
     ks = (rng.rand(L, hkv, NB) + 0.5) * per_head[None, :, None]
     vs = (rng.rand(L, hkv, NB) + 0.5) * per_head[None, ::-1, None]
     q_lens = np.array([1, 12, 0, 3], np.int32)      # narrow, wide, -, narrow
-    kv_lens = np.array([20, 12, 0, 9], np.int32)
+    # the wide row's 10 pages are two blocks of its arm (8 + 2): a scale a
+    # (head, page) INSIDE one soft-max update
+    kv_lens = np.array([20, 76, 0, 9], np.int32)
     tables = _own_pages(kv_lens, bs, nb)
     starts, T = _pack(q_lens, order=[0, 3, 1], tail=1)
     q = jnp.asarray(rng.randn(T, hq, D).astype(np.float32))
@@ -706,11 +757,11 @@ def test_step_writes_only_its_own_pages(params, kv_cache_dtype):
                                               was[:, :, page, n:])
 
 
-def test_one_dispatch_per_step_and_program_cache(params):
+def test_one_dispatch_per_step_and_program_cache(params, engines):
     rng = np.random.RandomState(1)
     prompts = [rng.randint(0, CFG.vocab_size, (n,)) for n in (5, 13, 9, 16)]
     news = [6, 3, 9, 4]
-    eng = mk(params)
+    eng = engines()
     rids = [eng.add_request(p, n) for p, n in zip(prompts, news)]
     res = eng.run()
     # exactly ONE compiled dispatch per engine step
@@ -743,13 +794,13 @@ def test_the_engine_is_one_however_it_is_asked_for(params):
 # ---------------------------------------------------------------------------
 # engine: ragged goldens (streaming, eos, temperature-0 determinism)
 # ---------------------------------------------------------------------------
-def test_ragged_streaming_and_eos(params):
+def test_ragged_streaming_and_eos(params, engines):
     rng = np.random.RandomState(4)
     prompt = rng.randint(0, CFG.vocab_size, (9,))
     g = golden(params, prompt, 10)
     eos = g[3]
     seen = []
-    eng = mk(params, max_batch=1)
+    eng = engines()
     rid = eng.add_request(prompt, 10, eos_id=eos,
                           on_token=lambda r, t: seen.append((r, t)))
     res = eng.run()
@@ -861,33 +912,35 @@ def test_int8_kv_admits_2x_sequences_at_fixed_budget():
     assert n_int8 / n_bf16 >= 1.9, (n_int8, n_bf16)
 
 
-def _int8_run(params, prompts, news, kv):
-    eng = mk(params, kv_cache_dtype=kv)
+def _int8_run(eng, prompts, news):
     rids = [eng.add_request(p, n) for p, n in zip(prompts, news)]
     res = eng.run()
     return [res[r] for r in rids]
 
 
-def test_int8_kv_outputs_deterministic(params):
+def test_int8_kv_outputs_deterministic(params, engines):
     """Acceptance: the quantized-KV run is bitwise-deterministic across
-    repeats (two FRESH engines — new pools, new compiles)."""
+    repeats: a FRESH engine (new pools, new compiles) serves what the
+    module's int8 engine serves, whatever that one has served before, and
+    serves it again."""
     rng = np.random.RandomState(9)
     prompts = [rng.randint(0, CFG.vocab_size, (n,)) for n in (9, 13)]
     news = [6, 6]
-    q1 = _int8_run(params, prompts, news, "int8")
-    q2 = _int8_run(params, prompts, news, "int8")
-    assert q1 == q2
+    fresh = mk(params, kv_cache_dtype="int8")
+    q1 = _int8_run(fresh, prompts, news)
+    q2 = _int8_run(engines(kv_cache_dtype="int8"), prompts, news)
+    assert q1 == q2 == _int8_run(fresh, prompts, news)
 
 
-def test_int8_kv_outputs_close_to_float(params):
+def test_int8_kv_outputs_close_to_float(engines):
     """int8 storage error stays token-level small vs the float pool
     (slow tier; the kernel-level bound is the fast-tier
     test_ragged_kernel_int8_pool_close)."""
     rng = np.random.RandomState(9)
     prompts = [rng.randint(0, CFG.vocab_size, (n,)) for n in (9, 13)]
     news = [6, 6]
-    fp = _int8_run(params, prompts, news, "auto")
-    q1 = _int8_run(params, prompts, news, "int8")
+    fp = _int8_run(engines(), prompts, news)
+    q1 = _int8_run(engines(kv_cache_dtype="int8"), prompts, news)
     total = sum(len(o) for o in fp)
     agree = sum(a == b for o1, o2 in zip(fp, q1)
                 for a, b in zip(o1, o2))
@@ -928,6 +981,7 @@ def test_page_scale_reset_on_block_reuse(params):
 # ---------------------------------------------------------------------------
 # TP: ragged path + the int8-weight satellite (exact parity)
 # ---------------------------------------------------------------------------
+@functools.lru_cache(maxsize=None)
 def _mesh4():
     from jax.sharding import Mesh
     return Mesh(np.array(jax.devices()[:4]), ("mp",))
@@ -945,7 +999,7 @@ def test_tp_ragged_matches_generate(params):
         assert res[rid] == golden(params, p, n), rid
 
 
-def test_tp_int8_weights_parity_smoke(params):
+def test_tp_int8_weights_parity_smoke(engines):
     """Fast-tier satellite gate: int8 W8A8 weights under TP reproduce
     the dense int8 engine exactly (one request; the multi-request run
     is in the slow tier)."""
@@ -953,14 +1007,14 @@ def test_tp_int8_weights_parity_smoke(params):
     prompt = rng.randint(0, CFG.vocab_size, (9,))
 
     def run(mesh):
-        eng = mk(params, int8=True, mesh=mesh)
+        eng = engines(int8=True, mesh=mesh)
         rid = eng.add_request(prompt, 5)
         return eng.run()[rid]
 
     assert run(None) == run(_mesh4())
 
 
-def test_tp_int8_weights_match_dense_int8_exactly(params):
+def test_tp_int8_weights_match_dense_int8_exactly(engines):
     """Satellite: int8 weights under TP serving — per-output-channel
     scales shard with the weight shards; the row-parallel sites share
     the activation scale (pmax) and psum the INT32 accumulator, so the
@@ -970,7 +1024,7 @@ def test_tp_int8_weights_match_dense_int8_exactly(params):
     news = [6, 5, 7]
 
     def run(mesh):
-        eng = mk(params, int8=True, mesh=mesh)
+        eng = engines(int8=True, mesh=mesh)
         rids = [eng.add_request(p, n) for p, n in zip(prompts, news)]
         res = eng.run()
         return [res[r] for r in rids]
@@ -978,11 +1032,11 @@ def test_tp_int8_weights_match_dense_int8_exactly(params):
     assert run(None) == run(_mesh4())
 
 
-def test_tp_int8_kv_pool(params):
+def test_tp_int8_kv_pool(params, engines):
     """int8 KV + TP compose on the ragged path (scales head-sharded)."""
     rng = np.random.RandomState(14)
     prompt = rng.randint(0, CFG.vocab_size, (9,))
-    dense = mk(params, kv_cache_dtype="int8")
+    dense = engines(kv_cache_dtype="int8")
     rd = dense.add_request(prompt, 6)
     tp = mk(params, kv_cache_dtype="int8", mesh=_mesh4())
     rt = tp.add_request(prompt, 6)
@@ -992,41 +1046,42 @@ def test_tp_int8_kv_pool(params):
 # ---------------------------------------------------------------------------
 # adaptive prefill/decode mix (telemetry-driven)
 # ---------------------------------------------------------------------------
-def test_adaptive_mix_shortens_bursts_under_pressure(params):
+def test_adaptive_mix_shortens_bursts_under_pressure(params, engines):
     rng = np.random.RandomState(15)
     prompts = [rng.randint(0, CFG.vocab_size, (6,)) for _ in range(6)]
     news = [8] * 6
 
     def mean_burst(adaptive):
-        eng = mk(params, decode_burst=8,
-                 adaptive_mix=adaptive)
+        eng = engines(decode_burst=8, adaptive_mix=adaptive)
+        micro0, steps0 = eng.decode_microsteps, eng.engine_steps
         rids = [eng.add_request(p, n) for p, n in zip(prompts, news)]
         res = eng.run()
         for rid, p, n in zip(rids, prompts, news):
             assert res[rid] == golden(params, p, n)
-        return eng.decode_microsteps / eng.engine_steps
+        return ((eng.decode_microsteps - micro0)
+                / (eng.engine_steps - steps0))
 
     # queue pressure (6 requests, 2 slots) -> shorter bursts than fixed
     assert mean_burst(True) < mean_burst(False)
 
 
-def test_adaptive_mix_full_burst_when_idle(params):
+def test_adaptive_mix_full_burst_when_idle(params, engines):
     rng = np.random.RandomState(16)
-    eng = mk(params, max_batch=2, decode_burst=8,
-             adaptive_mix=True)
+    eng = engines(max_batch=2, decode_burst=8, adaptive_mix=True)
+    micro0, steps0 = eng.decode_microsteps, eng.engine_steps
     prompt = rng.randint(0, CFG.vocab_size, (5,))
     rid = eng.add_request(prompt, 9)
     res = eng.run()
     assert res[rid] == golden(params, prompt, 9)
     # after prefill completes the queue is empty -> full bursts ran:
     # 9 tokens in few steps (prefill step + one full burst step)
-    assert eng.engine_steps <= 3
-    assert eng.decode_microsteps >= 8
+    assert eng.engine_steps - steps0 <= 3
+    assert eng.decode_microsteps - micro0 >= 8
 
 
-def test_dispatch_metrics_exported(params):
+def test_dispatch_metrics_exported(engines):
     rng = np.random.RandomState(17)
-    eng = mk(params)
+    eng = engines()
     eng.add_request(rng.randint(0, CFG.vocab_size, (5,)), 4)
     eng.run()
     text = eng.metrics_text()

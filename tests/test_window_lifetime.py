@@ -177,13 +177,24 @@ ARMS = {
     # starts mid-page, its last query's a page later), one from position
     # 0, a ragged tail, a single token beside them
     "chunk": (8, [8, 8, 3, 1], [22, 0, 9, 30]),
-    "chunk-odd": (8, [5, 0, 7, 2], [11, 0, 33, 6])}
+    "chunk-odd": (8, [5, 0, 7, 2], [11, 0, 33, 6]),
+    # the wide arm's BLOCKS, at two pages (8 positions) a block under a
+    # window of 24 (a ring of 10): a chunk whose window starts mid-block
+    # (first query at 50 sees 27-50: its row starts at page 6, the pages
+    # behind it NaN) and whose blocks are edge, edge, NO edge (40-47:
+    # behind every query, inside every window), edge (its own positions),
+    # edge (a last page and an unused slice); a chunk from position 0; a ragged
+    # chunk with two unmasked blocks; a decode row behind them
+    "chunk-blocks": (8, [8, 8, 5, 1], [50, 0, 41, 60])}
+# (window, ring, pages a block) where an arm does not take the toy model's
+GEOMETRY = {"chunk-blocks": (24, 10, 2)}
 
 
-def windowed_case(arm, window=8, bs=4, hq=4, hkv=2, D=16, layers=2, layer=1):
+def windowed_case(arm, bs=4, hq=4, hkv=2, D=16, layers=2, layer=1):
     c_att, q_lens, pos0 = ARMS[arm]
+    window, nbw, _ = GEOMETRY.get(arm, (8, RING, None))
     rng = np.random.default_rng(len(arm))
-    R_, nbw = len(q_lens), RING
+    R_ = len(q_lens)
     q_lens, pos0 = np.asarray(q_lens), np.asarray(pos0)
     kv_lens = pos0 + q_lens
     T = int(q_lens.sum()) + 3
@@ -223,7 +234,7 @@ def windowed_case(arm, window=8, bs=4, hq=4, hkv=2, D=16, layers=2, layer=1):
                 p = np.exp(s - s.max())
                 want[starts[r] + c, h] = (p / p.sum()) @ vals[r, js, h // g]
     return (q, kp, vp, tables, starts, q_lens, kv_lens, D ** -0.5, layer,
-            c_att, want)
+            c_att, window, want)
 
 
 @pytest.mark.parametrize("memory", ["copied", "aliased"])
@@ -235,13 +246,16 @@ def test_the_windowed_kernel_against_a_dense_masked_softmax(arm, memory,
     a key behind the edge that is not masked, shows in the output."""
     if memory == "aliased":
         request.getfixturevalue("aliasing")
-    (q, kp, vp, tables, starts, q_lens, kv_lens, scale, layer, c_att,
+    if arm in GEOMETRY:
+        request.getfixturevalue("monkeypatch").setattr(
+            RPA, "_BLOCK_PAGES", GEOMETRY[arm][2])
+    (q, kp, vp, tables, starts, q_lens, kv_lens, scale, layer, c_att, window,
      want) = windowed_case(arm)
     got = RPA.ragged_paged_attention(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
         jnp.asarray(tables), jnp.asarray(starts, jnp.int32),
         jnp.asarray(q_lens, jnp.int32), jnp.asarray(kv_lens, jnp.int32),
-        scale, None, None, jnp.int32(layer), c_att=c_att, window=8)
+        scale, None, None, jnp.int32(layer), c_att=c_att, window=window)
     got = np.asarray(got)
     assert np.isfinite(got).all()
     np.testing.assert_allclose(got, want, atol=2e-6)
@@ -256,3 +270,33 @@ def test_the_windowed_kernel_against_a_dense_masked_softmax(arm, memory,
                                atol=1e-3)
 
 
+
+
+def test_the_host_counts_the_wide_arms_blocks_as_the_kernel_takes_them(
+        monkeypatch):
+    """`wide_arm_pages` (the dispatch span's `chunk_pages` and
+    `chunk_masked_pages`) by hand. Trinity-Mini's geometry: 8 query heads a
+    KV head, chunks of 256, pages of 128 x 128 bf16, so 8 pages a block.
+    A chunk that ends at 8,192: 64 pages without a window, of which the
+    last block's 8 hold its own positions; under the window of 2,048 it
+    starts at page 46 and walks 18 pages: the first block's 8 (the trailing
+    edge), none of the second's, the last 2. Decode rows take the other arm.
+    Then the toy case above, block by block."""
+    trinity = dict(hq=32, hkv=4, bs=128, D=128, itemsize=2, c_att=256)
+    assert RPA.wide_arm_pages([256, 1, 0], [8192, 5000, 0],
+                              **trinity) == (64, 8)
+    assert RPA.wide_arm_pages([256, 1, 0], [8192, 5000, 0], window=2048,
+                              **trinity) == (18, 10)
+    # a burst pass holds one token a row: 8 folded rows, the narrow arm
+    assert RPA.wide_arm_pages([1, 1], [70, 9000], **dict(trinity, c_att=1)) \
+        == (0, 0)
+    c_att, q_lens, pos0 = ARMS["chunk-blocks"]
+    window, _, pages = GEOMETRY["chunk-blocks"]
+    monkeypatch.setattr(RPA, "_BLOCK_PAGES", pages)
+    toy = dict(hq=4, hkv=2, bs=4, D=16, itemsize=4, c_att=c_att,
+               window=window)
+    kv_lens = np.add(q_lens, pos0)
+    # row 0: pages 6-14, all but 10-11 in an edge block; row 1: its two
+    # pages; row 2: pages 4-11, the first and the last block
+    assert RPA.wide_arm_pages(q_lens, kv_lens, **toy) == (9 + 2 + 8,
+                                                          7 + 2 + 4)
